@@ -8,11 +8,6 @@ parallelism is sharding + ppermute instead of MPI send/recv.  No CUDA, NCCL
 or mpi4py anywhere in the import graph.
 """
 
-from . import _compat
-
-_compat.install()  # jax version shims (shard_map name, axis_size) — must
-# run before any submodule resolves those symbols.
-
 from . import extensions, functions, global_except_hook, iterators, links, observability, ops, parallel, runtime, serving, training  # noqa: F401,E402
 from .runtime import (FileDataset, PrefetchIterator,  # noqa: F401
                       write_file_dataset)

@@ -1,188 +1,51 @@
-"""JAX version-compat shims.
+"""Thin names over the one installed JAX.
 
-The codebase targets current JAX (top-level ``jax.shard_map``, vma
-tracking, ``jax.lax.axis_size``), but deployment floors — including this
-container's jax 0.4.37 — predate those.  Everything internal imports
-``shard_map`` from here instead of from ``jax`` so the package imports
-and the core SPMD paths (communicators, train steps, collectives) run on
-both sides of the rename.
-
-``install()`` additionally publishes the shims onto the ``jax`` module
-itself (``jax.shard_map``, ``jax.lax.axis_size``) when missing, so
-sibling code and tests written against new JAX (`from jax import
-shard_map`) keep working.  It never overwrites an existing attribute.
+The code is written for exactly the stack the sandbox and the chip
+machine carry: jax/jaxlib 0.9.0, libtpu 0.0.34, flax 0.12.3, optax
+0.2.6, Python 3.12.  Every API the package needs is native there
+(``jax.shard_map`` with vma checking, ``jax.lax.axis_size``,
+``jax.lax.pcast``, ``jax.typeof``, ``ShapeDtypeStruct(vma=)``,
+``pltpu.CompilerParams``, a differentiable ``optimization_barrier``),
+so this module holds no version branches — only the short names the
+package and its tests import from one place.
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
+from jax import shard_map  # noqa: F401  (re-exported: 30+ internal imports)
 
-try:  # new JAX: top-level export, `check_vma` kwarg
-    from jax import shard_map as _shard_map
-    _LEGACY = False
-except ImportError:  # jax <= 0.4.x: experimental module, `check_rep` kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _LEGACY = True
-
-
-@functools.wraps(_shard_map)
-def shard_map(f, **kwargs):
-    """``jax.shard_map`` on new JAX; the experimental one on old JAX.
-
-    On legacy JAX the ``check_vma`` argument is dropped and the old
-    replication checker (``check_rep``) DEFAULTS to off: it predates
-    ``pallas_call`` (no replication rule) and the newer scan-carry vma
-    typing, so programs that type-check under the current vma system —
-    what this codebase targets — are rejected by its rules even though
-    their math is correct (the parity/oracle tests exercise the
-    numerics directly).  A caller that explicitly passes ``check_rep``
-    is legacy-aware and keeps whatever it asked for; ``check_vma`` is
-    honored verbatim on new JAX.
-    """
-    if _LEGACY:
-        kwargs.pop("check_vma", None)
-        kwargs.setdefault("check_rep", False)
-    return _shard_map(f, **kwargs)
-
-
-# Resolved ONCE at import (before install() can publish our own shim
-# onto jax.lax — reading it lazily would recurse into ourselves).
-_NATIVE_AXIS_SIZE = getattr(jax.lax, "axis_size", None)
-
-
-def axis_size(axis_name):
-    """``jax.lax.axis_size`` where it exists; the ``psum(1, axis)``
-    identity (which lowers to the static axis size) everywhere else."""
-    if _NATIVE_AXIS_SIZE is not None:
-        return _NATIVE_AXIS_SIZE(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-_NATIVE_PCAST = getattr(jax.lax, "pcast", None)
-_NATIVE_PVARY = getattr(jax.lax, "pvary", None)
+axis_size = jax.lax.axis_size
+typeof = jax.typeof
+optimization_barrier = jax.lax.optimization_barrier
 
 
 def pcast_varying(x, axis_names):
-    """Promote a replicated value to varying over ``axis_names`` where
-    vma tracking exists (``pcast`` on current JAX, ``pvary`` on the
-    interim releases); identity on jax without vma tracking (0.4.x),
-    where the replicated/varying distinction does not exist and autodiff
-    of a replicated input already yields per-rank local cotangents
-    (verified against 0.4.37)."""
-    if _NATIVE_PCAST is not None:
-        return _NATIVE_PCAST(x, axis_names, to="varying")
-    if _NATIVE_PVARY is not None:
-        return _NATIVE_PVARY(x, axis_names)
-    return x
-
-
-def ad_inserts_replicated_psum() -> bool:
-    """Whether autodiff of a shard_map with REPLICATED params inserts the
-    cross-rank cotangent psum into the traced program.
-
-    True on vma-tracking jax (native ``pcast``/``pvary``): replicated
-    inputs carry a type-level broadcast whose transpose is a psum, so the
-    gradient all-reduce is a visible jaxpr equation.  False on 0.4.x
-    (``check_rep=False`` legacy shard_map): cotangents of replicated
-    inputs stay per-rank local and NO psum equation exists — which is why
-    ``train.py`` books that traffic via ``observability.comm.note`` and
-    why the shard-flow reconciliation (analysis/shardflow.py) gates the
-    noted row's expected equation on this probe.
-    """
-    return _NATIVE_PCAST is not None or _NATIVE_PVARY is not None
-
-
-try:
-    import inspect
-    _SDS_HAS_VMA = "vma" in inspect.signature(
-        jax.ShapeDtypeStruct.__init__).parameters
-except (ValueError, TypeError):  # pragma: no cover - exotic builds
-    _SDS_HAS_VMA = False
+    """Promote a replicated value to varying over ``axis_names``."""
+    return jax.lax.pcast(x, axis_names, to="varying")
 
 
 def shape_dtype_struct(shape, dtype, vma=None):
-    """``jax.ShapeDtypeStruct`` with the ``vma`` annotation dropped on
-    jax versions whose avals carry no varying-mesh-axes type."""
-    if _SDS_HAS_VMA:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
+    """``jax.ShapeDtypeStruct`` carrying the varying-mesh-axes type."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
-_NATIVE_TYPEOF = getattr(jax, "typeof", None)
+def all_gather_invariant(x, axis_name, *, axis: int = 0, tiled: bool = False):
+    """All-gather whose result is typed replication-INVARIANT.
 
-
-def typeof(x):
-    """``jax.typeof`` (current JAX) / ``jax.core.get_aval`` (0.4.x).
-
-    Legacy avals carry no ``vma`` field, which is exactly right: callers
-    read ``getattr(typeof(x), "vma", frozenset())`` and take their
-    no-vma-tracking fallback path."""
-    if _NATIVE_TYPEOF is not None:
-        return _NATIVE_TYPEOF(x)
-    return jax.core.get_aval(x)
-
-
-def _diffable_optimization_barrier():
-    """Whether this jax can differentiate ``optimization_barrier``
-    (rule added after 0.4.37); probed once, lazily, with a scalar jvp."""
-    global _OPT_BARRIER_DIFFABLE
-    if _OPT_BARRIER_DIFFABLE is None:
-        try:
-            jax.jvp(jax.lax.optimization_barrier, (1.0,), (1.0,))
-            _OPT_BARRIER_DIFFABLE = True
-        except NotImplementedError:
-            _OPT_BARRIER_DIFFABLE = False
-    return _OPT_BARRIER_DIFFABLE
-
-
-_OPT_BARRIER_DIFFABLE = None
-_BARRIER_VJP = None
-
-
-def optimization_barrier(args):
-    """Differentiable ``jax.lax.optimization_barrier``.
-
-    Native where the differentiation rule exists; on legacy jax (0.4.37:
-    ``NotImplementedError: Differentiation rule for 'optimization_barrier'``)
-    a ``custom_vjp`` wrapper with the same semantics — value identity,
-    scheduling edge on the forward, and the cotangents barriered too so
-    the BACKWARD pass keeps the ordering edge (the reference
-    pseudo_connect's whole point was backward ordering)."""
-    if _diffable_optimization_barrier():
-        return jax.lax.optimization_barrier(args)
-
-    global _BARRIER_VJP
-    if _BARRIER_VJP is None:
-        @jax.custom_vjp
-        def barrier(a):
-            return jax.lax.optimization_barrier(a)
-
-        def fwd(a):
-            return barrier(a), None
-
-        def bwd(_, ct):
-            return (jax.lax.optimization_barrier(ct),)
-
-        barrier.defvjp(fwd, bwd)
-        _BARRIER_VJP = barrier
-    return _BARRIER_VJP(args)
+    Under 0.9.0's vma rules ``jax.lax.all_gather`` is varying → varying,
+    so a value that has to leave ``shard_map`` through ``out_specs=P()``
+    (the int8 ring's gathered gradient on its way to the optimizer)
+    cannot end in it.  The varying → invariant form has the same
+    lowering and the same wire bytes; 0.9.0 ships it but exports it
+    under no public name, hence the one private import of the package.
+    """
+    from jax._src.lax.parallel import all_gather_invariant as gather
+    return gather(x, axis_name, axis=axis, tiled=tiled)
 
 
 def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` (current name) / ``TPUCompilerParams``
-    (pre-rename) — resolved lazily so importing this module never pulls
-    Pallas in."""
+    """``pltpu.CompilerParams`` — resolved lazily so importing this
+    module never pulls Pallas in."""
     from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
-
-
-def install() -> None:
-    """Idempotently publish missing new-JAX names onto ``jax`` itself."""
-    if not hasattr(jax, "shard_map"):
-        jax.shard_map = shard_map
-    if not hasattr(jax.lax, "axis_size"):
-        jax.lax.axis_size = axis_size
+    return pltpu.CompilerParams(**kwargs)

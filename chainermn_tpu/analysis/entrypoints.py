@@ -99,11 +99,15 @@ def _build_decode_tick() -> Dict[str, Any]:
         return lm_prefill(p, pr, total, head_dim=head_dim,
                           axis_name="model")
 
+    # the pool's own cache spec (serving/cache_pool.py): the flat K/V
+    # rows shard their head dimension over 'model', so they are typed
+    # varying over it and cannot leave through P()
+    kv = P(None, None, "model")
     sm_prefill = shard_map(prefill, mesh=mesh, in_specs=(specs, P()),
-                           out_specs=(P(), [(P(), P())]))
+                           out_specs=(P(), [(kv, kv)]))
     _, caches = sm_prefill(params, jnp.asarray(prompt))
 
-    cache_specs = [(P(), P()) for _ in caches]
+    cache_specs = [(kv, kv) for _ in caches]
     sm_tick = jax.jit(shard_map(
         tick, mesh=mesh, in_specs=(specs, P(), cache_specs, P()),
         out_specs=(P(), cache_specs)))
@@ -124,9 +128,8 @@ def _build_decode_tick() -> Dict[str, Any]:
             "bound_axes": {"model"},
             "variants": variants,
             # shard-flow: TP shards the matmul weights over 'model';
-            # norm scales/biases stay replicated by the Megatron layout,
-            # and the KV pool rows are whole per replica at this
-            # registration's cache specs.  tokens/pos are deliberately
+            # norm scales/biases stay replicated by the Megatron layout;
+            # the KV pool rows shard their heads.  tokens/pos are deliberately
             # UN-annotated: two tiny host-fed vectors kept as baseline
             # keepers (with comments) proving the gate bites.
             "data_axis": "model",
@@ -135,9 +138,6 @@ def _build_decode_tick() -> Dict[str, Any]:
                 "params": "Megatron TP layout: matmul weights shard "
                           "over 'model', norm scales/biases/embedding "
                           "remainders replicate by design",
-                "caches": "KV pool rows are whole per replica at the "
-                          "registered cache specs (TP>1 shards heads "
-                          "inside the flat K/V rows)",
             }}
 
 
@@ -163,7 +163,7 @@ def _build_prefill_family() -> Dict[str, Any]:
 
     jfn = jax.jit(shard_map(
         prefill, mesh=mesh, in_specs=(specs, P()),
-        out_specs=(P(), [(P(), P())])))
+        out_specs=(P(), [(P(None, None, "model"),) * 2])))
 
     p2 = np.zeros((1, 2), np.int32)
     p3 = np.zeros((1, 3), np.int32)
@@ -312,9 +312,6 @@ def _build_router_tick() -> Dict[str, Any]:
                 "params": "Megatron TP layout: matmul weights shard "
                           "over 'model', norm scales/biases/embedding "
                           "remainders replicate by design",
-                "caches": "KV pool rows are whole per replica at the "
-                          "registered cache specs (TP>1 shards heads "
-                          "inside the flat K/V rows)",
                 "pos": "per-slot position vector: 4 host-fed bytes "
                        "copied to every TP rank each tick — the same "
                        "replication the base decode-tick entry keeps "
@@ -515,9 +512,7 @@ def _build_train_step() -> Dict[str, Any]:
     report must name the full optimizer-state replication ZeRO-1
     (ROADMAP item 2) will remove.  Its gradient all-reduce on the default
     path is AUTODIFF-INSERTED and booked via ``comm.note`` — declared
-    here as a ``noted`` row (held byte-exact by the reconciliation) —
-    and on legacy jax the transpose of the loss pmean adds one scalar
-    psum equation no wrapper books (``ad_transpose_bytes``)."""
+    here as a ``noted`` row (held byte-exact by the reconciliation)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -565,9 +560,7 @@ def _build_train_step() -> Dict[str, Any]:
             },
             # the AD-inserted gradient psum, booked by train.py's
             # comm.note at exactly the params' byte size
-            "noted": {"grad_allreduce_ad@mn": params_bytes},
-            # legacy jax: transpose(psum(loss)) is one more scalar psum
-            "ad_transpose_bytes": {"psum@mn": 4}}
+            "noted": {"grad_allreduce_ad@mn": params_bytes}}
 
 
 def _build_quantized_train_step() -> Dict[str, Any]:

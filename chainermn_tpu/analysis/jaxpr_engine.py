@@ -43,6 +43,8 @@ JAXPR_RULES: Dict[str, Tuple[str, str]] = {
 _COLLECTIVE_PRIMS = frozenset({
     "psum", "pmax", "pmin", "ppermute", "all_gather", "all_to_all",
     "reduce_scatter", "pgather", "psum_scatter",
+    # their names under vma typing (same wire collectives)
+    "psum_invariant", "all_gather_invariant",
 })
 
 
@@ -69,10 +71,7 @@ class EntryPoint:
       by design (or a named debt, e.g. optimizer state until ZeRO-1);
       must be DELETED when the sharding lands (stale-annotation check);
     * ``noted``: ``{ledger_row_key: bytes}`` — comm.note() bookings this
-      program performs (traffic no wrapper sees), held to account;
-    * ``ad_transpose_bytes``: ``{primitive@axis: bytes}`` — equations
-      legacy-jax autodiff adds by transposing a wrapped collective,
-      which the ledger cannot book (see shardflow module docs).
+      program performs (traffic no wrapper sees), held to account.
     """
 
     name: str
@@ -147,6 +146,29 @@ def collective_sequence(jaxpr) -> List[Tuple[str, Tuple[str, ...]]]:
     return seq
 
 
+def _died_in_vma_cast(fn, args) -> bool:
+    """Re-trace with jax's traceback filtering off and say whether the
+    AssertionError comes from ``jax._src.core.pvary`` — how 0.9.0
+    reports a psum/pmean/ppermute over an axis no mesh binds."""
+    import traceback
+
+    import jax
+
+    prev = jax.config.jax_traceback_filtering
+    jax.config.update("jax_traceback_filtering", "off")
+    try:
+        jax.make_jaxpr(fn)(*args)
+    except AssertionError as e:
+        last = traceback.extract_tb(e.__traceback__)[-1]
+        return (last.name == "pvary"
+                and os.path.join("jax", "_src") in last.filename)
+    except Exception:  # noqa: BLE001 - some other failure: not ours
+        return False
+    finally:
+        jax.config.update("jax_traceback_filtering", prev)
+    return False
+
+
 def check_entrypoint(ep: EntryPoint) -> Tuple[List[Finding], TraceReport]:
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
@@ -174,18 +196,30 @@ def check_entrypoint(ep: EntryPoint) -> Tuple[List[Finding], TraceReport]:
     bound: Set[str] = set(spec.get("bound_axes", ()))
 
     # ---- axis binding: trace, then walk the collective eqns ----
-    try:
-        jaxpr = jax.make_jaxpr(fn)(*args)
-    except NameError as e:
-        # jax raises NameError("unbound axis name: ...") at trace time
+    def unbound_axis(why) -> Tuple[List[Finding], TraceReport]:
         findings.append(Finding(
             rule="unbound-axis", severity="error", path=loc, line=0,
-            message=(f"tracing failed: {e} — the body names a mesh axis "
+            message=(f"tracing failed: {why} — the body names a mesh axis "
                      f"the enclosing binding ({sorted(bound)}) does not "
                      "provide; the compiled gang would never agree on "
                      "this collective"),
             context=ep.name, snippet=ep.description))
-        report.error = str(e)
+        report.error = str(why)
+        return findings, report
+
+    try:
+        jaxpr = jax.make_jaxpr(fn)(*args)
+    except NameError as e:
+        # jax raises NameError("unbound axis name: ...") at trace time…
+        return unbound_axis(e)
+    except AssertionError as e:
+        # …except for psum/pmean/ppermute, where 0.9.0 dies in the bare
+        # assert of its vma cast before it gets to look the axis up
+        if _died_in_vma_cast(fn, args):
+            return unbound_axis("unbound axis name (jax's vma cast "
+                                "asserted on an axis the mesh does not "
+                                "bind)")
+        engine_error("trace", e)
         return findings, report
     except Exception as e:  # noqa: BLE001
         engine_error("trace", e)

@@ -38,7 +38,7 @@ chunk's (same global elements), so staging can never smuggle wrong
 bytes.
 
 Everything here is stdlib + numpy; no jax import (the analysis-package
-contract).  Cost constants are the BENCH_r04 ``project_dp_scaling``
+contract).  Cost constants are the ``project_dp_scaling``
 assumptions in ``bench.py`` (v5e ICI 1.8e11 B/s, 1 µs/hop; DCN 2.5e10
 B/s per host) — the schedule chooser and the scaling projection price
 the same wire.
@@ -639,7 +639,7 @@ def candidate_schedules(shape, dtype, src_spec, dst_spec, src_world,
 
 @dataclass(frozen=True)
 class CostModel:
-    """Wire constants from BENCH_r04 ``project_dp_scaling`` (bench.py):
+    """Wire constants from ``project_dp_scaling`` (bench.py):
     v5e ICI 1.8e11 B/s with 1 µs/hop alpha, DCN 2.5e10 B/s per host.
     The DCN alpha and local copy bandwidth are this model's own
     assumptions (cross-host message setup is dominated by the NIC/host

@@ -43,9 +43,7 @@ run ALSO executes the entry point once under the PR 1 accounting layer
 (a fresh build, so the compile lands inside a ``CommAccountant.step``
 bracket) and asserts, per ``primitive@axis`` group::
 
-    static_eqn_bytes == wrapped_ledger_bytes
-                        + (legacy jax ? declared ad_transpose_bytes : 0)
-                        + (vma jax    ? declared noted bytes        : 0)
+    static_eqn_bytes == wrapped_ledger_bytes + declared noted bytes
 
 * ``wrapped`` rows are bookings by the accounted collective face — each
   one has exactly its forward equation in the traced program, so the two
@@ -53,12 +51,9 @@ bracket) and asserts, per ``primitive@axis`` group::
   (either the model rotted or a collective bypasses the accounted face).
 * ``noted`` rows (``observability.comm.note`` — traffic no wrapper sees,
   e.g. the autodiff-inserted gradient psum of the default train step)
-  must equal the entry's declaration; whether the matching psum EQUATION
-  exists is jax-version dependent (``_compat.ad_inserts_replicated_psum``)
-  and the expectation adapts.
-* ``ad_transpose_bytes`` declares the equations legacy-jax autodiff adds
-  by transposing a *wrapped* collective (transpose(psum) = psum on
-  0.4.x), which the ledger cannot book.
+  must equal the entry's declaration; under vma typing the matching
+  psum IS an equation of the traced program, so the declared bytes are
+  added to the static side's expectation.
 
 The only tolerance is dtype-dependent padding: sub-byte or odd-itemsize
 wire dtypes may pad up to one element per call (``pad_tolerance``); for
@@ -100,8 +95,12 @@ SHARDFLOW_RULES: Dict[str, Tuple[str, str]] = {
                  "shard-flow analyzer"),
 }
 
-#: jaxpr primitive aliases across jax versions → canonical name.
-_PRIM_ALIAS = {"reduce_scatter": "psum_scatter"}
+#: jaxpr primitive aliases → canonical name.  Under vma typing a psum
+#: traces as ``psum_invariant`` and the varying → invariant all_gather as
+#: ``all_gather_invariant``: same wire collectives, other names.
+_PRIM_ALIAS = {"reduce_scatter": "psum_scatter",
+               "psum_invariant": "psum",
+               "all_gather_invariant": "all_gather"}
 
 #: Collectives whose result is replication-INVARIANT over their axes
 #: (the axes leave the varying set)…
@@ -174,9 +173,9 @@ def _var_nbytes(v) -> int:
 
 
 def _is_var(v) -> bool:
-    import jax
+    from jax.extend.core import Var
 
-    return isinstance(v, jax.core.Var)
+    return isinstance(v, Var)
 
 
 # --------------------------------------------------------------------------
@@ -493,7 +492,7 @@ def replication_report(jaxpr, args: Sequence[Any], data_axis: str,
     """Which argument leaves / intermediates are replicated across
     ``data_axis``?
 
-    Arg replication is read off the shard_map bindings' ``in_names``
+    Arg replication is read off the shard_map bindings' ``in_specs``
     (a leaf whose binding never splits a dimension over ``data_axis`` is
     fully materialized on every replica of that axis); intermediates come
     from varying-axes propagation through each shard_map body.  Returns::
@@ -508,12 +507,14 @@ def replication_report(jaxpr, args: Sequence[Any], data_axis: str,
     intermediates: List[Dict[str, Any]] = []
 
     for eqn, leaf_map in _find_shard_maps(jaxpr):
-        in_names = eqn.params.get("in_names") or ()
+        in_specs = eqn.params.get("in_specs") or ()
         body = eqn.params.get("jaxpr")
         in_vary: List[Set[str]] = []
-        for pos, names in enumerate(in_names):
+        for pos, spec in enumerate(in_specs):
             axes: Set[str] = set()
-            for dim_axes in dict(names).values():
+            for dim_axes in spec:        # PartitionSpec: one entry per dim
+                if dim_axes is None:
+                    continue
                 axes.update(dim_axes if isinstance(dim_axes, (tuple, list))
                             else (dim_axes,))
             in_vary.append(axes)
@@ -668,8 +669,6 @@ def analyze_entrypoint(ep, reconcile: bool = True,
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
 
-    from chainermn_tpu._compat import ad_inserts_replicated_psum
-
     report = ShardflowReport(name=ep.name)
     findings: List[Finding] = []
     loc = f"entrypoint:{ep.name}"
@@ -761,8 +760,6 @@ def analyze_entrypoint(ep, reconcile: bool = True,
         report.ledger_noted = noted
 
         declared_noted: Dict[str, int] = dict(spec.get("noted", {}))
-        ad_extra: Dict[str, int] = dict(spec.get("ad_transpose_bytes", {}))
-        vma = ad_inserts_replicated_psum()
 
         expected: Dict[str, int] = dict(wrapped)
 
@@ -791,19 +788,14 @@ def analyze_entrypoint(ep, reconcile: bool = True,
                     snippet=f"composite:{key}"))
             for g, b in dict(decl.get("static_groups", {})).items():
                 expected[g] = expected.get(g, 0) + int(b)
-        if not vma:
-            # legacy jax: transpose(psum) is a psum — declared equations
-            # the ledger cannot book
-            for g, b in ad_extra.items():
-                expected[g] = expected.get(g, 0) + int(b)
-        else:
-            # vma jax: the noted (AD-inserted) collectives ARE equations
-            from chainermn_tpu.ops.collective import LEDGER_TO_PRIMITIVE
-            for key, b in declared_noted.items():
-                op, _, axis = key.partition("@")
-                prim = LEDGER_TO_PRIMITIVE.get(op, _canon(op)) or op
-                g = f"{prim}@{axis}"
-                expected[g] = expected.get(g, 0) + int(b)
+        # the noted (AD-inserted) collectives ARE equations of the
+        # traced program under vma typing
+        from chainermn_tpu.ops.collective import LEDGER_TO_PRIMITIVE
+        for key, b in declared_noted.items():
+            op, _, axis = key.partition("@")
+            prim = LEDGER_TO_PRIMITIVE.get(op, _canon(op)) or op
+            g = f"{prim}@{axis}"
+            expected[g] = expected.get(g, 0) + int(b)
         report.expected_static = expected
 
         ok = composite_ok
@@ -819,10 +811,8 @@ def analyze_entrypoint(ep, reconcile: bool = True,
                         f"collective group `{g}`: traced program carries "
                         f"{got} payload bytes but the runtime ledger "
                         f"accounts for {want} (wrapped "
-                        f"{wrapped.get(g, 0)}"
-                        + (f" + declared AD-transpose {ad_extra[g]}"
-                           if not vma and g in ad_extra else "")
-                        + ") — the static cost model rotted, or a "
+                        f"{wrapped.get(g, 0)}) — the static cost model "
+                        "rotted, or a "
                         "collective on this path bypasses the accounted "
                         "face (ops.collective)"),
                     snippet=f"group:{g}"))

@@ -27,8 +27,6 @@ def pseudo_connect(delegate_variable, *actual_variables):
     """
     if not actual_variables:
         raise ValueError("pseudo_connect needs at least one actual variable")
-    # _compat shim: legacy jax (0.4.37) has no differentiation rule for
-    # optimization_barrier; the shim adds a same-semantics custom_vjp
     tied = optimization_barrier((delegate_variable, actual_variables))
     out = tied[1]
     return out[0] if len(out) == 1 else out

@@ -253,7 +253,7 @@ class ResNet(nn.Module):
 
 
 # --- Normalizer-free ResNets (Brock et al. 2021, NF-ResNet) ---------------
-# The measured BN-free variant (VERDICT r3 directive #2): BatchNorm's extra
+# The BN-free variant: BatchNorm's extra
 # activation passes cost 8.4 GB of ResNet-50's 44 GB/step on v5e
 # (scripts/probe_bn_traffic.py), and the zero-norm "affine floor" measures
 # +19% step throughput.  NF-ResNets reach that floor with PUBLISHED

@@ -44,20 +44,26 @@ HBM_BYTES_PER_S = [
 ]
 
 
-def peak_flops_for(device_kind: str) -> Optional[float]:
+def _per_generation(table, device_kind: str, what: str) -> Optional[float]:
     kind = device_kind.lower()
-    for key, peak in PEAK_BF16_FLOPS:
+    for key, value in table:
         if key in kind:
-            return peak
-    return None  # CPU / unknown: MFU not meaningful
+            return value
+    if "tpu" in kind:
+        # a device that is not in the table is an error, not a default:
+        # a utilization computed against a guessed peak is worse than none
+        raise ValueError(
+            f"no {what} on record for TPU device_kind {device_kind!r}; "
+            f"add its generation to chainermn_tpu/observability/metrics.py")
+    return None  # not a TPU (the CPU test mesh): utilization not meaningful
+
+
+def peak_flops_for(device_kind: str) -> Optional[float]:
+    return _per_generation(PEAK_BF16_FLOPS, device_kind, "peak bf16 FLOP/s")
 
 
 def hbm_bw_for(device_kind: str) -> Optional[float]:
-    kind = device_kind.lower()
-    for key, bw in HBM_BYTES_PER_S:
-        if key in kind:
-            return bw
-    return None
+    return _per_generation(HBM_BYTES_PER_S, device_kind, "HBM bandwidth")
 
 
 class StepBreakdownReport:

@@ -22,6 +22,7 @@ from typing import Optional
 import jax
 
 from .._compat import axis_size as _axis_size_compat
+from .._compat import all_gather_invariant as _all_gather_invariant
 from .._compat import pcast_varying as _pcast_varying
 from ..observability.comm import collective as _acc
 from ..topology import DEFAULT_AXIS_NAME
@@ -341,8 +342,14 @@ def pmean_if_bound(x, axis_name: Optional[str] = DEFAULT_AXIS_NAME):
     return pmean(x, axis_name)
 
 
-def all_gather(x, axis_name: str = DEFAULT_AXIS_NAME, axis: int = 0, tiled: bool = True):
-    return _acc("all_gather", axis_name, x, lambda: jax.lax.all_gather(
+def all_gather(x, axis_name: str = DEFAULT_AXIS_NAME, axis: int = 0,
+               tiled: bool = True, invariant: bool = False):
+    """``invariant=True``: the same wire collective typed varying →
+    INVARIANT, for a result that is replicated by definition and leaves
+    ``shard_map`` through ``out_specs=P()`` (plain ``all_gather`` is
+    varying → varying under vma typing)."""
+    gather = _all_gather_invariant if invariant else jax.lax.all_gather
+    return _acc("all_gather", axis_name, x, lambda: gather(
         x, axis_name, axis=axis, tiled=tiled))
 
 
@@ -383,7 +390,9 @@ def axis_size(axis_name: str = DEFAULT_AXIS_NAME) -> int:
 def bcast(x, root: int = 0, axis_name: str = DEFAULT_AXIS_NAME):
     """Every rank gets rank `root`'s block (in-jit broadcast)."""
     def one(v):
-        g = jax.lax.all_gather(v, axis_name, axis=0, tiled=False)
+        # the invariant-typed gather: root's block IS replicated, and
+        # may leave shard_map through out_specs=P()
+        g = _all_gather_invariant(v, axis_name, axis=0, tiled=False)
         return g[root]
     return _acc("bcast", axis_name, x,
                 lambda: jax.tree_util.tree_map(one, x))
@@ -418,10 +427,11 @@ def quantized_ring_pmean(x, axis_name: str = DEFAULT_AXIS_NAME,
       alpha/bandwidth cost model.
     * **gather ring** — the all-gather phase is one tiled int8
       ``all_gather`` of the packed finished chunk (block scales bitcast
-      in-band): the minimal ``(P-1)×chunk`` gather ring, typed
-      replication-invariant by the collective itself (the one-hot-psum
-      phase it replaces paid ~2× the minimal wire; its only virtue was
-      the invariant typing, which ``all_gather`` provides for free).
+      in-band): the minimal ``(P-1)×chunk`` gather ring, in its
+      varying → INVARIANT form (``_compat.all_gather_invariant``), so
+      the result reaches the optimizer typed replicated (the
+      one-hot-psum phase it replaces paid ~2× the minimal wire; its
+      only virtue was the invariant typing).
       The ring's start offset makes rank ``r`` finish its OWN chunk
       ``r``, so the gathered rows concatenate in order — no fix-up
       permutation between the collective and the output.
@@ -462,10 +472,8 @@ def quantized_ring_pmean(x, axis_name: str = DEFAULT_AXIS_NAME,
         # Reduce-scatter: rank i STARTS by forwarding chunk (i-1), so at
         # step s it carries the running sum of chunk (i - 1 - s) mod p
         # and after P-1 hops finishes its OWN chunk i — the gathered
-        # rows then concatenate in order with no fix-up permutation (the
-        # obvious start-at-own-chunk variant needs a roll after the
-        # gather, and XLA's roll+slice simplification MISCOMPILES that
-        # on the deployment floor's jax 0.4.37).  Each hop re-quantizes
+        # rows then concatenate in order with no fix-up permutation.
+        # Each hop re-quantizes
         # the running sum per block and moves each sub-chunk as its own
         # packed ppermute, so hop s+1's transfers are independent of hop
         # s's dequants.
@@ -504,18 +512,16 @@ def quantized_ring_pmean(x, axis_name: str = DEFAULT_AXIS_NAME,
 
         # Gather ring: ONE block quantization of the finished chunk, then
         # a single tiled all_gather of the packed (q + in-band scales)
-        # message — (P-1)×(chunk+scales) minimal wire, replication-
-        # invariant output by construction (the collective itself is the
-        # "replication fix-up": its output is invariant-typed, where a
-        # hand-rolled ppermute gather ring would come out axis-varying).
-        # tiled=True: the non-tiled form hits an XLA CPU fusion bug on
-        # the deployment floor (jax 0.4.37) where the dequant reads the
-        # wrong scale block under jit; the tiled lowering is also the
+        # message — (P-1)×(chunk+scales) minimal wire.  The INVARIANT
+        # form of the collective is the "replication fix-up": plain
+        # ``jax.lax.all_gather`` (like a hand-rolled ppermute gather
+        # ring) comes out axis-varying under vma typing and could not
+        # leave the step through ``out_specs=P()``.  tiled=True is the
         # layout the reshape below wants directly.
         nb = k * nb_sub
         q, scale = quant_rows(send.reshape(nb, eff_block))
-        ga = jax.lax.all_gather(pack(q, scale), axis_name, axis=0,
-                                tiled=True).reshape(p, -1)
+        ga = _all_gather_invariant(pack(q, scale), axis_name, axis=0,
+                                   tiled=True).reshape(p, -1)
         gq = ga[:, :nb * eff_block].reshape(p, nb, eff_block)
         raw = ga[:, nb * eff_block:].reshape(
             (p, nb, ratio) if ratio > 1 else (p, nb))

@@ -169,7 +169,7 @@ def pipeline_1f1b_grads(stage_fn: Callable, loss_fn: Callable, stage_params,
 
     def varying(z):
         # Idempotent: zeros_like(sharded input) is already axis-varying and
-        # pcast/pvary reject a varying→varying cast.
+        # pcast rejects a varying→varying cast.
         try:
             return pcast_varying(z, axis_name)
         except ValueError:

@@ -124,8 +124,10 @@ def _reshard_leaf(x, src: ShardSpec, dst: ShardSpec, axis_name: str):
         return jax.lax.dynamic_slice_in_dim(x, idx * block, block, axis=dst)
     if dst is None:
         # sharded → replicated: the textbook all_gather, tiled so the
-        # blocks concatenate back along the source axis.
-        return _col.all_gather(x, axis_name, axis=src, tiled=True)
+        # blocks concatenate back along the source axis — in its
+        # invariant-typed form, since "replicated" is the contract.
+        return _col.all_gather(x, axis_name, axis=src, tiled=True,
+                               invariant=True)
     # sharded(a) → sharded(b): ONE all_to_all — each rank keeps 1/P of
     # its block and receives 1/P from every peer; (P-1)/P of the payload
     # crosses the wire, vs (P-1)× for gather-then-slice.

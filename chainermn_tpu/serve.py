@@ -196,7 +196,9 @@ def main(argv=None):
         init_tp_transformer_lm, make_hybrid_shard_map_step, shard_pytree,
         state_specs_like, tp_transformer_lm_loss, transformer_lm_specs)
     from chainermn_tpu.serving import AdmissionError, ServingEngine
+    from chainermn_tpu.topology import enable_compile_cache
 
+    enable_compile_cache()
     if args.trace_out:
         obs.enable()
     # flight recorder: always on (bounded ring, negligible cost); crash
@@ -520,8 +522,18 @@ def main(argv=None):
     if statusz is not None:
         statusz.stop()
     service.close()
+    dev = jax.devices()[0]
     summary = {
         "schema": "chainermn_tpu.serve.v1",
+        # where every number below comes from: the parent's devices
+        # (training + in-process engines) and, for a process fleet,
+        # the platform each worker process was placed on
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": len(jax.devices())},
+        "worker_platforms": (
+            {name: getattr(w.proc, "jax_platforms", None)
+             for name, w in fleet.workers.items()}
+            if fleet is not None else None),
         "engine_steps": steps,
         "replicas": args.replicas,
         "disagg": args.disagg,

@@ -1696,7 +1696,19 @@ def spawn_worker(lane_dir: str, params_file: str, name: str, role: str,
                  env: Optional[Dict[str, str]] = None,
                  stdout=None) -> subprocess.Popen:
     """Exec one worker process (detached role loop over the file
-    lanes)."""
+    lanes).
+
+    PLATFORM: an accelerator chip belongs to ONE process, and the caller
+    (``serve --fleet-procs``, the autoscaler, a test) has usually touched
+    JAX already, so a worker cannot share its device.  The process fleet
+    is therefore a CPU protocol harness unless the caller — who must then
+    stay off the device itself — names a platform in
+    ``env={"JAX_PLATFORMS": ...}``.  The AMBIENT variable is not such a
+    decision (the chip machine sets ``tpu,cpu`` for everyone) and is not
+    inherited.  Placing workers on the CPU in a run whose ambient
+    platform is anything else is never silent: it is printed per worker,
+    and the platform chosen is kept on the returned process as
+    ``.jax_platforms`` for the fleet's summary."""
     cmd = [sys.executable, "-m", "chainermn_tpu.serving.worker",
            "--name", name, "--role", role, "--lane-dir", lane_dir,
            "--params", params_file, "--epoch", str(epoch),
@@ -1706,16 +1718,27 @@ def spawn_worker(lane_dir: str, params_file: str, name: str, role: str,
     if journal_dir:
         cmd += ["--journal-dir", journal_dir]
     penv = dict(os.environ)
-    penv.setdefault("JAX_PLATFORMS", "cpu")
     if env:
         penv.update(env)
+    if not (env or {}).get("JAX_PLATFORMS"):
+        penv["JAX_PLATFORMS"] = "cpu"
+        if os.environ.get("JAX_PLATFORMS") != "cpu":
+            print(f"fleet: worker {name} ({role}) runs on "
+                  f"JAX_PLATFORMS=cpu, not on this run's "
+                  f"{os.environ.get('JAX_PLATFORMS') or 'default'} "
+                  f"platform — the process fleet is a CPU protocol "
+                  f"harness (one chip belongs to one process); pass "
+                  f"env={{'JAX_PLATFORMS': ...}} to place it elsewhere",
+                  file=sys.stderr)
     if stdout is None:
         # keep the PARENT's stdout clean (the serve CLI's summary JSON
         # lives there); worker stderr inherits so crashes stay visible
-        return subprocess.Popen(cmd, env=penv,
-                                stdout=subprocess.DEVNULL)
-    return subprocess.Popen(cmd, env=penv, stdout=stdout,
-                            stderr=subprocess.STDOUT)
+        proc = subprocess.Popen(cmd, env=penv, stdout=subprocess.DEVNULL)
+    else:
+        proc = subprocess.Popen(cmd, env=penv, stdout=stdout,
+                                stderr=subprocess.STDOUT)
+    proc.jax_platforms = penv["JAX_PLATFORMS"]
+    return proc
 
 
 def _resolve_topology(topology, registry):

@@ -788,6 +788,9 @@ def main(argv=None) -> int:
 
     import jax  # noqa: F401 — ensure backend init before engine build
 
+    from ..topology import enable_compile_cache
+
+    enable_compile_cache()
     from .lanes import FileLaneStore
 
     with open(args.params, "rb") as f:

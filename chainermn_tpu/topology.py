@@ -42,6 +42,27 @@ DEFAULT_AXIS_NAME = "mn"
 
 _initialized = False
 
+#: The one place the package keeps compiled programs when nobody outside
+#: says otherwise: a fixed directory inside the checkout (the path is
+#: part of the cache key, so a directory that moves never hits).
+_REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; return the directory.
+
+    Called by every CLI entry point.  If ``JAX_COMPILATION_CACHE_DIR`` is
+    set, JAX reads it itself and the code sets NOTHING; otherwise the
+    cache lives at ``<checkout>/.jax_cache`` — never a temp name, pid or
+    timestamp.
+    """
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    jax.config.update("jax_compilation_cache_dir", _REPO_CACHE_DIR)
+    return _REPO_CACHE_DIR
+
 
 def init_distributed(
     coordinator_address: Optional[str] = None,

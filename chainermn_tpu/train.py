@@ -492,7 +492,9 @@ def main(argv=None) -> int:
     from .training.extensions import LogReport, PrintReport
     from .training.trainer import PRIORITY_EDITOR, Trainer
     from .training.updaters import StandardUpdater
+    from .topology import enable_compile_cache
 
+    enable_compile_cache()
     if args.trace_out or args.metrics_out:
         obs.enable()
     # flight recorder: bounded ring, always teed; crash bundles go to

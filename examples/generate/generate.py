@@ -71,6 +71,9 @@ def main():
         init_tp_transformer_lm, make_hybrid_shard_map_step, make_lm_generator,
         shard_pytree, state_specs_like, tp_transformer_lm_loss,
         transformer_lm_specs)
+    from chainermn_tpu.topology import enable_compile_cache
+
+    enable_compile_cache()
 
     n = len(jax.devices())
     dp = n // args.tp

@@ -48,6 +48,9 @@ def main():
     from chainermn_tpu.parallel import (
         init_tp_mlp_params, make_hybrid_shard_map_step, shard_pytree,
         state_specs_like, tp_mlp, tp_mlp_specs)
+    from chainermn_tpu.topology import enable_compile_cache
+
+    enable_compile_cache()
 
     n = len(jax.devices())
     if n % args.tp:

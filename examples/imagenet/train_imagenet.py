@@ -22,7 +22,10 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
 
-def main():
+def main(argv=None):
+    """Train; returns a small result dict (losses, compile and run
+    seconds, throughput) so a caller such as ``chip_smoke.py`` can drive
+    this entry point in-process."""
     parser = argparse.ArgumentParser(description="ChainerMN-TPU example: ImageNet")
     # Kept as a literal (not ARCHS.keys()): the registry import pulls in
     # jax, which must wait until --devices is applied.  A consistency
@@ -88,7 +91,7 @@ def main():
                         help="ZeRO-3: params, grads and optimizer state all "
                              "sharded 1/P (BatchNorm-free archs only — use "
                              "a ViT, e.g. --arch vit_s16)")
-    args = parser.parse_args()
+    args = parser.parse_args(argv)
 
     # Flag-combination checks that need nothing from jax: fail fast,
     # before device config / distributed init.
@@ -118,6 +121,9 @@ def main():
 
     import chainermn_tpu as mn
     from chainermn_tpu.models.mlp import cross_entropy_loss
+    from chainermn_tpu.topology import enable_compile_cache
+
+    enable_compile_cache()
     from chainermn_tpu.models.resnet import ARCHS
 
     # Drift guard over the FULL choices list (not just the picked arch),
@@ -270,23 +276,40 @@ def main():
     if comm.rank == 0 and not mn.runtime.native_available():
         print("note: native prefetcher unavailable, python fallback in use")
 
-    # warmup/compile
+    # compile ahead of time (the jitted DP step; the FSDP face is a plain
+    # wrapper), so compile and run are timed apart and the SAME executable
+    # runs every step; then one warm-up step
     batch = mn.shard_batch(it.next(), mesh)
+    t_compile = time.time()
+    has_kernel = None
+    if hasattr(step, "lower"):
+        step = step.lower(variables, opt_state, batch).compile()
+        has_kernel = "tpu_custom_call" in step.as_text()
+    compile_s = time.time() - t_compile
     variables, opt_state, loss, metrics = step(variables, opt_state, batch)
-    loss.block_until_ready()
+    first_loss = float(loss)
     t0 = time.time()
     for i in range(args.steps):
         batch = mn.shard_batch(it.next(), mesh)
         variables, opt_state, loss, metrics = step(variables, opt_state, batch)
         if args.devices:  # lockstep on thin hosts; async on real chips
             loss.block_until_ready()
-    loss.block_until_ready()
+    last_loss = float(loss)  # host readback = the timing barrier
     dt = time.time() - t0
+    ips = args.steps * global_batch / dt
+    dev = jax.devices()[0]
     if comm.rank == 0:
-        ips = args.steps * global_batch / dt
-        print(f"loss {float(loss):.4f}  acc {float(metrics['accuracy']):.4f}")
-        print(f"throughput: {ips:.1f} images/sec total, "
+        print(f"loss {last_loss:.4f}  acc {float(metrics['accuracy']):.4f}")
+        print(f"throughput on {n_chips} x {dev.platform} "
+              f"({dev.device_kind}): {ips:.1f} images/sec total, "
               f"{ips / n_chips:.1f} images/sec/chip")
+    return {"arch": args.arch, "chips": n_chips, "platform": dev.platform,
+            "device_kind": dev.device_kind, "global_batch": global_batch,
+            "steps": args.steps, "first_loss": first_loss,
+            "last_loss": last_loss, "compile_s": round(compile_s, 2),
+            "run_s": round(dt, 2),
+            "images_per_sec_per_chip": round(ips / n_chips, 1),
+            "tpu_custom_call": has_kernel}
 
 
 if __name__ == "__main__":

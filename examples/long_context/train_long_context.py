@@ -55,6 +55,9 @@ def main():
     from chainermn_tpu._compat import shard_map
     from chainermn_tpu.parallel import (
         init_tp_transformer_lm, sp_transformer_lm_loss)
+    from chainermn_tpu.topology import enable_compile_cache
+
+    enable_compile_cache()
 
     n = len(jax.devices())
     if args.seq_len % n:

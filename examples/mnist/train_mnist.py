@@ -61,6 +61,9 @@ def main():
 
     import chainermn_tpu as mn
     from chainermn_tpu.models import MLP, accuracy, cross_entropy_loss
+    from chainermn_tpu.topology import enable_compile_cache
+
+    enable_compile_cache()
 
     mn.init_distributed()
     comm = mn.create_communicator(args.communicator)

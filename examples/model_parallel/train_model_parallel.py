@@ -46,6 +46,9 @@ def main():
     import chainermn_tpu as mn
     from chainermn_tpu import functions as F
     from chainermn_tpu.links import MultiNodeChainList
+    from chainermn_tpu.topology import enable_compile_cache
+
+    enable_compile_cache()
 
     comm = mn.create_communicator("xla")
     mesh = comm.mesh

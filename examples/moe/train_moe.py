@@ -75,6 +75,9 @@ def main():
     from chainermn_tpu.parallel import (
         init_moe_mlp_params, make_hybrid_shard_map_step, moe_mlp,
         moe_mlp_specs, shard_pytree, state_specs_like)
+    from chainermn_tpu.topology import enable_compile_cache
+
+    enable_compile_cache()
 
     comm = mn.create_communicator("xla")
     mesh, ax = comm.mesh, comm.axis_name

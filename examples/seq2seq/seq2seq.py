@@ -67,6 +67,9 @@ def main():
     from chainermn_tpu.models.seq2seq import (
         PAD, EOS, Seq2seq, encode_pairs, masked_cross_entropy, token_accuracy)
     from chainermn_tpu.training import StandardUpdater, Trainer, extensions
+    from chainermn_tpu.topology import enable_compile_cache
+
+    enable_compile_cache()
 
     comm = mn.create_communicator(args.communicator)
     print(f"communicator={args.communicator} size={comm.size} "

@@ -20,7 +20,7 @@ from functools import partial
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
 
-def main():
+def main(argv=None):
     parser = argparse.ArgumentParser(
         description="ChainerMN-TPU example: DP x TP transformer LM")
     parser.add_argument("--devices", type=int, default=0,
@@ -43,7 +43,7 @@ def main():
                         choices=["auto", "xla", "fused"],
                         help="LM-head loss path; 'fused' = the Pallas "
                              "online-softmax kernels (big-vocab heads)")
-    args = parser.parse_args()
+    args = parser.parse_args(argv)
 
     if args.devices:
         import jax
@@ -61,7 +61,9 @@ def main():
     from chainermn_tpu.parallel import (
         init_tp_transformer_lm, make_hybrid_shard_map_step, shard_pytree,
         state_specs_like, tp_transformer_lm_loss, transformer_lm_specs)
+    from chainermn_tpu.topology import enable_compile_cache
 
+    enable_compile_cache()
     n = len(jax.devices())
     if n % args.tp:
         raise SystemExit(f"device count {n} not divisible by --tp {args.tp}")
@@ -101,7 +103,9 @@ def main():
             print(f"step {i + 1}  loss {float(loss):.4f}")
     dt = time.time() - t0
     tok_s = args.steps * args.batchsize * args.seq_len / dt
-    print(f"{tok_s:,.0f} tokens/sec  final loss {float(loss):.4f}")
+    dev = jax.devices()[0]
+    print(f"{tok_s:,.0f} tokens/sec on {n} x {dev.platform} "
+          f"({dev.device_kind})  final loss {float(loss):.4f}")
 
 
 if __name__ == "__main__":

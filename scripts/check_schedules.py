@@ -34,8 +34,7 @@ Exit codes (the ``check_perf_regression.py`` contract): 0 = all pairs
 verified and checks passed, 1 = a violation or a missed fault, 2 =
 inputs unusable.
 
-``--history-out`` appends one ``{n, cmd, rc, t, parsed}`` record (the
-``BENCH_r<N>.json`` driver shape) so schedule runs land on the same
+``--history-out`` appends one ``{n, cmd, rc, t, parsed}`` record so schedule runs land on the same
 ``bench_history.jsonl`` trajectory the perf gate diffs.
 
 No jax required: the analysis package is loaded standalone (same

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Ingest a real image corpus into the ``write_file_dataset`` record layout.
 
-VERDICT r3 #6: the file-backed data path (C++ prefetch ring → FileDataset →
+The file-backed data path (C++ prefetch ring → FileDataset →
 training) was measured end to end but only ever fed synthetic stand-ins.
 This recipe converts an actual corpus to the on-disk format the pread
 workers consume, with a deterministic train/val split:

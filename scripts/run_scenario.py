@@ -22,8 +22,7 @@ Exit codes (the ``check_perf_regression.py`` contract): 0 = scenario
 ran and every check passed, 1 = a check failed, 2 = inputs unusable
 (unknown scenario, no JAX backend, bad arguments).
 
-``--history-out`` appends one ``{n, cmd, rc, t, parsed}`` record (the
-``BENCH_r<N>.json`` driver shape) so scenario runs land on the same
+``--history-out`` appends one ``{n, cmd, rc, t, parsed}`` record so scenario runs land on the same
 ``bench_history.jsonl`` trajectory the perf gate diffs.
 
 Usage::

@@ -16,11 +16,12 @@ os.environ["XLA_FLAGS"] = (
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
+# The CLIs place a persistent compile cache (topology.enable_compile_cache)
+# and several tests call their main() in-process; the suite itself wants no
+# cache — CPU entries are useless to the chip and a described-chip compile
+# (tests/test_chip_compile.py) cannot be read back at all.
+jax.config.update("jax_enable_compilation_cache", False)
 
-# Importing the package here (before any test module loads) installs the
-# jax version-compat shims (chainermn_tpu/_compat.py: `jax.shard_map`,
-# `jax.lax.axis_size` on old jax), so test modules written against new
-# JAX (`from jax import shard_map`) collect on the container's floor.
 import chainermn_tpu  # noqa: E402,F401
 
 # Opt-in runtime lock-order cross-check (ISSUE 15 satellite): with

@@ -1,0 +1,238 @@
+"""Ahead-of-time compiles for the chip, without the chip.
+
+The TPU's compiler is installed in the sandbox and compiles for a chip
+that is DESCRIBED (``v5e:2x2``, device_kind "TPU v5 lite"), not attached.
+These tests hand it the main path's kernels at the real widths
+``chip_smoke.py`` runs — and the whole 135M LM train step and the serving
+programs inside ``shard_map`` with vma checking on — so a kernel the
+chip's compiler would refuse (unaligned slice, too much VMEM, a program
+that does not fit 16 GB) fails here, at no chip time.  A compile that
+passes is not a chip run and says nothing about results or speed.
+
+Rules this file keeps (on-chip-measurement guide §2): the topology is
+described INSIDE a module-scoped fixture that skips when it cannot be —
+never at import, in a ``skipif``/``parametrize`` argument or in
+``conftest.py`` (only one process may load the TPU library, and xdist
+workers that collect different tests run none); every compile happens in
+this process; ALL such tests live in this one file; the persistent
+compile cache is off around them (such an entry cannot be read back
+without a chip).  Code that asks ``jax.default_backend()`` sees the CPU
+here, so the TEST steers it (explicit ``interpret=False`` / impl names,
+or a monkeypatched ``default_backend``) — never a new program option.
+"""
+
+import os
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+# the real widths (chip_smoke.SIZES / bench.py's 135M LM row)
+VOCAB, D_MODEL, N_LAYERS, N_HEADS, HEAD_DIM, SEQ, BATCH = (
+    32768, 1024, 8, 8, 128, 1024, 8)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler / lock held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Steer ``jax.default_backend()`` branches to their TPU side."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _n_kernels(fn, *args) -> int:
+    """Compile ``fn`` for the described chip; count its Pallas kernels."""
+    return jax.jit(fn).lower(*args).compile().as_text().count(
+        "tpu_custom_call")
+
+
+def test_flash_attention_fwd(one_chip):
+    from chainermn_tpu.ops.flash_attention import flash_attention
+
+    q = _sds((4, 2048, 8, 128), jnp.bfloat16, one_chip)
+    n = _n_kernels(partial(flash_attention, causal=True, interpret=False),
+                   q, q, q)
+    assert n >= 1
+
+
+def test_flash_attention_fwd_bwd(one_chip):
+    from chainermn_tpu.ops.flash_attention import flash_attention
+
+    q = _sds((4, 2048, 8, 128), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    n = _n_kernels(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    assert n >= 2       # forward + backward kernels
+
+
+def test_fused_cross_entropy_fwd_bwd(one_chip):
+    from chainermn_tpu.ops.fused_ce import fused_cross_entropy
+
+    h = _sds((8192, D_MODEL), jnp.bfloat16, one_chip)
+    table = _sds((VOCAB, D_MODEL), jnp.bfloat16, one_chip)
+    tgt = _sds((8192,), jnp.int32, one_chip)
+
+    def loss(h, table, tgt):
+        return fused_cross_entropy(h, table, tgt, interpret=False).mean()
+
+    n = _n_kernels(jax.grad(loss, argnums=(0, 1)), h, table, tgt)
+    assert n >= 2
+
+
+def test_decode_attend(one_chip):
+    from chainermn_tpu.ops.decode_attention import decode_attend
+
+    b, s, h, hd = 8, 2048, 16, 128
+    q = _sds((b, h * hd), jnp.bfloat16, one_chip)
+    kc = _sds((b, s, h * hd), jnp.bfloat16, one_chip)
+    pos = _sds((), jnp.int32, one_chip)
+    n = _n_kernels(partial(decode_attend, n_heads=h, head_dim=hd,
+                           interpret=False), q, kc, kc, pos)
+    assert n >= 1
+
+
+def test_cache_append(one_chip, as_tpu):
+    # ops/kv_cache.py refuses impl="pallas", interpret=False off-TPU, so
+    # this compile needs the default_backend steer
+    from chainermn_tpu.ops.kv_cache import cache_append
+
+    b, s, d = 8, 2048, 16 * 128
+    kc = _sds((b, s, d), jnp.bfloat16, one_chip)
+    new = _sds((b, 1, d), jnp.bfloat16, one_chip)
+    pos = _sds((), jnp.int32, one_chip)
+    n = _n_kernels(partial(cache_append, axis=1, impl="pallas",
+                           interpret=False), kc, kc, new, new, pos)
+    assert n >= 1
+
+
+def test_conv3x3_backward(one_chip):
+    from chainermn_tpu.ops.conv_backward import conv3x3_dgrad, conv3x3_wgrad
+
+    x = _sds((32, 56, 56, 64), jnp.bfloat16, one_chip)
+    w = _sds((3, 3, 64, 64), jnp.bfloat16, one_chip)
+
+    def bwd(x, dy, w):
+        return (conv3x3_dgrad(dy, w, x.shape, 1, interpret=False),
+                conv3x3_wgrad(x, dy, 1, interpret=False))
+
+    n = _n_kernels(bwd, x, x, w)
+    assert n >= 2
+
+
+def _lm_shapes(mesh, max_len):
+    from chainermn_tpu.parallel import (init_tp_transformer_lm,
+                                        transformer_lm_specs)
+
+    params = jax.eval_shape(lambda: init_tp_transformer_lm(
+        jax.random.PRNGKey(0), VOCAB, D_MODEL, N_HEADS, N_LAYERS,
+        max_len=max_len, dtype=jnp.bfloat16))
+    specs = transformer_lm_specs(params, "model")
+
+    def shaped(tree, tree_specs):
+        return jax.tree_util.tree_map(
+            lambda x, s: _sds(x.shape, x.dtype, NamedSharding(mesh, s)),
+            tree, tree_specs)
+
+    return params, specs, shaped
+
+
+def test_lm_135m_train_step_in_shard_map(topo, as_tpu):
+    """The whole LM train step chip_smoke's train-lm phase runs:
+    make_hybrid_shard_map_step + flash attention + fused CE on a (1, 1)
+    mesh, vma checking on."""
+    import optax
+
+    from chainermn_tpu.parallel import (make_hybrid_shard_map_step,
+                                        state_specs_like,
+                                        tp_transformer_lm_loss)
+
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    params, specs, shaped = _lm_shapes(mesh, SEQ)
+    loss_fn = partial(tp_transformer_lm_loss, head_dim=HEAD_DIM,
+                      axis_name="model", attn_impl="flash", ce_impl="fused")
+    optimizer = optax.sgd(1e-2)
+    step = make_hybrid_shard_map_step(loss_fn, optimizer, mesh, params,
+                                      specs, data_axis="data",
+                                      batch_spec=P("data"))
+    opt_state = jax.eval_shape(optimizer.init, params)
+    batch = (_sds((BATCH, SEQ + 1), jnp.int32,
+                  NamedSharding(mesh, P("data"))),)
+    compiled = step.lower(
+        shaped(params, specs),
+        shaped(opt_state, state_specs_like(optimizer, params, specs)),
+        batch).compile()
+    # flash fwd + bwd per layer, plus the fused-CE kernels
+    assert compiled.as_text().count("tpu_custom_call") >= 2 * N_LAYERS + 2
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 16 * 2 ** 30)      # fits one v5e chip's HBM
+
+
+def test_serving_prefill_and_tick(topo, as_tpu):
+    """The ServingEngine's own prefill (prompt 512) and decode tick
+    (4 slots) at the LM width.  DecodeEngine's constructor places params
+    on devices, which a described chip cannot hold — so the program
+    builders run on a bare instance given the same attributes."""
+    from chainermn_tpu._compat import shard_map
+    from chainermn_tpu.serving.engine import DecodeEngine
+
+    n_slots, prompt, total = 4, 512, 512 + 64
+    mesh = Mesh(np.array(topo.devices[:1]), ("model",))
+    params, specs, shaped = _lm_shapes(mesh, SEQ)
+    kv = P(None, None, "model")
+    rep = NamedSharding(mesh, P())
+    caches = [(_sds((n_slots, total, D_MODEL), jnp.bfloat16,
+                    NamedSharding(mesh, kv)),) * 2 for _ in range(N_LAYERS)]
+    eng = DecodeEngine.__new__(DecodeEngine)
+    eng.mesh, eng.axis_name, eng.head_dim = mesh, "model", HEAD_DIM
+    eng._specs, eng._shard_map, eng._P = specs, shard_map, P
+    eng._cache_specs = [(kv, kv)] * N_LAYERS
+    p = shaped(params, specs)
+
+    prefill = eng._build_prefill(prompt).lower(
+        p, caches, _sds((1, prompt), jnp.int32, rep),
+        _sds((), jnp.int32, rep), _sds((), jnp.int32, rep),
+        _sds((2,), jnp.uint32, rep), _sds((), jnp.float32, rep)).compile()
+    # the prompt pass takes the flash kernel, one per layer
+    assert prefill.as_text().count("tpu_custom_call") >= N_LAYERS
+
+    eng._build_tick().lower(
+        p, caches, _sds((n_slots,), jnp.int32, rep),
+        _sds((n_slots,), jnp.int32, rep),
+        _sds((n_slots, 2), jnp.uint32, rep),
+        _sds((n_slots,), jnp.float32, rep)).compile()
+    # (the tick feeds per-row positions, which parallel/decode.py routes
+    # to einsum attention — no kernel is expected in it today)

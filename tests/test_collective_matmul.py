@@ -168,14 +168,6 @@ class TestMegatronSPBlocks:
         got = np.asarray(sp_fn(x, blk))
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
-    @pytest.mark.xfail(
-        strict=False,
-        reason="needs current-jax vma AD semantics (check_vma): the "
-               "all_gather/reduce_scatter transposes double-count "
-               "without rep tracking (sharded-param grads off by "
-               "exactly 7/8 after hand-psums). Passes on current jax. "
-               "See VERDICT.md 'PR 4 addendum — tier-1 failure "
-               "triage', 'Documented, not fixed (3)'.")
     def test_block_sp_gradients_match(self, mesh):
         from chainermn_tpu.parallel import tp_block, tp_block_sp
 

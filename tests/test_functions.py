@@ -99,10 +99,8 @@ def grad_through(fn, x):
     Each rank differentiates its LOCAL partial sum; cross-rank coupling
     flows through the transpose collectives inside ``fn``, so the result
     is exactly d(Σ_r loss_r)/dx.  Deliberately NO outer ``psum`` on the
-    scalar: under legacy shard_map with the replication checker off
-    (``_compat.shard_map`` on this container's jax), ``psum`` transposes
-    to ``psum`` rather than identity, inflating every gradient by the
-    axis size — the local-loss form is correct under both regimes.
+    scalar: the local-loss form needs no assumption about how the
+    checker types the scalar's transpose.
     """
     mesh = mn.make_mesh()
 

@@ -135,17 +135,9 @@ class TestVocabParallel:
                 mesh=mesh, in_specs=(P(), P("mn"), P()),
                 out_specs=P(), check_vma=False))(h, tab, tgt)
 
-    @pytest.mark.xfail(
-        strict=False,
-        reason="needs current-jax vma AD semantics (check_vma): with "
-               "0.4.37's check_rep=False the custom_vjp hand-psum "
-               "fallback and AD-through-psum route dtable's data-axis "
-               "reduction differently (~1%/step divergence). Passes on "
-               "current jax. See VERDICT.md 'PR 4 addendum — tier-1 "
-               "failure triage', 'Documented, not fixed (3)'.")
     def test_dp_tp_training_trajectory_matches_xla(self, devices):
         """3 training steps on a (2, 4) DP×TP mesh: ce_impl='fused' must
-        reproduce the xla path's loss trajectory exactly (the pvary
+        reproduce the xla path's loss trajectory exactly (the pcast
         promotions route dtable's data-psum and dh's model-psum through
         the custom_vjp boundary)."""
         import optax

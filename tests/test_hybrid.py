@@ -62,14 +62,6 @@ def make_2d_mesh():
 
 
 class TestShardMapFace:
-    @pytest.mark.xfail(
-        strict=False,
-        reason="needs current-jax vma AD semantics (check_vma): grads "
-               "of data-replicated params miss the out-spec psum legacy "
-               "shard_map never inserts (step-1 loss 1.63 vs oracle "
-               "2.88). Passes on current jax. See VERDICT.md 'PR 4 "
-               "addendum — tier-1 failure triage', 'Documented, not "
-               "fixed (3)'.")
     def test_parity_with_single_device_oracle(self):
         """TP MLP inside, DP gradient mean outside, one jitted step — equals
         the single-device full-batch step (incl. SGD momentum state)."""

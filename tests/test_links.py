@@ -104,7 +104,7 @@ def test_chain_list_differentiable_end_to_end():
 
 
 def test_chain_list_places_stages_on_their_chips():
-    """VERDICT r1 weak#3: placement must be REAL.  Eagerly, each stage's
+    """Placement must be REAL.  Eagerly, each stage's
     params live on its declared rank's chip and each transfer edge commits
     the activation to the consumer's chip — verified from the committed
     devices of params and output."""

@@ -329,16 +329,21 @@ def test_eos_and_deadline_eviction_live(devices):
     RUNNING request (deadline forced into the past between ticks)."""
     from chainermn_tpu.serving import ServingEngine
 
-    params = _params(seed=5)
+    # seed 57: a random init whose greedy continuation is not constant
+    # under the installed jax's PRNG ([8, 8, 0, ...]), so the eos lands
+    # on the THIRD token; the expectation below holds for any sequence
+    params = _params(seed=57)
     mesh = _mesh(devices, 1)
     eng = ServingEngine(params, head_dim=HEAD_DIM, n_slots=2, max_total=32,
                         mesh=mesh)
     prompt = np.arange(5, dtype=np.int32) % VOCAB
     want = _oracle(params, mesh, prompt, 6).tolist()
-    h = eng.submit(prompt, 6, eos_id=want[2])
+    eos = want[2]
+    h = eng.submit(prompt, 6, eos_id=eos)
     eng.run(steps_budget=50)
     assert h.status == "done" and h.finish_reason == "eos"
-    assert h.tokens == want[:3]          # eos token included, then stop
+    # stops at the FIRST eos, eos token included
+    assert h.tokens == want[:want.index(eos) + 1]
     assert eng.pool.busy_count == 0      # slot released
 
     h2 = eng.submit(prompt, 27, deadline_s=3600)    # 5 + 27 = max_total
