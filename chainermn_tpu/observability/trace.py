@@ -10,15 +10,27 @@ exported in the Chrome Trace Event format that ``chrome://tracing`` and
 
 Design rules:
 
-* **No-op when disabled.**  ``span()`` returns a shared singleton context
-  manager and every record call bails on one attribute read — tracing
-  must be free enough to leave the call sites in the hot path permanently
-  (the acceptance gate is <1% step-time regression with tracing off).
+* **One span primitive, two sinks.**  While a ``jax.profiler`` session
+  records, ``span()`` enters a ``jax.profiler.TraceAnnotation``, so the
+  span lands in the profiler's own trace, on the clock the device's
+  events are on — nobody has to call ``enable()`` for that.  With
+  ``enable()`` on it ALSO records the Chrome ``X`` event into the
+  in-memory buffer (the operator's opt-in: ``--trace-out``, the flight
+  recorder).  With neither, ``span()`` returns a shared no-op after two
+  flag reads (``TraceAnnotation.is_enabled()`` is one atomic load) and
+  every other record call bails on one attribute read — tracing must be
+  free enough to leave the call sites in the hot path permanently (the
+  acceptance gate is <1% step-time regression with tracing off).  The
+  annotation is NOT entered while no session records: on the v5e host,
+  annotations held open around a call that traces and compiles a program
+  made JAX's tracing of it up to half again as slow (PERF.md, Findings
+  PR 25).
 * **Thread-local nesting.**  Each thread keeps its own span stack, so
   iterator workers and the watchdog thread trace independently; Chrome
   renders nesting per ``tid`` from the timestamps.
-* **Stdlib only.**  Importable everywhere, including before a JAX
-  backend exists.
+* **Stdlib only at import.**  Importable everywhere, including before a
+  JAX backend exists; ``jax.profiler`` is looked up at the first span,
+  and where there is no JAX only the Chrome sink exists.
 
 Usage::
 
@@ -46,9 +58,10 @@ from typing import Any, Callable, Dict, List, Optional
 
 
 class _NullSpan:
-    """Shared do-nothing context manager — the disabled-tracer fast path.
+    """Shared do-nothing context manager — the fast path of a disabled
+    tracer with no profiler session recording.
 
-    A singleton so ``span()`` with tracing off allocates nothing."""
+    A singleton so ``span()`` then allocates nothing."""
 
     __slots__ = ()
 
@@ -61,20 +74,42 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+_ANNOTATION = None      # jax.profiler.TraceAnnotation, or False without JAX
+
+
+def _annotation(name: str, args: Dict[str, Any]):
+    """The profiler-side half of a span: a ``TraceAnnotation`` while a
+    profiler session records, else ``None`` (also where JAX is not
+    installed)."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _ANNOTATION = TraceAnnotation
+        except Exception:      # no JAX here: the Chrome sink still works
+            _ANNOTATION = False
+    if _ANNOTATION and _ANNOTATION.is_enabled():
+        return _ANNOTATION(name, **args)
+    return None
+
 
 class _Span:
-    """Records one Chrome ``X`` (complete) event on exit."""
+    """Enters the profiler annotation and records one Chrome ``X``
+    (complete) event on exit."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
-                 args: Optional[Dict[str, Any]]):
+                 args: Optional[Dict[str, Any]], ann):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
+        self._ann = ann
 
     def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
         self._tracer._stack().append(self.name)
         self._t0 = self._tracer._now_us()
         return self
@@ -82,6 +117,8 @@ class _Span:
     def __exit__(self, *exc):
         tr = self._tracer
         t1 = tr._now_us()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         stack = tr._stack()
         if stack and stack[-1] == self.name:
             stack.pop()
@@ -194,11 +231,15 @@ class Tracer:
 
     # ---- recording surface ----
     def span(self, name: str, cat: str = "span", **args):
-        """Context manager timing a nested span; no-op singleton when
-        disabled (zero allocation on the hot path)."""
+        """Context manager over a nested span: a
+        ``jax.profiler.TraceAnnotation`` while a profiler session records
+        (the span lands on the device trace's clock), the Chrome ``X``
+        event as well with the tracer enabled, the shared no-op with
+        neither."""
+        ann = _annotation(name, args)
         if not self.enabled:
-            return _NULL_SPAN
-        return _Span(self, name, cat, args or None)
+            return _NULL_SPAN if ann is None else ann
+        return _Span(self, name, cat, args or None, ann)
 
     def traced(self, name: Optional[str] = None, cat: str = "span"):
         """Decorator face of :meth:`span`."""
@@ -209,8 +250,6 @@ class Tracer:
 
             @functools.wraps(fn)
             def inner(*a, **kw):
-                if not self.enabled:
-                    return fn(*a, **kw)
                 with self.span(label, cat=cat):
                     return fn(*a, **kw)
             return inner

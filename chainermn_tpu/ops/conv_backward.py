@@ -256,6 +256,7 @@ def conv3x3_wgrad(x, dy, stride: int = 1, *, ksize: int = 3,
                                        vma=vma),
         compiler_params=_tpu_compiler_params(
             dimension_semantics=("arbitrary", "arbitrary")),
+        name="conv_bwd_dw",
         interpret=interpret,
     )(_promote_vma(x.reshape(n, h * w, ci), vma),
       _promote_vma(dy.reshape(n, h * w, co), vma))
@@ -332,6 +333,7 @@ def conv3x3_dgrad(dy, w, xshape, stride: int = 1, *,
                         if k > 1 else []),
         compiler_params=_tpu_compiler_params(
             dimension_semantics=("arbitrary", "arbitrary")),
+        name="conv_bwd_dx",
         interpret=interpret,
     )(_promote_vma(dy.reshape(n, h * ww_, co), vma),
       _promote_vma(w.reshape(k * k, ci, co), vma))

@@ -183,6 +183,7 @@ def decode_attend(q, kc, vc, pos, *, n_heads: int, head_dim: int,
                           scale=scale),
         grid_spec=grid_spec,
         out_shape=_sds((b, d), q.dtype, vma=vma),
+        name="decode_attn_mha",
         interpret=interpret,
     )(jnp.asarray([pos], jnp.int32), q, kc, vc, seg, seg.T)
 
@@ -328,6 +329,7 @@ def beam_attend_parts(q, kc, vc, amask=None, pos=None, *, beams: int,
         out_shape=[_sds((bk, d), jnp.float32, vma=vma),
                    _sds((bk, h), jnp.float32, vma=vma),
                    _sds((bk, h), jnp.float32, vma=vma)],
+        name="decode_attn_beam",
         interpret=interpret,
     )(jnp.asarray([0 if pos is None else pos], jnp.int32), q, kc, vc,
       seg, seg.T, amask.astype(jnp.float32))

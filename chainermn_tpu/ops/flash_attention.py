@@ -246,6 +246,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret, seq_len,
         ],
         compiler_params=_tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_fwd",
         interpret=interpret,
     )(q, k, v)
     return out, lse[..., 0]
@@ -461,6 +462,7 @@ def _bwd_pallas(q, k, v, out, lse, do, causal, scale, block_q, block_k,
         compiler_params=_tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
+        name="flash_bwd",
         interpret=interpret,
     )(k, v, q, do, lse, delta)
     dq = dq_part.astype(jnp.float32).sum(axis=1).astype(q.dtype)

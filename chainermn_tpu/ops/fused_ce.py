@@ -226,6 +226,7 @@ def ce_stats(h, table, targets, block_t: int = 256, block_v: int = 1024,
                         for _ in range(3)],
         compiler_params=_tpu_compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
+        name="fused_ce_stats",
         interpret=interpret,
     )(h, table, tgt_row)
     return m[:, 0], l[:, 0], p[:, 0]
@@ -267,6 +268,7 @@ def ce_grads(h, table, targets, lse, dnll, block_t: int = 256,
         scratch_shapes=[pltpu.VMEM((bt, d), jnp.float32)],
         compiler_params=_tpu_compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
+        name="fused_ce_dh",
         interpret=interpret,
     )(h, table, tgt_row, lse_row, dnll_row)
 
@@ -286,6 +288,7 @@ def ce_grads(h, table, targets, lse, dnll, block_t: int = 256,
         scratch_shapes=[pltpu.VMEM((bv, d), jnp.float32)],
         compiler_params=_tpu_compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
+        name="fused_ce_dtable",
         interpret=interpret,
     )(table, h, tgt_row, lse_row, dnll_row)
     return dh, dtable

@@ -200,5 +200,6 @@ def cache_append(kc, vc, k_new, v_new, pos, *, axis: int = 1,
         out_shape=[_sds(kc.shape, kc.dtype, vma=vma),
                    _sds(vc.shape, vc.dtype, vma=vma)],
         input_output_aliases={3: 0, 4: 1},  # kc, vc (after the scalar arg)
+        name="kv_cache_write",
         interpret=interpret,
     )(jnp.asarray([pos], jnp.int32).astype(jnp.int32), kn, vn, kc, vc)

@@ -113,9 +113,10 @@ def _decoder_core(params, head_dim: int, axis_name: str):
             # scatter (ops/kv_cache.py): the XLA dus costs a full extra
             # pass over the cache per tick; prefill's slab write (s_q >
             # 1) falls back to dus inside cache_append
-            kc, vc = cache_append(
-                k_cache, v_cache, k.reshape(n, s_q, hkv * head_dim),
-                v.reshape(n, s_q, hkv * head_dim), write_at, axis=1)
+            with jax.named_scope("cache_write"):
+                kc, vc = cache_append(
+                    k_cache, v_cache, k.reshape(n, s_q, hkv * head_dim),
+                    v.reshape(n, s_q, hkv * head_dim), write_at, axis=1)
             if s_q > 1 and isinstance(write_at, int) and write_at == 0 \
                     and isinstance(q_valid, int) and q_valid == 0:
                 # PREFILL: pure causal self-attention over the prompt —
@@ -340,12 +341,16 @@ def lm_decode_tick(params, tokens, caches, pos, *, head_dim: int,
     embed, attn_block, _, _ = _decoder_core(params, head_dim, axis_name)
     per_row = getattr(pos, "ndim", 0) == 1
     positions = pos[:, None] if per_row else pos[None]
-    x = embed(tokens[:, None], positions)
+    with jax.named_scope("tick/embed"):
+        x = embed(tokens[:, None], positions)
     new_caches = []
     for blk, (kc, vc) in zip(params["blocks"], caches):
-        x, kc, vc = attn_block(x, blk, kc, vc, positions, pos, pos)
+        # the block's cache append nests as tick/attn/cache_write
+        with jax.named_scope("tick/attn"):
+            x, kc, vc = attn_block(x, blk, kc, vc, positions, pos, pos)
         new_caches.append((kc, vc))
-    h = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
+    with jax.named_scope("tick/head"):
+        h = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
     return h[:, -1], new_caches
 
 

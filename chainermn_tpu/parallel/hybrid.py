@@ -318,7 +318,7 @@ def make_hybrid_shard_map_step(
         batch_spec = P(data_axis)
     st_specs = state_specs_like(optimizer, params, param_specs)
 
-    def spmd(params, opt_state, batch):
+    def train_step(params, opt_state, batch):
         def global_loss(p):
             out = loss_fn(p, batch)
             if has_aux:
@@ -327,17 +327,22 @@ def make_hybrid_shard_map_step(
                 local, aux = out, None
             return jax.lax.pmean(local, data_axis), aux
 
-        (loss, aux), grads = jax.value_and_grad(global_loss, has_aux=True)(params)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("loss_grad"):
+            (loss, aux), grads = jax.value_and_grad(
+                global_loss, has_aux=True)(params)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         if has_aux:
             return params, opt_state, loss, jax.lax.pmean(aux, data_axis)
         return params, opt_state, loss
 
     out_specs = ((param_specs, st_specs, P(), P()) if has_aux
                  else (param_specs, st_specs, P()))
+    # the jitted program is named after the function: ``jit_train_step``
+    # on the profiler's "XLA Modules" line
     smapped = shard_map(
-        spmd, mesh=mesh,
+        train_step, mesh=mesh,
         in_specs=(param_specs, st_specs, batch_spec),
         out_specs=out_specs,
     )

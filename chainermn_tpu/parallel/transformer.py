@@ -157,12 +157,14 @@ def _attend_local_heads(q, k, v, *, causal, attn_impl, head_dim):
 def tp_block(x, params, *, head_dim: int, axis_name: str, causal: bool = True,
              attn_impl: str = "auto", positions=None):
     """Pre-norm transformer block: LN→attn→residual, LN→MLP→residual."""
-    h = _layer_norm(x, params["ln1_scale"], params["ln1_bias"])
-    x = x + tp_attention(h, params["attn"], head_dim=head_dim,
-                         axis_name=axis_name, causal=causal,
-                         attn_impl=attn_impl, positions=positions)
-    h = _layer_norm(x, params["ln2_scale"], params["ln2_bias"])
-    return x + tp_mlp(h, params["mlp"], axis_name=axis_name)
+    with jax.named_scope("block/attn"):
+        h = _layer_norm(x, params["ln1_scale"], params["ln1_bias"])
+        x = x + tp_attention(h, params["attn"], head_dim=head_dim,
+                             axis_name=axis_name, causal=causal,
+                             attn_impl=attn_impl, positions=positions)
+    with jax.named_scope("block/mlp"):
+        h = _layer_norm(x, params["ln2_scale"], params["ln2_bias"])
+        return x + tp_mlp(h, params["mlp"], axis_name=axis_name)
 
 
 def tp_attention_sp(x, params, *, head_dim: int, axis_name: str,
@@ -220,12 +222,14 @@ def tp_block_sp(x, params, *, head_dim: int, axis_name: str,
     """
     from .tensor_parallel import tp_mlp_sp
 
-    h = _layer_norm(x, params["ln1_scale"], params["ln1_bias"])
-    x = x + tp_attention_sp(h, params["attn"], head_dim=head_dim,
-                            axis_name=axis_name, causal=causal,
-                            attn_impl=attn_impl, positions=positions)
-    h = _layer_norm(x, params["ln2_scale"], params["ln2_bias"])
-    return x + tp_mlp_sp(h, params["mlp"], axis_name=axis_name)
+    with jax.named_scope("block/attn"):
+        h = _layer_norm(x, params["ln1_scale"], params["ln1_bias"])
+        x = x + tp_attention_sp(h, params["attn"], head_dim=head_dim,
+                                axis_name=axis_name, causal=causal,
+                                attn_impl=attn_impl, positions=positions)
+    with jax.named_scope("block/mlp"):
+        h = _layer_norm(x, params["ln2_scale"], params["ln2_bias"])
+        return x + tp_mlp_sp(h, params["mlp"], axis_name=axis_name)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -361,19 +365,23 @@ def tp_transformer_lm_loss(params, batch, *, head_dim: int, axis_name: str,
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
     from .tensor_parallel import vocab_parallel_embedding
 
-    x = vocab_parallel_embedding(inputs, params["embed"], axis_name=axis_name)
-    x = x * (params["embed"].shape[1] ** 0.5)
     positions = None
-    if "pos_embed" in params:
-        x = x + params["pos_embed"][: x.shape[1]][None]
-    else:  # RoPE model (init with pos_impl='rope'): rotate inside attention
-        positions = jnp.arange(x.shape[1])
+    with jax.named_scope("embed"):
+        x = vocab_parallel_embedding(inputs, params["embed"],
+                                     axis_name=axis_name)
+        x = x * (params["embed"].shape[1] ** 0.5)
+        if "pos_embed" in params:
+            x = x + params["pos_embed"][: x.shape[1]][None]
+        else:  # RoPE model (init with pos_impl='rope'): rotate in attention
+            positions = jnp.arange(x.shape[1])
     for blk in params["blocks"]:
         x = tp_block(x, blk, head_dim=head_dim, axis_name=axis_name,
                      causal=causal, attn_impl=attn_impl, positions=positions)
-    x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
-    return vocab_parallel_logits_loss(x, params["embed"], targets,
-                                      axis_name=axis_name, ce_impl=ce_impl)
+    with jax.named_scope("head_ce"):
+        x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
+        return vocab_parallel_logits_loss(x, params["embed"], targets,
+                                          axis_name=axis_name,
+                                          ce_impl=ce_impl)
 
 
 def sp_block(x, params, *, head_dim: int, axis_name: str, causal: bool = True,

@@ -46,6 +46,8 @@ from typing import Optional
 
 import numpy as np
 
+from ..observability import trace as _trace
+
 
 class DecodeEngine:
     """Device half of the serving engine: owns the sharded params and the
@@ -106,19 +108,23 @@ class DecodeEngine:
         axis, head_dim = self.axis_name, self.head_dim
         P = self._P
 
-        def tick_inner(params, caches, tokens, pos, keys, temps):
+        # the jitted programs are named after these functions: the
+        # profiler's "XLA Modules" line says ``jit_serving_tick``,
+        # ``jit_serving_prefill_<s_pad>``, ``jit_serving_prefix_copy``
+        def serving_tick(params, caches, tokens, pos, keys, temps):
             h_last, new_caches = lm_decode_tick(
                 params, tokens, caches, pos, head_dim=head_dim,
                 axis_name=axis)
             # the consumed token sits at row ``pos``; the selected next
             # token is position ``pos + 1`` — lm_generate's step_pos
             # salt, so sampling stays token-exact per request
-            nxt = _next_token(params["embed"], h_last, axis, keys, temps,
-                              pos + 1)
+            with jax.named_scope("tick/head"):
+                nxt = _next_token(params["embed"], h_last, axis, keys,
+                                  temps, pos + 1)
             return nxt, new_caches
 
         return jax.jit(self._shard_map(
-            tick_inner, mesh=self.mesh,
+            serving_tick, mesh=self.mesh,
             in_specs=(self._specs, self._cache_specs, P(), P(), P(), P()),
             out_specs=(P(), self._cache_specs)))
 
@@ -151,6 +157,7 @@ class DecodeEngine:
                                                   start)))
             return tok, new_caches
 
+        prefill_inner.__name__ = f"serving_prefill_{s_pad}"
         return jax.jit(self._shard_map(
             prefill_inner, mesh=self.mesh,
             in_specs=(self._specs, self._cache_specs, P(), P(), P(), P(),
@@ -168,7 +175,7 @@ class DecodeEngine:
         tiny traced scalars, never static)."""
         import jax
 
-        def copy_inner(caches, src, dst):
+        def serving_prefix_copy(caches, src, dst):
             new_caches = []
             for kc, vc in caches:
                 k_row = jax.lax.dynamic_index_in_dim(kc, src, axis=0,
@@ -183,7 +190,7 @@ class DecodeEngine:
 
         P = self._P
         return jax.jit(self._shard_map(
-            copy_inner, mesh=self.mesh,
+            serving_prefix_copy, mesh=self.mesh,
             in_specs=(self._cache_specs, P(), P()),
             out_specs=self._cache_specs))
 
@@ -214,25 +221,30 @@ class DecodeEngine:
             raise ValueError(
                 f"padded prompt length {s_pad} exceeds the learned "
                 f"pos_embed max_len {self.max_positions}")
-        if s_pad > s_real:
-            prompt = np.pad(prompt, ((0, 0), (0, s_pad - s_real)))
-        prog = self._prefill_progs.get(s_pad)
-        if prog is None:
-            prog = self._prefill_progs[s_pad] = self._build_prefill(s_pad)
-            self.prefill_compiles += 1
-            from ..observability import flight as _flight
-            _flight.note("compile", program="serving_prefill",
-                         padded_len=s_pad,
-                         family_size=len(self._prefill_progs))
-        self.prefill_calls += 1
-        key = (np.zeros(2, np.uint32) if rng is None
-               else np.asarray(rng, np.uint32).reshape(2))
-        tok, self.pool.caches = prog(
-            self._params, self.pool.caches, jnp.asarray(prompt),
-            jnp.int32(s_real), jnp.int32(slot), jnp.asarray(key),
-            jnp.float32(temperature))
+        with _trace.span("serving/prefill/stage", cat="serving"):
+            if s_pad > s_real:
+                prompt = np.pad(prompt, ((0, 0), (0, s_pad - s_real)))
+            prog = self._prefill_progs.get(s_pad)
+            if prog is None:
+                prog = self._prefill_progs[s_pad] = self._build_prefill(
+                    s_pad)
+                self.prefill_compiles += 1
+                from ..observability import flight as _flight
+                _flight.note("compile", program="serving_prefill",
+                             padded_len=s_pad,
+                             family_size=len(self._prefill_progs))
+            self.prefill_calls += 1
+            key = (np.zeros(2, np.uint32) if rng is None
+                   else np.asarray(rng, np.uint32).reshape(2))
+            operands = (jnp.asarray(prompt), jnp.int32(s_real),
+                        jnp.int32(slot), jnp.asarray(key),
+                        jnp.float32(temperature))
+        with _trace.span("serving/prefill/dispatch", cat="serving"):
+            tok, self.pool.caches = prog(self._params, self.pool.caches,
+                                         *operands)
         self.pool.pos[slot] = s_real
-        return int(np.asarray(tok)[0])
+        with _trace.span("serving/prefill/readback", cat="serving"):
+            return int(np.asarray(tok)[0])
 
     def copy_prefix(self, src_slot: int, dst_slot: int,
                     prefix_len: int) -> None:
@@ -272,20 +284,24 @@ class DecodeEngine:
         import jax.numpy as jnp
 
         self.tick_calls += 1
-        tokens = jnp.asarray(np.array(last_tokens, np.int32, copy=True))
-        # COPY at the jax boundary: on CPU ``jnp.asarray`` may zero-copy
-        # alias the host buffer, and dispatch is ASYNC — an in-place
-        # ``pos += 1`` below would race the still-executing tick (seen as
-        # a repeated first token under cold-compile latency).
-        pos = jnp.asarray(np.array(self.pool.pos, np.int32, copy=True))
-        if keys is None:
-            keys = np.zeros((self.pool.n_slots, 2), np.uint32)
-        if temps is None:
-            temps = np.zeros(self.pool.n_slots, np.float32)
-        nxt, self.pool.caches = self._tick_prog(
-            self._params, self.pool.caches, tokens, pos,
-            jnp.asarray(np.array(keys, np.uint32, copy=True)),
-            jnp.asarray(np.array(temps, np.float32, copy=True)))
+        with _trace.span("serving/tick/stage", cat="serving"):
+            tokens = jnp.asarray(np.array(last_tokens, np.int32, copy=True))
+            # COPY at the jax boundary: on CPU ``jnp.asarray`` may
+            # zero-copy alias the host buffer, and dispatch is ASYNC — an
+            # in-place ``pos += 1`` below would race the still-executing
+            # tick (seen as a repeated first token under cold-compile
+            # latency).
+            pos = jnp.asarray(np.array(self.pool.pos, np.int32, copy=True))
+            if keys is None:
+                keys = np.zeros((self.pool.n_slots, 2), np.uint32)
+            if temps is None:
+                temps = np.zeros(self.pool.n_slots, np.float32)
+            keys = jnp.asarray(np.array(keys, np.uint32, copy=True))
+            temps = jnp.asarray(np.array(temps, np.float32, copy=True))
+        with _trace.span("serving/tick/dispatch", cat="serving"):
+            nxt, self.pool.caches = self._tick_prog(
+                self._params, self.pool.caches, tokens, pos, keys, temps)
         self.pool.pos = self.pool.pos + 1  # out-of-place: never mutate a
         #                                    buffer jax might still read
-        return np.asarray(nxt)
+        with _trace.span("serving/tick/readback", cat="serving"):
+            return np.asarray(nxt)

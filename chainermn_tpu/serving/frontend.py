@@ -254,6 +254,8 @@ class ServingEngine:
         self._ticks = 0
         self._occupancy_sum = 0.0
         self._rejected = 0
+        self._prefill_tokens_real = 0
+        self._prefill_tokens_padded = 0
         self._t0 = time.monotonic()
         # goodput attribution: step() partitions its own wall clock, and
         # the gap between steps books as queue_wait (work was waiting)
@@ -359,307 +361,300 @@ class ServingEngine:
         Returns host-side stats for the iteration (also streamed to the
         JSONL metrics writer when configured).
 
+        Spans: the iteration is one ``serving/step``; its phases are its
+        children (``serving/expire``, ``/admit``, ``/prefill``,
+        ``/prefix_copy``, ``/spill_restore``, ``/tick``, ``/emit``,
+        ``/bookkeeping``), laid at the ``time.monotonic()`` boundaries
+        the goodput ledger already takes.  A ``jax.profiler`` session
+        sees them on the device trace's clock (docs/OBSERVABILITY.md).
+        The body stays in THIS frame under the root span: one more Python
+        frame between here and a program's first call made JAX's tracing
+        of the prefill programs 40 % slower on the v5e host (PERF.md,
+        Findings PR 25).
+
         Goodput attribution: the whole iteration's wall clock lands in
         ledger buckets — prefill/tick device calls as ``compute`` (or
         ``compile`` on a call that built a new program), everything
         around them as ``host``, and the gap since the previous step as
         ``queue_wait`` (work was waiting) or ``stall`` (idle)."""
-        t_step0 = time.monotonic()
-        # the gap since the previous step — or, on the FIRST step, since
-        # construction/reset: a fleet replica can idle a long time while
-        # a sibling compiles, and leaving that window unattributed would
-        # swamp its ledger's coverage (ISSUE 7)
-        last = (self._last_step_end if self._last_step_end is not None
-                else self._t0)
-        gap = t_step0 - last
-        if gap > 0:
-            had_work = (self.scheduler.queue_depth > 0
-                        or self.pool.busy_count > 0)
-            self.goodput.add("queue_wait" if had_work else "stall", gap)
-        t_host = t_step0                       # start of current host segment
+        with obs.span("serving/step", cat="serving", tick=self._ticks):
+            t_step0 = time.monotonic()
+            # the gap since the previous step — or, on the FIRST step, since
+            # construction/reset: a fleet replica can idle a long time while
+            # a sibling compiles, and leaving that window unattributed would
+            # swamp its ledger's coverage (ISSUE 7)
+            last = (self._last_step_end if self._last_step_end is not None
+                    else self._t0)
+            gap = t_step0 - last
+            if gap > 0:
+                had_work = (self.scheduler.queue_depth > 0
+                            or self.pool.busy_count > 0)
+                self.goodput.add("queue_wait" if had_work else "stall", gap)
+            t_host = t_step0               # start of current host segment
 
-        now = time.monotonic()
-        for req in self.scheduler.expire_queued(now):
-            obs.instant("serving/request/expired", cat="serving",
-                        request=req.id, trace_id=req.trace_id)
-            self._finish_tracing(req, "deadline")
+            now = time.monotonic()
+            with obs.span("serving/expire", cat="serving"):
+                for req in self.scheduler.expire_queued(now):
+                    self._finish_tracing(req, "deadline")
 
-        # admit up to the interleave bound into free slots; rc==0 cached
-        # prefix slots count as free-after-eviction (scavengeable)
-        avail = self.pool.free_count
-        if self.prefix_cache is not None:
-            avail += self.prefix_cache.evictable_count()
-        admitted_batch = self.scheduler.admissions(avail, now)
-        for batch_i, req in enumerate(admitted_batch):
-            # match-and-PIN the radix trie BEFORE taking a slot: the
-            # acquire below may scavenge an rc==0 cached slot, and an
-            # unpinned match would be its own eviction victim — under a
-            # saturated pool every donation would be scavenged by the
-            # next admission and the cache could never produce a hit
-            entry = None
-            mlen = 0
-            if self.prefix_cache is not None:
-                entry, mlen = self.prefix_cache.match(req.prompt)
+            # admit up to the interleave bound into free slots; rc==0 cached
+            # prefix slots count as free-after-eviction (scavengeable)
+            with obs.span("serving/admit", cat="serving"):
+                avail = self.pool.free_count
+                if self.prefix_cache is not None:
+                    avail += self.prefix_cache.evictable_count()
+                admitted_batch = self.scheduler.admissions(avail, now)
+            for batch_i, req in enumerate(admitted_batch):
+                with obs.span("serving/admit", cat="serving",
+                              admitted=len(admitted_batch)):
+                    slot, entry, mlen = self._match_and_acquire(req)
+                    if slot is None:
+                        # every scavengeable slot is pinned by EARLIER
+                        # admissions in this batch — put THIS request AND
+                        # every later one admissions() already popped back
+                        # at the queue head (reverse order keeps FIFO;
+                        # dropping them would strand their handles un-done
+                        # forever); a finishing request unblocks the next
+                        # step
+                        for later in reversed(admitted_batch[batch_i:]):
+                            self.scheduler.requeue_front(later)
+                        break
+                req.slot = slot
+                req.status = "running"
+                t_admit = time.monotonic()
+                req.timestamps["prefill_start"] = t_admit
+                # the queue-wait span, retrospectively: submit → this admit
+                t_us = getattr(req, "trace_us", None)
+                if t_us is not None and obs.enabled():
+                    now_us = obs.now_us()
+                    obs.complete_event(
+                        "request/queue_wait", t_us["submitted"],
+                        now_us - t_us["submitted"], cat="serving_request",
+                        trace_id=req.trace_id, request=req.id)
+                obs.instant("serving/request/prefill", cat="serving",
+                            request=req.id, slot=slot, trace_id=req.trace_id)
+                _flight.note("serving", event="admitted", request=req.id,
+                             trace_id=req.trace_id, slot=slot)
+                # prefix HIT (matched above): copy the cached slot's K/V
+                # instead of re-prefilling the shared prefix; the un-cached
+                # suffix feeds through the shared decode tick one token per
+                # iteration (``req.forced``)
                 if entry is not None:
-                    self.prefix_cache.retain(entry)
-                    req.prefix_entry, req.prefix_len = entry, mlen
-            slot = self._acquire_slot()
-            if slot is None and entry is not None:
-                # OUR OWN match is the only scavengeable slot: with no
-                # busy slots nothing else will ever free one, so give
-                # up the hit rather than stall the pool — unpin and
-                # scavenge it like any other cold entry (and back the
-                # counters out: this became a miss)
-                self.prefix_cache.release(entry)
-                self.prefix_cache.hits -= 1
-                self.prefix_cache.misses += 1
-                self.prefix_cache.tokens_reused -= mlen
-                req.prefix_entry, req.prefix_len = None, 0
-                entry, mlen = None, 0
-                slot = self._acquire_slot()
-            if slot is None:
-                # every scavengeable slot is pinned by EARLIER
-                # admissions in this batch — put THIS request AND every
-                # later one admissions() already popped back at the
-                # queue head (reverse order keeps FIFO; dropping them
-                # would strand their handles un-done forever); a
-                # finishing request unblocks the next step
-                for later in reversed(admitted_batch[batch_i:]):
-                    self.scheduler.requeue_front(later)
-                break
-            req.slot = slot
-            req.status = "running"
-            t_admit = time.monotonic()
-            req.timestamps["prefill_start"] = t_admit
-            # the queue-wait span, retrospectively: submit → this admit
-            t_us = getattr(req, "trace_us", None)
-            if t_us is not None:
-                now_us = obs.now_us()
-                obs.complete_event(
-                    "request/queue_wait", t_us["submitted"],
-                    now_us - t_us["submitted"], cat="serving_request",
-                    trace_id=req.trace_id, request=req.id)
-            obs.instant("serving/request/prefill", cat="serving",
-                        request=req.id, slot=slot, trace_id=req.trace_id)
-            _flight.note("serving", event="admitted", request=req.id,
-                         trace_id=req.trace_id, slot=slot)
-            # prefix HIT (matched above): copy the cached slot's K/V
-            # instead of re-prefilling the shared prefix; the un-cached
-            # suffix feeds through the shared decode tick one token per
-            # iteration (``req.forced``)
-            if entry is not None:
-                req.forced.extend(req.prompt[mlen:])
-                self._set_slot_sampling(slot, req)
-                self.goodput.add("host", t_admit - t_host)
-                t_cp = time.monotonic()
-                try:
-                    with obs.span("serving/prefix_copy",
-                                  cat="serving_request", request=req.id,
-                                  trace_id=req.trace_id, slot=slot,
-                                  src_slot=entry.slot, prefix_len=mlen):
-                        self.engine.copy_prefix(entry.slot, slot, mlen)
-                    t_host = time.monotonic()
-                    self.goodput.add("compute", t_host - t_cp)
-                except Exception as e:
-                    t_host = time.monotonic()
-                    self.goodput.add("compute", t_host - t_cp)
-                    self._abort_slot(req, slot)
-                    req.finish("error", time.monotonic())
-                    obs.instant("serving/request/error", cat="serving",
-                                request=req.id, trace_id=req.trace_id)
-                    _flight.note("serving", event="error",
-                                 request=req.id, trace_id=req.trace_id,
-                                 error=repr(e))
-                    self._finish_tracing(req, "error")
-                    print(f"chainermn_tpu.serving: prefix copy for "
-                          f"request {req.id} failed: {e!r}",
-                          file=sys.stderr)
-                    continue
-                obs.instant("serving/request/prefix_hit", cat="serving",
-                            request=req.id, slot=slot,
-                            trace_id=req.trace_id, prefix_len=mlen,
-                            src_slot=entry.slot)
-                _flight.note("serving", event="prefix_hit",
-                             request=req.id, trace_id=req.trace_id,
-                             slot=slot, prefix_len=mlen)
-                with self._lock:
-                    self._running[slot] = req
-                # no token yet: the suffix's LAST tick emits the first
-                # one; only the deadline can evict before that
-                self._maybe_evict(req, time.monotonic())
-                continue
-            # device-cache miss: the host spill tier may still hold the
-            # prefix (ISSUE 12) — restore lands the CRC-verified slab
-            # straight into THIS request's slot and feeds the suffix
-            # through the shared tick, exactly the copy-on-extend shape
-            if self.spill is not None:
-                t_rs = time.monotonic()
-                self.goodput.add("host", t_rs - t_host)
-                with obs.span("serving/spill_restore",
-                              cat="serving_request", request=req.id,
-                              trace_id=req.trace_id, slot=slot):
-                    rlen = self._try_restore(req, slot)
-                t_host = time.monotonic()
-                self.goodput.add("compute" if rlen else "host",
-                                 t_host - t_rs)
-                if rlen:
-                    req.forced.extend(req.prompt[rlen:])
+                    req.forced.extend(req.prompt[mlen:])
                     self._set_slot_sampling(slot, req)
-                    obs.instant("serving/request/spill_restore",
-                                cat="serving", request=req.id,
-                                slot=slot, trace_id=req.trace_id,
-                                prefix_len=rlen)
-                    _flight.note("serving", event="restore",
+                    self.goodput.add("host", t_admit - t_host)
+                    t_cp = time.monotonic()
+                    try:
+                        with obs.span("serving/prefix_copy",
+                                      cat="serving_request", request=req.id,
+                                      trace_id=req.trace_id, slot=slot,
+                                      src_slot=entry.slot, prefix_len=mlen):
+                            self.engine.copy_prefix(entry.slot, slot, mlen)
+                        t_host = time.monotonic()
+                        self.goodput.add("compute", t_host - t_cp)
+                    except Exception as e:
+                        t_host = time.monotonic()
+                        self.goodput.add("compute", t_host - t_cp)
+                        self._abort_slot(req, slot)
+                        req.finish("error", time.monotonic())
+                        _flight.note("serving", event="error",
+                                     request=req.id, trace_id=req.trace_id,
+                                     error=repr(e))
+                        self._finish_tracing(req, "error")
+                        print(f"chainermn_tpu.serving: prefix copy for "
+                              f"request {req.id} failed: {e!r}",
+                              file=sys.stderr)
+                        continue
+                    _flight.note("serving", event="prefix_hit",
                                  request=req.id, trace_id=req.trace_id,
-                                 slot=slot, prefix_len=rlen)
+                                 slot=slot, prefix_len=mlen)
                     with self._lock:
                         self._running[slot] = req
+                    # no token yet: the suffix's LAST tick emits the first
+                    # one; only the deadline can evict before that
                     self._maybe_evict(req, time.monotonic())
                     continue
-            try:
-                # a failed restore attempt above already booked its own
-                # wall and advanced t_host past t_admit — never book a
-                # negative host segment
-                self.goodput.add("host", max(t_admit - t_host, 0.0))
-                compiles_before = self.engine.prefill_compiles
-                t_pf = time.monotonic()
-                with obs.span("serving/prefill", cat="serving_request",
-                              request=req.id, trace_id=req.trace_id,
-                              slot=slot):
-                    first = self.engine.prefill_into_slot(
-                        req.prompt, slot, rng=req.rng,
-                        temperature=req.temperature)
-                self._set_slot_sampling(slot, req)
-                t_host = time.monotonic()
-                # the engine's own counter says whether THIS call built
-                # a new program — no probing of its cache internals
-                self.goodput.add(
-                    "compile" if self.engine.prefill_compiles
-                    > compiles_before else "compute", t_host - t_pf)
-            except Exception as e:
-                t_host = time.monotonic()
-                self.goodput.add("compute", t_host - t_pf)
-                # never die holding a slot: a failed prefill (engine bug,
-                # OOM, ...) releases the slot and fails THIS request only
-                # — with start() an escaping exception would kill the
-                # background thread and stall every other request, so the
-                # engine sheds the request and keeps serving
-                self._abort_slot(req, slot)
-                req.finish("error", time.monotonic())
-                obs.instant("serving/request/error", cat="serving",
-                            request=req.id, trace_id=req.trace_id)
-                _flight.note("serving", event="error", request=req.id,
-                             trace_id=req.trace_id, error=repr(e))
-                self._finish_tracing(req, "error")
-                print(f"chainermn_tpu.serving: prefill of request "
-                      f"{req.id} failed: {e!r}", file=sys.stderr)
-                continue
-            self._emit(req, first, time.monotonic())
-            with self._lock:
-                self._running[slot] = req
-            self._maybe_evict(req, time.monotonic())
+                # device-cache miss: the host spill tier may still hold the
+                # prefix (ISSUE 12) — restore lands the CRC-verified slab
+                # straight into THIS request's slot and feeds the suffix
+                # through the shared tick, exactly the copy-on-extend shape
+                if self.spill is not None:
+                    t_rs = time.monotonic()
+                    self.goodput.add("host", t_rs - t_host)
+                    with obs.span("serving/spill_restore",
+                                  cat="serving_request", request=req.id,
+                                  trace_id=req.trace_id, slot=slot):
+                        rlen = self._try_restore(req, slot)
+                    t_host = time.monotonic()
+                    self.goodput.add("compute" if rlen else "host",
+                                     t_host - t_rs)
+                    if rlen:
+                        req.forced.extend(req.prompt[rlen:])
+                        self._set_slot_sampling(slot, req)
+                        _flight.note("serving", event="restore",
+                                     request=req.id, trace_id=req.trace_id,
+                                     slot=slot, prefix_len=rlen)
+                        with self._lock:
+                            self._running[slot] = req
+                        self._maybe_evict(req, time.monotonic())
+                        continue
+                try:
+                    # a failed restore attempt above already booked its own
+                    # wall and advanced t_host past t_admit — never book a
+                    # negative host segment
+                    self.goodput.add("host", max(t_admit - t_host, 0.0))
+                    compiles_before = self.engine.prefill_compiles
+                    s_pad = self.engine.padded_len(req.prompt_len)
+                    t_pf = time.monotonic()
+                    with obs.span("serving/prefill", cat="serving_request",
+                                  request=req.id, trace_id=req.trace_id,
+                                  slot=slot, s_real=req.prompt_len,
+                                  s_pad=s_pad):
+                        first = self.engine.prefill_into_slot(
+                            req.prompt, slot, rng=req.rng,
+                            temperature=req.temperature)
+                    self._set_slot_sampling(slot, req)
+                    t_host = time.monotonic()
+                    # the engine's own counter says whether THIS call built
+                    # a new program — no probing of its cache internals
+                    self.goodput.add(
+                        "compile" if self.engine.prefill_compiles
+                        > compiles_before else "compute", t_host - t_pf)
+                except Exception as e:
+                    t_host = time.monotonic()
+                    self.goodput.add("compute", t_host - t_pf)
+                    # never die holding a slot: a failed prefill (engine bug,
+                    # OOM, ...) releases the slot and fails THIS request only
+                    # — with start() an escaping exception would kill the
+                    # background thread and stall every other request, so the
+                    # engine sheds the request and keeps serving
+                    self._abort_slot(req, slot)
+                    req.finish("error", time.monotonic())
+                    _flight.note("serving", event="error", request=req.id,
+                                 trace_id=req.trace_id, error=repr(e))
+                    self._finish_tracing(req, "error")
+                    print(f"chainermn_tpu.serving: prefill of request "
+                          f"{req.id} failed: {e!r}", file=sys.stderr)
+                    continue
+                with obs.span("serving/emit", cat="serving", tokens=1):
+                    self._emit(req, first, time.monotonic())
+                    with self._lock:
+                        self._running[slot] = req
+                        self._prefill_tokens_real += req.prompt_len
+                        self._prefill_tokens_padded += s_pad
+                    self._maybe_evict(req, time.monotonic())
 
-        # one decode tick over the pool (skip when nothing is active)
-        with self._lock:
-            active = dict(self._running)
-        if active:
-            tokens = np.zeros(self.pool.n_slots, np.int32)
-            for slot, req in active.items():
-                # a prefix-hit request still owing suffix tokens feeds
-                # the next PROMPT token (its K/V row gets written; the
-                # prediction is known and discarded until the last one)
-                tokens[slot] = (req.forced[0] if req.forced
-                                else req.tokens[-1])
-            t_tick = time.monotonic()
-            self.goodput.add("host", t_tick - t_host)
-            # inter-tick gap: what a decoding request waits between its
-            # tokens — includes any prefill that ran above (the fused
-            # engine's tail; see the disagg bench section, ISSUE 9).
-            # Locked with reset_stats: a bench warm-up reset racing this
-            # read-modify-write could book one warm-up gap into the
-            # gated window (the unguarded-shared-write lint class)
+            # one decode tick over the pool (skip when nothing is active)
             with self._lock:
-                if self._last_tick_start is not None:
-                    self._tick_gap_ms.add(
-                        (t_tick - self._last_tick_start) * 1e3)
-                self._last_tick_start = t_tick
-            tick_bucket = ("compile" if self.engine.tick_calls == 0
-                           else "compute")
-            t_tick_us = obs.now_us()
-            with obs.span("serving/tick", cat="serving",
-                          active=len(active)):
-                with self.goodput.measure(tick_bucket):
-                    nxt = self.engine.tick(tokens, self._slot_keys,
-                                           self._slot_temps)
-            t_host = time.monotonic()
-            dt_ms = (t_host - t_tick) * 1e3
-            dt_us = obs.now_us() - t_tick_us
-            now = time.monotonic()
-            for slot, req in active.items():
-                # per-request decode-tick span, nested under the engine
-                # tick on the timeline and keyed by the trace id
-                obs.complete_event(
-                    "request/decode_tick", t_tick_us, dt_us,
-                    cat="serving_request", trace_id=req.trace_id,
-                    request=req.id, slot=slot, active=len(active))
-                still_forced = False
-                if req.forced:
-                    req.forced.popleft()
-                    still_forced = bool(req.forced)
-                if not still_forced:
-                    # miss path, or the suffix's last prompt token just
-                    # ran: the tick's prediction IS the next real token
-                    self._emit(req, int(nxt[slot]), now)
-                self._tok_lat_ms.add(dt_ms / max(len(active), 1))
-                self._maybe_evict(req, now)
-        else:
-            # an idle step breaks the tick cadence: the next gap would
-            # measure stall, not inter-token latency — restart the clock
-            with self._lock:
-                self._last_tick_start = None
+                active = dict(self._running)
+            if active:
+                tokens = np.zeros(self.pool.n_slots, np.int32)
+                for slot, req in active.items():
+                    # a prefix-hit request still owing suffix tokens feeds
+                    # the next PROMPT token (its K/V row gets written; the
+                    # prediction is known and discarded until the last one)
+                    tokens[slot] = (req.forced[0] if req.forced
+                                    else req.tokens[-1])
+                t_tick = time.monotonic()
+                self.goodput.add("host", t_tick - t_host)
+                # inter-tick gap: what a decoding request waits between its
+                # tokens — includes any prefill that ran above (the fused
+                # engine's tail; see the disagg bench section, ISSUE 9).
+                # Locked with reset_stats: a bench warm-up reset racing this
+                # read-modify-write could book one warm-up gap into the
+                # gated window (the unguarded-shared-write lint class)
+                with self._lock:
+                    if self._last_tick_start is not None:
+                        self._tick_gap_ms.add(
+                            (t_tick - self._last_tick_start) * 1e3)
+                    self._last_tick_start = t_tick
+                tick_bucket = ("compile" if self.engine.tick_calls == 0
+                               else "compute")
+                # the tracer's clock is read only for its own Chrome sink
+                recording = obs.enabled()
+                t_tick_us = obs.now_us() if recording else 0
+                with obs.span("serving/tick", cat="serving",
+                              active=len(active)):
+                    with self.goodput.measure(tick_bucket):
+                        nxt = self.engine.tick(tokens, self._slot_keys,
+                                               self._slot_temps)
+                t_host = time.monotonic()
+                dt_ms = (t_host - t_tick) * 1e3
+                dt_us = obs.now_us() - t_tick_us if recording else 0
+                now = time.monotonic()
+                with obs.span("serving/emit", cat="serving",
+                              tokens=len(active)):
+                    for slot, req in active.items():
+                        if recording:
+                            # per-request decode-tick span, nested under the
+                            # engine tick on the timeline and keyed by the
+                            # trace id
+                            obs.complete_event(
+                                "request/decode_tick", t_tick_us, dt_us,
+                                cat="serving_request", trace_id=req.trace_id,
+                                request=req.id, slot=slot, active=len(active))
+                        still_forced = False
+                        if req.forced:
+                            req.forced.popleft()
+                            still_forced = bool(req.forced)
+                        if not still_forced:
+                            # miss path, or the suffix's last prompt token
+                            # just ran: the tick's prediction IS the next
+                            # real token
+                            self._emit(req, int(nxt[slot]), now)
+                        self._tok_lat_ms.add(dt_ms / max(len(active), 1))
+                        self._maybe_evict(req, now)
+            else:
+                # an idle step breaks the tick cadence: the next gap would
+                # measure stall, not inter-token latency — restart the clock
+                with self._lock:
+                    self._last_tick_start = None
 
-        with self._lock:
-            self._ticks += 1
-            self._occupancy_sum += self.pool.busy_count / self.pool.n_slots
-            stats = {
-                "queue_depth": float(self.scheduler.queue_depth),
-                "active_slots": float(self.pool.busy_count),
-                "tokens_emitted": float(self._tokens_emitted),
-            }
-        obs.set_gauge("serving/queue_depth", stats["queue_depth"])
-        obs.set_gauge("serving/active_slots", stats["active_slots"])
-        el = time.monotonic() - self._t0
-        if el > 0:
-            obs.set_gauge("serving/tokens_per_sec",
-                          self._tokens_emitted / el)
-        if self.slo is not None and active:
-            # per-step instantaneous rate: tokens since the previous
-            # observation over the elapsed gap (idle steps don't count
-            # — zero demand is not an SLO violation).  The read-modify-
-            # write of _slo_last is atomic vs reset_stats; the SLO
-            # observation happens OUTSIDE the lock (SLOTracker has its
-            # own — nesting them would order the two locks)
-            now_t = time.monotonic()
-            with self._lock:
-                last_tok, last_t = self._slo_last
-                emitted = self._tokens_emitted
-                self._slo_last = (emitted, now_t)
-            dt = now_t - last_t
-            if dt > 0:
-                self.slo.observe_throughput((emitted - last_tok) / dt)
-        if self.metrics_writer is not None:
-            self.metrics_writer.write(
-                {f"serving/{k}": v for k, v in stats.items()},
-                kind="serving_step")
-        t_end = time.monotonic()
-        self.goodput.add("host", t_end - t_host)
-        with self._lock:
-            self._last_step_end = t_end
-        # phase stamp: the ring's "last completed unit of work" marker
-        # (what explain_bundle names when a serve loop dies mid-flight)
-        _flight.note("phase", name="serving/step", tick=self._ticks,
-                     active=int(stats["active_slots"]))
-        return stats
+            with obs.span("serving/bookkeeping", cat="serving"):
+                with self._lock:
+                    self._ticks += 1
+                    self._occupancy_sum += (self.pool.busy_count
+                                            / self.pool.n_slots)
+                    stats = {
+                        "queue_depth": float(self.scheduler.queue_depth),
+                        "active_slots": float(self.pool.busy_count),
+                        "tokens_emitted": float(self._tokens_emitted),
+                    }
+                obs.set_gauge("serving/queue_depth", stats["queue_depth"])
+                obs.set_gauge("serving/active_slots", stats["active_slots"])
+                el = time.monotonic() - self._t0
+                if el > 0:
+                    obs.set_gauge("serving/tokens_per_sec",
+                                  self._tokens_emitted / el)
+                if self.slo is not None and active:
+                    # per-step instantaneous rate: tokens since the
+                    # previous observation over the elapsed gap (idle
+                    # steps don't count — zero demand is not an SLO
+                    # violation).  The read-modify-write of _slo_last is
+                    # atomic vs reset_stats; the SLO observation happens
+                    # OUTSIDE the lock (SLOTracker has its own — nesting
+                    # them would order the two locks)
+                    now_t = time.monotonic()
+                    with self._lock:
+                        last_tok, last_t = self._slo_last
+                        emitted = self._tokens_emitted
+                        self._slo_last = (emitted, now_t)
+                    dt = now_t - last_t
+                    if dt > 0:
+                        self.slo.observe_throughput((emitted - last_tok) / dt)
+                if self.metrics_writer is not None:
+                    self.metrics_writer.write(
+                        {f"serving/{k}": v for k, v in stats.items()},
+                        kind="serving_step")
+                t_end = time.monotonic()
+                self.goodput.add("host", t_end - t_host)
+                with self._lock:
+                    self._last_step_end = t_end
+                # phase stamp: the ring's "last completed unit of work" marker
+                # (what explain_bundle names when a serve loop dies mid-flight)
+                _flight.note("phase", name="serving/step", tick=self._ticks,
+                             active=int(stats["active_slots"]))
+            return stats
 
     def _emit(self, req: Request, token: int, now: float) -> None:
         req.tokens.append(int(token))
@@ -739,6 +734,38 @@ class ServingEngine:
         self._finish_tracing(req, reason)
 
     # ---- slot lifecycle (prefix-cache aware; ISSUE 7) ----
+    def _match_and_acquire(self, req: Request):
+        """One admission's slot: ``(slot, prefix entry, matched length)``,
+        slot ``None`` when every scavengeable slot is pinned by earlier
+        admissions of the batch."""
+        # match-and-PIN the radix trie BEFORE taking a slot: the
+        # acquire below may scavenge an rc==0 cached slot, and an
+        # unpinned match would be its own eviction victim — under a
+        # saturated pool every donation would be scavenged by the
+        # next admission and the cache could never produce a hit
+        entry = None
+        mlen = 0
+        if self.prefix_cache is not None:
+            entry, mlen = self.prefix_cache.match(req.prompt)
+            if entry is not None:
+                self.prefix_cache.retain(entry)
+                req.prefix_entry, req.prefix_len = entry, mlen
+        slot = self._acquire_slot()
+        if slot is None and entry is not None:
+            # OUR OWN match is the only scavengeable slot: with no
+            # busy slots nothing else will ever free one, so give
+            # up the hit rather than stall the pool — unpin and
+            # scavenge it like any other cold entry (and back the
+            # counters out: this became a miss)
+            self.prefix_cache.release(entry)
+            self.prefix_cache.hits -= 1
+            self.prefix_cache.misses += 1
+            self.prefix_cache.tokens_reused -= mlen
+            req.prefix_entry, req.prefix_len = None, 0
+            entry, mlen = None, 0
+            slot = self._acquire_slot()
+        return slot, entry, mlen
+
     def _acquire_slot(self) -> Optional[int]:
         """Free slot, scavenging the LRU unpinned prefix entry when the
         free list is empty — the cache borrows capacity, never owns it."""
@@ -813,8 +840,6 @@ class ServingEngine:
                          prefix_len=entry.length,
                          bytes=len(payload),
                          store_bytes=self.spill.bytes_held)
-            obs.instant("serving/spill", cat="serving",
-                        prefix_len=entry.length, bytes=len(payload))
         return ok
 
     def _on_spill_evict(self, seq, length) -> None:
@@ -857,8 +882,6 @@ class ServingEngine:
             _flight.note("serving", event="spill_crc_refused",
                          request=req.id, trace_id=req.trace_id,
                          error=str(e))
-            obs.instant("serving/spill_crc_refused", cat="serving",
-                        request=req.id, trace_id=req.trace_id)
             return 0
         except Exception as e:  # noqa: BLE001 — inject failure: the
             # pool is unchanged (functional update never assigned);
@@ -941,6 +964,8 @@ class ServingEngine:
             self._ticks = 0
             self._occupancy_sum = 0.0
             self._rejected = 0
+            self._prefill_tokens_real = 0
+            self._prefill_tokens_padded = 0
             self.goodput.reset()
             self._last_step_end = None
             self._slo_last = (0, self._t0)
@@ -971,6 +996,12 @@ class ServingEngine:
                 "serving/queue_depth": float(self.scheduler.queue_depth),
                 "serving/active_slots": float(self.pool.busy_count),
                 "serving/rejected_total": float(self._rejected),
+                # useful over attempted prefill work: a prompt is padded
+                # up to a multiple of the engine's ``prefill_bucket``
+                "serving/prefill_tokens_real": float(
+                    self._prefill_tokens_real),
+                "serving/prefill_tokens_padded": float(
+                    self._prefill_tokens_padded),
                 "serving/slot_occupancy_pct": 100.0 * (
                     self._occupancy_sum / self._ticks if self._ticks
                     else 0.0),

@@ -274,7 +274,7 @@ def make_flax_train_step(
     if mesh is None:
         mesh = make_mesh(axis_name=axis_name)
 
-    def spmd(variables, opt_state, batch):
+    def train_step(variables, opt_state, batch):
         if preprocess is not None:
             batch = preprocess(batch)
         params = variables["params"]
@@ -287,18 +287,20 @@ def make_flax_train_step(
             loss, metrics = loss_and_metrics(out, batch)
             return loss, (mutated, metrics)
 
-        (loss, (mutated, metrics)), grads = _value_and_global_grads(
-            local_loss, params, axis_name, allreduce_grad_dtype,
-            grad_reduce=grad_reduce)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("loss_grad"):
+            (loss, (mutated, metrics)), grads = _value_and_global_grads(
+                local_loss, params, axis_name, allreduce_grad_dtype,
+                grad_reduce=grad_reduce)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         new_stats = _col.pmean(mutated["batch_stats"], axis_name)
         metrics = _col.pmean(metrics, axis_name)
         return ({"params": params, "batch_stats": new_stats},
                 opt_state, loss, metrics)
 
     smapped = shard_map(
-        spmd, mesh=mesh,
+        train_step, mesh=mesh,
         in_specs=(P(), P(), P(axis_name)),
         out_specs=(P(), P(), P(), P()),
     )

@@ -70,10 +70,18 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _n_kernels(fn, *args) -> int:
-    """Compile ``fn`` for the described chip; count its Pallas kernels."""
-    return jax.jit(fn).lower(*args).compile().as_text().count(
-        "tpu_custom_call")
+def _n_kernels(fn, *args, names=()) -> int:
+    """Compile ``fn`` for the described chip; count its Pallas kernels.
+    ``names``: each kernel's own ``name=``, which the custom call's
+    instruction name must hold (alone, ``%flash_fwd.2``, or inside the
+    autodiff wrapping when no scope stands between, ``%jvp_flash_fwd_``):
+    the profiler's ops line calls the kernel by that instruction name."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [ln.split(" = ")[0] for ln in text.split("\n")
+             if "tpu_custom_call" in ln]
+    for name in names:
+        assert any(name in c for c in calls), (name, calls)
+    return text.count("tpu_custom_call")
 
 
 def test_flash_attention_fwd(one_chip):
@@ -81,7 +89,7 @@ def test_flash_attention_fwd(one_chip):
 
     q = _sds((4, 2048, 8, 128), jnp.bfloat16, one_chip)
     n = _n_kernels(partial(flash_attention, causal=True, interpret=False),
-                   q, q, q)
+                   q, q, q, names=("flash_fwd",))
     assert n >= 1
 
 
@@ -94,7 +102,8 @@ def test_flash_attention_fwd_bwd(one_chip):
         out = flash_attention(q, k, v, causal=True, interpret=False)
         return out.astype(jnp.float32).sum()
 
-    n = _n_kernels(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    n = _n_kernels(jax.grad(loss, argnums=(0, 1, 2)), q, q, q,
+                   names=("flash_fwd", "flash_bwd"))
     assert n >= 2       # forward + backward kernels
 
 
@@ -108,7 +117,8 @@ def test_fused_cross_entropy_fwd_bwd(one_chip):
     def loss(h, table, tgt):
         return fused_cross_entropy(h, table, tgt, interpret=False).mean()
 
-    n = _n_kernels(jax.grad(loss, argnums=(0, 1)), h, table, tgt)
+    n = _n_kernels(jax.grad(loss, argnums=(0, 1)), h, table, tgt,
+                   names=("fused_ce_stats", "fused_ce_dh", "fused_ce_dtable"))
     assert n >= 2
 
 
@@ -120,7 +130,8 @@ def test_decode_attend(one_chip):
     kc = _sds((b, s, h * hd), jnp.bfloat16, one_chip)
     pos = _sds((), jnp.int32, one_chip)
     n = _n_kernels(partial(decode_attend, n_heads=h, head_dim=hd,
-                           interpret=False), q, kc, kc, pos)
+                           interpret=False), q, kc, kc, pos,
+                   names=("decode_attn_mha",))
     assert n >= 1
 
 
@@ -134,7 +145,8 @@ def test_cache_append(one_chip, as_tpu):
     new = _sds((b, 1, d), jnp.bfloat16, one_chip)
     pos = _sds((), jnp.int32, one_chip)
     n = _n_kernels(partial(cache_append, axis=1, impl="pallas",
-                           interpret=False), kc, kc, new, new, pos)
+                           interpret=False), kc, kc, new, new, pos,
+                   names=("kv_cache_write",))
     assert n >= 1
 
 
@@ -148,7 +160,7 @@ def test_conv3x3_backward(one_chip):
         return (conv3x3_dgrad(dy, w, x.shape, 1, interpret=False),
                 conv3x3_wgrad(x, dy, 1, interpret=False))
 
-    n = _n_kernels(bwd, x, x, w)
+    n = _n_kernels(bwd, x, x, w, names=("conv_bwd_dx", "conv_bwd_dw"))
     assert n >= 2
 
 
@@ -194,8 +206,15 @@ def test_lm_135m_train_step_in_shard_map(topo, as_tpu):
         shaped(params, specs),
         shaped(opt_state, state_specs_like(optimizer, params, specs)),
         batch).compile()
+    text = compiled.as_text()
     # flash fwd + bwd per layer, plus the fused-CE kernels
-    assert compiled.as_text().count("tpu_custom_call") >= 2 * N_LAYERS + 2
+    assert text.count("tpu_custom_call") >= 2 * N_LAYERS + 2
+    # every kernel and the program carry their own names (the profiler's
+    # ops and modules lines are read by them: benchmark/layer_metrics)
+    assert "HloModule jit_train_step" in text
+    for kernel in ("flash_fwd", "flash_bwd", "fused_ce_stats",
+                   "fused_ce_dh", "fused_ce_dtable"):
+        assert f"%{kernel}" in text, kernel
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < 16 * 2 ** 30)      # fits one v5e chip's HBM
@@ -228,11 +247,14 @@ def test_serving_prefill_and_tick(topo, as_tpu):
         _sds((2,), jnp.uint32, rep), _sds((), jnp.float32, rep)).compile()
     # the prompt pass takes the flash kernel, one per layer
     assert prefill.as_text().count("tpu_custom_call") >= N_LAYERS
+    assert f"HloModule jit_serving_prefill_{prompt}" in prefill.as_text()
+    assert "%flash_fwd" in prefill.as_text()
 
-    eng._build_tick().lower(
+    tick = eng._build_tick().lower(
         p, caches, _sds((n_slots,), jnp.int32, rep),
         _sds((n_slots,), jnp.int32, rep),
         _sds((n_slots, 2), jnp.uint32, rep),
         _sds((n_slots,), jnp.float32, rep)).compile()
+    assert "HloModule jit_serving_tick" in tick.as_text()
     # (the tick feeds per-row positions, which parallel/decode.py routes
     # to einsum attention — no kernel is expected in it today)
