@@ -25,12 +25,23 @@ heuristics:
   with ``SEGᵀ`` (MXU again) and reduces over the block's sublanes.
 
 Grid: ``(B, S/block_s)`` — per-batch-row state resets at the first
-S-block (the grid's minor dim iterates fastest).  The ``pos`` scalar
-arrives via scalar prefetch; positions beyond it are masked before the
-online max.  ``decode_attend`` covers h_q == h_kv; GQA decode rides the
-BEAM kernel (``decode_attend_gqa``: the g query groups of a batch row
-share its cache row — exactly the beam row mapping — with the position
-mask in the mask operand).
+S-block (the grid's minor dim iterates fastest).  ``pos`` arrives via
+scalar prefetch as ONE ENTRY PER CACHE ROW (``pos_ref[i]``): the closed
+batch of ``lm_generate`` passes a scalar, which is broadcast, and the
+serving tick passes each slot's own length — one kernel, and what
+decides is the rank of ``pos``.  Positions beyond ``pos[i]`` are masked
+before the online max, and the read is RAGGED: the K/V index maps clamp
+at the row's last live block (``min(pos[i], S - 1) // block_s``; Pallas
+issues no copy when consecutive steps map the same block) and the
+block's body runs under ``pl.when(j * block_s <= pos[i])``, so a block
+wholly above a row's position is neither fetched nor computed (it would
+have contributed exact zeros).  A position at or beyond ``S`` (a free
+serving slot, whose position the engine advances without bound) masks
+nothing and indexes nothing out of range; ``pos`` must be ≥ 0.
+``decode_attend`` covers h_q == h_kv; GQA decode rides the BEAM kernel
+(``decode_attend_gqa``: the g query groups of a batch row share its
+cache row — exactly the beam row mapping — with the same per-row
+position by scalar prefetch, ``masked='pos'``).
 
 Reference relationship: no analog — the reference decoded by re-running
 the full decoder per token (SURVEY.md §2.9 seq2seq).  Parity oracle:
@@ -45,12 +56,13 @@ import functools
 import jax
 
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .._compat import shape_dtype_struct as _sds
 
-__all__ = ["decode_attend", "decode_attend_gqa",
+__all__ = ["decode_attend", "decode_attend_gqa", "live_blocks",
            "beam_attend_parts", "merge_attend_parts"]
 
 _NEG = -1e30
@@ -84,6 +96,38 @@ def _pick_block_s(s: int, want: int = DEFAULT_BLOCK_S) -> int:
     return 0
 
 
+def _row_pos(pos, b: int):
+    """``pos`` — a scalar (every row at the same position) or a ``(B,)``
+    vector (each row at its own) — as the kernels' one ``(B,)`` int32
+    scalar-prefetch operand."""
+    return jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
+
+
+def _live_block_map(s: int, block_s: int):
+    """K/V index map of the ragged read: row ``i``'s S-block ``j``, held
+    at the row's last live block once ``j`` passes it — Pallas issues no
+    copy when consecutive steps map the same block.  The clamp to
+    ``s - 1`` keeps a position beyond the cache inside it."""
+    def index_map(i, j, pos_ref):
+        live = jnp.minimum(pos_ref[i], s - 1) // block_s
+        return i, jnp.minimum(j, live), 0
+    return index_map
+
+
+def live_blocks(pos, s: int, block_s: int = DEFAULT_BLOCK_S):
+    """HOST-side count of the ragged read, ``(read, total)``: the
+    S-blocks of a ``(len(pos), s, D)`` cache at or below each row's
+    position — what one kernel call fetches and computes, by
+    :func:`_live_block_map`'s own arithmetic — and the blocks the cache
+    holds.  ``(0, 0)`` where ``s`` admits no block (the einsum path)."""
+    bs = _pick_block_s(s, block_s)
+    if bs == 0:
+        return 0, 0
+    pos = np.asarray(pos)
+    return (int((np.minimum(pos, s - 1) // bs + 1).sum()),
+            pos.size * (s // bs))
+
+
 def _kernel(pos_ref, q_ref, k_ref, v_ref, seg_ref, segt_ref, o_ref,
             m_ref, l_ref, acc_ref, *, block_s, n_blocks, scale):
     i, j = pl.program_id(0), pl.program_id(1)
@@ -94,45 +138,48 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, seg_ref, segt_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    k = k_ref[0]                                   # (S_b, D)
-    # q/o blocks stay whole-(B, D) resident (a (1, D) block would break
-    # the (8, 128) tiling rule, and Mosaic rejects unaligned dynamic
-    # sublane indexing) — the batch row is selected by iota mask
-    bidx = jax.lax.broadcasted_iota(jnp.int32, q_ref.shape, 0)
-    q = jnp.where(bidx == i, q_ref[...], 0).astype(jnp.float32).sum(
-        axis=0, keepdims=True)                     # (1, D)
-    seg = seg_ref[...]                             # (D, H) 0/1 f32
-    # segmented per-head dot: (K ⊙ q) @ SEG — MXU does the 64-wide sums
-    t = k.astype(jnp.float32) * q                  # (S_b, D)
-    s_blk = jax.lax.dot_general(
-        t, seg, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale  # (S_b, H)
-    idx = j * block_s + jax.lax.broadcasted_iota(
-        jnp.int32, s_blk.shape, 0)
-    s_blk = jnp.where(idx <= pos_ref[0], s_blk, _NEG)
+    @pl.when(j * block_s <= pos_ref[i])
+    def _block():
+        k = k_ref[0]                                   # (S_b, D)
+        # q/o blocks stay whole-(B, D) resident (a (1, D) block would
+        # break the (8, 128) tiling rule, and Mosaic rejects unaligned
+        # dynamic sublane indexing) — the batch row is selected by iota
+        # mask
+        bidx = jax.lax.broadcasted_iota(jnp.int32, q_ref.shape, 0)
+        q = jnp.where(bidx == i, q_ref[...], 0).astype(jnp.float32).sum(
+            axis=0, keepdims=True)                     # (1, D)
+        seg = seg_ref[...]                             # (D, H) 0/1 f32
+        # segmented per-head dot: (K ⊙ q) @ SEG — MXU does the 64-wide sums
+        t = k.astype(jnp.float32) * q                  # (S_b, D)
+        s_blk = jax.lax.dot_general(
+            t, seg, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # (S_b, H)
+        idx = j * block_s + jax.lax.broadcasted_iota(
+            jnp.int32, s_blk.shape, 0)
+        s_blk = jnp.where(idx <= pos_ref[i], s_blk, _NEG)
 
-    m_prev = m_ref[...]                            # (1, H)
-    l_prev = l_ref[...]
-    m_new = jnp.maximum(m_prev, s_blk.max(axis=0, keepdims=True))
-    corr = jnp.exp(m_prev - m_new)                 # (1, H)
-    p = jnp.exp(s_blk - m_new)                     # (S_b, H)
-    m_ref[...] = m_new
-    l_ref[...] = l_prev * corr + p.sum(axis=0, keepdims=True)
-    segt = segt_ref[...]                           # (H, D)
-    p_lanes = jax.lax.dot_general(                 # (S_b, D)
-        p, segt, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    corr_lanes = jax.lax.dot_general(              # (1, D)
-        corr, segt, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    v = v_ref[0].astype(jnp.float32)               # (S_b, D)
-    acc_ref[...] = (acc_ref[...] * corr_lanes
-                    + (p_lanes * v).sum(axis=0, keepdims=True))
+        m_prev = m_ref[...]                            # (1, H)
+        l_prev = l_ref[...]
+        m_new = jnp.maximum(m_prev, s_blk.max(axis=0, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)                 # (1, H)
+        p = jnp.exp(s_blk - m_new)                     # (S_b, H)
+        m_ref[...] = m_new
+        l_ref[...] = l_prev * corr + p.sum(axis=0, keepdims=True)
+        segt = segt_ref[...]                           # (H, D)
+        p_lanes = jax.lax.dot_general(                 # (S_b, D)
+            p, segt, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        corr_lanes = jax.lax.dot_general(              # (1, D)
+            corr, segt, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        v = v_ref[0].astype(jnp.float32)               # (S_b, D)
+        acc_ref[...] = (acc_ref[...] * corr_lanes
+                        + (p_lanes * v).sum(axis=0, keepdims=True))
 
     @pl.when(j == n_blocks - 1)
     def _finish():
         l_lanes = jax.lax.dot_general(
-            l_ref[...], segt, (((1,), (0,)), ((), ())),
+            l_ref[...], segt_ref[...], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         # write row i, preserve the others (the (B, D) block stays VMEM-
         # resident across the whole grid; rows fill in as i advances)
@@ -148,10 +195,12 @@ def decode_attend(q, kc, vc, pos, *, n_heads: int, head_dim: int,
                   block_s: int = DEFAULT_BLOCK_S, interpret: bool = False):
     """One decode tick's attention over the whole cache.
 
-    ``q (B, H·hd)`` flat queries, ``kc/vc (B, S, H·hd)`` flat caches
-    (positions > ``pos`` masked), returns ``ctx (B, H·hd)``.  Requires
-    the q-head count to equal the cache's ``n_heads``; GQA decode goes
-    through :func:`decode_attend_gqa` (the beam kernel).
+    ``q (B, H·hd)`` flat queries, ``kc/vc (B, S, H·hd)`` flat caches,
+    ``pos`` a scalar or a ``(B,)`` int32 vector: row ``b`` attends its
+    cache prefix ``[0, pos[b]]`` and reads only the blocks that hold it
+    (module docstring).  Returns ``ctx (B, H·hd)``.  Requires the q-head
+    count to equal the cache's ``n_heads``; GQA decode goes through
+    :func:`decode_attend_gqa` (the beam kernel).
     """
     b, s, d = kc.shape
     h = n_heads
@@ -163,12 +212,13 @@ def decode_attend(q, kc, vc, pos, *, n_heads: int, head_dim: int,
     scale = 1.0 / (head_dim ** 0.5)
     seg = _seg(d, h)
     vma = _inherit_vma(q, kc, vc)
+    kv_map = _live_block_map(s, bs)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1, grid=(b, n_blocks),
         in_specs=[
             pl.BlockSpec((b, d), lambda i, j, p_: (0, 0)),
-            pl.BlockSpec((1, bs, d), lambda i, j, p_: (i, j, 0)),
-            pl.BlockSpec((1, bs, d), lambda i, j, p_: (i, j, 0)),
+            pl.BlockSpec((1, bs, d), kv_map),
+            pl.BlockSpec((1, bs, d), kv_map),
             pl.BlockSpec((d, h), lambda i, j, p_: (0, 0)),
             pl.BlockSpec((h, d), lambda i, j, p_: (0, 0)),
         ],
@@ -185,7 +235,7 @@ def decode_attend(q, kc, vc, pos, *, n_heads: int, head_dim: int,
         out_shape=_sds((b, d), q.dtype, vma=vma),
         name="decode_attn_mha",
         interpret=interpret,
-    )(jnp.asarray([pos], jnp.int32), q, kc, vc, seg, seg.T)
+    )(_row_pos(pos, b), q, kc, vc, seg, seg.T)
 
 
 def _beam_kernel(pos_ref, q_ref, k_ref, v_ref, seg_ref, segt_ref, mask_ref,
@@ -195,7 +245,9 @@ def _beam_kernel(pos_ref, q_ref, k_ref, v_ref, seg_ref, segt_ref, mask_ref,
     cache segment; per-row online-softmax state; outputs UNNORMALIZED
     (acc, m, l) so two segments (prompt + generated) merge outside with
     the standard flash combine.  ``masked`` selects the ancestry-mask
-    operand (generated segment) vs fully-valid (prompt segment)."""
+    operand (generated segment, ``'amask'``), the per-cache-row position
+    from scalar prefetch with :func:`_kernel`'s ragged read (GQA decode,
+    ``'pos'``) or fully-valid (prompt segment, ``'none'``)."""
     i, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
@@ -204,43 +256,51 @@ def _beam_kernel(pos_ref, q_ref, k_ref, v_ref, seg_ref, segt_ref, mask_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    kb = k_ref[0].astype(jnp.float32)              # (S_b, D)
-    vb = v_ref[0].astype(jnp.float32)
-    seg, segt = seg_ref[...], segt_ref[...]
-    rows = jax.lax.broadcasted_iota(jnp.int32, q_ref.shape, 0)
-    for s in range(beams):
-        q = jnp.where(rows == i * beams + s, q_ref[...], 0).astype(
-            jnp.float32).sum(axis=0, keepdims=True)           # (1, D)
-        s_blk = jax.lax.dot_general(
-            kb * q, seg, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale       # (S_b, H)
-        if masked == "amask":
-            # mask operand is f32: Mosaic only supports non-no-op minor-
-            # dim insertion ([:, None]) on 32-bit types
-            mrow = mask_ref[0, s, :][:, None]                 # (S_b, 1)
-            s_blk = jnp.where(mrow > 0.5, s_blk, _NEG)
-        elif masked == "pos":
-            # position-validity from the prefetch scalar — zero HBM cost
-            # (the GQA path's mask; an f32 operand here would stream
-            # B·g·S·4 bytes per layer per tick)
-            idx = j * block_s + jax.lax.broadcasted_iota(
-                jnp.int32, s_blk.shape, 0)
-            s_blk = jnp.where(idx <= pos_ref[0], s_blk, _NEG)
-        m_prev = m_ref[s:s + 1, :]                            # (1, H)
-        l_prev = l_ref[s:s + 1, :]
-        m_new = jnp.maximum(m_prev, s_blk.max(axis=0, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s_blk - m_new)
-        m_ref[s:s + 1, :] = m_new
-        l_ref[s:s + 1, :] = l_prev * corr + p.sum(axis=0, keepdims=True)
-        p_lanes = jax.lax.dot_general(
-            p, segt, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        corr_lanes = jax.lax.dot_general(
-            corr, segt, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        acc_ref[s:s + 1, :] = (acc_ref[s:s + 1, :] * corr_lanes
-                               + (p_lanes * vb).sum(axis=0, keepdims=True))
+    def _block():
+        kb = k_ref[0].astype(jnp.float32)              # (S_b, D)
+        vb = v_ref[0].astype(jnp.float32)
+        seg, segt = seg_ref[...], segt_ref[...]
+        rows = jax.lax.broadcasted_iota(jnp.int32, q_ref.shape, 0)
+        for s in range(beams):
+            q = jnp.where(rows == i * beams + s, q_ref[...], 0).astype(
+                jnp.float32).sum(axis=0, keepdims=True)           # (1, D)
+            s_blk = jax.lax.dot_general(
+                kb * q, seg, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale       # (S_b, H)
+            if masked == "amask":
+                # mask operand is f32: Mosaic only supports non-no-op minor-
+                # dim insertion ([:, None]) on 32-bit types
+                mrow = mask_ref[0, s, :][:, None]                 # (S_b, 1)
+                s_blk = jnp.where(mrow > 0.5, s_blk, _NEG)
+            elif masked == "pos":
+                # position-validity from the row's prefetch scalar —
+                # zero HBM cost (the GQA path's mask; an f32 operand
+                # here would stream B·g·S·4 bytes per layer per tick)
+                idx = j * block_s + jax.lax.broadcasted_iota(
+                    jnp.int32, s_blk.shape, 0)
+                s_blk = jnp.where(idx <= pos_ref[i], s_blk, _NEG)
+            m_prev = m_ref[s:s + 1, :]                            # (1, H)
+            l_prev = l_ref[s:s + 1, :]
+            m_new = jnp.maximum(m_prev, s_blk.max(axis=0, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s_blk - m_new)
+            m_ref[s:s + 1, :] = m_new
+            l_ref[s:s + 1, :] = l_prev * corr + p.sum(axis=0, keepdims=True)
+            p_lanes = jax.lax.dot_general(
+                p, segt, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            corr_lanes = jax.lax.dot_general(
+                corr, segt, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            acc_ref[s:s + 1, :] = (acc_ref[s:s + 1, :] * corr_lanes
+                                   + (p_lanes * vb).sum(axis=0, keepdims=True))
+
+    if masked == "pos":
+        # the ragged read: a block wholly above the row's position is
+        # not computed (and, by the index map, not fetched)
+        pl.when(j * block_s <= pos_ref[i])(_block)
+    else:
+        _block()
 
     @pl.when(j == n_blocks - 1)
     def _finish():
@@ -269,7 +329,10 @@ def beam_attend_parts(q, kc, vc, amask=None, pos=None, *, beams: int,
     the shared PROMPT cache (pass ``amask=None``: every position valid)
     or the flat per-slot GENERATED caches ``(B, slots·T, D)`` with
     ``amask (B, beams, S_seg)`` (any 0/1 dtype; carried as f32 in the
-    kernel) = ancestry ∧ validity.  Returns
+    kernel) = ancestry ∧ validity.  With ``amask=None`` and ``pos`` (a
+    scalar or a ``(B,)`` int32 vector, ≥ 0) batch row ``b``'s rows see
+    its segment's prefix ``[0, pos[b]]`` and read only the blocks that
+    hold it (GQA decode).  Returns
     ``(acc (B·beams, D) f32 unnormalized, m (B·beams, H) f32,
     l (B·beams, H) f32)``; merge segments with the flash combine
     (see ``merge_attend_parts``).
@@ -302,12 +365,14 @@ def beam_attend_parts(q, kc, vc, amask=None, pos=None, *, beams: int,
     else:
         mask_spec = pl.BlockSpec((1, beams, bs), lambda i, j, p_: (i, 0, j))
     vma = _inherit_vma(q, kc, vc)
+    kv_map = (_live_block_map(s, bs) if masked == "pos"
+              else lambda i, j, p_: (i, j, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1, grid=(b, n_blocks),
         in_specs=[
             pl.BlockSpec((bk, d), lambda i, j, p_: (0, 0)),
-            pl.BlockSpec((1, bs, d), lambda i, j, p_: (i, j, 0)),
-            pl.BlockSpec((1, bs, d), lambda i, j, p_: (i, j, 0)),
+            pl.BlockSpec((1, bs, d), kv_map),
+            pl.BlockSpec((1, bs, d), kv_map),
             pl.BlockSpec((d, h), lambda i, j, p_: (0, 0)),
             pl.BlockSpec((h, d), lambda i, j, p_: (0, 0)),
             mask_spec,
@@ -331,7 +396,7 @@ def beam_attend_parts(q, kc, vc, amask=None, pos=None, *, beams: int,
                    _sds((bk, h), jnp.float32, vma=vma)],
         name="decode_attn_beam",
         interpret=interpret,
-    )(jnp.asarray([0 if pos is None else pos], jnp.int32), q, kc, vc,
+    )(_row_pos(0 if pos is None else pos, b), q, kc, vc,
       seg, seg.T, amask.astype(jnp.float32))
 
 
@@ -378,11 +443,13 @@ def decode_attend_gqa(q, kc, vc, pos, *, n_q_heads: int, n_kv_heads: int,
     Structurally the BEAM problem: the ``g = n_q_heads/n_kv_heads`` query
     groups of batch row ``b`` all attend batch row ``b``'s cache — so the
     beam kernel serves GQA verbatim with ``beams=g`` and the position-
-    validity mask from the prefetch scalar (``masked='pos'``).  The
-    cache still streams ONCE per tick (grid is (B, S-blocks); the g
+    validity mask from the row's prefetch scalar (``masked='pos'``).
+    The cache still streams ONCE per tick (grid is (B, S-blocks); the g
     groups iterate in-register) — GQA's inference payoff is preserved.
 
-    ``q (B, Hq·hd)`` head-major flat; ``kc/vc (B, S, Hkv·hd)``; returns
+    ``q (B, Hq·hd)`` head-major flat; ``kc/vc (B, S, Hkv·hd)``; ``pos``
+    a scalar or a ``(B,)`` int32 vector (one position per cache row,
+    as :func:`decode_attend`); returns
     ``ctx (B, Hq·hd)``.  Group convention matches ``parallel/decode.py``:
     q-head h uses KV head ``h // g`` (head-major reshape to
     ``(Hkv, g, hd)``).

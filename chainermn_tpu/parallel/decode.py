@@ -90,8 +90,11 @@ def _decoder_core(params, head_dim: int, axis_name: str):
         ``write_at``/``q_valid`` may be RANK-1 vectors of length N (the
         serving tick): row ``b`` then writes at ``write_at[b]`` and
         attends its own prefix ``[:q_valid[b] + i + 1)`` — the ragged
-        iteration-level batch, on the einsum path (the flash-decode
-        kernel maps one scalar position per call).
+        iteration-level batch.  On a TPU the one-token tick takes the
+        flash-decode kernel either way (it maps one position per cache
+        row and reads each row's cache up to its own length); the
+        einsum below serves other backends, ``s_q > 1`` chunked fills
+        and totals with no 8-aligned block.
 
         Cache layout is FLAT — ``(B, total, H_kv·head_dim)`` — so every
         cache load streams dense 128-lane rows; per-head structure is
@@ -132,10 +135,12 @@ def _decoder_core(params, head_dim: int, axis_name: str):
             from ..ops.decode_attention import (_pick_block_s,
                                                  decode_attend,
                                                  decode_attend_gqa)
-            if s_q == 1 and not per_row and jax.default_backend() == "tpu" \
+            if s_q == 1 and jax.default_backend() == "tpu" \
                     and _pick_block_s(kc.shape[1]) > 0:
                 # DECODE on TPU: one flash-decode Pallas pass — cache
-                # read once at full lane density (ops/decode_attention).
+                # read once at full lane density (ops/decode_attention),
+                # each row up to its own ``write_at`` (scalar: the
+                # closed batch; vector: the serving tick's slots).
                 # GQA groups ride the beam kernel (g query groups share
                 # one cache row, exactly the beam row mapping).  Odd
                 # totals with no 8-aligned S-block (e.g. a max_new=1
@@ -149,8 +154,9 @@ def _decoder_core(params, head_dim: int, axis_name: str):
                         q.reshape(n, hl * head_dim), kc, vc, write_at,
                         n_q_heads=hl, n_kv_heads=hkv, head_dim=head_dim)
                 return ctx.reshape(n, 1, hl, head_dim), (kc, vc)
-            # Fallback (GQA groups, non-TPU backends): grouped einsum
-            # attention against head-view reshapes of the flat cache.
+            # Fallback (non-TPU backends, chunked fills, unaligned
+            # totals): grouped einsum attention against head-view
+            # reshapes of the flat cache.
             # Per-query valid lengths make one formula serve chunked
             # fills (causal) and decode (full prefix): query i sees
             # q_valid + i + 1 entries.

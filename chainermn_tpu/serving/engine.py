@@ -23,7 +23,10 @@ programs driven from the host, one tick at a time:
   per-row position vector + ``_greedy_token``), caches appended in
   place per row.  Compiled ONCE for the pool's lifetime: admission and
   eviction change only the host-side position/token vectors, never the
-  program.
+  program.  On a TPU its attention is the flash-decode kernel
+  ``lm_generate`` decodes through (``ops/decode_attention.py``), given
+  the position vector: each slot's cache is read once, up to the slot's
+  own length.
 
 Token-exactness vs ``lm_generate`` row-by-row is a test invariant
 (tests/test_serving.py): both paths run the identical per-row ops — the

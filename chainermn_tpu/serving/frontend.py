@@ -66,6 +66,7 @@ import numpy as np
 from .. import observability as obs
 from ..observability import flight as _flight
 from ..observability.slo import GoodputLedger, ReservoirSample, SLOTracker
+from ..ops.decode_attention import live_blocks
 from .cache_pool import CachePool
 from .engine import DecodeEngine
 from .prefix_cache import PrefixCache
@@ -256,6 +257,12 @@ class ServingEngine:
         self._rejected = 0
         self._prefill_tokens_real = 0
         self._prefill_tokens_padded = 0
+        # how much of the pool the tick's attention has to read: cache
+        # blocks at or below each slot's position over the blocks the
+        # pool holds (the flash-decode kernel's ragged read; all layers
+        # alike, so one is counted)
+        self._tick_cache_blocks_read = 0
+        self._tick_cache_blocks_total = 0
         self._t0 = time.monotonic()
         # goodput attribution: step() partitions its own wall clock, and
         # the gap between steps books as queue_wait (work was waiting)
@@ -563,11 +570,14 @@ class ServingEngine:
                 # Locked with reset_stats: a bench warm-up reset racing this
                 # read-modify-write could book one warm-up gap into the
                 # gated window (the unguarded-shared-write lint class)
+                read, total = live_blocks(self.pool.pos, self.pool.max_total)
                 with self._lock:
                     if self._last_tick_start is not None:
                         self._tick_gap_ms.add(
                             (t_tick - self._last_tick_start) * 1e3)
                     self._last_tick_start = t_tick
+                    self._tick_cache_blocks_read += read
+                    self._tick_cache_blocks_total += total
                 tick_bucket = ("compile" if self.engine.tick_calls == 0
                                else "compute")
                 # the tracer's clock is read only for its own Chrome sink
@@ -966,6 +976,8 @@ class ServingEngine:
             self._rejected = 0
             self._prefill_tokens_real = 0
             self._prefill_tokens_padded = 0
+            self._tick_cache_blocks_read = 0
+            self._tick_cache_blocks_total = 0
             self.goodput.reset()
             self._last_step_end = None
             self._slo_last = (0, self._t0)
@@ -1002,6 +1014,12 @@ class ServingEngine:
                     self._prefill_tokens_real),
                 "serving/prefill_tokens_padded": float(
                     self._prefill_tokens_padded),
+                # read over held: the share of the pool's cache blocks
+                # the ticks' attention had to read (ragged-read kernel)
+                "serving/tick_cache_blocks_read": float(
+                    self._tick_cache_blocks_read),
+                "serving/tick_cache_blocks_total": float(
+                    self._tick_cache_blocks_total),
                 "serving/slot_occupancy_pct": 100.0 * (
                     self._occupancy_sum / self._ticks if self._ticks
                     else 0.0),

@@ -122,13 +122,16 @@ def test_fused_cross_entropy_fwd_bwd(one_chip):
     assert n >= 2
 
 
-def test_decode_attend(one_chip):
+@pytest.mark.parametrize("per_row", [False, True], ids=["scalar", "vector"])
+def test_decode_attend(one_chip, per_row):
+    """One position for the batch (``lm_generate``) or one per cache row
+    (the serving tick): the same kernel, ``pos`` broadcast or not."""
     from chainermn_tpu.ops.decode_attention import decode_attend
 
     b, s, h, hd = 8, 2048, 16, 128
     q = _sds((b, h * hd), jnp.bfloat16, one_chip)
     kc = _sds((b, s, h * hd), jnp.bfloat16, one_chip)
-    pos = _sds((), jnp.int32, one_chip)
+    pos = _sds((b,) if per_row else (), jnp.int32, one_chip)
     n = _n_kernels(partial(decode_attend, n_heads=h, head_dim=hd,
                            interpret=False), q, kc, kc, pos,
                    names=("decode_attn_mha",))
@@ -164,12 +167,12 @@ def test_conv3x3_backward(one_chip):
     assert n >= 2
 
 
-def _lm_shapes(mesh, max_len):
+def _lm_shapes(mesh, max_len, n_heads=N_HEADS):
     from chainermn_tpu.parallel import (init_tp_transformer_lm,
                                         transformer_lm_specs)
 
     params = jax.eval_shape(lambda: init_tp_transformer_lm(
-        jax.random.PRNGKey(0), VOCAB, D_MODEL, N_HEADS, N_LAYERS,
+        jax.random.PRNGKey(0), VOCAB, D_MODEL, n_heads, N_LAYERS,
         max_len=max_len, dtype=jnp.bfloat16))
     specs = transformer_lm_specs(params, "model")
 
@@ -220,23 +223,30 @@ def test_lm_135m_train_step_in_shard_map(topo, as_tpu):
             < 16 * 2 ** 30)      # fits one v5e chip's HBM
 
 
-def test_serving_prefill_and_tick(topo, as_tpu):
-    """The ServingEngine's own prefill (prompt 512) and decode tick
-    (4 slots) at the LM width.  DecodeEngine's constructor places params
-    on devices, which a described chip cannot hold — so the program
-    builders run on a bare instance given the same attributes."""
+# (heads, head_dim, slots, prompt, total): the LM width chip_smoke runs,
+# and gpt2-medium's heads in the serving cell's pool (BENCHMARK.json)
+SERVING_SHAPES = {"8x128": (N_HEADS, HEAD_DIM, 4, 512, 512 + 64),
+                  "gpt2-medium": (16, 64, 32, 512, 1024)}
+
+
+@pytest.mark.parametrize("shape", sorted(SERVING_SHAPES))
+def test_serving_prefill_and_tick(topo, as_tpu, shape):
+    """The ServingEngine's own prefill (prompt 512) and decode tick at
+    the LM width.  DecodeEngine's constructor places params on devices,
+    which a described chip cannot hold — so the program builders run on
+    a bare instance given the same attributes."""
     from chainermn_tpu._compat import shard_map
     from chainermn_tpu.serving.engine import DecodeEngine
 
-    n_slots, prompt, total = 4, 512, 512 + 64
+    n_heads, head_dim, n_slots, prompt, total = SERVING_SHAPES[shape]
     mesh = Mesh(np.array(topo.devices[:1]), ("model",))
-    params, specs, shaped = _lm_shapes(mesh, SEQ)
+    params, specs, shaped = _lm_shapes(mesh, SEQ, n_heads)
     kv = P(None, None, "model")
     rep = NamedSharding(mesh, P())
     caches = [(_sds((n_slots, total, D_MODEL), jnp.bfloat16,
                     NamedSharding(mesh, kv)),) * 2 for _ in range(N_LAYERS)]
     eng = DecodeEngine.__new__(DecodeEngine)
-    eng.mesh, eng.axis_name, eng.head_dim = mesh, "model", HEAD_DIM
+    eng.mesh, eng.axis_name, eng.head_dim = mesh, "model", head_dim
     eng._specs, eng._shard_map, eng._P = specs, shard_map, P
     eng._cache_specs = [(kv, kv)] * N_LAYERS
     p = shaped(params, specs)
@@ -256,5 +266,7 @@ def test_serving_prefill_and_tick(topo, as_tpu):
         _sds((n_slots, 2), jnp.uint32, rep),
         _sds((n_slots,), jnp.float32, rep)).compile()
     assert "HloModule jit_serving_tick" in tick.as_text()
-    # (the tick feeds per-row positions, which parallel/decode.py routes
-    # to einsum attention — no kernel is expected in it today)
+    # the tick's per-slot position vector goes to the flash-decode
+    # kernel, one call a layer: a vector Mosaic refused would fail here
+    assert "%decode_attn_mha" in tick.as_text()
+    assert tick.as_text().count("tpu_custom_call") >= N_LAYERS

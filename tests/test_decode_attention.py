@@ -9,7 +9,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chainermn_tpu.ops.decode_attention import decode_attend
+from chainermn_tpu.ops.decode_attention import (decode_attend,
+                                                decode_attend_gqa)
 
 
 def oracle(q, kc, vc, pos, h, hd):
@@ -19,11 +20,30 @@ def oracle(q, kc, vc, pos, h, hd):
     v4 = vc.reshape(b, s, h, hd)
     sc = jnp.einsum("bqhd,bkhd->bhqk", q4, k4,
                     preferred_element_type=jnp.float32) / (hd ** 0.5)
+    # pos: a scalar, or one position per cache row
+    pos = jnp.asarray(pos).reshape(-1, 1, 1, 1)
     sc = jnp.where(jnp.arange(s)[None, None, None, :] <= pos, sc, -1e30)
     p = jax.nn.softmax(sc, -1)
     ctx = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v4.dtype), v4,
                      preferred_element_type=jnp.float32)
     return ctx.reshape(b, d)
+
+
+def oracle_gqa(q, kc, vc, pos, hq, hkv, hd):
+    """parallel/decode.py's grouped-einsum fallback."""
+    b, s, _ = kc.shape
+    q5 = q.reshape(b, 1, hkv, hq // hkv, hd)
+    kc4 = kc.reshape(b, s, hkv, hd)
+    vc4 = vc.reshape(b, s, hkv, hd)
+    sc = jnp.einsum("bqhgd,bkhd->bhgqk", q5, kc4,
+                    preferred_element_type=jnp.float32) / (hd ** 0.5)
+    pos = jnp.asarray(pos).reshape(-1, 1, 1, 1, 1)
+    sc = jnp.where(jnp.arange(s)[None, None, None, None, :] <= pos,
+                   sc, -1e30)
+    p = jax.nn.softmax(sc, axis=-1)
+    ctx = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(vc4.dtype), vc4,
+                     preferred_element_type=jnp.float32)
+    return ctx.reshape(b, hq * hd)
 
 
 @pytest.mark.parametrize("b,s,h,hd,pos", [
@@ -59,6 +79,86 @@ def test_bf16_cache():
     assert got.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want), rtol=2e-2, atol=2e-2)
+
+
+# The serving tick's face: one position per cache row (slot), each row
+# read up to its own length.  S = 128 in blocks of 32; the vectors hold 0,
+# a block's last and first row, S - 1, and values >= S (a free slot, whose
+# position the engine advances without bound).
+ROW_S, ROW_BLOCK = 128, 32
+ROW_POS = {
+    "edges": [0, ROW_BLOCK - 1, ROW_BLOCK, ROW_S - 1, ROW_S, 5 * ROW_S],
+    "mixed": [77, 3, 127, 64, 31, 96],
+    "equal": [70] * 6,
+}
+
+
+def _row_case(dtype, d, seed=4):
+    rs = np.random.RandomState(seed)
+    b = len(ROW_POS["edges"])
+    return (jnp.asarray(rs.randn(b, d), dtype),
+            jnp.asarray(rs.randn(b, ROW_S, d), dtype),
+            jnp.asarray(rs.randn(b, ROW_S, d), dtype))
+
+
+def _row_attend(heads, q, kc, vc, pos):
+    """MHA (``heads = (h,)``) or GQA (``(hq, hkv)``) at head_dim 16."""
+    if len(heads) == 1:
+        return decode_attend(q, kc, vc, pos, n_heads=heads[0], head_dim=16,
+                             block_s=ROW_BLOCK, interpret=True)
+    return decode_attend_gqa(q, kc, vc, pos, n_q_heads=heads[0],
+                             n_kv_heads=heads[1], head_dim=16,
+                             block_s=ROW_BLOCK, interpret=True)
+
+
+def _row_oracle(heads, q, kc, vc, pos):
+    f32 = [a.astype(jnp.float32) for a in (q, kc, vc)]
+    if len(heads) == 1:
+        return oracle(*f32, pos, heads[0], 16)
+    return oracle_gqa(*f32, pos, heads[0], heads[1], 16)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("heads", [(4,), (8, 2)], ids=["mha", "gqa"])
+@pytest.mark.parametrize("case", sorted(ROW_POS))
+def test_per_row_positions_match_einsum_oracle(case, heads, dtype):
+    q, kc, vc = _row_case(dtype, heads[0] * 16)
+    kv = tuple(a[..., :heads[-1] * 16] for a in (kc, vc))
+    pos = jnp.asarray(ROW_POS[case], jnp.int32)
+    got = _row_attend(heads, q, *kv, pos)
+    assert got.dtype == dtype and got.shape == q.shape
+    tol = (dict(rtol=2e-4, atol=2e-5) if dtype == jnp.float32
+           else dict(rtol=2e-2, atol=2e-2))
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(_row_oracle(heads, q, *kv, pos)),
+                               **tol)
+    if case == "equal":
+        # all rows at one position IS the scalar call (lm_generate's
+        # face), bit for bit: same mask, same block walk
+        np.testing.assert_array_equal(
+            np.asarray(got, np.float32),
+            np.asarray(_row_attend(heads, q, *kv, ROW_POS[case][0]),
+                       np.float32))
+
+
+@pytest.mark.parametrize("heads", [(4,), (8, 2)], ids=["mha", "gqa"])
+def test_blocks_above_a_rows_position_are_never_read(heads):
+    """Every block lying WHOLLY above ``pos[b]`` is filled with NaN: the
+    result is unchanged and finite, so a skipped block never enters the
+    sum.  (Rows above ``pos`` inside the last live block are masked by a
+    zero weight, in the kernel and in the einsum alike.)"""
+    q, kc, vc = _row_case(jnp.float32, heads[0] * 16)
+    kv = tuple(a[..., :heads[-1] * 16] for a in (kc, vc))
+    pos = np.asarray(ROW_POS["edges"], np.int32)
+    dead = (np.arange(ROW_S)[None, :] // ROW_BLOCK
+            > np.minimum(pos, ROW_S - 1)[:, None] // ROW_BLOCK)
+    assert dead.any() and not dead[-1].any()
+    poisoned = tuple(jnp.where(dead[:, :, None], jnp.nan, a) for a in kv)
+    want = _row_attend(heads, q, *kv, jnp.asarray(pos))
+    got = _row_attend(heads, q, *poisoned, jnp.asarray(pos))
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_block_must_divide():
@@ -156,25 +256,13 @@ class TestGQADecode:
 
         rs = np.random.RandomState(2)
         b, s, hq, hkv, hd, pos = 2, 64, 8, 2, 16, 40
-        g = hq // hkv
         q = jnp.asarray(rs.randn(b, hq * hd), jnp.float32)
         kc = jnp.asarray(rs.randn(b, s, hkv * hd), jnp.float32)
         vc = jnp.asarray(rs.randn(b, s, hkv * hd), jnp.float32)
         got = decode_attend_gqa(q, kc, vc, pos, n_q_heads=hq,
                                 n_kv_heads=hkv, head_dim=hd, block_s=16,
                                 interpret=True)
-        # the decode.py grouped-einsum fallback as oracle
-        q5 = q.reshape(b, 1, hkv, g, hd)
-        kc4 = kc.reshape(b, s, hkv, hd)
-        vc4 = vc.reshape(b, s, hkv, hd)
-        sc = jnp.einsum("bqhgd,bkhd->bhgqk", q5, kc4,
-                        preferred_element_type=jnp.float32) / (hd ** 0.5)
-        sc = jnp.where(jnp.arange(s)[None, None, None, None, :] <= pos,
-                       sc, -1e30)
-        p = jax.nn.softmax(sc, axis=-1)
-        ctx = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(vc4.dtype), vc4,
-                         preferred_element_type=jnp.float32)
-        want = ctx.reshape(b, hq * hd)
+        want = oracle_gqa(q, kc, vc, pos, hq, hkv, hd)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-4, atol=2e-5)
 

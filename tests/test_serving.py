@@ -398,6 +398,43 @@ def test_prefill_bucket_padding_counts_against_capacity(devices):
     assert h.tokens == _oracle(params, _mesh(devices, 1), prompt, 4).tolist()
 
 
+def test_tick_cache_block_counters(devices):
+    """How much of the pool the tick's attention has to read (the
+    flash-decode kernel's ragged read): blocks at or below each slot's
+    position over the blocks the pool holds, host arithmetic on
+    ``pool.pos`` — summed over ticks, in ``metrics()``, zeroed by
+    ``reset_stats()``."""
+    from chainermn_tpu.ops.decode_attention import DEFAULT_BLOCK_S
+    from chainermn_tpu.serving import ServingEngine
+
+    total = 2 * DEFAULT_BLOCK_S              # two blocks a slot
+    eng = ServingEngine(_params(pos_impl="rope"), head_dim=HEAD_DIM,
+                        n_slots=3, max_total=total, mesh=_mesh(devices, 1),
+                        max_prefills_per_tick=2)
+    m = eng.metrics()
+    assert m["serving/tick_cache_blocks_read"] == 0.0
+    assert m["serving/tick_cache_blocks_total"] == 0.0
+    rng = np.random.RandomState(5)
+    handles = [eng.submit(rng.randint(0, VOCAB, n).astype(np.int32), 4)
+               for n in (4, 9)]
+    # the third slot stays free, its position drifted into the second
+    # block (the tick advances every slot) — and on past the cache
+    eng.pool.pos[2] = total - 2
+    eng.run(steps_budget=20)
+    assert [h.status for h in handles] == ["done", "done"]
+    ticks = eng.engine.tick_calls
+    assert ticks >= 3
+    m = eng.metrics()
+    # slots 0 and 1 live in their first block, the free slot in both
+    assert m["serving/tick_cache_blocks_read"] == 4.0 * ticks
+    assert m["serving/tick_cache_blocks_total"] == 6.0 * ticks
+    eng.reset_stats()
+    m = eng.metrics()
+    assert m["serving/tick_cache_blocks_read"] == 0.0
+    assert m["serving/tick_cache_blocks_total"] == 0.0
+    eng.close()
+
+
 # ---------------------------------------------------------------------------
 # CLI
 # ---------------------------------------------------------------------------
