@@ -43,6 +43,13 @@ nothing and indexes nothing out of range; ``pos`` must be ≥ 0.
 cache row — exactly the beam row mapping — with the same per-row
 position by scalar prefetch, ``masked='pos'``).
 
+``decode_attend_mla`` is the face for a LATENT cache (multi-head latent
+attention in its absorbed form): every query head of a slot attends ONE
+shared ``(S, rank + rope)`` row set whose first ``rank`` columns are also
+the values, so keys and values are the same block, read once, and the
+scores and the weighted sum are two plain MXU matmuls (heads x block).
+Same grid, same ragged read, same one position per cache row.
+
 Reference relationship: no analog — the reference decoded by re-running
 the full decoder per token (SURVEY.md §2.9 seq2seq).  Parity oracle:
 the einsum attend in ``parallel/decode.py`` (``impl='xla'``), tested in
@@ -62,8 +69,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .._compat import shape_dtype_struct as _sds
 
-__all__ = ["decode_attend", "decode_attend_gqa", "live_blocks",
-           "beam_attend_parts", "merge_attend_parts"]
+__all__ = ["decode_attend", "decode_attend_gqa", "decode_attend_mla",
+           "live_blocks", "beam_attend_parts", "merge_attend_parts"]
 
 _NEG = -1e30
 DEFAULT_BLOCK_S = 512  # single source for the kernel AND dispatch gates
@@ -471,3 +478,88 @@ def decode_attend_gqa(q, kc, vc, pos, *, n_q_heads: int, n_kv_heads: int,
                                head_dim=head_dim, dtype=q.dtype)
     return ctx_g.reshape(b, g, n_kv_heads, head_dim) \
         .transpose(0, 2, 1, 3).reshape(b, n_q_heads * head_dim)
+
+
+def _mla_kernel(pos_ref, q_ref, c_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                block_s, n_blocks, scale, rank):
+    """Absorbed latent attention of ONE cache row per grid row: ``q_ref
+    (1, H, rank + rope)`` holds ``[W_UK^T q_nope | RoPE(q_rope)]`` per
+    head, ``c_ref (1, S_b, rank + rope)`` the latent rows ``[c_kv |
+    RoPE(k_rope)]``; the values are the block's first ``rank`` columns."""
+    i, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j * block_s <= pos_ref[i])
+    def _block():
+        c = c_ref[0]                                   # (S_b, R + r)
+        s_blk = jax.lax.dot_general(
+            q_ref[0], c, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # (H, S_b)
+        idx = j * block_s + jax.lax.broadcasted_iota(
+            jnp.int32, s_blk.shape, 1)
+        live = idx <= pos_ref[i]
+        s_blk = jnp.where(live, s_blk, _NEG)
+        m_prev = m_ref[:, :1]                          # (H, 1)
+        m_new = jnp.maximum(m_prev, s_blk.max(-1, keepdims=True))
+        p = jnp.where(live, jnp.exp(s_blk - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_ref[:, :1] * alpha + p.sum(-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(c.dtype), c[:, :rank], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)        # (H, R)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    @pl.when(j == n_blocks - 1)
+    def _finish():
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-37)
+                    ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "scale", "block_s",
+                                             "interpret"))
+def decode_attend_mla(q, cache, pos, *, rank: int, scale: float,
+                      block_s: int = DEFAULT_BLOCK_S,
+                      interpret: bool = False):
+    """One decode tick's absorbed latent attention over the whole cache.
+
+    ``q (B, H, rank + rope)`` per-head absorbed queries ``[W_UK^T q_nope |
+    RoPE(q_rope)]``, ``cache (B, S, rank + rope)`` the latent rows
+    ``[c_kv | RoPE(k_rope)]`` shared by all ``H`` heads, ``pos`` a scalar
+    or a ``(B,)`` int32 vector: row ``b`` attends ``[0, pos[b]]`` and
+    reads only the blocks that hold it (module docstring).  ``scale``
+    multiplies the scores (the model's softmax scale: the kernel knows no
+    head size).  Returns ``o_lat (B, H, rank)``, the softmax-weighted sum
+    of ``c_kv``; the caller applies ``W_UV``."""
+    b, s, width = cache.shape
+    _, h, wq = q.shape
+    assert wq == width and rank <= width, (q.shape, cache.shape, rank)
+    bs = _pick_block_s(s, block_s)
+    if bs == 0:
+        raise ValueError(f"S={s} has no 8-aligned block ≤ {block_s}")
+    n_blocks = s // bs
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(b, n_blocks),
+        in_specs=[
+            pl.BlockSpec((1, h, width), lambda i, j, p_: (i, 0, 0)),
+            pl.BlockSpec((1, bs, width), _live_block_map(s, bs)),
+        ],
+        out_specs=pl.BlockSpec((1, h, rank), lambda i, j, p_: (i, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((h, 128), jnp.float32),
+            pltpu.VMEM((h, 128), jnp.float32),
+            pltpu.VMEM((h, rank), jnp.float32),
+        ])
+    return pl.pallas_call(
+        functools.partial(_mla_kernel, block_s=bs, n_blocks=n_blocks,
+                          scale=scale, rank=rank),
+        grid_spec=grid_spec,
+        out_shape=_sds((b, h, rank), q.dtype, vma=_inherit_vma(q, cache)),
+        name="decode_attn_mla",
+        interpret=interpret,
+    )(_row_pos(pos, b), q, cache)

@@ -1,0 +1,401 @@
+"""The LM block's vocabulary, described once and used by every path.
+
+``parallel/transformer.py`` (the training loss) and ``parallel/decode.py``
+(prefill, the decode tick, the serving engine's programs) used to hard-code
+the same block twice: LayerNorm, ``gelu`` MLP with biases, a head tied to
+the embedding, the embedding times ``sqrt(d)``.  :class:`LMArch` names
+those choices; both modules read them through the helpers below, so a
+GPT-2-style model is this description with its default values and a model
+with RMSNorm, SiLU-gated MLPs, an untied head, multi-head latent attention
+and routed experts is the same code with other values — not a third copy
+of the block.
+
+Parameter layout per ``arch`` value (all GLOBAL arrays):
+
+* ``norm='layernorm'``: ``ln1_scale/ln1_bias/ln2_scale/ln2_bias`` per
+  block, ``lnf_scale/lnf_bias``; ``'rmsnorm'``: the ``*_scale`` only.
+* ``mlp='gelu'``: ``mlp = {wi, bi, wo, bo}`` (``tensor_parallel.tp_mlp``);
+  ``'swiglu'``: ``mlp = {w_gate, w_up, w_down}``, no biases.
+* layer kind ``'moe'``: ``moe = {router (D, E), router_bias (E,), shared =
+  {w_gate, w_up, w_down}, w_gate/w_up (E_held, D, F), w_down (E_held, F,
+  D)}`` — ``parallel/moe.py::moe_dropless``.
+* ``attn='mha'``: ``attn = {wqkv, bqkv, wo, bo}`` or the GQA ``wq/wkv``
+  form; ``'mla'``: ``attn = {wdq (D, q_rank), q_norm, wuq (q_rank,
+  H·(nope+rope)), wdkv (D, kv_rank+rope), kv_norm, wukv (kv_rank,
+  H·(nope+v)), wo (H·v, D)}``, head-major columns.
+* ``tied_head=False``: ``params['head'] (V, D)`` beside ``params['embed']``.
+
+What a layer's attention keeps per token is DECLARED here
+(:func:`cache_layout`) and the serving pool allocates exactly that: an
+MHA/GQA layer a ``(k, v)`` pair of ``n_kv·head_dim`` columns sharded over
+the model axis, an MLA layer ONE latent buffer ``[c_kv | RoPE(k_rope) |
+zero pad]``, replicated.
+"""
+
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+_LANES = 128
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention: the numbers the shapes do not give."""
+    n_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float = 10000.0
+    #: YaRN: ``(factor, original_max_position, beta_fast, beta_slow,
+    #: mscale, mscale_all_dim)`` or None for plain rotary
+    yarn: Optional[Tuple[float, int, float, float, float, float]] = None
+
+    @property
+    def latent_width(self) -> int:
+        """Columns of a cached row: ``kv_lora_rank + qk_rope_head_dim``,
+        rounded up to whole 128-lane tiles.  On the v5e a 576-wide bf16
+        buffer is not in the layout the flash-decode kernel takes and XLA
+        copies the whole cache in front of every call (my ahead-of-time
+        compile, PR 27); 640 columns pass through untouched."""
+        w = self.kv_lora_rank + self.qk_rope_head_dim
+        return -(-w // _LANES) * _LANES
+
+    @property
+    def softmax_scale(self) -> float:
+        s = (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        if self.yarn is not None:
+            factor, _, _, _, _, mscale_all_dim = self.yarn
+            m = _yarn_mscale(factor, mscale_all_dim)
+            s = s * m * m
+        return s
+
+
+@dataclass(frozen=True)
+class MoEConfig:
+    """Routed experts: sigmoid scores, group-limited top-k, this chip's
+    share ``held = (first, n)`` of the ``n_experts`` the router scores."""
+    n_experts: int
+    top_k: int
+    n_group: int
+    topk_group: int
+    routed_scaling_factor: float
+    norm_topk_prob: bool = True
+    held: Tuple[int, int] = (0, 0)
+
+
+@dataclass(frozen=True)
+class LMArch:
+    """One LM's block vocabulary.  The defaults ARE the GPT-2-style block
+    this package has always run."""
+    norm: str = "layernorm"            # | 'rmsnorm'
+    norm_eps: float = 1e-5
+    mlp: str = "gelu"                  # | 'swiglu'
+    attn: str = "mha"                  # | 'mla'   (mha covers GQA)
+    layer_kinds: Optional[Tuple[str, ...]] = None   # 'dense' | 'moe' each
+    tied_head: bool = True
+    embed_scale: bool = True           # embedding times sqrt(d_model)
+    mla: Optional[MLAConfig] = None
+    moe: Optional[MoEConfig] = None
+
+    def kind(self, layer: int) -> str:
+        return "dense" if self.layer_kinds is None else \
+            self.layer_kinds[layer]
+
+
+#: the description of every model that passes none
+DEFAULT_ARCH = LMArch()
+
+
+def resolve(arch: Optional[LMArch]) -> LMArch:
+    return DEFAULT_ARCH if arch is None else arch
+
+
+# --------------------------------------------------------------------------
+# norms, MLPs, embedding, head
+# --------------------------------------------------------------------------
+
+def rms_norm(x, scale, eps):
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt((x32 * x32).mean(-1, keepdims=True) + eps)
+    return (y * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def norm(arch: LMArch, x, p, name: str):
+    """``name`` in ``ln1 | ln2 | lnf``: the block's (or model's) norm."""
+    if arch.norm == "rmsnorm":
+        return rms_norm(x, p[name + "_scale"], arch.norm_eps)
+    from .transformer import _layer_norm
+    return _layer_norm(x, p[name + "_scale"], p[name + "_bias"])
+
+
+def swiglu(h, p):
+    """``W_down(silu(W_gate h) * W_up h)``, no biases."""
+    g = jnp.matmul(h, p["w_gate"], preferred_element_type=jnp.float32)
+    u = jnp.matmul(h, p["w_up"], preferred_element_type=jnp.float32)
+    a = (jax.nn.silu(g) * u).astype(h.dtype)
+    return jnp.matmul(a, p["w_down"],
+                      preferred_element_type=jnp.float32).astype(h.dtype)
+
+
+def ffn(arch: LMArch, layer: int, h, blk, axis_name: str, live=None):
+    """The block's second half on normed ``h (..., D)``: ``(y, routing)``;
+    ``routing`` is None for a dense layer and, for an expert layer,
+    ``(counts, idx)``: its int32 routing-count vector
+    (``moe.COUNT_FIELDS`` then one entry per held expert) and the experts
+    each token chose, ``(..., top_k)``.  ``live (...) bool`` names the
+    rows that carry a token (None: all); the others go to no expert."""
+    if arch.kind(layer) == "moe":
+        from .moe import moe_dropless
+        shape = h.shape
+        y, counts, idx = moe_dropless(
+            h.reshape(-1, shape[-1]), blk["moe"], arch.moe,
+            live=None if live is None else live.reshape(-1))
+        return y.reshape(shape), (counts, idx.reshape(shape[:-1] + (-1,)))
+    if arch.mlp == "swiglu":
+        return swiglu(h, blk["mlp"]), None
+    from .tensor_parallel import tp_mlp
+    return tp_mlp(h, blk["mlp"], axis_name=axis_name), None
+
+
+def scale_embedding(arch: LMArch, x, d_model: int):
+    return x * (d_model ** 0.5) if arch.embed_scale else x
+
+
+def head_table(arch: LMArch, params):
+    """The ``(V, D)`` table the logits are taken against."""
+    return params["embed"] if arch.tied_head else params["head"]
+
+
+def n_count_entries(arch: LMArch) -> int:
+    """Length of the routing-count vector a program of this model returns
+    beside its tokens (0: none)."""
+    if arch.moe is None:
+        return 0
+    from .moe import COUNT_FIELDS
+    return len(COUNT_FIELDS) + arch.moe.held[1]
+
+
+def route_shape(arch: LMArch) -> Tuple[int, int]:
+    """``(expert layers, top_k)``: the chosen experts a program of this
+    model returns for each token it emits; ``(0, 0)`` without experts."""
+    if arch.moe is None:
+        return 0, 0
+    return sum(k == "moe" for k in arch.layer_kinds or ()), arch.moe.top_k
+
+
+# --------------------------------------------------------------------------
+# rotary positions with YaRN
+# --------------------------------------------------------------------------
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_inv_freq(dim: int, theta: float, yarn=None):
+    """``(dim/2,)`` float32 inverse frequencies and the cos/sin scale.
+    YaRN (Peng et al. 2023, as DeepSeek-V3's modelling code applies it):
+    blend ``θ_i`` and ``θ_i / factor`` with a linear ramp between the
+    dimensions that turn ``beta_fast`` and ``beta_slow`` times over the
+    original context."""
+    exponent = jnp.arange(0, dim, 2, dtype=jnp.float32) / dim
+    extra = 1.0 / (theta ** exponent)
+    if yarn is None:
+        return extra, 1.0
+    factor, orig, beta_fast, beta_slow, mscale, mscale_all_dim = yarn
+    inter = extra / factor
+
+    def correction_dim(rotations):
+        return dim * math.log(orig / (rotations * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    span = (high - low) if high != low else 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low) / span,
+                    0.0, 1.0)
+    keep = 1.0 - ramp                    # 1: keep θ_i, 0: take θ_i / factor
+    return (inter * (1.0 - keep) + extra * keep,
+            _yarn_mscale(factor, mscale) / _yarn_mscale(factor,
+                                                        mscale_all_dim))
+
+
+def apply_rope_freqs(x, positions, inv_freq, scale: float = 1.0):
+    """Rotate ``x (B, S, H, dim)`` at ``positions`` — ``(S,)`` shared or
+    ``(B, S)`` per row — with given inverse frequencies; HALF-SPLIT pair
+    layout (pair ``i`` is columns ``i`` and ``i + dim/2``), as
+    ``transformer.apply_rope``."""
+    half = x.shape[-1] // 2
+    ang = positions.astype(jnp.float32)[..., None] * inv_freq
+    cos, sin = jnp.cos(ang) * scale, jnp.sin(ang) * scale
+    if positions.ndim == 2:
+        cos, sin = cos[:, :, None, :], sin[:, :, None, :]
+    else:
+        cos, sin = cos[None, :, None, :], sin[None, :, None, :]
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                           -1).astype(x.dtype)
+
+
+# --------------------------------------------------------------------------
+# multi-head latent attention
+# --------------------------------------------------------------------------
+
+def _dense(h, w):
+    return jnp.matmul(h, w, preferred_element_type=jnp.float32
+                      ).astype(h.dtype)
+
+
+def mla_project(cfg: MLAConfig, h, a, positions, eps: float):
+    """The projections both forms share, from normed ``h (B, S, D)``:
+    ``q_nope (B, S, H, nope)``, ``q_rope (B, S, H, rope)`` rotated, and
+    the token's cache row parts ``c_kv (B, S, rank)`` normed, ``k_rope
+    (B, S, rope)`` rotated (one key for all heads)."""
+    b, s, _ = h.shape
+    nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    inv_freq, cs = rope_inv_freq(rope, cfg.rope_theta, cfg.yarn)
+    c_q = rms_norm(_dense(h, a["wdq"]), a["q_norm"], eps)
+    q = _dense(c_q, a["wuq"]).reshape(b, s, cfg.n_heads, nope + rope)
+    q_rope = apply_rope_freqs(q[..., nope:], positions, inv_freq, cs)
+    ckv = _dense(h, a["wdkv"])
+    c_kv = rms_norm(ckv[..., :cfg.kv_lora_rank], a["kv_norm"], eps)
+    k_rope = apply_rope_freqs(ckv[..., cfg.kv_lora_rank:][:, :, None, :],
+                              positions, inv_freq, cs)[:, :, 0, :]
+    return q[..., :nope], q_rope, c_kv, k_rope
+
+
+def mla_latent_rows(cfg: MLAConfig, c_kv, k_rope):
+    """``(B, S, latent_width)`` cache rows ``[c_kv | k_rope | 0 pad]``."""
+    pad = cfg.latent_width - cfg.kv_lora_rank - cfg.qk_rope_head_dim
+    rows = jnp.concatenate([c_kv, k_rope], -1)
+    return jnp.pad(rows, ((0, 0), (0, 0), (0, pad))) if pad else rows
+
+
+def _wukv(cfg: MLAConfig, a):
+    """``W_UKV`` as ``(rank, H, nope + v)``: per head ``[W_UK | W_UV]``."""
+    return a["wukv"].reshape(cfg.kv_lora_rank, cfg.n_heads,
+                             cfg.qk_nope_head_dim + cfg.v_head_dim)
+
+
+def mla_attend_prefill(cfg: MLAConfig, q_nope, q_rope, c_kv, k_rope, a,
+                       attn_impl: str):
+    """PREFILL form: per-head keys and values from the latent, causal
+    attention with ``nope + rope``-wide queries/keys and ``v``-wide
+    values.  Returns ``ctx (B, S, H·v)``.
+
+    The flash kernel takes one width for q, k and v, so v is zero-padded
+    from ``v_head_dim`` to the q/k width inside this call and the pad cut
+    off after it: the P·V matmul does ``(nope + rope) / v`` times the
+    work it needs (1.5 x at 192 / 128), the Q·K^T matmul none extra.  The
+    kernel divides by ``sqrt(width)``; the model's softmax scale is put
+    on q."""
+    b, s, h, nope = q_nope.shape
+    kv = jnp.einsum("bsr,rhd->bshd", c_kv, _wukv(cfg, a),
+                    preferred_element_type=jnp.float32).astype(c_kv.dtype)
+    k = jnp.concatenate(
+        [kv[..., :nope],
+         jnp.broadcast_to(k_rope[:, :, None, :], (b, s, h, k_rope.shape[-1]))],
+        -1)
+    v = kv[..., nope:]
+    q = jnp.concatenate([q_nope, q_rope], -1)
+    width = q.shape[-1]
+    if attn_impl == "flash":
+        from ..ops.flash_attention import flash_attention
+        qs = (q.astype(jnp.float32)
+              * (cfg.softmax_scale * width ** 0.5)).astype(q.dtype)
+        vp = jnp.pad(v, ((0, 0),) * 3 + ((0, width - v.shape[-1]),))
+        ctx = flash_attention(qs, k, vp, causal=True)[..., :v.shape[-1]]
+    else:
+        sc = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                        preferred_element_type=jnp.float32)
+        sc = sc * cfg.softmax_scale
+        mask = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
+        p = jax.nn.softmax(jnp.where(mask[None, None], sc, -1e30), axis=-1)
+        ctx = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
+                         preferred_element_type=jnp.float32).astype(q.dtype)
+    return ctx.reshape(b, s, h * cfg.v_head_dim)
+
+
+def mla_attend_absorbed(cfg: MLAConfig, q_nope, q_rope, cache, valid, a,
+                        use_kernel: bool):
+    """DECODE form over the latent ``cache (B, total, latent_width)``:
+    ``q_lat = W_UK^T q_nope``, scores against the shared rows, the
+    weighted sum of ``c_kv``, then ``W_UV``.  ``valid (B, S_q)`` int32:
+    query ``i`` of row ``b`` sees cache rows ``[0, valid[b, i])``.
+    Returns ``ctx (B, S_q, H·v)``.  ``use_kernel``: the flash-decode
+    kernel (one query per row); else an einsum."""
+    b, s_q, h, nope = q_nope.shape
+    rank = cfg.kv_lora_rank
+    w = _wukv(cfg, a)
+    q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w[..., :nope],
+                       preferred_element_type=jnp.float32
+                       ).astype(q_nope.dtype)
+    pad = cache.shape[-1] - rank - q_rope.shape[-1]
+    q_abs = jnp.concatenate([q_lat, q_rope], -1)
+    if pad:
+        q_abs = jnp.pad(q_abs, ((0, 0),) * 3 + ((0, pad),))
+    if use_kernel:
+        from ..ops.decode_attention import decode_attend_mla
+        o_lat = decode_attend_mla(q_abs[:, 0], cache, valid[:, 0] - 1,
+                                  rank=rank, scale=cfg.softmax_scale
+                                  )[:, None]
+    else:
+        sc = jnp.einsum("bshw,bkw->bhsk", q_abs, cache,
+                        preferred_element_type=jnp.float32)
+        sc = sc * cfg.softmax_scale
+        mask = (jnp.arange(cache.shape[1])[None, None, None, :]
+                < valid[:, None, :, None])
+        p = jax.nn.softmax(jnp.where(mask, sc, -1e30), axis=-1)
+        # float32 operands: the CPU backend has no bf16 x bf16 -> f32 dot
+        # of this shape, and this path is the one other backends take
+        o_lat = jnp.einsum("bhsk,bkr->bshr", p,
+                           cache[..., :rank].astype(jnp.float32)
+                           ).astype(q_nope.dtype)
+    ctx = jnp.einsum("bshr,rhd->bshd", o_lat, w[..., nope:],
+                     preferred_element_type=jnp.float32
+                     ).astype(q_nope.dtype)
+    return ctx.reshape(b, s_q, h * cfg.v_head_dim)
+
+
+# --------------------------------------------------------------------------
+# what a layer keeps per token, and how a model's parameters are sharded
+# --------------------------------------------------------------------------
+
+def cache_layout(arch: LMArch, n_layers: int, kv_dim: int,
+                 axis_name: str):
+    """Per layer, the buffers its attention keeps for each token: a tuple
+    of ``(columns, PartitionSpec)`` — the serving pool allocates one
+    ``(n_slots, max_total, columns)`` buffer for each."""
+    if arch.attn == "mla":
+        one = ((arch.mla.latent_width, P()),)
+    else:
+        spec = P(None, None, axis_name)
+        one = ((kv_dim, spec), (kv_dim, spec))
+    return [one] * n_layers
+
+
+def lm_specs(arch: LMArch, params, axis_name: str):
+    """PartitionSpecs of a model's parameters over the model axis.  The
+    default description is ``transformer_lm_specs``' Megatron layout; an
+    MLA / expert model's attention, router and shared expert are whole on
+    every chip (data-parallel, as DeepSeek's own serving runs MLA), so the
+    blocks are replicated; the embedding and the head stay vocab-sharded,
+    which is what the embedding lookup and the token pick assume."""
+    if arch.attn == "mha" and arch.moe is None and arch.mlp == "gelu" \
+            and arch.norm == "layernorm" and arch.tied_head:
+        from .transformer import transformer_lm_specs
+        return transformer_lm_specs(params, axis_name)
+    specs = jax.tree_util.tree_map(lambda _: P(), params)
+    for table in ("embed", "head"):
+        if table in specs:
+            specs[table] = P(axis_name, None)
+    return specs

@@ -30,11 +30,13 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .._compat import pcast_varying
+from . import blocks as _blocks
 from .tensor_parallel import row_parallel_dense
 from .transformer import _layer_norm, _project_qkv, apply_rope
 
 
-def _decoder_core(params, head_dim: int, axis_name: str):
+def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
+                  live=None):
     """Shared incremental-decoding machinery:
     ``(embed, attn_block, block_with, rope)``.
 
@@ -42,9 +44,21 @@ def _decoder_core(params, head_dim: int, axis_name: str):
     greedy path (batch B) and beam search (batch B·K); ``block_with`` is
     the underlying scaffolding with a pluggable attend stage (the lazy
     beam swaps in its ancestry-masked attention there).
+
+    ``arch`` (a ``blocks.LMArch``; None = the GPT-2-style default) names
+    the block's vocabulary — norm, MLP, attention and layer kinds, head,
+    embedding scale — read here once and shared with the training loss
+    (``parallel/transformer.py``).  A layer's cache is a TUPLE of buffers,
+    whatever its attention declares (``blocks.cache_layout``): ``(k, v)``
+    for MHA/GQA, one latent buffer for MLA.  ``attn_block.moe_routing``
+    collects the expert layers' ``(counts, idx)`` in trace order; ``live
+    (N, S_q) bool`` names the rows that carry a token (None: all) — the
+    expert layers send the others to no expert.
     """
+    arch = _blocks.resolve(arch)
     d_model = params["embed"].shape[1]
     rope = "pos_embed" not in params
+    moe_routing = []
 
     def embed(tokens, positions):
         from .tensor_parallel import vocab_parallel_embedding
@@ -53,7 +67,7 @@ def _decoder_core(params, head_dim: int, axis_name: str):
         # would index local rows with global ids.
         x = vocab_parallel_embedding(tokens, params["embed"],
                                      axis_name=axis_name)
-        x = x * (d_model ** 0.5)
+        x = _blocks.scale_embedding(arch, x, d_model)
         if not rope:
             pe = jnp.take(params["pos_embed"], positions, axis=0)
             # (S,) positions broadcast over the batch; (N, S) positions
@@ -62,14 +76,23 @@ def _decoder_core(params, head_dim: int, axis_name: str):
             x = x + (pe if positions.ndim == 2 else pe[None])
         return x
 
-    def block_with(x, blk, positions, attend):
+    def second_half(x, blk, layer):
+        """residual stream after attention → norm → the layer's FFN (dense
+        MLP or experts, by ``arch``) → residual."""
+        h = _blocks.norm(arch, x, blk, "ln2")
+        y, routing = _blocks.ffn(arch, layer, h, blk, axis_name, live)
+        if routing is not None:
+            moe_routing.append(routing)
+        return x + y
+
+    def block_with(x, blk, positions, attend, layer: int = 0):
         """Shared block scaffolding: ln1 → qkv projection (+rope) →
         pluggable ``attend(q, k, v) -> (ctx, extras)`` → wo row-parallel →
-        residual → ln2 → tp_mlp.  ONE copy of the model structure serves
-        the physical-cache path and the lazy-beam path; only the
+        residual → ln2 → the layer's FFN.  ONE copy of the model structure
+        serves the physical-cache path and the lazy-beam path; only the
         score/context stage differs."""
         n, s_q = x.shape[0], x.shape[1]
-        h = _layer_norm(x, blk["ln1_scale"], blk["ln1_bias"])
+        h = _blocks.norm(arch, x, blk, "ln1")
         q, k, v = _project_qkv(h, blk["attn"], head_dim, axis_name)
         if rope:
             q = apply_rope(q, positions)
@@ -78,14 +101,53 @@ def _decoder_core(params, head_dim: int, axis_name: str):
         ctx = ctx.reshape(n, s_q, -1)
         attn_out = row_parallel_dense(ctx, blk["attn"]["wo"],
                                       blk["attn"]["bo"], axis_name=axis_name)
-        x = x + attn_out
-        h = _layer_norm(x, blk["ln2_scale"], blk["ln2_bias"])
-        from .tensor_parallel import tp_mlp
-        return (x + tp_mlp(h, blk["mlp"], axis_name=axis_name),) + extras
+        return (second_half(x + attn_out, blk, layer),) + extras
 
-    def attn_block(x, blk, k_cache, v_cache, positions, write_at, q_valid):
+    def mla_block(x, blk, cache, positions, write_at, q_valid, layer):
+        """The MLA layer: the token's latent row is written to ``cache``
+        (one buffer), a prefill attends in the prefill form through the
+        flash kernel, everything else in the absorbed form over the
+        latent rows — on a TPU the one-token tick through the
+        flash-decode kernel, one position per cache row."""
+        from ..ops.decode_attention import _pick_block_s
+        from ..ops.flash_attention import resolve_attn_impl
+
+        cfg = arch.mla
+        n, s_q = x.shape[0], x.shape[1]
+        with jax.named_scope("block/mla"):
+            h = _blocks.norm(arch, x, blk, "ln1")
+            q_nope, q_rope, c_kv, k_rope = _blocks.mla_project(
+                cfg, h, blk["attn"], positions, arch.norm_eps)
+            rows = _blocks.mla_latent_rows(cfg, c_kv, k_rope)
+            with jax.named_scope("cache_write"):
+                cache = _write_rows(cache, rows.astype(cache.dtype),
+                                    write_at)
+            if s_q > 1 and isinstance(write_at, int) and write_at == 0 \
+                    and isinstance(q_valid, int) and q_valid == 0:
+                ctx = _blocks.mla_attend_prefill(
+                    cfg, q_nope, q_rope, c_kv, k_rope, blk["attn"],
+                    resolve_attn_impl("auto", s_q))
+            else:
+                valid = (jnp.asarray(q_valid, jnp.int32).reshape(-1, 1)
+                         + jnp.arange(s_q, dtype=jnp.int32)[None] + 1)
+                valid = jnp.broadcast_to(valid, (n, s_q))
+                use_kernel = (s_q == 1 and jax.default_backend() == "tpu"
+                              and _pick_block_s(cache.shape[1]) > 0)
+                ctx = _blocks.mla_attend_absorbed(
+                    cfg, q_nope, q_rope, cache, valid, blk["attn"],
+                    use_kernel)
+            attn_out = jnp.matmul(
+                ctx, blk["attn"]["wo"],
+                preferred_element_type=jnp.float32).astype(x.dtype)
+        return second_half(x + attn_out, blk, layer), cache
+
+    def attn_block(x, blk, k_cache, v_cache, positions, write_at, q_valid,
+                   layer: int = 0):
         """x (N,S,D) → block output; caches written at ``write_at + i`` for
         the i-th input position; query i attends cache [:q_valid + i + 1).
+
+        An MLA layer (``arch.attn == 'mla'``) keeps ONE buffer: pass it as
+        ``k_cache`` and None as ``v_cache``; the result is ``(x, cache)``.
 
         ``write_at``/``q_valid`` may be RANK-1 vectors of length N (the
         serving tick): row ``b`` then writes at ``write_at[b]`` and
@@ -105,6 +167,9 @@ def _decoder_core(params, head_dim: int, axis_name: str):
         VPU multiply+reduce fusions over half-empty 64-lane vregs
         (scripts/profile_decode.py + the round-5 HLO dump).
         """
+        if arch.attn == "mla":
+            return mla_block(x, blk, k_cache, positions, write_at, q_valid,
+                             layer)
         n = x.shape[0]
         per_row = getattr(write_at, "ndim", 0) == 1
 
@@ -187,9 +252,43 @@ def _decoder_core(params, head_dim: int, axis_name: str):
                              ).astype(x.dtype)
             return ctx, (kc, vc)
 
-        return block_with(x, blk, positions, attend)
+        return block_with(x, blk, positions, attend, layer)
 
+    attn_block.moe_routing = moe_routing
+    attn_block.arch = arch
     return embed, attn_block, block_with, rope
+
+
+def _write_rows(cache, rows, write_at):
+    """``rows (N, S_q, W)`` into ``cache (N, total, W)`` at ``write_at``:
+    an int / scalar (every row at the same offset) or an ``(N,)`` vector
+    (the serving tick: row ``b`` at ``write_at[b]``, clamped inside the
+    buffer as ``dynamic_update_slice`` clamps)."""
+    if getattr(write_at, "ndim", 0) == 1:
+        return jax.vmap(lambda c, r, p: jax.lax.dynamic_update_slice(
+            c, r, (p, 0)))(cache, rows, write_at)
+    return jax.lax.dynamic_update_slice(cache, rows, (0, write_at, 0))
+
+
+def _run_layer(attn_block, x, blk, bufs, positions, write_at, q_valid,
+               layer: int):
+    """One block over the layer's cache tuple ``bufs`` — ``(k, v)`` or one
+    latent buffer — returning ``(x, new cache tuple)``."""
+    x, *new = attn_block(x, blk, bufs[0], bufs[1] if len(bufs) > 1 else None,
+                         positions, write_at, q_valid, layer)
+    return x, tuple(new)
+
+
+def _routing(attn_block):
+    """The expert layers' routing of one traced forward: ``(counts,
+    routes)`` — the int32 count vectors summed over layers, and the
+    chosen experts ``(N, S_q, expert layers, top_k)``; None for a model
+    without experts."""
+    if not attn_block.moe_routing:
+        return None
+    counts = [c for c, _ in attn_block.moe_routing]
+    return (sum(counts[1:], counts[0]),
+            jnp.stack([idx for _, idx in attn_block.moe_routing], axis=2))
 
 
 def _check_length(params, total: int, rope: bool) -> None:
@@ -202,26 +301,30 @@ def _check_length(params, total: int, rope: bool) -> None:
 
 def _kv_heads(params, head_dim: int) -> int:
     a = params["blocks"][0]["attn"]
+    if "wdkv" in a:      # MLA: one shared latent row, no per-head K/V
+        return 0
     return (a["wkv"].shape[1] // (2 * head_dim) if "wkv" in a
             else a["bqkv"].shape[0] // (3 * head_dim))
 
 
 def _prefill(params, embed, attn_block, prompt, total: int, head_dim: int):
     """Run the full prompt through the stack, returning ``(h_final,
-    caches)`` with per-layer KV caches of length ``total`` (prompt written,
-    tail zeros) in the flat ``(B, total, H_kv·head_dim)`` layout (see
-    ``attn_block``)."""
+    caches)`` with per-layer caches of length ``total`` (prompt written,
+    tail zeros): per layer the tuple of flat ``(B, total, columns)``
+    buffers its attention declares (``blocks.cache_layout``; ``(k, v)`` of
+    ``H_kv·head_dim`` columns for MHA/GQA — see ``attn_block``)."""
+    arch = attn_block.arch
     b, s_p = prompt.shape
-    n_kv = _kv_heads(params, head_dim)
+    layout = _blocks.cache_layout(arch, len(params["blocks"]),
+                                  _kv_heads(params, head_dim) * head_dim, "")
     positions = jnp.arange(s_p)
     x = embed(prompt, positions)
     caches = []
-    for blk in params["blocks"]:
-        k0 = jnp.zeros((b, total, n_kv * head_dim), x.dtype)
-        v0 = jnp.zeros((b, total, n_kv * head_dim), x.dtype)
-        x, kc, vc = attn_block(x, blk, k0, v0, positions, 0, 0)
-        caches.append((kc, vc))
-    return _layer_norm(x, params["lnf_scale"], params["lnf_bias"]), caches
+    for i, (blk, bufs) in enumerate(zip(params["blocks"], layout)):
+        zeros = [jnp.zeros((b, total, w), x.dtype) for w, _ in bufs]
+        x, new = _run_layer(attn_block, x, blk, zeros, positions, 0, 0, i)
+        caches.append(new)
+    return _blocks.norm(arch, x, params, "lnf"), caches
 
 
 def _greedy_token(table, h_last, axis_name: str):
@@ -311,30 +414,41 @@ def _next_token(table, h_last, axis_name, keys, temps, step_pos):
         jnp.where(winner, local_idx, jnp.int32(2 ** 30)), axis_name)
 
 
-def lm_prefill(params, prompt, total: int, *, head_dim: int, axis_name: str):
+def lm_prefill(params, prompt, total: int, *, head_dim: int, axis_name: str,
+               arch=None, live=None, with_routing: bool = False):
     """Iteration-level PREFILL step: run the full ``prompt (B, S_p)``
     through the stack, returning ``(h, caches)`` — ``h (B, S_p, D)`` is
     the post-final-layer-norm hidden state (greedy-select the first
     generated token from ``h[:, s_real - 1]``), and ``caches`` is the
-    per-layer list of flat ``(B, total, H_kv·head_dim)`` K/V pairs with
+    per-layer list of cache tuples (``(k, v)`` flat ``(B, total,
+    H_kv·head_dim)`` pairs for MHA/GQA, one latent buffer for MLA) with
     the prompt written at rows ``[0, S_p)``.
 
     Call INSIDE ``shard_map`` with the model axis bound.  This is the
     "prefill(prompt) → slot" half of the serving engine's per-tick API
     (``chainermn_tpu/serving/engine.py``): the caches slot straight into
     a pool row, and generation continues via :func:`lm_decode_tick` —
-    no closed ``lax.scan`` batch required.
+    no closed ``lax.scan`` batch required.  ``arch``: the model's
+    ``blocks.LMArch`` (None = the GPT-2-style default).  ``live (B, S_p)
+    bool``: the positions that carry a token (None: all; a padded prompt's
+    padding goes to no expert).  ``with_routing=True`` appends the expert
+    layers' ``(counts, routes)`` — the summed int32 routing counts and the
+    chosen experts ``(B, S_p, expert layers, top_k)`` — to the result
+    (None without experts).
     """
-    embed, attn_block, _, rope = _decoder_core(params, head_dim, axis_name)
+    embed, attn_block, _, rope = _decoder_core(params, head_dim, axis_name,
+                                               arch, live)
     _check_length(params, total, rope)
-    return _prefill(params, embed, attn_block, prompt, total, head_dim)
+    out = _prefill(params, embed, attn_block, prompt, total, head_dim)
+    return out + (_routing(attn_block),) if with_routing else out
 
 
 def lm_decode_tick(params, tokens, caches, pos, *, head_dim: int,
-                   axis_name: str):
+                   axis_name: str, arch=None, live=None,
+                   with_routing: bool = False):
     """ONE iteration-level decode tick: consume ``tokens (N,)`` (the last
-    emitted token per row), write each row's K/V at ``pos`` and attend
-    its own cache prefix ``[0, pos]``, returning ``(h_last (N, D),
+    emitted token per row), write each row's cache entry at ``pos`` and
+    attend its own cache prefix ``[0, pos]``, returning ``(h_last (N, D),
     new_caches)`` — feed ``h_last`` to :func:`_greedy_token` (or a
     sampler) for the next token.
 
@@ -342,22 +456,30 @@ def lm_decode_tick(params, tokens, caches, pos, *, head_dim: int,
     ``lm_generate`` batch) or an ``(N,)`` int32 vector (every row at its
     OWN position — the serving engine's slot pool, where sequences are
     inserted and evicted between ticks).  Call INSIDE ``shard_map`` with
-    the model axis bound.
+    the model axis bound.  ``arch`` / ``with_routing`` as
+    :func:`lm_prefill` (routes ``(N, 1, expert layers, top_k)``); ``live
+    (N,) bool``: the rows that carry a token (None: all; the serving
+    tick's free slots go to no expert).
     """
-    embed, attn_block, _, _ = _decoder_core(params, head_dim, axis_name)
+    embed, attn_block, _, _ = _decoder_core(
+        params, head_dim, axis_name, arch,
+        None if live is None else live[:, None])
+    arch = attn_block.arch
     per_row = getattr(pos, "ndim", 0) == 1
     positions = pos[:, None] if per_row else pos[None]
     with jax.named_scope("tick/embed"):
         x = embed(tokens[:, None], positions)
     new_caches = []
-    for blk, (kc, vc) in zip(params["blocks"], caches):
+    for i, (blk, bufs) in enumerate(zip(params["blocks"], caches)):
         # the block's cache append nests as tick/attn/cache_write
         with jax.named_scope("tick/attn"):
-            x, kc, vc = attn_block(x, blk, kc, vc, positions, pos, pos)
-        new_caches.append((kc, vc))
+            x, new = _run_layer(attn_block, x, blk, bufs, positions, pos,
+                                pos, i)
+        new_caches.append(new)
     with jax.named_scope("tick/head"):
-        h = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
-    return h[:, -1], new_caches
+        h = _blocks.norm(arch, x, params, "lnf")
+    out = (h[:, -1], new_caches)
+    return out + (_routing(attn_block),) if with_routing else out
 
 
 def _make_face(mesh: Optional[Mesh], axis_name: str, inner, has_rng: bool,

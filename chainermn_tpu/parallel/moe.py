@@ -18,6 +18,16 @@ Switch-Transformer / Mesh-TF dispatch formulation, which XLA maps well):
 * the load-balancing auxiliary loss (Switch eq. 4) comes back alongside the
   output; gradients flow through dispatch/combine einsums and the
   all_to_alls automatically (shard_map transposes them).
+
+:func:`moe_dropless` is the SERVING layer (a served token that loses an
+expert is a wrong answer, so nothing is dropped): sigmoid scores with a
+selection-only bias, group-limited top-k, renormalised and scaled gates
+(:func:`sigmoid_group_route`), a layer that is TOLD which experts it holds
+(``held = (first, n)``), routes over all of them and computes its own
+experts' part, with one grouped product per projection over the experts
+that have tokens (``ops/moe_gmm.py``) beside a shared expert every token
+takes.  It runs without an exchange: a chip's result is its PART of the
+layer (the exchange between the parts waits for a four-chip cell).
 """
 
 from __future__ import annotations
@@ -185,3 +195,151 @@ def make_moe_mlp(num_experts: int, mesh: Optional[Mesh] = None,
                 capacity_factor=capacity_factor, activation=activation,
                 router_topk=router_topk),
         mesh, (P(ax), specs), (P(ax), P()))
+
+
+# --------------------------------------------------------------------------
+# the dropless serving layer
+# --------------------------------------------------------------------------
+
+#: leading entries of the int32 routing-count vector ``moe_dropless``
+#: returns; one entry per held expert (its tokens) follows
+COUNT_FIELDS = ("assignments_total", "assignments_held", "experts_hit")
+
+
+def sigmoid_group_route(x, router, bias, cfg):
+    """Sigmoid scoring with a selection-only bias and group-limited top-k
+    over ALL ``cfg.n_experts``: ``(idx (T, k) int32, gates (T, k) f32)``.
+
+    ``s = sigmoid(x @ router)`` in float32; selection scores ``s + bias``;
+    a group's score is the sum of its two largest selection scores; the
+    ``topk_group`` best groups are kept and the ``top_k`` best selection
+    scores inside them chosen; the gates are ``s`` (NOT ``s + bias``) at
+    the chosen, divided by their sum, times ``routed_scaling_factor``."""
+    t = x.shape[0]
+    e, g = cfg.n_experts, cfg.n_group
+    s = jax.nn.sigmoid(jnp.matmul(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    sel = s + bias.astype(jnp.float32)
+    group_score = jax.lax.top_k(sel.reshape(t, g, e // g), 2)[0].sum(-1)
+    _, keep = jax.lax.top_k(group_score, cfg.topk_group)          # (T, kg)
+    in_kept = (jnp.arange(g)[None, :, None] == keep[:, None, :]).any(-1)
+    masked = jnp.where(jnp.repeat(in_kept, e // g, axis=1), sel, -jnp.inf)
+    _, idx = jax.lax.top_k(masked, cfg.top_k)
+    gates = jnp.take_along_axis(s, idx, axis=1)
+    if cfg.norm_topk_prob:
+        gates = gates / (gates.sum(-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), gates * cfg.routed_scaling_factor
+
+
+def _row_tile(n_assign: int) -> int:
+    """Rows of one grouped-product tile: small while a tick's few rows per
+    expert would mostly be padding, MXU-high for a prefill."""
+    return 32 if n_assign <= 2048 else 128
+
+
+def _held_experts_product(x, p, idx, gates, first, n_held: int,
+                          use_kernel: bool, interpret: bool):
+    """``Σ_{chosen ∧ held} gate · E(x)`` over the held experts ``[first,
+    first + n_held)`` and the per-held-expert token counts.  Kernel path:
+    assignments sorted by expert into tile-aligned groups, three grouped
+    products, a gather-combine (no scatter).  Fallback: a dense loop over
+    the held experts (tiny CPU sizes)."""
+    t, d = x.shape
+    k = idx.shape[1]
+    local = idx - first
+    is_held = (local >= 0) & (local < n_held)
+    onehot = (jnp.where(is_held, local, n_held).reshape(-1, 1)
+              == jnp.arange(n_held)[None, :])                  # (T·k, E_h)
+    counts = onehot.sum(0).astype(jnp.int32)
+    if not use_kernel:
+        from .blocks import swiglu
+        y = jnp.zeros((t, d), jnp.float32)
+        for e in range(n_held):
+            g_e = jnp.where(local == e, gates, 0.0).sum(-1)      # (T,)
+            y_e = swiglu(x, {n: p[n][e] for n in
+                             ("w_gate", "w_up", "w_down")})
+            y = y + y_e.astype(jnp.float32) * g_e[:, None]
+        return y, counts
+
+    from ..ops.moe_gmm import moe_gmm
+    a = t * k
+    tm = _row_tile(a)
+    m_pad = -(-(a + n_held * (tm - 1)) // tm) * tm
+    padded = -(-counts // tm) * tm
+    ends = jnp.cumsum(padded)
+    starts = ends - padded
+    rank = ((jnp.cumsum(onehot, axis=0) - 1) * onehot).sum(-1)   # (T·k,)
+    flat_held = is_held.reshape(-1)
+    dest = jnp.where(flat_held,
+                     starts[jnp.clip(local.reshape(-1), 0, n_held - 1)]
+                     + rank, m_pad).astype(jnp.int32)
+    row_token = jnp.zeros((m_pad,), jnp.int32).at[dest].set(
+        jnp.repeat(jnp.arange(t, dtype=jnp.int32), k), mode="drop")
+    tile_expert = jnp.minimum(jnp.searchsorted(
+        ends, jnp.arange(m_pad // tm, dtype=jnp.int32) * tm, side="right"),
+        n_held - 1)
+    n_valid = ends[-1] // tm
+    with jax.named_scope("block/moe/gmm"):
+        xs = jnp.take(x, row_token, axis=0)                      # (M, D)
+        gmm = lambda lhs, w: moe_gmm(lhs, w, tile_expert, n_valid, tm=tm,
+                                     interpret=interpret)
+        hidden = (jax.nn.silu(gmm(xs, p["w_gate"]).astype(jnp.float32))
+                  * gmm(xs, p["w_up"]).astype(jnp.float32)).astype(x.dtype)
+        rows = gmm(hidden, p["w_down"])                          # (M, D)
+        # gather-combine, one choice at a time: each token reads its own
+        # held rows back; rows of dead tiles are never named (a non-held
+        # choice reads a clamped row and is masked, not multiplied)
+        dest = jnp.minimum(dest, m_pad - 1).reshape(t, k)
+        y = jnp.zeros((t, d), jnp.float32)
+        for j in range(k):
+            picked = jnp.take(rows, dest[:, j], axis=0).astype(jnp.float32)
+            y = y + jnp.where(is_held[:, j, None],
+                              picked * gates[:, j, None], 0.0)
+    return y, counts
+
+
+def moe_dropless(x, params, cfg, *, live=None,
+                 interpret: Optional[bool] = None):
+    """Dropless expert FFN of ``x (T, D)``: ``(y, counts, idx)`` — ``y =
+    E_shared(x) + Σ_{chosen ∧ held here} gate · E_i(x)``, the int32
+    routing-count vector (:data:`COUNT_FIELDS`, then the tokens of each
+    held expert) and the chosen experts ``idx (T, top_k)``.
+
+    ``cfg`` is a ``blocks.MoEConfig``; ``cfg.held = (first, n)`` names the
+    routed experts this chip holds — ``params['w_gate'|'w_up'|'w_down']``
+    stack exactly those.  The router scores ALL ``cfg.n_experts`` and the
+    gates are normalised over all chosen, held here or not: with ``n <
+    n_experts`` the result is this chip's PART of the layer (what expert
+    parallelism asks of a chip anyway).
+
+    ``live (T,) bool`` names the rows that carry a token (None: all).  A
+    row that carries none — a free slot of the serving tick, a prefill's
+    padding — is routed to NO expert: it reads no expert's weights, its
+    routed part is zero, it is in no count, and its ``idx`` is
+    ``cfg.n_experts`` (no expert's number).
+
+    The grouped product is the kernel ``moe_gmm`` on a TPU and a dense
+    loop over the held experts elsewhere; ``interpret=True`` runs the
+    kernel path in interpret mode (tests).
+    """
+    from .blocks import swiglu
+
+    use_kernel = interpret is not None or jax.default_backend() == "tpu"
+    first, n_held = cfg.held
+    with jax.named_scope("block/moe/route"):
+        idx, gates = sigmoid_group_route(x, params["router"],
+                                         params["router_bias"], cfg)
+        if live is not None:
+            idx = jnp.where(live[:, None], idx, jnp.int32(cfg.n_experts))
+    y, per_expert = _held_experts_product(
+        x, params, idx, gates, first, n_held, use_kernel, bool(interpret))
+    with jax.named_scope("block/moe/shared"):
+        y = y + swiglu(x, params["shared"]).astype(jnp.float32)
+    n_rows = jnp.int32(x.shape[0]) if live is None else live.sum()
+    counts = jnp.concatenate([
+        jnp.stack([(n_rows * idx.shape[1]).astype(jnp.int32),
+                   per_expert.sum(),
+                   (per_expert > 0).sum().astype(jnp.int32)]),
+        per_expert])
+    return y.astype(x.dtype), counts, idx

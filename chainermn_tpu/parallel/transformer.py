@@ -155,16 +155,40 @@ def _attend_local_heads(q, k, v, *, causal, attn_impl, head_dim):
 
 
 def tp_block(x, params, *, head_dim: int, axis_name: str, causal: bool = True,
-             attn_impl: str = "auto", positions=None):
-    """Pre-norm transformer block: LN→attn→residual, LN→MLP→residual."""
+             attn_impl: str = "auto", positions=None, arch=None,
+             layer: int = 0):
+    """Pre-norm transformer block: norm→attn→residual, norm→FFN→residual.
+
+    ``arch`` (``blocks.LMArch``; None = the GPT-2-style default: LayerNorm,
+    MHA/GQA, ``gelu`` MLP) names the block's vocabulary — the SAME
+    description ``parallel/decode.py`` reads, so a model is described once
+    for training, prefill and the decode tick.  ``layer`` picks the
+    layer's kind (dense MLP | experts) from it."""
+    from . import blocks as _blocks
+
+    arch = _blocks.resolve(arch)
     with jax.named_scope("block/attn"):
-        h = _layer_norm(x, params["ln1_scale"], params["ln1_bias"])
-        x = x + tp_attention(h, params["attn"], head_dim=head_dim,
-                             axis_name=axis_name, causal=causal,
-                             attn_impl=attn_impl, positions=positions)
+        h = _blocks.norm(arch, x, params, "ln1")
+        if arch.attn == "mla":
+            from ..ops.flash_attention import resolve_attn_impl
+            s = x.shape[1]
+            q_nope, q_rope, c_kv, k_rope = _blocks.mla_project(
+                arch.mla, h, params["attn"],
+                jnp.arange(s) if positions is None else positions,
+                arch.norm_eps)
+            ctx = _blocks.mla_attend_prefill(
+                arch.mla, q_nope, q_rope, c_kv, k_rope, params["attn"],
+                resolve_attn_impl(attn_impl, s))
+            x = x + jnp.matmul(ctx, params["attn"]["wo"],
+                               preferred_element_type=jnp.float32
+                               ).astype(x.dtype)
+        else:
+            x = x + tp_attention(h, params["attn"], head_dim=head_dim,
+                                 axis_name=axis_name, causal=causal,
+                                 attn_impl=attn_impl, positions=positions)
     with jax.named_scope("block/mlp"):
-        h = _layer_norm(x, params["ln2_scale"], params["ln2_bias"])
-        return x + tp_mlp(h, params["mlp"], axis_name=axis_name)
+        h = _blocks.norm(arch, x, params, "ln2")
+        return x + _blocks.ffn(arch, layer, h, params, axis_name)[0]
 
 
 def tp_attention_sp(x, params, *, head_dim: int, axis_name: str,
@@ -353,35 +377,40 @@ def vocab_parallel_logits_loss(h, table, targets, *, axis_name: str,
 
 def tp_transformer_lm_loss(params, batch, *, head_dim: int, axis_name: str,
                            causal: bool = True, attn_impl: str = "auto",
-                           ce_impl: str = "auto"):
+                           ce_impl: str = "auto", arch=None):
     """Per-token mean NLL of a decoder-only LM over the LOCAL batch shard.
 
     ``batch``: ``(tokens (B, S+1) int32,)`` — inputs are ``[:, :-1]``,
     targets ``[:, 1:]``.  Feed to ``make_hybrid_shard_map_step`` for DP×TP
     (``functools.partial`` the static args first).  ``ce_impl`` selects
-    the loss path (see :func:`vocab_parallel_logits_loss`).
+    the loss path (see :func:`vocab_parallel_logits_loss`).  ``arch``: the
+    model's ``blocks.LMArch`` (None = the GPT-2-style default).
     """
+    from . import blocks as _blocks
+    from .tensor_parallel import vocab_parallel_embedding
+
+    arch = _blocks.resolve(arch)
     tokens = batch[0]
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
-    from .tensor_parallel import vocab_parallel_embedding
 
     positions = None
     with jax.named_scope("embed"):
         x = vocab_parallel_embedding(inputs, params["embed"],
                                      axis_name=axis_name)
-        x = x * (params["embed"].shape[1] ** 0.5)
+        x = _blocks.scale_embedding(arch, x, params["embed"].shape[1])
         if "pos_embed" in params:
             x = x + params["pos_embed"][: x.shape[1]][None]
         else:  # RoPE model (init with pos_impl='rope'): rotate in attention
             positions = jnp.arange(x.shape[1])
-    for blk in params["blocks"]:
+    for i, blk in enumerate(params["blocks"]):
         x = tp_block(x, blk, head_dim=head_dim, axis_name=axis_name,
-                     causal=causal, attn_impl=attn_impl, positions=positions)
+                     causal=causal, attn_impl=attn_impl, positions=positions,
+                     arch=arch, layer=i)
     with jax.named_scope("head_ce"):
-        x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
-        return vocab_parallel_logits_loss(x, params["embed"], targets,
-                                          axis_name=axis_name,
-                                          ce_impl=ce_impl)
+        x = _blocks.norm(arch, x, params, "lnf")
+        return vocab_parallel_logits_loss(
+            x, _blocks.head_table(arch, params), targets,
+            axis_name=axis_name, ce_impl=ce_impl)
 
 
 def sp_block(x, params, *, head_dim: int, axis_name: str, causal: bool = True,

@@ -221,6 +221,11 @@ class SlotAllocator:
     def refcount(self, slot: int) -> Optional[int]:
         return self._cached.get(slot)
 
+    def busy_slots(self) -> List[int]:
+        """The busy slots, as a list taken under the lock."""
+        with self._lock:
+            return list(self._busy)
+
     @property
     def free_count(self) -> int:
         return len(self._free)
@@ -253,19 +258,25 @@ class SlotAllocator:
 
 
 class CachePool:
-    """Device-buffer half: per-layer flat K/V pools + per-slot positions.
+    """Device-buffer half: per-layer flat cache pools + per-slot positions.
 
     ``caches`` is the pytree the engine's compiled programs thread
-    through (list of ``(k, v)`` per layer, each ``(n_slots, max_total,
-    kv_dim)`` sharded ``P(None, None, axis)`` over the model axis — each
+    through: per layer, a tuple of ``(n_slots, max_total, columns)``
+    buffers — whatever that layer's attention DECLARES it keeps per token
+    (``layout``: per layer a tuple of ``(columns, PartitionSpec)``, from
+    ``parallel/blocks.py::cache_layout``).  Without a ``layout`` every
+    layer is the MHA/GQA declaration: a ``(k, v)`` pair of ``kv_dim``
+    columns sharded ``P(None, None, axis)`` over the model axis — each
     chip holds only its local heads' columns, exactly the closed-batch
-    decoder's TP layout).  ``pos`` lives HOST-side as numpy (the
-    scheduler reads/writes it every tick; shipping it to device happens
-    once per tick as a tiny operand).
+    decoder's TP layout.  A latent-attention layer declares ONE replicated
+    buffer.  Prefix copy, spill and transfer walk the same declaration.
+    ``pos`` lives HOST-side as numpy (the scheduler reads/writes it every
+    tick; shipping it to device happens once per tick as a tiny operand).
     """
 
     def __init__(self, n_slots: int, max_total: int, n_layers: int,
-                 kv_dim: int, dtype, mesh, axis_name: str = "model"):
+                 kv_dim: int, dtype, mesh, axis_name: str = "model",
+                 layout=None):
         import jax
         import jax.numpy as jnp
         import numpy as np
@@ -280,21 +291,56 @@ class CachePool:
         self.kv_dim = int(kv_dim)
         self.axis_name = axis_name
         self.mesh = mesh
-        self.cache_spec = P(None, None, axis_name)
-        sharding = NamedSharding(mesh, self.cache_spec)
-        shape = (self.n_slots, self.max_total, self.kv_dim)
+        if layout is None:
+            pair = P(None, None, axis_name)
+            layout = [((self.kv_dim, pair), (self.kv_dim, pair))
+                      ] * self.n_layers
+        if len(layout) != self.n_layers:
+            raise ValueError(f"layout names {len(layout)} layers, the "
+                             f"model has {self.n_layers}")
+        self.layout = [tuple((int(w), spec) for w, spec in bufs)
+                       for bufs in layout]
+        #: the caches pytree's PartitionSpecs (programs' in/out specs)
+        self.cache_specs = [tuple(spec for _, spec in bufs)
+                            for bufs in self.layout]
+        # the first buffer's spec: what a K/V pool's every buffer has
+        self.cache_spec = self.layout[0][0][1]
         self.caches = [
-            (jax.device_put(jnp.zeros(shape, dtype), sharding),
-             jax.device_put(jnp.zeros(shape, dtype), sharding))
-            for _ in range(self.n_layers)]
+            tuple(jax.device_put(
+                jnp.zeros((self.n_slots, self.max_total, w), dtype),
+                NamedSharding(mesh, spec)) for w, spec in bufs)
+            for bufs in self.layout]
+        #: bytes one token keeps across all layers (whole model axis)
+        self.bytes_per_token = jnp.dtype(dtype).itemsize * sum(
+            w for bufs in self.layout for w, _ in bufs)
         # host-side per-slot NEXT-WRITE position (== sequence length so
-        # far).  The tick advances EVERY slot's pos (one fixed program),
-        # so a free slot's position drifts upward until the next prefill
-        # resets it; its garbage writes land at the drifting row (clamped
-        # to max_total-1 by dynamic_update_slice) INSIDE ITS OWN SLOT
-        # ROW, which stays safe by the module-docstring argument: the
-        # next occupant rewrites row p before its own pos reaches p.
+        # far).  The tick runs EVERY slot (one fixed program) but only a
+        # BUSY slot's position advances (``advance``): a free, cached or
+        # reserved slot holds its position, so its garbage write keeps
+        # landing on the one row ``pos`` INSIDE ITS OWN SLOT ROW — row 0
+        # of a free slot, the first row above a cached prefix — which
+        # stays safe by the module-docstring argument: the next occupant
+        # rewrites row p before its own pos reaches p.
         self.pos = np.zeros(self.n_slots, np.int32)
+
+    def busy_mask(self):
+        """``(n_slots,) bool``: the slots a request holds right now."""
+        import numpy as np
+
+        mask = np.zeros(self.n_slots, bool)
+        mask[self.allocator.busy_slots()] = True
+        return mask
+
+    def advance(self, busy) -> None:
+        """One tick happened: every BUSY slot (``busy``: the mask the tick
+        ran with) consumed a token.  Out of place — a
+        dispatched program may still read the old vector.  (The tick used
+        to advance every slot without bound; past a learned position table
+        a free slot then read NaN into its last row and the next occupant
+        served the sentinel: PERF.md, Findings PR 24.)"""
+        import numpy as np
+
+        self.pos = self.pos + busy.astype(np.int32)
 
     # thin faces over the allocator (the frontend talks to the pool)
     def acquire(self) -> Optional[int]:

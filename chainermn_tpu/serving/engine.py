@@ -57,19 +57,29 @@ class DecodeEngine:
     compiled prefill/tick programs; the :class:`~chainermn_tpu.serving
     .cache_pool.CachePool` owns the buffers the programs thread through.
 
-    ``params`` are GLOBAL arrays in ``init_tp_transformer_lm`` layout;
-    ``mesh`` must carry ``axis_name`` (default: a fresh 1-D mesh over
-    all local devices, like ``make_lm_generator``).
+    ``params`` are GLOBAL arrays in ``init_tp_transformer_lm`` layout —
+    or, with ``arch`` (a ``parallel.blocks.LMArch``), in the layout that
+    description names (RMSNorm, gated MLPs, latent attention, experts,
+    an untied head: ``parallel/blocks.py``); ``mesh`` must carry
+    ``axis_name`` (default: a fresh 1-D mesh over all local devices, like
+    ``make_lm_generator``).  The programs thread the pool's caches as a
+    pytree, whatever each layer declares.  A model with experts is told
+    which rows carry a token (the tick's busy slots, a prompt's real
+    positions: the others go to no expert) and returns its routing in the
+    SAME int32 vector as the tokens: the counts (``moe_counts_tick`` /
+    ``moe_counts_prefill`` accumulate them) and the experts chosen for
+    each emitted token (``tick_routes (n_slots, expert layers, top_k)``,
+    ``prefill_routes (expert layers, top_k)``: the last call's).
     """
 
     def __init__(self, params, pool, mesh=None, axis_name: str = "model",
-                 *, head_dim: int, prefill_bucket: int = 1):
+                 *, head_dim: int, prefill_bucket: int = 1, arch=None):
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from .._compat import shard_map
+        from ..parallel import blocks as _blocks
         from ..parallel.decode import _kv_heads
-        from ..parallel.transformer import transformer_lm_specs
 
         if mesh is None:
             from ..topology import make_mesh
@@ -83,14 +93,22 @@ class DecodeEngine:
         self.rope = "pos_embed" not in params
         self.max_positions = (None if self.rope
                               else int(params["pos_embed"].shape[0]))
-        self._specs = transformer_lm_specs(params, axis_name)
+        self.arch = _blocks.resolve(arch)
+        self._specs = _blocks.lm_specs(self.arch, params, axis_name)
         self._params = jax.tree_util.tree_map(
             lambda x, sp: jax.device_put(x, NamedSharding(mesh, sp)),
             params, self._specs)
         self._shard_map = shard_map
         self._P = P
-        self._cache_specs = [(pool.cache_spec, pool.cache_spec)
-                             for _ in range(pool.n_layers)]
+        self._cache_specs = pool.cache_specs
+        # routing counts of the expert layers (``moe.COUNT_FIELDS`` then
+        # one entry per held expert), summed over layers in the program
+        # and over calls here; empty for a model without experts
+        self.n_counts = _blocks.n_count_entries(self.arch)
+        self.moe_counts_tick = np.zeros(self.n_counts, np.int64)
+        self.moe_counts_prefill = np.zeros(self.n_counts, np.int64)
+        self._route_shape = _blocks.route_shape(self.arch)
+        self.tick_routes = self.prefill_routes = None
         self._prefill_progs = {}   # padded prompt length -> compiled fn
         self._tick_prog = self._build_tick()
         self._prefix_copy_prog = None   # built lazily on first hit
@@ -106,59 +124,67 @@ class DecodeEngine:
     def _build_tick(self):
         import jax
 
+        from ..parallel import blocks as _blocks
         from ..parallel.decode import _next_token, lm_decode_tick
 
-        axis, head_dim = self.axis_name, self.head_dim
+        axis, head_dim, arch = self.axis_name, self.head_dim, self.arch
         P = self._P
 
         # the jitted programs are named after these functions: the
         # profiler's "XLA Modules" line says ``jit_serving_tick``,
         # ``jit_serving_prefill_<s_pad>``, ``jit_serving_prefix_copy``
-        def serving_tick(params, caches, tokens, pos, keys, temps):
-            h_last, new_caches = lm_decode_tick(
+        def serving_tick(params, caches, tokens, pos, keys, temps,
+                         live=None):
+            h_last, new_caches, routing = lm_decode_tick(
                 params, tokens, caches, pos, head_dim=head_dim,
-                axis_name=axis)
+                axis_name=axis, arch=arch, live=live, with_routing=True)
             # the consumed token sits at row ``pos``; the selected next
             # token is position ``pos + 1`` — lm_generate's step_pos
             # salt, so sampling stays token-exact per request
             with jax.named_scope("tick/head"):
-                nxt = _next_token(params["embed"], h_last, axis, keys,
-                                  temps, pos + 1)
-            return nxt, new_caches
+                nxt = _next_token(_blocks.head_table(arch, params), h_last,
+                                  axis, keys, temps, pos + 1)
+            return _with_routing(nxt, routing), new_caches
 
+        # a model with experts takes the busy mask as a fifth vector
         return jax.jit(self._shard_map(
             serving_tick, mesh=self.mesh,
-            in_specs=(self._specs, self._cache_specs, P(), P(), P(), P()),
+            in_specs=(self._specs, self._cache_specs)
+            + (P(),) * (5 if self.n_counts else 4),
             out_specs=(P(), self._cache_specs)))
 
     def _build_prefill(self, s_pad: int):
         import jax
+        import jax.numpy as jnp
 
+        from ..parallel import blocks as _blocks
         from ..parallel.decode import _next_token, lm_prefill
 
-        axis, head_dim = self.axis_name, self.head_dim
-        P = self._P
+        axis, head_dim, arch = self.axis_name, self.head_dim, self.arch
+        P, moe = self._P, bool(self.n_counts)
 
         def prefill_inner(params, caches, prompt, s_real, slot, key, temp):
             # slab caches sized to the padded prompt only; pads are above
             # every real row and never read back (causal + pos mask)
-            h, slabs = lm_prefill(params, prompt, s_pad, head_dim=head_dim,
-                                  axis_name=axis)
+            real = (jnp.arange(s_pad) < s_real)[None] if moe else None
+            h, slabs, routing = lm_prefill(
+                params, prompt, s_pad, head_dim=head_dim, axis_name=axis,
+                arch=arch, live=real, with_routing=True)
+            if routing is not None:   # the routes of the emitting position
+                routing = (routing[0], jax.lax.dynamic_index_in_dim(
+                    routing[1], s_real - 1, axis=1, keepdims=False))
             h_last = jax.lax.dynamic_index_in_dim(h, s_real - 1, axis=1,
                                                   keepdims=False)
             # first generated token = position s_real (lm_generate's
             # first = logits_next(h[:, -1], s_p) salt)
-            tok = _next_token(params["embed"], h_last, axis, key[None],
-                              temp[None], s_real[None])
-            new_caches = []
-            for (kc, vc), (ks, vs) in zip(caches, slabs):
-                start = (slot, 0, 0)
-                new_caches.append(
-                    (jax.lax.dynamic_update_slice(kc, ks.astype(kc.dtype),
-                                                  start),
-                     jax.lax.dynamic_update_slice(vc, vs.astype(vc.dtype),
-                                                  start)))
-            return tok, new_caches
+            tok = _next_token(_blocks.head_table(arch, params), h_last,
+                              axis, key[None], temp[None], s_real[None])
+            # every buffer a layer declares gets its slab, at the slot's
+            # rows [0, s_pad)
+            new_caches = jax.tree_util.tree_map(
+                lambda c, slab: jax.lax.dynamic_update_slice(
+                    c, slab.astype(c.dtype), (slot, 0, 0)), caches, slabs)
+            return _with_routing(tok, routing), new_caches
 
         prefill_inner.__name__ = f"serving_prefill_{s_pad}"
         return jax.jit(self._shard_map(
@@ -168,9 +194,11 @@ class DecodeEngine:
             out_specs=(P(), self._cache_specs)))
 
     def _build_prefix_copy(self):
-        """Slot-to-slot K/V slab copy — the prefix cache's copy-on-
+        """Slot-to-slot cache slab copy — the prefix cache's copy-on-
         extend device half (ISSUE 7).  Copies the ENTIRE src slot row
-        into dst for every layer: rows beyond the matched prefix length
+        into dst for every buffer of every layer (a K/V pair, a latent
+        buffer: whatever the pool declares): rows beyond the matched
+        prefix length
         carry stale K/V, but they are unreachable by the standard
         above-``pos`` masking argument and the next occupant's writes
         land below its own pos first — so the program needs no length
@@ -179,17 +207,11 @@ class DecodeEngine:
         import jax
 
         def serving_prefix_copy(caches, src, dst):
-            new_caches = []
-            for kc, vc in caches:
-                k_row = jax.lax.dynamic_index_in_dim(kc, src, axis=0,
-                                                     keepdims=True)
-                v_row = jax.lax.dynamic_index_in_dim(vc, src, axis=0,
-                                                     keepdims=True)
-                start = (dst, 0, 0)
-                new_caches.append(
-                    (jax.lax.dynamic_update_slice(kc, k_row, start),
-                     jax.lax.dynamic_update_slice(vc, v_row, start)))
-            return new_caches
+            return jax.tree_util.tree_map(
+                lambda c: jax.lax.dynamic_update_slice(
+                    c, jax.lax.dynamic_index_in_dim(c, src, axis=0,
+                                                    keepdims=True),
+                    (dst, 0, 0)), caches)
 
         P = self._P
         return jax.jit(self._shard_map(
@@ -247,7 +269,12 @@ class DecodeEngine:
                                          *operands)
         self.pool.pos[slot] = s_real
         with _trace.span("serving/prefill/readback", cat="serving"):
-            return int(np.asarray(tok)[0])
+            out = np.asarray(tok)
+        if self.n_counts:
+            self.moe_counts_prefill += out[1:1 + self.n_counts]
+            self.prefill_routes = out[1 + self.n_counts:].reshape(
+                self._route_shape)
+        return int(out[0])
 
     def copy_prefix(self, src_slot: int, dst_slot: int,
                     prefix_len: int) -> None:
@@ -279,7 +306,8 @@ class DecodeEngine:
              temps=None) -> np.ndarray:
         """One decode tick for ALL slots: consume ``last_tokens
         (n_slots,)`` at the pool's per-slot positions, append K/V in
-        place, advance every position, and return the next token per
+        place, advance every BUSY slot's position (a free slot holds
+        its own: ``CachePool.advance``), and return the next token per
         slot (the caller keeps only the active rows).  ``keys (n_slots,
         2) uint32`` / ``temps (n_slots,)`` carry each slot's request rng
         and temperature (ISSUE 9 sampling plumbing); None = all-greedy
@@ -301,10 +329,33 @@ class DecodeEngine:
                 temps = np.zeros(self.pool.n_slots, np.float32)
             keys = jnp.asarray(np.array(keys, np.uint32, copy=True))
             temps = jnp.asarray(np.array(temps, np.float32, copy=True))
+            busy = self.pool.busy_mask()
+            operands = (tokens, pos, keys, temps) + (
+                (jnp.asarray(busy),) if self.n_counts else ())
         with _trace.span("serving/tick/dispatch", cat="serving"):
             nxt, self.pool.caches = self._tick_prog(
-                self._params, self.pool.caches, tokens, pos, keys, temps)
-        self.pool.pos = self.pool.pos + 1  # out-of-place: never mutate a
-        #                                    buffer jax might still read
+                self._params, self.pool.caches, *operands)
+        self.pool.advance(busy)   # out-of-place: never mutate a buffer
+        #                           jax might still read
         with _trace.span("serving/tick/readback", cat="serving"):
-            return np.asarray(nxt)
+            out = np.asarray(nxt)
+        if self.n_counts:
+            n = self.pool.n_slots
+            self.moe_counts_tick += out[n:n + self.n_counts]
+            self.tick_routes = out[n + self.n_counts:].reshape(
+                (n,) + self._route_shape)
+            out = out[:n]
+        return out
+
+
+def _with_routing(tokens, routing):
+    """The program's one int32 result: the tokens, then — where the model
+    has expert layers — their routing counts and the experts chosen for
+    each emitting row; one readback, no second transfer."""
+    import jax.numpy as jnp
+
+    if routing is None:
+        return tokens
+    counts, routes = routing
+    return jnp.concatenate([tokens, counts.astype(tokens.dtype),
+                            routes.reshape(-1).astype(tokens.dtype)])

@@ -100,6 +100,14 @@ class RequestHandle:
         return list(self._req.tokens)
 
     @property
+    def routes(self) -> list:
+        """For a model with expert layers, the experts the serving
+        programs chose for the input of each emitted token, an ``(expert
+        layers, top_k)`` int32 array a token, as the prefill and the ticks
+        read them back; empty otherwise."""
+        return list(self._req.routes)
+
+    @property
     def timestamps(self) -> Dict[str, float]:
         return dict(self._req.timestamps)
 
@@ -175,9 +183,16 @@ class ServingEngine:
                  recent_capacity: int = 64,
                  prefix_cache: bool = True,
                  min_prefix_len: int = 2,
-                 spill_bytes: int = 32 << 20):
+                 spill_bytes: int = 32 << 20,
+                 arch=None):
+        from ..parallel import blocks as _blocks
         from ..parallel.decode import _kv_heads
 
+        # ``arch`` (parallel.blocks.LMArch; None = the GPT-2-style block)
+        # names the model's block vocabulary and, with it, what each
+        # layer's attention keeps per token: the pool allocates exactly
+        # that declaration
+        arch = _blocks.resolve(arch)
         n_kv = _kv_heads(params, head_dim)
         dtype = params["embed"].dtype
         # pool and engine share one mesh (created here when not given,
@@ -185,11 +200,14 @@ class ServingEngine:
         if mesh is None:
             from ..topology import make_mesh
             mesh = make_mesh(axis_name=axis_name)
-        self.pool = CachePool(n_slots, max_total, len(params["blocks"]),
-                              n_kv * head_dim, dtype, mesh, axis_name)
+        n_layers = len(params["blocks"])
+        self.pool = CachePool(
+            n_slots, max_total, n_layers, n_kv * head_dim, dtype, mesh,
+            axis_name, layout=_blocks.cache_layout(
+                arch, n_layers, n_kv * head_dim, axis_name))
         self.engine = DecodeEngine(params, self.pool, mesh, axis_name,
                                    head_dim=head_dim,
-                                   prefill_bucket=prefill_bucket)
+                                   prefill_bucket=prefill_bucket, arch=arch)
         self.scheduler = Scheduler(
             queue_capacity, max_total,
             max_prefills_per_tick=max_prefills_per_tick,
@@ -263,6 +281,8 @@ class ServingEngine:
         # alike, so one is counted)
         self._tick_cache_blocks_read = 0
         self._tick_cache_blocks_total = 0
+        # the rows those blocks had to hold: each slot's own length
+        self._tick_cache_rows_live = 0
         self._t0 = time.monotonic()
         # goodput attribution: step() partitions its own wall clock, and
         # the gap between steps books as queue_wait (work was waiting)
@@ -544,6 +564,7 @@ class ServingEngine:
                           f"{req.id} failed: {e!r}", file=sys.stderr)
                     continue
                 with obs.span("serving/emit", cat="serving", tokens=1):
+                    self._keep_routes(req, self.engine.prefill_routes)
                     self._emit(req, first, time.monotonic())
                     with self._lock:
                         self._running[slot] = req
@@ -578,6 +599,9 @@ class ServingEngine:
                     self._last_tick_start = t_tick
                     self._tick_cache_blocks_read += read
                     self._tick_cache_blocks_total += total
+                    self._tick_cache_rows_live += int(np.minimum(
+                        self.pool.pos, self.pool.max_total - 1).sum()
+                        ) + self.pool.n_slots
                 tick_bucket = ("compile" if self.engine.tick_calls == 0
                                else "compute")
                 # the tracer's clock is read only for its own Chrome sink
@@ -592,6 +616,7 @@ class ServingEngine:
                 dt_ms = (t_host - t_tick) * 1e3
                 dt_us = obs.now_us() - t_tick_us if recording else 0
                 now = time.monotonic()
+                routes = self.engine.tick_routes
                 with obs.span("serving/emit", cat="serving",
                               tokens=len(active)):
                     for slot, req in active.items():
@@ -611,6 +636,8 @@ class ServingEngine:
                             # miss path, or the suffix's last prompt token
                             # just ran: the tick's prediction IS the next
                             # real token
+                            if routes is not None:
+                                self._keep_routes(req, routes[slot])
                             self._emit(req, int(nxt[slot]), now)
                         self._tok_lat_ms.add(dt_ms / max(len(active), 1))
                         self._maybe_evict(req, now)
@@ -665,6 +692,13 @@ class ServingEngine:
                 _flight.note("phase", name="serving/step", tick=self._ticks,
                              active=int(stats["active_slots"]))
             return stats
+
+    def _keep_routes(self, req: Request, routes) -> None:
+        """A model with expert layers: the experts the program chose for
+        the input of the token about to be emitted (``routes``: None for
+        a model without)."""
+        if routes is not None:
+            req.routes.append(routes)
 
     def _emit(self, req: Request, token: int, now: float) -> None:
         req.tokens.append(int(token))
@@ -978,6 +1012,9 @@ class ServingEngine:
             self._prefill_tokens_padded = 0
             self._tick_cache_blocks_read = 0
             self._tick_cache_blocks_total = 0
+            self._tick_cache_rows_live = 0
+            self.engine.moe_counts_tick[:] = 0
+            self.engine.moe_counts_prefill[:] = 0
             self.goodput.reset()
             self._last_step_end = None
             self._slo_last = (0, self._t0)
@@ -994,6 +1031,32 @@ class ServingEngine:
                 sp.spills = sp.restores = sp.hits = sp.misses = 0
                 sp.crc_refusals = sp.evictions = 0
                 sp.rejected_oversize = 0
+
+    def _moe_metrics(self) -> Dict[str, float]:
+        """The expert layers' routing counters (a model without experts:
+        none).  Rows are the tokens served — a tick's busy slots, a
+        prompt's real positions; free slots and padding go to no expert
+        and are in no count.  ``serving/moe_*``: ticks and prefills together;
+        ``serving/moe_tick_*``: the ticks alone (the tick's kernel time
+        is read against them)."""
+        eng = self.engine
+        if not eng.n_counts:
+            return {}
+        from ..parallel.moe import COUNT_FIELDS
+        n = len(COUNT_FIELDS)
+        both = eng.moe_counts_tick + eng.moe_counts_prefill
+        out = {
+            "serving/moe_assignments_total": float(both[0]),
+            "serving/moe_assignments_held": float(both[1]),
+            "serving/moe_experts_hit": float(both[2]),
+            "serving/moe_tick_assignments_held": float(
+                eng.moe_counts_tick[1]),
+            "serving/moe_tick_experts_hit": float(eng.moe_counts_tick[2]),
+        }
+        # one key per held expert (every value of metrics() is a float)
+        out.update({f"serving/moe_expert_tokens/{i}": float(v)
+                    for i, v in enumerate(both[n:])})
+        return out
 
     def metrics(self) -> Dict[str, float]:
         """Host-side serving summary (the Prometheus ``extra_gauges`` /
@@ -1020,10 +1083,17 @@ class ServingEngine:
                     self._tick_cache_blocks_read),
                 "serving/tick_cache_blocks_total": float(
                     self._tick_cache_blocks_total),
+                "serving/tick_cache_rows_live": float(
+                    self._tick_cache_rows_live),
+                # what one token keeps in the pool, all layers (gauge)
+                "serving/cache_bytes_per_token": float(
+                    self.pool.bytes_per_token),
+                "serving/tick_calls": float(self.engine.tick_calls),
                 "serving/slot_occupancy_pct": 100.0 * (
                     self._occupancy_sum / self._ticks if self._ticks
                     else 0.0),
             }
+            out.update(self._moe_metrics())
             for name, res in (("ttft", self._ttft_ms),
                               ("token_latency", self._tok_lat_ms),
                               ("tick_gap", self._tick_gap_ms)):

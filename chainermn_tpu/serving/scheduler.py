@@ -143,6 +143,7 @@ class Request:
         # /requestz rows and shed payloads keep the attribution.
         self.tenant = None if tenant is None else str(tenant)
         self.tokens: List[int] = []       # generated tokens, in order
+        self.routes: list = []            # per token: its chosen experts
         self.status = "queued"            # queued|running|done|evicted
         self.finish_reason: Optional[str] = None
         self.slot: Optional[int] = None

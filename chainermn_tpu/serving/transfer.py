@@ -74,10 +74,16 @@ def slab_crc32(rows) -> int:
     a bad DIMM) is REFUSED at landing rather than silently decoded
     into wrong-but-plausible tokens."""
     crc = 0
-    for k, v in rows:
-        crc = zlib.crc32(np.ascontiguousarray(k).tobytes(), crc)
-        crc = zlib.crc32(np.ascontiguousarray(v).tobytes(), crc)
+    for layer in rows:      # a layer's buffers in declared order: (k, v),
+        for buf in layer:   # or one latent buffer
+            crc = zlib.crc32(np.ascontiguousarray(buf).tobytes(), crc)
     return crc & 0xFFFFFFFF
+
+
+def _widths(pool):
+    """The pool's declaration as plain data: per layer, the columns of
+    each buffer (``[(kv_dim, kv_dim), ...]`` for a K/V pool)."""
+    return [tuple(w for w, _ in bufs) for bufs in pool.layout]
 
 
 def _shard_axis_of(spec, axis_name: str) -> Optional[int]:
@@ -208,7 +214,7 @@ class KvTransferPlane:
     def _local_key(self, src_pool, dst_pool):
         def sig(pool):
             return (pool.n_layers, pool.n_slots, pool.max_total,
-                    pool.kv_dim, str(pool.caches[0][0].dtype))
+                    tuple(_widths(pool)), str(pool.caches[0][0].dtype))
         return (sig(src_pool), sig(dst_pool), id(src_pool.mesh),
                 id(dst_pool.mesh), src_pool.axis_name)
 
@@ -225,42 +231,33 @@ class KvTransferPlane:
                 "local transfer needs src and dst pools on ONE mesh/"
                 "axis; cross-mesh transfers go over the object lanes "
                 "(pack/unpack_into)")
-        if src_pool.kv_dim != dst_pool.kv_dim \
-                or src_pool.n_layers != dst_pool.n_layers:
+        if _widths(src_pool) != _widths(dst_pool):
             raise ValueError(
                 f"pool shape mismatch: src (layers={src_pool.n_layers}, "
                 f"kv_dim={src_pool.kv_dim}) vs dst "
-                f"(layers={dst_pool.n_layers}, kv_dim={dst_pool.kv_dim})")
+                f"(layers={dst_pool.n_layers}, kv_dim={dst_pool.kv_dim})"
+                f" — the layers' declared buffers differ")
         axis = src_pool.axis_name
         copy_rows = min(src_pool.max_total, dst_pool.max_total)
-        s_spec = _shard_axis_of(src_pool.cache_spec, axis)
-        d_spec = _shard_axis_of(dst_pool.cache_spec, axis)
-        src_specs = [(src_pool.cache_spec, src_pool.cache_spec)
-                     for _ in range(src_pool.n_layers)]
-        dst_specs = [(dst_pool.cache_spec, dst_pool.cache_spec)
-                     for _ in range(dst_pool.n_layers)]
+        src_specs, dst_specs = src_pool.cache_specs, dst_pool.cache_specs
+
+        def move(src, dst, s_spec, d_spec, src_slot, dst_slot):
+            row = jax.lax.dynamic_index_in_dim(src, src_slot, axis=0,
+                                               keepdims=True)[:, :copy_rows]
+            # the portable redistribution primitive: identity while
+            # both pools shard the buffer's columns identically, the
+            # minimal accounted collective the moment they differ
+            row = reshard(row, _shard_axis_of(s_spec, axis),
+                          _shard_axis_of(d_spec, axis), axis)
+            return jax.lax.dynamic_update_slice(dst, row.astype(dst.dtype),
+                                                (dst_slot, 0, 0))
 
         def body(src_caches, dst_caches, src_slot, dst_slot):
-            out = []
-            for (ks, vs), (kd, vd) in zip(src_caches, dst_caches):
-                k_row = jax.lax.dynamic_index_in_dim(ks, src_slot, axis=0,
-                                                     keepdims=True)
-                v_row = jax.lax.dynamic_index_in_dim(vs, src_slot, axis=0,
-                                                     keepdims=True)
-                k_row = k_row[:, :copy_rows]
-                v_row = v_row[:, :copy_rows]
-                # the portable redistribution primitive: identity while
-                # both pools shard the KV columns identically, the
-                # minimal accounted collective the moment they differ
-                k_row = reshard(k_row, s_spec, d_spec, axis)
-                v_row = reshard(v_row, s_spec, d_spec, axis)
-                start = (dst_slot, 0, 0)
-                out.append(
-                    (jax.lax.dynamic_update_slice(
-                        kd, k_row.astype(kd.dtype), start),
-                     jax.lax.dynamic_update_slice(
-                        vd, v_row.astype(vd.dtype), start)))
-            return out
+            # every buffer each layer declares (a K/V pair, one latent)
+            return [tuple(move(s_, d_, ss, ds, src_slot, dst_slot)
+                          for s_, d_, ss, ds in zip(*layer))
+                    for layer in zip(src_caches, dst_caches, src_specs,
+                                     dst_specs)]
 
         return jax.jit(shard_map(
             body, mesh=src_pool.mesh,
@@ -326,16 +323,17 @@ class KvTransferPlane:
         if not (0 < int(length) <= src_pool.max_total):
             raise ValueError(f"pack length {length} out of range "
                              f"(0, {src_pool.max_total}]")
-        rows = []
-        for kc, vc in src_pool.caches:
-            rows.append((np.asarray(jax.device_get(kc[src_slot, :length])),
-                         np.asarray(jax.device_get(vc[src_slot, :length]))))
+        rows = [tuple(np.asarray(jax.device_get(buf[src_slot, :length]))
+                      for buf in layer) for layer in src_pool.caches]
         return pickle.dumps({
             "schema": WIRE_SCHEMA,
             "meta": dict(meta),
             "pos": int(length),
             "n_layers": src_pool.n_layers,
             "kv_dim": src_pool.kv_dim,
+            # what each layer keeps per token: (kv_dim, kv_dim) for a K/V
+            # pool, one latent width for a latent-attention layer
+            "widths": _widths(src_pool),
             "dtype": str(rows[0][0].dtype),
             # end-to-end integrity stamp (ISSUE 12): the receiver
             # recomputes this over the decoded rows and REFUSES a
@@ -383,28 +381,21 @@ class KvTransferPlane:
         from .._compat import shard_map
 
         key = (dst_pool.n_layers, dst_pool.n_slots, dst_pool.max_total,
-               dst_pool.kv_dim, str(dst_pool.caches[0][0].dtype),
+               tuple(_widths(dst_pool)), str(dst_pool.caches[0][0].dtype),
                id(dst_pool.mesh))
         prog = self._inject_programs.get(key)
         if prog is None:
-            dst_specs = [(dst_pool.cache_spec, dst_pool.cache_spec)
-                         for _ in range(dst_pool.n_layers)]
+            dst_specs = dst_pool.cache_specs
             # a slab row is the cache row minus the slot dim: same
             # column sharding, one rank lower
-            row_spec = P(*tuple(dst_pool.cache_spec)[1:])
-            slab_specs = [(row_spec, row_spec)
-                          for _ in range(dst_pool.n_layers)]
+            slab_specs = [tuple(P(*tuple(spec)[1:]) for spec in layer)
+                          for layer in dst_specs]
 
             def body(dst_caches, slabs, dst_slot):
-                out = []
-                for (kd, vd), (ks, vs) in zip(dst_caches, slabs):
-                    start = (dst_slot, 0, 0)
-                    out.append(
-                        (jax.lax.dynamic_update_slice(
-                            kd, ks[None].astype(kd.dtype), start),
-                         jax.lax.dynamic_update_slice(
-                            vd, vs[None].astype(vd.dtype), start)))
-                return out
+                return jax.tree_util.tree_map(
+                    lambda d, slab: jax.lax.dynamic_update_slice(
+                        d, slab[None].astype(d.dtype), (dst_slot, 0, 0)),
+                    dst_caches, slabs)
 
             prog = self._inject_programs[key] = jax.jit(shard_map(
                 body, mesh=dst_pool.mesh,
@@ -439,7 +430,9 @@ class KvTransferPlane:
                 f"{data.get('schema')!r} (this receiver speaks "
                 f"{WIRE_SCHEMA})")
         if data["n_layers"] != dst_pool.n_layers \
-                or data["kv_dim"] != dst_pool.kv_dim:
+                or data["kv_dim"] != dst_pool.kv_dim \
+                or [tuple(w) for w in data.get(
+                    "widths", _widths(dst_pool))] != _widths(dst_pool):
             raise ValueError(
                 f"slab shape mismatch: wire (layers={data['n_layers']}, "
                 f"kv_dim={data['kv_dim']}) vs pool "
@@ -462,22 +455,24 @@ class KvTransferPlane:
         prog = self.inject_program(dst_pool)
         # pad each layer's rows to the pool row (rows above ``length``
         # are stale-but-unreachable, the standard masking argument)
-        slabs = []
         dt = dst_pool.caches[0][0].dtype
-        for k, v in data["rows"]:
-            kp = np.zeros((dst_pool.max_total, dst_pool.kv_dim),
-                          np.asarray(k).dtype)
-            vp = np.zeros_like(kp)
-            kp[:length] = k
-            vp[:length] = v
-            slabs.append((jnp.asarray(kp.astype(dt)),
-                          jnp.asarray(vp.astype(dt))))
+
+        def padded(buf):
+            buf = np.asarray(buf)
+            out = np.zeros((dst_pool.max_total, buf.shape[1]), buf.dtype)
+            out[:length] = buf
+            return jnp.asarray(out.astype(dt))
+
+        slabs = [tuple(padded(buf) for buf in layer)
+                 for layer in data["rows"]]
         dst_pool.caches = prog(dst_pool.caches, slabs,
                                jnp.int32(dst_slot))
         dst_pool.pos[dst_slot] = length
 
-        nbytes = slab_nbytes(data["n_layers"], length, data["kv_dim"],
-                             data["dtype"])
+        # the raw bytes of the written rows of every declared buffer (a
+        # K/V pool: exactly ``slab_nbytes``)
+        nbytes = sum(np.asarray(buf).nbytes for layer in data["rows"]
+                     for buf in layer)
         ms = (time.monotonic() - t0) * 1e3
         self.transfers += 1
         self.lane_transfers += 1

@@ -236,6 +236,7 @@ def test_serving_prefill_and_tick(topo, as_tpu, shape):
     which a described chip cannot hold — so the program builders run on
     a bare instance given the same attributes."""
     from chainermn_tpu._compat import shard_map
+    from chainermn_tpu.parallel import blocks
     from chainermn_tpu.serving.engine import DecodeEngine
 
     n_heads, head_dim, n_slots, prompt, total = SERVING_SHAPES[shape]
@@ -247,6 +248,7 @@ def test_serving_prefill_and_tick(topo, as_tpu, shape):
                     NamedSharding(mesh, kv)),) * 2 for _ in range(N_LAYERS)]
     eng = DecodeEngine.__new__(DecodeEngine)
     eng.mesh, eng.axis_name, eng.head_dim = mesh, "model", head_dim
+    eng.arch, eng.n_counts = blocks.DEFAULT_ARCH, 0
     eng._specs, eng._shard_map, eng._P = specs, shard_map, P
     eng._cache_specs = [(kv, kv)] * N_LAYERS
     p = shaped(params, specs)
@@ -270,3 +272,89 @@ def test_serving_prefill_and_tick(topo, as_tpu, shape):
     # kernel, one call a layer: a vector Mosaic refused would fail here
     assert "%decode_attn_mha" in tick.as_text()
     assert tick.as_text().count("tpu_custom_call") >= N_LAYERS
+
+
+def test_latent_attention_and_expert_serving_programs(topo, as_tpu):
+    """The serving programs of a latent-attention / routed-expert model at
+    DeepSeek-V3's published widths (one dense and one expert layer, 16 of
+    256 experts held, 64 slots of 4096 rows: the benchmark's
+    ``deepseek-v3-ep16`` pool): the tick takes the absorbed flash-decode
+    kernel over the 640-column latent pool and the grouped expert product,
+    the prefill the flash forward kernel at 192/128 and the same product;
+    kernels and programs keep the names the trace readers match."""
+    from chainermn_tpu._compat import shard_map
+    from chainermn_tpu.parallel import blocks
+    from chainermn_tpu.parallel.blocks import LMArch, MLAConfig, MoEConfig
+    from chainermn_tpu.serving.engine import DecodeEngine
+
+    d, heads, q_rank, kv_rank, nope, rope, v = 7168, 128, 1536, 512, 128, 64, 128
+    inner, e_inner, experts, held, vocab = 18432, 2048, 256, 16, 16160
+    n_slots, total, prompt, layers = 64, 4096, 1024, 2
+    arch = LMArch(
+        norm="rmsnorm", norm_eps=1e-6, mlp="swiglu", attn="mla",
+        tied_head=False, embed_scale=False, layer_kinds=("dense", "moe"),
+        mla=MLAConfig(heads, q_rank, kv_rank, nope, rope, v, 10000.0,
+                      (40, 4096, 32, 1, 1, 1)),
+        moe=MoEConfig(experts, 8, 8, 4, 2.5, True, (0, held)))
+    mesh = Mesh(np.array(topo.devices[:1]), ("model",))
+    rep = NamedSharding(mesh, P())
+    bf = jnp.bfloat16
+
+    def gated(n_inner, lead=()):
+        return {"w_gate": _sds(lead + (d, n_inner), bf, rep),
+                "w_up": _sds(lead + (d, n_inner), bf, rep),
+                "w_down": _sds(lead + (n_inner, d), bf, rep)}
+
+    def block(kind):
+        out = {"ln1_scale": _sds((d,), bf, rep),
+               "ln2_scale": _sds((d,), bf, rep),
+               "attn": {"wdq": _sds((d, q_rank), bf, rep),
+                        "q_norm": _sds((q_rank,), bf, rep),
+                        "wuq": _sds((q_rank, heads * (nope + rope)), bf, rep),
+                        "wdkv": _sds((d, kv_rank + rope), bf, rep),
+                        "kv_norm": _sds((kv_rank,), bf, rep),
+                        "wukv": _sds((kv_rank, heads * (nope + v)), bf, rep),
+                        "wo": _sds((heads * v, d), bf, rep)}}
+        if kind == "dense":
+            out["mlp"] = gated(inner)
+        else:
+            out["moe"] = dict(gated(e_inner, (held,)),
+                              router=_sds((d, experts), bf, rep),
+                              router_bias=_sds((experts,), jnp.float32, rep),
+                              shared=gated(e_inner))
+        return out
+
+    table = NamedSharding(mesh, P("model", None))
+    p = {"embed": _sds((vocab, d), bf, table),
+         "head": _sds((vocab, d), bf, table),
+         "lnf_scale": _sds((d,), bf, rep),
+         "blocks": [block(k) for k in arch.layer_kinds]}
+    layout = blocks.cache_layout(arch, layers, 0, "model")
+    assert [[w for w, _ in bufs] for bufs in layout] == [[640]] * layers
+    caches = [tuple(_sds((n_slots, total, w), bf, NamedSharding(mesh, spec))
+                    for w, spec in bufs) for bufs in layout]
+    eng = DecodeEngine.__new__(DecodeEngine)
+    eng.mesh, eng.axis_name, eng.head_dim, eng.arch = mesh, "model", v, arch
+    eng.n_counts = blocks.n_count_entries(arch)
+    eng._specs = blocks.lm_specs(arch, p, "model")
+    eng._shard_map, eng._P = shard_map, P
+    eng._cache_specs = [tuple(spec for _, spec in bufs) for bufs in layout]
+
+    tick = eng._build_tick().lower(
+        p, caches, _sds((n_slots,), jnp.int32, rep),
+        _sds((n_slots,), jnp.int32, rep),
+        _sds((n_slots, 2), jnp.uint32, rep),
+        _sds((n_slots,), jnp.float32, rep),
+        _sds((n_slots,), jnp.bool_, rep)).compile().as_text()   # busy mask
+    assert "HloModule jit_serving_tick" in tick
+    assert tick.count("%decode_attn_mla") >= layers
+    assert tick.count("%moe_gmm") >= 3          # gate, up, down
+
+    prefill = eng._build_prefill(prompt).lower(
+        p, caches, _sds((1, prompt), jnp.int32, rep),
+        _sds((), jnp.int32, rep), _sds((), jnp.int32, rep),
+        _sds((2,), jnp.uint32, rep), _sds((), jnp.float32, rep)
+    ).compile().as_text()
+    assert f"HloModule jit_serving_prefill_{prompt}" in prefill
+    assert prefill.count("%flash_fwd") >= layers
+    assert prefill.count("%moe_gmm") >= 3
