@@ -344,16 +344,18 @@ def _build_prefix_copy() -> Dict[str, Any]:
                      mesh, "model")
     eng = DecodeEngine(params, pool, mesh, "model", head_dim=head_dim)
     jfn = eng._build_prefix_copy()
-    caches = pool.caches
 
     def run(c, src, dst):
         return jfn(c, src, dst)
 
+    # the program takes the pool DONATED (the arrays passed are deleted
+    # by the call): every executed variant gets buffers of its own
+    args0 = (pool.fresh_buffers(), jnp.int32(0), jnp.int32(1))
     variants = (jfn, [
-        (caches, jnp.int32(0), jnp.int32(1)),
-        (caches, jnp.int32(1), jnp.int32(0)),
+        args0,
+        (pool.fresh_buffers(), jnp.int32(1), jnp.int32(0)),
     ])
-    return {"trace": (run, (caches, jnp.int32(0), jnp.int32(1))),
+    return {"trace": (run, args0),
             "bound_axes": {"model"},
             "variants": variants,
             "data_axis": "model",
@@ -401,11 +403,16 @@ def _build_kv_transfer() -> Dict[str, Any]:
     def run(src_caches, dst_caches, src, dst):
         return jfn(src_caches, dst_caches, src, dst)
 
-    args0 = (staging.caches, decode.caches, jnp.int32(0), jnp.int32(1))
+    # the destination pool is donated (never the source): a destination
+    # of its own for every executed variant
+    args0 = (staging.caches, decode.fresh_buffers(), jnp.int32(0),
+             jnp.int32(1))
     variants = (jfn, [
         args0,
-        (staging.caches, decode.caches, jnp.int32(1), jnp.int32(2)),
-        (staging.caches, decode.caches, jnp.int32(0), jnp.int32(0)),
+        (staging.caches, decode.fresh_buffers(), jnp.int32(1),
+         jnp.int32(2)),
+        (staging.caches, decode.fresh_buffers(), jnp.int32(0),
+         jnp.int32(0)),
     ])
     return {"trace": (run, args0),
             "bound_axes": {"model"},
@@ -896,10 +903,12 @@ def _build_worker_lane() -> Dict[str, Any]:
     def run(caches, slabs, dst):
         return probe(caches, slabs, dst)
 
-    args0 = (pool.caches, slab, jnp.int32(0))
+    # the inject program takes the pool donated (not the slabs): buffers
+    # of its own for every executed variant
+    args0 = (pool.fresh_buffers(), slab, jnp.int32(0))
     variants = (probe, [
         args0,
-        (pool.caches, slab, jnp.int32(1)),
+        (pool.fresh_buffers(), slab, jnp.int32(1)),
     ])
     return {"trace": (run, args0),
             "bound_axes": {"model"},
@@ -968,7 +977,7 @@ class _KvSpillProbe:
                 got, pool, 1, ledger_op=SPILL_OP,
                 ledger_axis=SPILL_AXIS)
             want = transfer_cost(pool.n_layers, L, pool.kv_dim,
-                                 pool.caches[0][0].dtype, mode="lanes")
+                                 pool.dtype, mode="lanes")
             assert stats["ledger_bytes"] == want["ledger_bytes"], (
                 stats, want)
             # byte-exact round trip: the restored rows ARE the packed
@@ -1030,10 +1039,13 @@ def _build_kv_spill() -> Dict[str, Any]:
 
     slab = [(jnp.asarray(rng.randn(8, n_kv * head_dim).astype(dtype)),
              jnp.asarray(rng.randn(8, n_kv * head_dim).astype(dtype)))]
-    args0 = (pool.caches, slab, jnp.int32(0))
+    # the probe's own round trip goes through the pool (which threads
+    # its donated buffers itself); the inject call it then makes on the
+    # variant's operands gets buffers of its own
+    args0 = (pool.fresh_buffers(), slab, jnp.int32(0))
     variants = (probe, [
         args0,
-        (pool.caches, slab, jnp.int32(1)),
+        (pool.fresh_buffers(), slab, jnp.int32(1)),
     ])
     return {"trace": (run, args0),
             "bound_axes": {"model"},
@@ -1086,8 +1098,7 @@ class _RemotePullProbe:
             stats = self._plane.unpack_into(got, self._dst, slot)
             want = transfer_cost(self._dst.n_layers, L,
                                  self._dst.kv_dim,
-                                 self._dst.caches[0][0].dtype,
-                                 mode="lanes")
+                                 self._dst.dtype, mode="lanes")
             assert stats["ledger_bytes"] == want["ledger_bytes"], (
                 stats, want)
             self._dst.commit_reservation(slot)
@@ -1135,10 +1146,12 @@ def _build_remote_pull() -> Dict[str, Any]:
     def run(caches, slabs, dst_slot):
         return probe(caches, slabs, dst_slot)
 
-    args0 = (dst.caches, slab, jnp.int32(0))
+    # as in the spill entry: the pull lands through ``dst`` itself, the
+    # variant's own inject call takes buffers of its own
+    args0 = (dst.fresh_buffers(), slab, jnp.int32(0))
     variants = (probe, [
         args0,
-        (dst.caches, slab, jnp.int32(1)),
+        (dst.fresh_buffers(), slab, jnp.int32(1)),
     ])
     return {"trace": (run, args0),
             "bound_axes": {"model"},

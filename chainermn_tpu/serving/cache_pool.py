@@ -13,6 +13,13 @@ nothing re-jits: the tick program's operand shapes are fixed for the
 pool's lifetime, which is the whole point — a freed slot is recycled by
 the NEXT prefill while the other slots keep decoding.
 
+The buffers have a single owner, the pool: every program that returns
+them (tick, prefill, prefix copy, the transfer plane's two) takes them
+donated, so its ``dynamic_update_slice`` lands in place instead of on a
+copy of every buffer, and the arrays bound before the call are deleted by
+it.  ``CachePool.update`` / ``CachePool.read`` are the only way a program
+reaches them — read, launch and rebind under one lock.
+
 Correctness of recycling without zeroing: a slot's rows ``> pos`` may
 hold a previous occupant's K/V, but every attention read is masked to
 the occupant's own prefix ``[0, pos]``, and row ``p`` is written by the
@@ -62,6 +69,7 @@ ref pins a slot forever, silently shrinking the pool.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import threading
 from typing import Dict, List, Optional
@@ -272,6 +280,17 @@ class CachePool:
     buffer.  Prefix copy, spill and transfer walk the same declaration.
     ``pos`` lives HOST-side as numpy (the scheduler reads/writes it every
     tick; shipping it to device happens once per tick as a tiny operand).
+
+    The buffers have ONE owner, this pool.  Every compiled program that
+    returns them takes them DONATED and writes in place, so the arrays
+    bound before a call are deleted by it; nobody keeps a reference to
+    ``caches`` across a call.  A program call goes through :meth:`update`
+    (it replaces the buffers) or :meth:`read` (it only reads them): both
+    do "read ``caches`` → launch → bind the result" under the pool's
+    lock, so two threads on one pool (the disaggregated fleet's role
+    drivers) can never hand a program buffers the other has just given
+    away.  ``calls`` / ``calls_donated`` count the updates and those that
+    did delete what they were given (``serving/pool_calls*``).
     """
 
     def __init__(self, n_slots: int, max_total: int, n_layers: int,
@@ -305,13 +324,14 @@ class CachePool:
                             for bufs in self.layout]
         # the first buffer's spec: what a K/V pool's every buffer has
         self.cache_spec = self.layout[0][0][1]
-        self.caches = [
-            tuple(jax.device_put(
-                jnp.zeros((self.n_slots, self.max_total, w), dtype),
-                NamedSharding(mesh, spec)) for w, spec in bufs)
-            for bufs in self.layout]
+        self.dtype = jnp.dtype(dtype)
+        self.caches = self.fresh_buffers()
+        # held for a program's LAUNCH only (dispatch is asynchronous)
+        self._buffers_lock = threading.Lock()
+        self.calls = 0           # updates: program calls that returned
+        self.calls_donated = 0   # the buffers; those that deleted theirs
         #: bytes one token keeps across all layers (whole model axis)
-        self.bytes_per_token = jnp.dtype(dtype).itemsize * sum(
+        self.bytes_per_token = self.dtype.itemsize * sum(
             w for bufs in self.layout for w, _ in bufs)
         # host-side per-slot NEXT-WRITE position (== sequence length so
         # far).  The tick runs EVERY slot (one fixed program) but only a
@@ -322,6 +342,45 @@ class CachePool:
         # stays safe by the module-docstring argument: the next occupant
         # rewrites row p before its own pos reaches p.
         self.pos = np.zeros(self.n_slots, np.int32)
+
+    def fresh_buffers(self):
+        """A zeroed caches pytree as the layout declares it, placed like
+        the pool's own (what ``__init__`` binds; the analysis entries
+        give each call variant of a donating program its own)."""
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import NamedSharding
+
+        return [
+            tuple(jax.device_put(
+                jnp.zeros((self.n_slots, self.max_total, w), self.dtype),
+                NamedSharding(self.mesh, spec)) for w, spec in bufs)
+            for bufs in self.layout]
+
+    def update(self, launch, *sources):
+        """Run a program that RETURNS the buffers: ``launch(caches,
+        *source_caches)`` dispatches it and gives ``(result, new
+        caches)``; the new buffers are bound and ``result`` returned.
+        ``sources`` are pools the same program only reads (a transfer's
+        staging pool): their locks are held too, in one global order."""
+        with contextlib.ExitStack() as held:
+            for p in sorted({id(p): p for p in (self,) + sources}.values(),
+                            key=id):
+                held.enter_context(p._buffers_lock)
+            old = self.caches
+            result, self.caches = launch(old, *(p.caches for p in sources))
+            self.calls += 1
+            # one leaf says it (O(1), no wait on the device): a backend
+            # that declines the donation copies, and shows here
+            self.calls_donated += bool(old[0][0].is_deleted())
+        return result
+
+    def read(self, launch):
+        """Run something that only READS the buffers (a slice for the
+        wire, a spill): ``launch(caches)`` under the lock, so no update
+        deletes them between the read and the dispatch."""
+        with self._buffers_lock:
+            return launch(self.caches)
 
     def busy_mask(self):
         """``(n_slots,) bool``: the slots a request holds right now."""

@@ -9,7 +9,8 @@ programs driven from the host, one tick at a time:
 * **prefill_into_slot** — full-prompt forward (``lm_prefill``), greedy
   first token from the LAST REAL prompt position, and a
   ``dynamic_update_slice`` of the prompt's K/V slab into the target
-  slot's rows of the pool.  Compiled once per padded prompt length.
+  slot's rows of the pool, which the program takes DONATED: the slab
+  lands in place.  Compiled once per padded prompt length.
   ``prefill_bucket > 1`` right-pads prompts to bucket multiples to
   bound the number of compiles under mixed lengths: causal attention
   never lets a real token see a pad, and pad rows in the cache sit
@@ -21,7 +22,8 @@ programs driven from the host, one tick at a time:
   ``lm_generate``.
 * **tick** — one token for EVERY slot (``lm_decode_tick`` with the
   per-row position vector + ``_greedy_token``), caches appended in
-  place per row.  Compiled ONCE for the pool's lifetime: admission and
+  place per row (the pool donated: no buffer is copied to be written).
+  Compiled ONCE for the pool's lifetime: admission and
   eviction change only the host-side position/token vectors, never the
   program.  On a TPU its attention is the flash-decode kernel
   ``lm_generate`` decodes through (``ops/decode_attention.py``), given
@@ -55,7 +57,9 @@ from ..observability import trace as _trace
 class DecodeEngine:
     """Device half of the serving engine: owns the sharded params and the
     compiled prefill/tick programs; the :class:`~chainermn_tpu.serving
-    .cache_pool.CachePool` owns the buffers the programs thread through.
+    .cache_pool.CachePool` owns the buffers the programs thread through,
+    alone: every call here goes through ``pool.update``, which hands the
+    program the buffers donated and binds the ones it returns.
 
     ``params`` are GLOBAL arrays in ``init_tp_transformer_lm`` layout —
     or, with ``arch`` (a ``parallel.blocks.LMArch``), in the layout that
@@ -146,12 +150,14 @@ class DecodeEngine:
                                   axis, keys, temps, pos + 1)
             return _with_routing(nxt, routing), new_caches
 
-        # a model with experts takes the busy mask as a fifth vector
+        # a model with experts takes the busy mask as a fifth vector.
+        # The pool is DONATED to every program that returns it: the row
+        # write lands in place (un-donated, XLA copied every buffer first)
         return jax.jit(self._shard_map(
             serving_tick, mesh=self.mesh,
             in_specs=(self._specs, self._cache_specs)
             + (P(),) * (5 if self.n_counts else 4),
-            out_specs=(P(), self._cache_specs)))
+            out_specs=(P(), self._cache_specs)), donate_argnums=(1,))
 
     def _build_prefill(self, s_pad: int):
         import jax
@@ -191,7 +197,7 @@ class DecodeEngine:
             prefill_inner, mesh=self.mesh,
             in_specs=(self._specs, self._cache_specs, P(), P(), P(), P(),
                       P()),
-            out_specs=(P(), self._cache_specs)))
+            out_specs=(P(), self._cache_specs)), donate_argnums=(1,))
 
     def _build_prefix_copy(self):
         """Slot-to-slot cache slab copy — the prefix cache's copy-on-
@@ -217,7 +223,7 @@ class DecodeEngine:
         return jax.jit(self._shard_map(
             serving_prefix_copy, mesh=self.mesh,
             in_specs=(self._cache_specs, P(), P()),
-            out_specs=self._cache_specs))
+            out_specs=self._cache_specs), donate_argnums=(0,))
 
     # ---- serving faces (host-driven, one call per engine iteration) ----
     def padded_len(self, s_real: int) -> int:
@@ -265,8 +271,8 @@ class DecodeEngine:
                         jnp.int32(slot), jnp.asarray(key),
                         jnp.float32(temperature))
         with _trace.span("serving/prefill/dispatch", cat="serving"):
-            tok, self.pool.caches = prog(self._params, self.pool.caches,
-                                         *operands)
+            tok = self.pool.update(
+                lambda caches: prog(self._params, caches, *operands))
         self.pool.pos[slot] = s_real
         with _trace.span("serving/prefill/readback", cat="serving"):
             out = np.asarray(tok)
@@ -282,11 +288,12 @@ class DecodeEngine:
         ``dst_slot`` and set ``pool.pos[dst_slot] = prefix_len`` so the
         occupant's next write lands at the first un-cached position.
         The source slot is READ-ONLY shared state (refcounted by the
-        prefix cache); jax arrays are immutable, so the 'copy' is a
-        functional update producing new pool caches — the cached rows
-        can never be corrupted by the reader.  One compiled program for
-        the pool's lifetime (asserted by the ``serving.prefix_copy``
-        analysis entry point)."""
+        prefix cache).  The program takes the pool donated and writes
+        the destination slot's rows in place; the source slot's rows
+        are read before the write and are no part of it, so the cached
+        rows stay as they were (``src == dst`` rewrites a row with
+        itself).  One compiled program for the pool's lifetime (asserted
+        by the ``serving.prefix_copy`` analysis entry point)."""
         import jax.numpy as jnp
 
         if not (0 < int(prefix_len) <= self.pool.max_total):
@@ -298,8 +305,9 @@ class DecodeEngine:
             from ..observability import flight as _flight
             _flight.note("compile", program="serving_prefix_copy")
         self.prefix_copies += 1
-        self.pool.caches = self._prefix_copy_prog(
-            self.pool.caches, jnp.int32(src_slot), jnp.int32(dst_slot))
+        src, dst = jnp.int32(src_slot), jnp.int32(dst_slot)
+        self.pool.update(
+            lambda caches: (None, self._prefix_copy_prog(caches, src, dst)))
         self.pool.pos[dst_slot] = int(prefix_len)
 
     def tick(self, last_tokens: np.ndarray, keys=None,
@@ -333,8 +341,9 @@ class DecodeEngine:
             operands = (tokens, pos, keys, temps) + (
                 (jnp.asarray(busy),) if self.n_counts else ())
         with _trace.span("serving/tick/dispatch", cat="serving"):
-            nxt, self.pool.caches = self._tick_prog(
-                self._params, self.pool.caches, *operands)
+            nxt = self.pool.update(
+                lambda caches: self._tick_prog(self._params, caches,
+                                               *operands))
         self.pool.advance(busy)   # out-of-place: never mutate a buffer
         #                           jax might still read
         with _trace.span("serving/tick/readback", cat="serving"):

@@ -1015,6 +1015,7 @@ class ServingEngine:
             self._tick_cache_rows_live = 0
             self.engine.moe_counts_tick[:] = 0
             self.engine.moe_counts_prefill[:] = 0
+            self.pool.calls = self.pool.calls_donated = 0
             self.goodput.reset()
             self._last_step_end = None
             self._slo_last = (0, self._t0)
@@ -1089,6 +1090,13 @@ class ServingEngine:
                 "serving/cache_bytes_per_token": float(
                     self.pool.bytes_per_token),
                 "serving/tick_calls": float(self.engine.tick_calls),
+                # program calls that returned the pool's buffers (ticks,
+                # prefills, prefix copies, landed slabs), and those after
+                # which the buffers passed were deleted: donated, so
+                # written in place and not copied first
+                "serving/pool_calls": float(self.pool.calls),
+                "serving/pool_calls_donated": float(
+                    self.pool.calls_donated),
                 "serving/slot_occupancy_pct": 100.0 * (
                     self._occupancy_sum / self._ticks if self._ticks
                     else 0.0),

@@ -214,7 +214,7 @@ class KvTransferPlane:
     def _local_key(self, src_pool, dst_pool):
         def sig(pool):
             return (pool.n_layers, pool.n_slots, pool.max_total,
-                    tuple(_widths(pool)), str(pool.caches[0][0].dtype))
+                    tuple(_widths(pool)), str(pool.dtype))
         return (sig(src_pool), sig(dst_pool), id(src_pool.mesh),
                 id(dst_pool.mesh), src_pool.axis_name)
 
@@ -262,7 +262,7 @@ class KvTransferPlane:
         return jax.jit(shard_map(
             body, mesh=src_pool.mesh,
             in_specs=(src_specs, dst_specs, P(), P()),
-            out_specs=dst_specs))
+            out_specs=dst_specs), donate_argnums=(1,))   # dst, never src
 
     def local_program(self, src_pool, dst_pool):
         """The compiled (src-pool, dst-pool) transfer program — cached;
@@ -292,8 +292,10 @@ class KvTransferPlane:
                 f"{dst_pool.max_total})")
         prog = self.local_program(src_pool, dst_pool)
         t0 = time.monotonic()
-        dst_pool.caches = prog(src_pool.caches, dst_pool.caches,
-                               jnp.int32(src_slot), jnp.int32(dst_slot))
+        src, dst = jnp.int32(src_slot), jnp.int32(dst_slot)
+        dst_pool.update(
+            lambda dst_caches, src_caches: (
+                None, prog(src_caches, dst_caches, src, dst)), src_pool)
         dst_pool.pos[dst_slot] = int(length)
         ms = (time.monotonic() - t0) * 1e3
         self.transfers += 1
@@ -301,7 +303,7 @@ class KvTransferPlane:
         axis = src_pool.axis_name
         cost = transfer_cost(
             src_pool.n_layers, length, src_pool.kv_dim,
-            src_pool.caches[0][0].dtype, mode="local",
+            src_pool.dtype, mode="local",
             axis_size=src_pool.mesh.shape[axis],
             src_spec=_shard_axis_of(src_pool.cache_spec, axis),
             dst_spec=_shard_axis_of(dst_pool.cache_spec, axis),
@@ -323,8 +325,13 @@ class KvTransferPlane:
         if not (0 < int(length) <= src_pool.max_total):
             raise ValueError(f"pack length {length} out of range "
                              f"(0, {src_pool.max_total}]")
-        rows = [tuple(np.asarray(jax.device_get(buf[src_slot, :length]))
-                      for buf in layer) for layer in src_pool.caches]
+        # the slices are dispatched under the pool's lock (an update may
+        # delete the buffers right after); the copy to the host is not
+        rows = src_pool.read(lambda caches: [
+            tuple(buf[src_slot, :length] for buf in layer)
+            for layer in caches])
+        rows = [tuple(np.asarray(jax.device_get(buf)) for buf in layer)
+                for layer in rows]
         return pickle.dumps({
             "schema": WIRE_SCHEMA,
             "meta": dict(meta),
@@ -381,7 +388,7 @@ class KvTransferPlane:
         from .._compat import shard_map
 
         key = (dst_pool.n_layers, dst_pool.n_slots, dst_pool.max_total,
-               tuple(_widths(dst_pool)), str(dst_pool.caches[0][0].dtype),
+               tuple(_widths(dst_pool)), str(dst_pool.dtype),
                id(dst_pool.mesh))
         prog = self._inject_programs.get(key)
         if prog is None:
@@ -400,7 +407,7 @@ class KvTransferPlane:
             prog = self._inject_programs[key] = jax.jit(shard_map(
                 body, mesh=dst_pool.mesh,
                 in_specs=(dst_specs, slab_specs, P()),
-                out_specs=dst_specs))
+                out_specs=dst_specs), donate_argnums=(0,))   # not the slabs
             from ..observability import flight as _flight
             _flight.note("compile", program="serving_kv_inject")
         return prog
@@ -455,7 +462,7 @@ class KvTransferPlane:
         prog = self.inject_program(dst_pool)
         # pad each layer's rows to the pool row (rows above ``length``
         # are stale-but-unreachable, the standard masking argument)
-        dt = dst_pool.caches[0][0].dtype
+        dt = dst_pool.dtype
 
         def padded(buf):
             buf = np.asarray(buf)
@@ -465,8 +472,8 @@ class KvTransferPlane:
 
         slabs = [tuple(padded(buf) for buf in layer)
                  for layer in data["rows"]]
-        dst_pool.caches = prog(dst_pool.caches, slabs,
-                               jnp.int32(dst_slot))
+        slot = jnp.int32(dst_slot)
+        dst_pool.update(lambda caches: (None, prog(caches, slabs, slot)))
         dst_pool.pos[dst_slot] = length
 
         # the raw bytes of the written rows of every declared buffer (a

@@ -201,7 +201,7 @@ class WorkerRuntime:
         worker's prefixes in token units (transfer_cost statics)."""
         pool = self.engine.pool
         return {"n_layers": pool.n_layers, "kv_dim": pool.kv_dim,
-                "dtype": str(pool.caches[0][0].dtype),
+                "dtype": str(pool.dtype),
                 "model_id": self.model_id}
 
     def _announce_insert(self, entry) -> None:

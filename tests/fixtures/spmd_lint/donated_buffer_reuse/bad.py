@@ -19,3 +19,10 @@ def stale_loop_reuse(params, batches):
 def cache_pool_attribute(pool, batch):
     out = step(pool.caches, batch)  # the serving cache-pool hazard:
     return out, pool.caches         # pool row donated, then read
+
+
+def pool_method_launch_reads_back(pool, batch):
+    def launch(caches):
+        new = step(caches, batch)   # the pool's buffers donated...
+        return caches[0].sum(), new  # ...and read inside the launch
+    return pool.update(launch)

@@ -26,3 +26,11 @@ def exclusive_branches(params, batch, on_device):
 def pool_row_rebound(pool, batch):
     pool.caches = step(pool.caches, batch)  # attribute rebinding
     return pool.caches              # consumes the donation
+
+
+def pool_method(pool, batch):
+    # the cache pool's own face (serving/cache_pool.py::CachePool.update):
+    # the launch is handed the buffers, donates them, and the pool binds
+    # what comes back — no name outlives the call
+    pool.update(lambda caches: (None, step(caches, batch)))
+    return pool.caches

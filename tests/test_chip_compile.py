@@ -223,6 +223,18 @@ def test_lm_135m_train_step_in_shard_map(topo, as_tpu):
             < 16 * 2 ** 30)      # fits one v5e chip's HBM
 
 
+def _assert_pool_written_in_place(text: str, pool_shape) -> None:
+    """The program takes the cache pool donated: its result aliases the
+    argument and no buffer of the pool's shape is copied to be written
+    (un-donated, every K, V or latent buffer was: 48 a gpt2-medium tick)."""
+    import re
+
+    assert "input_output_alias" in text
+    dims = ",".join(str(n) for n in pool_shape)
+    copies = re.findall(rf"= bf16\[{dims}\]\S* copy\(", text)
+    assert not copies, f"{len(copies)} pool-sized copies left"
+
+
 # (heads, head_dim, slots, prompt, total): the LM width chip_smoke runs,
 # and gpt2-medium's heads in the serving cell's pool (BENCHMARK.json)
 SERVING_SHAPES = {"8x128": (N_HEADS, HEAD_DIM, 4, 512, 512 + 64),
@@ -261,6 +273,8 @@ def test_serving_prefill_and_tick(topo, as_tpu, shape):
     assert prefill.as_text().count("tpu_custom_call") >= N_LAYERS
     assert f"HloModule jit_serving_prefill_{prompt}" in prefill.as_text()
     assert "%flash_fwd" in prefill.as_text()
+    _assert_pool_written_in_place(prefill.as_text(),
+                                  (n_slots, total, D_MODEL))
 
     tick = eng._build_tick().lower(
         p, caches, _sds((n_slots,), jnp.int32, rep),
@@ -272,6 +286,7 @@ def test_serving_prefill_and_tick(topo, as_tpu, shape):
     # kernel, one call a layer: a vector Mosaic refused would fail here
     assert "%decode_attn_mha" in tick.as_text()
     assert tick.as_text().count("tpu_custom_call") >= N_LAYERS
+    _assert_pool_written_in_place(tick.as_text(), (n_slots, total, D_MODEL))
 
 
 def test_latent_attention_and_expert_serving_programs(topo, as_tpu):
@@ -349,6 +364,7 @@ def test_latent_attention_and_expert_serving_programs(topo, as_tpu):
     assert "HloModule jit_serving_tick" in tick
     assert tick.count("%decode_attn_mla") >= layers
     assert tick.count("%moe_gmm") >= 3          # gate, up, down
+    _assert_pool_written_in_place(tick, (n_slots, total, 640))
 
     prefill = eng._build_prefill(prompt).lower(
         p, caches, _sds((1, prompt), jnp.int32, rep),
@@ -358,3 +374,4 @@ def test_latent_attention_and_expert_serving_programs(topo, as_tpu):
     assert f"HloModule jit_serving_prefill_{prompt}" in prefill
     assert prefill.count("%flash_fwd") >= layers
     assert prefill.count("%moe_gmm") >= 3
+    _assert_pool_written_in_place(prefill, (n_slots, total, 640))

@@ -435,6 +435,123 @@ def test_tick_cache_block_counters(devices):
     eng.close()
 
 
+def _leaves(caches):
+    import jax
+
+    return jax.tree_util.tree_leaves(caches)
+
+
+@pytest.mark.parametrize("program", ["prefill", "tick", "prefix_copy"])
+def test_pool_is_donated_to_every_program_that_returns_it(devices, program):
+    """The pool's buffers have one owner: the prefill, the tick and the
+    prefix copy take them DONATED, so the arrays bound before the call
+    are deleted by it (a stale reader fails, it does not get a copy) and
+    ``pool.caches`` holds live ones.  The prefix copy reads its source
+    slot out of the very buffer it writes: the source rows stay."""
+    from chainermn_tpu.serving import ServingEngine
+
+    eng = ServingEngine(_params(pos_impl="rope"), head_dim=HEAD_DIM,
+                        n_slots=3, max_total=16, mesh=_mesh(devices, 2))
+    dec, pool = eng.engine, eng.pool
+    prompt = np.arange(1, 7, dtype=np.int32)
+    slot = pool.acquire()
+    dec.prefill_into_slot(prompt, slot)
+    dec.tick(np.zeros(pool.n_slots, np.int32))
+    src_rows = [np.asarray(buf[slot]) for buf in _leaves(pool.caches)]
+    assert any(r.any() for r in src_rows)       # the slot holds real K/V
+
+    old = _leaves(pool.caches)
+    if program == "prefill":
+        dec.prefill_into_slot(prompt[:4], pool.acquire())
+    elif program == "tick":
+        dec.tick(np.zeros(pool.n_slots, np.int32))
+    else:
+        dst = pool.acquire()
+        dec.copy_prefix(slot, dst, 6)
+        for buf, want in zip(_leaves(pool.caches), src_rows):
+            np.testing.assert_array_equal(np.asarray(buf[slot]), want)
+            np.testing.assert_array_equal(np.asarray(buf[dst]), want)
+    new = _leaves(pool.caches)
+    assert all(x.is_deleted() for x in old)
+    assert not any(x.is_deleted() for x in new)
+    with pytest.raises(RuntimeError, match="deleted"):
+        np.asarray(old[0])
+    eng.close()
+
+
+def test_pool_update_is_safe_under_concurrent_callers(devices):
+    """``CachePool.update`` / ``read`` do "read the buffers → launch → bind
+    the result" under the pool's lock: more threads than cores hammering
+    one pool with a donating program (and readers slicing it) lose no
+    update and never hand a program buffers another call has given away."""
+    import threading
+
+    import jax
+
+    from chainermn_tpu.serving.cache_pool import CachePool
+
+    pool = CachePool(2, 4, 1, 8, np.float32, _mesh(devices, 1))
+    bump = jax.jit(lambda caches: jax.tree_util.tree_map(
+        lambda c: c + 1, caches), donate_argnums=(0,))
+    n_threads, per_thread = 16, 40
+    errors = []
+
+    def writer():
+        try:
+            for _ in range(per_thread):
+                pool.update(lambda caches: (None, bump(caches)))
+                pool.read(
+                    lambda caches: caches[0][0][0, 0]).block_until_ready()
+        except Exception as e:  # noqa: BLE001 - reported below
+            errors.append(repr(e))
+
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=writer) for _ in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(was)
+    assert not any(t.is_alive() for t in threads)
+    assert errors == []
+    assert pool.calls == pool.calls_donated == n_threads * per_thread
+    for buf in _leaves(pool.caches):
+        assert float(np.asarray(buf).min()) == n_threads * per_thread
+
+
+def test_pool_call_counters(devices):
+    """``serving/pool_calls`` counts the program calls that returned the
+    pool's buffers, ``serving/pool_calls_donated`` those that deleted the
+    ones they were given: equal when every call was donated (a backend
+    that declines would read 0, not copy silently).  Tokens stay those of
+    ``lm_generate``; ``reset_stats()`` zeroes both."""
+    from chainermn_tpu.serving import ServingEngine
+
+    params, mesh = _params(), _mesh(devices, 2)
+    eng = ServingEngine(params, head_dim=HEAD_DIM, n_slots=2, max_total=32,
+                        mesh=mesh, queue_capacity=8)
+    m = eng.metrics()
+    assert m["serving/pool_calls"] == m["serving/pool_calls_donated"] == 0.0
+    rng = np.random.RandomState(11)
+    prompts = [rng.randint(0, VOCAB, n).astype(np.int32) for n in (5, 6, 4)]
+    handles = [eng.submit(p, 5) for p in prompts]
+    eng.run(steps_budget=100)
+    for p, h in zip(prompts, handles):
+        assert h.tokens == _oracle(params, mesh, p, 5).tolist()
+    dec = eng.engine
+    m = eng.metrics()
+    assert m["serving/pool_calls"] == float(
+        dec.tick_calls + dec.prefill_calls + dec.prefix_copies) > 0.0
+    assert m["serving/pool_calls_donated"] == m["serving/pool_calls"]
+    eng.reset_stats()
+    m = eng.metrics()
+    assert m["serving/pool_calls"] == m["serving/pool_calls_donated"] == 0.0
+    eng.close()
+
+
 # ---------------------------------------------------------------------------
 # CLI
 # ---------------------------------------------------------------------------

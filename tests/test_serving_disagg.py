@@ -274,6 +274,47 @@ def test_transfer_exactness_fuzz(devices, transport):
         fleet.close()
 
 
+@pytest.mark.parametrize("transport", ["local", "lanes"])
+def test_role_parallel_drive_shares_a_donated_staging_pool(devices,
+                                                           transport):
+    """The role-parallel drive: the prefill thread dispatches (donating)
+    prefills into a staging pool while the decode thread reads that pool
+    for a transfer (local) or the prefill thread packs it while ticks run
+    (lanes).  Every program call takes the pool's lock for its launch, so
+    a few hundred transfers run with no "Array has been deleted" — which,
+    un-locked, the donation turns the old silent race into."""
+    from chainermn_tpu.serving import build_disagg_fleet
+
+    params = _params(pos_impl="rope", n_kv_heads=2)
+    mesh = _mesh(devices, 1)
+    fleet = build_disagg_fleet(
+        params, 1, 1, head_dim=HEAD_DIM, max_total=16, n_slots=4,
+        staging_slots=3, mesh=mesh, queue_capacity=512,
+        transport_mode=transport)
+    n = 200
+    rng = np.random.RandomState(9)
+    prompts = [rng.randint(0, VOCAB, rng.randint(3, 7)).astype(np.int32)
+               for _ in range(n)]
+    try:
+        fleet.start()
+        handles = [fleet.submit(p, 2) for p in prompts]
+        for h in handles:
+            assert h.wait(120), "the drive stalled (a role driver died?)"
+        fleet.stop()
+        assert not fleet._threads            # both drivers joined
+        assert all(h.status == "done" for h in handles)
+        for i in (0, n // 2, n - 1):
+            assert handles[i].tokens == _oracle(params, mesh, prompts[i], 2)
+        m = fleet.metrics()
+        assert m["disagg/transfers_total"] >= float(n)
+        for pool in ([pw.pool for pw in fleet.prefill_workers]
+                     + [dw.engine.pool for dw in fleet.decode_workers]):
+            assert pool.calls_donated == pool.calls > 0
+        _drained(fleet)
+    finally:
+        fleet.close()
+
+
 def test_sampling_token_exact_vs_lm_generate(devices):
     """The ISSUE 9 sampling satellite: per-request rng/temperature ride
     ``Request`` through the shared decode tick, and a sampled request
