@@ -36,8 +36,8 @@ The collective surface is *derived*, not hardcoded: ``registry.py`` parses
 linted the day they land.
 
 Runners: ``python -m chainermn_tpu.analysis <paths>`` and
-``scripts/lint_spmd.py`` (exit 0 clean / 1 findings / 2 unusable — the
-``check_perf_regression.py`` contract).  Accepted findings live in the
+``scripts/lint_spmd.py`` (exit 0 clean / 1 findings / 2 unusable).
+Accepted findings live in the
 checked-in baseline (``.spmd-lint-baseline.json``); one-off exceptions use
 ``# spmd-lint: disable=<rule>`` inline.  See docs/ANALYSIS.md.
 

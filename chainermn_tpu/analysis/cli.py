@@ -1,6 +1,6 @@
 """Runner: ``python -m chainermn_tpu.analysis`` / ``scripts/lint_spmd.py``.
 
-Exit-code contract (same as ``scripts/check_perf_regression.py``):
+Exit-code contract:
 
 * **0** — clean: no findings beyond the checked-in baseline;
 * **1** — findings: at least one non-baselined finding (any severity);

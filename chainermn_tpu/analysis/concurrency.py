@@ -1043,8 +1043,8 @@ def find_concurrency_baseline(start: Optional[str] = None
 
 def main(argv: Optional[List[str]] = None) -> int:
     """Concurrency-lint runner.  Exit contract: 0 = clean modulo
-    baseline, 1 = findings, 2 = unusable inputs (the
-    ``check_perf_regression.py`` / ``lint_spmd.py`` contract)."""
+    baseline, 1 = findings, 2 = unusable inputs (the ``lint_spmd.py``
+    contract)."""
     import argparse
     import json
     import sys

@@ -26,7 +26,7 @@ from typing import FrozenSet, Optional
 #: per-rank queries, and the static cost-model faces.  Everything else
 #: public in that module is treated as a collective.  (axis_index/
 #: axis_size read topology, they don't sync; the *_cost functions are
-#: pure arithmetic the shard-flow analyzer and bench share.)
+#: pure arithmetic the shard-flow analyzer reads.)
 _NON_COLLECTIVE_OPS = frozenset({
     "zeros_like_vma", "axis_index", "axis_size",
     "collective_wire_cost", "quantized_ring_cost",

@@ -38,10 +38,9 @@ chunk's (same global elements), so staging can never smuggle wrong
 bytes.
 
 Everything here is stdlib + numpy; no jax import (the analysis-package
-contract).  Cost constants are the ``project_dp_scaling``
-assumptions in ``bench.py`` (v5e ICI 1.8e11 B/s, 1 µs/hop; DCN 2.5e10
-B/s per host) — the schedule chooser and the scaling projection price
-the same wire.
+contract).  The wire's cost constants (v5e ICI 1.8e11 B/s, 1 µs/hop;
+DCN 2.5e10 B/s per host) have their one home here, in
+:class:`CostModel`.
 """
 
 from __future__ import annotations
@@ -639,7 +638,7 @@ def candidate_schedules(shape, dtype, src_spec, dst_spec, src_world,
 
 @dataclass(frozen=True)
 class CostModel:
-    """Wire constants from ``project_dp_scaling`` (bench.py):
+    """The wire constants' one home:
     v5e ICI 1.8e11 B/s with 1 µs/hop alpha, DCN 2.5e10 B/s per host.
     The DCN alpha and local copy bandwidth are this model's own
     assumptions (cross-host message setup is dominated by the NIC/host

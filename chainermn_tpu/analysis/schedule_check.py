@@ -428,8 +428,8 @@ class ScheduleExecProfile:
         self._run_seq = None  # assigned lazily per begin()
         # (kind, arg) -> (link, bytes), precomputed so on_op stays a
         # single dict lookup — this runs inside reshard_host's
-        # schedule interpreter and its cost is the profiler_overhead
-        # the schedule_truth bench gates < 3%.
+        # schedule interpreter, once per op, so its cost is the
+        # profiler's whole overhead.
         self._info: Dict[Tuple[str, str], Tuple[str, int]] = {}
         for tid, t in sched.transfers.items():
             nb = sched.chunks[t.chunk].nelems * self._item
@@ -538,7 +538,7 @@ def execute_profiled(sched: Schedule,
                      reps: int = 1
                      ) -> Tuple[List[np.ndarray], ScheduleExecProfile]:
     """Run a verified schedule ``reps`` times under a fresh profiler
-    and return (last outputs, profile) — the bench/`--measure` face."""
+    and return (last outputs, profile) — the `--measure` face."""
     prof = ScheduleExecProfile(sched)
     ins = in_blocks if in_blocks is not None else make_input_blocks(sched)
     outs: List[np.ndarray] = []

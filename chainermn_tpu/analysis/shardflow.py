@@ -62,7 +62,7 @@ the shipped dtypes the comparison is exact.
 Findings flow through the same fingerprint/baseline/suppression
 machinery as the AST engine; the checked-in baseline is
 ``.shardflow-baseline.json`` and ``scripts/shardflow_report.py`` is the
-CI runner (exit 0/1/2 — the ``check_perf_regression.py`` contract).
+CI runner (exit 0/1/2 — the ``lint_spmd.py`` contract).
 
 jax is imported lazily: importing this module costs nothing on jax-free
 boxes (same contract as ``jaxpr_engine``).
@@ -935,9 +935,9 @@ def _render_report(r: ShardflowReport) -> str:
 
 
 def main(argv: Optional[List[str]] = None) -> int:
-    """Shard-flow report runner.  Exit contract (the
-    ``check_perf_regression.py`` / ``lint_spmd.py`` contract): 0 = clean
-    modulo baseline, 1 = findings, 2 = unusable inputs."""
+    """Shard-flow report runner.  Exit contract (the ``lint_spmd.py``
+    contract): 0 = clean modulo baseline, 1 = findings, 2 = unusable
+    inputs."""
     import argparse
     import json
     import sys

@@ -150,7 +150,7 @@ class SelfHealingGang:
         A lane store (``serving.lanes.FileLaneStore`` for elastic gangs
         of unrelated processes, ``comm.gang_lease_store()`` over the
         jax.distributed KV store for gangs sharing a coordinator, or the
-        in-process loopback for tests/bench).  Every operation rides
+        in-process loopback for tests).  Every operation rides
         :func:`~chainermn_tpu.communicators.base.lane_call`.
     rank / world:
         This member's ORIGINAL rank and the launch world size.  Member

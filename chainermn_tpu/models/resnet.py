@@ -51,8 +51,8 @@ class StaleBatchNorm(nn.Module):
     Standard training BN cannot normalize until the CURRENT batch's
     mean/var exist, which forces the conv output through HBM extra times
     (a stats read plus a normalize read+write) — 8.4 GB of ResNet-50's
-    44 GB/step on v5e (docs/PERF.md roofline, measured by
-    scripts/probe_bn_traffic.py).  Normalizing with statistics that are
+    44 GB/step on v5e (docs/PERF.md roofline, "the BN traffic
+    measured").  Normalizing with statistics that are
     CONSTANTS at this step makes the apply side a per-channel affine — a
     pure elementwise epilogue XLA fuses into the producing conv — and
     the current batch's stats reduction fuses too (measured: within 2%
@@ -255,7 +255,7 @@ class ResNet(nn.Module):
 # --- Normalizer-free ResNets (Brock et al. 2021, NF-ResNet) ---------------
 # The BN-free variant: BatchNorm's extra
 # activation passes cost 8.4 GB of ResNet-50's 44 GB/step on v5e
-# (scripts/probe_bn_traffic.py), and the zero-norm "affine floor" measures
+# (docs/PERF.md, "the BN traffic measured"), and the zero-norm "affine floor" measures
 # +19% step throughput.  NF-ResNets reach that floor with PUBLISHED
 # convergence parity on ImageNet: scaled weight standardization (statistics
 # over the WEIGHTS — 25 M params, negligible traffic — not the activations),

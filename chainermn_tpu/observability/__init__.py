@@ -34,7 +34,7 @@ Production triad (ISSUE 5):
   percentiles.
 * :mod:`.introspect` — live ``/statusz`` / ``/metricsz`` / ``/requestz``
   / ``/debugz`` HTTP endpoint (``--statusz-port`` in the train/serve
-  CLIs and bench.py).
+  CLIs).
 
 Quick start::
 
@@ -70,7 +70,6 @@ from .comm import (  # noqa: F401
 )
 from .metrics import (  # noqa: F401
     StepBreakdownReport,
-    hbm_bw_for,
     peak_flops_for,
 )
 from .aggregate import (  # noqa: F401
@@ -153,7 +152,6 @@ __all__ = [
     "comm_report",
     "StepBreakdownReport",
     "peak_flops_for",
-    "hbm_bw_for",
     # fleet layer (ISSUE 2)
     "shard_path",
     "find_shards",

@@ -414,7 +414,6 @@ _payload_info = payload_info
 
 _SCHED_LOCK = threading.Lock()
 _SCHED_EXEC: Dict[str, float] = {}
-_ACTIVE_CALIBRATION: Optional[Dict[str, Any]] = None
 _CAL_PROVIDER_REGISTERED = False
 
 
@@ -462,38 +461,14 @@ def schedule_exec_gauges() -> Dict[str, float]:
         return dict(_SCHED_EXEC)
 
 
-def set_active_calibration(cal: Optional[Dict[str, Any]]) -> None:
-    """Install (or clear) the calibration artifact the process is
-    currently pricing schedules with; surfaces via the /statusz
-    ``calibration`` provider."""
-    global _ACTIVE_CALIBRATION
-    with _SCHED_LOCK:
-        _ACTIVE_CALIBRATION = cal
-    if cal is not None:
-        _register_calibration_provider()
-
-
 def calibration_snapshot() -> Dict[str, Any]:
-    """The /statusz ``calibration`` provider: live counters plus the
-    active artifact's fitted constants (if one is installed)."""
-    with _SCHED_LOCK:
-        counters = dict(_SCHED_EXEC)
-        cal = _ACTIVE_CALIBRATION
-    out: Dict[str, Any] = {"counters": counters}
-    if cal is None:
-        out["calibration"] = None
-    else:
-        out["calibration"] = {
-            "schema": cal.get("schema"),
-            "n_records": cal.get("n_records"),
-            "links": cal.get("links"),
-        }
-    return out
+    """The /statusz ``calibration`` provider: the live counters.  No
+    calibration artifact is installed in a process (``calibration`` stays
+    ``None``); a fit is applied per call, ``price_schedule(calibration=)``."""
+    return {"counters": schedule_exec_gauges(), "calibration": None}
 
 
 def reset_schedule_exec() -> None:
-    """Test hook: clear counters and the active calibration."""
-    global _ACTIVE_CALIBRATION
+    """Test hook: clear the counters."""
     with _SCHED_LOCK:
         _SCHED_EXEC.clear()
-        _ACTIVE_CALIBRATION = None

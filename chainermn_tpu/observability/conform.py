@@ -4,8 +4,8 @@ PR 15's model checker (``analysis/protocol.py``) proves the fleet's
 three load-bearing protocols correct over EVERY interleaving of a small
 bounded model; this module closes the other half of the loop — it maps
 a REAL run's causal journal (``journal.py``) onto those models' action
-alphabets and replays it, so every chaos test, every bench chaos
-section, and any production run with ``--journal`` is continuously
+alphabets and replays it, so every chaos test, every scenario run
+and any production run with ``--journal`` is continuously
 model-checked:
 
 * ``done_xor_shed`` — every request's fleet lifecycle (``submitted`` /

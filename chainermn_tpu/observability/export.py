@@ -14,8 +14,8 @@ trainer's observation path):
   record stamped with the versioned schema id (``SCHEMA``), a kind, a
   wall-clock timestamp, and (under multi-controller) the writing rank.
   Append-only + per-line flush means a killed run keeps every record up
-  to the kill, and ``scripts/check_perf_regression.py`` can diff two
-  streams without any end-of-run finalization having happened.
+  to the kill, and :func:`read_metrics_jsonl` can read the stream
+  without any end-of-run finalization having happened.
 
 :func:`health_snapshot` assembles the "what was this process doing"
 dict — counters, gauges, span summary, comm ledger, last step report,

@@ -18,8 +18,8 @@ debugger, without restarting, from ``curl``:
   the live postmortem trigger.
 * ``/healthz``  — 200 "ok" (load-balancer liveness).
 
-Wired behind ``--statusz-port`` in ``chainermn_tpu.train``,
-``chainermn_tpu.serve``, and ``bench.py``; binds 127.0.0.1 by default
+Wired behind ``--statusz-port`` in ``chainermn_tpu.train`` and
+``chainermn_tpu.serve``; binds 127.0.0.1 by default
 (introspection is an operator tool, not a public API).  Port 0 picks a
 free port (tests); the chosen port is on ``StatusServer.port``.
 """

@@ -18,8 +18,8 @@ from .comm import get_accountant
 
 # Peak dense bf16 FLOP/s per chip by TPU generation (public spec sheets).
 # Matched by substring against jax.devices()[0].device_kind (lowercased).
-# Single source of truth — bench.py and the breakdown extension both read
-# this table.
+# Read by StepBreakdownReport below (the ``perf/mfu`` gauge); the benchmark
+# keeps its own copy in benchmark/harness/device.py by design (PERF.md §3).
 PEAK_BF16_FLOPS = [
     ("v6e", 918e12),
     ("trillium", 918e12),
@@ -31,39 +31,20 @@ PEAK_BF16_FLOPS = [
     ("v2", 46e12),
 ]
 
-# HBM bandwidth (bytes/s) per chip by TPU generation (public spec sheets).
-HBM_BYTES_PER_S = [
-    ("v6e", 1.64e12),
-    ("trillium", 1.64e12),
-    ("v5p", 2.765e12),
-    ("v5e", 8.19e11),
-    ("v5 lite", 8.19e11),
-    ("v4", 1.228e12),
-    ("v3", 9.0e11),
-    ("v2", 7.0e11),
-]
 
-
-def _per_generation(table, device_kind: str, what: str) -> Optional[float]:
+def peak_flops_for(device_kind: str) -> Optional[float]:
     kind = device_kind.lower()
-    for key, value in table:
+    for key, value in PEAK_BF16_FLOPS:
         if key in kind:
             return value
     if "tpu" in kind:
         # a device that is not in the table is an error, not a default:
         # a utilization computed against a guessed peak is worse than none
         raise ValueError(
-            f"no {what} on record for TPU device_kind {device_kind!r}; "
-            f"add its generation to chainermn_tpu/observability/metrics.py")
+            f"no peak bf16 FLOP/s on record for TPU device_kind "
+            f"{device_kind!r}; add its generation to "
+            f"chainermn_tpu/observability/metrics.py")
     return None  # not a TPU (the CPU test mesh): utilization not meaningful
-
-
-def peak_flops_for(device_kind: str) -> Optional[float]:
-    return _per_generation(PEAK_BF16_FLOPS, device_kind, "peak bf16 FLOP/s")
-
-
-def hbm_bw_for(device_kind: str) -> Optional[float]:
-    return _per_generation(HBM_BYTES_PER_S, device_kind, "HBM bandwidth")
 
 
 class StepBreakdownReport:

@@ -74,8 +74,8 @@ def collective_wire_cost(primitive: str, payload_bytes: int,
     it to the ring decomposition every textbook (and XLA's default ICI
     schedule) uses: an all-reduce is reduce-scatter + all-gather, each
     moving ``(P-1)/P`` of the payload over ``P-1`` hops.  At axis size 1
-    everything is free.  Used by the shard-flow cost model and the bench
-    wire-byte gate — one formula, not two.
+    everything is free.  Used by the shard-flow cost model and the comm
+    ledger's tests — one formula, not two.
     """
     p = int(axis_size)
     if p <= 1:
@@ -202,7 +202,7 @@ def choose_pipeline_depth(chunk_bytes: int, bw_bytes_per_s: float = 1.8e11,
     """Pick the pipeline depth ``k`` for :func:`quantized_ring_pmean`
     from the r04 multislice cost-model terms (per-hop latency ``alpha``
     and link bandwidth — v5e ICI defaults, same table as
-    ``bench.project_dp_scaling``).
+    ``analysis/schedule.py``'s ``CostModel``).
 
     Model per ring hop with ``k`` sub-chunks: the transfer of sub-chunk
     ``j+1`` overlaps the dequant+accumulate of sub-chunk ``j``, so the

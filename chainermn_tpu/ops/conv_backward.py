@@ -5,7 +5,7 @@ docs/PERF.md "Conv backward: why the Pallas kernels lost".  The kernels
 are parity-exact and compile inside the full sharded train step, but lose
 to XLA's native conv engine at every ResNet shape (2x at 14x14x256 up to
 ~30x at 56x56x64; NF-ResNet-50 end-to-end 119.6 vs 40.6 ms/step,
-scripts/ab_conv_impl.py).  Two findings worth the price of the experiment:
+docs/PERF.md).  Two findings worth the price of the experiment:
 
 1. XLA's backward convs already run AT the HBM-roofline floor in
    wall-clock (56x56x64 dgrad: 0.12 ms measured vs 0.126 ms floor).  The
@@ -46,8 +46,8 @@ any future windowed kernel):
   ``conv2d`` only swaps the VJP, and falls back to XLA's transpose rule
   for shapes the kernels don't cover — behavior never gates on coverage.
 
-Parity: tests/test_conv_backward.py (interpret mode, any host) and the
-real-chip A/B in scripts/ab_conv_impl.py.
+Parity: tests/test_conv_backward.py (interpret mode, any host) and
+chip_smoke.py's kernel-parity phase (compiled, on the chip).
 """
 
 from __future__ import annotations

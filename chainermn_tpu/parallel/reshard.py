@@ -23,8 +23,8 @@ across the mesh axis, and "block" is the per-rank shard.  Every wire leg
 routes through the ACCOUNTED collective face (``ops.collective``), so
 the PR 1 comm ledger books each call and the PR 6 shard-flow static
 model reconciles the traced equations byte-exactly — the cost of a
-reshard is never invisible (``reshard_cost`` is the same formula the
-bench gate and the property tests read).
+reshard is never invisible (``reshard_cost`` is the formula the
+property tests hold the ledger to).
 
 Two faces, one spec language:
 
@@ -248,7 +248,7 @@ def reshard_cost(shape: Sequence[int], dtype, src: ShardSpec,
 
 def reshard_tree_cost(tree, src_spec, dst_spec, axis_size: int) -> dict:
     """Sum of :func:`reshard_cost` over a pytree — the whole transfer's
-    predicted ledger/wire bytes (bench's elastic section reads this)."""
+    predicted ledger/wire bytes."""
     import jax
 
     src_leaves, leaves, _ = _spec_tree(tree, src_spec)

@@ -15,7 +15,7 @@ stdout (request outcomes + the serving metrics dict), optional
 ``--metrics-out`` JSONL stream (``chainermn_tpu.metrics.v1`` records,
 kinds ``serving_step``/``serving_summary``) and ``--prom-out``
 Prometheus textfile — both the formats the observability layer already
-exports and ``scripts/check_perf_regression.py`` gates on.
+exports.
 
 ``--replicas N`` (ISSUE 7) stands up N engines behind the serving
 router instead: least-loaded prefix-affine dispatch, SLO-aware

@@ -26,7 +26,7 @@ loop:
   clock restarts at the decision (``down_stable_s`` of continuous calm
   must follow it); (3) after a down at ``t``, an up is refused until
   ``t + up_cooldown_s``.  :meth:`flap_count` re-derives the invariant
-  from the recorded decision history — the bench gates on it staying 0.
+  from the recorded decision history — the tests hold it at 0.
 
 * :class:`FleetAutoscaler` — binds one policy PER ROLE to a live
   :class:`~chainermn_tpu.serving.fleet.FleetRouter`: signals come from
@@ -307,7 +307,7 @@ class AutoscalePolicy:
 
     def flap_count(self) -> int:
         """Opposite-direction decision pairs closer than the relevant
-        cooldown, re-derived from the RECORDED history (the bench/test
+        cooldown, re-derived from the RECORDED history (the test
         acceptance: must be 0 — the refusal logic above makes it so,
         this measures rather than trusts)."""
         flaps = 0
@@ -353,7 +353,7 @@ class FleetAutoscaler:
     Drive: ``router.step()`` calls :meth:`maybe_tick` when an
     autoscaler is attached (throttled to ``interval_s``), so the
     router's supervisor thread IS the control loop; :meth:`tick` is
-    the deterministic face tests and the bench drive directly.
+    the deterministic face tests drive directly.
     """
 
     def __init__(self, router, spawn: Callable[[str, str], Any], *,
@@ -559,7 +559,7 @@ class FleetAutoscaler:
 
     # ---- read-out ----
     # every reader takes the same lock tick() holds while appending
-    # decisions / registering workers: a /statusz scrape or a bench
+    # decisions / registering workers: a /statusz scrape or a
     # metrics() call iterating the decision deque mid-append would
     # otherwise raise RuntimeError (the dict-mutation race this PR
     # fixed in FleetRouter._live, on the autoscaler's own state)

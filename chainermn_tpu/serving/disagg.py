@@ -16,7 +16,7 @@ interference).  This module splits the roles:
   transferred slabs landed in reserved slots
   (``ServingEngine.install_request``), so its compiled tick runs
   back-to-back and the decode tick-gap p99 collapses to the tick cost
-  (the bench ``serving_disagg`` section measures exactly this).
+  (not yet measured on the chip: ROADMAP R5).
 * :class:`DisaggRouter` — the role-aware composition: prompts dispatch
   to the least-loaded LIVE prefill worker; finished slabs to the decode
   worker chosen by free (reservation-aware) slots + deadline
@@ -35,9 +35,9 @@ the real disaggregated shape (a decode worker's loop is the only thing
 that touches its pool) and what makes role-PARALLEL drive race-free:
 ``start()`` runs one driver thread per role, so a prefill never sits
 between two decode ticks and the decode tick-gap p99 collapses to the
-tick cost — the ISSUE 9 acceptance metric, measured by the bench
-``serving_disagg`` section against the fused engine at the same
-offered load.  ``step()``/``run()`` keep the deterministic
+tick cost — the ISSUE 9 acceptance metric, to be read against the
+fused engine at the same offered load (ROADMAP R5).
+``step()``/``run()`` keep the deterministic
 single-thread interleave (prefill round, then decode round) for tests.
 
 Failure domain (the one place a :class:`~chainermn_tpu.communicators
@@ -859,7 +859,7 @@ class DisaggRouter(RouterBase):
         handoff keeps each pool single-threaded (prefill thread: admit/
         prefill/publish + reserve destination slots; decode thread:
         land/commit/tick), so prefill wall never sits between two
-        decode ticks — the disaggregation payoff the bench measures.
+        decode ticks — the disaggregation payoff.
         A cross-process deployment runs the same two loop bodies in
         separate processes over the lane transport."""
         import threading
@@ -961,9 +961,7 @@ class DisaggRouter(RouterBase):
     # ---- metrics / introspection ----
     def metrics(self) -> Dict[str, float]:
         """Fleet summary under ``disagg/*`` (the /metricsz
-        ``extra_gauges`` payload + the bench section's source).
-        ``transfer*/tick_gap*/rejected*`` keys are lower-is-better
-        under the regression gate's direction inference."""
+        ``extra_gauges`` payload)."""
         with self._lock:
             dispatched = self._dispatched
             rejected = dict(self._rejected)

@@ -33,8 +33,8 @@ router that owns three planes:
   ``worker_lost`` + ``retry_after_ms`` attached to the handle
   (``shed_payload``).  ``drain(worker)`` is the graceful inverse: stop
   admitting, let the worker finish in-flight, collect ``drained``, and
-  the process exits 0 — the rolling-restart primitive the
-  ``serving_chaos`` bench section measures.
+  the process exits 0 — the rolling-restart primitive
+  (tests/test_chaos_serving.py holds it against real processes).
 
 Disaggregated topologies ride the same plane: prompts dispatch to
 prefill workers, their ``slab_ready`` announcements route to the
@@ -1503,9 +1503,7 @@ class FleetRouter(RouterBase):
     # ------------------------------------------------------------------
     def metrics(self) -> Dict[str, float]:
         """Fleet summary under ``fleet/*``: liveness, dispatch/failover
-        counters, fencing refusals, detection latency — the
-        ``serving_chaos`` bench section's source.  ``*_ms``/``shed``/
-        ``rejected``/``refus`` keys gate lower-is-better."""
+        counters, fencing refusals, detection latency."""
         with self._lock:
             rejected = dict(self._rejected)
             dispatched = self._dispatched
@@ -1593,7 +1591,7 @@ class FleetRouter(RouterBase):
                 self._failover_ttft_ms.capacity)
         # one epoch for every cache-economy rate counter: warm-up
         # hits/misses/stale fallbacks must not leak into the measured
-        # window the bench gates on
+        # window
         self.cache_index.reset_counters()
         self.goodput.reset()
 
@@ -1836,9 +1834,8 @@ def build_local_fleet(params, topology: Dict[str, Any], *,
     store: returns ``(router, runtimes)`` with every worker a
     :class:`~chainermn_tpu.serving.worker.WorkerRuntime` the caller
     steps (or drives on threads).  Same protocol, same fault
-    discipline — the fast-tier tests and the ``serving_chaos`` bench
-    exercise the real lanes/fencing/failover code without process
-    spawn cost.  ``topology`` role values may be model_id lists
+    discipline — the fast-tier tests exercise the real
+    lanes/fencing/failover code without process spawn cost.  ``topology`` role values may be model_id lists
     resolved through ``registry`` (heterogeneous fleet, ISSUE 18)."""
     from .transfer import InProcessLaneStore
     from .worker import WorkerRuntime

@@ -201,7 +201,7 @@ class FleetCacheIndex:
 
     def reset_counters(self) -> None:
         """Zero the rate counters (hits/misses/stale fallbacks) while
-        keeping the structure and its structural counters — the bench
+        keeping the structure and its structural counters — a
         warm-up must not leak into the measured window
         (``FleetRouter.reset_stats`` calls this)."""
         with self._lock:

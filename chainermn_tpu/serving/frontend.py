@@ -46,11 +46,11 @@ docs/OBSERVABILITY.md):
   ``observability.export.write_prometheus_textfile`` scrapes them with
   everything else, plus :meth:`ServingEngine.metrics` (TTFT p50/p99,
   per-token latency, slot occupancy — O(1)-memory reservoir samples,
-  never unbounded lists) as the ``extra_gauges`` / bench-section
+  never unbounded lists) as the ``extra_gauges`` / summary-record
   payload;
 * optional per-step JSONL via ``observability.export.MetricsWriter``
-  (kind ``serving_step`` records + one ``serving_summary``), the
-  ``scripts/check_perf_regression.py``-gateable stream.
+  (kind ``serving_step`` records + one ``serving_summary``), each a
+  versioned record ``observability.read_metrics_jsonl`` validates.
 """
 
 from __future__ import annotations
@@ -587,10 +587,10 @@ class ServingEngine:
                 self.goodput.add("host", t_tick - t_host)
                 # inter-tick gap: what a decoding request waits between its
                 # tokens — includes any prefill that ran above (the fused
-                # engine's tail; see the disagg bench section, ISSUE 9).
-                # Locked with reset_stats: a bench warm-up reset racing this
+                # engine's tail; disaggregation exists to cut it, ISSUE 9).
+                # Locked with reset_stats: a warm-up reset racing this
                 # read-modify-write could book one warm-up gap into the
-                # gated window (the unguarded-shared-write lint class)
+                # measured window (the unguarded-shared-write lint class)
                 read, total = live_blocks(self.pool.pos, self.pool.max_total)
                 with self._lock:
                     if self._last_tick_start is not None:
@@ -997,7 +997,7 @@ class ServingEngine:
     def reset_stats(self) -> None:
         """Zero the rolling serving stats and restart the throughput
         clock — call after warm-up (compiles) so steady-state numbers
-        don't absorb one-off costs (bench.py's serving section does)."""
+        don't absorb one-off costs (benchmark/families/gpt2.py does)."""
         with self._lock:
             self._t0 = time.monotonic()
             self._ttft_ms = ReservoirSample(self.stats_capacity)
@@ -1021,7 +1021,7 @@ class ServingEngine:
             self._slo_last = (0, self._t0)
             if self.prefix_cache is not None:
                 # zero the cumulative counters; entries/pins stay (the
-                # warm cache IS the steady state bench measures)
+                # warm cache IS the steady state a measurement wants)
                 pc = self.prefix_cache
                 pc.hits = pc.misses = pc.tokens_reused = 0
                 pc.insertions = pc.rejected_insertions = 0
@@ -1061,8 +1061,8 @@ class ServingEngine:
 
     def metrics(self) -> Dict[str, float]:
         """Host-side serving summary (the Prometheus ``extra_gauges`` /
-        bench-section payload).  ``*_ms`` keys are lower-is-better under
-        the regression gate's direction inference."""
+        summary-record payload).  Each end-to-end metric's direction and
+        bound are BENCHMARK.json's to state, not a key name's."""
         with self._lock:
             el = max(time.monotonic() - self._t0, 1e-9)
             out = {

@@ -436,7 +436,7 @@ class ServingRouter(RouterBase):
     def step(self) -> int:
         """ONE fleet scheduling round: step every replica that has
         work; returns how many did (0 == drained).  The deterministic
-        single-thread driver the tests and bench use; production runs
+        single-thread driver the tests use; production runs
         :meth:`start` instead."""
         stepped = 0
         for rep in self.replicas:
@@ -472,7 +472,7 @@ class ServingRouter(RouterBase):
     def reset_stats(self) -> None:
         """Zero router counters AND every replica's rolling stats —
         call after warm-up so steady-state numbers don't absorb the
-        one-off compiles (bench.py's serving_router section does)."""
+        one-off compiles."""
         with self._lock:
             self._dispatched = 0
             self._dispatched_by = {n: 0 for n in self._dispatched_by}
@@ -484,9 +484,8 @@ class ServingRouter(RouterBase):
     # ---- metrics / introspection ----
     def metrics(self) -> Dict[str, float]:
         """Fleet summary + per-reason rejection counters (the
-        ``/metricsz`` ``extra_gauges`` payload and the bench section's
-        source).  ``shed``/``rejected`` keys are lower-is-better under
-        the regression gate's direction inference."""
+        ``/metricsz`` ``extra_gauges`` payload and the ``router_summary``
+        record's source)."""
         with self._lock:
             dispatched = self._dispatched
             rejected = dict(self._rejected)

@@ -1,17 +1,17 @@
 """Trace-driven workload engine: seeded, replayable serving scenarios
 (ISSUE 18, ROADMAP item 4 — the planet-scale scenario plane).
 
-Every serving bench section used to hand-roll its arrival loop
-(``submit every k steps``, an inline diurnal phase table); real traffic
-is diurnal, bursty, adversarial, and faulty, and none of those loops
-could be replayed or cross-checked.  This module makes the WORKLOAD a
+Real traffic is diurnal, bursty, adversarial, and faulty, and a
+hand-rolled arrival loop (``submit every k steps``) can be neither
+replayed nor cross-checked.  This module makes the WORKLOAD a
 first-class artifact:
 
 * **Generators** — pure host Python, jax-free, seeded: diurnal curves,
   flash crowds, prefix-sniping/long-prompt adversarial tenants, mixed
   deadline classes, and composed chaos (worker kill + burst + SIGSTOP
   zombie in one stream).  Same seed ⇒ byte-identical event stream
-  (:func:`stream_digest` is the proof the tests and the bench gate on).
+  (:func:`stream_digest` is the proof the tests and
+  ``scripts/run_scenario.py`` gate on).
 * **Event stream** — schema ``chainermn_tpu.scenario.v1``: one record
   per arrival (virtual time, tenant, priority, prompt SPEC, deadline)
   or fault injection.  Prompts ride as specs (seed + length + prefix
@@ -22,7 +22,7 @@ first-class artifact:
   wall-clock against a REAL fleet (:class:`~.fleet.FleetRouter` + its
   autoscale/tenancy/chaos planes as the system under test), applies
   the fault events to the live workers, and records the per-scenario
-  SLO / shed / autoscale / degradation-rung matrix the bench gates.
+  SLO / shed / autoscale / degradation-rung matrix.
 
 The stream is deterministic; the REPLAY is wall-clock (scheduling
 jitter, compile stalls) — which is exactly the split the robustness
@@ -59,8 +59,7 @@ EVENT_KINDS = ("request", "fault")
 FAULT_ACTIONS = ("kill", "pause", "resume")
 
 #: The default diurnal curve (night → morning → PEAK+BURST → evening →
-#: night): (phase name, requests, interarrival seconds) — the shape the
-#: ``serving_autoscale`` bench section drove inline before ISSUE 18.
+#: night): (phase name, requests, interarrival seconds).
 DIURNAL_PHASES: Tuple[Tuple[str, int, float], ...] = (
     ("night", 3, 0.05), ("morning", 10, 0.005),
     ("peak_burst", 20, 0.0), ("evening", 6, 0.02),
@@ -209,8 +208,8 @@ def canonical_bytes(ev: Dict[str, Any]) -> bytes:
 
 def stream_digest(events: Sequence[Dict[str, Any]]) -> str:
     """SHA-256 over the stream's canonical bytes: two generator runs
-    with the same seed must produce the SAME digest (gated in bench and
-    fuzzed in tests/test_scenarios.py)."""
+    with the same seed must produce the SAME digest (gated in
+    scripts/run_scenario.py and fuzzed in tests/test_scenarios.py)."""
     h = hashlib.sha256()
     for ev in events:
         h.update(canonical_bytes(ev))
@@ -248,9 +247,8 @@ def staggered(n: int, interarrival: float, *, seed: int = 0,
               ) -> List[Dict[str, Any]]:
     """The primitive arrival source: ``n`` requests, one every
     ``interarrival`` virtual units.  The unit is the REPLAYER's choice
-    — wall seconds under :func:`run_scenario`, engine steps under the
-    ``bench_serving`` loop (which is how the bench sections and the
-    scenario plane share ONE seeded source, ISSUE 18 satellite)."""
+    — wall seconds under :func:`run_scenario`, engine steps under
+    ``chainermn_tpu.serve``'s demo load and ``chip_smoke.py``."""
     rng = random.Random(_stable_seed("staggered", seed))
     return finalize([
         request_event(
@@ -270,9 +268,9 @@ def diurnal(seed: int = 0, *,
             jitter_frac: float = 0.0) -> List[Dict[str, Any]]:
     """Diurnal offered-load curve: ``phases`` of (name, requests,
     interarrival seconds), tenants alternating deterministically per
-    arrival, optional ±``jitter_frac`` seeded jitter on each gap.  The
-    ``serving_autoscale`` bench drives exactly this shape (scale-up on
-    the peak, no-flap scale-down on the nights)."""
+    arrival, optional ±``jitter_frac`` seeded jitter on each gap (an
+    autoscaler should scale up on the peak and, without flapping, down
+    on the nights)."""
     rng = random.Random(_stable_seed("diurnal", seed))
     events, t, k = [], 0.0, 0
     for name, n_req, gap in phases:
@@ -387,8 +385,8 @@ def composed_chaos(seed: int = 0, *, kill_at: float = 0.08,
     return merge(load, faults)
 
 
-#: Named scenario registry (``scripts/run_scenario.py`` and the bench
-#: matrix build from here): name → zero-config builder(seed).
+#: Named scenario registry (``scripts/run_scenario.py`` builds from
+#: here): name → zero-config builder(seed).
 SCENARIOS: Dict[str, Callable[..., List[Dict[str, Any]]]] = {
     "diurnal": diurnal,
     "flash_crowd": flash_crowd,
@@ -449,7 +447,7 @@ def run_scenario(events: Sequence[Dict[str, Any]], router, *,
                  sleep: Callable[[float], None] = time.sleep
                  ) -> Dict[str, Any]:
     """Replay a finalized stream against a live fleet in scaled
-    wall-clock; returns the per-scenario matrix row the bench gates.
+    wall-clock; returns the per-scenario matrix row.
 
     Each request event materializes its prompt, submits through
     :func:`~.fleet.submit_with_retry` (tenant/priority/deadline ride
@@ -459,9 +457,9 @@ def run_scenario(events: Sequence[Dict[str, Any]], router, *,
     The caller owns warm-up and ``router.reset_stats()`` — this
     function measures, it does not prepare.
 
-    Matrix keys (direction under scripts/check_perf_regression.py):
-    ``shed_rate``/``slo_burn``/``max_rung``/``flap``/``drain_shed``/
-    ``*_degraded`` lower-is-better, ``terminal_frac`` higher.
+    Matrix keys: ``shed_rate``/``slo_burn``/``max_rung``/``flap``/
+    ``drain_shed``/``*_degraded`` lower-is-better, ``terminal_frac``
+    higher.
     """
     from .fleet import submit_with_retry
     from .scheduler import AdmissionError

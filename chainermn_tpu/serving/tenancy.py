@@ -378,8 +378,7 @@ class TenantTable:
     # ---- read-out ----
     def metrics(self) -> Dict[str, float]:
         """Flat per-tenant gauges (``tenant/<name>/*`` — the
-        ``/metricsz`` and bench-section payload).  ``shed``/``degraded``
-        keys gate lower-is-better."""
+        ``/metricsz`` payload)."""
         out: Dict[str, float] = {}
         lad = self.ladder.state()
         out["tenant/degradation_rung"] = float(lad["rung"])

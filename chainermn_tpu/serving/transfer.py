@@ -52,7 +52,7 @@ import numpy as np
 WIRE_SCHEMA = "chainermn_tpu.kv_transfer.v1"
 
 #: The ledger key every lane-mode transfer books under (op@axis) — the
-#: shard-flow/bench reconciliation joins on it.
+#: shard-flow reconciliation joins on it.
 LANE_OP = "kv_transfer_lane"
 LANE_AXIS = "dcn"
 
@@ -202,7 +202,7 @@ class KvTransferPlane:
         self.lane_config = lane_config
         self._programs: Dict[Any, Any] = {}   # local-path program cache
         self._inject_programs: Dict[Any, Any] = {}
-        # host-side counters (the fleet's /statusz + bench read these)
+        # host-side counters (the fleet's /statusz reads these)
         self.transfers = 0
         self.lane_transfers = 0
         self.bytes_moved = 0            # ledger-convention slab bytes

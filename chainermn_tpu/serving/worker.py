@@ -35,8 +35,8 @@ the graceful half of a rolling restart.
 
 ``python -m chainermn_tpu.serving.worker --role engine --name w0
 --lane-dir D --params P.pkl`` is the process entry the fleet spawner
-execs; :class:`WorkerRuntime` is transport-agnostic so tests and the
-bench drive the same loop in-process over the loopback store.
+execs; :class:`WorkerRuntime` is transport-agnostic so tests drive
+the same loop in-process over the loopback store.
 """
 
 from __future__ import annotations
@@ -93,7 +93,7 @@ class WorkerRuntime:
     """One fleet member's role loop (transport-agnostic).
 
     ``store`` is any object lane (``FileLaneStore`` across processes,
-    ``InProcessLaneStore`` for in-process tests/bench — same protocol,
+    ``InProcessLaneStore`` for in-process tests — same protocol,
     same fault discipline).  ``kill()`` is the chaos face: the runtime
     stops doing ANY work, including heartbeats — to the supervisor it
     is indistinguishable from a SIGKILL'd process.

@@ -46,8 +46,8 @@ from functools import partial
 
 REPO = os.path.dirname(os.path.abspath(__file__))
 
-#: The real sizes: the repo's own headline configs (bench.py's ResNet-50
-#: and 135M LM rows), never cut in width.
+#: The real sizes: the repo's own headline configs (ResNet-50 and the
+#: 135M LM), never cut in width.
 SIZES = {
     "parity": {"flash": (2, 256, 4, 64), "decode": (2, 256, 4, 128),
                "conv": (8, 28, 28, 128)},
@@ -223,7 +223,7 @@ def phase_train_resnet(ctx):
 # --------------------------------------------------------------------------
 
 def _lm_setup(ctx, devices, dp, tp, global_batch):
-    """The bench's 135M LM train step on a (dp, tp) mesh over ``devices``:
+    """The 135M LM train step on a (dp, tp) mesh over ``devices``:
     (step, params, opt_state, batch, mesh, specs), weights from --seed."""
     import jax
     import jax.numpy as jnp

@@ -107,8 +107,7 @@ def main():
             batch = mn.shard_batch(
                 (np.concatenate(xs), np.concatenate(ys)), mesh)
             params, opt_state, loss, acc = step(params, opt_state, batch)
-            # keep virtual devices in lockstep on thin hosts (see tests);
-            # real-chip throughput runs use bench.py's async pipeline instead
+            # keep virtual devices in lockstep on thin hosts (see tests)
             loss.block_until_ready()
         if comm.rank == 0:
             print(f"epoch {epoch}  loss {float(loss):.4f}  acc {float(acc):.3f}  "

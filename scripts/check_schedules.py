@@ -30,12 +30,8 @@ Checks (any failure ⇒ exit 1):
   for ``price_schedule(calibration=)`` /
   ``python -m chainermn_tpu.analysis --gate`` drift checking.
 
-Exit codes (the ``check_perf_regression.py`` contract): 0 = all pairs
-verified and checks passed, 1 = a violation or a missed fault, 2 =
-inputs unusable.
-
-``--history-out`` appends one ``{n, cmd, rc, t, parsed}`` record so schedule runs land on the same
-``bench_history.jsonl`` trajectory the perf gate diffs.
+Exit codes: 0 = all pairs verified and checks passed, 1 = a violation
+or a missed fault, 2 = inputs unusable.
 
 No jax required: the analysis package is loaded standalone (same
 importlib trick as ``lint_spmd.py``), numpy is the only dependency.
@@ -44,7 +40,6 @@ Usage::
 
     python scripts/check_schedules.py
     python scripts/check_schedules.py --shape 48,8 --chunks 2 --json
-    python scripts/check_schedules.py --history-out bench_history.jsonl
     python scripts/check_schedules.py --measure --calibration-out \
         calibration.json
 """
@@ -56,7 +51,6 @@ import importlib.util
 import json
 import os
 import sys
-import time
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _PKG = os.path.join(_REPO, "chainermn_tpu", "analysis")
@@ -75,25 +69,6 @@ def _load_analysis():
     sys.modules[name] = mod
     spec.loader.exec_module(mod)
     return mod
-
-
-def _append_history(path: str, parsed: dict, rc: int) -> None:
-    n = 0
-    if os.path.exists(path):
-        with open(path) as f:
-            for line in f:
-                if not line.strip():
-                    continue
-                try:
-                    rec = json.loads(line)
-                except ValueError:
-                    continue  # torn tail from a killed run
-                if isinstance(rec, dict) and isinstance(rec.get("n"), int):
-                    n = max(n, rec["n"])
-    record = {"n": n + 1, "cmd": " ".join(sys.argv), "rc": rc,
-              "t": round(time.time(), 3), "parsed": parsed}
-    with open(path, "a") as f:
-        f.write(json.dumps(record) + "\n")
 
 
 def main(argv=None) -> int:
@@ -121,9 +96,6 @@ def main(argv=None) -> int:
     p.add_argument("--calibration-out", default=None,
                    help="with --measure: persist the fitted "
                         "calibration artifact to this path")
-    p.add_argument("--history-out", default=None,
-                   help="append one {n, cmd, rc, t, parsed} record to "
-                        "this bench_history.jsonl trajectory")
     args = p.parse_args(argv)
 
     try:
@@ -281,11 +253,6 @@ def main(argv=None) -> int:
     print(json.dumps(verdict, indent=2, sort_keys=True))
     for v in violations:
         print(v, file=sys.stderr)
-    if args.history_out:
-        slim = {k: v for k, v in verdict.items() if k != "pairs"}
-        slim["chosen"] = {k: p["chosen"] for k, p in pairs.items()}
-        _append_history(args.history_out,
-                        {"collective_schedules": slim}, rc)
     return rc
 
 

@@ -15,8 +15,7 @@ Usage::
     python scripts/explain_bundle.py result/ --all      # whole gang
     python scripts/explain_bundle.py <bundle> --json    # machine shape
 
-No JAX import; runs on any box that can read JSON (same contract as
-check_perf_regression.py).
+No JAX import; runs on any box that can read JSON.
 """
 
 from __future__ import annotations

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """SPMD lint gate — CI face of ``chainermn_tpu.analysis``.
 
-Same exit-code contract as ``scripts/check_perf_regression.py``:
+Exit-code contract:
 0 = clean (modulo baseline), 1 = findings, 2 = inputs unusable.
 
 Unlike ``python -m chainermn_tpu.analysis`` (which imports the full

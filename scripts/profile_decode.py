@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 # spmd-lint: disable-file=prng-constant-key — fixed seeds are the point:
 # profile/probe runs must be bit-reproducible across commits to be comparable
-"""Component breakdown of the greedy decode tick (bench config).
+"""Component breakdown of the greedy decode tick.
 
 Where does the per-token time go at d1024/L8/h16/V32k/b8?  Replicates
 ``parallel/decode.py :: lm_generate``'s scan with switchable components
@@ -19,8 +19,7 @@ Variants (cumulative knockouts):
   no_cache    caches not even carried (pure projections/MLP tick)
 
 Timing: best-of-3 chains of ``reps`` generator calls with one host
-readback at the end (amortized over the chain), identical to
-bench.py :: bench_decode.
+readback at the end (amortized over the chain).
 """
 
 import json

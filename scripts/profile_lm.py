@@ -3,14 +3,14 @@
 # profile/probe runs must be bit-reproducible across commits to be comparable
 """Component-level timing breakdown of the transformer-LM train step.
 
-Answers "where does the non-MXU time go" for the bench config
+Answers "where does the non-MXU time go" for the 135M LM config
 (d1024 L8 h16 S1024 V32768 b8, bf16, flash) by timing nested subsets:
 
   full step  =  fwd + bwd + optimizer + dispatch
   grad       =  fwd + bwd
   fwd        =  forward loss only
   body-only  =  same minus the vocab-parallel cross entropy (mean(h) loss)
-  attn micro =  flash fwd / fwd+bwd at the bench shape, isolated
+  attn micro =  flash fwd / fwd+bwd at that shape, isolated
   vocab  CE  =  logits+CE fwd / fwd+bwd, isolated
 
 Timing barrier: HOST READBACK of a scalar that data-depends on the work
@@ -69,8 +69,7 @@ def main():
                              "Chrome-trace/Perfetto JSON here")
     parser.add_argument("--metrics-out", default=None,
                         help="append the report as one record of the "
-                             "versioned JSONL metrics stream "
-                             "(check_perf_regression.py input)")
+                             "versioned JSONL metrics stream")
     args = parser.parse_args()
     obs = None
     if args.trace_out or args.metrics_out:
@@ -101,7 +100,7 @@ def main():
         0, VOCAB, (B * n_chips, S + 1)).astype(np.int32)
     batch = (jax.device_put(tokens, NamedSharding(mesh, P("data"))),)
 
-    # --- full step (threads donated state like bench.measure) --------------
+    # --- full step (threads the donated state from call to call) -----------
     pp, sst = p, st
     pp, sst, loss, *_ = step(pp, sst, batch)
     float(loss)

@@ -70,8 +70,7 @@ def main():
                              "Chrome-trace/Perfetto JSON here")
     parser.add_argument("--metrics-out", default=None,
                         help="append the component timings as one record "
-                             "of the versioned JSONL metrics stream "
-                             "(check_perf_regression.py input)")
+                             "of the versioned JSONL metrics stream")
     args = parser.parse_args()
     obs = None
     if args.trace_out or args.metrics_out:

@@ -18,19 +18,14 @@ Checks (any failure ⇒ exit 1):
 * **conformance** — the journal replay finds 0 protocol violations;
 * optional operator bounds ``--max-shed-rate`` / ``--max-slo-burn``.
 
-Exit codes (the ``check_perf_regression.py`` contract): 0 = scenario
-ran and every check passed, 1 = a check failed, 2 = inputs unusable
-(unknown scenario, no JAX backend, bad arguments).
-
-``--history-out`` appends one ``{n, cmd, rc, t, parsed}`` record so scenario runs land on the same
-``bench_history.jsonl`` trajectory the perf gate diffs.
+Exit codes: 0 = scenario ran and every check passed, 1 = a check
+failed, 2 = inputs unusable (unknown scenario, no JAX backend, bad
+arguments).
 
 Usage::
 
     python scripts/run_scenario.py flash_crowd
     python scripts/run_scenario.py composed_chaos --seed 3 --workers 2
-    python scripts/run_scenario.py adversarial \
-        --history-out bench_history.jsonl
 """
 
 from __future__ import annotations
@@ -46,25 +41,6 @@ import time
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO not in sys.path:
     sys.path.insert(0, _REPO)
-
-
-def _append_history(path: str, parsed: dict, rc: int) -> None:
-    n = 0
-    if os.path.exists(path):
-        with open(path) as f:
-            for line in f:
-                if not line.strip():
-                    continue
-                try:
-                    rec = json.loads(line)
-                except ValueError:
-                    continue  # torn tail from a killed run
-                if isinstance(rec, dict) and isinstance(rec.get("n"), int):
-                    n = max(n, rec["n"])
-    record = {"n": n + 1, "cmd": " ".join(sys.argv), "rc": rc,
-              "t": round(time.time(), 3), "parsed": parsed}
-    with open(path, "a") as f:
-        f.write(json.dumps(record) + "\n")
 
 
 def main(argv=None) -> int:
@@ -88,9 +64,6 @@ def main(argv=None) -> int:
                    help="fail (exit 1) when shed_rate exceeds this")
     p.add_argument("--max-slo-burn", type=float, default=None,
                    help="fail (exit 1) when slo_burn exceeds this")
-    p.add_argument("--history-out", default=None,
-                   help="append one {n, cmd, rc, t, parsed} record to "
-                        "this bench_history.jsonl trajectory")
     args = p.parse_args(argv)
 
     if args.scenario not in sc.SCENARIOS:
@@ -208,9 +181,6 @@ def main(argv=None) -> int:
            if k not in ("worker_trace", "fault_log")},
     }
     print(json.dumps(verdict, indent=2, sort_keys=True))
-    if args.history_out:
-        _append_history(args.history_out,
-                        {f"scenario_{args.scenario}": verdict}, rc)
     return rc
 
 

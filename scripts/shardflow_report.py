@@ -7,7 +7,7 @@ peak-live-memory-per-replica estimate, the replication report across the
 entry's data axis, and the static↔dynamic reconciliation verdict against
 the PR 1 runtime comm ledger.
 
-Same exit-code contract as ``scripts/check_perf_regression.py`` and
+Same exit-code contract as
 ``scripts/lint_spmd.py``: 0 = clean (modulo the checked-in
 ``.shardflow-baseline.json``), 1 = findings, 2 = inputs unusable.
 

@@ -18,10 +18,8 @@ Contracts under test:
   named with its dominant link/op, and the overlap fraction
   (wire hidden behind other work / total wire) matches hand math.
 * **Gates** — ``calibrate.main`` keeps the 0/1/2 contract (0 ok or
-  gate-skip, 1 drift, 2 unusable/stale), the ``calibration`` stage
-  rides ``python -m chainermn_tpu.analysis --gate``, and
-  ``scripts/bench_trajectory.py`` keeps the same contract over a
-  bench history trajectory.
+  gate-skip, 1 drift, 2 unusable/stale) and the ``calibration`` stage
+  rides ``python -m chainermn_tpu.analysis --gate``.
 """
 
 import json
@@ -265,9 +263,8 @@ class TestProfiler:
         assert len({rec["run"] for rec in prof.records}) == 2
 
     def test_on_op_cost_is_bounded(self):
-        # the bench gates profiler_overhead_frac < 3% against real op
-        # walls; here just pin the per-record cost to an order of
-        # magnitude that cannot dominate ms-scale transfers.
+        # pin the per-record cost to an order of magnitude that cannot
+        # dominate ms-scale transfers.
         import time
         sched = SC.verified_schedule("chunked", (24, 4), "float32",
                                      0, 0, 4, 2, Topology(2, 2))
@@ -279,7 +276,7 @@ class TestProfiler:
             tb = prof.now_ns()
             prof.on_op(op, 0, tb, prof.now_ns())
         per_record = (time.perf_counter() - t0) / 2000
-        assert per_record < 50e-6  # generous CI bound; bench pins 3%
+        assert per_record < 50e-6  # generous CI bound
 
 
 # ==========================================================================
@@ -419,46 +416,3 @@ class TestGates:
         assert "rel_err_calibrated" in pair["measured"]
         assert C.load_calibration(str(out))["n_records"] == \
             verdict["measured"]["n_records"]
-
-    def test_bench_trajectory_exit_contract(self, tmp_path):
-        script = os.path.join(REPO, "scripts", "bench_trajectory.py")
-
-        def run(*argv):
-            return subprocess.run([sys.executable, script, *argv],
-                                  capture_output=True, text=True,
-                                  timeout=60)
-
-        hist = tmp_path / "bench_history.jsonl"
-        rows = [
-            {"n": 1, "cmd": "bench", "rc": 0, "t": 1.0, "parsed": {
-                "schedule_truth": {"median_rel_err_calibrated": 0.5,
-                                   "wire_exposed_frac": 0.5,
-                                   "overlap_frac": 0.5}, "mfu": 0.4}},
-            {"n": 2, "cmd": "bench", "rc": 0, "t": 2.0, "parsed": {
-                "schedule_truth": {"median_rel_err_calibrated": 0.51,
-                                   "wire_exposed_frac": 0.49,
-                                   "overlap_frac": 0.51}, "mfu": 0.41}},
-        ]
-        hist.write_text("\n".join(json.dumps(r) for r in rows) + "\n")
-        r = run(str(hist))
-        assert r.returncode == 0, r.stderr
-        # direction markers: rel_err/exposed gate lower (<), overlap
-        # gates higher (>) — the documented two faces of one quantity
-        assert "< schedule_truth/median_rel_err_calibrated" in r.stdout
-        assert "< schedule_truth/wire_exposed_frac" in r.stdout
-        assert "> schedule_truth/overlap_frac" in r.stdout
-        # 1: the newest round regresses (error way up, overlap down)
-        rows.append(
-            {"n": 3, "cmd": "bench", "rc": 0, "t": 3.0, "parsed": {
-                "schedule_truth": {"median_rel_err_calibrated": 0.9,
-                                   "wire_exposed_frac": 0.8,
-                                   "overlap_frac": 0.2}, "mfu": 0.4}})
-        hist.write_text("\n".join(json.dumps(r) for r in rows) + "\n")
-        r = run(str(hist), "--json")
-        assert r.returncode == 1
-        doc = json.loads(r.stdout)
-        assert doc["n_regressions"] >= 3
-        # 2: fewer than two usable rounds
-        solo = tmp_path / "solo.jsonl"
-        solo.write_text(json.dumps(rows[0]) + "\n")
-        assert run(str(solo)).returncode == 2

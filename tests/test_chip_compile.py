@@ -31,7 +31,7 @@ import pytest
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
-# the real widths (chip_smoke.SIZES / bench.py's 135M LM row)
+# the real widths (chip_smoke.SIZES' 135M LM row)
 VOCAB, D_MODEL, N_LAYERS, N_HEADS, HEAD_DIM, SEQ, BATCH = (
     32768, 1024, 8, 8, 128, 1024, 8)
 

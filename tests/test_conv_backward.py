@@ -1,8 +1,9 @@
 """Parity tests for the Pallas 3x3 conv backward kernels.
 
 Oracle: jax.vjp of the same XLA conv the forward uses.  Shapes are tiny so
-interpret mode stays fast; the real-chip compiled path is exercised by
-scripts/ab_conv_impl.py and the bench.
+interpret mode stays fast; the compiled path runs on the chip in
+chip_smoke.py's kernel-parity phase and compiles for it, without one, in
+tests/test_chip_compile.py.
 """
 
 import jax

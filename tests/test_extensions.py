@@ -145,6 +145,19 @@ class TestAsyncCheckpointWrites:
         cp.save({"x": 1}, iteration=2)
         assert cp.maybe_load()[1] == 2
 
+    def test_steps_to_recover_with_and_without_final_save(self, comm,
+                                                          tmp_path):
+        """Periodic saves at 5 and 10, preempted at 13: with the
+        preemption handler's final save resume replays 0 steps; with that
+        shard gone (SIGKILL, no final save) it replays 13 - 10 = 3."""
+        cp = create_multi_node_checkpointer(
+            "job", comm, path=str(tmp_path), keep=10, async_write=False)
+        for it in (5, 10, 13):
+            cp.save({"iteration": it}, iteration=it)
+        assert 13 - cp.maybe_load()[1] == 0
+        os.unlink(cp._filename(13))
+        assert 13 - cp.maybe_load()[1] == 3
+
     def test_save_does_not_block_on_disk_io(self, comm, tmp_path):
         """The save call itself should return in ~detach time: its write is
         still in flight (or done) but never serialized inline.  We assert

@@ -430,44 +430,6 @@ def test_status_server_endpoints(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# bench trajectory (satellite)
-# ---------------------------------------------------------------------------
-
-def test_bench_history_append_and_gate(tmp_path):
-    sys.path.insert(0, ROOT)
-    try:
-        from bench import append_history
-    finally:
-        sys.path.remove(ROOT)
-    hist = tmp_path / "bench_history.jsonl"
-    r1 = append_history(str(hist), {"value": 100.0, "unit": "ips"},
-                        cmd="bench r1")
-    r2 = append_history(str(hist), {"value": 99.0, "unit": "ips"},
-                        cmd="bench r2")
-    assert (r1["n"], r2["n"]) == (1, 2)       # rounds auto-increment
-    lines = [json.loads(x) for x in hist.read_text().splitlines()]
-    assert [r["n"] for r in lines] == [1, 2]
-    assert set(lines[0]) >= {"n", "cmd", "rc", "t", "parsed"}  # BENCH shape
-
-    gate = os.path.join(ROOT, "scripts", "check_perf_regression.py")
-    ok = subprocess.run([sys.executable, gate, "--history", str(hist)],
-                        capture_output=True, text=True, timeout=60)
-    assert ok.returncode == 0, (ok.stdout, ok.stderr)  # 1% < 5% threshold
-
-    append_history(str(hist), {"value": 50.0, "unit": "ips"}, cmd="r3")
-    bad = subprocess.run([sys.executable, gate, "--history", str(hist)],
-                         capture_output=True, text=True, timeout=60)
-    assert bad.returncode == 1, (bad.stdout, bad.stderr)
-    assert "REGRESSION" in bad.stdout
-
-    short = tmp_path / "one.jsonl"
-    append_history(str(short), {"value": 1.0}, cmd="only")
-    two = subprocess.run([sys.executable, gate, "--history", str(short)],
-                         capture_output=True, text=True, timeout=60)
-    assert two.returncode == 2                 # nothing to gate
-
-
-# ---------------------------------------------------------------------------
 # death tests (the acceptance gate): subprocess serving runs
 # ---------------------------------------------------------------------------
 

@@ -1,7 +1,7 @@
 """ResNet + flax train step tests (BASELINE configs #2/#4 machinery).
 
 Reference parity: examples/imagenet smoke coverage (SURVEY.md §4) — tiny
-shapes on the virtual mesh; full-size throughput lives in bench.py.
+shapes on the virtual mesh; the full size runs in chip_smoke.py.
 """
 
 import jax

@@ -373,21 +373,16 @@ class TestGateCLI:
         cap = capsys.readouterr()
         assert "schedules=0" in cap.out + cap.err
 
-    def test_check_schedules_script_end_to_end(self, tmp_path):
-        hist = tmp_path / "bench_history.jsonl"
+    def test_check_schedules_script_end_to_end(self):
         proc = subprocess.run(
             [sys.executable,
-             os.path.join(REPO, "scripts", "check_schedules.py"),
-             "--history-out", str(hist)],
+             os.path.join(REPO, "scripts", "check_schedules.py")],
             capture_output=True, text=True, timeout=300)
         assert proc.returncode == 0, proc.stderr[-2000:]
         verdict = json.loads(proc.stdout)
         assert verdict["ok"] and verdict["checks"]["hierarchical_win"]
         assert verdict["fault_corpus"]["false_negatives"] == []
-        (rec,) = [json.loads(line) for line in
-                  hist.read_text().splitlines()]
-        assert rec["rc"] == 0
-        assert rec["parsed"]["collective_schedules"]["hier_speedup"] > 1
+        assert verdict["hier_speedup"] > 1
 
 
 # ==========================================================================

@@ -15,8 +15,7 @@ Three layers, cheapest first:
   the no-cross-talk oracle: slots share every tick's batch and are
   recycled between requests, so any leakage between sequences breaks
   exactness).  The serving gauges must reach the Prometheus textfile
-  and the bench-shaped serving section must be ACCEPTED by
-  ``scripts/check_perf_regression.py``.
+  and ``metrics()`` must be a JSON-clean record of finite numbers.
 * **CLI smoke**: ``chainermn_tpu.serve`` in-process with a tiny config —
   summary JSON on stdout, schema-valid metrics JSONL, exit 0.
 """
@@ -212,8 +211,8 @@ def test_iteration_level_batching_end_to_end(devices, tmp_path):
     """THE acceptance test: 4-slot pool, 8 staggered requests; a late
     arrival starts decoding before the first batch drains; outputs are
     token-exact vs lm_generate alone (= no cross-talk through the shared
-    pool / recycled slots); gauges reach Prometheus and the serving
-    bench section passes the regression gate."""
+    pool / recycled slots); gauges reach Prometheus and ``metrics()``
+    is a JSON-clean record."""
     from chainermn_tpu import observability as obs
     from chainermn_tpu.serving import ServingEngine
 
@@ -281,23 +280,12 @@ def test_iteration_level_batching_end_to_end(devices, tmp_path):
     assert "chainermn_tpu_serving_ttft_p50_ms" in prom
     assert "chainermn_tpu_serving_slot_occupancy_pct" in prom
 
-    # bench-shaped serving section round-trips the regression gate
-    m = eng.metrics()
-    section = {"serving": {"load_test": {
-        "tokens_per_sec": m["serving/tokens_per_sec"],
-        "ttft_p50_ms": m["serving/ttft_p50_ms"],
-        "ttft_p99_ms": m["serving/ttft_p99_ms"],
-        "slot_occupancy_pct": m["serving/slot_occupancy_pct"],
-    }}}
-    path = tmp_path / "serving_bench.json"
-    path.write_text(json.dumps(section))
-    gate = subprocess.run(
-        [sys.executable,
-         os.path.join(ROOT, "scripts", "check_perf_regression.py"),
-         str(path), str(path)],
-        capture_output=True, text=True, timeout=120)
-    assert gate.returncode == 0, (gate.stdout, gate.stderr)
-    assert "0 regression(s)" in gate.stdout
+    # metrics() round-trips JSON and its headline keys are finite numbers
+    m = json.loads(json.dumps(eng.metrics()))
+    for key in ("serving/tokens_per_sec", "serving/ttft_p50_ms",
+                "serving/ttft_p99_ms", "serving/slot_occupancy_pct"):
+        assert np.isfinite(m[key]), (key, m[key])
+    assert m["serving/tokens_per_sec"] > 0
 
 
 @pytest.mark.parametrize("pos_impl,n_kv_heads", [("rope", 2)])
@@ -625,49 +613,6 @@ def test_latency_stats_bounded_by_reservoir(devices):
     assert flight._PROVIDERS.get("serving") is not None
     eng.close()
     assert "serving" not in flight._PROVIDERS
-
-
-@pytest.mark.slow
-def test_bench_serving_section_shape_and_gate(tmp_path):
-    """The REAL bench section: offered-load sweep runs, reports the
-    documented keys, and its JSON round-trips the regression gate with
-    the intended directions (ttft/latency/rejected lower-is-better,
-    steps skipped as bookkeeping)."""
-    sys.path.insert(0, ROOT)
-    try:
-        import bench
-        section = bench.bench_serving()
-    finally:
-        sys.path.remove(ROOT)
-    for point in ("load_high", "load_low"):
-        row = section[point]
-        for key in ("tokens_per_sec", "ttft_p50_ms", "ttft_p99_ms",
-                    "token_latency_p50_ms", "slot_occupancy_pct",
-                    "rejected", "steps"):
-            assert key in row, (point, key, row)
-        assert row["tokens_per_sec"] > 0
-    path = tmp_path / "serving.json"
-    path.write_text(json.dumps({"serving": section}))
-    gate = subprocess.run(
-        [sys.executable,
-         os.path.join(ROOT, "scripts", "check_perf_regression.py"),
-         str(path), str(path), "--json"],
-        capture_output=True, text=True, timeout=120)
-    assert gate.returncode == 0, (gate.stdout, gate.stderr)
-    verdict = json.loads(gate.stdout)
-    assert verdict["ok"] and verdict["compared"] >= 10
-    # direction inference: the gate must treat these as lower-is-better
-    sys.path.insert(0, ROOT)
-    try:
-        from scripts.check_perf_regression import lower_is_better
-    finally:
-        sys.path.remove(ROOT)
-    for key in ("serving/load_high/ttft_p99_ms",
-                "serving/load_low/token_latency_p50_ms",
-                "serving/load_high/rejected"):
-        assert lower_is_better(key), key
-    assert not lower_is_better("serving/load_high/tokens_per_sec")
-    assert not lower_is_better("serving/load_high/slot_occupancy_pct")
 
 
 @pytest.mark.slow
