@@ -13,23 +13,33 @@ Forward: one Pallas kernel, grid ``(B·H, S/block_q, S/block_k)``, the last
 dimension sequential ("arbitrary") so scratch accumulates across K blocks.
 Saves the log-sum-exp alongside the output.
 
-Backward: ONE fused Pallas kernel (round 4; previously a dQ + dKV pair
-that recomputed ``qk``/``do·v`` twice and read the operands from HBM
-twice).  Grid is K-major with (group, Q) sequential: dk/dv accumulate in
-fp32 VMEM scratch (the GQA head-group fold happens in-scratch), while
-each cell's dq contribution is written as a per-K-block PARTIAL slab —
-input dtype, summed in fp32 by one XLA reduce — because K-major cells
-visit a given q block non-consecutively (no scratch residency) and HBM
-read-modify-write aliasing would race the block prefetch at diagonal
-corners.  Probabilities recompute from the saved LSE (``p = exp(s −
-lse)`` is the exact softmax, no renormalisation pass); causal
-above-diagonal cells are skipped AND their dead block DMA elided by
-index-map clamping.  O(S·block) live memory in VMEM, an O(nk·S·D)
-HBM transient for the dq partials.  A
-``lax.scan`` XLA fallback (``backward='xla'``) covers Mosaic-hostile
-block geometries and serves as the oracle in tests.  On CPU (tests,
-debugging) the kernels run in Pallas interpret mode; the math is
+Backward: ONE fused Pallas kernel.  Grid is K-major with (group, Q)
+sequential: dk/dv accumulate in fp32 VMEM scratch (the GQA head-group fold
+happens in-scratch), while each cell's dq contribution is written as a
+per-K-block PARTIAL slab — input dtype, summed in fp32 by one XLA reduce —
+because K-major cells visit a given q block non-consecutively (no scratch
+residency) and HBM read-modify-write aliasing would race the block
+prefetch at diagonal corners.  Probabilities recompute from the saved LSE
+(``p = exp(s − lse)`` is the exact softmax, no renormalisation pass).
+O(S·block) live memory in VMEM, an O(nk·S·D) HBM transient for the dq
+partials.  A ``lax.scan`` XLA fallback (``backward='xla'``) covers
+Mosaic-hostile block geometries and serves as the oracle in tests.  On CPU
+(tests, debugging) the kernels run in Pallas interpret mode; the math is
 identical.
+
+The causal (and padded-tail) schedule, both kernels: the GRID stays coarse
+— a grid step costs about as much as a 128 × 128 score tile at head 64 —
+so the unit of skipping and of masking is a SUB-BLOCK inside a grid cell
+(``_SUB_BLOCK``; :func:`causal_schedule`).  Grid cells wholly above the
+diagonal are skipped and their dead block DMA elided by index-map
+clamping, as before.  Inside a cell that runs, each Q sub-block computes
+only the K sub-blocks at or below its diagonal, as one product as wide as
+those columns, and pays the mask's iota / compare / select only on the
+sub-blocks the diagonal (or a padded tail) crosses.  The schedule is
+static: every distinct way a cell is computed (its *plan*) is one
+straight-line body, all of the cell's Q sub-blocks in one region, and the
+cell picks its plan from its program ids.  ``causal=False`` with no tail
+has one plan, the undivided cell.
 
 Layout: ``(B, S, H, D)`` — the same convention as ``parallel/``'s ring and
 Ulysses attention, which uses this kernel for its local (post-all-to-all)
@@ -39,6 +49,7 @@ attention when ``attn_impl='flash'``.
 from __future__ import annotations
 
 import functools
+import itertools
 from typing import Optional
 
 import jax
@@ -49,6 +60,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .._compat import shape_dtype_struct as _sds
 from .._compat import tpu_compiler_params as _tpu_compiler_params
+from ..observability import trace as _trace
 
 NEG_INF = -1e30
 _LANES = 128  # TPU vector lane count: scratch vectors are (block_q, 128)
@@ -111,79 +123,254 @@ def _pick_lane_block(s: int, want: int) -> int:
     return _pick_aligned_block(s, want)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref, *, scale, causal,
-                block_q, block_k, num_kblocks, seq_len):
+# Edge of a sub-block inside a grid cell (causal_schedule).  Found on the
+# chip at the benchmark cells' shapes (scripts/tune_flash_bwd.py; PERF.md
+# Findings PR 30): at B 8, H 16, S 1024, D 64 the forward reads 0.378 ms at
+# 128, 0.398 at 256, 0.453 at 512 (the undivided parent 0.737) and the
+# backward 0.907 / 0.956 / 1.019 (1.221); the latent prefill (H 128, D 192,
+# S 1024) 0.669 / 0.705 / 0.821 (1.213).
+_SUB_BLOCK = 128
+
+
+def _sub_block(block: int) -> int:
+    """Edge of the sub-blocks a grid cell's ``block`` rows (or columns) are
+    walked in: the largest lane multiple ≤ ``_SUB_BLOCK`` that divides it
+    (sub-block slices then start on a 128 boundary, which the backward's
+    LSE row slices and packed bf16 tiles need), else the whole block."""
+    for b in range(min(_SUB_BLOCK, block) // _LANES * _LANES, 0, -_LANES):
+        if block % b == 0:
+            return b
+    return block
+
+
+def _k_sub_range(row0, col0, sub_q, sub_k, n_sub_k, causal, seq_len,
+                 least=jnp.minimum, most=jnp.maximum):
+    """``(n_full, n_run)`` for the Q sub-block of ``sub_q`` rows at ``row0``
+    against a cell's ``n_sub_k`` K sub-blocks, the first at column ``col0``:
+    sub-blocks ``[0, n_full)`` lie wholly below the diagonal and inside the
+    real sequence (no mask), ``[n_full, n_run)`` are crossed by the diagonal
+    or hold the padded tail (masked body), the rest are never computed.
+    Works on program ids inside a kernel and, with ``least=min,
+    most=max``, on plain ints (:func:`causal_schedule`)."""
+    n_full = n_run = n_sub_k
+    if causal:
+        d = row0 - col0
+        n_full = least(most(d + 1, 0) // sub_k, n_full)
+        n_run = least(most(d + sub_q + sub_k - 1, 0) // sub_k, n_run)
+    if seq_len is not None:
+        live = most(seq_len - col0, 0)          # real columns from col0 on
+        n_full = least(live // sub_k, n_full)
+        n_run = least((live + sub_k - 1) // sub_k, n_run)
+        # a Q sub-block that holds padded rows is masked throughout (the
+        # backward masks them), one of nothing but padded rows is skipped
+        n_full = n_full * most(least(seq_len - row0 - sub_q + 1, 1), 0)
+        n_run = n_run * most(least(seq_len - row0, 1), 0)
+    return n_full, n_run
+
+
+def causal_schedule(s: int, block_q: int, block_k: int, causal: bool = True,
+                    seq_len: Optional[int] = None) -> dict:
+    """The static schedule of one head's ``s × s`` score matrix under a
+    ``block_q × block_k`` grid: the sub-block edges a cell is divided by
+    (the whole block where nothing is masked: ``causal=False``, no tail),
+    how many (Q sub-block, K sub-block) pairs are computed at all
+    (``run``), computed under the mask (``masked``) and in the square
+    (``total``), and the ``plans``: every distinct way a grid cell is
+    computed, as one ``(n_full, n_masked)`` for each of its Q sub-blocks —
+    of the cell's K sub-blocks the first ``n_full`` unmasked, the next
+    ``n_masked`` masked, the rest not at all.  The kernels hold one static
+    body per plan and pick a cell's from its program ids with the same
+    :func:`_k_sub_range`.  ``seq_len < s`` says where a padded tail
+    begins."""
+    if seq_len == s:
+        seq_len = None
+    divide = causal or seq_len is not None
+    sub_q = _sub_block(block_q) if divide else block_q
+    sub_k = _sub_block(block_k) if divide else block_k
+    n_sub_k = block_k // sub_k
+    run = masked = 0
+    plans = set()
+    for cell_row0 in range(0, s, block_q):
+        for col0 in range(0, s, block_k):
+            plan = []
+            for row0 in range(cell_row0, cell_row0 + block_q, sub_q):
+                n_full, n_run = _k_sub_range(
+                    row0, col0, sub_q, sub_k, n_sub_k, causal, seq_len,
+                    least=min, most=max)
+                run += n_run
+                masked += n_run - n_full
+                plan.append((n_full, n_run - n_full))
+            plans.add(tuple(plan))
+    return {"sub_q": sub_q, "sub_k": sub_k, "n_sub_k": n_sub_k, "run": run,
+            "masked": masked, "total": (s // sub_q) * (s // sub_k),
+            "plans": tuple(sorted(plans))}
+
+
+def _count_score_blocks(schedule: dict, heads: int) -> None:
+    """Book one traced kernel call's schedule (all ``heads`` of it) with the
+    tracer, as ``comm/<op>`` is booked: at trace time, off when disabled."""
+    tr = _trace.get_tracer()
+    for key in ("run", "masked", "total"):
+        tr.add_counter(f"flash/score_blocks_{key}", heads * schedule[key])
+
+
+def _run_plan(schedule, cell_row0, cell_col0, causal, seq_len, body) -> None:
+    """Give the grid cell at ``(cell_row0, cell_col0)`` the static body of
+    its plan, all of it in ONE straight-line region, chosen by comparing
+    what :func:`_k_sub_range` finds for each of its Q sub-blocks from the
+    program ids with each plan of ``schedule``.
+
+    ``body(first, count, n_full, n_masked)`` is a GENERATOR for the run of
+    ``count`` Q sub-blocks from ``first`` that share an outcome; it yields
+    between its phases (scores; softmax; products), and the runs of a cell
+    are advanced in lockstep, so the region reads: every run's score
+    products, then every run's vector work, then every run's second
+    products.  Program order is where the chip's scheduler starts from:
+    with four runs a cell this order measured 20 % (forward) and 11 %
+    (backward) faster than run after run, the widest run first another 3 %,
+    and a loop over sub-blocks — whose iterations cannot overlap at all,
+    each serialising two MXU round trips behind its row maximum — up to
+    2.5 x SLOWER than the undivided cell (PERF.md Findings PR 30)."""
+    def run(plan):
+        # neighbours that compute the same columns go as one taller run (a
+        # cell wholly below the diagonal is then the undivided cell)
+        runs = []
+        for outcome, group in itertools.groupby(enumerate(plan),
+                                                lambda e: e[1]):
+            first = next(group)[0]
+            runs.append(body(first, 1 + sum(1 for _ in group), *outcome))
+        runs.reverse()      # the widest (under causal: the last) run first
+        done = object()
+        while runs:
+            runs = [r for r in runs if next(r, done) is not done]
+
+    # (a lone plan goes through the same test: always true, and the kernel's
+    # work stays inside a region, which interpret mode under shard_map's
+    # vma check needs — it types constants only there)
+    sub_q, sub_k, plans = (schedule[key] for key in ("sub_q", "sub_k", "plans"))
+    found = [_k_sub_range(cell_row0 + i * sub_q, cell_col0, sub_q, sub_k,
+                          schedule["n_sub_k"], causal, seq_len)
+             for i in range(len(plans[0]))]
+    for plan in plans:
+        same = [jnp.logical_and(n_full == full, n_run == full + n_masked)
+                for (n_full, n_run), (full, n_masked) in zip(found, plan)]
+        pl.when(functools.reduce(jnp.logical_and, same))(
+            functools.partial(run, plan))
+
+
+def _score_mask(row0, col0, rows, cols, causal, seq_len, mask_q_tail):
+    """The keep-mask of the ``rows × cols`` scores at ``(row0, col0)``: the
+    causal triangle and/or the real sequence."""
+    q_pos = row0 + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+    k_pos = col0 + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+    mask = (q_pos >= k_pos) if causal else None
+    if seq_len is not None:
+        tail = k_pos < seq_len
+        if mask_q_tail:
+            tail = jnp.logical_and(tail, q_pos < seq_len)
+        mask = tail if mask is None else jnp.logical_and(mask, tail)
+    return mask
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *state, scale, causal,
+                block_q, block_k, schedule, num_kblocks, seq_len):
     iq, ik = pl.program_id(1), pl.program_id(2)
+    sub_q, sub_k = schedule["sub_q"], schedule["sub_k"]
+    # One K block holds every row whole: nothing to carry from cell to
+    # cell, so no scratch (``state`` is empty), no zeroing of it and no
+    # rescaling by exp(m_prev - m_new) — a sixth of the undivided cell's
+    # instructions at S = 1024.
+    carried = num_kblocks > 1
 
-    @pl.when(ik == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def finish(rows, m, l, acc):
+        """Write the rows' output and log-sum-exp from their final row
+        maximum, row sum (either ``(rows, 1)`` or lane-replicated) and
+        unnormalised output."""
+        l = jnp.maximum(l, 1e-37)
+        o_ref[0, rows, :] = (acc / l[:, :1]).astype(o_ref.dtype)
+        # LSE is lane-replicated (rows, LANES) — Mosaic needs the last two
+        # block dims tileable; callers slice [..., 0].
+        lse_ref[0, rows, :] = jnp.broadcast_to(m + jnp.log(l),
+                                               (acc.shape[0], _LANES))
 
-    # seq_len < the padded S means a masked tail (prime/odd S padded up to
-    # the block size); those K positions must contribute nothing.
-    tail = seq_len is not None
+    if carried:
+        acc_ref, m_ref, l_ref = state
 
-    # Causal: K blocks entirely above the diagonal contribute nothing —
-    # skip their matmuls (≈2× FLOP saving at long S).  Fully-padded K
-    # blocks likewise.
-    run = (ik * block_k <= iq * block_q + block_q - 1) if causal else True
-    if tail:
-        run = jnp.logical_and(run, ik * block_k < seq_len)
+        @pl.when(ik == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(run)
-    def _body():
-        q = q_ref[0]                                   # (block_q, D)
-        k = k_ref[0]                                   # (block_k, D)
-        v = v_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (block_q, block_k)
+    def body(first, count, n_full, n_masked):
+        """Softmax update of the ``count`` Q sub-blocks from ``first`` with
+        the cell's first ``n_full`` K sub-blocks unmasked and the next
+        ``n_masked`` under the mask (causal triangle; a padded S's tail:
+        those K positions must contribute nothing); the K sub-blocks
+        beyond, wholly above the diagonal or wholly padding, are never
+        computed."""
+        rows = slice(first * sub_q, (first + count) * sub_q)
+        height = count * sub_q
+        row0 = iq * block_q + first * sub_q
+        lo, hi = n_full * sub_k, (n_full + n_masked) * sub_k
+        if not hi:          # nothing of this cell's: a carried state stands
+            if not carried:  # (rows of padding only: no cell writes them)
+                finish(rows, jnp.full((height, 1), NEG_INF, jnp.float32),
+                       jnp.zeros((height, 1), jnp.float32),
+                       jnp.zeros((height, o_ref.shape[-1]), jnp.float32))
+            return
 
-        mask = None
-        if causal or tail:
-            q_pos = iq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ik * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            mask = (q_pos >= k_pos) if causal else (k_pos == k_pos)
-            if tail:
-                mask = jnp.logical_and(mask, k_pos < seq_len)
-            s = jnp.where(mask, s, NEG_INF)
+        def on_masked(x, fill):
+            """``fill`` where the mask hides a score of the columns from
+            ``lo`` on — the only ones it can hide."""
+            if not n_masked:
+                return x
+            mask = _score_mask(row0, ik * block_k + lo, height, hi - lo,
+                               causal, seq_len, False)
+            tail = jnp.where(mask, x[:, lo:], fill)
+            return jnp.concatenate([x[:, :lo], tail], 1) if lo else tail
 
-        m_prev = m_ref[:, :1]                          # (block_q, 1)
-        l_prev = l_ref[:, :1]
-        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-        # NEG_INF is finite, so exp(s - m_new) alone would turn fully-masked
+        s = on_masked(jax.lax.dot_general(
+            q_ref[0, rows, :], k_ref[0, :hi, :], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale,     # (height, hi)
+            NEG_INF)
+        yield
+        m = s.max(-1, keepdims=True)                   # (height, 1)
+        if carried:
+            m_prev = m_ref[rows, :1]
+            m = jnp.maximum(m_prev, m)
+        # NEG_INF is finite, so exp(s - m) alone would turn fully-masked
         # rows into 1s — multiply by the mask explicitly.
-        p = jnp.exp(s - m_new)
-        if mask is not None:
-            p = jnp.where(mask, p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)                # (block_q, 1)
-        l_new = l_prev * alpha + p.sum(-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p = on_masked(jnp.exp(s - m), 0.0)
+        l = p.sum(-1, keepdims=True)
+        yield
+        v = v_ref[0, :hi, :]
+        acc = jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        if not carried:
+            finish(rows, m, l, acc)
+            return
+        alpha = jnp.exp(m_prev - m)                    # (height, 1)
+        acc_ref[rows, :] = acc_ref[rows, :] * alpha + acc
+        m_ref[rows, :] = jnp.broadcast_to(m, (height, _LANES))
+        l_ref[rows, :] = jnp.broadcast_to(l_ref[rows, :1] * alpha + l,
+                                          (height, _LANES))
 
-    # For causal, the last contributing K block for this Q block is the one
-    # covering the diagonal, not num_kblocks-1.
-    if causal:
-        last_ik = jnp.minimum(
-            (iq * block_q + block_q - 1) // block_k, num_kblocks - 1)
-    else:
-        last_ik = num_kblocks - 1
+    _run_plan(schedule, iq * block_q, ik * block_k, causal, seq_len, body)
 
-    @pl.when(ik == last_ik)
-    def _finalize():
-        l = l_ref[:, :1]
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-37)).astype(o_ref.dtype)
-        # LSE is lane-replicated (block_q, LANES) — Mosaic needs the last
-        # two block dims tileable; callers slice [..., 0].
-        lse_ref[0] = m_ref[...] + jnp.log(jnp.maximum(l_ref[...], 1e-37))
+    if carried:
+        # For causal, the last contributing K block for this Q block is
+        # the one covering the diagonal, not num_kblocks-1.
+        if causal:
+            last_ik = jnp.minimum(
+                (iq * block_q + block_q - 1) // block_k, num_kblocks - 1)
+        else:
+            last_ik = num_kblocks - 1
+
+        @pl.when(ik == last_ik)
+        def _finalize():
+            finish(slice(None), m_ref[...], l_ref[...], acc_ref[...])
 
 
 def _inherit_vma(*xs) -> frozenset:
@@ -209,10 +396,12 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret, seq_len,
     assert bq and bk, (s, block_q, block_k)  # wrapper pads unalignable S
     nq, nk = s // bq, s // bk
     vma = _inherit_vma(q, k, v)
+    schedule = causal_schedule(s, bq, bk, causal, seq_len)
+    _count_score_blocks(schedule, bh)
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal,
-        block_q=bq, block_k=bk, num_kblocks=nk,
+        block_q=bq, block_k=bk, schedule=schedule, num_kblocks=nk,
         seq_len=None if seq_len == s else seq_len)
 
     def kv_index(b, i, j):
@@ -239,11 +428,12 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret, seq_len,
             _sds((bh, s, d), q.dtype, vma=vma),
             _sds((bh, s, _LANES), jnp.float32, vma=vma),
         ],
+        # the online-softmax state, carried from K block to K block
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),
-        ],
+        ] if nk > 1 else [],
         compiler_params=_tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         name="flash_fwd",
@@ -315,7 +505,8 @@ def _bwd_blockwise(q, k, v, out, lse, do, causal, scale, block_k, seq_len,
 
 def _bwd_fused_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
                       dqp_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale,
-                      causal, block_q, block_k, num_qblocks, group, seq_len):
+                      causal, block_q, block_k, schedule, num_qblocks,
+                      group, seq_len):
     """Fused backward: ONE kernel produces dk, dv AND dq.
 
     Grid ``(B·H_kv, S/block_k, group, S/block_q)`` with the (group, Q)
@@ -336,55 +527,60 @@ def _bwd_fused_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
     one XLA sum over nk finishes the job — O(nk·S·D) fp32 transient,
     ~0.7 ms of the ~5 ms the fusion saves at S=8192."""
     jk, g, iq = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    sub_q, sub_k = schedule["sub_q"], schedule["sub_k"]
 
     @pl.when(jnp.logical_and(g == 0, iq == 0))
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    tail = seq_len is not None
-    run = (iq * block_q + block_q - 1 >= jk * block_k) if causal else True
-    if tail:
-        run = jnp.logical_and(run, iq * block_q < seq_len)
-
-    @pl.when(run)
-    def _body():
-        k, v, q, do = k_ref[0], v_ref[0], q_ref[0], do_ref[0]
-        lse = lse_ref[0, 0, pl.dslice(iq * block_q, block_q)]
-        delta = delta_ref[0, 0, pl.dslice(iq * block_q, block_q)]
+    def body(first, count, n_full, n_masked):
+        """The ``count`` Q sub-blocks from ``first`` against the cell's
+        first ``n_full`` K sub-blocks unmasked and the next ``n_masked``
+        under the mask; their dq for this cell is one float32 product over
+        both, rounded to the slab's dtype once.  With nothing to compute
+        they write zeros: the sum outside reads every slab slice, and an
+        unwritten one would be uninitialized memory."""
+        rows = slice(first * sub_q, (first + count) * sub_q)
+        height = count * sub_q
+        row0 = iq * block_q + first * sub_q
+        if sub_q % _LANES == 0:
+            row0 = pl.multiple_of(row0, _LANES)
+        lo, hi = n_full * sub_k, (n_full + n_masked) * sub_k
+        if not hi:
+            dqp_ref[0, 0, rows, :] = jnp.zeros_like(dqp_ref[0, 0, rows, :])
+            return
+        q, do = q_ref[0, rows, :], do_ref[0, rows, :]
+        k, v = k_ref[0, :hi, :], v_ref[0, :hi, :]
+        lse = lse_ref[0, 0, pl.ds(row0, height)]
+        delta = delta_ref[0, 0, pl.ds(row0, height)]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (bq, bk)
-        p = jnp.exp(s - lse[:, None])
-        if causal or tail:
-            q_pos = iq * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = jk * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            mask = (q_pos >= k_pos) if causal else (k_pos == k_pos)
-            if tail:
-                mask = jnp.logical_and(
-                    mask, jnp.logical_and(k_pos < seq_len, q_pos < seq_len))
-            p = jnp.where(mask, p, 0.0)
-        dv_acc[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # (bk, d)
+            preferred_element_type=jnp.float32) * scale      # (height, hi)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
+        yield
+        p = jnp.exp(s - lse[:, None])
+        if n_masked:
+            # only the columns from ``lo`` on can be hidden
+            tail = jnp.where(
+                _score_mask(row0, jk * block_k + lo, height, hi - lo, causal,
+                            seq_len, True), p[:, lo:], 0.0)
+            p = jnp.concatenate([p[:, :lo], tail], 1) if lo else tail
         ds = p * (dp - delta[:, None]) * scale
-        dk_acc[...] += jax.lax.dot_general(
+        yield
+        dv_acc[:hi, :] += jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)              # (hi, d)
+        dk_acc[:hi, :] += jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dqp_ref[0, 0] = jax.lax.dot_general(
+        dqp_ref[0, 0, rows, :] = jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(dqp_ref.dtype)  # bf16 partial: fp32 sum outside
+            preferred_element_type=jnp.float32).astype(dqp_ref.dtype)
 
-    @pl.when(jnp.logical_not(run))
-    def _skip():
-        # this cell's partial slice is summed unconditionally outside —
-        # unwritten blocks would be uninitialized memory, not zeros
-        dqp_ref[0, 0] = jnp.zeros_like(dqp_ref[0, 0])
+    _run_plan(schedule, iq * block_q, jk * block_k, causal, seq_len, body)
 
     @pl.when(jnp.logical_and(g == group - 1, iq == num_qblocks - 1))
     def _fin():
@@ -415,6 +611,8 @@ def _bwd_pallas(q, k, v, out, lse, do, causal, scale, block_q, block_k,
     lse = lse.astype(jnp.float32)[:, None, :]
     delta = delta[:, None, :]
     sl = None if seq_len == s else seq_len
+    schedule = causal_schedule(s, bq, bk, causal, seq_len)
+    _count_score_blocks(schedule, bh)
 
     def qdo_index(b, j, g, i):
         # Q blocks strictly above the diagonal (i·bq + bq − 1 < j·bk) are
@@ -427,7 +625,8 @@ def _bwd_pallas(q, k, v, out, lse, do, causal, scale, block_q, block_k,
     dq_part, dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_fused_kernel, scale=scale, causal=causal, block_q=bq,
-            block_k=bk, num_qblocks=nq, group=group, seq_len=sl),
+            block_k=bk, schedule=schedule, num_qblocks=nq, group=group,
+            seq_len=sl),
         grid=(bh_kv, nk, group, nq),
         in_specs=[
             pl.BlockSpec((1, bk, d), lambda b, j, g, i: (b, j, 0)),
@@ -558,8 +757,10 @@ def _bwd_dispatch(q, k, v, out, lse, do, causal, scale, block_q, block_k,
                     seq_len, group, dlse=dlse)
 
 
-@functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11))
+_STATIC = tuple(range(3, 12))    # every argument after q, k, v
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=_STATIC)
 def _flash_bhsd(q, k, v, causal, block_q, block_k, interpret, seq_len, group,
                 backward, bwd_block_q=None, bwd_block_k=None):
     scale = 1.0 / (q.shape[-1] ** 0.5)
@@ -588,8 +789,7 @@ def _flash_bhsd_bwd(causal, block_q, block_k, interpret, seq_len, group,
 _flash_bhsd.defvjp(_flash_bhsd_fwd, _flash_bhsd_bwd)
 
 
-@functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11))
+@functools.partial(jax.custom_vjp, nondiff_argnums=_STATIC)
 def _flash_bhsd_lse(q, k, v, causal, block_q, block_k, interpret, seq_len,
                     group, backward, bwd_block_q=None, bwd_block_k=None):
     """Like :func:`_flash_bhsd` but also returns the LSE as a DIFFERENTIABLE
@@ -622,6 +822,13 @@ def _flash_bhsd_lse_bwd(causal, block_q, block_k, interpret, seq_len,
 
 _flash_bhsd_lse.defvjp(_flash_bhsd_lse_fwd, _flash_bhsd_lse_bwd)
 
+# A model calls these once a layer with the same shapes; under jit the
+# kernels (forward, and backward through the pjit's own rules) are traced
+# and lowered once per shape and not once per call — a kernel body holds
+# every plan of its schedule, and 24 layers of them were seconds of set-up.
+_flash_bhsd_jit = jax.jit(_flash_bhsd, static_argnums=_STATIC)
+_flash_bhsd_lse_jit = jax.jit(_flash_bhsd_lse, static_argnums=_STATIC)
+
 
 def flash_attention(q, k, v, causal: bool = False,
                     block_q: Optional[int] = None,
@@ -640,28 +847,36 @@ def flash_attention(q, k, v, causal: bool = False,
     the tail masked inside the kernel.  Differentiable via the blockwise
     LSE backward; O(S·block) live memory both directions.
 
-    Default blocks (``block_q/block_k=None``) are tuned on TPU v5e:
-    128×128 leaves the grid too fine (measured ~5× slower at S=1024 —
-    per-cell overhead dominates the two (block_q × d × block_k) MXU
-    issues).  512×1024 amortises it at short S; from S ≥ 2048 the
-    forward measurably prefers 1024×1024 (S=8192: 6.11 → 4.92 ms,
-    docs/PERF.md long-context round 4) and the fp32 score tile (4 MB)
-    still fits VMEM, so the q block widens automatically.  Explicit
-    values are always honored.
+    Default blocks (``block_q/block_k=None``): 512 × 1024, and 1024 × 1024
+    from S ≥ 2048 (the fp32 score tile, 4 MB, still fits VMEM); explicit
+    values are always honored.  The grid is kept that coarse because a grid
+    step is dear at head 64; what a cell computes is decided per SUB-BLOCK
+    of 128 (``_SUB_BLOCK``, :func:`causal_schedule`): with ``causal=True``
+    each run of Q rows computes one product over the K sub-blocks at or
+    below its diagonal and masks only those the diagonal crosses, so at
+    S = 1024 the kernels compute 36 of the 64 sub-block pairs and mask 8,
+    where one cell per K block computed and masked all 64.  Where one K
+    block holds the whole row (S ≤ 1024 at the defaults) the forward
+    carries no online-softmax state at all.  Measured on the v5e under jax
+    0.9.0 (scan-chained, ``scripts/tune_flash_bwd.py``; PERF.md Findings
+    PR 30), undivided cell → this schedule: B 8, H 16, S 1024, D 64
+    causal, forward 0.737 → 0.378 ms, backward 1.221 → 0.907 ms; the same
+    shape not causal, forward 0.752 → 0.531 ms (no carried state), backward
+    unchanged; H 128, D 192 causal forward, S 1024 1.213 → 0.669 ms,
+    S 3072 7.30 → 5.87 ms; B 2, H 16, S 8192, D 64, forward 5.71 → 5.46 ms,
+    backward 10.66 → 9.31 ms.
 
     ``backward`` selects the gradient path: ``'pallas'`` — the ONE fused
     dq/dk/dv kernel (blockwise LSE recompute in VMEM, fp32 dk/dv scratch,
-    input-dtype dq partials + fp32 XLA sum, causal cells skipped with
-    their DMA elided, GQA group-fold in-scratch);
+    input-dtype dq partials + fp32 XLA sum, the same sub-block schedule,
+    dead cells' DMA elided, GQA group-fold in-scratch);
     ``'xla'`` — the lax.scan blockwise recompute; ``'auto'`` — Pallas
     whenever the block geometry is Mosaic-aligned (any S that is a multiple
     of 128 after padding), else XLA.
 
-    ``bwd_block_q``/``bwd_block_k`` (default None → 512x2048, v5e-tuned)
-    tile the BACKWARD independently of the forward: the optima differ
-    (S=16384 measured: bwd 512x2048 vs the forward-optimal 1024x1024 is
-    ~2-5% end to end; S=4096 fwd+bwd improved 0.30 → 0.47 attn-MFU when
-    the backward stopped inheriting the forward's 1024-wide q block).
+    ``bwd_block_q``/``bwd_block_k`` (default None → 512 × 2048) tile the
+    BACKWARD independently of the forward: its five-product body wants a
+    wider K block than the forward's two.
 
     ``return_lse=True`` additionally returns the per-query log-sum-exp
     ``(B, H, S)`` as a differentiable output (the block-merge currency of
@@ -704,12 +919,12 @@ def flash_attention(q, k, v, causal: bool = False,
         return x.transpose(0, 2, 1, 3).reshape(b * nh, s_pad, x.shape[-1])
 
     if return_lse:
-        out, lse = _flash_bhsd_lse(to_bhsd(q), to_bhsd(k), to_bhsd(v),
-                                   causal, block_q, block_k, interpret, s,
-                                   group, backward, bwd_block_q, bwd_block_k)
+        out, lse = _flash_bhsd_lse_jit(
+            to_bhsd(q), to_bhsd(k), to_bhsd(v), causal, block_q, block_k,
+            interpret, s, group, backward, bwd_block_q, bwd_block_k)
         return (out.reshape(b, h, s_pad, d)[:, :, :s].transpose(0, 2, 1, 3),
                 lse.reshape(b, h, s_pad)[:, :, :s])
-    out = _flash_bhsd(to_bhsd(q), to_bhsd(k), to_bhsd(v),
-                      causal, block_q, block_k, interpret, s, group,
-                      backward, bwd_block_q, bwd_block_k)
+    out = _flash_bhsd_jit(to_bhsd(q), to_bhsd(k), to_bhsd(v),
+                          causal, block_q, block_k, interpret, s, group,
+                          backward, bwd_block_q, bwd_block_k)
     return out.reshape(b, h, s_pad, d)[:, :, :s].transpose(0, 2, 1, 3)

@@ -1,15 +1,24 @@
 #!/usr/bin/env python
-"""Long-context flash backward block hunt.
+"""Flash attention sweep on the chip: the sub-block edge inside a grid
+cell, and the grid's blocks.
 
-S=8k/16k attention MFU sat at 0.22-0.245 vs 0.50+ for the same kernels at
-S=1k.  This sweep times forward-only and forward+backward separately per
-(block_q, block_k) so the slow half is identified rather than guessed, on
-the real chip with the scan-chain method (one readback per rep chain;
-nothing is subtracted from a measured wall time).
+Times forward-only and forward+backward separately per point, so the slow
+half is identified rather than guessed, on the real chip with the
+scan-chain method (one readback per rep chain; nothing is subtracted from a
+measured wall time).  The default shape is the train cell's
+(``gpt2-medium-train-s1024``: B 8, H 16, S 1024, D 64, causal, bf16); the
+sub-block edge (``ops/flash_attention.py::_SUB_BLOCK``, a module constant)
+is swept by setting it before each trace.
 
-Usage: python scripts/tune_flash_bwd.py [S]
+Usage:
+  python scripts/tune_flash_bwd.py                       # the cell, edges 128/256/512
+  python scripts/tune_flash_bwd.py --seq 8192 --batch 2 --head-dim 128 \\
+      --blocks 512x1024,1024x1024,512x2048 --sub 256     # the grid's blocks
+  python scripts/tune_flash_bwd.py --heads 128 --batch 1 --head-dim 192 \\
+      --seq 3072 --fwd-only                              # a prefill's shape
 """
 
+import argparse
 import json
 import sys
 import time
@@ -20,7 +29,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chainermn_tpu.ops.flash_attention import flash_attention
+import chainermn_tpu  # noqa: F401  (the package's `ops` re-exports the function
+#                       under the module's name: take the module itself)
+
+fa = sys.modules["chainermn_tpu.ops.flash_attention"]
 
 PEAK = 197e12
 
@@ -42,53 +54,70 @@ def timed_ms(fn, x, reps):
     return best * 1e3
 
 
+def _pairs(text):
+    return [tuple(int(n) for n in p.split("x")) for p in text.split(",")]
+
+
 def main():
-    S = int(sys.argv[1]) if len(sys.argv) > 1 else 8192
-    B = 2 if S <= 8192 else 1
-    H, D = 16, 64
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--batch", type=int, default=8)
+    ap.add_argument("--heads", type=int, default=16)
+    ap.add_argument("--seq", type=int, default=1024)
+    ap.add_argument("--head-dim", type=int, default=64)
+    ap.add_argument("--causal", type=int, default=1)
+    ap.add_argument("--sub", default="128,256,512",
+                    help="sub-block edges to sweep (comma separated)")
+    ap.add_argument("--blocks", default=None,
+                    help="grid blocks to sweep, QxK comma separated "
+                         "(default: the kernels' own defaults)")
+    ap.add_argument("--fwd-only", action="store_true")
+    ap.add_argument("--reps", type=int, default=20)
+    a = ap.parse_args()
+
+    B, S, H, D, causal = a.batch, a.seq, a.heads, a.head_dim, bool(a.causal)
     rs = np.random.RandomState(0)
     q = jax.device_put(rs.randn(B, S, H, D).astype(jnp.bfloat16))
-    flops_fwd = 2 * 2 * B * H * S * S * D / 2
+    flops_fwd = 2 * 2 * B * H * S * S * D / (2 if causal else 1)
     flops_fb = flops_fwd * 3.5
-    reps = 20 if S <= 8192 else 12
 
-    for bq, bk in ((512, 1024), (512, 512), (1024, 512), (1024, 1024),
-                   (256, 1024), (2048, 512), (512, 2048), (2048, 1024),
-                   (1024, 2048)):
-        def fwd(c, bq=bq, bk=bk):
-            return flash_attention(c, c, c, causal=True,
-                                   block_q=bq, block_k=bk)
+    for sub in (int(e) for e in a.sub.split(",")):
+        for bq, bk in (_pairs(a.blocks) if a.blocks else [(None, None)]):
+            fa._SUB_BLOCK = sub
 
-        def fb(c, bq=bq, bk=bk):
-            # Sweep the BACKWARD blocks too: since the late-round-4
-            # decoupling, the backward no longer reads the forward's
-            # blocks, so a forward-only sweep would time the fixed
-            # bwd default at every point.
-            o, vjp = jax.vjp(lambda a: flash_attention(
-                a, a, a, causal=True, block_q=bq, block_k=bk,
-                bwd_block_q=bq, bwd_block_k=bk), c)
-            (dq,) = vjp(o)
-            return dq
+            def fwd(c, bq=bq, bk=bk):
+                return fa.flash_attention(c, c, c, causal=causal,
+                                          block_q=bq, block_k=bk)
 
-        row = {"S": S, "bq": bq, "bk": bk}
-        try:
-            ms_f = timed_ms(fwd, q, reps)
-            row["fwd_ms"] = round(ms_f, 2)
-            row["fwd_mfu"] = round(flops_fwd / (ms_f / 1e3) / PEAK, 3)
-        except Exception as e:
-            row["fwd_err"] = repr(e)[:120]
-        try:
-            ms_fb = timed_ms(fb, q, reps)
-            row["fb_ms"] = round(ms_fb, 2)
-            row["fb_mfu"] = round(flops_fb / (ms_fb / 1e3) / PEAK, 3)
-            if "fwd_ms" in row:
-                bwd = ms_fb - row["fwd_ms"]
-                row["bwd_ms"] = round(bwd, 2)
-                row["bwd_mfu"] = round(
-                    (flops_fb - flops_fwd) / (bwd / 1e3) / PEAK, 3)
-        except Exception as e:
-            row["fb_err"] = repr(e)[:120]
-        print(json.dumps(row), flush=True)
+            def fb(c, bq=bq, bk=bk):
+                # the backward's blocks are its own (it does not read the
+                # forward's), so a swept pair is given to both
+                o, vjp = jax.vjp(lambda x: fa.flash_attention(
+                    x, x, x, causal=causal, block_q=bq, block_k=bk,
+                    bwd_block_q=bq, bwd_block_k=bk), c)
+                (dq,) = vjp(o)
+                return dq
+
+            row = {"B": B, "H": H, "S": S, "D": D, "causal": causal,
+                   "sub": sub, "bq": bq, "bk": bk}
+            try:
+                ms_f = timed_ms(fwd, q, a.reps)
+                row["fwd_ms"] = round(ms_f, 4)
+                row["fwd_mfu"] = round(flops_fwd / (ms_f / 1e3) / PEAK, 3)
+            except Exception as e:
+                row["fwd_err"] = repr(e)[:200]
+            if not a.fwd_only:
+                try:
+                    ms_fb = timed_ms(fb, q, a.reps)
+                    row["fb_ms"] = round(ms_fb, 4)
+                    row["fb_mfu"] = round(flops_fb / (ms_fb / 1e3) / PEAK, 3)
+                    if "fwd_ms" in row:
+                        bwd = ms_fb - row["fwd_ms"]
+                        row["bwd_ms"] = round(bwd, 4)
+                        row["bwd_mfu"] = round(
+                            (flops_fb - flops_fwd) / (bwd / 1e3) / PEAK, 3)
+                except Exception as e:
+                    row["fb_err"] = repr(e)[:200]
+            print(json.dumps(row), flush=True)
 
 
 if __name__ == "__main__":

@@ -107,6 +107,39 @@ def test_flash_attention_fwd_bwd(one_chip):
     assert n >= 2       # forward + backward kernels
 
 
+# (batch, seq, heads, head width, backward too): the benchmark's cells at
+# their own shapes — a grid cell there is wider than a sub-block, so the
+# kernels' sub-block loops (traced trip counts, sublane slices of Q/K/V,
+# lane slices of the LSE row) meet the chip's compiler here
+FLASH_CELL_SHAPES = {
+    "gpt2-medium-train-s1024": (8, 1024, 16, 64, True),
+    "deepseek-v3-prefill-1024": (1, 1024, 128, 192, False),
+    "deepseek-v3-prefill-3072": (1, 3072, 128, 192, False),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(FLASH_CELL_SHAPES))
+def test_flash_attention_at_the_cells_shapes(one_chip, cell):
+    from chainermn_tpu.ops.flash_attention import flash_attention
+
+    b, s, h, d, bwd = FLASH_CELL_SHAPES[cell]
+    q = _sds((b, s, h, d), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    if bwd:
+        n = _n_kernels(jax.grad(loss, argnums=(0, 1, 2)), q, q, q,
+                       names=("flash_fwd", "flash_bwd"))
+        assert n >= 2
+    else:
+        n = _n_kernels(partial(flash_attention, causal=True,
+                               interpret=False), q, q, q,
+                       names=("flash_fwd",))
+        assert n >= 1
+
+
 def test_fused_cross_entropy_fwd_bwd(one_chip):
     from chainermn_tpu.ops.fused_ce import fused_cross_entropy
 

@@ -278,3 +278,233 @@ class TestLaneBlockPicker:
             *a, causal=True, backward="xla")))(q)
         np.testing.assert_allclose(np.asarray(g_pallas), np.asarray(g_xla),
                                    rtol=2e-4, atol=2e-4)
+
+
+def _module():
+    """The MODULE (``chainermn_tpu.ops`` re-exports the function under the
+    same name, which shadows the attribute)."""
+    import sys
+
+    return sys.modules["chainermn_tpu.ops.flash_attention"]
+
+
+def _qkv_default(s, d=64, h=2, h_kv=None, seed=5, scale=1.0):
+    rng = np.random.RandomState(seed)
+    q = (rng.randn(1, s, h, d) * scale).astype(np.float32)
+    k, v = ((rng.randn(1, s, h_kv or h, d) * scale).astype(np.float32)
+            for _ in range(2))
+    return q, k, v
+
+
+def _assert_grads_match(q, k, v, ref_fn, rtol, atol, **flash_kw):
+    """d/d(q, k, v) of sum(out²): ``flash_attention(**flash_kw)`` against
+    the materialising ``ref_fn``."""
+    got = jax.grad(lambda *a: (flash_attention(*a, **flash_kw) ** 2).sum(),
+                   argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: (ref_fn(*a) ** 2).sum(),
+                    argnums=(0, 1, 2))(q, k, v)
+    for g, w, name in zip(got, want, "qkv"):
+        assert g.shape == w.shape, name
+        assert np.all(np.isfinite(np.asarray(g))), name
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=rtol,
+                                   atol=atol, err_msg=f"grad wrt {name}")
+
+
+class TestSubBlockSchedule:
+    """The DEFAULT blocks (no explicit ``block_*``), where a grid cell is
+    wider than a sub-block: the kernels walk it by sub-block, skip the
+    pairs above the diagonal, run the pairs below it unmasked and mask only
+    those the diagonal (or a padded tail) crosses."""
+
+    @pytest.mark.parametrize("s", [256, 768, 1024])
+    def test_causal_forward_at_default_blocks(self, s):
+        q, k, v = _qkv_default(s)
+        got = flash_attention(q, k, v, causal=True)
+        np.testing.assert_allclose(np.asarray(got),
+                                   np.asarray(reference(q, k, v, True)),
+                                   rtol=2e-4, atol=2e-5)
+
+    @pytest.mark.parametrize("backward", ["pallas", "xla"])
+    @pytest.mark.parametrize("s", [256, 768, 1024])
+    def test_causal_gradients_at_default_blocks(self, s, backward):
+        q, k, v = _qkv_default(s)
+        _assert_grads_match(q, k, v, lambda *a: reference(*a, True), 5e-4,
+                            5e-5, causal=True, backward=backward)
+
+    @pytest.mark.parametrize("backward", ["pallas", "xla"])
+    def test_latent_prefill_widths(self, backward):
+        """q/k 192 wide, v zero-padded from 128 to 192 (the MLA prefill's
+        call, ``parallel/blocks.py``), S 1024."""
+        q, k, v = _qkv_default(1024, d=192, scale=0.5)
+        v[..., 128:] = 0.0
+        out = flash_attention(q, k, v, causal=True)
+        assert not np.asarray(out)[..., 128:].any()
+        np.testing.assert_allclose(np.asarray(out),
+                                   np.asarray(reference(q, k, v, True)),
+                                   rtol=2e-4, atol=2e-5)
+        _assert_grads_match(q, k, v, lambda *a: reference(*a, True), 5e-4,
+                            5e-5, causal=True, backward=backward)
+
+    @pytest.mark.parametrize("backward", ["pallas", "xla"])
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_padded_tail_at_default_blocks(self, causal, backward):
+        """S 1000 pads to 1024: the tail mask stays on the sub-blocks that
+        hold the tail, and only there."""
+        q, k, v = _qkv_default(1000)
+        got = flash_attention(q, k, v, causal=causal)
+        assert got.shape == q.shape
+        np.testing.assert_allclose(np.asarray(got),
+                                   np.asarray(reference(q, k, v, causal)),
+                                   rtol=2e-4, atol=2e-5)
+        _assert_grads_match(q, k, v, lambda *a: reference(*a, causal), 2e-3,
+                            2e-4, causal=causal, backward=backward)
+
+    @pytest.mark.parametrize("backward", ["pallas", "xla"])
+    def test_gqa_group_2_at_default_blocks(self, backward):
+        q, k, v = _qkv_default(1024, h=4, h_kv=2)
+        ref = TestGQA()._reference_gqa
+        np.testing.assert_allclose(
+            np.asarray(flash_attention(q, k, v, causal=True)),
+            np.asarray(ref(q, k, v, causal=True)), rtol=2e-4, atol=2e-5)
+        _assert_grads_match(q, k, v, lambda *a: ref(*a, causal=True), 2e-3,
+                            2e-4, causal=True, backward=backward)
+
+    def test_return_lse_at_default_blocks(self):
+        """The LSE output and the gradient through it (ring attention's
+        merge weights), Pallas against the XLA oracle."""
+        q, k, v = _qkv_default(1024)
+        out, lse = flash_attention(q, k, v, causal=True, return_lse=True)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / 8.0
+        s = jnp.where(np.tril(np.ones((1024, 1024), bool))[None, None],
+                      s, -1e30)
+        np.testing.assert_allclose(
+            np.asarray(lse), np.asarray(jax.nn.logsumexp(s, -1)),
+            rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(np.asarray(out),
+                                   np.asarray(reference(q, k, v, True)),
+                                   rtol=2e-4, atol=2e-5)
+
+        def grads(backward):
+            def f(q, k, v):
+                o, l = flash_attention(q, k, v, causal=True,
+                                       return_lse=True, backward=backward)
+                return (o ** 2).sum() + jnp.sin(l).sum()
+            return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+
+        for g, w, name in zip(grads("pallas"), grads("xla"), "qkv"):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       rtol=1e-4, atol=1e-5,
+                                       err_msg=f"grad wrt {name}")
+
+    @pytest.mark.parametrize("backward", ["pallas", "xla"])
+    def test_non_causal_at_default_blocks(self, backward):
+        """``causal=False`` computes every sub-block, none masked, inside
+        the tolerances the non-causal tests above hold."""
+        q, k, v = _qkv_default(1024)
+        np.testing.assert_allclose(np.asarray(flash_attention(q, k, v)),
+                                   np.asarray(reference(q, k, v)),
+                                   rtol=2e-4, atol=2e-5)
+        _assert_grads_match(q, k, v, reference, 5e-4, 5e-5, backward=backward)
+
+    # (s, block_q, block_k, causal, seq_len, edge) -> (run, masked, total)
+    SCHEDULES = [
+        ((1024, 512, 1024, True, None, 256), (10, 4, 16)),
+        ((1024, 512, 1024, True, None, 128), (36, 8, 64)),
+        # nothing to mask or skip: the cell is not divided (2 cells a head)
+        ((1024, 512, 1024, False, None, 256), (2, 0, 2)),
+        ((1024, 512, 1024, False, None, 128), (2, 0, 2)),
+        # the grid's own skip still counts: 4 cells of 256, 3 run
+        ((512, 256, 256, True, None, 256), (3, 2, 4)),
+        # a block no lane multiple divides is one sub-block
+        ((64, 32, 32, True, None, 256), (3, 2, 4)),
+        # S 1000 padded to 1024: the last Q sub-block holds padded rows and
+        # is masked throughout; the last K sub-block holds padded columns
+        ((1024, 512, 1024, True, 1000, 256), (10, 7, 16)),
+        ((1024, 512, 1024, False, 1000, 256), (16, 7, 16)),
+        # the serving prefill's 768 bucket, blocks 384 x 768: 256 does not
+        # divide 384, so Q goes in 128s against K in 256s
+        ((768, 384, 768, True, None, 256), (12, 6, 18)),
+        ((2048, 1024, 1024, True, None, 256), (36, 8, 64)),
+    ]
+
+    @pytest.mark.parametrize("args,want", SCHEDULES)
+    def test_causal_schedule_table(self, args, want, monkeypatch):
+        s, bq, bk, causal, seq_len, edge = args
+        mod = _module()
+        monkeypatch.setattr(mod, "_SUB_BLOCK", edge)
+        got = mod.causal_schedule(s, bq, bk, causal, seq_len)
+        assert (got["run"], got["masked"], got["total"]) == want
+        assert got["total"] == (s // got["sub_q"]) * (s // got["sub_k"])
+        # a plan says, for each Q sub-block of a cell, how many of the
+        # cell's K sub-blocks it computes unmasked and masked
+        assert all(len(plan) == bq // got["sub_q"] for plan in got["plans"])
+        assert all(n_full + n_masked <= bk // got["sub_k"]
+                   for plan in got["plans"] for n_full, n_masked in plan)
+
+    def test_schedule_is_what_the_kernels_compute(self, monkeypatch):
+        """A K sub-block the schedule skips is never read: poison every
+        key and value above each Q sub-block's diagonal reach with NaN-free
+        but enormous values at a row the mask would hide anyway — the
+        result must not move (it would through a 0·inf if computed)."""
+        mod = _module()
+        s = 1024
+        q, k, v = _qkv_default(s, h=1)
+        base = np.asarray(flash_attention(q, k, v, causal=True))
+        # rows 0..255 may only see keys 0..255: an inf in v beyond is
+        # multiplied by an exact 0 (NaN) if its sub-block is computed
+        v2 = v.copy()
+        v2[:, 256:] = np.inf
+        got = np.asarray(flash_attention(q, k, v2, causal=True))
+        sub = mod.causal_schedule(s, 512, 1024)["sub_q"]
+        assert sub <= 256
+        np.testing.assert_array_equal(got[:, :256], base[:, :256])
+
+    def test_counters_recorded_once_per_traced_call(self):
+        from chainermn_tpu import observability as obs
+        from chainermn_tpu.observability import trace
+
+        mod = _module()
+        # a head count no other test has: the kernels are traced once per
+        # shape in a process (flash_attention's inner jit), and booked then
+        q, k, v = (jnp.asarray(x) for x in _qkv_default(1024, h=3))
+        sched = mod.causal_schedule(1024, 512, 1024)
+        heads = q.shape[0] * q.shape[2]
+        was = trace.get_tracer().enabled
+        obs.enable()
+        try:
+            trace.get_tracer().reset()
+            fwd = jax.jit(lambda q, k, v: flash_attention(q, k, v,
+                                                          causal=True))
+            fwd(q, k, v)
+            want = {f"flash/score_blocks_{key}": float(heads * sched[key])
+                    for key in ("run", "masked", "total")}
+            counters = trace.get_tracer().counters()
+            assert {n: counters[n] for n in want} == want
+            fwd(q, k, v)        # a cache hit traces nothing, books nothing
+            counters = trace.get_tracer().counters()
+            assert {n: counters[n] for n in want} == want
+            # nor does a second call site of the same shape (a model's
+            # next layer): one trace, one booking
+            jax.jit(lambda q, k, v: flash_attention(
+                flash_attention(q, k, v, causal=True), k, v,
+                causal=True))(q, k, v)
+            counters = trace.get_tracer().counters()
+            assert {n: counters[n] for n in want} == want
+            # forward + backward
+            trace.get_tracer().reset()
+            jax.jit(jax.grad(lambda q: flash_attention(
+                q, k, v, causal=True, backward="pallas").sum()))(q)
+            counters = trace.get_tracer().counters()
+            # whole traces only: the forward rule's and the backward's at
+            # the least (the primal's too where jit traces it)
+            traces, rest = divmod(counters["flash/score_blocks_total"],
+                                  heads * sched["total"])
+            assert rest == 0 and traces >= 2
+            assert (counters["flash/score_blocks_run"]
+                    == traces * heads * sched["run"])
+            assert (counters["flash/score_blocks_masked"]
+                    == traces * heads * sched["masked"])
+        finally:
+            trace.get_tracer().reset()
+            if not was:
+                obs.disable()
