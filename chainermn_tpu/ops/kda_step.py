@@ -1,0 +1,137 @@
+"""One step of a gated delta rule on a per-slot recurrent state, in place.
+
+A delta-rule linear-attention layer keeps, for each sequence and head, one
+``(d_k, d_v)`` float32 state ``S`` instead of a row a token.  A decode tick
+moves every BUSY slot's state one token on::
+
+    S' = Diag(exp(g)) S
+    S_new = S' + beta k (v - S'^T k)^T
+    o = S_new^T q
+
+and has to leave every other slot's state as it is, bit for bit: a free
+slot's state is the next occupant's zero start and a cached slot's state is
+what a prefix hit copies.  So the kernel does not run "every slot, then
+select": the busy slots are compacted to the front of the grid's slot axis
+by a scalar-prefetched index vector, the steps past the last busy slot map
+to the block the last busy step already holds (no fetch, no write-back:
+``ops/moe_gmm.py``'s rule), and the state operand is aliased to the state
+result, so the blocks no step maps to are never touched.  What a tick reads
+and writes of the pool's state is then the busy slots' share, not the pool.
+
+The work of a step is a handful of passes over ``S`` on the vector unit
+(decay, ``S'^T k``, the rank-1 update, the read-out), all float32: the
+state is the layer's memory of the whole sequence and a rounding here is
+carried to every later token.  The column forms of ``k``, ``q`` and the
+decay come from one in-kernel transpose of an ``(heads, d_k)`` tile each.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .._compat import shape_dtype_struct as _sds
+
+__all__ = ["kda_step", "kda_step_xla"]
+
+
+def kda_step_xla(q, k, v, g, beta, state, busy):
+    """The same step in plain ``jax.numpy`` (other backends, and the
+    kernel's oracle): ``q, k, g (N, H, d_k)``, ``v (N, H, d_v)``, ``beta
+    (N, H)``, ``state (N, H, d_k, d_v)`` float32, ``busy (N,) bool``.
+    Returns ``(o (N, H, d_v), new state)``; rows that are not busy keep
+    their state and read 0."""
+    s_dec = state * jnp.exp(g)[..., None]
+    u = (s_dec * k[..., None]).sum(-2)
+    r = beta[..., None] * (v - u)
+    s_new = s_dec + k[..., None] * r[..., None, :]
+    o = (s_new * q[..., None]).sum(-2)
+    keep = busy[:, None, None]
+    return (jnp.where(keep, o, 0.0),
+            jnp.where(keep[..., None], s_new, state))
+
+
+def _kernel(slot_ref, n_busy_ref, qkg_ref, vb_ref, s_ref, o_ref, so_ref):
+    del slot_ref                              # read by the index maps only
+    n_heads = s_ref.shape[1]
+
+    @pl.when(pl.program_id(1) < n_busy_ref[0])
+    def _step():
+        # (heads, d_k) tiles to (d_k, heads): column j is head j's vector
+        q_t = qkg_ref[0, 0].T
+        k_t = qkg_ref[0, 1].T
+        a_t = jnp.exp(qkg_ref[0, 2]).T
+        for j in range(n_heads):
+            k_col = k_t[:, j:j + 1]
+            s_dec = s_ref[0, j] * a_t[:, j:j + 1]
+            u = (s_dec * k_col).sum(0, keepdims=True)            # (1, d_v)
+            r = vb_ref[0, 1, j:j + 1] * (vb_ref[0, 0, j:j + 1] - u)
+            s_new = s_dec + k_col * r
+            so_ref[0, j] = s_new
+            o_ref[0, j:j + 1] = (s_new * q_t[:, j:j + 1]).sum(
+                0, keepdims=True)
+
+    @pl.when(n_busy_ref[0] == 0)
+    def _nothing_busy():
+        # every step maps to one block; hand it back as it came
+        so_ref[...] = s_ref[...]
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+def _head_block(n_heads: int) -> int:
+    return 8 if n_heads % 8 == 0 else n_heads
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def kda_step(q, k, v, g, beta, state, busy, *, interpret: bool = False):
+    """:func:`kda_step_xla` as one Pallas pass over the busy slots'
+    state, written in place (``state`` is aliased to the result: donate
+    it).  Shapes as there; ``d_k`` and ``d_v`` whole lane tiles on a TPU."""
+    n, h, dk = q.shape
+    dv = v.shape[-1]
+    hb = _head_block(h)
+    f32 = jnp.float32
+    n_busy = busy.sum().astype(jnp.int32)
+    # busy slots first, in slot order; the rest of the axis repeats the
+    # last busy slot, whose blocks the kernel then already holds
+    order = jnp.argsort(~busy, stable=True).astype(jnp.int32)
+    slots = jnp.where(jnp.arange(n) < n_busy, order,
+                      order[jnp.maximum(n_busy - 1, 0)])
+    qkg = jnp.stack([q, k, g], axis=1).astype(f32)            # (N, 3, H, dk)
+    vb = jnp.stack([v.astype(f32), jnp.broadcast_to(
+        beta.astype(f32)[..., None], (n, h, dv))], axis=1)     # (N, 2, H, dv)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(h // hb, n),
+        in_specs=[
+            pl.BlockSpec((1, 3, hb, dk), lambda b, i, s, nb: (s[i], 0, b, 0)),
+            pl.BlockSpec((1, 2, hb, dv), lambda b, i, s, nb: (s[i], 0, b, 0)),
+            pl.BlockSpec((1, hb, dk, dv),
+                         lambda b, i, s, nb: (s[i], b, 0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, hb, dv), lambda b, i, s, nb: (s[i], b, 0)),
+            pl.BlockSpec((1, hb, dk, dv),
+                         lambda b, i, s, nb: (s[i], b, 0, 0)),
+        ])
+    vma = frozenset().union(*(getattr(getattr(a, "aval", None), "vma", None)
+                              or () for a in (q, state)))
+    o, new_state = pl.pallas_call(
+        _kernel,
+        grid_spec=grid_spec,
+        out_shape=[_sds((n, h, dv), f32, vma=vma),
+                   _sds(state.shape, f32, vma=vma)],
+        # operands count the two prefetched scalars: state is the fifth
+        input_output_aliases={4: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        name="kda_step",
+        interpret=interpret,
+    )(slots, n_busy.reshape(1), qkg, vb, state)
+    # a slot that is not busy was given no block: its read-out is whatever
+    # the buffer held, and must not reach the rows above a cached prefix
+    return jnp.where(busy[:, None, None], o, 0.0), new_state

@@ -22,14 +22,23 @@ Parameter layout per ``arch`` value (all GLOBAL arrays):
 * ``attn='mha'``: ``attn = {wqkv, bqkv, wo, bo}`` or the GQA ``wq/wkv``
   form; ``'mla'``: ``attn = {wdq (D, q_rank), q_norm, wuq (q_rank,
   H·(nope+rope)), wdkv (D, kv_rank+rope), kv_norm, wukv (kv_rank,
-  H·(nope+v)), wo (H·v, D)}``, head-major columns.
+  H·(nope+v)), wo (H·v, D)}``, head-major columns — with ``q_lora_rank =
+  None`` the queries are projected directly, ``wq (D, H·(nope+rope))`` in
+  place of ``wdq / q_norm / wuq``; ``'kda'`` (a gated delta-rule layer,
+  ``parallel/kda.py``): ``attn = {wqkv (D, 3·H·d), conv (W, 3·H·d), w_low
+  (D, 2·rank + H), wf_up / wg_up (rank, H·d), dt_bias (H·d,), a_log (H,),
+  o_norm (d,), wo (H·d, D)}``.
+* ``attn_kinds``: the attention kind of each LAYER where a model mixes
+  them (None: every layer is ``attn``), as ``layer_kinds`` is for the MLP.
 * ``tied_head=False``: ``params['head'] (V, D)`` beside ``params['embed']``.
 
-What a layer's attention keeps per token is DECLARED here
-(:func:`cache_layout`) and the serving pool allocates exactly that: an
-MHA/GQA layer a ``(k, v)`` pair of ``n_kv·head_dim`` columns sharded over
-the model axis, an MLA layer ONE latent buffer ``[c_kv | RoPE(k_rope) |
-zero pad]``, replicated.
+What a layer's attention keeps is DECLARED here (:func:`cache_layout`) and
+the serving pool allocates exactly that.  ROWS, one a token: an MHA/GQA
+layer a ``(k, v)`` pair of ``n_kv·head_dim`` columns sharded over the model
+axis, an MLA layer ONE latent buffer ``[c_kv | RoPE(k_rope) | zero pad]``,
+replicated.  STATE, one a sequence whatever its length: a KDA layer its
+``(H, d, d)`` float32 recurrent state and the last ``W - 1`` rows of its
+fused projection.
 """
 
 from __future__ import annotations
@@ -49,7 +58,7 @@ _LANES = 128
 class MLAConfig:
     """Multi-head latent attention: the numbers the shapes do not give."""
     n_heads: int
-    q_lora_rank: int
+    q_lora_rank: Optional[int]          # None: queries projected directly
     kv_lora_rank: int
     qk_nope_head_dim: int
     qk_rope_head_dim: int
@@ -58,6 +67,9 @@ class MLAConfig:
     #: YaRN: ``(factor, original_max_position, beta_fast, beta_slow,
     #: mscale, mscale_all_dim)`` or None for plain rotary
     yarn: Optional[Tuple[float, int, float, float, float, float]] = None
+    #: False: no rotation at all (the ``rope`` columns are carried as they
+    #: are projected; the row keeps its width)
+    rope: bool = True
 
     @property
     def latent_width(self) -> int:
@@ -93,22 +105,54 @@ class MoEConfig:
 
 
 @dataclass(frozen=True)
+class KDAConfig:
+    """A gated delta-rule layer (``parallel/kda.py``): heads of ``head_dim``
+    keys and values, a causal depthwise convolution of ``conv_width``
+    tokens, low-rank decay and output gates of ``gate_rank``, and the
+    prefill's chunk."""
+    n_heads: int
+    head_dim: int
+    conv_width: int = 4
+    gate_rank: int = 128
+    chunk: int = 64
+
+    @property
+    def state_shapes(self):
+        """What a sequence keeps: the recurrent state (float32) and the
+        convolution window (the model's dtype)."""
+        width = self.n_heads * self.head_dim
+        return ((self.n_heads, self.head_dim, self.head_dim),
+                (self.conv_width - 1, 3 * width))
+
+
+@dataclass(frozen=True)
 class LMArch:
     """One LM's block vocabulary.  The defaults ARE the GPT-2-style block
     this package has always run."""
     norm: str = "layernorm"            # | 'rmsnorm'
     norm_eps: float = 1e-5
     mlp: str = "gelu"                  # | 'swiglu'
-    attn: str = "mha"                  # | 'mla'   (mha covers GQA)
+    attn: str = "mha"                  # | 'mla' | 'kda' (mha covers GQA)
     layer_kinds: Optional[Tuple[str, ...]] = None   # 'dense' | 'moe' each
     tied_head: bool = True
     embed_scale: bool = True           # embedding times sqrt(d_model)
     mla: Optional[MLAConfig] = None
     moe: Optional[MoEConfig] = None
+    attn_kinds: Optional[Tuple[str, ...]] = None    # a kind of ``attn`` each
+    kda: Optional[KDAConfig] = None
 
     def kind(self, layer: int) -> str:
         return "dense" if self.layer_kinds is None else \
             self.layer_kinds[layer]
+
+    def attn_kind(self, layer: int) -> str:
+        return self.attn if self.attn_kinds is None else \
+            self.attn_kinds[layer]
+
+    @property
+    def has_state(self) -> bool:
+        """Some layer keeps a per-sequence state (and no row a token)."""
+        return "kda" in (self.attn_kinds or (self.attn,))
 
 
 #: the description of every model that passes none
@@ -262,14 +306,19 @@ def mla_project(cfg: MLAConfig, h, a, positions, eps: float):
     (B, S, rope)`` rotated (one key for all heads)."""
     b, s, _ = h.shape
     nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    inv_freq, cs = rope_inv_freq(rope, cfg.rope_theta, cfg.yarn)
-    c_q = rms_norm(_dense(h, a["wdq"]), a["q_norm"], eps)
-    q = _dense(c_q, a["wuq"]).reshape(b, s, cfg.n_heads, nope + rope)
-    q_rope = apply_rope_freqs(q[..., nope:], positions, inv_freq, cs)
+    if cfg.q_lora_rank is None:
+        q = _dense(h, a["wq"])
+    else:
+        q = _dense(rms_norm(_dense(h, a["wdq"]), a["q_norm"], eps), a["wuq"])
+    q = q.reshape(b, s, cfg.n_heads, nope + rope)
     ckv = _dense(h, a["wdkv"])
     c_kv = rms_norm(ckv[..., :cfg.kv_lora_rank], a["kv_norm"], eps)
-    k_rope = apply_rope_freqs(ckv[..., cfg.kv_lora_rank:][:, :, None, :],
-                              positions, inv_freq, cs)[:, :, 0, :]
+    q_rope, k_rope = q[..., nope:], ckv[..., cfg.kv_lora_rank:]
+    if cfg.rope:
+        inv_freq, cs = rope_inv_freq(rope, cfg.rope_theta, cfg.yarn)
+        q_rope = apply_rope_freqs(q_rope, positions, inv_freq, cs)
+        k_rope = apply_rope_freqs(k_rope[:, :, None, :], positions,
+                                  inv_freq, cs)[:, :, 0, :]
     return q[..., :nope], q_rope, c_kv, k_rope
 
 
@@ -372,15 +421,26 @@ def mla_attend_absorbed(cfg: MLAConfig, q_nope, q_rope, cache, valid, a,
 
 def cache_layout(arch: LMArch, n_layers: int, kv_dim: int,
                  axis_name: str):
-    """Per layer, the buffers its attention keeps for each token: a tuple
-    of ``(columns, PartitionSpec)`` — the serving pool allocates one
-    ``(n_slots, max_total, columns)`` buffer for each."""
-    if arch.attn == "mla":
-        one = ((arch.mla.latent_width, P()),)
-    else:
+    """Per layer, the buffers its attention keeps, a tuple of
+    declarations of two forms.  ROWS ``(columns, PartitionSpec)``: one row
+    a token — the serving pool allocates ``(n_slots, max_total, columns)``
+    in its own dtype.  STATE ``(shape, dtype, PartitionSpec)``: one a
+    sequence, overwritten in place — the pool allocates ``(n_slots,) +
+    shape``; ``dtype`` None is the pool's."""
+    def one(kind):
+        if kind == "mla":
+            return ((arch.mla.latent_width, P()),)
+        if kind == "kda":
+            state, window = arch.kda.state_shapes
+            return ((state, jnp.float32, P()), (window, None, P()))
         spec = P(None, None, axis_name)
-        one = ((kv_dim, spec), (kv_dim, spec))
-    return [one] * n_layers
+        return ((kv_dim, spec), (kv_dim, spec))
+    return [one(arch.attn_kind(i)) for i in range(n_layers)]
+
+
+def is_state(buf) -> bool:
+    """A :func:`cache_layout` declaration of the STATE form."""
+    return len(buf) == 3
 
 
 def lm_specs(arch: LMArch, params, axis_name: str):
@@ -390,8 +450,9 @@ def lm_specs(arch: LMArch, params, axis_name: str):
     every chip (data-parallel, as DeepSeek's own serving runs MLA), so the
     blocks are replicated; the embedding and the head stay vocab-sharded,
     which is what the embedding lookup and the token pick assume."""
-    if arch.attn == "mha" and arch.moe is None and arch.mlp == "gelu" \
-            and arch.norm == "layernorm" and arch.tied_head:
+    if arch.attn == "mha" and arch.attn_kinds is None and arch.moe is None \
+            and arch.mlp == "gelu" and arch.norm == "layernorm" \
+            and arch.tied_head:
         from .transformer import transformer_lm_specs
         return transformer_lm_specs(params, axis_name)
     specs = jax.tree_util.tree_map(lambda _: P(), params)

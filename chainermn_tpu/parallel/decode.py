@@ -50,10 +50,13 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
     embedding scale — read here once and shared with the training loss
     (``parallel/transformer.py``).  A layer's cache is a TUPLE of buffers,
     whatever its attention declares (``blocks.cache_layout``): ``(k, v)``
-    for MHA/GQA, one latent buffer for MLA.  ``attn_block.moe_routing``
+    for MHA/GQA, one latent buffer for MLA, ``(state, window)`` for a
+    gated delta-rule layer — the kind is the LAYER's
+    (``arch.attn_kind(layer)``).  ``attn_block.moe_routing``
     collects the expert layers' ``(counts, idx)`` in trace order; ``live
     (N, S_q) bool`` names the rows that carry a token (None: all) — the
-    expert layers send the others to no expert.
+    expert layers send the others to no expert, and a delta-rule layer
+    leaves their state as it is.
     """
     arch = _blocks.resolve(arch)
     d_model = params["embed"].shape[1]
@@ -141,13 +144,29 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
                 preferred_element_type=jnp.float32).astype(x.dtype)
         return second_half(x + attn_out, blk, layer), cache
 
+    def kda_block(x, blk, state, window, layer):
+        """The gated delta-rule layer: no rows, a state a sequence.  One
+        token a row is the tick (``ops/kda_step``: the live rows' state
+        moves on in place, the others' is not touched); more are the
+        chunked form from the state given, which after a padded prompt
+        stands at the last live position, not at the last row."""
+        from .kda import kda_layer
+
+        with jax.named_scope("block/kda"):
+            h = _blocks.norm(arch, x, blk, "ln1")
+            y, state, window = kda_layer(arch.kda, h, blk["attn"], state,
+                                         window, live, arch.norm_eps)
+        return second_half(x + y, blk, layer), state, window
+
     def attn_block(x, blk, k_cache, v_cache, positions, write_at, q_valid,
                    layer: int = 0):
         """x (N,S,D) → block output; caches written at ``write_at + i`` for
         the i-th input position; query i attends cache [:q_valid + i + 1).
 
-        An MLA layer (``arch.attn == 'mla'``) keeps ONE buffer: pass it as
-        ``k_cache`` and None as ``v_cache``; the result is ``(x, cache)``.
+        An MLA layer (the layer's ``arch.attn_kind``) keeps ONE buffer:
+        pass it as ``k_cache`` and None as ``v_cache``; the result is ``(x,
+        cache)``.  A delta-rule layer takes ``(state, window)`` there and
+        no position: the state says where it stands.
 
         ``write_at``/``q_valid`` may be RANK-1 vectors of length N (the
         serving tick): row ``b`` then writes at ``write_at[b]`` and
@@ -167,9 +186,12 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
         VPU multiply+reduce fusions over half-empty 64-lane vregs
         (scripts/profile_decode.py + the round-5 HLO dump).
         """
-        if arch.attn == "mla":
+        kind = arch.attn_kind(layer)
+        if kind == "mla":
             return mla_block(x, blk, k_cache, positions, write_at, q_valid,
                              layer)
+        if kind == "kda":
+            return kda_block(x, blk, k_cache, v_cache, layer)
         n = x.shape[0]
         per_row = getattr(write_at, "ndim", 0) == 1
 
@@ -301,7 +323,8 @@ def _check_length(params, total: int, rope: bool) -> None:
 
 def _kv_heads(params, head_dim: int) -> int:
     a = params["blocks"][0]["attn"]
-    if "wdkv" in a:      # MLA: one shared latent row, no per-head K/V
+    if "wdkv" in a or "conv" in a:
+        # MLA: one shared latent row; delta rule: a state.  No per-head K/V
         return 0
     return (a["wkv"].shape[1] // (2 * head_dim) if "wkv" in a
             else a["bqkv"].shape[0] // (3 * head_dim))
@@ -312,7 +335,8 @@ def _prefill(params, embed, attn_block, prompt, total: int, head_dim: int):
     caches)`` with per-layer caches of length ``total`` (prompt written,
     tail zeros): per layer the tuple of flat ``(B, total, columns)``
     buffers its attention declares (``blocks.cache_layout``; ``(k, v)`` of
-    ``H_kv·head_dim`` columns for MHA/GQA — see ``attn_block``)."""
+    ``H_kv·head_dim`` columns for MHA/GQA — see ``attn_block``), or the
+    ``(B,) + shape`` state it declares, after the prompt's live rows."""
     arch = attn_block.arch
     b, s_p = prompt.shape
     layout = _blocks.cache_layout(arch, len(params["blocks"]),
@@ -321,7 +345,9 @@ def _prefill(params, embed, attn_block, prompt, total: int, head_dim: int):
     x = embed(prompt, positions)
     caches = []
     for i, (blk, bufs) in enumerate(zip(params["blocks"], layout)):
-        zeros = [jnp.zeros((b, total, w), x.dtype) for w, _ in bufs]
+        zeros = [jnp.zeros((b,) + tuple(buf[0]), buf[1] or x.dtype)
+                 if _blocks.is_state(buf)
+                 else jnp.zeros((b, total, buf[0]), x.dtype) for buf in bufs]
         x, new = _run_layer(attn_block, x, blk, zeros, positions, 0, 0, i)
         caches.append(new)
     return _blocks.norm(arch, x, params, "lnf"), caches

@@ -163,13 +163,26 @@ def tp_block(x, params, *, head_dim: int, axis_name: str, causal: bool = True,
     MHA/GQA, ``gelu`` MLP) names the block's vocabulary — the SAME
     description ``parallel/decode.py`` reads, so a model is described once
     for training, prefill and the decode tick.  ``layer`` picks the
-    layer's kind (dense MLP | experts) from it."""
+    layer's kinds (attention: MHA | MLA | gated delta rule; dense MLP |
+    experts) from it."""
     from . import blocks as _blocks
 
     arch = _blocks.resolve(arch)
-    with jax.named_scope("block/attn"):
+    kind = arch.attn_kind(layer)
+    with jax.named_scope("block/kda" if kind == "kda" else "block/attn"):
         h = _blocks.norm(arch, x, params, "ln1")
-        if arch.attn == "mla":
+        if kind == "kda":
+            # a gated delta-rule layer from a zero state, in the chunked
+            # form (plain XLA: matmuls, a triangular solve and a scan, all
+            # of which differentiate)
+            from .kda import kda_layer
+            state, window = (jnp.zeros((x.shape[0],) + shape, dtype)
+                             for shape, dtype in zip(
+                                 arch.kda.state_shapes,
+                                 (jnp.float32, x.dtype)))
+            x = x + kda_layer(arch.kda, h, params["attn"], state, window,
+                              None, arch.norm_eps)[0]
+        elif kind == "mla":
             from ..ops.flash_attention import resolve_attn_impl
             s = x.shape[1]
             q_nope, q_rope, c_kv, k_rope = _blocks.mla_project(

@@ -20,13 +20,34 @@ copy of every buffer, and the arrays bound before the call are deleted by
 it.  ``CachePool.update`` / ``CachePool.read`` are the only way a program
 reaches them — read, launch and rebind under one lock.
 
-Correctness of recycling without zeroing: a slot's rows ``> pos`` may
-hold a previous occupant's K/V, but every attention read is masked to
-the occupant's own prefix ``[0, pos]``, and row ``p`` is written by the
-current occupant strictly before ``pos`` reaches ``p`` (prefill writes
-``[0, s_p)``; each tick writes row ``pos`` before attending it).  Stale
-rows are therefore unreachable — asserted token-exactly by the
-cross-talk fuzz in tests/test_serving.py.
+A layer declares one of two kinds of buffer (``parallel/blocks.py::
+cache_layout``), and recycling a slot without zeroing it rests on a
+different invariant for each:
+
+* ROWS ``(n_slots, max_total, columns)``, one row a token.  *Unreachable
+  above ``pos``*: a slot's rows ``> pos`` may hold a previous occupant's
+  K/V — or the one garbage row a free or cached slot's tick writes at its
+  own held ``pos`` — but every attention read is masked to the occupant's
+  own prefix ``[0, pos]``, and row ``p`` is written by the current
+  occupant strictly before ``pos`` reaches ``p`` (prefill writes ``[0,
+  s_p)``; each tick writes row ``pos`` before attending it).  Stale rows
+  are therefore unreachable — asserted token-exactly by the cross-talk
+  fuzz in tests/test_serving.py.
+* STATE ``(n_slots,) + shape``, one a sequence, overwritten in place (a
+  delta-rule layer's recurrent state and convolution window).  There is
+  no "above": whatever is written is read by the next token.  *Never
+  written unless busy, overwritten whole on admission*: the tick is told
+  which slots are busy and leaves every other slot's state bit-identical
+  (``ops/kda_step.py``), so a cached slot's state stays the state of its
+  donated length and a free slot's is nobody's; and every way into a slot
+  — a prefill, a prefix copy — writes the WHOLE state (the prefill's own,
+  started from zero; the source slot's), so an occupant never reads its
+  predecessor's.  ``release`` / ``uncache`` therefore reset ``pos`` alone
+  for both kinds.  What cannot be done with a state is slicing it: a slot
+  holds rows for every position but a state for ONE, the ``pos`` it
+  stands at, which is why a prefix hit on such a layout is usable only at
+  the donated length, and why spill and the transfer plane — which pack
+  "rows ``[0, len)``" — refuse such a pool (``transfer.py``).
 
 :class:`SlotAllocator` is the jax-free bookkeeping half (fuzzable
 standalone); :class:`CachePool` adds the device buffers.
@@ -265,6 +286,12 @@ class SlotAllocator:
         assert all(rc >= 0 for rc in self._cached.values()), self._cached
 
 
+def _is_state(buf) -> bool:
+    """A layout declaration of the STATE form ``(shape, dtype, spec)``
+    (``parallel/blocks.py::is_state``; this module imports no jax)."""
+    return len(buf) == 3
+
+
 class CachePool:
     """Device-buffer half: per-layer flat cache pools + per-slot positions.
 
@@ -272,7 +299,9 @@ class CachePool:
     through: per layer, a tuple of ``(n_slots, max_total, columns)``
     buffers — whatever that layer's attention DECLARES it keeps per token
     (``layout``: per layer a tuple of ``(columns, PartitionSpec)``, from
-    ``parallel/blocks.py::cache_layout``).  Without a ``layout`` every
+    ``parallel/blocks.py::cache_layout``) — or of ``(n_slots,) + shape``
+    STATE buffers, one a slot, where it declares ``(shape, dtype,
+    PartitionSpec)`` (``dtype`` None: the pool's).  Without a ``layout`` every
     layer is the MHA/GQA declaration: a ``(k, v)`` pair of ``kv_dim``
     columns sharded ``P(None, None, axis)`` over the model axis — each
     chip holds only its local heads' columns, exactly the closed-batch
@@ -317,30 +346,42 @@ class CachePool:
         if len(layout) != self.n_layers:
             raise ValueError(f"layout names {len(layout)} layers, the "
                              f"model has {self.n_layers}")
-        self.layout = [tuple((int(w), spec) for w, spec in bufs)
-                       for bufs in layout]
+        self.dtype = jnp.dtype(dtype)
+        # rows ``(columns, spec)``; state ``(shape, dtype, spec)``
+        self.layout = [tuple(
+            (tuple(int(n) for n in buf[0]), jnp.dtype(buf[1] or self.dtype),
+             buf[2]) if _is_state(buf) else (int(buf[0]), buf[1])
+            for buf in bufs) for bufs in layout]
         #: the caches pytree's PartitionSpecs (programs' in/out specs)
-        self.cache_specs = [tuple(spec for _, spec in bufs)
+        self.cache_specs = [tuple(buf[-1] for buf in bufs)
                             for bufs in self.layout]
         # the first buffer's spec: what a K/V pool's every buffer has
-        self.cache_spec = self.layout[0][0][1]
-        self.dtype = jnp.dtype(dtype)
+        self.cache_spec = self.layout[0][0][-1]
         self.caches = self.fresh_buffers()
         # held for a program's LAUNCH only (dispatch is asynchronous)
         self._buffers_lock = threading.Lock()
         self.calls = 0           # updates: program calls that returned
         self.calls_donated = 0   # the buffers; those that deleted theirs
-        #: bytes one token keeps across all layers (whole model axis)
+        #: bytes one token keeps across all ROW layers (whole model axis)
         self.bytes_per_token = self.dtype.itemsize * sum(
-            w for bufs in self.layout for w, _ in bufs)
+            buf[0] for bufs in self.layout for buf in bufs
+            if not _is_state(buf))
+        #: bytes one slot keeps across all STATE layers, whatever its
+        #: length (0: every layer keeps rows), and how many layers those are
+        self.state_bytes_per_slot = sum(
+            int(np.prod(buf[0])) * buf[1].itemsize
+            for bufs in self.layout for buf in bufs if _is_state(buf))
+        self.n_state_layers = sum(
+            any(_is_state(buf) for buf in bufs) for bufs in self.layout)
         # host-side per-slot NEXT-WRITE position (== sequence length so
         # far).  The tick runs EVERY slot (one fixed program) but only a
         # BUSY slot's position advances (``advance``): a free, cached or
-        # reserved slot holds its position, so its garbage write keeps
+        # reserved slot holds its position, so its garbage ROW write keeps
         # landing on the one row ``pos`` INSIDE ITS OWN SLOT ROW — row 0
         # of a free slot, the first row above a cached prefix — which
-        # stays safe by the module-docstring argument: the next occupant
-        # rewrites row p before its own pos reaches p.
+        # stays safe by the module docstring's argument for rows: the next
+        # occupant rewrites row p before its own pos reaches p.  (Its
+        # STATE is not written at all: the tick is given the busy mask.)
         self.pos = np.zeros(self.n_slots, np.int32)
 
     def fresh_buffers(self):
@@ -351,11 +392,16 @@ class CachePool:
         import jax.numpy as jnp
         from jax.sharding import NamedSharding
 
-        return [
-            tuple(jax.device_put(
-                jnp.zeros((self.n_slots, self.max_total, w), self.dtype),
-                NamedSharding(self.mesh, spec)) for w, spec in bufs)
-            for bufs in self.layout]
+        def zeros(buf):
+            if _is_state(buf):
+                shape, dtype, spec = buf
+                z = jnp.zeros((self.n_slots,) + shape, dtype)
+            else:
+                z = jnp.zeros((self.n_slots, self.max_total, buf[0]),
+                              self.dtype)
+            return jax.device_put(z, NamedSharding(self.mesh, buf[-1]))
+
+        return [tuple(zeros(buf) for buf in bufs) for bufs in self.layout]
 
     def update(self, launch, *sources):
         """Run a program that RETURNS the buffers: ``launch(caches,
@@ -406,6 +452,8 @@ class CachePool:
         return self.allocator.acquire()
 
     def release(self, slot: int) -> None:
+        # ``pos`` alone: the slot's rows are unreachable above it, and its
+        # state is written whole by whatever admits the next occupant
         self.pos[slot] = 0
         self.allocator.release(slot)
 
@@ -423,16 +471,17 @@ class CachePool:
         self.allocator.cancel_reservation(slot)
 
     # prefix-cache faces.  A cached slot's ``pos`` is deliberately NOT
-    # reset: the tick still advances every slot's position, so the
-    # cached slot's garbage writes keep landing at its drifting pos —
-    # strictly ABOVE the donated prefix length — leaving the read-only
-    # rows [0, length) intact for the copy-on-extend path (the same
-    # above-``pos`` unreachability argument as free-slot recycling).
+    # reset, and held (only a busy slot's advances): the tick's garbage
+    # row of a cached slot lands AT the donated prefix length, above the
+    # read-only rows [0, length) the copy-on-extend path reads (the same
+    # above-``pos`` unreachability argument as free-slot recycling) — and
+    # a cached slot's STATE, which is not busy, is not written: it stays
+    # the state at ``pos``, the one length a hit on it can be used at.
     def cache(self, slot: int) -> None:
         self.allocator.cache(slot)
 
     def uncache(self, slot: int) -> None:
-        self.pos[slot] = 0
+        self.pos[slot] = 0        # as ``release``: ``pos`` alone
         self.allocator.uncache(slot)
 
     def retain(self, slot: int) -> int:

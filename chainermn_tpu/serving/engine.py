@@ -67,9 +67,13 @@ class DecodeEngine:
     an untied head: ``parallel/blocks.py``); ``mesh`` must carry
     ``axis_name`` (default: a fresh 1-D mesh over all local devices, like
     ``make_lm_generator``).  The programs thread the pool's caches as a
-    pytree, whatever each layer declares.  A model with experts is told
+    pytree, whatever each layer declares — rows a token or a state a slot
+    (``cache_pool.py``).  A model with experts or with state layers is told
     which rows carry a token (the tick's busy slots, a prompt's real
-    positions: the others go to no expert) and returns its routing in the
+    positions): the others go to no expert, and leave a layer's state as it
+    is — a free or cached slot's state is not the tick's to write, and a
+    padded prompt's state stands at its last real token.  A model with
+    experts returns its routing in the
     SAME int32 vector as the tokens: the counts (``moe_counts_tick`` /
     ``moe_counts_prefill`` accumulate them) and the experts chosen for
     each emitted token (``tick_routes (n_slots, expert layers, top_k)``,
@@ -124,6 +128,12 @@ class DecodeEngine:
         self.tick_calls = 0
         self.prefix_copies = 0
 
+    @property
+    def _takes_live(self) -> bool:
+        """The programs take the rows that carry a token: expert layers
+        route only those, state layers move only those on."""
+        return bool(self.n_counts) or self.arch.has_state
+
     # ---- program builders ----
     def _build_tick(self):
         import jax
@@ -150,13 +160,14 @@ class DecodeEngine:
                                   axis, keys, temps, pos + 1)
             return _with_routing(nxt, routing), new_caches
 
-        # a model with experts takes the busy mask as a fifth vector.
-        # The pool is DONATED to every program that returns it: the row
-        # write lands in place (un-donated, XLA copied every buffer first)
+        # a model with experts or state layers takes the busy mask as a
+        # fifth vector.  The pool is DONATED to every program that returns
+        # it: the row write lands in place (un-donated, XLA copied every
+        # buffer first)
         return jax.jit(self._shard_map(
             serving_tick, mesh=self.mesh,
             in_specs=(self._specs, self._cache_specs)
-            + (P(),) * (5 if self.n_counts else 4),
+            + (P(),) * (5 if self._takes_live else 4),
             out_specs=(P(), self._cache_specs)), donate_argnums=(1,))
 
     def _build_prefill(self, s_pad: int):
@@ -167,12 +178,13 @@ class DecodeEngine:
         from ..parallel.decode import _next_token, lm_prefill
 
         axis, head_dim, arch = self.axis_name, self.head_dim, self.arch
-        P, moe = self._P, bool(self.n_counts)
+        P, takes_live = self._P, self._takes_live
 
         def prefill_inner(params, caches, prompt, s_real, slot, key, temp):
             # slab caches sized to the padded prompt only; pads are above
-            # every real row and never read back (causal + pos mask)
-            real = (jnp.arange(s_pad) < s_real)[None] if moe else None
+            # every real row and never read back (causal + pos mask).  A
+            # state is not rows: the pads must not move it (``real``)
+            real = (jnp.arange(s_pad) < s_real)[None] if takes_live else None
             h, slabs, routing = lm_prefill(
                 params, prompt, s_pad, head_dim=head_dim, axis_name=axis,
                 arch=arch, live=real, with_routing=True)
@@ -186,10 +198,11 @@ class DecodeEngine:
             tok = _next_token(_blocks.head_table(arch, params), h_last,
                               axis, key[None], temp[None], s_real[None])
             # every buffer a layer declares gets its slab, at the slot's
-            # rows [0, s_pad)
+            # rows [0, s_pad) — or the slot's whole state
             new_caches = jax.tree_util.tree_map(
                 lambda c, slab: jax.lax.dynamic_update_slice(
-                    c, slab.astype(c.dtype), (slot, 0, 0)), caches, slabs)
+                    c, slab.astype(c.dtype), (slot,) + (0,) * (c.ndim - 1)),
+                caches, slabs)
             return _with_routing(tok, routing), new_caches
 
         prefill_inner.__name__ = f"serving_prefill_{s_pad}"
@@ -203,8 +216,8 @@ class DecodeEngine:
         """Slot-to-slot cache slab copy — the prefix cache's copy-on-
         extend device half (ISSUE 7).  Copies the ENTIRE src slot row
         into dst for every buffer of every layer (a K/V pair, a latent
-        buffer: whatever the pool declares): rows beyond the matched
-        prefix length
+        buffer, a state: whatever the pool declares): rows beyond the
+        matched prefix length
         carry stale K/V, but they are unreachable by the standard
         above-``pos`` masking argument and the next occupant's writes
         land below its own pos first — so the program needs no length
@@ -217,7 +230,7 @@ class DecodeEngine:
                 lambda c: jax.lax.dynamic_update_slice(
                     c, jax.lax.dynamic_index_in_dim(c, src, axis=0,
                                                     keepdims=True),
-                    (dst, 0, 0)), caches)
+                    (dst,) + (0,) * (c.ndim - 1)), caches)
 
         P = self._P
         return jax.jit(self._shard_map(
@@ -300,6 +313,14 @@ class DecodeEngine:
             raise ValueError(
                 f"prefix_len {prefix_len} out of range (0, "
                 f"{self.pool.max_total}]")
+        if self.pool.state_bytes_per_slot \
+                and int(prefix_len) != int(self.pool.pos[src_slot]):
+            # rows [0, k) are the rows of any prefix; a state is the state
+            # of ONE position, the one the source slot stands at
+            raise ValueError(
+                f"slot {src_slot} holds a layer state at position "
+                f"{int(self.pool.pos[src_slot])}: a prefix of {prefix_len} "
+                f"tokens has no state to copy")
         if self._prefix_copy_prog is None:
             self._prefix_copy_prog = self._build_prefix_copy()
             from ..observability import flight as _flight
@@ -339,7 +360,7 @@ class DecodeEngine:
             temps = jnp.asarray(np.array(temps, np.float32, copy=True))
             busy = self.pool.busy_mask()
             operands = (tokens, pos, keys, temps) + (
-                (jnp.asarray(busy),) if self.n_counts else ())
+                (jnp.asarray(busy),) if self._takes_live else ())
         with _trace.span("serving/tick/dispatch", cat="serving"):
             nxt = self.pool.update(
                 lambda caches: self._tick_prog(self._params, caches,

@@ -215,6 +215,17 @@ class ServingEngine:
         # radix-trie prefix cache (ISSUE 7): finished requests donate
         # their slot (busy -> cached, read-only, refcounted); admission
         # scavenges rc==0 entries LRU-first when the free list is empty
+        # On a pool with STATE layers a donated slot holds rows for every
+        # position but a state for one, its donated length: an entry is
+        # usable only whole (``whole_only``), and the spill tier, which
+        # packs "rows [0, len)", is refused rather than left to drop it.
+        has_state = bool(self.pool.state_bytes_per_slot)
+        if has_state and prefix_cache and int(spill_bytes) > 0:
+            raise ValueError(
+                "this model has 'kda' layers, which keep a per-slot "
+                "recurrent state and no rows: the host spill tier packs "
+                "rows [0, len) of each buffer and would drop the state; "
+                "construct the engine with spill_bytes=0")
         self.prefix_cache: Optional[PrefixCache] = None
         if prefix_cache:
             self.prefix_cache = PrefixCache(
@@ -223,7 +234,8 @@ class ServingEngine:
                 evict_slot=self.pool.uncache,
                 min_prefix_len=min_prefix_len,
                 on_insert=self._on_prefix_insert,
-                on_evict=self._on_prefix_evict)
+                on_evict=self._on_prefix_evict,
+                whole_only=has_state)
         # host-RAM spill tier (ISSUE 12): a scavenged rc==0 prefix slot
         # spills its CRC-stamped slab into a bounded LRU host store
         # instead of vanishing; a later matching prompt restores it
@@ -283,6 +295,11 @@ class ServingEngine:
         self._tick_cache_blocks_total = 0
         # the rows those blocks had to hold: each slot's own length
         self._tick_cache_rows_live = 0
+        # state layers: (busy slot, state layer) pairs a tick moved on —
+        # the state it had to read and write is that times a slot's
+        # state bytes a layer, as the rows it had to read are the live
+        # rows times ``bytes_per_token`` (host arithmetic, both)
+        self._tick_state_slots_live = 0
         self._t0 = time.monotonic()
         # goodput attribution: step() partitions its own wall clock, and
         # the gap between steps books as queue_wait (work was waiting)
@@ -602,6 +619,8 @@ class ServingEngine:
                     self._tick_cache_rows_live += int(np.minimum(
                         self.pool.pos, self.pool.max_total - 1).sum()
                         ) + self.pool.n_slots
+                    self._tick_state_slots_live += (
+                        self.pool.busy_count * self.pool.n_state_layers)
                 tick_bucket = ("compile" if self.engine.tick_calls == 0
                                else "compute")
                 # the tracer's clock is read only for its own Chrome sink
@@ -1013,6 +1032,7 @@ class ServingEngine:
             self._tick_cache_blocks_read = 0
             self._tick_cache_blocks_total = 0
             self._tick_cache_rows_live = 0
+            self._tick_state_slots_live = 0
             self.engine.moe_counts_tick[:] = 0
             self.engine.moe_counts_prefill[:] = 0
             self.pool.calls = self.pool.calls_donated = 0
@@ -1025,7 +1045,7 @@ class ServingEngine:
                 pc = self.prefix_cache
                 pc.hits = pc.misses = pc.tokens_reused = 0
                 pc.insertions = pc.rejected_insertions = 0
-                pc.evictions = 0
+                pc.evictions = pc.state_misses = 0
             if self.spill is not None:
                 # same discipline: counters reset, spilled payloads stay
                 sp = self.spill
@@ -1086,9 +1106,24 @@ class ServingEngine:
                     self._tick_cache_blocks_total),
                 "serving/tick_cache_rows_live": float(
                     self._tick_cache_rows_live),
-                # what one token keeps in the pool, all layers (gauge)
+                # what one token keeps in the pool, all row layers, and
+                # what one slot keeps whatever its length, all state
+                # layers (gauges)
                 "serving/cache_bytes_per_token": float(
                     self.pool.bytes_per_token),
+                "serving/cache_state_bytes_per_slot": float(
+                    self.pool.state_bytes_per_slot),
+                # what the ticks had to touch of each kind of cache: the
+                # busy slots' state (read and written once a tick; other
+                # slots' is not touched), and the live rows
+                "serving/tick_state_slots_live": float(
+                    self._tick_state_slots_live),
+                "serving/tick_state_bytes": float(
+                    self._tick_state_slots_live
+                    * (self.pool.state_bytes_per_slot
+                       // max(self.pool.n_state_layers, 1))),
+                "serving/tick_latent_bytes": float(
+                    self._tick_cache_rows_live * self.pool.bytes_per_token),
                 "serving/tick_calls": float(self.engine.tick_calls),
                 # program calls that returned the pool's buffers (ticks,
                 # prefills, prefix copies, landed slabs), and those after

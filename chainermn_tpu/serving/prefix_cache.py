@@ -19,6 +19,13 @@ exact K/V of any prefix ``S[:k]``.  The trie therefore needs no
 per-token granularity bookkeeping — matching walks edges and any entry
 below the deepest matched point supplies the slot.
 
+That holds for ROWS.  A layer that keeps a STATE a sequence (a delta-rule
+layer: ``cache_pool.py``) keeps it for ONE position, the donated length:
+on such a pool the cache is built ``whole_only`` — an entry serves a hit
+only where its WHOLE sequence is a prefix of the prompt, a longer entry
+neither covers nor subsumes a shorter one, and a match that rows alone
+could have served counts in ``state_misses`` and takes the whole prefill.
+
 Matches are capped at ``len(prompt) - 1``: the FIRST GENERATED token
 comes from the last prompt position's hidden state, which is not
 cached — at least one prompt token always runs through the engine, and
@@ -114,11 +121,15 @@ class PrefixCache:
     ``min_prefix_len``: hits shorter than this are treated as misses —
     copying a 1-token prefix saves one embedding lookup and costs a
     slab copy; the knob keeps the trade explicit.
+
+    ``whole_only``: the slots hold a per-sequence STATE beside their rows,
+    which stands at the donated length alone — an entry is usable only
+    whole (module docstring).
     """
 
     def __init__(self, retain_slot=None, release_slot=None,
                  evict_slot=None, min_prefix_len: int = 2,
-                 on_insert=None, on_evict=None):
+                 on_insert=None, on_evict=None, whole_only: bool = False):
         # one reentrant lock around every trie/entry mutation AND read:
         # with Replica.start() the engine's driver thread donates and
         # evicts while the router's caller thread peeks for affinity —
@@ -133,6 +144,7 @@ class PrefixCache:
         self._pins: Dict[int, int] = {}                 # entry id -> rc
         self._clock = 0
         self.min_prefix_len = max(int(min_prefix_len), 1)
+        self.whole_only = bool(whole_only)
         self._retain_slot = retain_slot or (lambda slot: None)
         self._release_slot = release_slot or (lambda slot: None)
         self._evict_slot = evict_slot or (lambda slot: None)
@@ -156,6 +168,8 @@ class PrefixCache:
         self.insertions = 0
         self.rejected_insertions = 0
         self.evictions = 0
+        # matches refused because no state stands at the matched length
+        self.state_misses = 0
 
     # ---- matching ----
     def _walk(self, seq) -> Tuple["_Node", int, Optional["_Node"]]:
@@ -175,6 +189,22 @@ class PrefixCache:
                 return node, depth, child
             node = child
         return node, depth, None
+
+    def _whole_entry(self, seq) -> Optional[PrefixEntry]:
+        """The longest entry whose WHOLE sequence is a prefix of ``seq``
+        (it terminates at a node on ``seq``'s own path)."""
+        node, depth, best = self._root, 0, None
+        while depth < len(seq):
+            edge = node.edges.get(seq[depth])
+            if edge is None:
+                break
+            label, child = edge
+            if _common_len(label, seq[depth:]) < len(label):
+                break
+            node, depth = child, depth + len(label)
+            if node.entry is not None:
+                best = node.entry
+        return best
 
     def _subtree_entry(self, node: "_Node") -> Optional[PrefixEntry]:
         """Most-recently-used entry in ``node``'s subtree (entry count
@@ -212,6 +242,15 @@ class PrefixCache:
         if match_len < self.min_prefix_len:
             self.misses += 1
             return None, 0
+        if self.whole_only:
+            whole = self._whole_entry(prompt[:len(prompt) - 1])
+            if whole is None or whole.length < self.min_prefix_len:
+                # rows could have served ``match_len`` tokens; no state
+                # stands there
+                self.state_misses += 1
+                self.misses += 1
+                return None, 0
+            entry, match_len = whole, whole.length
         self.hits += 1
         self.tokens_reused += match_len
         self._clock += 1
@@ -232,7 +271,11 @@ class PrefixCache:
                                     else node)
         if entry is None or depth < self.min_prefix_len:
             return 0
-        match_len = min(depth, entry.length, len(prompt) - 1)
+        if self.whole_only:
+            entry = self._whole_entry(prompt[:len(prompt) - 1])
+            match_len = entry.length if entry is not None else 0
+        else:
+            match_len = min(depth, entry.length, len(prompt) - 1)
         return match_len if match_len >= self.min_prefix_len else 0
 
     @_locked
@@ -253,7 +296,8 @@ class PrefixCache:
         entry = self._subtree_entry(partial if partial is not None
                                     else node)
         if entry is None or entry.length < len(seq) \
-                or entry.seq[: len(seq)] != seq:
+                or entry.seq[: len(seq)] != seq \
+                or (self.whole_only and entry.length != len(seq)):
             return None
         self.retain(entry)
         return entry
@@ -299,8 +343,11 @@ class PrefixCache:
             # every entry in the subtree below the matched point passes
             # through all of seq — rows [0, len(seq)) of its slot
             # already hold this exact K/V, so the donation adds nothing
-            covering = self._subtree_entry(
-                partial if partial is not None else node)
+            if self.whole_only:     # only the same sequence covers it
+                covering = node.entry if partial is None else None
+            else:
+                covering = self._subtree_entry(
+                    partial if partial is not None else node)
             if covering is not None:
                 self.rejected_insertions += 1
                 return None
@@ -316,7 +363,8 @@ class PrefixCache:
         # a strictly-shorter entry whose seq prefixes the new one is
         # subsumed: every hit it could serve, the new entry serves
         # better.  Evict the unpinned ones now (their slot frees up).
-        for other in list(self._entries.values()):
+        # (``whole_only``: a shorter entry's state is its own — kept.)
+        for other in () if self.whole_only else list(self._entries.values()):
             if other.id != entry.id and other.length < entry.length \
                     and entry.seq[: other.length] == other.seq \
                     and self._pins.get(other.id, 0) == 0:
@@ -429,6 +477,7 @@ class PrefixCache:
             "tokens_reused": float(self.tokens_reused),
             "insertions": float(self.insertions),
             "evictions": float(self.evictions),
+            "state_misses": float(self.state_misses),
         }
 
     @_locked

@@ -82,7 +82,17 @@ def slab_crc32(rows) -> int:
 
 def _widths(pool):
     """The pool's declaration as plain data: per layer, the columns of
-    each buffer (``[(kv_dim, kv_dim), ...]`` for a K/V pool)."""
+    each buffer (``[(kv_dim, kv_dim), ...]`` for a K/V pool).  Every
+    program and payload of this plane starts here, and a pool that holds
+    per-slot STATE is refused here: the plane moves "rows ``[0, len)`` of
+    every declared buffer", and a state is no rows — moved so it would be
+    silently dropped, and the receiver would decode from a stale one."""
+    if getattr(pool, "state_bytes_per_slot", 0):
+        raise ValueError(
+            "the KV-transfer plane (local transfer, pack/unpack, the host "
+            "spill tier) moves rows [0, len) of each buffer; this pool "
+            "holds per-slot state of 'kda' layers (a recurrent state and "
+            "a convolution window), which it would drop — refused")
     return [tuple(w for w, _ in bufs) for bufs in pool.layout]
 
 
@@ -322,6 +332,7 @@ class KvTransferPlane:
         payload is transport-agnostic bytes."""
         import jax
 
+        _widths(src_pool)       # refuses a pool that holds state
         if not (0 < int(length) <= src_pool.max_total):
             raise ValueError(f"pack length {length} out of range "
                              f"(0, {src_pool.max_total}]")

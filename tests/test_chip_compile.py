@@ -408,3 +408,130 @@ def test_latent_attention_and_expert_serving_programs(topo, as_tpu):
     assert prefill.count("%flash_fwd") >= layers
     assert prefill.count("%moe_gmm") >= 3
     _assert_pool_written_in_place(prefill, (n_slots, total, 640))
+
+
+def _kimi_programs(topo, n_layers, prompts):
+    """The serving programs of the benchmark's ``kimi-linear-48b-ep16``
+    configuration (every width as published, the chip's share of experts
+    and vocabulary, 64 slots of 4096 rows) with its first ``n_layers``
+    layers: ``(arch, layout, tick, {prompt: prefill})`` compiled."""
+    import importlib.util
+    import json
+
+    from chainermn_tpu._compat import shard_map
+    from chainermn_tpu.parallel import blocks
+    from chainermn_tpu.parallel.blocks import (KDAConfig, LMArch, MLAConfig,
+                                               MoEConfig)
+    from chainermn_tpu.serving.engine import DecodeEngine
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    spec = importlib.util.spec_from_file_location(
+        "kimi_reference", os.path.join(here, "kimi_linear_reference.py"))
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+    with open(os.path.join(os.path.dirname(here), "benchmark", "configs",
+                           "kimi-linear-48b-ep16.json")) as f:
+        cfg = dict(json.load(f), num_hidden_layers=n_layers)
+    lin = cfg["linear_attn_config"]
+    arch = LMArch(
+        norm="rmsnorm", norm_eps=cfg["rms_norm_eps"], mlp="swiglu",
+        attn="mla", tied_head=False, embed_scale=False,
+        attn_kinds=tuple("kda" if i + 1 in lin["kda_layers"] else "mla"
+                         for i in range(n_layers)),
+        layer_kinds=tuple("dense" if i < cfg["first_k_dense_replace"]
+                          else "moe" for i in range(n_layers)),
+        kda=KDAConfig(lin["num_heads"], lin["head_dim"],
+                      lin["short_conv_kernel_size"], cfg["kda_gate_rank"]),
+        mla=MLAConfig(cfg["num_attention_heads"], cfg["q_lora_rank"],
+                      cfg["kv_lora_rank"], cfg["qk_nope_head_dim"],
+                      cfg["qk_rope_head_dim"], cfg["v_head_dim"],
+                      rope=not cfg["mla_use_nope"]),
+        moe=MoEConfig(cfg["num_experts"], cfg["num_experts_per_token"],
+                      cfg["num_expert_group"], cfg["topk_group"],
+                      cfg["routed_scaling_factor"], cfg["moe_renormalize"],
+                      (0, cfg["num_experts_held"])))
+    n_slots, total = 64, 4096
+    mesh = Mesh(np.array(topo.devices[:1]), ("model",))
+    rep = NamedSharding(mesh, P())
+    shapes = jax.eval_shape(
+        lambda k: ref.init_params(k, cfg, jnp.bfloat16), jax.random.PRNGKey(0))
+    specs = blocks.lm_specs(arch, shapes, "model")
+    p = jax.tree_util.tree_map(
+        lambda x, sp: _sds(x.shape, x.dtype, NamedSharding(mesh, sp)),
+        shapes, specs)
+    layout = blocks.cache_layout(arch, n_layers, 0, "model")
+    caches = [tuple(
+        _sds((n_slots,) + tuple(b[0]), b[1] or jnp.bfloat16,
+             NamedSharding(mesh, b[2])) if blocks.is_state(b)
+        else _sds((n_slots, total, b[0]), jnp.bfloat16,
+                  NamedSharding(mesh, b[1])) for b in bufs)
+        for bufs in layout]
+    eng = DecodeEngine.__new__(DecodeEngine)
+    eng.mesh, eng.axis_name, eng.arch = mesh, "model", arch
+    eng.head_dim = cfg["v_head_dim"]
+    eng.n_counts = blocks.n_count_entries(arch)
+    eng._specs, eng._shard_map, eng._P = specs, shard_map, P
+    eng._cache_specs = [tuple(b[-1] for b in bufs) for bufs in layout]
+    tick = eng._build_tick().lower(
+        p, caches, _sds((n_slots,), jnp.int32, rep),
+        _sds((n_slots,), jnp.int32, rep),
+        _sds((n_slots, 2), jnp.uint32, rep),
+        _sds((n_slots,), jnp.float32, rep),
+        _sds((n_slots,), jnp.bool_, rep)).compile()          # busy mask
+    prefills = {
+        s: eng._build_prefill(s).lower(
+            p, caches, _sds((1, s), jnp.int32, rep),
+            _sds((), jnp.int32, rep), _sds((), jnp.int32, rep),
+            _sds((2,), jnp.uint32, rep), _sds((), jnp.float32, rep)
+        ).compile() for s in prompts}
+    return arch, layout, tick, prefills
+
+
+def test_state_and_row_layers_in_one_pool_serving_programs(topo, as_tpu):
+    """The serving programs of a model that keeps a recurrent STATE a slot
+    in most layers (gated delta rule) and a latent ROW a token in the
+    others, at Kimi-Linear-48B's published widths (the benchmark's
+    ``kimi-linear-48b-ep16``).  The TICK at full depth, 27 layers: the
+    ``kda_step`` kernel over the float32 state (20 layers), the absorbed
+    flash-decode kernel over the 640-column latent pool (7), the grouped
+    expert product (26); both kinds of buffer written in place; weights +
+    pool + temporaries inside one v5e chip.  The widest PREFILL (2048) at
+    one period of the pattern behind the dense first layer (K K K M; its
+    temporaries are a layer's, whatever the depth): the chunked delta rule
+    is plain XLA, the latent layer takes the flash kernel at 192/128.
+    Kernels, programs and scopes keep the names the trace readers match."""
+    arch, layout, tick, _ = _kimi_programs(topo, 27, ())
+    assert sum(arch.attn_kind(i) == "kda" for i in range(27)) == 20
+    assert [[b[0] for b in bufs] for bufs in layout[2:4]] == [
+        [(32, 128, 128), (3, 12288)], [640]]
+    text, mem = tick.as_text(), tick.memory_analysis()
+    assert "HloModule jit_serving_tick" in text
+    calls = [ln.split(" = ")[0] for ln in text.split("\n")
+             if "tpu_custom_call" in ln]
+    count = lambda name: sum(name in c for c in calls)
+    assert count("kda_step") == 20 and count("decode_attn_mla") == 7
+    assert count("moe_gmm") >= 3 * 26
+    # no reader of an accepted metric may match the new kernel by substring
+    assert not any(n in "kda_step" for n in ("decode_attn", "moe_gmm",
+                                             "flash"))
+    for scope in ("block/kda", "conv", "gate", "state_update", "tick/attn",
+                  "block/mla", "block/moe"):
+        assert scope in text, scope
+    _assert_pool_written_in_place(text, (64, 4096, 640))
+    import re
+    assert not re.findall(r"= f32\[64,32,128,128\]\S* copy\(", text)
+    # 8.59 GB of weights + 5.13 GB of pool (state 2.78, rows 2.35)
+    assert 13.6e9 < mem.argument_size_in_bytes < 13.8e9
+    assert mem.temp_size_in_bytes < 0.3e9
+
+    _, _, _, prefills = _kimi_programs(topo, 4, (2048,))
+    pre, pmem = prefills[2048].as_text(), prefills[2048].memory_analysis()
+    assert "HloModule jit_serving_prefill_2048" in pre
+    assert pre.count("%flash_fwd") >= 1 and pre.count("%moe_gmm") >= 3
+    assert "kda_step" not in pre            # the chunked form, not the step
+    for scope in ("block/kda", "state_update"):
+        assert scope in pre, scope
+    _assert_pool_written_in_place(pre, (64, 4096, 640))
+    # the whole model's arguments with the widest prefill's temporaries:
+    # under 15.0 GB, the line ISSUE 31 draws for 64 slots
+    assert mem.argument_size_in_bytes + pmem.temp_size_in_bytes < 15.0e9

@@ -453,12 +453,24 @@ def _share(m, first, n):
                       for k in ("w_gate", "w_up", "w_down")})
 
 
+#: the router's numbers: DeepSeek-V3's (4 groups of which 2 are kept, gates
+#: times 2.5) and Kimi Linear's (one group, all kept: plain top-k; times
+#: 2.446) -- one router, one dropless layer, other numbers
+ROUTERS = {"4-groups-x2.5": {},
+           "1-group-x2.446": {"n_group": 1, "topk_group": 1,
+                              "routed_scaling_factor": 2.446}}
+
+
+@pytest.mark.parametrize("router", sorted(ROUTERS))
 @pytest.mark.parametrize("interpret", [None, True], ids=["dense", "kernel"])
-def test_the_shares_add_up_to_the_uncut_layer(interpret):
+def test_the_shares_add_up_to_the_uncut_layer(interpret, router):
     """The routed parts of all 4 shares (4 experts each), plus the shared
     expert counted once, are the uncut layer: program and reference."""
     m, x = _full_layer()
-    moe = arch_of(CFG).moe
+    cfg = dict(CFG, **ROUTERS[router])
+    moe = arch_of(cfg).moe
+    assert (moe.n_group, moe.routed_scaling_factor) == (
+        cfg["n_group"], cfg["routed_scaling_factor"])
     whole = MoEConfig(**dict(moe.__dict__, held=(0, 16)))
     want, counts, _ = moe_dropless(x, m, whole, interpret=interpret)
     shared = blocks.swiglu(x, m["shared"])
@@ -475,7 +487,7 @@ def test_the_shares_add_up_to_the_uncut_layer(interpret):
                                rtol=1e-4, atol=1e-5)
     assert held_sum == int(counts[1]) == x.shape[0] * 4   # nothing dropped
     # the reference's uncut layer, and its shares
-    idx, gates = ref.route(x, m, CFG, "float32")
+    idx, gates = ref.route(x, m, cfg, "float32")
     ref_whole = ref.gated_mlp(x, m["shared"], "float32") + ref.moe_routed(
         x, m, idx, gates, (0, 16), "float32")
     np.testing.assert_allclose(np.asarray(want), np.asarray(ref_whole),
@@ -683,8 +695,9 @@ def _gpt2_block(seed=0):
     return p, x
 
 
-@pytest.mark.parametrize("arch", [None, blocks.DEFAULT_ARCH],
-                         ids=["none", "default"])
+@pytest.mark.parametrize(
+    "arch", [None, blocks.DEFAULT_ARCH, LMArch(attn_kinds=("mha", "mha"))],
+    ids=["none", "default", "kind-per-layer"])
 def test_gpt2_block_is_bit_identical_through_the_description(mesh, arch):
     """``tp_block`` through ``blocks`` against the block written out as it
     stood before the description: the same bits."""
@@ -716,6 +729,12 @@ def test_gpt2_description_is_the_default():
         "layernorm", "gelu", "mha", True, True)
     assert blocks.head_table(a, p) is p["embed"]
     assert blocks.n_count_entries(a) == 0
+    # one attention kind for the whole model, no state: nothing of the
+    # per-layer kinds (PR 31) shows in the defaults
+    assert (a.attn_kinds, a.kda, a.has_state) == (None, None, False)
+    assert [a.attn_kind(i) for i in range(3)] == ["mha"] * 3
+    assert blocks.cache_layout(a, 2, 32, "model") == [
+        ((32, P(None, None, "model")),) * 2] * 2
     from chainermn_tpu.parallel.transformer import transformer_lm_specs
     assert blocks.lm_specs(a, p, "model") == transformer_lm_specs(p, "model")
 
@@ -735,7 +754,56 @@ def test_gpt2_tick_program_returns_tokens_alone(devices):
     m = eng.metrics()
     assert not any("moe" in k for k in m)
     assert m["serving/cache_bytes_per_token"] == 2 * 2 * 32 * 4
+    # rows alone: no state, no fifth operand, nothing counted as state
+    assert not eng.engine._takes_live
+    assert m["serving/cache_state_bytes_per_slot"] == 0
+    assert m["serving/tick_state_slots_live"] == 0
+    assert m["serving/tick_state_bytes"] == 0
+    assert m["serving/tick_latent_bytes"] \
+        == m["serving/tick_cache_rows_live"] * 2 * 2 * 32 * 4
     eng.close()
+
+
+def test_deepseek_description_is_bit_identical_with_a_kind_per_layer(
+        params, mesh):
+    """``attn='mla'`` for the whole model and ``'mla'`` named layer by
+    layer (``attn_kinds``, which a model that mixes kinds uses) are one
+    description: the same declaration, the same logits bit for bit from
+    the prefill and from a tick, the same served tokens and routes."""
+    import dataclasses
+
+    from chainermn_tpu.parallel.decode import lm_prefill
+    from chainermn_tpu.serving import ServingEngine
+
+    whole = arch_of(CFG)
+    each = dataclasses.replace(whole, attn_kinds=("mla",) * 3)
+    assert not whole.has_state and not each.has_state
+    assert blocks.cache_layout(whole, 3, 0, "model") \
+        == blocks.cache_layout(each, 3, 0, "model")
+    assert blocks.lm_specs(whole, params, "model") \
+        == blocks.lm_specs(each, params, "model")
+    prompt = jnp.asarray(
+        np.random.RandomState(5).randint(0, CFG["vocab_size"], (2, 11)),
+        jnp.int32)
+
+    def hidden(arch):
+        fn = lambda p, t: lm_prefill(p, t, 16, head_dim=HEAD_DIM,
+                                     axis_name="model", arch=arch)[0]
+        return np.asarray(_in_mesh(fn, mesh, 2)(params, prompt))
+
+    np.testing.assert_array_equal(hidden(whole), hidden(each))
+    prompts = [np.arange(3, 12, dtype=np.int32),
+               np.arange(20, 27, dtype=np.int32)]
+    served = []
+    for arch in (whole, each):
+        eng = ServingEngine(params, head_dim=HEAD_DIM, mesh=mesh, arch=arch,
+                            n_slots=4, max_total=48, prefill_bucket=8,
+                            queue_capacity=8, spill_bytes=0)
+        handles = _serve(eng, prompts, 6)
+        served.append([(list(h.tokens), np.asarray(h.routes).tolist())
+                       for h in handles])
+        eng.close()
+    assert served[0] == served[1]
 
 
 def test_the_reference_under_tests_is_the_benchmarks_text():
