@@ -28,7 +28,11 @@ programs driven from the host, one tick at a time:
   program.  On a TPU its attention is the flash-decode kernel
   ``lm_generate`` decodes through (``ops/decode_attention.py``), given
   the position vector: each slot's cache is read once, up to the slot's
-  own length.
+  own length.  A slot's input token is the PREVIOUS tick's result for it,
+  taken on the device, unless the host hands one in (the first token
+  after a prefill or an install, a prefix hit's owed prompt tokens): so a
+  tick can be launched before the one ahead of it has been read back
+  (``launch_tick`` / ``collect_tick``; ``tick`` is both in one call).
 
 Token-exactness vs ``lm_generate`` row-by-row is a test invariant
 (tests/test_serving.py): both paths run the identical per-row ops — the
@@ -119,6 +123,12 @@ class DecodeEngine:
         self.tick_routes = self.prefill_routes = None
         self._prefill_progs = {}   # padded prompt length -> compiled fn
         self._tick_prog = self._build_tick()
+        # the newest tick's result, on the device: the next tick's tokens
+        # (placed as a result is, so the first tick is no other program)
+        self._last_result = jax.device_put(
+            np.zeros(result_size(self.arch, pool.n_slots), np.int32),
+            NamedSharding(mesh, P()))
+        self._uncollected = 0           # ticks launched and not yet read
         self._prefix_copy_prog = None   # built lazily on first hit
         # program/compile accounting (flight bundles + /statusz report
         # these: a growing prefill-family or a tick_calls≈compile count
@@ -126,6 +136,9 @@ class DecodeEngine:
         self.prefill_compiles = 0
         self.prefill_calls = 0
         self.tick_calls = 0
+        # of those, the launches made while the tick before was still
+        # unread: the device had the next tick queued behind the running one
+        self.tick_launches_overlapped = 0
         self.prefix_copies = 0
 
     @property
@@ -137,6 +150,7 @@ class DecodeEngine:
     # ---- program builders ----
     def _build_tick(self):
         import jax
+        import jax.numpy as jnp
 
         from ..parallel import blocks as _blocks
         from ..parallel.decode import _next_token, lm_decode_tick
@@ -147,8 +161,12 @@ class DecodeEngine:
         # the jitted programs are named after these functions: the
         # profiler's "XLA Modules" line says ``jit_serving_tick``,
         # ``jit_serving_prefill_<s_pad>``, ``jit_serving_prefix_copy``
-        def serving_tick(params, caches, tokens, pos, keys, temps,
+        def serving_tick(params, caches, prev, override, pos, keys, temps,
                          live=None):
+            # a slot's token: the host's where it hands one in, else what
+            # the tick before chose for the slot (``prev``: its whole result)
+            tokens = jnp.where(override >= 0, override,
+                               prev[:override.shape[0]])
             h_last, new_caches, routing = lm_decode_tick(
                 params, tokens, caches, pos, head_dim=head_dim,
                 axis_name=axis, arch=arch, live=live, with_routing=True)
@@ -160,14 +178,14 @@ class DecodeEngine:
                                   axis, keys, temps, pos + 1)
             return _with_routing(nxt, routing), new_caches
 
-        # a model with experts or state layers takes the busy mask as a
-        # fifth vector.  The pool is DONATED to every program that returns
+        # a model with experts or state layers takes the live mask as a
+        # sixth vector.  The pool is DONATED to every program that returns
         # it: the row write lands in place (un-donated, XLA copied every
         # buffer first)
         return jax.jit(self._shard_map(
             serving_tick, mesh=self.mesh,
             in_specs=(self._specs, self._cache_specs)
-            + (P(),) * (5 if self._takes_live else 4),
+            + (P(),) * (6 if self._takes_live else 5),
             out_specs=(P(), self._cache_specs)), donate_argnums=(1,))
 
     def _build_prefill(self, s_pad: int):
@@ -331,21 +349,25 @@ class DecodeEngine:
             lambda caches: (None, self._prefix_copy_prog(caches, src, dst)))
         self.pool.pos[dst_slot] = int(prefix_len)
 
-    def tick(self, last_tokens: np.ndarray, keys=None,
-             temps=None) -> np.ndarray:
-        """One decode tick for ALL slots: consume ``last_tokens
-        (n_slots,)`` at the pool's per-slot positions, append K/V in
-        place, advance every BUSY slot's position (a free slot holds
-        its own: ``CachePool.advance``), and return the next token per
-        slot (the caller keeps only the active rows).  ``keys (n_slots,
+    def launch_tick(self, override: np.ndarray, keys=None, temps=None,
+                    live=None):
+        """Launch one decode tick for ALL slots and return without waiting
+        for it: slot ``i`` consumes ``override[i]`` where that is >= 0,
+        else the token the PREVIOUS launch chose for it (still on the
+        device), at the pool's per-slot positions; K/V is appended in
+        place and every ``live`` slot's position advances (``live``: the
+        slots that carry a request's token, default the pool's busy slots
+        — the others hold theirs: ``CachePool.advance``).  ``keys (n_slots,
         2) uint32`` / ``temps (n_slots,)`` carry each slot's request rng
         and temperature (ISSUE 9 sampling plumbing); None = all-greedy
-        (dummy keys, never consumed)."""
+        (dummy keys, never consumed).  The result starts on its way to the
+        host at once; :meth:`collect_tick` takes what this returns."""
         import jax.numpy as jnp
 
         self.tick_calls += 1
+        self.tick_launches_overlapped += bool(self._uncollected)
         with _trace.span("serving/tick/stage", cat="serving"):
-            tokens = jnp.asarray(np.array(last_tokens, np.int32, copy=True))
+            override = jnp.asarray(np.array(override, np.int32, copy=True))
             # COPY at the jax boundary: on CPU ``jnp.asarray`` may
             # zero-copy alias the host buffer, and dispatch is ASYNC — an
             # in-place ``pos += 1`` below would race the still-executing
@@ -358,17 +380,28 @@ class DecodeEngine:
                 temps = np.zeros(self.pool.n_slots, np.float32)
             keys = jnp.asarray(np.array(keys, np.uint32, copy=True))
             temps = jnp.asarray(np.array(temps, np.float32, copy=True))
-            busy = self.pool.busy_mask()
-            operands = (tokens, pos, keys, temps) + (
-                (jnp.asarray(busy),) if self._takes_live else ())
+            if live is None:
+                live = self.pool.busy_mask()
+            operands = (self._last_result, override, pos, keys, temps) + (
+                (jnp.asarray(live),) if self._takes_live else ())
         with _trace.span("serving/tick/dispatch", cat="serving"):
-            nxt = self.pool.update(
+            nxt = self._last_result = self.pool.update(
                 lambda caches: self._tick_prog(self._params, caches,
                                                *operands))
-        self.pool.advance(busy)   # out-of-place: never mutate a buffer
+            nxt.copy_to_host_async()
+        self.pool.advance(live)   # out-of-place: never mutate a buffer
         #                           jax might still read
+        self._uncollected += 1
+        return nxt
+
+    def collect_tick(self, launched) -> np.ndarray:
+        """Wait for a launched tick and return its next token per slot
+        (the caller keeps the rows that were live in it).  A model with
+        experts: ``tick_routes`` and ``moe_counts_tick`` are this tick's
+        from here on."""
         with _trace.span("serving/tick/readback", cat="serving"):
-            out = np.asarray(nxt)
+            out = np.asarray(launched)
+        self._uncollected -= 1
         if self.n_counts:
             n = self.pool.n_slots
             self.moe_counts_tick += out[n:n + self.n_counts]
@@ -376,6 +409,23 @@ class DecodeEngine:
                 (n,) + self._route_shape)
             out = out[:n]
         return out
+
+    def tick(self, last_tokens: np.ndarray, keys=None,
+             temps=None) -> np.ndarray:
+        """One decode tick, launched and read back in one call: every
+        slot consumes ``last_tokens (n_slots,)`` (all >= 0: the host
+        hands every token in)."""
+        return self.collect_tick(self.launch_tick(last_tokens, keys, temps))
+
+
+def result_size(arch, n_slots: int) -> int:
+    """Entries of a tick's one int32 result: a token a slot, then — where
+    the model has expert layers — the routing counts and each slot's
+    chosen experts (:func:`_with_routing`)."""
+    from ..parallel import blocks as _blocks
+
+    return (n_slots * (1 + int(np.prod(_blocks.route_shape(arch))))
+            + _blocks.n_count_entries(arch))
 
 
 def _with_routing(tokens, routing):

@@ -11,8 +11,9 @@ compiled per-tick programs into the loop a service actually runs::
     print(h.tokens, h.status, h.ttft_ms)
 
 Each ``step()`` is one engine iteration: expire overdue queued work,
-admit (prefill) up to the interleaving bound, run ONE decode tick over
-the pool, stream the new tokens, evict finished sequences.  Requests
+admit (prefill) up to the interleaving bound, LAUNCH one decode tick over
+the pool, read back the tick launched a step ago (the device ran it
+meanwhile), stream its tokens, evict finished sequences.  Requests
 therefore join and leave between ticks — a late submit starts decoding
 as soon as a slot frees, while earlier sequences keep running
 (iteration-level / continuous batching).
@@ -59,7 +60,7 @@ import sys
 import threading
 import time
 from collections import deque
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, NamedTuple, Optional
 
 import numpy as np
 
@@ -71,6 +72,17 @@ from .cache_pool import CachePool
 from .engine import DecodeEngine
 from .prefix_cache import PrefixCache
 from .scheduler import AdmissionError, Request, Scheduler
+
+
+class _TickInFlight(NamedTuple):
+    """A launched tick whose result is not read yet: the device array, the
+    rows it ran for — ``{slot: (request, emits)}`` as they stood AT LAUNCH,
+    ``emits`` False while the row fed a prompt token whose prediction is
+    known — and when it was launched (monotonic seconds; tracer's us)."""
+    result: Any
+    rows: Dict[int, tuple]
+    t_launch: float
+    t_launch_us: int
 
 
 class RequestHandle:
@@ -276,6 +288,12 @@ class ServingEngine:
         # the ISSUE 9 acceptance metric (tick_gap p99/p50 collapse).
         self._tick_gap_ms = ReservoirSample(self.stats_capacity)
         self._last_tick_start: Optional[float] = None
+        # the tick launched by the last step and not read back yet (the
+        # stepping thread's alone), when the last read ended, and the rows
+        # computed for a request that had ended before they were read
+        self._in_flight: Optional[_TickInFlight] = None
+        self._last_collect_end = 0.0
+        self._tick_rows_discarded = 0
         # per-slot sampling operands (ISSUE 9): each slot's request rng
         # key + temperature ride every tick; greedy slots carry zeros
         # (their key is never consumed)
@@ -401,7 +419,16 @@ class ServingEngine:
 
     # ---- the engine iteration ----
     def step(self) -> Dict[str, float]:
-        """ONE engine iteration: expire → admit/prefill → tick → evict.
+        """ONE engine iteration: expire → admit/prefill → LAUNCH the next
+        tick → READ BACK the tick launched a step ago → emit its tokens →
+        evict.  One tick is always in flight while rows owe tokens: the
+        device runs tick N+1 (fed tick N's tokens where they are, on the
+        device) while the host emits N's, books, takes submissions and
+        stages N+2.  A request whose tokens are all emitted or in flight
+        launches no row; one that ends where the host cannot foresee it
+        (EOS, a deadline) leaves one row behind, whose token is dropped
+        (``serving/tick_rows_discarded``).  A step with nothing to launch
+        only reads back, so the pipeline drains by itself.
         Returns host-side stats for the iteration (also streamed to the
         JSONL metrics writer when configured).
 
@@ -417,9 +444,10 @@ class ServingEngine:
         Findings PR 25).
 
         Goodput attribution: the whole iteration's wall clock lands in
-        ledger buckets — prefill/tick device calls as ``compute`` (or
-        ``compile`` on a call that built a new program), everything
-        around them as ``host``, and the gap since the previous step as
+        ledger buckets — a prefill's device call and the wait for a tick's
+        result as ``compute`` (``compile`` on a call that built a new
+        program), everything around them, the tick's launch included, as
+        ``host``, and the gap since the previous step as
         ``queue_wait`` (work was waiting) or ``stall`` (idle)."""
         with obs.span("serving/step", cat="serving", tick=self._ticks):
             t_step0 = time.monotonic()
@@ -589,82 +617,95 @@ class ServingEngine:
                         self._prefill_tokens_padded += s_pad
                     self._maybe_evict(req, time.monotonic())
 
-            # one decode tick over the pool (skip when nothing is active)
+            # one decode tick IN FLIGHT: launch the next tick over the rows
+            # that still owe a token, then read back the one launched a step
+            # ago, which the device ran meanwhile.  A row's input is the
+            # tick-before's result on the device unless the host knows it
+            # (a first token, an owed prompt token, a drained pipeline)
+            flying = self._in_flight
             with self._lock:
-                active = dict(self._running)
-            if active:
-                tokens = np.zeros(self.pool.n_slots, np.int32)
-                for slot, req in active.items():
+                running = dict(self._running)
+            rows: Dict[int, tuple] = {}
+            override = np.zeros(self.pool.n_slots, np.int32)
+            for slot, req in running.items():
+                ahead = flying.rows.get(slot) if flying is not None else None
+                emits_ahead = (ahead is not None and ahead[0] is req
+                               and ahead[1])
+                if len(req.tokens) + emits_ahead >= req.max_new_tokens:
+                    # every token it may have is emitted or in flight: no
+                    # row; the slot stays its own until that one is read
+                    continue
+                if req.forced:
                     # a prefix-hit request still owing suffix tokens feeds
                     # the next PROMPT token (its K/V row gets written; the
                     # prediction is known and discarded until the last one)
-                    tokens[slot] = (req.forced[0] if req.forced
-                                    else req.tokens[-1])
-                t_tick = time.monotonic()
-                self.goodput.add("host", t_tick - t_host)
-                # inter-tick gap: what a decoding request waits between its
-                # tokens — includes any prefill that ran above (the fused
-                # engine's tail; disaggregation exists to cut it, ISSUE 9).
-                # Locked with reset_stats: a warm-up reset racing this
-                # read-modify-write could book one warm-up gap into the
-                # measured window (the unguarded-shared-write lint class)
-                read, total = live_blocks(self.pool.pos, self.pool.max_total)
-                with self._lock:
-                    if self._last_tick_start is not None:
-                        self._tick_gap_ms.add(
-                            (t_tick - self._last_tick_start) * 1e3)
-                    self._last_tick_start = t_tick
-                    self._tick_cache_blocks_read += read
-                    self._tick_cache_blocks_total += total
-                    self._tick_cache_rows_live += int(np.minimum(
-                        self.pool.pos, self.pool.max_total - 1).sum()
-                        ) + self.pool.n_slots
-                    self._tick_state_slots_live += (
-                        self.pool.busy_count * self.pool.n_state_layers)
-                tick_bucket = ("compile" if self.engine.tick_calls == 0
-                               else "compute")
-                # the tracer's clock is read only for its own Chrome sink
-                recording = obs.enabled()
-                t_tick_us = obs.now_us() if recording else 0
-                with obs.span("serving/tick", cat="serving",
-                              active=len(active)):
-                    with self.goodput.measure(tick_bucket):
-                        nxt = self.engine.tick(tokens, self._slot_keys,
-                                               self._slot_temps)
-                t_host = time.monotonic()
-                dt_ms = (t_host - t_tick) * 1e3
-                dt_us = obs.now_us() - t_tick_us if recording else 0
-                now = time.monotonic()
-                routes = self.engine.tick_routes
-                with obs.span("serving/emit", cat="serving",
-                              tokens=len(active)):
-                    for slot, req in active.items():
-                        if recording:
-                            # per-request decode-tick span, nested under the
-                            # engine tick on the timeline and keyed by the
-                            # trace id
-                            obs.complete_event(
-                                "request/decode_tick", t_tick_us, dt_us,
-                                cat="serving_request", trace_id=req.trace_id,
-                                request=req.id, slot=slot, active=len(active))
-                        still_forced = False
-                        if req.forced:
-                            req.forced.popleft()
-                            still_forced = bool(req.forced)
-                        if not still_forced:
-                            # miss path, or the suffix's last prompt token
-                            # just ran: the tick's prediction IS the next
-                            # real token
-                            if routes is not None:
-                                self._keep_routes(req, routes[slot])
-                            self._emit(req, int(nxt[slot]), now)
-                        self._tok_lat_ms.add(dt_ms / max(len(active), 1))
-                        self._maybe_evict(req, now)
-            else:
-                # an idle step breaks the tick cadence: the next gap would
-                # measure stall, not inter-token latency — restart the clock
-                with self._lock:
-                    self._last_tick_start = None
+                    override[slot] = req.forced.popleft()
+                elif emits_ahead:
+                    override[slot] = -1    # the token in flight, on the device
+                else:
+                    override[slot] = req.tokens[-1]
+                rows[slot] = (req, not req.forced)
+            launched = None
+            with obs.span("serving/tick", cat="serving", active=len(rows)):
+                if rows:
+                    live = np.zeros(self.pool.n_slots, bool)
+                    live[list(rows)] = True
+                    t_tick = time.monotonic()
+                    # inter-tick gap, launch to launch: what a decoding
+                    # request waits between its tokens — includes any
+                    # prefill that ran above (the fused engine's tail;
+                    # disaggregation exists to cut it, ISSUE 9).  Locked
+                    # with reset_stats: a warm-up reset racing this
+                    # read-modify-write could book one warm-up gap into the
+                    # measured window (the unguarded-shared-write lint class)
+                    read, total = live_blocks(self.pool.pos,
+                                              self.pool.max_total)
+                    with self._lock:
+                        if self._last_tick_start is not None:
+                            self._tick_gap_ms.add(
+                                (t_tick - self._last_tick_start) * 1e3)
+                        self._last_tick_start = t_tick
+                        self._tick_cache_blocks_read += read
+                        self._tick_cache_blocks_total += total
+                        self._tick_cache_rows_live += int(np.minimum(
+                            self.pool.pos, self.pool.max_total - 1).sum()
+                            ) + self.pool.n_slots
+                        self._tick_state_slots_live += (
+                            len(rows) * self.pool.n_state_layers)
+                    # the tracer's clock is read only for its own Chrome sink
+                    t_tick_us = obs.now_us() if obs.enabled() else 0
+                    first = self.engine.tick_calls == 0
+                    launched = _TickInFlight(
+                        self.engine.launch_tick(override, self._slot_keys,
+                                                self._slot_temps, live),
+                        rows, t_tick, t_tick_us)
+                    if first:
+                        # a launch is the host's time, but for the first,
+                        # which builds the program
+                        self.goodput.add("host", t_tick - t_host)
+                        t_host = time.monotonic()
+                        self.goodput.add("compile", t_host - t_tick)
+                else:
+                    # a step that launches nothing breaks the tick cadence:
+                    # the next gap would measure stall, not inter-token
+                    # latency — restart the clock
+                    with self._lock:
+                        self._last_tick_start = None
+                nxt = None
+                if flying is not None:
+                    t_host, nxt = self._collect_tick(flying, t_host)
+            self._in_flight = launched
+            if nxt is not None:
+                self._emit_tick(flying, nxt, t_host)
+                if launched is not None and all(
+                        req.finish_reason is not None
+                        for req, _ in launched.rows.values()):
+                    # every request of the tick in flight ended on the token
+                    # just read (EOS, a deadline): nothing will step the
+                    # engine for its sake, so read it here and drop its rows
+                    self._drain_tick()
+                    t_host = time.monotonic()
+            active = bool(rows) or flying is not None
 
             with obs.span("serving/bookkeeping", cat="serving"):
                 with self._lock:
@@ -718,6 +759,65 @@ class ServingEngine:
         a model without)."""
         if routes is not None:
             req.routes.append(routes)
+
+    def _collect_tick(self, flying: _TickInFlight, t_host: float):
+        """Block until ``flying`` has run and read its result: ``(now, next
+        token per slot)``.  The wait is the ledger's ``compute``, the host
+        segment since ``t_host`` its ``host``."""
+        t_wait = time.monotonic()
+        self.goodput.add("host", t_wait - t_host)
+        nxt = self.engine.collect_tick(flying.result)
+        now = time.monotonic()
+        self.goodput.add("compute", now - t_wait)
+        return now, nxt
+
+    def _emit_tick(self, flying: _TickInFlight, nxt, now: float) -> None:
+        """Hand a collected tick's tokens to the requests it ran for — the
+        rows as they stood at ITS launch, never ``_running`` as it stands
+        now — and evict what finished.  A row whose request ended while it
+        was in flight (EOS, a deadline: ends the host cannot foresee) is
+        dropped and counted; the engine's ``tick_routes`` are this tick's."""
+        # the wall time the engine needed for this tick: read to read while
+        # ticks follow each other, launch to read for the first of a run
+        dt_ms = (now - max(flying.t_launch, self._last_collect_end)) * 1e3
+        self._last_collect_end = now
+        recording = obs.enabled() and flying.t_launch_us
+        dt_us = obs.now_us() - flying.t_launch_us if recording else 0
+        routes = self.engine.tick_routes
+        n_rows = len(flying.rows)
+        with obs.span("serving/emit", cat="serving", tokens=n_rows):
+            for slot, (req, emits) in flying.rows.items():
+                if req.finish_reason is not None:
+                    with self._lock:
+                        self._tick_rows_discarded += 1
+                    continue
+                if recording:
+                    # per-request decode-tick span, launch to read, keyed
+                    # by the trace id
+                    obs.complete_event(
+                        "request/decode_tick", flying.t_launch_us, dt_us,
+                        cat="serving_request", trace_id=req.trace_id,
+                        request=req.id, slot=slot, active=n_rows)
+                if emits:
+                    # miss path, or the suffix's last prompt token just
+                    # ran: the tick's prediction IS the next real token
+                    if routes is not None:
+                        self._keep_routes(req, routes[slot])
+                    self._emit(req, int(nxt[slot]), now)
+                self._tok_lat_ms.add(dt_ms / n_rows)
+                self._maybe_evict(req, now)
+
+    def _drain_tick(self) -> None:
+        """Read back the tick in flight, if any, and emit its tokens: no
+        driver leaves a launched tick unread."""
+        flying, self._in_flight = self._in_flight, None
+        if flying is None:
+            return
+        with obs.span("serving/tick", cat="serving", active=0):
+            now, nxt = self._collect_tick(flying, time.monotonic())
+        self._emit_tick(flying, nxt, now)
+        with self._lock:
+            self._last_step_end = time.monotonic()
 
     def _emit(self, req: Request, token: int, now: float) -> None:
         req.tokens.append(int(token))
@@ -851,9 +951,14 @@ class ServingEngine:
     def _retire_slot(self, req: Request, slot: int) -> None:
         """Finished request: unpin its prefix source, then DONATE the
         slot to the prefix cache (busy → cached, rc=0) keyed by every
-        K/V row actually written — ``prompt + generated[:-1]`` clipped
-        to the slot's position — falling back to a plain release when
-        the cache dedups the donation or is disabled."""
+        token the slot has consumed — ``prompt + generated`` clipped to
+        the slot's position — falling back to a plain release when the
+        cache dedups the donation or is disabled.  The last generated
+        token is consumed only where the request ended under a tick in
+        flight (EOS, a deadline): that tick's row for it, which any later
+        program on the pool runs after, is the token's own row — and, on
+        a state layer, its state — so the slot is booked where the device
+        stands."""
         cache = self.prefix_cache
         if req.prefix_entry is not None and cache is not None:
             cache.release(req.prefix_entry)
@@ -863,7 +968,7 @@ class ServingEngine:
         self._slot_temps[slot] = 0.0
         if cache is not None:
             length = int(self.pool.pos[slot])
-            seq = list(req.prompt) + list(req.tokens[:-1])
+            seq = list(req.prompt) + list(req.tokens)
             if length >= cache.min_prefix_len \
                     and cache.insert(seq[:length], slot, length) is not None:
                 self.pool.cache(slot)
@@ -977,6 +1082,7 @@ class ServingEngine:
                 continue
             self.step()
             n += 1
+        self._drain_tick()
         return n
 
     def start(self) -> None:
@@ -998,10 +1104,13 @@ class ServingEngine:
         self._thread.start()
 
     def stop(self) -> None:
+        """Stop the driver thread; the tick in flight is read back and its
+        tokens emitted (from the caller's thread)."""
         self._stop.set()
         if self._thread is not None:
             self._thread.join(timeout=10)
             self._thread = None
+        self._drain_tick()
 
     def close(self) -> None:
         """Retire the engine: stop the driver thread and drop the
@@ -1033,6 +1142,7 @@ class ServingEngine:
             self._tick_cache_blocks_total = 0
             self._tick_cache_rows_live = 0
             self._tick_state_slots_live = 0
+            self._tick_rows_discarded = 0
             self.engine.moe_counts_tick[:] = 0
             self.engine.moe_counts_prefill[:] = 0
             self.pool.calls = self.pool.calls_donated = 0
@@ -1125,6 +1235,13 @@ class ServingEngine:
                 "serving/tick_latent_bytes": float(
                     self._tick_cache_rows_live * self.pool.bytes_per_token),
                 "serving/tick_calls": float(self.engine.tick_calls),
+                # of the launches, those made while the tick before was
+                # still unread (the device never waited for the host), and
+                # the rows computed for a request that had already ended
+                "serving/tick_launches_overlapped": float(
+                    self.engine.tick_launches_overlapped),
+                "serving/tick_rows_discarded": float(
+                    self._tick_rows_discarded),
                 # program calls that returned the pool's buffers (ticks,
                 # prefills, prefix copies, landed slabs), and those after
                 # which the buffers passed were deleted: donated, so
@@ -1193,6 +1310,7 @@ class ServingEngine:
             "rejected": self._rejected,
             "prefill_compiles": self.engine.prefill_compiles,
             "tick_calls": self.engine.tick_calls,
+            "tick_in_flight": self._in_flight is not None,
             "prefix_copies": self.engine.prefix_copies,
             "goodput": self.goodput.report(),
             "requests": self.requests_table(),

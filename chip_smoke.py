@@ -384,6 +384,7 @@ def phase_serve(ctx):
         n = sz["n_slots"]
         return engine.engine._tick_prog.lower(
             engine.engine._params, engine.pool.caches,
+            engine.engine._last_result,      # the tick before's, on device
             jnp.asarray(z(n, np.int32)), jnp.asarray(z(n, np.int32)),
             jnp.asarray(z((n, 2), np.uint32)),
             jnp.asarray(z(n, np.float32))).compile()

@@ -282,7 +282,7 @@ def test_serving_prefill_and_tick(topo, as_tpu, shape):
     a bare instance given the same attributes."""
     from chainermn_tpu._compat import shard_map
     from chainermn_tpu.parallel import blocks
-    from chainermn_tpu.serving.engine import DecodeEngine
+    from chainermn_tpu.serving.engine import DecodeEngine, result_size
 
     n_heads, head_dim, n_slots, prompt, total = SERVING_SHAPES[shape]
     mesh = Mesh(np.array(topo.devices[:1]), ("model",))
@@ -310,8 +310,8 @@ def test_serving_prefill_and_tick(topo, as_tpu, shape):
                                   (n_slots, total, D_MODEL))
 
     tick = eng._build_tick().lower(
-        p, caches, _sds((n_slots,), jnp.int32, rep),
-        _sds((n_slots,), jnp.int32, rep),
+        p, caches, _sds((result_size(eng.arch, n_slots),), jnp.int32, rep),
+        _sds((n_slots,), jnp.int32, rep), _sds((n_slots,), jnp.int32, rep),
         _sds((n_slots, 2), jnp.uint32, rep),
         _sds((n_slots,), jnp.float32, rep)).compile()
     assert "HloModule jit_serving_tick" in tick.as_text()
@@ -333,7 +333,7 @@ def test_latent_attention_and_expert_serving_programs(topo, as_tpu):
     from chainermn_tpu._compat import shard_map
     from chainermn_tpu.parallel import blocks
     from chainermn_tpu.parallel.blocks import LMArch, MLAConfig, MoEConfig
-    from chainermn_tpu.serving.engine import DecodeEngine
+    from chainermn_tpu.serving.engine import DecodeEngine, result_size
 
     d, heads, q_rank, kv_rank, nope, rope, v = 7168, 128, 1536, 512, 128, 64, 128
     inner, e_inner, experts, held, vocab = 18432, 2048, 256, 16, 16160
@@ -389,11 +389,11 @@ def test_latent_attention_and_expert_serving_programs(topo, as_tpu):
     eng._cache_specs = [tuple(spec for _, spec in bufs) for bufs in layout]
 
     tick = eng._build_tick().lower(
-        p, caches, _sds((n_slots,), jnp.int32, rep),
-        _sds((n_slots,), jnp.int32, rep),
+        p, caches, _sds((result_size(eng.arch, n_slots),), jnp.int32, rep),
+        _sds((n_slots,), jnp.int32, rep), _sds((n_slots,), jnp.int32, rep),
         _sds((n_slots, 2), jnp.uint32, rep),
         _sds((n_slots,), jnp.float32, rep),
-        _sds((n_slots,), jnp.bool_, rep)).compile().as_text()   # busy mask
+        _sds((n_slots,), jnp.bool_, rep)).compile().as_text()   # live mask
     assert "HloModule jit_serving_tick" in tick
     assert tick.count("%decode_attn_mla") >= layers
     assert tick.count("%moe_gmm") >= 3          # gate, up, down
@@ -422,7 +422,7 @@ def _kimi_programs(topo, n_layers, prompts):
     from chainermn_tpu.parallel import blocks
     from chainermn_tpu.parallel.blocks import (KDAConfig, LMArch, MLAConfig,
                                                MoEConfig)
-    from chainermn_tpu.serving.engine import DecodeEngine
+    from chainermn_tpu.serving.engine import DecodeEngine, result_size
 
     here = os.path.dirname(os.path.abspath(__file__))
     spec = importlib.util.spec_from_file_location(
@@ -473,11 +473,11 @@ def _kimi_programs(topo, n_layers, prompts):
     eng._specs, eng._shard_map, eng._P = specs, shard_map, P
     eng._cache_specs = [tuple(b[-1] for b in bufs) for bufs in layout]
     tick = eng._build_tick().lower(
-        p, caches, _sds((n_slots,), jnp.int32, rep),
-        _sds((n_slots,), jnp.int32, rep),
+        p, caches, _sds((result_size(eng.arch, n_slots),), jnp.int32, rep),
+        _sds((n_slots,), jnp.int32, rep), _sds((n_slots,), jnp.int32, rep),
         _sds((n_slots, 2), jnp.uint32, rep),
         _sds((n_slots,), jnp.float32, rep),
-        _sds((n_slots,), jnp.bool_, rep)).compile()          # busy mask
+        _sds((n_slots,), jnp.bool_, rep)).compile()          # live mask
     prefills = {
         s: eng._build_prefill(s).lower(
             p, caches, _sds((1, s), jnp.int32, rep),
