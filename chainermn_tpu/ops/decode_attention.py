@@ -38,10 +38,18 @@ wholly above a row's position is neither fetched nor computed (it would
 have contributed exact zeros).  A position at or beyond ``S`` (a free
 serving slot, whose position the engine advances without bound) masks
 nothing and indexes nothing out of range; ``pos`` must be ≥ 0.
-``decode_attend`` covers h_q == h_kv; GQA decode rides the BEAM kernel
-(``decode_attend_gqa``: the g query groups of a batch row share its
-cache row — exactly the beam row mapping — with the same per-row
-position by scalar prefetch, ``masked='pos'``).
+``decode_attend`` covers h_q == h_kv.  GQA decode (``decode_attend_gqa``)
+has the same face — one position per cache row, the ragged read — through
+its own kernel where a head is whole lane tiles (``head_dim % 128 == 0``):
+a KV head's columns are then an aligned slice of the flat row, and its
+``g`` query heads meet it as two plain MXU matmuls (``(g, hd) x (hd,
+S_b)``, ``(g, S_b) x (S_b, hd)``), the cache read once.  Narrower heads
+ride the BEAM kernel (the g query groups of a batch row share its cache
+row — exactly the beam row mapping — ``masked='pos'``).  A cache of ``W``
+rows that is a RING (a windowed layer: position ``p`` at row ``p % W``,
+every key rotated at its own position before it was cached) needs nothing
+more: a position at or beyond ``W`` masks nothing, so ``min(pos + 1, W)``
+rows are read, and the softmax does not care about their order.
 
 ``decode_attend_mla`` is the face for a LATENT cache (multi-head latent
 attention in its absorbed form): every query head of a slot attends ONE
@@ -442,17 +450,113 @@ def merge_attend_parts(parts, n_heads: int, head_dim: int, dtype):
     return jnp.where(den > 0, ctx, 0.0).astype(dtype)
 
 
+def _gqa_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
+                *, block_s, n_blocks, scale, n_kv, rows, head_dim):
+    """One cache row per grid row, as :func:`_mla_kernel`, with a K/V pair
+    of ``n_kv`` heads: ``q_ref (1, n_kv·rows, hd)`` float32 holds KV head
+    ``h``'s query heads in rows ``[h·rows, (h+1)·rows)`` (``rows``: the
+    group rounded up to whole sublane tiles, the pad rows zero), ``k_ref /
+    v_ref (1, S_b, n_kv·hd)`` the flat cache block, whose columns ``[h·hd,
+    (h+1)·hd)`` are head ``h``'s."""
+    i, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j * block_s <= pos_ref[i])
+    def _block():
+        idx = j * block_s + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, block_s), 1)
+        live = idx <= pos_ref[i]
+        for h in range(n_kv):
+            r = slice(h * rows, (h + 1) * rows)
+            c = slice(h * head_dim, (h + 1) * head_dim)
+            k = k_ref[0, :, c]                         # (S_b, hd)
+            v = v_ref[0, :, c]
+            s_blk = jax.lax.dot_general(
+                q_ref[0, r, :].astype(k.dtype), k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (rows, S_b)
+            s_blk = jnp.where(live, s_blk, _NEG)
+            m_prev = m_ref[r, :1]                      # (rows, 1)
+            m_new = jnp.maximum(m_prev, s_blk.max(-1, keepdims=True))
+            p = jnp.where(live, jnp.exp(s_blk - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = l_ref[r, :1] * alpha + p.sum(-1, keepdims=True)
+            acc_ref[r, :] = acc_ref[r, :] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)    # (rows, hd)
+            m_ref[r, :] = jnp.broadcast_to(m_new, (rows, m_ref.shape[1]))
+            l_ref[r, :] = jnp.broadcast_to(l_new, (rows, l_ref.shape[1]))
+
+    @pl.when(j == n_blocks - 1)
+    def _finish():
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-37)
+                    ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "n_q_heads", "n_kv_heads", "head_dim", "block_s", "interpret"))
+def _decode_attend_gqa_lanes(q, kc, vc, pos, *, n_q_heads, n_kv_heads,
+                             head_dim, block_s, interpret):
+    """:func:`decode_attend_gqa` through :func:`_gqa_kernel`."""
+    b, s, d_kv = kc.shape
+    g = n_q_heads // n_kv_heads
+    rows = -(-g // 8) * 8
+    bs = _pick_block_s(s, block_s)
+    if bs == 0:
+        raise ValueError(f"S={s} has no 8-aligned block ≤ {block_s}")
+    n_blocks = s // bs
+    # head-major (Hkv, g, hd): query head h·g + r is KV head h's r-th
+    qg = q.reshape(b, n_kv_heads, g, head_dim).astype(jnp.float32)
+    if rows != g:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows - g), (0, 0)))
+    kv_map = _live_block_map(s, bs)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(b, n_blocks),
+        in_specs=[
+            pl.BlockSpec((1, n_kv_heads * rows, head_dim),
+                         lambda i, j, p_: (i, 0, 0)),
+            pl.BlockSpec((1, bs, d_kv), kv_map),
+            pl.BlockSpec((1, bs, d_kv), kv_map),
+        ],
+        out_specs=pl.BlockSpec((1, n_kv_heads * rows, head_dim),
+                               lambda i, j, p_: (i, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((n_kv_heads * rows, 128), jnp.float32),
+            pltpu.VMEM((n_kv_heads * rows, 128), jnp.float32),
+            pltpu.VMEM((n_kv_heads * rows, head_dim), jnp.float32),
+        ])
+    out = pl.pallas_call(
+        functools.partial(_gqa_kernel, block_s=bs, n_blocks=n_blocks,
+                          scale=1.0 / (head_dim ** 0.5), n_kv=n_kv_heads,
+                          rows=rows, head_dim=head_dim),
+        grid_spec=grid_spec,
+        out_shape=_sds((b, n_kv_heads * rows, head_dim), q.dtype,
+                       vma=_inherit_vma(q, kc, vc)),
+        name="decode_attn_gqa",
+        interpret=interpret,
+    )(_row_pos(pos, b), qg.reshape(b, n_kv_heads * rows, head_dim), kc, vc)
+    return out.reshape(b, n_kv_heads, rows, head_dim)[:, :, :g].reshape(
+        b, n_q_heads * head_dim)
+
+
 def decode_attend_gqa(q, kc, vc, pos, *, n_q_heads: int, n_kv_heads: int,
                       head_dim: int, block_s: int = DEFAULT_BLOCK_S,
                       interpret: bool = False):
     """GQA decode tick: grouped queries against the shared-KV-head cache.
 
-    Structurally the BEAM problem: the ``g = n_q_heads/n_kv_heads`` query
+    Heads of whole lane tiles (``head_dim % 128 == 0``) take the
+    one-position-per-cache-row face of :func:`decode_attend` through
+    :func:`_gqa_kernel`.  Narrower heads ride the beam kernel, for it is
+    structurally the BEAM problem: the ``g = n_q_heads/n_kv_heads`` query
     groups of batch row ``b`` all attend batch row ``b``'s cache — so the
     beam kernel serves GQA verbatim with ``beams=g`` and the position-
     validity mask from the row's prefetch scalar (``masked='pos'``).
-    The cache still streams ONCE per tick (grid is (B, S-blocks); the g
-    groups iterate in-register) — GQA's inference payoff is preserved.
+    Either way the cache streams ONCE per tick (grid is (B, S-blocks); the
+    g groups iterate in-register) — GQA's inference payoff is preserved.
 
     ``q (B, Hq·hd)`` head-major flat; ``kc/vc (B, S, Hkv·hd)``; ``pos``
     a scalar or a ``(B,)`` int32 vector (one position per cache row,
@@ -465,6 +569,10 @@ def decode_attend_gqa(q, kc, vc, pos, *, n_q_heads: int, n_kv_heads: int,
     g = n_q_heads // n_kv_heads
     if n_q_heads % n_kv_heads or g < 1:
         raise ValueError(f"bad head ratio {n_q_heads}/{n_kv_heads}")
+    if head_dim % 128 == 0:
+        return _decode_attend_gqa_lanes(
+            q, kc, vc, pos, n_q_heads=n_q_heads, n_kv_heads=n_kv_heads,
+            head_dim=head_dim, block_s=block_s, interpret=interpret)
     # (B, Hkv, g, hd) -> group-major rows (B·g, Hkv·hd), b-major like the
     # beam kernel's row->cache mapping expects
     q_g = q.reshape(b, n_kv_heads, g, head_dim).transpose(0, 2, 1, 3) \

@@ -39,7 +39,13 @@ sub-blocks the diagonal (or a padded tail) crosses.  The schedule is
 static: every distinct way a cell is computed (its *plan*) is one
 straight-line body, all of the cell's Q sub-blocks in one region, and the
 cell picks its plan from its program ids.  ``causal=False`` with no tail
-has one plan, the undivided cell.
+has one plan, the undivided cell.  A WINDOW (``window=W``: query ``q`` sees
+keys ``0 <= q - k < W``; forward only) is one more edge of the same
+schedule: grid cells wholly left of the band are skipped and their DMA
+elided like those above the diagonal, and inside a cell each Q sub-block
+starts its one product at the first K sub-block the band reaches and
+masks those the band's left edge crosses, as it masks those the diagonal
+crosses.
 
 Layout: ``(B, S, H, D)`` — the same convention as ``parallel/``'s ring and
 Ulysses attention, which uses this kernel for its local (post-all-to-all)
@@ -168,8 +174,31 @@ def _k_sub_range(row0, col0, sub_q, sub_k, n_sub_k, causal, seq_len,
     return n_full, n_run
 
 
+def _k_band_range(row0, col0, sub_q, sub_k, n_sub_k, causal, seq_len, window,
+                  least=jnp.minimum, most=jnp.maximum):
+    """:func:`_k_sub_range` under a window, as ``(n_skip, n_edge, n_full,
+    n_masked)``: of the cell's K sub-blocks the first ``n_skip`` lie wholly
+    left of the band (``q - k >= window`` for every pair) and are never
+    computed, the next ``n_edge`` are crossed by the band's left edge
+    (masked), the next ``n_full`` need no mask, the next ``n_masked`` are
+    crossed by the diagonal or the padded tail (masked), the rest are never
+    computed.  All zero where nothing is computed."""
+    n_full, n_run = _k_sub_range(row0, col0, sub_q, sub_k, n_sub_k, causal,
+                                 seq_len, least, most)
+    d = row0 - col0
+    skip = least(most(d - window + 1, 0) // sub_k, n_run)
+    # from here on no pair is left of the band
+    clear = most(d + sub_q - 1 - window + sub_k, 0) // sub_k
+    edge_end = least(most(clear, skip), n_run)
+    full_end = least(most(n_full, edge_end), n_run)
+    some = least(n_run - skip, 1)              # 0: nothing is computed
+    return (skip * some, (edge_end - skip) * some,
+            (full_end - edge_end) * some, (n_run - full_end) * some)
+
+
 def causal_schedule(s: int, block_q: int, block_k: int, causal: bool = True,
-                    seq_len: Optional[int] = None) -> dict:
+                    seq_len: Optional[int] = None,
+                    window: Optional[int] = None) -> dict:
     """The static schedule of one head's ``s × s`` score matrix under a
     ``block_q × block_k`` grid: the sub-block edges a cell is divided by
     (the whole block where nothing is masked: ``causal=False``, no tail),
@@ -181,9 +210,12 @@ def causal_schedule(s: int, block_q: int, block_k: int, causal: bool = True,
     ``n_masked`` masked, the rest not at all.  The kernels hold one static
     body per plan and pick a cell's from its program ids with the same
     :func:`_k_sub_range`.  ``seq_len < s`` says where a padded tail
-    begins."""
+    begins.  With ``window`` (causal only) a Q sub-block's entry is
+    :func:`_k_band_range`'s ``(n_skip, n_edge, n_full, n_masked)``."""
     if seq_len == s:
         seq_len = None
+    if window is not None and not causal:
+        raise ValueError("a window is the causal band 0 <= q - k < window")
     divide = causal or seq_len is not None
     sub_q = _sub_block(block_q) if divide else block_q
     sub_k = _sub_block(block_k) if divide else block_k
@@ -194,6 +226,14 @@ def causal_schedule(s: int, block_q: int, block_k: int, causal: bool = True,
         for col0 in range(0, s, block_k):
             plan = []
             for row0 in range(cell_row0, cell_row0 + block_q, sub_q):
+                if window is not None:
+                    entry = _k_band_range(
+                        row0, col0, sub_q, sub_k, n_sub_k, causal, seq_len,
+                        window, least=min, most=max)
+                    run += sum(entry[1:])
+                    masked += entry[1] + entry[3]
+                    plan.append(entry)
+                    continue
                 n_full, n_run = _k_sub_range(
                     row0, col0, sub_q, sub_k, n_sub_k, causal, seq_len,
                     least=min, most=max)
@@ -203,7 +243,7 @@ def causal_schedule(s: int, block_q: int, block_k: int, causal: bool = True,
             plans.add(tuple(plan))
     return {"sub_q": sub_q, "sub_k": sub_k, "n_sub_k": n_sub_k, "run": run,
             "masked": masked, "total": (s // sub_q) * (s // sub_k),
-            "plans": tuple(sorted(plans))}
+            "plans": tuple(sorted(plans)), "window": window}
 
 
 def _count_score_blocks(schedule: dict, heads: int) -> None:
@@ -220,8 +260,10 @@ def _run_plan(schedule, cell_row0, cell_col0, causal, seq_len, body) -> None:
     what :func:`_k_sub_range` finds for each of its Q sub-blocks from the
     program ids with each plan of ``schedule``.
 
-    ``body(first, count, n_full, n_masked)`` is a GENERATOR for the run of
-    ``count`` Q sub-blocks from ``first`` that share an outcome; it yields
+    ``body(first, count, *outcome)`` — ``(n_full, n_masked)``, under a
+    window ``(n_skip, n_edge, n_full, n_masked)`` — is a GENERATOR for the
+    run of ``count`` Q sub-blocks from ``first`` that share an outcome; it
+    yields
     between its phases (scores; softmax; products), and the runs of a cell
     are advanced in lockstep, so the region reads: every run's score
     products, then every run's vector work, then every run's second
@@ -248,6 +290,17 @@ def _run_plan(schedule, cell_row0, cell_col0, causal, seq_len, body) -> None:
     # work stays inside a region, which interpret mode under shard_map's
     # vma check needs — it types constants only there)
     sub_q, sub_k, plans = (schedule[key] for key in ("sub_q", "sub_k", "plans"))
+    window = schedule["window"]
+    if window is not None:
+        found = [_k_band_range(cell_row0 + i * sub_q, cell_col0, sub_q,
+                               sub_k, schedule["n_sub_k"], causal, seq_len,
+                               window) for i in range(len(plans[0]))]
+        for plan in plans:
+            same = [a == b for got, want in zip(found, plan)
+                    for a, b in zip(got, want)]
+            pl.when(functools.reduce(jnp.logical_and, same))(
+                functools.partial(run, plan))
+        return
     found = [_k_sub_range(cell_row0 + i * sub_q, cell_col0, sub_q, sub_k,
                           schedule["n_sub_k"], causal, seq_len)
              for i in range(len(plans[0]))]
@@ -258,12 +311,16 @@ def _run_plan(schedule, cell_row0, cell_col0, causal, seq_len, body) -> None:
             functools.partial(run, plan))
 
 
-def _score_mask(row0, col0, rows, cols, causal, seq_len, mask_q_tail):
+def _score_mask(row0, col0, rows, cols, causal, seq_len, mask_q_tail,
+                window=None):
     """The keep-mask of the ``rows × cols`` scores at ``(row0, col0)``: the
-    causal triangle and/or the real sequence."""
+    causal triangle (under ``window`` the band ``0 <= q - k < window``)
+    and/or the real sequence."""
     q_pos = row0 + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
     k_pos = col0 + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
     mask = (q_pos >= k_pos) if causal else None
+    if window is not None:
+        mask = jnp.logical_and(mask, q_pos - k_pos < window)
     if seq_len is not None:
         tail = k_pos < seq_len
         if mask_q_tail:
@@ -302,18 +359,24 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *state, scale, causal,
             m_ref[...] = jnp.full_like(m_ref, NEG_INF)
             l_ref[...] = jnp.zeros_like(l_ref)
 
-    def body(first, count, n_full, n_masked):
+    def body(first, count, *outcome):
         """Softmax update of the ``count`` Q sub-blocks from ``first`` with
         the cell's first ``n_full`` K sub-blocks unmasked and the next
         ``n_masked`` under the mask (causal triangle; a padded S's tail:
         those K positions must contribute nothing); the K sub-blocks
         beyond, wholly above the diagonal or wholly padding, are never
-        computed."""
+        computed.  Under a window the columns start behind the ``n_skip``
+        sub-blocks left of the band, and the ``n_edge`` that its left edge
+        crosses are masked too."""
+        n_skip, n_edge, n_full, n_masked = \
+            outcome if len(outcome) == 4 else (0, 0) + outcome
         rows = slice(first * sub_q, (first + count) * sub_q)
         height = count * sub_q
         row0 = iq * block_q + first * sub_q
-        lo, hi = n_full * sub_k, (n_full + n_masked) * sub_k
-        if not hi:          # nothing of this cell's: a carried state stands
+        start, edge = n_skip * sub_k, (n_skip + n_edge) * sub_k
+        lo = edge + n_full * sub_k
+        hi = lo + n_masked * sub_k
+        if hi == start:     # nothing of this cell's: a carried state stands
             if not carried:  # (rows of padding only: no cell writes them)
                 finish(rows, jnp.full((height, 1), NEG_INF, jnp.float32),
                        jnp.zeros((height, 1), jnp.float32),
@@ -321,18 +384,27 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *state, scale, causal,
             return
 
         def on_masked(x, fill):
-            """``fill`` where the mask hides a score of the columns from
-            ``lo`` on — the only ones it can hide."""
-            if not n_masked:
-                return x
-            mask = _score_mask(row0, ik * block_k + lo, height, hi - lo,
-                               causal, seq_len, False)
-            tail = jnp.where(mask, x[:, lo:], fill)
-            return jnp.concatenate([x[:, :lo], tail], 1) if lo else tail
+            """``fill`` where the mask hides a score: of ``x``'s columns
+            ``[start, hi)`` those before ``edge`` and those from ``lo`` on
+            — the only ones it can hide."""
+            def columns(a, b, masked):
+                part = x if (a, b) == (start, hi) else \
+                    x[:, a - start:b - start]
+                if not masked:
+                    return part
+                return jnp.where(_score_mask(
+                    row0, ik * block_k + a, height, b - a, causal, seq_len,
+                    False, schedule["window"]), part, fill)
+
+            parts = [columns(a, b, masked) for a, b, masked in (
+                (start, edge, True), (edge, lo, False), (lo, hi, True))
+                if b > a]
+            return parts[0] if len(parts) == 1 else jnp.concatenate(parts, 1)
 
         s = on_masked(jax.lax.dot_general(
-            q_ref[0, rows, :], k_ref[0, :hi, :], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale,     # (height, hi)
+            q_ref[0, rows, :], k_ref[0, start:hi, :],
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale,  # (height, hi - start)
             NEG_INF)
         yield
         m = s.max(-1, keepdims=True)                   # (height, 1)
@@ -344,7 +416,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *state, scale, causal,
         p = on_masked(jnp.exp(s - m), 0.0)
         l = p.sum(-1, keepdims=True)
         yield
-        v = v_ref[0, :hi, :]
+        v = v_ref[0, start:hi, :]
         acc = jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -386,7 +458,7 @@ def _inherit_vma(*xs) -> frozenset:
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret, seq_len,
-               group: int = 1):
+               group: int = 1, window: Optional[int] = None):
     """``q (B·H, S, D)``, ``k/v (B·H/group, S, D)``: ``group`` consecutive
     q heads share one KV head (GQA/MQA).  The sharing happens in the
     BlockSpec index_map — KV is never materialized at H heads."""
@@ -396,7 +468,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret, seq_len,
     assert bq and bk, (s, block_q, block_k)  # wrapper pads unalignable S
     nq, nk = s // bq, s // bk
     vma = _inherit_vma(q, k, v)
-    schedule = causal_schedule(s, bq, bk, causal, seq_len)
+    schedule = causal_schedule(s, bq, bk, causal, seq_len, window)
     _count_score_blocks(schedule, bh)
 
     kernel = functools.partial(
@@ -410,6 +482,8 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret, seq_len,
         # elides the (otherwise dead) K/V DMA for the whole skipped tail.
         if causal:
             j = jnp.minimum(j, (i * bq + bq - 1) // bk)
+        if window is not None:      # and those wholly left of the band
+            j = jnp.maximum(j, jnp.maximum(i * bq - window + 1, 0) // bk)
         return (b // group, j, 0)
 
     out, lse = pl.pallas_call(
@@ -436,7 +510,9 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret, seq_len,
         ] if nk > 1 else [],
         compiler_params=_tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        name="flash_fwd",
+        # the profiler's ops line calls a kernel by this name: the banded
+        # forward's is its own, and still holds ``flash_fwd``
+        name="flash_fwd" if window is None else "window_flash_fwd",
         interpret=interpret,
     )(q, k, v)
     return out, lse[..., 0]
@@ -757,28 +833,34 @@ def _bwd_dispatch(q, k, v, out, lse, do, causal, scale, block_q, block_k,
                     seq_len, group, dlse=dlse)
 
 
-_STATIC = tuple(range(3, 12))    # every argument after q, k, v
+_STATIC = tuple(range(3, 13))    # every argument after q, k, v
+_STATIC_LSE = _STATIC[:-1]       # the LSE face takes no window
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=_STATIC)
 def _flash_bhsd(q, k, v, causal, block_q, block_k, interpret, seq_len, group,
-                backward, bwd_block_q=None, bwd_block_k=None):
+                backward, bwd_block_q=None, bwd_block_k=None, window=None):
     scale = 1.0 / (q.shape[-1] ** 0.5)
     out, _ = _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-                        seq_len, group)
+                        seq_len, group, window)
     return out
 
 
 def _flash_bhsd_fwd(q, k, v, causal, block_q, block_k, interpret, seq_len,
-                    group, backward, bwd_block_q=None, bwd_block_k=None):
+                    group, backward, bwd_block_q=None, bwd_block_k=None,
+                    window=None):
     scale = 1.0 / (q.shape[-1] ** 0.5)
     out, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-                          seq_len, group)
+                          seq_len, group, window)
     return out, (q, k, v, out, lse)
 
 
 def _flash_bhsd_bwd(causal, block_q, block_k, interpret, seq_len, group,
-                    backward, bwd_block_q, bwd_block_k, res, do):
+                    backward, bwd_block_q, bwd_block_k, window, res, do):
+    if window is not None:
+        raise NotImplementedError(
+            "flash_attention(window=...) is forward only: neither backward "
+            "(the fused Pallas kernel, the XLA scan) takes the band")
     q, k, v, out, lse = res
     scale = 1.0 / (q.shape[-1] ** 0.5)
     return _bwd_dispatch(q, k, v, out, lse, do, causal, scale, block_q,
@@ -789,7 +871,7 @@ def _flash_bhsd_bwd(causal, block_q, block_k, interpret, seq_len, group,
 _flash_bhsd.defvjp(_flash_bhsd_fwd, _flash_bhsd_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=_STATIC)
+@functools.partial(jax.custom_vjp, nondiff_argnums=_STATIC_LSE)
 def _flash_bhsd_lse(q, k, v, causal, block_q, block_k, interpret, seq_len,
                     group, backward, bwd_block_q=None, bwd_block_k=None):
     """Like :func:`_flash_bhsd` but also returns the LSE as a DIFFERENTIABLE
@@ -827,7 +909,7 @@ _flash_bhsd_lse.defvjp(_flash_bhsd_lse_fwd, _flash_bhsd_lse_bwd)
 # and lowered once per shape and not once per call — a kernel body holds
 # every plan of its schedule, and 24 layers of them were seconds of set-up.
 _flash_bhsd_jit = jax.jit(_flash_bhsd, static_argnums=_STATIC)
-_flash_bhsd_lse_jit = jax.jit(_flash_bhsd_lse, static_argnums=_STATIC)
+_flash_bhsd_lse_jit = jax.jit(_flash_bhsd_lse, static_argnums=_STATIC_LSE)
 
 
 def flash_attention(q, k, v, causal: bool = False,
@@ -836,7 +918,8 @@ def flash_attention(q, k, v, causal: bool = False,
                     interpret: Optional[bool] = None,
                     return_lse: bool = False, backward: str = "auto",
                     bwd_block_q: Optional[int] = None,
-                    bwd_block_k: Optional[int] = None):
+                    bwd_block_k: Optional[int] = None,
+                    window: Optional[int] = None):
     """Flash attention over ``(B, S, H, D)`` arrays.
 
     ``interpret=None`` auto-selects: the compiled Pallas kernel on TPU,
@@ -878,6 +961,13 @@ def flash_attention(q, k, v, causal: bool = False,
     BACKWARD independently of the forward: its five-product body wants a
     wider K block than the forward's two.
 
+    ``window=W`` (with ``causal=True``; FORWARD ONLY, differentiating
+    raises): query ``q`` sees keys ``0 <= q - k < W`` — itself and the ``W -
+    1`` before it.  The band is one more edge of the sub-block schedule
+    (module docstring): at S = 3072, W = 512 a row of Q sub-blocks computes
+    5 K sub-blocks, 2 of them masked, where the causal schedule computes up
+    to 24.  The kernel is then named ``window_flash_fwd``.
+
     ``return_lse=True`` additionally returns the per-query log-sum-exp
     ``(B, H, S)`` as a differentiable output (the block-merge currency of
     ring attention).
@@ -918,6 +1008,9 @@ def flash_attention(q, k, v, causal: bool = False,
         nh = x.shape[2]
         return x.transpose(0, 2, 1, 3).reshape(b * nh, s_pad, x.shape[-1])
 
+    if window is not None and (return_lse or not causal or window < 1):
+        raise ValueError("window needs causal=True, window >= 1 and "
+                         "return_lse=False")
     if return_lse:
         out, lse = _flash_bhsd_lse_jit(
             to_bhsd(q), to_bhsd(k), to_bhsd(v), causal, block_q, block_k,
@@ -926,5 +1019,5 @@ def flash_attention(q, k, v, causal: bool = False,
                 lse.reshape(b, h, s_pad)[:, :, :s])
     out = _flash_bhsd_jit(to_bhsd(q), to_bhsd(k), to_bhsd(v),
                           causal, block_q, block_k, interpret, s, group,
-                          backward, bwd_block_q, bwd_block_k)
+                          backward, bwd_block_q, bwd_block_k, window)
     return out.reshape(b, h, s_pad, d)[:, :, :s].transpose(0, 2, 1, 3)

@@ -19,8 +19,13 @@ Parameter layout per ``arch`` value (all GLOBAL arrays):
 * layer kind ``'moe'``: ``moe = {router (D, E), router_bias (E,), shared =
   {w_gate, w_up, w_down}, w_gate/w_up (E_held, D, F), w_down (E_held, F,
   D)}`` — ``parallel/moe.py::moe_dropless``.
-* ``attn='mha'``: ``attn = {wqkv, bqkv, wo, bo}`` or the GQA ``wq/wkv``
-  form; ``'mla'``: ``attn = {wdq (D, q_rank), q_norm, wuq (q_rank,
+* ``attn='mha'``: ``attn = {wqkv, bqkv, wo, bo}`` or the GQA form ``{wq,
+  bq, wkv, bkv, wo, bo}`` (``wkv`` columns per KV head ``[k_h | v_h]``; the
+  query-head count is the weights', so it may differ from layer to layer);
+  ``attn_bias=False``: a model whose attention has no biases carries none
+  (no ``b*`` entry, not zero vectors); ``attn_gate=True`` adds ``wg (D,
+  H)``, the per-head sigmoid gate on the context; ``windows`` / ``rotary``
+  give a layer its band and its rotation (below); ``'mla'``: ``attn = {wdq (D, q_rank), q_norm, wuq (q_rank,
   H·(nope+rope)), wdkv (D, kv_rank+rope), kv_norm, wukv (kv_rank,
   H·(nope+v)), wo (H·v, D)}``, head-major columns — with ``q_lora_rank =
   None`` the queries are projected directly, ``wq (D, H·(nope+rope))`` in
@@ -36,7 +41,11 @@ What a layer's attention keeps is DECLARED here (:func:`cache_layout`) and
 the serving pool allocates exactly that.  ROWS, one a token: an MHA/GQA
 layer a ``(k, v)`` pair of ``n_kv·head_dim`` columns sharded over the model
 axis, an MLA layer ONE latent buffer ``[c_kv | RoPE(k_rope) | zero pad]``,
-replicated.  STATE, one a sequence whatever its length: a KDA layer its
+replicated.  RING, the last ``W`` rows of a sequence: an MHA/GQA layer with a
+window (``windows[layer] = W``: a token sees itself and the ``W - 1`` before
+it) keeps its ``(k, v)`` pair for those alone, position ``p`` at ring row ``p
+% W``, each key rotated at its absolute position before it is cached (so the
+order of the rows means nothing to the softmax).  STATE, one a sequence whatever its length: a KDA layer its
 ``(H, d, d)`` float32 recurrent state and the last ``W - 1`` rows of its
 fused projection.
 """
@@ -126,13 +135,26 @@ class KDAConfig:
 
 
 @dataclass(frozen=True)
+class Rotary:
+    """Rotary positions of one kind of MHA/GQA layer, half-split pairs:
+    the first ``fraction`` of each head's columns rotated (the rest carried
+    as projected), ``theta``, YaRN ``(factor, original_max_position,
+    beta_fast, beta_slow)`` or None for plain, and the factor on cos and
+    sin (YaRN's attention factor)."""
+    theta: float = 10000.0
+    fraction: float = 1.0
+    yarn: Optional[Tuple[float, int, float, float]] = None
+    attention_factor: float = 1.0
+
+
+@dataclass(frozen=True)
 class LMArch:
     """One LM's block vocabulary.  The defaults ARE the GPT-2-style block
     this package has always run."""
     norm: str = "layernorm"            # | 'rmsnorm'
     norm_eps: float = 1e-5
     mlp: str = "gelu"                  # | 'swiglu'
-    attn: str = "mha"                  # | 'mla' | 'kda' (mha covers GQA)
+    attn: str = "mha"                  # | 'mla' | 'kda' ('mha': MHA and GQA)
     layer_kinds: Optional[Tuple[str, ...]] = None   # 'dense' | 'moe' each
     tied_head: bool = True
     embed_scale: bool = True           # embedding times sqrt(d_model)
@@ -140,6 +162,13 @@ class LMArch:
     moe: Optional[MoEConfig] = None
     attn_kinds: Optional[Tuple[str, ...]] = None    # a kind of ``attn`` each
     kda: Optional[KDAConfig] = None
+    # MHA/GQA layers, a value a LAYER (None: no layer has one): the window
+    # (None: the whole prefix) and the rotation (None: ``apply_rope`` where
+    # the model has no position table, as ever)
+    windows: Optional[Tuple[Optional[int], ...]] = None
+    rotary: Optional[Tuple[Optional[Rotary], ...]] = None
+    attn_gate: bool = False            # per-head sigmoid gate on the context
+    attn_bias: bool = True             # False: no b* entries at all
 
     def kind(self, layer: int) -> str:
         return "dense" if self.layer_kinds is None else \
@@ -149,10 +178,21 @@ class LMArch:
         return self.attn if self.attn_kinds is None else \
             self.attn_kinds[layer]
 
+    def window(self, layer: int) -> Optional[int]:
+        """Layer ``layer`` sees the last ``window`` tokens (None: all)."""
+        if self.windows is None or self.attn_kind(layer) != "mha":
+            return None
+        return self.windows[layer]
+
     @property
     def has_state(self) -> bool:
         """Some layer keeps a per-sequence state (and no row a token)."""
         return "kda" in (self.attn_kinds or (self.attn,))
+
+    @property
+    def has_ring(self) -> bool:
+        """Some layer keeps a ring of its window's rows."""
+        return any(self.window(i) for i in range(len(self.windows or ())))
 
 
 #: the description of every model that passes none
@@ -290,6 +330,32 @@ def apply_rope_freqs(x, positions, inv_freq, scale: float = 1.0):
                            -1).astype(x.dtype)
 
 
+def rotate(cfg: Rotary, x, positions):
+    """``x (B, S, H, d)`` with the first ``fraction`` of each head's
+    columns rotated at ``positions`` and the rest carried as they are."""
+    d = x.shape[-1]
+    rot = int(d * cfg.fraction)
+    inv_freq, _ = rope_inv_freq(
+        rot, cfg.theta, None if cfg.yarn is None else cfg.yarn + (0.0, 0.0))
+    turned = apply_rope_freqs(x[..., :rot], positions, inv_freq,
+                              cfg.attention_factor)
+    return turned if rot == d else jnp.concatenate([turned, x[..., rot:]], -1)
+
+
+def ring_rows(rows, s_real, window: int):
+    """The ring a prompt leaves: ``rows (B, S, C)``, position ``p`` at row
+    ``p``, of which the first ``s_real (B,)`` are real -> ``(B, window,
+    C)`` with ring row ``r`` holding the LAST real position ``p`` with ``p %
+    window == r`` (rows ``[max(0, s_real - window), s_real)``; never a
+    padded row), zeros where no position has come yet."""
+    r = jnp.arange(window)[None, :]
+    last = s_real.astype(jnp.int32)[:, None] - 1
+    p = r + window * ((last - r) // window)              # (B, window)
+    ring = jnp.take_along_axis(
+        rows, jnp.clip(p, 0, rows.shape[1] - 1)[..., None], axis=1)
+    return jnp.where((r <= last)[..., None], ring, 0)
+
+
 # --------------------------------------------------------------------------
 # multi-head latent attention
 # --------------------------------------------------------------------------
@@ -422,25 +488,43 @@ def mla_attend_absorbed(cfg: MLAConfig, q_nope, q_rope, cache, valid, a,
 def cache_layout(arch: LMArch, n_layers: int, kv_dim: int,
                  axis_name: str):
     """Per layer, the buffers its attention keeps, a tuple of
-    declarations of two forms.  ROWS ``(columns, PartitionSpec)``: one row
+    declarations of three forms.  ROWS ``(columns, PartitionSpec)``: one row
     a token — the serving pool allocates ``(n_slots, max_total, columns)``
     in its own dtype.  STATE ``(shape, dtype, PartitionSpec)``: one a
     sequence, overwritten in place — the pool allocates ``(n_slots,) +
-    shape``; ``dtype`` None is the pool's."""
-    def one(kind):
+    shape``; ``dtype`` None is the pool's.  RING ``(columns, PartitionSpec,
+    window)``: the last ``window`` rows of a sequence, position ``p`` at
+    row ``p % window`` — the pool allocates ``(n_slots, window,
+    columns)``."""
+    def one(layer):
+        kind = arch.attn_kind(layer)
         if kind == "mla":
             return ((arch.mla.latent_width, P()),)
         if kind == "kda":
             state, window = arch.kda.state_shapes
             return ((state, jnp.float32, P()), (window, None, P()))
-        spec = P(None, None, axis_name)
-        return ((kv_dim, spec), (kv_dim, spec))
-    return [one(arch.attn_kind(i)) for i in range(n_layers)]
+        buf = (kv_dim, P(None, None, axis_name))
+        if arch.window(layer):
+            buf += (arch.window(layer),)
+        return (buf, buf)
+    return [one(i) for i in range(n_layers)]
 
 
 def is_state(buf) -> bool:
     """A :func:`cache_layout` declaration of the STATE form."""
-    return len(buf) == 3
+    return isinstance(buf[0], tuple)
+
+
+def is_ring(buf) -> bool:
+    """A :func:`cache_layout` declaration of the RING form."""
+    return len(buf) == 3 and not is_state(buf)
+
+
+def buffer_shape(buf, n_slots: int, max_total: int):
+    """The pool buffer a declaration asks for (every form)."""
+    if is_state(buf):
+        return (n_slots,) + tuple(buf[0])
+    return (n_slots, buf[2] if is_ring(buf) else max_total, buf[0])
 
 
 def lm_specs(arch: LMArch, params, axis_name: str):
@@ -452,7 +536,7 @@ def lm_specs(arch: LMArch, params, axis_name: str):
     which is what the embedding lookup and the token pick assume."""
     if arch.attn == "mha" and arch.attn_kinds is None and arch.moe is None \
             and arch.mlp == "gelu" and arch.norm == "layernorm" \
-            and arch.tied_head:
+            and arch.tied_head and not arch.attn_gate and arch.attn_bias:
         from .transformer import transformer_lm_specs
         return transformer_lm_specs(params, axis_name)
     specs = jax.tree_util.tree_map(lambda _: P(), params)

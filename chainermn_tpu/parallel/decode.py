@@ -50,7 +50,8 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
     embedding scale — read here once and shared with the training loss
     (``parallel/transformer.py``).  A layer's cache is a TUPLE of buffers,
     whatever its attention declares (``blocks.cache_layout``): ``(k, v)``
-    for MHA/GQA, one latent buffer for MLA, ``(state, window)`` for a
+    for MHA/GQA — rows a token, or under a window a RING of the window's
+    rows — one latent buffer for MLA, ``(state, window)`` for a
     gated delta-rule layer — the kind is the LAYER's
     (``arch.attn_kind(layer)``).  ``attn_block.moe_routing``
     collects the expert layers' ``(counts, idx)`` in trace order; ``live
@@ -96,14 +97,29 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
         score/context stage differs."""
         n, s_q = x.shape[0], x.shape[1]
         h = _blocks.norm(arch, x, blk, "ln1")
-        q, k, v = _project_qkv(h, blk["attn"], head_dim, axis_name)
-        if rope:
+        a = blk["attn"]
+        q, k, v = _project_qkv(h, a, head_dim, axis_name, arch.attn_bias)
+        turn = arch.rotary[layer] if arch.rotary is not None else None
+        if turn is not None:     # the layer's own rotation (theta, the
+            #                      rotated fraction, YaRN)
+            q = _blocks.rotate(turn, q, positions)
+            k = _blocks.rotate(turn, k, positions)
+        elif rope:
             q = apply_rope(q, positions)
             k = apply_rope(k, positions)
         ctx, extras = attend(q, k, v)
+        if arch.attn_gate:
+            # per-head sigmoid gate from the attention's own input, on the
+            # context, before the output projection
+            with jax.named_scope("block/attn/gate"):
+                gate = jax.nn.sigmoid(jnp.matmul(
+                    h, a["wg"], preferred_element_type=jnp.float32))
+                ctx = (ctx.reshape(n, s_q, -1, head_dim).astype(jnp.float32)
+                       * gate[..., None]).astype(x.dtype)
         ctx = ctx.reshape(n, s_q, -1)
-        attn_out = row_parallel_dense(ctx, blk["attn"]["wo"],
-                                      blk["attn"]["bo"], axis_name=axis_name)
+        attn_out = row_parallel_dense(
+            ctx, a["wo"], a["bo"] if arch.attn_bias else None,
+            axis_name=axis_name)
         return (second_half(x + attn_out, blk, layer),) + extras
 
     def mla_block(x, blk, cache, positions, write_at, q_valid, layer):
@@ -194,30 +210,65 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
             return kda_block(x, blk, k_cache, v_cache, layer)
         n = x.shape[0]
         per_row = getattr(write_at, "ndim", 0) == 1
+        window = arch.window(layer)
 
         def attend(q, k, v):
+            if not window:
+                return attend_rows(q, k, v)
+            with jax.named_scope("block/attn/window"):
+                return attend_rows(q, k, v)
+
+        def attend_rows(q, k, v):
             from ..ops.kv_cache import cache_append
             s_q = q.shape[1]
             hl, hkv = q.shape[2], k.shape[2]
+            flat = lambda t: t.reshape(n, s_q, hkv * head_dim)
+            prefill = s_q > 1 and isinstance(write_at, int) \
+                and write_at == 0 and isinstance(q_valid, int) \
+                and q_valid == 0
+            if window and not prefill:
+                # A layer that sees the last ``window`` tokens keeps a
+                # RING, position p at row ``p % window``.  The tick writes
+                # there and attends the ring as it would a rows buffer —
+                # at most ``window`` rows, ``pos + 1`` before the first
+                # wrap (a position beyond the buffer masks nothing), in
+                # whatever order: each key was rotated at its own position
+                # before it was cached.
+                if s_q != 1:
+                    raise NotImplementedError(
+                        f"layer {layer} keeps a ring of {window} rows: it "
+                        f"takes a whole prompt or one token a row, not a "
+                        f"chunk of {s_q} behind a cache")
+                at = write_at % window
+            else:
+                at = write_at
             # one-row decode appends go through the Pallas in-place
             # scatter (ops/kv_cache.py): the XLA dus costs a full extra
             # pass over the cache per tick; prefill's slab write (s_q >
             # 1) falls back to dus inside cache_append
             with jax.named_scope("cache_write"):
-                kc, vc = cache_append(
-                    k_cache, v_cache, k.reshape(n, s_q, hkv * head_dim),
-                    v.reshape(n, s_q, hkv * head_dim), write_at, axis=1)
-            if s_q > 1 and isinstance(write_at, int) and write_at == 0 \
-                    and isinstance(q_valid, int) and q_valid == 0:
+                if window and prefill:
+                    # the ring of the prompt's REAL rows (``live``): a
+                    # padded row would land on a real one's place
+                    s_real = (jnp.full((n,), s_q, jnp.int32) if live is None
+                              else live.sum(-1).astype(jnp.int32))
+                    kc, vc = (_blocks.ring_rows(flat(t), s_real, window
+                                                ).astype(c.dtype)
+                              for t, c in ((k, k_cache), (v, v_cache)))
+                else:
+                    kc, vc = cache_append(k_cache, v_cache, flat(k),
+                                          flat(v), at, axis=1)
+            if prefill:
                 # PREFILL: pure causal self-attention over the prompt —
                 # the flash kernels, not the naive einsum, which would
                 # materialize an (n, h, s_q, total) fp32 score tensor
                 # (268 MB/layer at the bench config; the HLO cost model
                 # ranked its softmax reductions above every decode op,
                 # and its cost GREW with the cache length, polluting the
-                # measured per-token decode rate).
+                # measured per-token decode rate).  Under a window, the
+                # band ``0 <= q - k < window`` of it.
                 from ..ops.flash_attention import flash_attention
-                ctx = flash_attention(q, k, v, causal=True)
+                ctx = flash_attention(q, k, v, causal=True, window=window)
                 return ctx.astype(x.dtype), (kc, vc)
             from ..ops.decode_attention import (_pick_block_s,
                                                  decode_attend,
@@ -228,10 +279,10 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
                 # read once at full lane density (ops/decode_attention),
                 # each row up to its own ``write_at`` (scalar: the
                 # closed batch; vector: the serving tick's slots).
-                # GQA groups ride the beam kernel (g query groups share
-                # one cache row, exactly the beam row mapping).  Odd
-                # totals with no 8-aligned S-block (e.g. a max_new=1
-                # probe's 513) stay on the einsum fallback below.
+                # GQA has the same face (``decode_attend_gqa``: its own
+                # kernel at heads of whole lane tiles, else the beam
+                # kernel).  Odd totals with no 8-aligned S-block (e.g. a
+                # max_new=1 probe's 513) stay on the einsum fallback below.
                 if hl == hkv:
                     ctx = decode_attend(
                         q.reshape(n, hl * head_dim), kc, vc, write_at,
@@ -327,7 +378,7 @@ def _kv_heads(params, head_dim: int) -> int:
         # MLA: one shared latent row; delta rule: a state.  No per-head K/V
         return 0
     return (a["wkv"].shape[1] // (2 * head_dim) if "wkv" in a
-            else a["bqkv"].shape[0] // (3 * head_dim))
+            else a["wqkv"].shape[1] // (3 * head_dim))
 
 
 def _prefill(params, embed, attn_block, prompt, total: int, head_dim: int):
@@ -335,8 +386,9 @@ def _prefill(params, embed, attn_block, prompt, total: int, head_dim: int):
     caches)`` with per-layer caches of length ``total`` (prompt written,
     tail zeros): per layer the tuple of flat ``(B, total, columns)``
     buffers its attention declares (``blocks.cache_layout``; ``(k, v)`` of
-    ``H_kv·head_dim`` columns for MHA/GQA — see ``attn_block``), or the
-    ``(B,) + shape`` state it declares, after the prompt's live rows."""
+    ``H_kv·head_dim`` columns for MHA/GQA — see ``attn_block``), the ``(B,
+    window, columns)`` ring of a windowed layer, or the ``(B,) + shape``
+    state it declares, after the prompt's live rows."""
     arch = attn_block.arch
     b, s_p = prompt.shape
     layout = _blocks.cache_layout(arch, len(params["blocks"]),
@@ -345,10 +397,18 @@ def _prefill(params, embed, attn_block, prompt, total: int, head_dim: int):
     x = embed(prompt, positions)
     caches = []
     for i, (blk, bufs) in enumerate(zip(params["blocks"], layout)):
-        zeros = [jnp.zeros((b,) + tuple(buf[0]), buf[1] or x.dtype)
-                 if _blocks.is_state(buf)
-                 else jnp.zeros((b, total, buf[0]), x.dtype) for buf in bufs]
+        zeros = [jnp.zeros(_blocks.buffer_shape(buf, b, total),
+                           (buf[1] if _blocks.is_state(buf) else None)
+                           or x.dtype) for buf in bufs]
         x, new = _run_layer(attn_block, x, blk, zeros, positions, 0, 0, i)
+        if arch.window(i):
+            # a ring is a gather of the layer's k and v that nothing wants
+            # before the pool is written at the program's end: left alone,
+            # the compiler defers every such gather and keeps each sliding
+            # layer's (S, columns) k and v alive until then (0.9 GB more
+            # temporaries at 40 layers and S = 3072: my ahead-of-time
+            # compile, PR 33)
+            x, new = jax.lax.optimization_barrier((x, new))
         caches.append(new)
     return _blocks.norm(arch, x, params, "lnf"), caches
 

@@ -73,9 +73,11 @@ def apply_rope(x, positions, *, base: float = 10000.0):
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1).astype(x.dtype)
 
 
-def _project_qkv(h, a, head_dim: int, axis_name: str):
+def _project_qkv(h, a, head_dim: int, axis_name: str, bias: bool = True):
     """Shared QKV projection for both attention param layouts: returns
-    local ``q (B, S, Hl, hd)`` and ``k, v (B, S, Hkv_l, hd)``.
+    local ``q (B, S, Hl, hd)`` and ``k, v (B, S, Hkv_l, hd)``.  ``bias=
+    False`` (``LMArch.attn_bias``): the model's attention has no biases
+    and ``a`` carries none.
 
     Works for TP-sharded weights (column shards produce local heads) and
     replicated weights (SP blocks — full heads) alike, since
@@ -84,10 +86,13 @@ def _project_qkv(h, a, head_dim: int, axis_name: str):
     ``sp_block`` and the KV-cache decoder.
     """
     b, s, _ = h.shape
+    bias_of = (lambda name: a[name]) if bias else (lambda name: None)
     if "wq" in a:
-        q = column_parallel_dense(h, a["wq"], a["bq"], axis_name=axis_name)
+        q = column_parallel_dense(h, a["wq"], bias_of("bq"),
+                                  axis_name=axis_name)
         q = q.reshape(b, s, -1, head_dim)
-        kv = column_parallel_dense(h, a["wkv"], a["bkv"], axis_name=axis_name)
+        kv = column_parallel_dense(h, a["wkv"], bias_of("bkv"),
+                                   axis_name=axis_name)
         if kv.shape[-1] % (2 * head_dim):
             raise ValueError(
                 f"local wkv shard width {kv.shape[-1]} is not a whole "
@@ -95,28 +100,30 @@ def _project_qkv(h, a, head_dim: int, axis_name: str):
                 f"n_kv_heads must be divisible by the model-axis size")
         kv = kv.reshape(b, s, -1, 2, head_dim)
         return q, kv[..., 0, :], kv[..., 1, :]
-    qkv = column_parallel_dense(h, a["wqkv"], a["bqkv"], axis_name=axis_name)
+    qkv = column_parallel_dense(h, a["wqkv"], bias_of("bqkv"),
+                                axis_name=axis_name)
     qkv = qkv.reshape(b, s, -1, 3, head_dim)
     return qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
 
 
 def tp_attention(x, params, *, head_dim: int, axis_name: str,
                  causal: bool = True, attn_impl: str = "auto",
-                 positions=None):
+                 positions=None, bias: bool = True):
     """Multi-head self-attention with heads sharded over ``axis_name``.
 
     ``x``: replicated-local ``(B, S, D)``; ``params``: local shards
     ``wqkv (D, 3·D/P)`` laid out HEAD-MAJOR (columns grouped per head as
     ``[q_h | k_h | v_h]`` so a contiguous column shard is whole heads —
     see :func:`init_tp_transformer_lm`), ``bqkv (3·D/P,)``,
-    ``wo (D/P, D)``, replicated ``bo (D,)``.  One psum (in the
-    row-parallel output projection) per call.
+    ``wo (D/P, D)``, replicated ``bo (D,)`` (``bias=False``: none of the
+    three biases).  One psum (in the row-parallel output projection) per
+    call.
     """
     from ..ops.flash_attention import resolve_attn_impl
 
     b, s, d = x.shape
     attn_impl = resolve_attn_impl(attn_impl, s)
-    q, k, v = _project_qkv(x, params, head_dim, axis_name)
+    q, k, v = _project_qkv(x, params, head_dim, axis_name, bias)
     h_local = q.shape[2]
 
     if positions is not None:  # RoPE (positions are global token indices)
@@ -126,7 +133,8 @@ def tp_attention(x, params, *, head_dim: int, axis_name: str,
     ctx = _attend_local_heads(q, k, v, causal=causal, attn_impl=attn_impl,
                               head_dim=head_dim)
     ctx = ctx.reshape(b, s, h_local * head_dim)             # (B, S, D/P)
-    return row_parallel_dense(ctx, params["wo"], params["bo"],
+    return row_parallel_dense(ctx, params["wo"],
+                              params["bo"] if bias else None,
                               axis_name=axis_name)
 
 
@@ -169,6 +177,16 @@ def tp_block(x, params, *, head_dim: int, axis_name: str, causal: bool = True,
 
     arch = _blocks.resolve(arch)
     kind = arch.attn_kind(layer)
+    if kind == "mha" and (arch.window(layer) or arch.attn_gate
+                          or arch.rotary is not None):
+        # no silent full-attention substitute: the loss path has no band
+        # (the banded flash kernel is forward only), no output gate and no
+        # per-layer rotation yet
+        raise NotImplementedError(
+            f"tp_block: layer {layer} is described with window="
+            f"{arch.window(layer)}, attn_gate={arch.attn_gate}, rotary="
+            f"{arch.rotary is not None}; the training block runs none of "
+            f"the three (serving does: parallel/decode.py)")
     with jax.named_scope("block/kda" if kind == "kda" else "block/attn"):
         h = _blocks.norm(arch, x, params, "ln1")
         if kind == "kda":
@@ -198,7 +216,8 @@ def tp_block(x, params, *, head_dim: int, axis_name: str, causal: bool = True,
         else:
             x = x + tp_attention(h, params["attn"], head_dim=head_dim,
                                  axis_name=axis_name, causal=causal,
-                                 attn_impl=attn_impl, positions=positions)
+                                 attn_impl=attn_impl, positions=positions,
+                                 bias=arch.attn_bias)
     with jax.named_scope("block/mlp"):
         h = _blocks.norm(arch, x, params, "ln2")
         return x + _blocks.ffn(arch, layer, h, params, axis_name)[0]

@@ -20,7 +20,7 @@ copy of every buffer, and the arrays bound before the call are deleted by
 it.  ``CachePool.update`` / ``CachePool.read`` are the only way a program
 reaches them — read, launch and rebind under one lock.
 
-A layer declares one of two kinds of buffer (``parallel/blocks.py::
+A layer declares one of three kinds of buffer (``parallel/blocks.py::
 cache_layout``), and recycling a slot without zeroing it rests on a
 different invariant for each:
 
@@ -48,6 +48,28 @@ different invariant for each:
   stands at, which is why a prefix hit on such a layout is usable only at
   the donated length, and why spill and the transfer plane — which pack
   "rows ``[0, len)``" — refuse such a pool (``transfer.py``).
+* RING ``(n_slots, W, columns)``, the last ``W`` rows of a sequence (a
+  windowed attention layer: a query at position ``q`` sees keys ``q - W <
+  k <= q``), position ``p`` at ring row ``p % W``.  *The row a write lands
+  on is the one row the next query cannot see.*  The tick runs every slot,
+  and a free or cached slot's garbage write lands on ring row ``pos % W``
+  at its held ``pos``.  That row holds position ``pos - W``: exactly one
+  position OUTSIDE the window of the next real query, which is at ``pos``
+  and sees ``pos - W + 1 .. pos`` — and it is the row that query's own
+  token overwrites before attending.  So a cached slot's ring still serves
+  a request that continues at the donated length ``pos`` (and only there:
+  as with a state, a shorter prefix has lost rows, ``[m - W, pos - W)``
+  for a match of ``m``, so such a pool's prefix cache is ``whole_only``
+  too), and a recycled slot's occupant writes ring row ``p % W`` at every
+  position ``p`` strictly before a query that can see row ``p % W`` as
+  position ``p`` arrives: the prefill writes the rows of ``[max(0, s_p -
+  W), s_p)`` and zeros the rest, each tick writes ``pos % W`` before it
+  attends, and a query at ``pos < W`` is masked to rows ``[0, pos]``.  The
+  argument needs the ring to be EXACTLY ``W`` rows and the mask exactly
+  ``q - k < W``: were the ring rounded up, row ``pos % W'`` would hold a
+  position inside the window and the write would have to be masked by the
+  busy mask, as a state's is.  Spill and the transfer plane refuse a ring
+  as they refuse a state.
 
 :class:`SlotAllocator` is the jax-free bookkeeping half (fuzzable
 standalone); :class:`CachePool` adds the device buffers.
@@ -289,7 +311,12 @@ class SlotAllocator:
 def _is_state(buf) -> bool:
     """A layout declaration of the STATE form ``(shape, dtype, spec)``
     (``parallel/blocks.py::is_state``; this module imports no jax)."""
-    return len(buf) == 3
+    return isinstance(buf[0], tuple)
+
+
+def _is_ring(buf) -> bool:
+    """A layout declaration of the RING form ``(columns, spec, window)``."""
+    return len(buf) == 3 and not _is_state(buf)
 
 
 class CachePool:
@@ -301,7 +328,9 @@ class CachePool:
     (``layout``: per layer a tuple of ``(columns, PartitionSpec)``, from
     ``parallel/blocks.py::cache_layout``) — or of ``(n_slots,) + shape``
     STATE buffers, one a slot, where it declares ``(shape, dtype,
-    PartitionSpec)`` (``dtype`` None: the pool's).  Without a ``layout`` every
+    PartitionSpec)`` (``dtype`` None: the pool's), or of ``(n_slots,
+    window, columns)`` RING buffers where it declares ``(columns,
+    PartitionSpec, window)``.  Without a ``layout`` every
     layer is the MHA/GQA declaration: a ``(k, v)`` pair of ``kv_dim``
     columns sharded ``P(None, None, axis)`` over the model axis — each
     chip holds only its local heads' columns, exactly the closed-batch
@@ -347,16 +376,18 @@ class CachePool:
             raise ValueError(f"layout names {len(layout)} layers, the "
                              f"model has {self.n_layers}")
         self.dtype = jnp.dtype(dtype)
-        # rows ``(columns, spec)``; state ``(shape, dtype, spec)``
+        # rows ``(columns, spec)``; state ``(shape, dtype, spec)``; ring
+        # ``(columns, spec, window)``
         self.layout = [tuple(
             (tuple(int(n) for n in buf[0]), jnp.dtype(buf[1] or self.dtype),
-             buf[2]) if _is_state(buf) else (int(buf[0]), buf[1])
+             buf[2]) if _is_state(buf)
+            else (int(buf[0]), buf[1]) + tuple(int(w) for w in buf[2:])
             for buf in bufs) for bufs in layout]
         #: the caches pytree's PartitionSpecs (programs' in/out specs)
-        self.cache_specs = [tuple(buf[-1] for buf in bufs)
-                            for bufs in self.layout]
+        self.cache_specs = [tuple(buf[2] if _is_state(buf) else buf[1]
+                                  for buf in bufs) for bufs in self.layout]
         # the first buffer's spec: what a K/V pool's every buffer has
-        self.cache_spec = self.layout[0][0][-1]
+        self.cache_spec = self.cache_specs[0][0]
         self.caches = self.fresh_buffers()
         # held for a program's LAUNCH only (dispatch is asynchronous)
         self._buffers_lock = threading.Lock()
@@ -365,7 +396,19 @@ class CachePool:
         #: bytes one token keeps across all ROW layers (whole model axis)
         self.bytes_per_token = self.dtype.itemsize * sum(
             buf[0] for bufs in self.layout for buf in bufs
-            if not _is_state(buf))
+            if len(buf) == 2)
+        #: bytes one slot keeps across all RING layers, whatever its length
+        #: (0: no layer has a window); per layer, the window (0: none) —
+        #: ``ring_rows_live`` reads it
+        self.ring_bytes_per_slot = self.dtype.itemsize * sum(
+            buf[0] * buf[2] for bufs in self.layout for buf in bufs
+            if _is_ring(buf))
+        self.ring_windows = np.array(
+            [bufs[0][2] for bufs in self.layout if _is_ring(bufs[0])],
+            np.int64)
+        #: layers that keep rows a token (the others: a ring, a state)
+        self.n_row_layers = sum(
+            any(len(buf) == 2 for buf in bufs) for bufs in self.layout)
         #: bytes one slot keeps across all STATE layers, whatever its
         #: length (0: every layer keeps rows), and how many layers those are
         self.state_bytes_per_slot = sum(
@@ -397,11 +440,22 @@ class CachePool:
                 shape, dtype, spec = buf
                 z = jnp.zeros((self.n_slots,) + shape, dtype)
             else:
-                z = jnp.zeros((self.n_slots, self.max_total, buf[0]),
-                              self.dtype)
-            return jax.device_put(z, NamedSharding(self.mesh, buf[-1]))
+                rows = buf[2] if _is_ring(buf) else self.max_total
+                z = jnp.zeros((self.n_slots, rows, buf[0]), self.dtype)
+                spec = buf[1]
+            return jax.device_put(z, NamedSharding(self.mesh, spec))
 
         return [tuple(zeros(buf) for buf in bufs) for bufs in self.layout]
+
+    def ring_rows_live(self, slots=None) -> int:
+        """Σ over ring layers and ``slots`` (a mask; None: every slot) of
+        ``min(pos + 1, W)``: the ring rows a tick's attention has to read
+        for them (host arithmetic on ``pos``)."""
+        import numpy as np
+
+        pos = self.pos if slots is None else self.pos[slots]
+        return int(np.minimum(pos[None, :] + 1,
+                              self.ring_windows[:, None]).sum())
 
     def update(self, launch, *sources):
         """Run a program that RETURNS the buffers: ``launch(caches,
@@ -452,8 +506,9 @@ class CachePool:
         return self.allocator.acquire()
 
     def release(self, slot: int) -> None:
-        # ``pos`` alone: the slot's rows are unreachable above it, and its
-        # state is written whole by whatever admits the next occupant
+        # ``pos`` alone: the slot's rows are unreachable above it, its
+        # state is written whole by whatever admits the next occupant, and
+        # so are the rows of a ring that occupant can see
         self.pos[slot] = 0
         self.allocator.release(slot)
 

@@ -71,12 +71,14 @@ class DecodeEngine:
     an untied head: ``parallel/blocks.py``); ``mesh`` must carry
     ``axis_name`` (default: a fresh 1-D mesh over all local devices, like
     ``make_lm_generator``).  The programs thread the pool's caches as a
-    pytree, whatever each layer declares — rows a token or a state a slot
-    (``cache_pool.py``).  A model with experts or with state layers is told
+    pytree, whatever each layer declares — rows a token, a state a slot or
+    a ring of a window's rows (``cache_pool.py``).  A model with experts,
+    state layers or windowed layers is told
     which rows carry a token (the tick's busy slots, a prompt's real
     positions): the others go to no expert, and leave a layer's state as it
     is — a free or cached slot's state is not the tick's to write, and a
-    padded prompt's state stands at its last real token.  A model with
+    padded prompt's state stands at its last real token, as its ring holds
+    the rows before its last real token and no padded one.  A model with
     experts returns its routing in the
     SAME int32 vector as the tokens: the counts (``moe_counts_tick`` /
     ``moe_counts_prefill`` accumulate them) and the experts chosen for
@@ -144,8 +146,10 @@ class DecodeEngine:
     @property
     def _takes_live(self) -> bool:
         """The programs take the rows that carry a token: expert layers
-        route only those, state layers move only those on."""
-        return bool(self.n_counts) or self.arch.has_state
+        route only those, state layers move only those on, a windowed
+        layer's prefill rings only those."""
+        return bool(self.n_counts) or self.arch.has_state \
+            or self.arch.has_ring
 
     # ---- program builders ----
     def _build_tick(self):
@@ -216,7 +220,7 @@ class DecodeEngine:
             tok = _next_token(_blocks.head_table(arch, params), h_last,
                               axis, key[None], temp[None], s_real[None])
             # every buffer a layer declares gets its slab, at the slot's
-            # rows [0, s_pad) — or the slot's whole state
+            # rows [0, s_pad) — or the slot's whole state, or its whole ring
             new_caches = jax.tree_util.tree_map(
                 lambda c, slab: jax.lax.dynamic_update_slice(
                     c, slab.astype(c.dtype), (slot,) + (0,) * (c.ndim - 1)),
@@ -234,7 +238,7 @@ class DecodeEngine:
         """Slot-to-slot cache slab copy — the prefix cache's copy-on-
         extend device half (ISSUE 7).  Copies the ENTIRE src slot row
         into dst for every buffer of every layer (a K/V pair, a latent
-        buffer, a state: whatever the pool declares): rows beyond the
+        buffer, a state, a ring: whatever the pool declares): rows beyond the
         matched prefix length
         carry stale K/V, but they are unreachable by the standard
         above-``pos`` masking argument and the next occupant's writes
@@ -331,14 +335,16 @@ class DecodeEngine:
             raise ValueError(
                 f"prefix_len {prefix_len} out of range (0, "
                 f"{self.pool.max_total}]")
-        if self.pool.state_bytes_per_slot \
+        if (self.pool.state_bytes_per_slot or self.pool.ring_bytes_per_slot) \
                 and int(prefix_len) != int(self.pool.pos[src_slot]):
             # rows [0, k) are the rows of any prefix; a state is the state
-            # of ONE position, the one the source slot stands at
+            # of ONE position, the one the source slot stands at, and a
+            # ring holds the window's rows before that position alone
+            what = "state" if self.pool.state_bytes_per_slot else "ring"
             raise ValueError(
-                f"slot {src_slot} holds a layer state at position "
+                f"slot {src_slot} holds a layer {what} at position "
                 f"{int(self.pool.pos[src_slot])}: a prefix of {prefix_len} "
-                f"tokens has no state to copy")
+                f"tokens has no {what} to copy")
         if self._prefix_copy_prog is None:
             self._prefix_copy_prog = self._build_prefix_copy()
             from ..observability import flight as _flight

@@ -146,6 +146,15 @@ class RequestHandle:
         return self._req.done_event.wait(timeout)
 
 
+def _band_pairs(s_real: int, windows) -> int:
+    """The (query, key) pairs a prompt of ``s_real`` tokens needs in the
+    layers with a window (``windows``: one entry a windowed layer): query
+    ``t`` meets ``min(t + 1, W)`` keys."""
+    head = np.minimum(windows, s_real)
+    return int((head * (head + 1) // 2
+                + (s_real - head) * np.asarray(windows)).sum())
+
+
 def _request_row(req: Request) -> Dict[str, Any]:
     """One JSON-able /requestz row (also the bundle's serving view)."""
     ts = dict(req.timestamps)
@@ -231,13 +240,23 @@ class ServingEngine:
         # position but a state for one, its donated length: an entry is
         # usable only whole (``whole_only``), and the spill tier, which
         # packs "rows [0, len)", is refused rather than left to drop it.
+        # A RING is the same case: it holds the window's rows before the
+        # donated length, and a shorter match has lost some of its own.
         has_state = bool(self.pool.state_bytes_per_slot)
+        has_ring = bool(self.pool.ring_bytes_per_slot)
         if has_state and prefix_cache and int(spill_bytes) > 0:
             raise ValueError(
                 "this model has 'kda' layers, which keep a per-slot "
                 "recurrent state and no rows: the host spill tier packs "
                 "rows [0, len) of each buffer and would drop the state; "
                 "construct the engine with spill_bytes=0")
+        if has_ring and prefix_cache and int(spill_bytes) > 0:
+            raise ValueError(
+                "this model has windowed attention layers, which declare a "
+                "ring (columns, spec, window) of their last `window` rows: "
+                "the host spill tier packs rows [0, len) of each buffer "
+                "and would drop the ring; construct the engine with "
+                "spill_bytes=0")
         self.prefix_cache: Optional[PrefixCache] = None
         if prefix_cache:
             self.prefix_cache = PrefixCache(
@@ -247,7 +266,7 @@ class ServingEngine:
                 min_prefix_len=min_prefix_len,
                 on_insert=self._on_prefix_insert,
                 on_evict=self._on_prefix_evict,
-                whole_only=has_state)
+                whole_only=has_state or has_ring)
         # host-RAM spill tier (ISSUE 12): a scavenged rc==0 prefix slot
         # spills its CRC-stamped slab into a bounded LRU host store
         # instead of vanishing; a later matching prompt restores it
@@ -318,6 +337,23 @@ class ServingEngine:
         # state bytes a layer, as the rows it had to read are the live
         # rows times ``bytes_per_token`` (host arithmetic, both)
         self._tick_state_slots_live = 0
+        # rings (windowed layers): ring rows of EVERY slot a tick read (it
+        # runs them all), summed over ring layers, in rows and in blocks —
+        # averaged with the row layers' into ``tick_cache_*``, so that
+        # share stays a share — and what it HAD to touch: the busy slots'
+        # ring rows (x ring layers) and their rows (one row layer)
+        self._tick_ring_rows_read = 0
+        self._tick_ring_blocks_read = 0
+        self._tick_ring_blocks_total = 0
+        self._tick_ring_rows_live = 0
+        # (window, ring layers that have it): a tick counts a kind once
+        self._ring_kinds = [(int(w), int(c)) for w, c in zip(*np.unique(
+            self.pool.ring_windows, return_counts=True))]
+        self._tick_rows_busy = 0
+        # the windowed prefills' needed score work: per real query
+        # position the keys in its band, x windowed layers
+        self._prefill_band_pairs = 0
+        self._prefill_band_pairs_padded = 0     # the same of the padded rows
         self._t0 = time.monotonic()
         # goodput attribution: step() partitions its own wall clock, and
         # the gap between steps books as queue_wait (work was waiting)
@@ -615,6 +651,10 @@ class ServingEngine:
                         self._running[slot] = req
                         self._prefill_tokens_real += req.prompt_len
                         self._prefill_tokens_padded += s_pad
+                        self._prefill_band_pairs += _band_pairs(
+                            req.prompt_len, self.pool.ring_windows)
+                        self._prefill_band_pairs_padded += _band_pairs(
+                            s_pad, self.pool.ring_windows)
                     self._maybe_evict(req, time.monotonic())
 
             # one decode tick IN FLIGHT: launch the next tick over the rows
@@ -660,6 +700,11 @@ class ServingEngine:
                     # measured window (the unguarded-shared-write lint class)
                     read, total = live_blocks(self.pool.pos,
                                               self.pool.max_total)
+                    ring_read = ring_total = 0
+                    for w, n_layers in self._ring_kinds:
+                        r, t = live_blocks(self.pool.pos, w)
+                        ring_read += n_layers * r
+                        ring_total += n_layers * t
                     with self._lock:
                         if self._last_tick_start is not None:
                             self._tick_gap_ms.add(
@@ -672,6 +717,15 @@ class ServingEngine:
                             ) + self.pool.n_slots
                         self._tick_state_slots_live += (
                             len(rows) * self.pool.n_state_layers)
+                        if self._ring_kinds:
+                            self._tick_ring_blocks_read += ring_read
+                            self._tick_ring_blocks_total += ring_total
+                            self._tick_ring_rows_read += \
+                                self.pool.ring_rows_live()
+                            self._tick_ring_rows_live += \
+                                self.pool.ring_rows_live(live)
+                            self._tick_rows_busy += int(
+                                self.pool.pos[live].sum()) + len(rows)
                     # the tracer's clock is read only for its own Chrome sink
                     t_tick_us = obs.now_us() if obs.enabled() else 0
                     first = self.engine.tick_calls == 0
@@ -1193,6 +1247,17 @@ class ServingEngine:
         """Host-side serving summary (the Prometheus ``extra_gauges`` /
         summary-record payload).  Each end-to-end metric's direction and
         bound are BENCHMARK.json's to state, not a key name's."""
+        pool = self.pool
+        n_rings = len(pool.ring_windows)
+        ring_row_bytes = (pool.ring_bytes_per_slot
+                          // max(int(pool.ring_windows.sum()), 1))
+
+        def per_layer(of_a_row_layer, over_ring_layers):
+            if not n_rings:
+                return float(of_a_row_layer)
+            return float(pool.n_row_layers * of_a_row_layer
+                         + over_ring_layers) / (pool.n_row_layers + n_rings)
+
         with self._lock:
             el = max(time.monotonic() - self._t0, 1e-9)
             out = {
@@ -1210,19 +1275,41 @@ class ServingEngine:
                     self._prefill_tokens_padded),
                 # read over held: the share of the pool's cache blocks
                 # the ticks' attention had to read (ragged-read kernel)
-                "serving/tick_cache_blocks_read": float(
-                    self._tick_cache_blocks_read),
-                "serving/tick_cache_blocks_total": float(
-                    self._tick_cache_blocks_total),
-                "serving/tick_cache_rows_live": float(
-                    self._tick_cache_rows_live),
+                # (a layer of the pool on average: layers that keep rows
+                # are all alike, a ring layer reads ``min(pos + 1, W)``
+                # rows of its one block a slot)
+                "serving/tick_cache_blocks_read": per_layer(
+                    self._tick_cache_blocks_read,
+                    self._tick_ring_blocks_read),
+                "serving/tick_cache_blocks_total": per_layer(
+                    self._tick_cache_blocks_total,
+                    self._tick_ring_blocks_total),
+                "serving/tick_cache_rows_live": per_layer(
+                    self._tick_cache_rows_live, self._tick_ring_rows_read),
                 # what one token keeps in the pool, all row layers, and
                 # what one slot keeps whatever its length, all state
-                # layers (gauges)
+                # layers and all ring layers (gauges)
                 "serving/cache_bytes_per_token": float(
                     self.pool.bytes_per_token),
                 "serving/cache_state_bytes_per_slot": float(
                     self.pool.state_bytes_per_slot),
+                "serving/cache_ring_bytes_per_slot": float(
+                    self.pool.ring_bytes_per_slot),
+                # what the ticks HAD to touch of a pool with rings: the
+                # busy slots' ring rows, ``min(pos + 1, W)`` each, summed
+                # over ring layers, their bytes, and the bytes of the busy
+                # slots' rows in the layers that keep every row; and the
+                # windowed prefills' needed (query, key) pairs
+                "serving/tick_ring_rows_live": float(
+                    self._tick_ring_rows_live),
+                "serving/tick_ring_bytes": float(
+                    self._tick_ring_rows_live * ring_row_bytes),
+                "serving/tick_row_bytes": float(
+                    self._tick_rows_busy * self.pool.bytes_per_token),
+                "serving/prefill_band_pairs": float(
+                    self._prefill_band_pairs),
+                "serving/prefill_band_pairs_padded": float(
+                    self._prefill_band_pairs_padded),
                 # what the ticks had to touch of each kind of cache: the
                 # busy slots' state (read and written once a tick; other
                 # slots' is not touched), and the live rows
@@ -1270,6 +1357,10 @@ class ServingEngine:
         if self.prefix_cache is not None:
             for k, v in self.prefix_cache.stats().items():
                 out[f"serving/prefix/{k}"] = v
+            # a ring no longer holds a shorter match's rows: the same
+            # refusal as ``state_misses``, under the ring's name
+            out["serving/prefix/window_misses"] = (
+                out["serving/prefix/state_misses"] if n_rings else 0.0)
             out["serving/prefix/cached_slots"] = float(
                 self.pool.cached_count)
         if self.spill is not None:

@@ -25,6 +25,11 @@ on such a pool the cache is built ``whole_only`` — an entry serves a hit
 only where its WHOLE sequence is a prefix of the prompt, a longer entry
 neither covers nor subsumes a shorter one, and a match that rows alone
 could have served counts in ``state_misses`` and takes the whole prefill.
+A RING (a windowed attention layer's last ``W`` rows: ``cache_pool.py``) is
+the same case — it holds the rows before the donated length alone, and a
+match of ``m`` tokens has lost rows ``[m - W, len - W)`` — so a layout with
+a ring is built ``whole_only`` too, and the engine reports the refusals as
+``serving/prefix/window_misses``.
 
 Matches are capped at ``len(prompt) - 1``: the FIRST GENERATED token
 comes from the last prompt position's hidden state, which is not

@@ -93,6 +93,13 @@ def _widths(pool):
             "spill tier) moves rows [0, len) of each buffer; this pool "
             "holds per-slot state of 'kda' layers (a recurrent state and "
             "a convolution window), which it would drop — refused")
+    if getattr(pool, "ring_bytes_per_slot", 0):
+        raise ValueError(
+            "the KV-transfer plane (local transfer, pack/unpack, the host "
+            "spill tier) moves rows [0, len) of each buffer; this pool "
+            "holds a ring declaration (columns, spec, window) for its "
+            "windowed attention layers — the last `window` rows of a "
+            "sequence at row p % window — which is no such rows: refused")
     return [tuple(w for w, _ in bufs) for bufs in pool.layout]
 
 

@@ -535,3 +535,118 @@ def test_state_and_row_layers_in_one_pool_serving_programs(topo, as_tpu):
     # the whole model's arguments with the widest prefill's temporaries:
     # under 15.0 GB, the line ISSUE 31 draws for 64 slots
     assert mem.argument_size_in_bytes + pmem.temp_size_in_bytes < 15.0e9
+
+
+def _laguna_programs(topo, n_layers, prompts, tick=True):
+    """The serving programs of the benchmark's ``laguna-xs2-ep16``
+    configuration (every width as published, the chip's share of experts,
+    the whole vocabulary, 24 slots of 4096 rows) with its first
+    ``n_layers`` layers: ``(arch, layout, tick, {prompt: prefill})``
+    compiled."""
+    import importlib.util
+    import json
+
+    from chainermn_tpu._compat import shard_map
+    from chainermn_tpu.parallel import blocks
+    from chainermn_tpu.serving.engine import DecodeEngine, result_size
+
+    here = os.path.dirname(os.path.abspath(__file__))
+
+    def load(name, path):
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+
+    bench = os.path.join(os.path.dirname(here), "benchmark")
+    ref = load("laguna_reference", os.path.join(here, "laguna_reference.py"))
+    fam = load("laguna_family", os.path.join(bench, "families", "laguna.py"))
+    with open(os.path.join(bench, "configs", "laguna-xs2-ep16.json")) as f:
+        cfg = json.load(f)
+    cfg = dict(cfg, num_hidden_layers=n_layers, **{
+        k: cfg[k][:n_layers] for k in (
+            "layer_types", "mlp_layer_types",
+            "num_attention_heads_per_layer")})
+    arch = fam.arch_of(cfg)
+    n_slots, total = 24, 4096
+    mesh = Mesh(np.array(topo.devices[:1]), ("model",))
+    rep = NamedSharding(mesh, P())
+    shapes = jax.eval_shape(
+        lambda k: ref.init_params(k, cfg, jnp.bfloat16), jax.random.PRNGKey(0))
+    specs = blocks.lm_specs(arch, shapes, "model")
+    p = jax.tree_util.tree_map(
+        lambda x, sp: _sds(x.shape, x.dtype, NamedSharding(mesh, sp)),
+        shapes, specs)
+    layout = blocks.cache_layout(arch, n_layers, 8 * 128, "model")
+    caches = [tuple(_sds(blocks.buffer_shape(b, n_slots, total),
+                         jnp.bfloat16, NamedSharding(mesh, b[1]))
+                    for b in bufs) for bufs in layout]
+    eng = DecodeEngine.__new__(DecodeEngine)
+    eng.mesh, eng.axis_name, eng.arch = mesh, "model", arch
+    eng.head_dim = cfg["head_dim"]
+    eng.n_counts = blocks.n_count_entries(arch)
+    eng._specs, eng._shard_map, eng._P = specs, shard_map, P
+    eng._cache_specs = [tuple(b[1] for b in bufs) for bufs in layout]
+    tick = eng._build_tick().lower(
+        p, caches, _sds((result_size(eng.arch, n_slots),), jnp.int32, rep),
+        _sds((n_slots,), jnp.int32, rep), _sds((n_slots,), jnp.int32, rep),
+        _sds((n_slots, 2), jnp.uint32, rep),
+        _sds((n_slots,), jnp.float32, rep),
+        _sds((n_slots,), jnp.bool_, rep)).compile() if tick else None
+    prefills = {
+        s: eng._build_prefill(s).lower(
+            p, caches, _sds((1, s), jnp.int32, rep),
+            _sds((), jnp.int32, rep), _sds((), jnp.int32, rep),
+            _sds((2,), jnp.uint32, rep), _sds((), jnp.float32, rep)
+        ).compile() for s in prompts}
+    return arch, layout, tick, prefills
+
+
+def test_ring_and_row_layers_in_one_pool_serving_programs(topo, as_tpu):
+    """The serving programs of a model whose sliding-window GQA layers keep
+    a RING of 512 rows a slot (64 query heads) and whose full-attention
+    layers keep every row (48), 8 KV heads of 128, at Laguna-XS.2's
+    published widths (the benchmark's ``laguna-xs2-ep16``), at FULL depth,
+    40 layers, and the whole 100,352-row vocabulary.  The TICK: the GQA
+    flash-decode kernel through the one-position-per-slot face in every
+    layer, over rows and rings alike (no beam kernel), the grouped expert
+    product (39); both kinds of buffer written in place; weights + pool +
+    temporaries inside one v5e chip.  The widest PREFILL (3072): the banded
+    flash forward in the 30 sliding layers, the causal one in the 10 full.
+    Kernels, programs and scopes keep the names the trace readers match."""
+    arch, layout, tick, prefills = _laguna_programs(topo, 40, (3072,))
+    assert sum(bool(arch.window(i)) for i in range(40)) == 30
+    assert [tuple(b[0::2] if len(b) == 3 else b[:1] for b in bufs)
+            for bufs in layout[:2]] == [((1024,),) * 2, ((1024, 512),) * 2]
+    text, mem = tick.as_text(), tick.memory_analysis()
+    assert "HloModule jit_serving_tick" in text
+    calls = [ln.split(" = ")[0] for ln in text.split("\n")
+             if "tpu_custom_call" in ln]
+    count = lambda name: sum(name in c for c in calls)
+    assert count("decode_attn_gqa") == 40 and count("decode_attn_beam") == 0
+    assert count("decode_attn") == 40       # what decode_attn_ms_per_tick sums
+    assert count("moe_gmm") >= 3 * 39
+    # no reader of another model's metric may match the new names
+    for name in ("decode_attn_gqa", "window_flash_fwd"):
+        assert not any(n in name for n in ("mla", "moe_gmm", "kda"))
+    for scope in ("tick/attn", "block/attn/window", "block/attn/gate",
+                  "cache_write", "block/moe"):
+        assert scope in text, scope
+    _assert_pool_written_in_place(text, (24, 4096, 1024))
+    _assert_pool_written_in_place(text, (24, 512, 1024))
+    # 8.0 GB of weights + 5.54 GB of pool (rows 4.03, rings 1.51)
+    assert 13.5e9 < mem.argument_size_in_bytes < 13.6e9
+    assert mem.temp_size_in_bytes < 0.3e9
+
+    pre, pmem = prefills[3072].as_text(), prefills[3072].memory_analysis()
+    assert "HloModule jit_serving_prefill_3072" in pre
+    assert pre.count("%window_flash_fwd") >= 30
+    assert pre.count("%flash_fwd") >= 10 and pre.count("%moe_gmm") >= 3 * 39
+    assert "decode_attn" not in pre
+    for scope in ("block/attn/window", "block/attn/gate", "cache_write"):
+        assert scope in pre, scope
+    _assert_pool_written_in_place(pre, (24, 4096, 1024))
+    _assert_pool_written_in_place(pre, (24, 512, 1024))
+    # arguments plus the widest prefill's temporaries: under 15.0 GB, the
+    # line ISSUE 33 draws for 24 slots
+    assert pmem.argument_size_in_bytes + pmem.temp_size_in_bytes < 15.0e9

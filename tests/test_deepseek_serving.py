@@ -440,9 +440,9 @@ def test_the_references_router_is_the_programs():
 # the dropless layer: shares, skew, kernel
 # --------------------------------------------------------------------------
 
-def _full_layer(seed=0):
+def _full_layer(seed=0, **sizes):
     """An expert layer holding all 16 experts, and tokens."""
-    cfg = dict(CFG, n_routed_experts_held=16)
+    cfg = dict(CFG, n_routed_experts_held=16, **sizes)
     m = ref.init_params(jax.random.PRNGKey(seed), cfg)["blocks"][1]["moe"]
     x = jax.random.normal(jax.random.PRNGKey(seed + 100), (24, 64))
     return m, x
@@ -454,11 +454,16 @@ def _share(m, first, n):
 
 
 #: the router's numbers: DeepSeek-V3's (4 groups of which 2 are kept, gates
-#: times 2.5) and Kimi Linear's (one group, all kept: plain top-k; times
-#: 2.446) -- one router, one dropless layer, other numbers
+#: times 2.5), Kimi Linear's (one group, all kept: plain top-k; times
+#: 2.446) and Laguna's (one group, times 2.5, experts a quarter as wide as
+#: the model where the others' are a half) -- one router, one dropless
+#: layer, other numbers
 ROUTERS = {"4-groups-x2.5": {},
            "1-group-x2.446": {"n_group": 1, "topk_group": 1,
-                              "routed_scaling_factor": 2.446}}
+                              "routed_scaling_factor": 2.446},
+           "1-group-x2.5-narrow": {"n_group": 1, "topk_group": 1,
+                                   "routed_scaling_factor": 2.5,
+                                   "moe_intermediate_size": 16}}
 
 
 @pytest.mark.parametrize("router", sorted(ROUTERS))
@@ -466,8 +471,9 @@ ROUTERS = {"4-groups-x2.5": {},
 def test_the_shares_add_up_to_the_uncut_layer(interpret, router):
     """The routed parts of all 4 shares (4 experts each), plus the shared
     expert counted once, are the uncut layer: program and reference."""
-    m, x = _full_layer()
     cfg = dict(CFG, **ROUTERS[router])
+    m, x = _full_layer(moe_intermediate_size=cfg["moe_intermediate_size"])
+    assert m["w_gate"].shape == (16, 64, cfg["moe_intermediate_size"])
     moe = arch_of(cfg).moe
     assert (moe.n_group, moe.routed_scaling_factor) == (
         cfg["n_group"], cfg["routed_scaling_factor"])
@@ -733,6 +739,11 @@ def test_gpt2_description_is_the_default():
     # one attention kind for the whole model, no state: nothing of the
     # per-layer kinds (PR 31) shows in the defaults
     assert (a.attn_kinds, a.kda, a.has_state) == (None, None, False)
+    # nor of a window, a rotation of the layer's own, a gate on the context
+    # or absent biases (PR 33)
+    assert (a.windows, a.rotary, a.attn_gate, a.attn_bias, a.has_ring) == (
+        None, None, False, True, False)
+    assert [a.window(i) for i in range(3)] == [None] * 3
     assert [a.attn_kind(i) for i in range(3)] == ["mha"] * 3
     assert blocks.cache_layout(a, 2, 32, "model") == [
         ((32, P(None, None, "model")),) * 2] * 2
@@ -779,6 +790,12 @@ def test_deepseek_description_is_bit_identical_with_a_kind_per_layer(
     whole = arch_of(CFG)
     each = dataclasses.replace(whole, attn_kinds=("mla",) * 3)
     assert not whole.has_state and not each.has_state
+    # a window names MHA/GQA layers: a latent layer has none, whatever the
+    # tuple says, and keeps its one rows buffer
+    windowed = dataclasses.replace(whole, windows=(8,) * 3)
+    assert not whole.has_ring and not windowed.has_ring
+    assert blocks.cache_layout(windowed, 3, 0, "model") \
+        == blocks.cache_layout(whole, 3, 0, "model")
     assert blocks.cache_layout(whole, 3, 0, "model") \
         == blocks.cache_layout(each, 3, 0, "model")
     assert blocks.lm_specs(whole, params, "model") \

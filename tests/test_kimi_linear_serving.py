@@ -325,12 +325,18 @@ def test_cache_layout_declares_rows_or_state_per_layer():
     assert int(np.prod(state)) * 4 == 2_097_152
     assert int(np.prod(window)) * 2 == 73_728
     assert ARCH.has_state and not blocks.DEFAULT_ARCH.has_state
+    # a state is no ring, and no layer of this model has a window (PR 33)
+    assert not ARCH.has_ring and not any(
+        blocks.is_ring(buf) for bufs in layout for buf in bufs)
+    assert (ARCH.windows, ARCH.rotary, ARCH.attn_gate) == (None, None, False)
 
 
 def test_pool_allocates_both_kinds_and_counts_both(params, mesh):
     eng = _engine(params, mesh)
     pool = eng.pool
     shapes = [tuple(buf.shape for buf in layer) for layer in pool.caches]
+    assert pool.ring_bytes_per_slot == 0 and len(pool.ring_windows) == 0
+    assert pool.n_row_layers == N_MLA
     assert shapes[3] == ((4, 48, 128),)
     assert shapes[0] == ((4, 4, 16, 16), (4, 3, 192))
     assert pool.caches[0][0].dtype == jnp.float32
