@@ -14,7 +14,8 @@ one expert.  This kernel multiplies each row tile by its expert's weight:
 * Row tiles at or past ``n_valid`` (the worst-case padding a dropless
   layer must size for) hold the index maps at the last live tile and skip
   the body under ``pl.when`` — no copy, no matmul, about a grid step's
-  overhead each (the ragged structure of ``ops/decode_attention.py``).
+  overhead each (as the steps past the last busy slot in
+  ``ops/kda_step.py``).
   Their output rows are never written: the caller masks them.
 * Grid ``(N tiles, row tiles)``, rows fastest, the whole contraction in
   one block: a weight block is read once per (expert, N tile), the small

@@ -441,13 +441,15 @@ def mla_attend_prefill(cfg: MLAConfig, q_nope, q_rope, c_kv, k_rope, a,
 
 
 def mla_attend_absorbed(cfg: MLAConfig, q_nope, q_rope, cache, valid, a,
-                        use_kernel: bool):
+                        use_kernel: bool, busy=None, work=None):
     """DECODE form over the latent ``cache (B, total, latent_width)``:
     ``q_lat = W_UK^T q_nope``, scores against the shared rows, the
     weighted sum of ``c_kv``, then ``W_UV``.  ``valid (B, S_q)`` int32:
     query ``i`` of row ``b`` sees cache rows ``[0, valid[b, i])``.
     Returns ``ctx (B, S_q, H·v)``.  ``use_kernel``: the flash-decode
-    kernel (one query per row); else an einsum."""
+    kernel (one query per row; ``work``: its work list where the caller
+    holds it); else an einsum.  ``busy (B,) bool`` (None: all): the rows
+    that attend at all; the others' context is 0 on either path."""
     b, s_q, h, nope = q_nope.shape
     rank = cfg.kv_lora_rank
     w = _wukv(cfg, a)
@@ -458,11 +460,11 @@ def mla_attend_absorbed(cfg: MLAConfig, q_nope, q_rope, cache, valid, a,
     q_abs = jnp.concatenate([q_lat, q_rope], -1)
     if pad:
         q_abs = jnp.pad(q_abs, ((0, 0),) * 3 + ((0, pad),))
+    from ..ops.decode_attention import decode_attend_mla, zero_idle_rows
     if use_kernel:
-        from ..ops.decode_attention import decode_attend_mla
-        o_lat = decode_attend_mla(q_abs[:, 0], cache, valid[:, 0] - 1,
-                                  rank=rank, scale=cfg.softmax_scale
-                                  )[:, None]
+        o_lat = decode_attend_mla(q_abs[:, 0], cache, valid[:, 0] - 1, busy,
+                                  rank=rank, scale=cfg.softmax_scale,
+                                  work=work)[:, None]
     else:
         sc = jnp.einsum("bshw,bkw->bhsk", q_abs, cache,
                         preferred_element_type=jnp.float32)
@@ -475,6 +477,7 @@ def mla_attend_absorbed(cfg: MLAConfig, q_nope, q_rope, cache, valid, a,
         o_lat = jnp.einsum("bhsk,bkr->bshr", p,
                            cache[..., :rank].astype(jnp.float32)
                            ).astype(q_nope.dtype)
+        o_lat = zero_idle_rows(o_lat, busy)
     ctx = jnp.einsum("bshr,rhd->bshd", o_lat, w[..., nope:],
                      preferred_element_type=jnp.float32
                      ).astype(q_nope.dtype)

@@ -56,13 +56,32 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
     (``arch.attn_kind(layer)``).  ``attn_block.moe_routing``
     collects the expert layers' ``(counts, idx)`` in trace order; ``live
     (N, S_q) bool`` names the rows that carry a token (None: all) — the
-    expert layers send the others to no expert, and a delta-rule layer
-    leaves their state as it is.
+    expert layers send the others to no expert, a delta-rule layer
+    leaves their state as it is, and the tick's attention (``S_q == 1``)
+    reads their cache not at all: such a row's context is 0.
     """
     arch = _blocks.resolve(arch)
     d_model = params["embed"].shape[1]
     rope = "pos_embed" not in params
     moe_routing = []
+
+    def tick_work(work, pos, n, rows):
+        """The flash-decode kernels' work list over an ``(n, rows, ·)``
+        cache — the busy slots' live blocks — from the tick's own memo
+        ``work`` (one list a cache shape, built where the first layer of
+        that shape attends, handed to every later one); None without a
+        memo: the kernel builds its own."""
+        from ..ops.decode_attention import work_list
+
+        if work is None:
+            return None
+        if rows not in work:
+            work[rows] = work_list(pos, busy_rows(), n, rows)
+        return work[rows]
+
+    def busy_rows():
+        """``(N,) bool`` of a tick's rows that carry a token (None: all)."""
+        return None if live is None else live[:, 0]
 
     def embed(tokens, positions):
         from .tensor_parallel import vocab_parallel_embedding
@@ -122,7 +141,7 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
             axis_name=axis_name)
         return (second_half(x + attn_out, blk, layer),) + extras
 
-    def mla_block(x, blk, cache, positions, write_at, q_valid, layer):
+    def mla_block(x, blk, cache, positions, write_at, q_valid, layer, work):
         """The MLA layer: the token's latent row is written to ``cache``
         (one buffer), a prefill attends in the prefill form through the
         flash kernel, everything else in the absorbed form over the
@@ -154,7 +173,9 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
                               and _pick_block_s(cache.shape[1]) > 0)
                 ctx = _blocks.mla_attend_absorbed(
                     cfg, q_nope, q_rope, cache, valid, blk["attn"],
-                    use_kernel)
+                    use_kernel, busy_rows() if s_q == 1 else None,
+                    tick_work(work, valid[:, 0] - 1, n, cache.shape[1])
+                    if use_kernel else None)
             attn_out = jnp.matmul(
                 ctx, blk["attn"]["wo"],
                 preferred_element_type=jnp.float32).astype(x.dtype)
@@ -175,7 +196,7 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
         return second_half(x + y, blk, layer), state, window
 
     def attn_block(x, blk, k_cache, v_cache, positions, write_at, q_valid,
-                   layer: int = 0):
+                   layer: int = 0, work=None):
         """x (N,S,D) → block output; caches written at ``write_at + i`` for
         the i-th input position; query i attends cache [:q_valid + i + 1).
 
@@ -188,8 +209,9 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
         serving tick): row ``b`` then writes at ``write_at[b]`` and
         attends its own prefix ``[:q_valid[b] + i + 1)`` — the ragged
         iteration-level batch.  On a TPU the one-token tick takes the
-        flash-decode kernel either way (it maps one position per cache
-        row and reads each row's cache up to its own length); the
+        flash-decode kernel either way (it walks the live blocks of the
+        rows that carry a token, each up to the row's own position;
+        ``work``: a tick's memo of its work lists, ``tick_work``); the
         einsum below serves other backends, ``s_q > 1`` chunked fills
         and totals with no 8-aligned block.
 
@@ -205,7 +227,7 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
         kind = arch.attn_kind(layer)
         if kind == "mla":
             return mla_block(x, blk, k_cache, positions, write_at, q_valid,
-                             layer)
+                             layer, work)
         if kind == "kda":
             return kda_block(x, blk, k_cache, v_cache, layer)
         n = x.shape[0]
@@ -272,25 +294,30 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
                 return ctx.astype(x.dtype), (kc, vc)
             from ..ops.decode_attention import (_pick_block_s,
                                                  decode_attend,
-                                                 decode_attend_gqa)
+                                                 decode_attend_gqa,
+                                                 zero_idle_rows)
             if s_q == 1 and jax.default_backend() == "tpu" \
                     and _pick_block_s(kc.shape[1]) > 0:
-                # DECODE on TPU: one flash-decode Pallas pass — cache
-                # read once at full lane density (ops/decode_attention),
-                # each row up to its own ``write_at`` (scalar: the
-                # closed batch; vector: the serving tick's slots).
-                # GQA has the same face (``decode_attend_gqa``: its own
-                # kernel at heads of whole lane tiles, else the beam
-                # kernel).  Odd totals with no 8-aligned S-block (e.g. a
-                # max_new=1 probe's 513) stay on the einsum fallback below.
+                # DECODE on TPU: one flash-decode Pallas pass — the
+                # cache of the rows that carry a token read once at full
+                # lane density (ops/decode_attention), each row up to its
+                # own ``write_at`` (scalar: the closed batch; vector: the
+                # serving tick's slots).  GQA has the same face
+                # (``decode_attend_gqa``: its own kernel at heads of whole
+                # lane tiles, else the beam kernel).  Odd totals with no
+                # 8-aligned S-block (e.g. a max_new=1 probe's 513) stay on
+                # the einsum fallback below.
+                lists = tick_work(work, write_at, n, kc.shape[1])
                 if hl == hkv:
                     ctx = decode_attend(
                         q.reshape(n, hl * head_dim), kc, vc, write_at,
-                        n_heads=hkv, head_dim=head_dim)
+                        busy_rows(), n_heads=hkv, head_dim=head_dim,
+                        work=lists)
                 else:
                     ctx = decode_attend_gqa(
                         q.reshape(n, hl * head_dim), kc, vc, write_at,
-                        n_q_heads=hl, n_kv_heads=hkv, head_dim=head_dim)
+                        busy_rows(), n_q_heads=hl, n_kv_heads=hkv,
+                        head_dim=head_dim, work=lists)
                 return ctx.reshape(n, 1, hl, head_dim), (kc, vc)
             # Fallback (non-TPU backends, chunked fills, unaligned
             # totals): grouped einsum attention against head-view
@@ -323,6 +350,8 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
             ctx = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(vc4.dtype), vc4,
                              preferred_element_type=jnp.float32
                              ).astype(x.dtype)
+            if s_q == 1:    # the kernels' contract: an idle row reads 0
+                ctx = zero_idle_rows(ctx, busy_rows())
             return ctx, (kc, vc)
 
         return block_with(x, blk, positions, attend, layer)
@@ -344,11 +373,12 @@ def _write_rows(cache, rows, write_at):
 
 
 def _run_layer(attn_block, x, blk, bufs, positions, write_at, q_valid,
-               layer: int):
+               layer: int, work=None):
     """One block over the layer's cache tuple ``bufs`` — ``(k, v)`` or one
-    latent buffer — returning ``(x, new cache tuple)``."""
+    latent buffer — returning ``(x, new cache tuple)``.  ``work``: a tick's
+    memo of work lists (``attn_block``)."""
     x, *new = attn_block(x, blk, bufs[0], bufs[1] if len(bufs) > 1 else None,
-                         positions, write_at, q_valid, layer)
+                         positions, write_at, q_valid, layer, work)
     return x, tuple(new)
 
 
@@ -545,7 +575,10 @@ def lm_decode_tick(params, tokens, caches, pos, *, head_dim: int,
     the model axis bound.  ``arch`` / ``with_routing`` as
     :func:`lm_prefill` (routes ``(N, 1, expert layers, top_k)``); ``live
     (N,) bool``: the rows that carry a token (None: all; the serving
-    tick's free slots go to no expert).
+    tick's other slots go to no expert, keep their state, and have none
+    of their cache read: on a TPU the flash-decode kernels walk one list
+    of the live rows' blocks, built once a cache shape and shared by the
+    layers).
     """
     embed, attn_block, _, _ = _decoder_core(
         params, head_dim, axis_name, arch,
@@ -556,11 +589,12 @@ def lm_decode_tick(params, tokens, caches, pos, *, head_dim: int,
     with jax.named_scope("tick/embed"):
         x = embed(tokens[:, None], positions)
     new_caches = []
+    work = {}       # rows of a cache -> its work list, for every layer
     for i, (blk, bufs) in enumerate(zip(params["blocks"], caches)):
         # the block's cache append nests as tick/attn/cache_write
         with jax.named_scope("tick/attn"):
             x, new = _run_layer(attn_block, x, blk, bufs, positions, pos,
-                                pos, i)
+                                pos, i, work)
         new_caches.append(new)
     with jax.named_scope("tick/head"):
         h = _blocks.norm(arch, x, params, "lnf")

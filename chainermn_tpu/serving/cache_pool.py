@@ -51,9 +51,10 @@ different invariant for each:
 * RING ``(n_slots, W, columns)``, the last ``W`` rows of a sequence (a
   windowed attention layer: a query at position ``q`` sees keys ``q - W <
   k <= q``), position ``p`` at ring row ``p % W``.  *The row a write lands
-  on is the one row the next query cannot see.*  The tick runs every slot,
-  and a free or cached slot's garbage write lands on ring row ``pos % W``
-  at its held ``pos``.  That row holds position ``pos - W``: exactly one
+  on is the one row the next query cannot see.*  The tick writes a row
+  for every slot (it READS only the busy slots' caches), and a free or
+  cached slot's garbage write lands on ring row ``pos % W`` at its held
+  ``pos``.  That row holds position ``pos - W``: exactly one
   position OUTSIDE the window of the next real query, which is at ``pos``
   and sees ``pos - W + 1 .. pos`` — and it is the row that query's own
   token overwrites before attending.  So a cached slot's ring still serves

@@ -27,8 +27,11 @@ programs driven from the host, one tick at a time:
   eviction change only the host-side position/token vectors, never the
   program.  On a TPU its attention is the flash-decode kernel
   ``lm_generate`` decodes through (``ops/decode_attention.py``), given
-  the position vector: each slot's cache is read once, up to the slot's
-  own length.  A slot's input token is the PREVIOUS tick's result for it,
+  the position vector and the busy mask: each BUSY slot's cache is read
+  once, up to the slot's own length, and a slot that serves nobody (free,
+  or holding a cached prefix) is not read at all — its attention is 0, and
+  no token anyone reads depends on it.  A slot's input token is the
+  PREVIOUS tick's result for it,
   taken on the device, unless the host hands one in (the first token
   after a prefill or an install, a prefix hit's owed prompt tokens): so a
   tick can be launched before the one ahead of it has been read back
@@ -44,9 +47,9 @@ TP composes exactly as in the closed-batch path: params stay in
 ``transformer_lm_specs`` layout, pool caches are sharded ``P(None,
 None, model)`` (each chip holds its local heads' columns), and the
 greedy pick is the (pmax, pmin) pair — the full logits never gather.
-Inactive slots still burn FLOPs (their output is discarded); a
-real-traffic engine keeps the pool near-full, which is the scheduler's
-job.
+Inactive slots still run the tick's dense parts as rows (their output
+is discarded) but none of the attention's cache reads; a real-traffic
+engine keeps the pool near-full, which is the scheduler's job.
 """
 
 from __future__ import annotations
@@ -143,14 +146,6 @@ class DecodeEngine:
         self.tick_launches_overlapped = 0
         self.prefix_copies = 0
 
-    @property
-    def _takes_live(self) -> bool:
-        """The programs take the rows that carry a token: expert layers
-        route only those, state layers move only those on, a windowed
-        layer's prefill rings only those."""
-        return bool(self.n_counts) or self.arch.has_state \
-            or self.arch.has_ring
-
     # ---- program builders ----
     def _build_tick(self):
         import jax
@@ -166,7 +161,7 @@ class DecodeEngine:
         # profiler's "XLA Modules" line says ``jit_serving_tick``,
         # ``jit_serving_prefill_<s_pad>``, ``jit_serving_prefix_copy``
         def serving_tick(params, caches, prev, override, pos, keys, temps,
-                         live=None):
+                         live):
             # a slot's token: the host's where it hands one in, else what
             # the tick before chose for the slot (``prev``: its whole result)
             tokens = jnp.where(override >= 0, override,
@@ -182,14 +177,14 @@ class DecodeEngine:
                                   axis, keys, temps, pos + 1)
             return _with_routing(nxt, routing), new_caches
 
-        # a model with experts or state layers takes the live mask as a
-        # sixth vector.  The pool is DONATED to every program that returns
-        # it: the row write lands in place (un-donated, XLA copied every
-        # buffer first)
+        # ``live``: the slots that carry a request's token — the attention
+        # reads only their cache, expert layers route only them, state
+        # layers move only them on.  The pool is DONATED to every program
+        # that returns it: the row write lands in place (un-donated, XLA
+        # copied every buffer first)
         return jax.jit(self._shard_map(
             serving_tick, mesh=self.mesh,
-            in_specs=(self._specs, self._cache_specs)
-            + (P(),) * (6 if self._takes_live else 5),
+            in_specs=(self._specs, self._cache_specs) + (P(),) * 6,
             out_specs=(P(), self._cache_specs)), donate_argnums=(1,))
 
     def _build_prefill(self, s_pad: int):
@@ -200,13 +195,14 @@ class DecodeEngine:
         from ..parallel.decode import _next_token, lm_prefill
 
         axis, head_dim, arch = self.axis_name, self.head_dim, self.arch
-        P, takes_live = self._P, self._takes_live
+        P = self._P
 
         def prefill_inner(params, caches, prompt, s_real, slot, key, temp):
             # slab caches sized to the padded prompt only; pads are above
             # every real row and never read back (causal + pos mask).  A
-            # state is not rows: the pads must not move it (``real``)
-            real = (jnp.arange(s_pad) < s_real)[None] if takes_live else None
+            # state is not rows, nor is a ring: the pads must not move
+            # them, and go to no expert (``real``)
+            real = (jnp.arange(s_pad) < s_real)[None]
             h, slabs, routing = lm_prefill(
                 params, prompt, s_pad, head_dim=head_dim, axis_name=axis,
                 arch=arch, live=real, with_routing=True)
@@ -388,8 +384,8 @@ class DecodeEngine:
             temps = jnp.asarray(np.array(temps, np.float32, copy=True))
             if live is None:
                 live = self.pool.busy_mask()
-            operands = (self._last_result, override, pos, keys, temps) + (
-                (jnp.asarray(live),) if self._takes_live else ())
+            operands = (self._last_result, override, pos, keys, temps,
+                        jnp.asarray(np.array(live, bool, copy=True)))
         with _trace.span("serving/tick/dispatch", cat="serving"):
             nxt = self._last_result = self.pool.update(
                 lambda caches: self._tick_prog(self._params, caches,

@@ -324,32 +324,29 @@ class ServingEngine:
         self._rejected = 0
         self._prefill_tokens_real = 0
         self._prefill_tokens_padded = 0
-        # how much of the pool the tick's attention has to read: cache
-        # blocks at or below each slot's position over the blocks the
-        # pool holds (the flash-decode kernel's ragged read; all layers
-        # alike, so one is counted)
+        # how much of the pool the tick's attention reads: the busy slots'
+        # cache blocks at or below their positions (the flash-decode
+        # kernels' work list, by its own arithmetic) over the blocks the
+        # pool holds (all layers alike, so one is counted)
         self._tick_cache_blocks_read = 0
         self._tick_cache_blocks_total = 0
-        # the rows those blocks had to hold: each slot's own length
+        # the rows those blocks had to hold: each busy slot's own length
         self._tick_cache_rows_live = 0
         # state layers: (busy slot, state layer) pairs a tick moved on —
         # the state it had to read and write is that times a slot's
         # state bytes a layer, as the rows it had to read are the live
         # rows times ``bytes_per_token`` (host arithmetic, both)
         self._tick_state_slots_live = 0
-        # rings (windowed layers): ring rows of EVERY slot a tick read (it
-        # runs them all), summed over ring layers, in rows and in blocks —
+        # rings (windowed layers): the busy slots' ring rows, ``min(pos +
+        # 1, W)`` each, summed over ring layers, in rows and in blocks —
         # averaged with the row layers' into ``tick_cache_*``, so that
-        # share stays a share — and what it HAD to touch: the busy slots'
-        # ring rows (x ring layers) and their rows (one row layer)
-        self._tick_ring_rows_read = 0
+        # share stays a share
         self._tick_ring_blocks_read = 0
         self._tick_ring_blocks_total = 0
         self._tick_ring_rows_live = 0
         # (window, ring layers that have it): a tick counts a kind once
         self._ring_kinds = [(int(w), int(c)) for w, c in zip(*np.unique(
             self.pool.ring_windows, return_counts=True))]
-        self._tick_rows_busy = 0
         # the windowed prefills' needed score work: per real query
         # position the keys in its band, x windowed layers
         self._prefill_band_pairs = 0
@@ -699,10 +696,10 @@ class ServingEngine:
                     # read-modify-write could book one warm-up gap into the
                     # measured window (the unguarded-shared-write lint class)
                     read, total = live_blocks(self.pool.pos,
-                                              self.pool.max_total)
+                                              self.pool.max_total, busy=live)
                     ring_read = ring_total = 0
                     for w, n_layers in self._ring_kinds:
-                        r, t = live_blocks(self.pool.pos, w)
+                        r, t = live_blocks(self.pool.pos, w, busy=live)
                         ring_read += n_layers * r
                         ring_total += n_layers * t
                     with self._lock:
@@ -713,19 +710,15 @@ class ServingEngine:
                         self._tick_cache_blocks_read += read
                         self._tick_cache_blocks_total += total
                         self._tick_cache_rows_live += int(np.minimum(
-                            self.pool.pos, self.pool.max_total - 1).sum()
-                            ) + self.pool.n_slots
+                            self.pool.pos[live], self.pool.max_total - 1
+                            ).sum()) + len(rows)
                         self._tick_state_slots_live += (
                             len(rows) * self.pool.n_state_layers)
                         if self._ring_kinds:
                             self._tick_ring_blocks_read += ring_read
                             self._tick_ring_blocks_total += ring_total
-                            self._tick_ring_rows_read += \
-                                self.pool.ring_rows_live()
                             self._tick_ring_rows_live += \
                                 self.pool.ring_rows_live(live)
-                            self._tick_rows_busy += int(
-                                self.pool.pos[live].sum()) + len(rows)
                     # the tracer's clock is read only for its own Chrome sink
                     t_tick_us = obs.now_us() if obs.enabled() else 0
                     first = self.engine.tick_calls == 0
@@ -1196,6 +1189,9 @@ class ServingEngine:
             self._tick_cache_blocks_total = 0
             self._tick_cache_rows_live = 0
             self._tick_state_slots_live = 0
+            self._tick_ring_blocks_read = 0
+            self._tick_ring_blocks_total = 0
+            self._tick_ring_rows_live = 0
             self._tick_rows_discarded = 0
             self.engine.moe_counts_tick[:] = 0
             self.engine.moe_counts_prefill[:] = 0
@@ -1274,10 +1270,10 @@ class ServingEngine:
                 "serving/prefill_tokens_padded": float(
                     self._prefill_tokens_padded),
                 # read over held: the share of the pool's cache blocks
-                # the ticks' attention had to read (ragged-read kernel)
+                # the ticks' attention read — the busy slots' live blocks
                 # (a layer of the pool on average: layers that keep rows
                 # are all alike, a ring layer reads ``min(pos + 1, W)``
-                # rows of its one block a slot)
+                # rows of its one block a busy slot)
                 "serving/tick_cache_blocks_read": per_layer(
                     self._tick_cache_blocks_read,
                     self._tick_ring_blocks_read),
@@ -1285,7 +1281,7 @@ class ServingEngine:
                     self._tick_cache_blocks_total,
                     self._tick_ring_blocks_total),
                 "serving/tick_cache_rows_live": per_layer(
-                    self._tick_cache_rows_live, self._tick_ring_rows_read),
+                    self._tick_cache_rows_live, self._tick_ring_rows_live),
                 # what one token keeps in the pool, all row layers, and
                 # what one slot keeps whatever its length, all state
                 # layers and all ring layers (gauges)
@@ -1295,9 +1291,9 @@ class ServingEngine:
                     self.pool.state_bytes_per_slot),
                 "serving/cache_ring_bytes_per_slot": float(
                     self.pool.ring_bytes_per_slot),
-                # what the ticks HAD to touch of a pool with rings: the
-                # busy slots' ring rows, ``min(pos + 1, W)`` each, summed
-                # over ring layers, their bytes, and the bytes of the busy
+                # what the ticks touch of a pool with rings: the busy
+                # slots' ring rows, ``min(pos + 1, W)`` each, summed over
+                # ring layers, their bytes, and the bytes of the busy
                 # slots' rows in the layers that keep every row; and the
                 # windowed prefills' needed (query, key) pairs
                 "serving/tick_ring_rows_live": float(
@@ -1305,7 +1301,7 @@ class ServingEngine:
                 "serving/tick_ring_bytes": float(
                     self._tick_ring_rows_live * ring_row_bytes),
                 "serving/tick_row_bytes": float(
-                    self._tick_rows_busy * self.pool.bytes_per_token),
+                    self._tick_cache_rows_live * self.pool.bytes_per_token),
                 "serving/prefill_band_pairs": float(
                     self._prefill_band_pairs),
                 "serving/prefill_band_pairs_padded": float(

@@ -387,7 +387,8 @@ def phase_serve(ctx):
             engine.engine._last_result,      # the tick before's, on device
             jnp.asarray(z(n, np.int32)), jnp.asarray(z(n, np.int32)),
             jnp.asarray(z((n, 2), np.uint32)),
-            jnp.asarray(z(n, np.float32))).compile()
+            jnp.asarray(z(n, np.float32)),
+            jnp.asarray(z(n, bool))).compile()      # the busy mask
 
     tick = compile_tick(eng)
     prefill = dec._prefill_progs[dec.padded_len(sz["prompt"])].lower(

@@ -60,7 +60,8 @@ def main() -> int:
                           else (tokens, on_device))
         ops = (prev, jnp.asarray(np.array(override, np.int32, copy=True)),
                jnp.asarray(np.array(pool.pos, np.int32, copy=True)),
-               jnp.asarray(keys.copy()), jnp.asarray(temps.copy()))
+               jnp.asarray(keys.copy()), jnp.asarray(temps.copy()),
+               jnp.asarray(busy.copy()))
         nxt = pool.update(lambda caches: de._tick_prog(de._params, caches,
                                                        *ops))
         pool.advance(busy)

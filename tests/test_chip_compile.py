@@ -155,18 +155,20 @@ def test_fused_cross_entropy_fwd_bwd(one_chip):
     assert n >= 2
 
 
-@pytest.mark.parametrize("per_row", [False, True], ids=["scalar", "vector"])
-def test_decode_attend(one_chip, per_row):
-    """One position for the batch (``lm_generate``) or one per cache row
-    (the serving tick): the same kernel, ``pos`` broadcast or not."""
+@pytest.mark.parametrize("face", ["scalar", "vector", "busy"])
+def test_decode_attend(one_chip, face):
+    """One position for the batch (``lm_generate``), one per cache row, or
+    one per cache row and the rows that are busy (the serving tick): the
+    same kernel on a grid whose bound is the work list's length."""
     from chainermn_tpu.ops.decode_attention import decode_attend
 
     b, s, h, hd = 8, 2048, 16, 128
     q = _sds((b, h * hd), jnp.bfloat16, one_chip)
     kc = _sds((b, s, h * hd), jnp.bfloat16, one_chip)
-    pos = _sds((b,) if per_row else (), jnp.int32, one_chip)
+    pos = _sds(() if face == "scalar" else (b,), jnp.int32, one_chip)
+    busy = (_sds((b,), jnp.bool_, one_chip),) if face == "busy" else ()
     n = _n_kernels(partial(decode_attend, n_heads=h, head_dim=hd,
-                           interpret=False), q, kc, kc, pos,
+                           interpret=False), q, kc, kc, pos, *busy,
                    names=("decode_attn_mha",))
     assert n >= 1
 
@@ -313,10 +315,12 @@ def test_serving_prefill_and_tick(topo, as_tpu, shape):
         p, caches, _sds((result_size(eng.arch, n_slots),), jnp.int32, rep),
         _sds((n_slots,), jnp.int32, rep), _sds((n_slots,), jnp.int32, rep),
         _sds((n_slots, 2), jnp.uint32, rep),
-        _sds((n_slots,), jnp.float32, rep)).compile()
+        _sds((n_slots,), jnp.float32, rep),
+        _sds((n_slots,), jnp.bool_, rep)).compile()
     assert "HloModule jit_serving_tick" in tick.as_text()
-    # the tick's per-slot position vector goes to the flash-decode
-    # kernel, one call a layer: a vector Mosaic refused would fail here
+    # the tick's per-slot positions and busy mask go to the flash-decode
+    # kernel as one work list, one call a layer on a grid the list's
+    # length bounds: a list or a bound Mosaic refused would fail here
     assert "%decode_attn_mha" in tick.as_text()
     assert tick.as_text().count("tpu_custom_call") >= N_LAYERS
     _assert_pool_written_in_place(tick.as_text(), (n_slots, total, D_MODEL))

@@ -4,13 +4,17 @@ Oracle: the einsum attend from parallel/decode.py's decode tick — same
 masking (positions ≤ pos), same fp32 softmax.
 """
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from chainermn_tpu.ops.decode_attention import (decode_attend,
-                                                decode_attend_gqa)
+                                                decode_attend_gqa,
+                                                decode_attend_mla,
+                                                live_blocks, work_list)
 
 
 def oracle(q, kc, vc, pos, h, hd):
@@ -278,3 +282,121 @@ class TestGQADecode:
                                 head_dim=hd, block_s=8, interpret=True)
         assert got.shape == (b, hq * hd)
         assert np.isfinite(np.asarray(got)).all()
+
+
+# The work list (PR 34): every face that takes one position a cache row
+# walks the busy slots' live blocks only.  Four slots of S = 128 in blocks
+# of 32; a slot that is not busy reads exact 0, a busy one what the einsum
+# oracle reads; ``busy=None`` is every slot, bit for bit, and bit for bit
+# what the (B, S / block) walk of PR 33 gave (recorded:
+# fixtures/decode_attn_pr33.npz, with the oracle's own result beside it to
+# tell whether this host rounds as the recording host did).
+WORK_S, WORK_BLOCK, WORK_SLOTS = 128, 32, 4
+WORK_POS = {
+    "zero": [0, 0, 0, 0],
+    "inside": [40, 5, 70, 100],
+    "edge": [31, 32, 63, 127],              # a block's last row, first row
+    "wrapped": [128, 300, 129, 640],        # >= S: a ring past its first lap
+}
+WORK_BUSY = {
+    "none": [0, 0, 0, 0],
+    "one": [0, 0, 1, 0],
+    "alternating": [1, 0, 1, 0],
+    "all": [1, 1, 1, 1],
+}
+#: face -> (query heads, KV heads, head size); ``mla``: (heads, rank, rope)
+WORK_FACES = {"mha": (4, 4, 16), "gqa128": (4, 2, 128), "gqa64": (4, 2, 64),
+              "mla": (4, 32, 16)}
+WORK_RECORD = os.path.join(os.path.dirname(__file__), "fixtures",
+                           "decode_attn_pr33.npz")
+
+
+def _work_inputs(face):
+    rs = np.random.RandomState(34)
+    b, s = WORK_SLOTS, WORK_S
+    if face == "mla":
+        h, rank, rope = WORK_FACES[face]
+        return (jnp.asarray(rs.randn(b, h, rank + rope), jnp.float32),
+                jnp.asarray(rs.randn(b, s, rank + rope), jnp.float32), None)
+    hq, hkv, hd = WORK_FACES[face]
+    return (jnp.asarray(rs.randn(b, hq * hd), jnp.float32),
+            jnp.asarray(rs.randn(b, s, hkv * hd), jnp.float32),
+            jnp.asarray(rs.randn(b, s, hkv * hd), jnp.float32))
+
+
+def _work_call(face, q, kc, vc, pos, busy=None):
+    kw = dict(block_s=WORK_BLOCK, interpret=True)
+    if face == "mla":
+        out = decode_attend_mla(q, kc, pos, busy, rank=WORK_FACES[face][1],
+                                scale=0.25, **kw)
+        return out.reshape(WORK_SLOTS, -1)
+    hq, hkv, hd = WORK_FACES[face]
+    if face == "mha":
+        return decode_attend(q, kc, vc, pos, busy, n_heads=hq, head_dim=hd,
+                             **kw)
+    return decode_attend_gqa(q, kc, vc, pos, busy, n_q_heads=hq,
+                             n_kv_heads=hkv, head_dim=hd, **kw)
+
+
+def _work_oracle(face, q, kc, vc, pos):
+    if face == "mla":
+        rank = WORK_FACES[face][1]
+        sc = jnp.einsum("bhw,bkw->bhk", q, kc) * 0.25
+        sc = jnp.where(jnp.arange(WORK_S)[None, None, :]
+                       <= pos[:, None, None], sc, -1e30)
+        return jnp.einsum("bhk,bkr->bhr", jax.nn.softmax(sc, -1),
+                          kc[..., :rank]).reshape(WORK_SLOTS, -1)
+    hq, hkv, hd = WORK_FACES[face]
+    return oracle_gqa(q, kc, vc, pos, hq, hkv, hd)
+
+
+@pytest.mark.parametrize("case", list(WORK_POS))
+@pytest.mark.parametrize("mask", list(WORK_BUSY))
+@pytest.mark.parametrize("face", list(WORK_FACES))
+def test_work_list_reads_the_busy_slots_and_nothing_else(face, mask, case):
+    q, kc, vc = _work_inputs(face)
+    pos = jnp.asarray(WORK_POS[case], jnp.int32)
+    busy = np.asarray(WORK_BUSY[mask], bool)
+    # every block of a slot that is not busy is NaN: read, it would show
+    idle = jnp.asarray(~busy)[:, None, None]
+    poisoned = [None if a is None else jnp.where(idle, jnp.nan, a)
+                for a in (kc, vc)]
+    got = np.asarray(_work_call(face, q, *poisoned, pos, jnp.asarray(busy)))
+    want = np.asarray(_work_oracle(face, q, kc, vc, pos))
+    np.testing.assert_allclose(got[busy], want[busy], rtol=2e-4, atol=2e-5)
+    np.testing.assert_array_equal(got[~busy], 0.0)
+    if mask == "all":
+        # no mask IS every slot busy, and is the walk PR 33 had, bit for
+        # bit (on a host that rounds as the recording host did: the
+        # recorded oracle says; elsewhere to float32's last digits)
+        plain = np.asarray(_work_call(face, q, kc, vc, pos))
+        np.testing.assert_array_equal(plain, got)
+        with np.load(WORK_RECORD) as rec:
+            then, then_oracle = rec[f"{face}-{case}"], \
+                rec[f"oracle-{face}-{case}"]
+        if np.array_equal(then_oracle, want):
+            np.testing.assert_array_equal(plain, then)
+        else:
+            np.testing.assert_allclose(plain, then, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("mask", list(WORK_BUSY))
+@pytest.mark.parametrize("case", list(WORK_POS))
+def test_work_list_is_the_busy_slots_live_blocks_in_order(case, mask):
+    """The device's list against the definition, and its length against
+    the host's twin (what the engine's counters sum)."""
+    pos, busy = WORK_POS[case], np.asarray(WORK_BUSY[mask], bool)
+    want = [(i, j) for i in range(WORK_SLOTS) if busy[i]
+            for j in range(min(pos[i], WORK_S - 1) // WORK_BLOCK + 1)]
+    work = work_list(jnp.asarray(pos, jnp.int32), jnp.asarray(busy),
+                     WORK_SLOTS, WORK_S, WORK_BLOCK)
+    n = int(work.n[0])
+    pairs = list(zip(np.asarray(work.slot).tolist(),
+                     np.asarray(work.block).tolist()))
+    assert len(pairs) == WORK_SLOTS * (WORK_S // WORK_BLOCK)
+    assert pairs[:n] == want
+    # past the list: the pair the last step held (nothing busy: one block)
+    assert set(pairs[n:]) <= {want[-1] if want else (WORK_SLOTS - 1, 0)}
+    assert live_blocks(pos, WORK_S, WORK_BLOCK, busy) == (n, len(pairs))
+    assert live_blocks(pos, WORK_S, WORK_BLOCK) == live_blocks(
+        pos, WORK_S, WORK_BLOCK, np.ones(WORK_SLOTS, bool))

@@ -766,8 +766,7 @@ def test_gpt2_tick_program_returns_tokens_alone(devices):
     m = eng.metrics()
     assert not any("moe" in k for k in m)
     assert m["serving/cache_bytes_per_token"] == 2 * 2 * 32 * 4
-    # rows alone: no state, no fifth operand, nothing counted as state
-    assert not eng.engine._takes_live
+    # rows alone: no state, nothing counted as state
     assert m["serving/cache_state_bytes_per_slot"] == 0
     assert m["serving/tick_state_slots_live"] == 0
     assert m["serving/tick_state_bytes"] == 0

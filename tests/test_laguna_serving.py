@@ -332,7 +332,7 @@ def test_served_requests_are_the_reference(params, mesh):
         ((4, 48, KV_DIM),) * 2, ((4, W, KV_DIM),) * 2]
     assert pool.bytes_per_token == N_FULL * 2 * KV_DIM * 4
     assert pool.ring_bytes_per_slot == N_RING * 2 * W * KV_DIM * 4
-    assert pool.state_bytes_per_slot == 0 and eng.engine._takes_live
+    assert pool.state_bytes_per_slot == 0
     prompts = [_prompt(1, 5), _prompt(2, 11), _prompt(3, 19)]
     handles = _serve(eng, prompts, 2 * W + 4)
     tokens = np.zeros((3, 49), np.int32)

@@ -387,11 +387,10 @@ def test_prefill_bucket_padding_counts_against_capacity(devices):
 
 
 def test_tick_cache_block_counters(devices):
-    """How much of the pool the tick's attention has to read (the
-    flash-decode kernel's ragged read): blocks at or below each slot's
-    position over the blocks the pool holds, host arithmetic on
-    ``pool.pos`` — summed over ticks, in ``metrics()``, zeroed by
-    ``reset_stats()``."""
+    """How much of the pool the tick's attention reads (the flash-decode
+    kernels' work list): blocks at or below each BUSY slot's position over
+    the blocks the pool holds, host arithmetic on ``pool.pos`` — summed
+    over ticks, in ``metrics()``, zeroed by ``reset_stats()``."""
     from chainermn_tpu.ops.decode_attention import DEFAULT_BLOCK_S
     from chainermn_tpu.serving import ServingEngine
 
@@ -405,17 +404,21 @@ def test_tick_cache_block_counters(devices):
     rng = np.random.RandomState(5)
     handles = [eng.submit(rng.randint(0, VOCAB, n).astype(np.int32), 4)
                for n in (4, 9)]
-    # the third slot stays free, its position drifted into the second
-    # block (the tick advances every slot) — and on past the cache
+    # the third slot stays free, holding a position in the second block
+    # (a cached prefix would): it serves nobody, so none of it is read
     eng.pool.pos[2] = total - 2
     eng.run(steps_budget=20)
     assert [h.status for h in handles] == ["done", "done"]
     ticks = eng.engine.tick_calls
     assert ticks >= 3
     m = eng.metrics()
-    # slots 0 and 1 live in their first block, the free slot in both
-    assert m["serving/tick_cache_blocks_read"] == 4.0 * ticks
+    # slots 0 and 1 live in their first block: one block a busy slot a
+    # tick (both are busy in every tick: 4 tokens each, admitted together)
+    assert m["serving/tick_cache_blocks_read"] == 2.0 * ticks
     assert m["serving/tick_cache_blocks_total"] == 6.0 * ticks
+    # and the rows a tick reads: ``pos + 1`` of each (the token it writes)
+    assert m["serving/tick_cache_rows_live"] == sum(
+        (4 + k + 1) + (9 + k + 1) for k in range(ticks))
     eng.reset_stats()
     m = eng.metrics()
     assert m["serving/tick_cache_blocks_read"] == 0.0

@@ -52,7 +52,8 @@ def models():
                     max_len=64, pos_impl="learned"), D // HEADS, None, VOCAB)
             else:
                 t = _load({"expert": "test_deepseek_serving",
-                           "state": "test_kimi_linear_serving"}[name])
+                           "state": "test_kimi_linear_serving",
+                           "ring": "test_laguna_serving"}[name])
                 built[name] = (
                     t.ref.init_params(jax.random.PRNGKey(3), t.CFG,
                                       jnp.float32),
@@ -382,3 +383,103 @@ def test_tick_equals_launch_then_collect(models, mesh, name):
     assert de.tick_launches_overlapped >= 6
     pool.release(slot)
     eng.close()
+
+
+# --------------------------------------------------------------------------
+# (g) the tick's attention is the busy slots' alone (ISSUE 34)
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("name", ["plain", "expert", "ring"])
+def test_ticks_leave_the_slots_that_serve_nobody_alone(models, mesh, name):
+    """A pool with a busy, a cached and a free slot.  The busy slot's
+    tokens are the request's served alone; after all its ticks the cached
+    slot's rows below its position, its ring but for the one row a stray
+    write lands on (``pos % W``, outside the next query's window) and the
+    free slot's buffers but for row 0 are bit for bit what they were; a
+    prefix hit on the cached slot the ticks skipped serves what a cold
+    engine serves; and the engine's counters are the host twin of the
+    kernels' work list, summed over the ticks."""
+    from chainermn_tpu.ops.decode_attention import live_blocks
+
+    model = models(name)
+    vocab = model[3]
+    rng = np.random.RandomState(34)
+    first = rng.randint(0, vocab, 13).astype(np.int32)
+    second = rng.randint(0, vocab, 9).astype(np.int32)
+    tail = rng.randint(0, vocab, 3).astype(np.int32)
+
+    def serve(eng, prompt, max_new):
+        h = eng.submit(prompt, max_new)
+        _drive(eng)
+        assert h.status == "done"
+        return h.tokens
+
+    eng = _engine(model, mesh, max_total=64)
+    pool = eng.pool
+    want_second, _ = _alone(eng, second, 20)
+    donated = np.concatenate([first, serve(eng, first, 11)])
+    (cached,) = [s for s in range(pool.n_slots)
+                 if pool.allocator.refcount(s) is not None]
+    before = jax.tree_util.tree_map(np.asarray, pool.caches)
+    held = pool.pos.copy()
+    eng.reset_stats()
+    calls0 = eng.engine.tick_calls
+
+    # the second request's ticks, each launch's positions and mask noted
+    launches, launch = [], eng.engine.launch_tick
+
+    def noted(override, keys, temps, live):
+        launches.append((pool.pos.copy(), np.array(live, bool)))
+        return launch(override, keys, temps, live)
+
+    eng.engine.launch_tick = noted
+    h = eng.submit(second, 20)
+    _drive(eng)
+    blocks = sum(live_blocks(pos, pool.max_total, busy=live)[0]
+                 for pos, live in launches)
+    rows = sum(int(pos[live].sum()) + int(live.sum())
+               for pos, live in launches)
+    assert h.tokens == want_second
+    ticks = eng.engine.tick_calls - calls0
+    assert ticks == len(launches) >= 19
+    (busy_slot,) = [s for s in range(pool.n_slots)
+                    if pool.pos[s] != held[s]]
+    assert busy_slot != cached and pool.pos[cached] == held[cached]
+
+    m = eng.metrics()
+    if not len(pool.ring_windows):              # rows alone: one layer's
+        assert m["serving/tick_cache_blocks_read"] == blocks == ticks
+        assert m["serving/tick_cache_rows_live"] == rows
+    else:
+        assert m["serving/tick_ring_rows_live"] == sum(
+            int(np.minimum(pos[live][None] + 1,
+                           pool.ring_windows[:, None]).sum())
+            for pos, live in launches)
+    assert m["serving/tick_row_bytes"] == rows * pool.bytes_per_token
+    assert m["serving/tick_cache_blocks_total"] >= 3 * ticks * bool(blocks)
+
+    after = jax.tree_util.tree_map(np.asarray, pool.caches)
+    free = [s for s in range(pool.n_slots) if s not in (cached, busy_slot)]
+    for was, now in zip(jax.tree_util.tree_leaves(before),
+                        jax.tree_util.tree_leaves(after)):
+        if was.ndim != 3:
+            # a state: never written unless busy
+            np.testing.assert_array_equal(now[cached], was[cached])
+            continue
+        n_rows = was.shape[1]
+        stray = held[cached] % n_rows           # rows: pos itself
+        keep = np.arange(n_rows) != stray
+        if n_rows == pool.max_total:
+            keep &= np.arange(n_rows) < held[cached]
+        np.testing.assert_array_equal(now[cached][keep], was[cached][keep])
+        for s in free:                          # position 0: row 0 alone
+            np.testing.assert_array_equal(now[s][1:], was[s][1:])
+
+    # a prefix hit on the slot every one of those ticks skipped
+    follow = np.concatenate([donated, tail])
+    got = serve(eng, follow, 9)
+    assert eng.metrics()["serving/prefix/hits"] >= 1
+    eng.close()
+    cold = _engine(model, mesh, max_total=64, prefix_cache=False)
+    assert got == serve(cold, follow, 9)
+    cold.close()
