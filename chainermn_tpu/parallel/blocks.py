@@ -414,30 +414,35 @@ def mla_attend_prefill(cfg: MLAConfig, q_nope, q_rope, c_kv, k_rope, a,
     kernel divides by ``sqrt(width)``; the model's softmax scale is put
     on q."""
     b, s, h, nope = q_nope.shape
-    kv = jnp.einsum("bsr,rhd->bshd", c_kv, _wukv(cfg, a),
-                    preferred_element_type=jnp.float32).astype(c_kv.dtype)
-    k = jnp.concatenate(
-        [kv[..., :nope],
-         jnp.broadcast_to(k_rope[:, :, None, :], (b, s, h, k_rope.shape[-1]))],
-        -1)
-    v = kv[..., nope:]
-    q = jnp.concatenate([q_nope, q_rope], -1)
+    with jax.named_scope("proj"):       # the keys and values of every head
+        kv = jnp.einsum("bsr,rhd->bshd", c_kv, _wukv(cfg, a),
+                        preferred_element_type=jnp.float32
+                        ).astype(c_kv.dtype)
+        k = jnp.concatenate(
+            [kv[..., :nope],
+             jnp.broadcast_to(k_rope[:, :, None, :],
+                              (b, s, h, k_rope.shape[-1]))], -1)
+        v = kv[..., nope:]
+        q = jnp.concatenate([q_nope, q_rope], -1)
     width = q.shape[-1]
-    if attn_impl == "flash":
-        from ..ops.flash_attention import flash_attention
-        qs = (q.astype(jnp.float32)
-              * (cfg.softmax_scale * width ** 0.5)).astype(q.dtype)
-        vp = jnp.pad(v, ((0, 0),) * 3 + ((0, width - v.shape[-1]),))
-        ctx = flash_attention(qs, k, vp, causal=True)[..., :v.shape[-1]]
-    else:
-        sc = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                        preferred_element_type=jnp.float32)
-        sc = sc * cfg.softmax_scale
-        mask = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
-        p = jax.nn.softmax(jnp.where(mask[None, None], sc, -1e30), axis=-1)
-        ctx = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
-                         preferred_element_type=jnp.float32).astype(q.dtype)
-    return ctx.reshape(b, s, h * cfg.v_head_dim)
+    with jax.named_scope("core"):
+        if attn_impl == "flash":
+            from ..ops.flash_attention import flash_attention
+            qs = (q.astype(jnp.float32)
+                  * (cfg.softmax_scale * width ** 0.5)).astype(q.dtype)
+            vp = jnp.pad(v, ((0, 0),) * 3 + ((0, width - v.shape[-1]),))
+            ctx = flash_attention(qs, k, vp, causal=True)[..., :v.shape[-1]]
+        else:
+            sc = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                            preferred_element_type=jnp.float32)
+            sc = sc * cfg.softmax_scale
+            mask = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
+            p = jax.nn.softmax(jnp.where(mask[None, None], sc, -1e30),
+                               axis=-1)
+            ctx = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
+                             preferred_element_type=jnp.float32
+                             ).astype(q.dtype)
+        return ctx.reshape(b, s, h * cfg.v_head_dim)
 
 
 def mla_attend_absorbed(cfg: MLAConfig, q_nope, q_rope, cache, valid, a,
@@ -452,36 +457,40 @@ def mla_attend_absorbed(cfg: MLAConfig, q_nope, q_rope, cache, valid, a,
     that attend at all; the others' context is 0 on either path."""
     b, s_q, h, nope = q_nope.shape
     rank = cfg.kv_lora_rank
-    w = _wukv(cfg, a)
-    q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w[..., :nope],
-                       preferred_element_type=jnp.float32
-                       ).astype(q_nope.dtype)
-    pad = cache.shape[-1] - rank - q_rope.shape[-1]
-    q_abs = jnp.concatenate([q_lat, q_rope], -1)
-    if pad:
-        q_abs = jnp.pad(q_abs, ((0, 0),) * 3 + ((0, pad),))
-    from ..ops.decode_attention import decode_attend_mla, zero_idle_rows
-    if use_kernel:
-        o_lat = decode_attend_mla(q_abs[:, 0], cache, valid[:, 0] - 1, busy,
-                                  rank=rank, scale=cfg.softmax_scale,
-                                  work=work)[:, None]
-    else:
-        sc = jnp.einsum("bshw,bkw->bhsk", q_abs, cache,
-                        preferred_element_type=jnp.float32)
-        sc = sc * cfg.softmax_scale
-        mask = (jnp.arange(cache.shape[1])[None, None, None, :]
-                < valid[:, None, :, None])
-        p = jax.nn.softmax(jnp.where(mask, sc, -1e30), axis=-1)
-        # float32 operands: the CPU backend has no bf16 x bf16 -> f32 dot
-        # of this shape, and this path is the one other backends take
-        o_lat = jnp.einsum("bhsk,bkr->bshr", p,
-                           cache[..., :rank].astype(jnp.float32)
+    with jax.named_scope("proj"):       # W_UK absorbed into the query
+        w = _wukv(cfg, a)
+        q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w[..., :nope],
+                           preferred_element_type=jnp.float32
                            ).astype(q_nope.dtype)
-        o_lat = zero_idle_rows(o_lat, busy)
-    ctx = jnp.einsum("bshr,rhd->bshd", o_lat, w[..., nope:],
-                     preferred_element_type=jnp.float32
-                     ).astype(q_nope.dtype)
-    return ctx.reshape(b, s_q, h * cfg.v_head_dim)
+        pad = cache.shape[-1] - rank - q_rope.shape[-1]
+        q_abs = jnp.concatenate([q_lat, q_rope], -1)
+        if pad:
+            q_abs = jnp.pad(q_abs, ((0, 0),) * 3 + ((0, pad),))
+    from ..ops.decode_attention import decode_attend_mla, zero_idle_rows
+    with jax.named_scope("core"):
+        if use_kernel:
+            o_lat = decode_attend_mla(q_abs[:, 0], cache, valid[:, 0] - 1,
+                                      busy, rank=rank,
+                                      scale=cfg.softmax_scale,
+                                      work=work)[:, None]
+        else:
+            sc = jnp.einsum("bshw,bkw->bhsk", q_abs, cache,
+                            preferred_element_type=jnp.float32)
+            sc = sc * cfg.softmax_scale
+            mask = (jnp.arange(cache.shape[1])[None, None, None, :]
+                    < valid[:, None, :, None])
+            p = jax.nn.softmax(jnp.where(mask, sc, -1e30), axis=-1)
+            # float32 operands: the CPU backend has no bf16 x bf16 -> f32
+            # dot of this shape, and this path is the one other backends take
+            o_lat = jnp.einsum("bhsk,bkr->bshr", p,
+                               cache[..., :rank].astype(jnp.float32)
+                               ).astype(q_nope.dtype)
+            o_lat = zero_idle_rows(o_lat, busy)
+    with jax.named_scope("proj"):       # W_UV on the weighted latent sum
+        ctx = jnp.einsum("bshr,rhd->bshd", o_lat, w[..., nope:],
+                         preferred_element_type=jnp.float32
+                         ).astype(q_nope.dtype)
+        return ctx.reshape(b, s_q, h * cfg.v_head_dim)
 
 
 # --------------------------------------------------------------------------

@@ -59,6 +59,12 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
     expert layers send the others to no expert, a delta-rule layer
     leaves their state as it is, and the tick's attention (``S_q == 1``)
     reads their cache not at all: such a row's context is 0.
+
+    Every equation of a block lies under one ``jax.named_scope`` of the
+    vocabulary a traced program's device time is split by
+    (docs/OBSERVABILITY.md, "Device time by scope"): the attention half is
+    ``block/{attn,mla,kda}/proj``, ``.../core`` and ``cache_write``, the
+    FFN half ``block/mlp`` — ``with`` blocks only, never a function layer.
     """
     arch = _blocks.resolve(arch)
     d_model = params["embed"].shape[1]
@@ -76,7 +82,8 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
         if work is None:
             return None
         if rows not in work:
-            work[rows] = work_list(pos, busy_rows(), n, rows)
+            with jax.named_scope("tick/work_list"):
+                work[rows] = work_list(pos, busy_rows(), n, rows)
         return work[rows]
 
     def busy_rows():
@@ -102,11 +109,12 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
     def second_half(x, blk, layer):
         """residual stream after attention → norm → the layer's FFN (dense
         MLP or experts, by ``arch``) → residual."""
-        h = _blocks.norm(arch, x, blk, "ln2")
-        y, routing = _blocks.ffn(arch, layer, h, blk, axis_name, live)
-        if routing is not None:
-            moe_routing.append(routing)
-        return x + y
+        with jax.named_scope("block/mlp"):
+            h = _blocks.norm(arch, x, blk, "ln2")
+            y, routing = _blocks.ffn(arch, layer, h, blk, axis_name, live)
+            if routing is not None:
+                moe_routing.append(routing)
+            return x + y
 
     def block_with(x, blk, positions, attend, layer: int = 0):
         """Shared block scaffolding: ln1 → qkv projection (+rope) →
@@ -115,18 +123,21 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
         serves the physical-cache path and the lazy-beam path; only the
         score/context stage differs."""
         n, s_q = x.shape[0], x.shape[1]
-        h = _blocks.norm(arch, x, blk, "ln1")
         a = blk["attn"]
-        q, k, v = _project_qkv(h, a, head_dim, axis_name, arch.attn_bias)
-        turn = arch.rotary[layer] if arch.rotary is not None else None
-        if turn is not None:     # the layer's own rotation (theta, the
-            #                      rotated fraction, YaRN)
-            q = _blocks.rotate(turn, q, positions)
-            k = _blocks.rotate(turn, k, positions)
-        elif rope:
-            q = apply_rope(q, positions)
-            k = apply_rope(k, positions)
-        ctx, extras = attend(q, k, v)
+        with jax.named_scope("block/attn/proj"):
+            h = _blocks.norm(arch, x, blk, "ln1")
+            q, k, v = _project_qkv(h, a, head_dim, axis_name, arch.attn_bias)
+            turn = arch.rotary[layer] if arch.rotary is not None else None
+            if turn is not None:     # the layer's own rotation (theta, the
+                #                      rotated fraction, YaRN)
+                q = _blocks.rotate(turn, q, positions)
+                k = _blocks.rotate(turn, k, positions)
+            elif rope:
+                q = apply_rope(q, positions)
+                k = apply_rope(k, positions)
+        # the attend stage's own cache append nests as .../core/cache_write
+        with jax.named_scope("block/attn/core"):
+            ctx, extras = attend(q, k, v)
         if arch.attn_gate:
             # per-head sigmoid gate from the attention's own input, on the
             # context, before the output projection
@@ -135,11 +146,12 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
                     h, a["wg"], preferred_element_type=jnp.float32))
                 ctx = (ctx.reshape(n, s_q, -1, head_dim).astype(jnp.float32)
                        * gate[..., None]).astype(x.dtype)
-        ctx = ctx.reshape(n, s_q, -1)
-        attn_out = row_parallel_dense(
-            ctx, a["wo"], a["bo"] if arch.attn_bias else None,
-            axis_name=axis_name)
-        return (second_half(x + attn_out, blk, layer),) + extras
+        with jax.named_scope("block/attn/proj"):
+            ctx = ctx.reshape(n, s_q, -1)
+            x = x + row_parallel_dense(
+                ctx, a["wo"], a["bo"] if arch.attn_bias else None,
+                axis_name=axis_name)
+        return (second_half(x, blk, layer),) + extras
 
     def mla_block(x, blk, cache, positions, write_at, q_valid, layer, work):
         """The MLA layer: the token's latent row is written to ``cache``
@@ -153,10 +165,11 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
         cfg = arch.mla
         n, s_q = x.shape[0], x.shape[1]
         with jax.named_scope("block/mla"):
-            h = _blocks.norm(arch, x, blk, "ln1")
-            q_nope, q_rope, c_kv, k_rope = _blocks.mla_project(
-                cfg, h, blk["attn"], positions, arch.norm_eps)
-            rows = _blocks.mla_latent_rows(cfg, c_kv, k_rope)
+            with jax.named_scope("proj"):
+                h = _blocks.norm(arch, x, blk, "ln1")
+                q_nope, q_rope, c_kv, k_rope = _blocks.mla_project(
+                    cfg, h, blk["attn"], positions, arch.norm_eps)
+                rows = _blocks.mla_latent_rows(cfg, c_kv, k_rope)
             with jax.named_scope("cache_write"):
                 cache = _write_rows(cache, rows.astype(cache.dtype),
                                     write_at)
@@ -166,20 +179,24 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
                     cfg, q_nope, q_rope, c_kv, k_rope, blk["attn"],
                     resolve_attn_impl("auto", s_q))
             else:
-                valid = (jnp.asarray(q_valid, jnp.int32).reshape(-1, 1)
-                         + jnp.arange(s_q, dtype=jnp.int32)[None] + 1)
-                valid = jnp.broadcast_to(valid, (n, s_q))
-                use_kernel = (s_q == 1 and jax.default_backend() == "tpu"
-                              and _pick_block_s(cache.shape[1]) > 0)
+                with jax.named_scope("core"):
+                    valid = (jnp.asarray(q_valid, jnp.int32).reshape(-1, 1)
+                             + jnp.arange(s_q, dtype=jnp.int32)[None] + 1)
+                    valid = jnp.broadcast_to(valid, (n, s_q))
+                    use_kernel = (s_q == 1
+                                  and jax.default_backend() == "tpu"
+                                  and _pick_block_s(cache.shape[1]) > 0)
+                    busy = busy_rows() if s_q == 1 else None
+                    lists = tick_work(work, valid[:, 0] - 1, n,
+                                      cache.shape[1]) if use_kernel else None
                 ctx = _blocks.mla_attend_absorbed(
                     cfg, q_nope, q_rope, cache, valid, blk["attn"],
-                    use_kernel, busy_rows() if s_q == 1 else None,
-                    tick_work(work, valid[:, 0] - 1, n, cache.shape[1])
-                    if use_kernel else None)
-            attn_out = jnp.matmul(
-                ctx, blk["attn"]["wo"],
-                preferred_element_type=jnp.float32).astype(x.dtype)
-        return second_half(x + attn_out, blk, layer), cache
+                    use_kernel, busy, lists)
+            with jax.named_scope("proj"):
+                x = x + jnp.matmul(
+                    ctx, blk["attn"]["wo"],
+                    preferred_element_type=jnp.float32).astype(x.dtype)
+        return second_half(x, blk, layer), cache
 
     def kda_block(x, blk, state, window, layer):
         """The gated delta-rule layer: no rows, a state a sequence.  One
@@ -190,10 +207,13 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
         from .kda import kda_layer
 
         with jax.named_scope("block/kda"):
-            h = _blocks.norm(arch, x, blk, "ln1")
+            with jax.named_scope("proj"):
+                h = _blocks.norm(arch, x, blk, "ln1")
             y, state, window = kda_layer(arch.kda, h, blk["attn"], state,
                                          window, live, arch.norm_eps)
-        return second_half(x + y, blk, layer), state, window
+            with jax.named_scope("proj"):
+                x = x + y
+        return second_half(x, blk, layer), state, window
 
     def attn_block(x, blk, k_cache, v_cache, positions, write_at, q_valid,
                    layer: int = 0, work=None):
@@ -423,13 +443,15 @@ def _prefill(params, embed, attn_block, prompt, total: int, head_dim: int):
     b, s_p = prompt.shape
     layout = _blocks.cache_layout(arch, len(params["blocks"]),
                                   _kv_heads(params, head_dim) * head_dim, "")
-    positions = jnp.arange(s_p)
-    x = embed(prompt, positions)
+    with jax.named_scope("prefill/embed"):
+        positions = jnp.arange(s_p)
+        x = embed(prompt, positions)
     caches = []
     for i, (blk, bufs) in enumerate(zip(params["blocks"], layout)):
-        zeros = [jnp.zeros(_blocks.buffer_shape(buf, b, total),
-                           (buf[1] if _blocks.is_state(buf) else None)
-                           or x.dtype) for buf in bufs]
+        with jax.named_scope("cache_write"):
+            zeros = [jnp.zeros(_blocks.buffer_shape(buf, b, total),
+                               (buf[1] if _blocks.is_state(buf) else None)
+                               or x.dtype) for buf in bufs]
         x, new = _run_layer(attn_block, x, blk, zeros, positions, 0, 0, i)
         if arch.window(i):
             # a ring is a gather of the layer's k and v that nothing wants
@@ -438,9 +460,11 @@ def _prefill(params, embed, attn_block, prompt, total: int, head_dim: int):
             # layer's (S, columns) k and v alive until then (0.9 GB more
             # temporaries at 40 layers and S = 3072: my ahead-of-time
             # compile, PR 33)
-            x, new = jax.lax.optimization_barrier((x, new))
+            with jax.named_scope("cache_write"):
+                x, new = jax.lax.optimization_barrier((x, new))
         caches.append(new)
-    return _blocks.norm(arch, x, params, "lnf"), caches
+    with jax.named_scope("prefill/head"):
+        return _blocks.norm(arch, x, params, "lnf"), caches
 
 
 def _greedy_token(table, h_last, axis_name: str):
@@ -556,7 +580,8 @@ def lm_prefill(params, prompt, total: int, *, head_dim: int, axis_name: str,
                                                arch, live)
     _check_length(params, total, rope)
     out = _prefill(params, embed, attn_block, prompt, total, head_dim)
-    return out + (_routing(attn_block),) if with_routing else out
+    with jax.named_scope("prefill/head"):
+        return out + (_routing(attn_block),) if with_routing else out
 
 
 def lm_decode_tick(params, tokens, caches, pos, *, head_dim: int,
@@ -580,26 +605,28 @@ def lm_decode_tick(params, tokens, caches, pos, *, head_dim: int,
     of the live rows' blocks, built once a cache shape and shared by the
     layers).
     """
-    embed, attn_block, _, _ = _decoder_core(
-        params, head_dim, axis_name, arch,
-        None if live is None else live[:, None])
-    arch = attn_block.arch
     per_row = getattr(pos, "ndim", 0) == 1
-    positions = pos[:, None] if per_row else pos[None]
     with jax.named_scope("tick/embed"):
+        embed, attn_block, _, _ = _decoder_core(
+            params, head_dim, axis_name, arch,
+            None if live is None else live[:, None])
+        arch = attn_block.arch
+        positions = pos[:, None] if per_row else pos[None]
         x = embed(tokens[:, None], positions)
     new_caches = []
     work = {}       # rows of a cache -> its work list, for every layer
     for i, (blk, bufs) in enumerate(zip(params["blocks"], caches)):
-        # the block's cache append nests as tick/attn/cache_write
-        with jax.named_scope("tick/attn"):
+        # one whole layer, attention half and FFN half: the halves and
+        # their stages are told apart by the block's own scopes below it
+        # (``block/attn/proj``, ``.../core/cache_write``, ``block/mlp``, ...)
+        with jax.named_scope("tick/layer"):
             x, new = _run_layer(attn_block, x, blk, bufs, positions, pos,
                                 pos, i, work)
         new_caches.append(new)
     with jax.named_scope("tick/head"):
         h = _blocks.norm(arch, x, params, "lnf")
-    out = (h[:, -1], new_caches)
-    return out + (_routing(attn_block),) if with_routing else out
+        out = (h[:, -1], new_caches)
+        return out + (_routing(attn_block),) if with_routing else out
 
 
 def _make_face(mesh: Optional[Mesh], axis_name: str, inner, has_rng: bool,

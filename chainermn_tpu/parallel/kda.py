@@ -76,9 +76,9 @@ def kda_project(cfg, h, a, window, live):
     is not in the window."""
     b, s, _ = h.shape
     nh, dk, dv, r = cfg.n_heads, cfg.head_dim, cfg.head_dim, cfg.gate_rank
-    n_real = (jnp.full((b,), s, jnp.int32) if live is None
-              else live.sum(-1).astype(jnp.int32))
     with jax.named_scope("conv"):
+        n_real = (jnp.full((b,), s, jnp.int32) if live is None
+                  else live.sum(-1).astype(jnp.int32))
         mixed = _dense(h, a["wqkv"])
         y, window = _short_conv(window, mixed, a["conv"], n_real)
         y = jax.nn.silu(y)
@@ -206,5 +206,6 @@ def kda_layer(cfg, h, a, state, window, live, eps: float):
             o = o[:, None]
         else:
             o, state = kda_chunked(q, k, v, g, beta, state, cfg.chunk)
-    return (kda_output(cfg, o, gate, a, eps, h.dtype), state,
-            new_window.astype(window.dtype))
+    with jax.named_scope("proj"):       # head norm, output gate, W_o
+        return (kda_output(cfg, o, gate, a, eps, h.dtype), state,
+                new_window.astype(window.dtype))

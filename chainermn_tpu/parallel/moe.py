@@ -247,39 +247,44 @@ def _held_experts_product(x, p, idx, gates, first, n_held: int,
     the held experts (tiny CPU sizes)."""
     t, d = x.shape
     k = idx.shape[1]
-    local = idx - first
-    is_held = (local >= 0) & (local < n_held)
-    onehot = (jnp.where(is_held, local, n_held).reshape(-1, 1)
-              == jnp.arange(n_held)[None, :])                  # (T·k, E_h)
-    counts = onehot.sum(0).astype(jnp.int32)
+    # the index work between the routing and the product: which choices
+    # are held here, each one's place in its expert's tile-aligned group
+    with jax.named_scope("block/moe/dispatch"):
+        local = idx - first
+        is_held = (local >= 0) & (local < n_held)
+        onehot = (jnp.where(is_held, local, n_held).reshape(-1, 1)
+                  == jnp.arange(n_held)[None, :])              # (T·k, E_h)
+        counts = onehot.sum(0).astype(jnp.int32)
     if not use_kernel:
         from .blocks import swiglu
-        y = jnp.zeros((t, d), jnp.float32)
-        for e in range(n_held):
-            g_e = jnp.where(local == e, gates, 0.0).sum(-1)      # (T,)
-            y_e = swiglu(x, {n: p[n][e] for n in
-                             ("w_gate", "w_up", "w_down")})
-            y = y + y_e.astype(jnp.float32) * g_e[:, None]
+        with jax.named_scope("block/moe/gmm"):
+            y = jnp.zeros((t, d), jnp.float32)
+            for e in range(n_held):
+                g_e = jnp.where(local == e, gates, 0.0).sum(-1)  # (T,)
+                y_e = swiglu(x, {n: p[n][e] for n in
+                                 ("w_gate", "w_up", "w_down")})
+                y = y + y_e.astype(jnp.float32) * g_e[:, None]
         return y, counts
 
     from ..ops.moe_gmm import moe_gmm
     a = t * k
     tm = _row_tile(a)
     m_pad = -(-(a + n_held * (tm - 1)) // tm) * tm
-    padded = -(-counts // tm) * tm
-    ends = jnp.cumsum(padded)
-    starts = ends - padded
-    rank = ((jnp.cumsum(onehot, axis=0) - 1) * onehot).sum(-1)   # (T·k,)
-    flat_held = is_held.reshape(-1)
-    dest = jnp.where(flat_held,
-                     starts[jnp.clip(local.reshape(-1), 0, n_held - 1)]
-                     + rank, m_pad).astype(jnp.int32)
-    row_token = jnp.zeros((m_pad,), jnp.int32).at[dest].set(
-        jnp.repeat(jnp.arange(t, dtype=jnp.int32), k), mode="drop")
-    tile_expert = jnp.minimum(jnp.searchsorted(
-        ends, jnp.arange(m_pad // tm, dtype=jnp.int32) * tm, side="right"),
-        n_held - 1)
-    n_valid = ends[-1] // tm
+    with jax.named_scope("block/moe/dispatch"):
+        padded = -(-counts // tm) * tm
+        ends = jnp.cumsum(padded)
+        starts = ends - padded
+        rank = ((jnp.cumsum(onehot, axis=0) - 1) * onehot).sum(-1)  # (T·k,)
+        flat_held = is_held.reshape(-1)
+        dest = jnp.where(flat_held,
+                         starts[jnp.clip(local.reshape(-1), 0, n_held - 1)]
+                         + rank, m_pad).astype(jnp.int32)
+        row_token = jnp.zeros((m_pad,), jnp.int32).at[dest].set(
+            jnp.repeat(jnp.arange(t, dtype=jnp.int32), k), mode="drop")
+        tile_expert = jnp.minimum(jnp.searchsorted(
+            ends, jnp.arange(m_pad // tm, dtype=jnp.int32) * tm,
+            side="right"), n_held - 1)
+        n_valid = ends[-1] // tm
     with jax.named_scope("block/moe/gmm"):
         xs = jnp.take(x, row_token, axis=0)                      # (M, D)
         gmm = lambda lhs, w: moe_gmm(lhs, w, tile_expert, n_valid, tm=tm,
@@ -336,10 +341,12 @@ def moe_dropless(x, params, cfg, *, live=None,
         x, params, idx, gates, first, n_held, use_kernel, bool(interpret))
     with jax.named_scope("block/moe/shared"):
         y = y + swiglu(x, params["shared"]).astype(jnp.float32)
-    n_rows = jnp.int32(x.shape[0]) if live is None else live.sum()
-    counts = jnp.concatenate([
-        jnp.stack([(n_rows * idx.shape[1]).astype(jnp.int32),
-                   per_expert.sum(),
-                   (per_expert > 0).sum().astype(jnp.int32)]),
-        per_expert])
-    return y.astype(x.dtype), counts, idx
+    with jax.named_scope("block/moe/route"):    # the routing's counts
+        n_rows = jnp.int32(x.shape[0]) if live is None else live.sum()
+        counts = jnp.concatenate([
+            jnp.stack([(n_rows * idx.shape[1]).astype(jnp.int32),
+                       per_expert.sum(),
+                       (per_expert > 0).sum().astype(jnp.int32)]),
+            per_expert])
+    with jax.named_scope("block/moe/shared"):
+        return y.astype(x.dtype), counts, idx

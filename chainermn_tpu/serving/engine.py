@@ -164,8 +164,9 @@ class DecodeEngine:
                          live):
             # a slot's token: the host's where it hands one in, else what
             # the tick before chose for the slot (``prev``: its whole result)
-            tokens = jnp.where(override >= 0, override,
-                               prev[:override.shape[0]])
+            with jax.named_scope("tick/embed"):
+                tokens = jnp.where(override >= 0, override,
+                                   prev[:override.shape[0]])
             h_last, new_caches, routing = lm_decode_tick(
                 params, tokens, caches, pos, head_dim=head_dim,
                 axis_name=axis, arch=arch, live=live, with_routing=True)
@@ -175,7 +176,7 @@ class DecodeEngine:
             with jax.named_scope("tick/head"):
                 nxt = _next_token(_blocks.head_table(arch, params), h_last,
                                   axis, keys, temps, pos + 1)
-            return _with_routing(nxt, routing), new_caches
+                return _with_routing(nxt, routing), new_caches
 
         # ``live``: the slots that carry a request's token — the attention
         # reads only their cache, expert layers route only them, state
@@ -202,26 +203,30 @@ class DecodeEngine:
             # every real row and never read back (causal + pos mask).  A
             # state is not rows, nor is a ring: the pads must not move
             # them, and go to no expert (``real``)
-            real = (jnp.arange(s_pad) < s_real)[None]
+            with jax.named_scope("prefill/embed"):
+                real = (jnp.arange(s_pad) < s_real)[None]
             h, slabs, routing = lm_prefill(
                 params, prompt, s_pad, head_dim=head_dim, axis_name=axis,
                 arch=arch, live=real, with_routing=True)
-            if routing is not None:   # the routes of the emitting position
-                routing = (routing[0], jax.lax.dynamic_index_in_dim(
-                    routing[1], s_real - 1, axis=1, keepdims=False))
-            h_last = jax.lax.dynamic_index_in_dim(h, s_real - 1, axis=1,
-                                                  keepdims=False)
-            # first generated token = position s_real (lm_generate's
-            # first = logits_next(h[:, -1], s_p) salt)
-            tok = _next_token(_blocks.head_table(arch, params), h_last,
-                              axis, key[None], temp[None], s_real[None])
+            with jax.named_scope("prefill/head"):
+                if routing is not None:     # the emitting position's routes
+                    routing = (routing[0], jax.lax.dynamic_index_in_dim(
+                        routing[1], s_real - 1, axis=1, keepdims=False))
+                h_last = jax.lax.dynamic_index_in_dim(h, s_real - 1, axis=1,
+                                                      keepdims=False)
+                # first generated token = position s_real (lm_generate's
+                # first = logits_next(h[:, -1], s_p) salt)
+                tok = _next_token(_blocks.head_table(arch, params), h_last,
+                                  axis, key[None], temp[None], s_real[None])
             # every buffer a layer declares gets its slab, at the slot's
             # rows [0, s_pad) — or the slot's whole state, or its whole ring
-            new_caches = jax.tree_util.tree_map(
-                lambda c, slab: jax.lax.dynamic_update_slice(
-                    c, slab.astype(c.dtype), (slot,) + (0,) * (c.ndim - 1)),
-                caches, slabs)
-            return _with_routing(tok, routing), new_caches
+            with jax.named_scope("cache_write"):
+                new_caches = jax.tree_util.tree_map(
+                    lambda c, slab: jax.lax.dynamic_update_slice(
+                        c, slab.astype(c.dtype),
+                        (slot,) + (0,) * (c.ndim - 1)), caches, slabs)
+            with jax.named_scope("prefill/head"):
+                return _with_routing(tok, routing), new_caches
 
         prefill_inner.__name__ = f"serving_prefill_{s_pad}"
         return jax.jit(self._shard_map(

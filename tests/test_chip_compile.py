@@ -253,6 +253,11 @@ def test_lm_135m_train_step_in_shard_map(topo, as_tpu):
     for kernel in ("flash_fwd", "flash_bwd", "fused_ce_stats",
                    "fused_ce_dh", "fused_ce_dtable"):
         assert f"%{kernel}" in text, kernel
+    # the step's scopes, by which ``step_ms.*`` split it: the phases and,
+    # inside ``loss_grad``, the blocks' (autodiff wraps each: ``jvp(...)``)
+    _assert_scopes(text, "/loss_grad/", "/optimizer/", "jvp(embed)",
+                   "transpose(jvp(block/attn))", "transpose(jvp(block/mlp))",
+                   "jvp(head_ce)")
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < 16 * 2 ** 30)      # fits one v5e chip's HBM
@@ -268,6 +273,22 @@ def _assert_pool_written_in_place(text: str, pool_shape) -> None:
     dims = ",".join(str(n) for n in pool_shape)
     copies = re.findall(rf"= bf16\[{dims}\]\S* copy\(", text)
     assert not copies, f"{len(copies)} pool-sized copies left"
+
+
+def _assert_scopes(text: str, *scopes, tick: bool = False) -> None:
+    """The compiled program's ``op_name`` metadata holds each scope path —
+    what the chip's trace carries as ``tf_op`` and
+    ``benchmark/harness/scope_trace.py::BUCKETS`` books by
+    (docs/OBSERVABILITY.md has the vocabulary).  A tick wraps each whole
+    layer in ``tick/layer`` — until PR 35 ``tick/attn``, a name that was
+    wrong for the FFN half it also covered — with the token pick and the
+    embedding under ``tick/embed`` and the final norm, the logits and the
+    selection under ``tick/head``."""
+    if tick:
+        assert "tick/attn" not in text
+        scopes += ("tick/embed", "tick/head")
+    for scope in scopes:
+        assert scope in text, scope
 
 
 # (heads, head_dim, slots, prompt, total): the LM width chip_smoke runs,
@@ -308,6 +329,9 @@ def test_serving_prefill_and_tick(topo, as_tpu, shape):
     assert prefill.as_text().count("tpu_custom_call") >= N_LAYERS
     assert f"HloModule jit_serving_prefill_{prompt}" in prefill.as_text()
     assert "%flash_fwd" in prefill.as_text()
+    _assert_scopes(prefill.as_text(), "prefill/embed", "prefill/head",
+                   "block/attn/proj", "block/attn/core", "cache_write",
+                   "block/mlp")
     _assert_pool_written_in_place(prefill.as_text(),
                                   (n_slots, total, D_MODEL))
 
@@ -323,6 +347,11 @@ def test_serving_prefill_and_tick(topo, as_tpu, shape):
     # length bounds: a list or a bound Mosaic refused would fail here
     assert "%decode_attn_mha" in tick.as_text()
     assert tick.as_text().count("tpu_custom_call") >= N_LAYERS
+    _assert_scopes(tick.as_text(), "tick/layer/block/attn/proj",
+                   "tick/layer/block/attn/core/cache_write",
+                   "block/attn/core/tick/work_list",
+                   "block/attn/core/jit(decode_attend)/decode_attn_mha",
+                   "tick/layer/block/mlp", tick=True)
     _assert_pool_written_in_place(tick.as_text(), (n_slots, total, D_MODEL))
 
 
@@ -401,6 +430,12 @@ def test_latent_attention_and_expert_serving_programs(topo, as_tpu):
     assert "HloModule jit_serving_tick" in tick
     assert tick.count("%decode_attn_mla") >= layers
     assert tick.count("%moe_gmm") >= 3          # gate, up, down
+    _assert_scopes(tick, "tick/layer/block/mla/proj",
+                   "tick/layer/block/mla/cache_write",
+                   "block/mla/core/tick/work_list", "decode_attn_mla",
+                   "tick/layer/block/mlp/block/moe/route",
+                   "block/mlp/block/moe/dispatch", "block/mlp/block/moe/gmm",
+                   "block/mlp/block/moe/shared", tick=True)
     _assert_pool_written_in_place(tick, (n_slots, total, 640))
 
     prefill = eng._build_prefill(prompt).lower(
@@ -411,6 +446,9 @@ def test_latent_attention_and_expert_serving_programs(topo, as_tpu):
     assert f"HloModule jit_serving_prefill_{prompt}" in prefill
     assert prefill.count("%flash_fwd") >= layers
     assert prefill.count("%moe_gmm") >= 3
+    _assert_scopes(prefill, "prefill/embed", "prefill/head",
+                   "block/mla/proj", "block/mla/core", "cache_write",
+                   "block/moe/route", "block/moe/dispatch", "block/moe/gmm")
     _assert_pool_written_in_place(prefill, (n_slots, total, 640))
 
 
@@ -518,9 +556,14 @@ def test_state_and_row_layers_in_one_pool_serving_programs(topo, as_tpu):
     # no reader of an accepted metric may match the new kernel by substring
     assert not any(n in "kda_step" for n in ("decode_attn", "moe_gmm",
                                              "flash"))
-    for scope in ("block/kda", "conv", "gate", "state_update", "tick/attn",
-                  "block/mla", "block/moe"):
-        assert scope in text, scope
+    _assert_scopes(text, "tick/layer/block/kda/proj",
+                   "tick/layer/block/kda/conv", "tick/layer/block/kda/gate",
+                   "block/kda/state_update/jit(kda_step)/kda_step",
+                   "tick/layer/block/mla/proj", "block/mla/cache_write",
+                   "block/mla/core/tick/work_list",
+                   "tick/layer/block/mlp/block/moe/route",
+                   "block/moe/dispatch", "block/moe/gmm", "block/moe/shared",
+                   tick=True)
     _assert_pool_written_in_place(text, (64, 4096, 640))
     import re
     assert not re.findall(r"= f32\[64,32,128,128\]\S* copy\(", text)
@@ -533,8 +576,9 @@ def test_state_and_row_layers_in_one_pool_serving_programs(topo, as_tpu):
     assert "HloModule jit_serving_prefill_2048" in pre
     assert pre.count("%flash_fwd") >= 1 and pre.count("%moe_gmm") >= 3
     assert "kda_step" not in pre            # the chunked form, not the step
-    for scope in ("block/kda", "state_update"):
-        assert scope in pre, scope
+    _assert_scopes(pre, "block/kda/proj", "block/kda/conv",
+                   "block/kda/gate", "block/kda/state_update",
+                   "block/mla/core", "prefill/head")
     _assert_pool_written_in_place(pre, (64, 4096, 640))
     # the whole model's arguments with the widest prefill's temporaries:
     # under 15.0 GB, the line ISSUE 31 draws for 64 slots
@@ -633,9 +677,12 @@ def test_ring_and_row_layers_in_one_pool_serving_programs(topo, as_tpu):
     # no reader of another model's metric may match the new names
     for name in ("decode_attn_gqa", "window_flash_fwd"):
         assert not any(n in name for n in ("mla", "moe_gmm", "kda"))
-    for scope in ("tick/attn", "block/attn/window", "block/attn/gate",
-                  "cache_write", "block/moe"):
-        assert scope in text, scope
+    _assert_scopes(text, "tick/layer/block/attn/proj",
+                   "block/attn/core/block/attn/window/cache_write",
+                   "tick/layer/block/attn/core/cache_write",
+                   "block/attn/core/tick/work_list", "decode_attn_gqa",
+                   "tick/layer/block/attn/gate",
+                   "tick/layer/block/mlp/block/moe/dispatch", tick=True)
     _assert_pool_written_in_place(text, (24, 4096, 1024))
     _assert_pool_written_in_place(text, (24, 512, 1024))
     # 8.0 GB of weights + 5.54 GB of pool (rows 4.03, rings 1.51)
@@ -647,8 +694,9 @@ def test_ring_and_row_layers_in_one_pool_serving_programs(topo, as_tpu):
     assert pre.count("%window_flash_fwd") >= 30
     assert pre.count("%flash_fwd") >= 10 and pre.count("%moe_gmm") >= 3 * 39
     assert "decode_attn" not in pre
-    for scope in ("block/attn/window", "block/attn/gate", "cache_write"):
-        assert scope in pre, scope
+    _assert_scopes(pre, "block/attn/core/block/attn/window",
+                   "block/attn/gate", "cache_write", "block/attn/proj",
+                   "prefill/embed")
     _assert_pool_written_in_place(pre, (24, 4096, 1024))
     _assert_pool_written_in_place(pre, (24, 512, 1024))
     # arguments plus the widest prefill's temporaries: under 15.0 GB, the

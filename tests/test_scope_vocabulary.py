@@ -1,0 +1,255 @@
+"""The program's ``jax.named_scope`` vocabulary is a PARTITION (ISSUE 35).
+
+``benchmark/harness/scope_trace.py`` books a traced program's device time to
+the scope each operation was written under, so every equation of the served
+programs and of the train step has to lie under a scope its ``BUCKETS`` table
+names, and the attention half and the FFN half of a block must be told apart.
+Walked here on the jaxprs (the scopes are the equations' name stacks: the same
+strings the compiler writes into the HLO's ``op_name``), at the tiny sizes the
+four ``tests/test_*_serving.py`` files build, with ``jax.default_backend``
+steered to the TPU side so the kernel paths — the ones the chip runs — are the
+ones walked (tracing a Pallas call needs no chip).
+"""
+
+import importlib.util
+import json
+import os
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import PartitionSpec as P
+
+import chainermn_tpu as mn
+from benchmark.harness.scope_trace import (BUCKETS, PHASE, bucket_of,
+                                           leaf_of, scope_path)
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+#: architecture -> the serving test file whose tiny model it is
+FIXTURES = {"gpt2": None, "mla+moe": "test_deepseek_serving",
+            "kda+mla+moe": "test_kimi_linear_serving",
+            "window-gqa+moe": "test_laguna_serving"}
+SERVED = {"cache_write", "attn_proj", "attn_core", "ffn_dense", "embed_head"}
+EXPERTS = {"moe_route", "moe_experts"}
+#: what an equation of an attention half, and of an FFN half, lies under
+ATTENTION = ("tick/work_list", "cache_write", "block/attn/", "block/mla/",
+             "block/kda/")
+FFN = ("block/mlp", "block/moe/")
+
+
+def _fixture(name):
+    spec = importlib.util.spec_from_file_location(
+        "scope_" + name, os.path.join(HERE, name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def paths_of(jaxpr, prefix=""):
+    """The scope path of every equation, sub-jaxprs walked in place of the
+    equation that holds them (a Pallas kernel's body is one operation)."""
+    for eqn in jaxpr.eqns:
+        path = prefix + str(eqn.source_info.name_stack)
+        inner = []
+        if eqn.primitive.name != "pallas_call":
+            for value in eqn.params.values():
+                for x in (value if isinstance(value, (tuple, list))
+                          else (value,)):
+                    x = getattr(x, "jaxpr", x)
+                    if hasattr(x, "eqns"):
+                        inner.append(x)
+        if inner:
+            for sub in inner:
+                yield from paths_of(sub, path + "/")
+        else:
+            yield f"{path}/{eqn.primitive.name}"
+
+
+def _served_programs(arch_name, devices):
+    from chainermn_tpu.serving import ServingEngine
+    from chainermn_tpu.serving.engine import result_size
+
+    mesh = mn.make_nd_mesh(("model",), (1,), devices[:1])
+    if FIXTURES[arch_name] is None:
+        params = mn.parallel.init_tp_transformer_lm(
+            jax.random.PRNGKey(0), 32, 16, 4, 2, max_len=64)
+        eng = ServingEngine(params, head_dim=4, mesh=mesh, n_slots=4,
+                            max_total=48, prefill_bucket=8, queue_capacity=8,
+                            spill_bytes=0)
+    else:
+        mod = _fixture(FIXTURES[arch_name])
+        eng = mod._engine(mod.ref.init_params(jax.random.PRNGKey(3), mod.CFG,
+                                              jnp.float32), mesh)
+    dec, n = eng.engine, eng.pool.n_slots
+    caches = eng.pool.read(lambda c: c)
+    tick = jax.make_jaxpr(dec._tick_prog)(
+        dec._params, caches, np.zeros(result_size(dec.arch, n), np.int32),
+        np.zeros(n, np.int32), np.zeros(n, np.int32),
+        np.zeros((n, 2), np.uint32), np.zeros(n, np.float32),
+        np.ones(n, bool))
+    prefill = jax.make_jaxpr(dec._build_prefill(16))(
+        dec._params, caches, np.zeros((1, 16), np.int32), jnp.int32(9),
+        jnp.int32(1), np.zeros(2, np.uint32), jnp.float32(0))
+    return {"serving_tick": tick, "serving_prefill": prefill}
+
+
+def _train_step(devices):
+    import optax
+
+    from chainermn_tpu.parallel import (init_tp_transformer_lm,
+                                        make_hybrid_shard_map_step,
+                                        tp_transformer_lm_loss,
+                                        transformer_lm_specs)
+
+    mesh = mn.make_nd_mesh(("data", "model"), (1, 1), devices[:1])
+    params = init_tp_transformer_lm(jax.random.PRNGKey(0), 32, 16, 4, 2,
+                                    max_len=16)
+    optimizer = optax.adamw(1e-3)
+    step = make_hybrid_shard_map_step(
+        partial(tp_transformer_lm_loss, head_dim=4, axis_name="model"),
+        optimizer, mesh, params, transformer_lm_specs(params, "model"),
+        data_axis="data", batch_spec=P("data"), donate=False)
+    return jax.make_jaxpr(step)(params, optimizer.init(params),
+                                (np.zeros((2, 17), np.int32),))
+
+
+CASES = [(a, p) for a in FIXTURES for p in ("serving_tick", "serving_prefill")
+         ] + [("gpt2", "train_step")]
+
+
+@pytest.fixture(scope="module")
+def programs(devices):
+    """Each architecture's programs traced once, on first use."""
+    traced = {}
+
+    def get(arch_name, program, monkeypatch):
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        if program == "train_step":
+            return _train_step(devices)
+        if arch_name not in traced:
+            traced[arch_name] = _served_programs(arch_name, devices)
+        return traced[arch_name][program]
+
+    return get
+
+
+@pytest.mark.parametrize("arch_name,program", CASES,
+                         ids=[f"{a}-{p}" for a, p in CASES])
+def test_every_equation_lies_in_one_named_bucket(programs, monkeypatch,
+                                                 arch_name, program):
+    paths = list(paths_of(programs(arch_name, program, monkeypatch).jaxpr))
+    assert len(paths) > 100
+    unscoped = [p for p in paths if bucket_of(p) is None]
+    assert not unscoped, unscoped[:10]
+    found = {bucket_of(p) for p in paths}
+    if program == "train_step":
+        assert found == {"fwd", "bwd", "optimizer"}
+        leaves = {leaf_of(p) for p in paths}
+        for phase in PHASE:     # each phase is split by the block's scopes
+            assert {(phase, s) for s in ("embed", "block/attn", "block/mlp",
+                                         "head_ce")} <= leaves
+        return
+    want = SERVED | (EXPERTS if "moe" in arch_name else set())
+    assert found == want
+    scopes = {leaf_of(p)[1] for p in paths}
+    assert ("tick/work_list" in scopes) == (program == "serving_tick")
+    if "moe" in arch_name:
+        assert {"block/moe/route", "block/moe/dispatch", "block/moe/gmm",
+                "block/moe/shared"} <= scopes
+    if "kda" in arch_name:
+        assert {"block/kda/conv", "block/kda/gate", "block/kda/state_update",
+                "block/kda/proj", "block/mla/core"} <= scopes
+    if "window" in arch_name:
+        assert "block/attn/gate" in scopes
+        assert any("/block/attn/window/" in scope_path(p) for p in paths)
+    # the halves of a block are told apart: nothing of an FFN half lies
+    # under an attention scope (``tick/attn`` wrapped both until PR 35)
+    for p in paths:
+        path = scope_path(p)
+        assert "/tick/attn/" not in path, p
+        if any(f"/{s}" in path for s in FFN):
+            assert not any(f"/{s}" in path for s in ATTENTION), p
+    if program == "serving_tick":       # one wrapper a layer, and no other
+        layered = [p for p in paths if "/tick/layer/" in scope_path(p)]
+        assert {bucket_of(p) for p in layered} == want - {"embed_head"}
+
+
+UNIT = [
+    ("jit(serving_tick)/jit(main)/jit(shmap_body)/tick/embed/select_n",
+     "embed_head", "tick/embed"),
+    ("jit(serving_tick)/tick/head/cond/branch_1_fun/argmax:",
+     "embed_head", "tick/head"),
+    ("jit(serving_tick)/tick/layer/block/attn/core/cache_write/scatter",
+     "cache_write", "cache_write"),
+    ("jit(serving_tick)/tick/layer/block/attn/core/block/attn/window/"
+     "decode_attn_gqa", "attn_core", "block/attn/core"),
+    ("jit(serving_tick)/tick/layer/block/mla/core/tick/work_list/cumsum",
+     "attn_core", "tick/work_list"),
+    ("jit(serving_tick)/tick/layer/block/attn/gate/logistic",
+     "attn_proj", "block/attn/gate"),
+    ("jit(serving_tick)/tick/layer/block/kda/gate/logistic",
+     "attn_core", "block/kda/gate"),
+    ("jit(serving_tick)/tick/layer/block/kda/proj/dot_general",
+     "attn_proj", "block/kda/proj"),
+    ("jit(serving_tick)/tick/layer/block/mlp/block/moe/dispatch/while/body/"
+     "add", "moe_route", "block/moe/dispatch"),
+    ("jit(serving_tick)/tick/layer/block/mlp/block/moe/gmm/moe_gmm",
+     "moe_experts", "block/moe/gmm"),
+    ("jit(serving_tick)/tick/layer/block/mlp/block/moe/shared/dot_general",
+     "ffn_dense", "block/moe/shared"),
+    ("jit(serving_tick)/tick/layer/block/mlp/dot_general:",
+     "ffn_dense", "block/mlp"),
+    ("jit(serving_prefill_1024)/prefill/head/dot_general",
+     "embed_head", "prefill/head"),
+    # a wrapper alone names no bucket, nor does a path without scopes
+    ("jit(serving_tick)/tick/layer/add", None, None),
+    ("jit(serving_tick)/tick/attn/block/mla/dot_general", None, None),
+    ("jit(serving_tick)/jit(main)/convert_element_type", None, None),
+    ("", None, None),
+    # whole components only: ``embed`` is not ``tick/embed``'s bucket
+    ("jit(f)/attick/embedding/add", None, None),
+    # the train step, by phase; the leaf is the block's own scope
+    ("jit(train_step)/jit(main)/optimizer/mul", "optimizer", "optimizer"),
+    ("jit(train_step)/loss_grad/jvp(block/attn)/dot_general",
+     "fwd", "block/attn"),
+    ("jit(train_step)/loss_grad/transpose(jvp(block/attn))/dot_general",
+     "bwd", "block/attn"),
+    ("jit(train_step)/loss_grad/transpose(jvp(block/mlp))/"
+     "rematted_computation/jvp(block/mlp)/tanh", "bwd", "block/mlp"),
+    ("jit(train_step)/loss_grad/jvp(head_ce)/fused_ce_stats",
+     "fwd", "head_ce"),
+    ("jit(train_step)/loss_grad/transpose(jvp(embed))/scatter-add",
+     "bwd", "embed"),
+    ("jit(train_step)/loss_grad/pmean", "fwd", "loss_grad"),
+    # under ``loss_grad`` the phase wins over a served program's scope
+    ("jit(train_step)/loss_grad/transpose(jvp(block/kda))/"
+     "transpose(jvp(gate))/mul", "bwd", "loss_grad"),
+]
+
+
+@pytest.mark.parametrize("op_name,bucket,leaf", UNIT,
+                         ids=[str(i) for i in range(len(UNIT))])
+def test_bucket_of_and_the_phase_rule(op_name, bucket, leaf):
+    assert bucket_of(op_name) == bucket
+    assert leaf_of(op_name) == (bucket, leaf)
+
+
+def test_the_table_is_ordered_inner_scopes_first():
+    """A scope that holds another comes after it, and every bucket the
+    manifest's ``tick_ms.*`` / ``step_ms.*`` metrics read is in the table."""
+    scopes = [s for s, _ in BUCKETS]
+    assert len(set(scopes)) == len(scopes)
+    assert scopes.index("cache_write") < scopes.index("block/attn/core")
+    assert scopes.index("tick/work_list") < scopes.index("block/mla/core")
+    assert scopes.index("block/moe/route") < scopes.index("block/mlp")
+    assert scopes.index("loss_grad") < scopes.index("block/kda/gate")
+    with open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json")) as f:
+        names = {m["name"] for m in json.load(f)["per_layer"]}
+    buckets = {b for _, b in BUCKETS if b is not PHASE} | set(PHASE)
+    for bucket in buckets:
+        prefix = "step_ms." if bucket in PHASE + ("optimizer",) \
+            else "tick_ms."
+        assert prefix + bucket in names
+    assert {"tick_ms.unscoped", "step_ms.unscoped"} <= names
