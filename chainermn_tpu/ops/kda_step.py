@@ -35,6 +35,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .._compat import shape_dtype_struct as _sds
+from .kv_cache import busy_slots
 
 __all__ = ["kda_step", "kda_step_xla"]
 
@@ -95,12 +96,9 @@ def kda_step(q, k, v, g, beta, state, busy, *, interpret: bool = False):
     dv = v.shape[-1]
     hb = _head_block(h)
     f32 = jnp.float32
-    n_busy = busy.sum().astype(jnp.int32)
     # busy slots first, in slot order; the rest of the axis repeats the
     # last busy slot, whose blocks the kernel then already holds
-    order = jnp.argsort(~busy, stable=True).astype(jnp.int32)
-    slots = jnp.where(jnp.arange(n) < n_busy, order,
-                      order[jnp.maximum(n_busy - 1, 0)])
+    slots, n_busy = busy_slots(busy, n)
     qkg = jnp.stack([q, k, g], axis=1).astype(f32)            # (N, 3, H, dk)
     vb = jnp.stack([v.astype(f32), jnp.broadcast_to(
         beta.astype(f32)[..., None], (n, h, dv))], axis=1)     # (N, 2, H, dv)
@@ -131,7 +129,7 @@ def kda_step(q, k, v, g, beta, state, busy, *, interpret: bool = False):
             dimension_semantics=("arbitrary", "arbitrary")),
         name="kda_step",
         interpret=interpret,
-    )(slots, n_busy.reshape(1), qkg, vb, state)
+    )(slots, n_busy, qkg, vb, state)
     # a slot that is not busy was given no block: its read-out is whatever
     # the buffer held, and must not reach the rows above a cached prefix
     return jnp.where(busy[:, None, None], o, 0.0), new_state

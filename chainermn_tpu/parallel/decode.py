@@ -90,6 +90,34 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
         """``(N,) bool`` of a tick's rows that carry a token (None: all)."""
         return None if live is None else live[:, 0]
 
+    def write_new_rows(bufs, rows, write_at, work):
+        """The layer's new ``rows`` (a tuple of ``(N, S_q, W_i)``) into its
+        cache buffers ``bufs`` at ``write_at`` — every caller's one door,
+        under its ``cache_write`` scope.  The tick (one row a slot, each at
+        its own position ``write_at (N,)``, clamped inside the buffer)
+        goes through ``ops/kv_cache.py::write_rows``: the busy slots' rows
+        alone, in place, over the tick's busy list (``work``'s memo: built
+        where the first layer writes, handed to every later one).  A
+        scalar position keeps the closed batch's writers: ``cache_append``
+        for a K/V pair, a ``dynamic_update_slice`` for one buffer."""
+        from ..ops.kv_cache import busy_slots, cache_append, write_rows
+
+        if getattr(write_at, "ndim", 0) == 1:
+            if rows[0].shape[1] != 1:       # a chunk behind a cache
+                return write_rows(bufs, rows, write_at)
+            if work is not None and "slots" not in work:
+                # a writer that takes no kernel leaves the list unread,
+                # and the compiler drops it
+                work["slots"] = busy_slots(busy_rows(), bufs[0].shape[0])
+            return write_rows(bufs, rows, write_at, busy_rows(),
+                              slots=None if work is None
+                              else work["slots"])
+        if len(bufs) == 2:
+            return cache_append(*bufs, *rows, write_at, axis=1)
+        return tuple(jax.lax.dynamic_update_slice(
+            c, r.astype(c.dtype), (0, write_at, 0))
+            for c, r in zip(bufs, rows))
+
     def embed(tokens, positions):
         from .tensor_parallel import vocab_parallel_embedding
 
@@ -171,8 +199,7 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
                     cfg, h, blk["attn"], positions, arch.norm_eps)
                 rows = _blocks.mla_latent_rows(cfg, c_kv, k_rope)
             with jax.named_scope("cache_write"):
-                cache = _write_rows(cache, rows.astype(cache.dtype),
-                                    write_at)
+                (cache,) = write_new_rows((cache,), (rows,), write_at, work)
             if s_q > 1 and isinstance(write_at, int) and write_at == 0 \
                     and isinstance(q_valid, int) and q_valid == 0:
                 ctx = _blocks.mla_attend_prefill(
@@ -261,7 +288,6 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
                 return attend_rows(q, k, v)
 
         def attend_rows(q, k, v):
-            from ..ops.kv_cache import cache_append
             s_q = q.shape[1]
             hl, hkv = q.shape[2], k.shape[2]
             flat = lambda t: t.reshape(n, s_q, hkv * head_dim)
@@ -285,9 +311,11 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
             else:
                 at = write_at
             # one-row decode appends go through the Pallas in-place
-            # scatter (ops/kv_cache.py): the XLA dus costs a full extra
-            # pass over the cache per tick; prefill's slab write (s_q >
-            # 1) falls back to dus inside cache_append
+            # writers (ops/kv_cache.py) — the tick's per-slot positions
+            # over the busy slots alone, K and V in one call; the closed
+            # batch's scalar position over every row: the XLA dus costs a
+            # full extra pass over the cache per tick, its vmap a loop
+            # over every slot; prefill's slab write (s_q > 1) is a dus
             with jax.named_scope("cache_write"):
                 if window and prefill:
                     # the ring of the prompt's REAL rows (``live``): a
@@ -298,8 +326,8 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
                                                 ).astype(c.dtype)
                               for t, c in ((k, k_cache), (v, v_cache)))
                 else:
-                    kc, vc = cache_append(k_cache, v_cache, flat(k),
-                                          flat(v), at, axis=1)
+                    kc, vc = write_new_rows((k_cache, v_cache),
+                                            (flat(k), flat(v)), at, work)
             if prefill:
                 # PREFILL: pure causal self-attention over the prompt —
                 # the flash kernels, not the naive einsum, which would
@@ -379,17 +407,6 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
     attn_block.moe_routing = moe_routing
     attn_block.arch = arch
     return embed, attn_block, block_with, rope
-
-
-def _write_rows(cache, rows, write_at):
-    """``rows (N, S_q, W)`` into ``cache (N, total, W)`` at ``write_at``:
-    an int / scalar (every row at the same offset) or an ``(N,)`` vector
-    (the serving tick: row ``b`` at ``write_at[b]``, clamped inside the
-    buffer as ``dynamic_update_slice`` clamps)."""
-    if getattr(write_at, "ndim", 0) == 1:
-        return jax.vmap(lambda c, r, p: jax.lax.dynamic_update_slice(
-            c, r, (p, 0)))(cache, rows, write_at)
-    return jax.lax.dynamic_update_slice(cache, rows, (0, write_at, 0))
 
 
 def _run_layer(attn_block, x, blk, bufs, positions, write_at, q_valid,

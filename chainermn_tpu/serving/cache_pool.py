@@ -26,8 +26,9 @@ different invariant for each:
 
 * ROWS ``(n_slots, max_total, columns)``, one row a token.  *Unreachable
   above ``pos``*: a slot's rows ``> pos`` may hold a previous occupant's
-  K/V — or the one garbage row a free or cached slot's tick writes at its
-  own held ``pos`` — but every attention read is masked to the occupant's
+  K/V (a tick writes the BUSY slots' rows alone: a free or cached slot's
+  buffers come out of it bit for bit, ``ops/kv_cache.py::write_rows``),
+  but every attention read is masked to the occupant's
   own prefix ``[0, pos]``, and row ``p`` is written by the current
   occupant strictly before ``pos`` reaches ``p`` (prefill writes ``[0,
   s_p)``; each tick writes row ``pos`` before attending it).  Stale rows
@@ -52,12 +53,12 @@ different invariant for each:
   windowed attention layer: a query at position ``q`` sees keys ``q - W <
   k <= q``), position ``p`` at ring row ``p % W``.  *The row a write lands
   on is the one row the next query cannot see.*  The tick writes a row
-  for every slot (it READS only the busy slots' caches), and a free or
-  cached slot's garbage write lands on ring row ``pos % W`` at its held
-  ``pos``.  That row holds position ``pos - W``: exactly one
-  position OUTSIDE the window of the next real query, which is at ``pos``
-  and sees ``pos - W + 1 .. pos`` — and it is the row that query's own
-  token overwrites before attending.  So a cached slot's ring still serves
+  for every BUSY slot (and READS only the busy slots' caches), on ring
+  row ``pos % W``.  That row holds position ``pos - W``: exactly one
+  position OUTSIDE the window of the query at ``pos``, which
+  sees ``pos - W + 1 .. pos`` — the row that query's own
+  token overwrites before attending.  A free or cached slot's ring is not
+  written at all.  So a cached slot's ring serves
   a request that continues at the donated length ``pos`` (and only there:
   as with a state, a shorter prefix has lost rows, ``[m - W, pos - W)``
   for a match of ``m``, so such a pool's prefix cache is ``whole_only``
@@ -410,6 +411,10 @@ class CachePool:
         #: layers that keep rows a token (the others: a ring, a state)
         self.n_row_layers = sum(
             any(len(buf) == 2 for buf in bufs) for bufs in self.layout)
+        #: buffers a tick writes ONE row a busy slot into: every layer's
+        #: rows and rings (K and V each count), not a state
+        self.n_row_buffers = sum(
+            not _is_state(buf) for bufs in self.layout for buf in bufs)
         #: bytes one slot keeps across all STATE layers, whatever its
         #: length (0: every layer keeps rows), and how many layers those are
         self.state_bytes_per_slot = sum(
@@ -419,13 +424,10 @@ class CachePool:
             any(_is_state(buf) for buf in bufs) for bufs in self.layout)
         # host-side per-slot NEXT-WRITE position (== sequence length so
         # far).  The tick runs EVERY slot (one fixed program) but only a
-        # BUSY slot's position advances (``advance``): a free, cached or
-        # reserved slot holds its position, so its garbage ROW write keeps
-        # landing on the one row ``pos`` INSIDE ITS OWN SLOT ROW — row 0
-        # of a free slot, the first row above a cached prefix — which
-        # stays safe by the module docstring's argument for rows: the next
-        # occupant rewrites row p before its own pos reaches p.  (Its
-        # STATE is not written at all: the tick is given the busy mask.)
+        # BUSY slot's position advances (``advance``) and only a busy
+        # slot is written: the tick is given the busy mask, and a free,
+        # cached or reserved slot's rows, ring and state come back bit
+        # for bit (``ops/kv_cache.py::write_rows``, ``ops/kda_step.py``).
         self.pos = np.zeros(self.n_slots, np.int32)
 
     def fresh_buffers(self):
@@ -527,12 +529,11 @@ class CachePool:
         self.allocator.cancel_reservation(slot)
 
     # prefix-cache faces.  A cached slot's ``pos`` is deliberately NOT
-    # reset, and held (only a busy slot's advances): the tick's garbage
-    # row of a cached slot lands AT the donated prefix length, above the
-    # read-only rows [0, length) the copy-on-extend path reads (the same
-    # above-``pos`` unreachability argument as free-slot recycling) — and
-    # a cached slot's STATE, which is not busy, is not written: it stays
-    # the state at ``pos``, the one length a hit on it can be used at.
+    # reset, and held (only a busy slot's advances): a tick writes
+    # nothing of a slot that is not busy, so the read-only rows [0,
+    # length) the copy-on-extend path reads, the ring and the STATE stay
+    # as donated — the state at ``pos``, the one length a hit on it can
+    # be used at.
     def cache(self, slot: int) -> None:
         self.allocator.cache(slot)
 

@@ -330,6 +330,8 @@ class ServingEngine:
         # pool holds (all layers alike, so one is counted)
         self._tick_cache_blocks_read = 0
         self._tick_cache_blocks_total = 0
+        self._tick_cache_rows_written = 0
+        self._tick_cache_rows_offered = 0
         # the rows those blocks had to hold: each busy slot's own length
         self._tick_cache_rows_live = 0
         # state layers: (busy slot, state layer) pairs a tick moved on —
@@ -709,6 +711,10 @@ class ServingEngine:
                         self._last_tick_start = t_tick
                         self._tick_cache_blocks_read += read
                         self._tick_cache_blocks_total += total
+                        self._tick_cache_rows_written += (
+                            len(rows) * self.pool.n_row_buffers)
+                        self._tick_cache_rows_offered += (
+                            self.pool.n_slots * self.pool.n_row_buffers)
                         self._tick_cache_rows_live += int(np.minimum(
                             self.pool.pos[live], self.pool.max_total - 1
                             ).sum()) + len(rows)
@@ -1187,6 +1193,8 @@ class ServingEngine:
             self._prefill_tokens_padded = 0
             self._tick_cache_blocks_read = 0
             self._tick_cache_blocks_total = 0
+            self._tick_cache_rows_written = 0
+            self._tick_cache_rows_offered = 0
             self._tick_cache_rows_live = 0
             self._tick_state_slots_live = 0
             self._tick_ring_blocks_read = 0
@@ -1282,6 +1290,15 @@ class ServingEngine:
                     self._tick_ring_blocks_total),
                 "serving/tick_cache_rows_live": per_layer(
                     self._tick_cache_rows_live, self._tick_ring_rows_live),
+                # written over offered: the new rows the ticks' writers
+                # put into the pool — one a busy slot a buffer (every
+                # layer's rows and rings, K and V each; not a state) —
+                # over one a slot a buffer, what a write of every slot
+                # would touch
+                "serving/tick_cache_rows_written": float(
+                    self._tick_cache_rows_written),
+                "serving/tick_cache_rows_offered": float(
+                    self._tick_cache_rows_offered),
                 # what one token keeps in the pool, all row layers, and
                 # what one slot keeps whatever its length, all state
                 # layers and all ring layers (gauges)

@@ -188,6 +188,33 @@ def test_cache_append(one_chip, as_tpu):
     assert n >= 1
 
 
+@pytest.mark.parametrize("shape,n_bufs", [
+    ((32, 1024, 1024), 2),      # GPT-2 medium's K and V rows
+    ((24, 512, 1024), 2),       # Laguna's sliding layers' ring
+    ((64, 4096, 640), 1),       # DeepSeek's padded latent rows
+], ids=["gpt2_rows", "laguna_ring", "deepseek_latent"])
+def test_cache_write_rows(one_chip, as_tpu, shape, n_bufs):
+    """The served tick's writer (per-slot positions, the busy slots alone):
+    one kernel a layer, every buffer aliased to its result — the compiled
+    program holds no copy of a buffer and no loop."""
+    from chainermn_tpu.ops.kv_cache import write_rows
+
+    b, _, d = shape
+    bufs = (_sds(shape, jnp.bfloat16, one_chip),) * n_bufs
+    new = (_sds((b, 1, d), jnp.bfloat16, one_chip),) * n_bufs
+    pos = _sds((b,), jnp.int32, one_chip)
+    busy = _sds((b,), jnp.bool_, one_chip)
+    fn = jax.jit(lambda bufs, new, pos, busy: write_rows(bufs, new, pos, busy),
+                 donate_argnums=(0,))
+    text = fn.lower(bufs, new, pos, busy).compile().as_text()
+    calls = [ln.split(" = ")[0] for ln in text.split("\n")
+             if "custom_call_target=\"tpu_custom_call\"" in ln]
+    assert len(calls) == 1 and "cache_write_rows" in calls[0], calls
+    assert " while(" not in text
+    assert not [ln for ln in text.split("\n") if " copy(" in ln
+                and f"[{shape[0]},{shape[1]},{shape[2]}]" in ln]
+
+
 def test_conv3x3_backward(one_chip):
     from chainermn_tpu.ops.conv_backward import conv3x3_dgrad, conv3x3_wgrad
 
@@ -275,6 +302,22 @@ def _assert_pool_written_in_place(text: str, pool_shape) -> None:
     assert not copies, f"{len(copies)} pool-sized copies left"
 
 
+def _assert_tick_writes_rows_in_place(text: str, layers: int) -> None:
+    """The tick's new rows go through ONE ``cache_write_rows`` kernel a
+    layer that keeps rows or a ring (ops/kv_cache.py::write_rows, under the
+    layer's ``cache_write`` scope), and the per-slot ``while`` loops of the
+    vmapped ``dynamic_update_slice`` are gone (ISSUE 37).  No reader of an
+    accepted metric may match the kernel's name by substring."""
+    calls = [ln.split(" = ")[0] for ln in text.split("\n")
+             if "custom_call_target=\"tpu_custom_call\"" in ln]
+    assert sum("cache_write_rows" in c for c in calls) == layers, calls
+    assert "cache_write/jit(_write_rows_kernel)/cache_write_rows" in text
+    assert not [ln for ln in text.split("\n")
+                if " while(" in ln and "cache_write" in ln]
+    assert not any(n in "cache_write_rows" for n in (
+        "decode_attn", "moe_gmm", "kda", "mla", "flash", "fused_ce"))
+
+
 def _assert_scopes(text: str, *scopes, tick: bool = False) -> None:
     """The compiled program's ``op_name`` metadata holds each scope path —
     what the chip's trace carries as ``tf_op`` and
@@ -353,6 +396,7 @@ def test_serving_prefill_and_tick(topo, as_tpu, shape):
                    "block/attn/core/jit(decode_attend)/decode_attn_mha",
                    "tick/layer/block/mlp", tick=True)
     _assert_pool_written_in_place(tick.as_text(), (n_slots, total, D_MODEL))
+    _assert_tick_writes_rows_in_place(tick.as_text(), N_LAYERS)
 
 
 def test_latent_attention_and_expert_serving_programs(topo, as_tpu):
@@ -437,6 +481,7 @@ def test_latent_attention_and_expert_serving_programs(topo, as_tpu):
                    "block/mlp/block/moe/dispatch", "block/mlp/block/moe/gmm",
                    "block/mlp/block/moe/shared", tick=True)
     _assert_pool_written_in_place(tick, (n_slots, total, 640))
+    _assert_tick_writes_rows_in_place(tick, layers)
 
     prefill = eng._build_prefill(prompt).lower(
         p, caches, _sds((1, prompt), jnp.int32, rep),
@@ -565,6 +610,7 @@ def test_state_and_row_layers_in_one_pool_serving_programs(topo, as_tpu):
                    "block/moe/dispatch", "block/moe/gmm", "block/moe/shared",
                    tick=True)
     _assert_pool_written_in_place(text, (64, 4096, 640))
+    _assert_tick_writes_rows_in_place(text, 7)
     import re
     assert not re.findall(r"= f32\[64,32,128,128\]\S* copy\(", text)
     # 8.59 GB of weights + 5.13 GB of pool (state 2.78, rows 2.35)
@@ -685,6 +731,7 @@ def test_ring_and_row_layers_in_one_pool_serving_programs(topo, as_tpu):
                    "tick/layer/block/mlp/block/moe/dispatch", tick=True)
     _assert_pool_written_in_place(text, (24, 4096, 1024))
     _assert_pool_written_in_place(text, (24, 512, 1024))
+    _assert_tick_writes_rows_in_place(text, 40)
     # 8.0 GB of weights + 5.54 GB of pool (rows 4.03, rings 1.51)
     assert 13.5e9 < mem.argument_size_in_bytes < 13.6e9
     assert mem.temp_size_in_bytes < 0.3e9

@@ -197,3 +197,104 @@ def test_pallas_on_non_tpu_backend_raises_descriptive_error():
     np.testing.assert_array_equal(
         np.asarray(got),
         np.asarray(jax.lax.dynamic_update_slice_in_dim(kc, kn, 6, 1)))
+
+
+# --------------------------------------------------------------------------
+# the served tick's writer: per-slot positions, the busy slots alone
+# --------------------------------------------------------------------------
+
+def _oracle_rows(c, new, pos, busy):
+    """Stacked per-row ``dynamic_update_slice`` (which clamps the start
+    inside the buffer); a slot that is not busy keeps its buffer."""
+    return np.stack([
+        np.asarray(jax.lax.dynamic_update_slice_in_dim(
+            c[b], new[b], int(pos[b]), 0) if busy is None or busy[b]
+            else c[b], np.float32)
+        for b in range(c.shape[0])])
+
+
+_N = 6
+_BUSY = {"all_by_none": None, "none": [], "one": [4], "some": [0, 2, 5],
+         "all": list(range(_N))}
+#: name -> (rows a buffer, columns of each buffer of the layer, window)
+_LAYERS = {"kv_rows": (48, (1024, 1024), 0),
+           "latent": (48, (640,), 0),
+           "ring": (32, (256, 256), 32)}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("layer", list(_LAYERS))
+@pytest.mark.parametrize("busy", list(_BUSY))
+def test_write_rows_busy_slots(busy, layer, dtype):
+    """``write_rows``' kernel (interpret mode) against the per-row oracle:
+    positions at 0, the block edges of both dtypes (7, 8, 15, 16), ``rows
+    - 1`` and beyond it (clamped), or a ring's ``pos % window``; a slot
+    that is not busy comes back bit for bit; ``busy`` None is the vector
+    path as it was; the XLA path beside the kernel gives the same."""
+    from chainermn_tpu.ops.kv_cache import busy_slots, write_rows
+
+    total, columns, window = _LAYERS[layer]
+    mask = None if _BUSY[busy] is None else np.isin(np.arange(_N),
+                                                    _BUSY[busy])
+    pos = np.asarray([0, 7, 8, 15, 16, total - 1], np.int32)
+    if window:
+        pos = (pos + np.asarray([0, 3, 5, 1, 2, 4]) * window) % window
+    for shift in (0, 1):        # second pass: 1, 8, 9, 16, 17, past the end
+        at = jnp.asarray(pos + shift if not window
+                         else (pos + shift) % window)
+        bufs = tuple(_mk((_N, total, c), dtype, 40 + i)
+                     for i, c in enumerate(columns))
+        new = tuple(_mk((_N, 1, c), dtype, 50 + i)
+                    for i, c in enumerate(columns))
+        bmask = None if mask is None else jnp.asarray(mask)
+        got = write_rows(bufs, new, at, bmask, interpret=True)
+        handed = write_rows(bufs, new, at, bmask, interpret=True,
+                            slots=busy_slots(bmask, _N))
+        plain = write_rows(bufs, new, at, bmask)            # the XLA path
+        assert len(got) == len(bufs)
+        for c, n, g, h, x in zip(bufs, new, got, handed, plain):
+            want = _oracle_rows(c, n, np.asarray(at), mask)
+            assert g.dtype == c.dtype
+            np.testing.assert_array_equal(np.asarray(g, np.float32), want)
+            np.testing.assert_array_equal(np.asarray(h, np.float32), want)
+            np.testing.assert_array_equal(np.asarray(x, np.float32), want)
+            if mask is None:    # today's vector path, bit for bit
+                v, _ = cache_append(c, c, n, n, at, axis=1, impl="xla")
+                np.testing.assert_array_equal(np.asarray(v, np.float32),
+                                              want)
+
+
+def test_write_rows_under_jit_and_rejections():
+    from chainermn_tpu.ops.kv_cache import busy_slots, write_rows
+
+    c = _mk((4, 32, 128), jnp.bfloat16, 60)
+    n = _mk((4, 1, 128), jnp.bfloat16, 61)
+    busy = jnp.asarray([True, False, False, True])
+
+    @jax.jit
+    def go(c, pos, busy):
+        return write_rows((c,), (n,), pos, busy, interpret=True)[0]
+
+    for pos in ([0, 1, 2, 3], [31, 30, 16, 15]):
+        np.testing.assert_array_equal(
+            np.asarray(go(c, jnp.asarray(pos, jnp.int32), busy), np.float32),
+            _oracle_rows(c, n, pos, np.asarray(busy)))
+    slots = busy_slots(busy, 4)
+    assert int(slots.n[0]) == 2 and list(np.asarray(slots.slot)) == [0, 3,
+                                                                     3, 3]
+    assert int(busy_slots(None, 4).n[0]) == 4
+    # 30 rows are no whole sublane blocks; two rows a slot are no tick
+    odd = jnp.zeros((4, 30, 128), jnp.bfloat16)
+    with pytest.raises(ValueError, match="whole sublane blocks"):
+        write_rows((odd,), (n,), jnp.zeros(4, jnp.int32), interpret=True)
+    with pytest.raises(ValueError, match="whole sublane blocks"):
+        write_rows((c,), (jnp.zeros((4, 2, 128), jnp.bfloat16),),
+                   jnp.zeros(4, jnp.int32), interpret=True)
+    with pytest.raises(ValueError, match="length"):
+        write_rows((c,), (n,), jnp.zeros(3, jnp.int32))
+    # off the kernel's envelope the XLA path keeps the busy contract
+    got = write_rows((odd + 1,), (n,), jnp.asarray([0, 5, 29, 40]), busy)[0]
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32),
+        _oracle_rows(odd + 1, n, [0, 5, 29, 40], np.asarray(busy)))

@@ -393,12 +393,12 @@ def test_tick_equals_launch_then_collect(models, mesh, name):
 def test_ticks_leave_the_slots_that_serve_nobody_alone(models, mesh, name):
     """A pool with a busy, a cached and a free slot.  The busy slot's
     tokens are the request's served alone; after all its ticks the cached
-    slot's rows below its position, its ring but for the one row a stray
-    write lands on (``pos % W``, outside the next query's window) and the
-    free slot's buffers but for row 0 are bit for bit what they were; a
-    prefix hit on the cached slot the ticks skipped serves what a cold
-    engine serves; and the engine's counters are the host twin of the
-    kernels' work list, summed over the ticks."""
+    slot's and the free slots' buffers — rows, rings, states — are bit for
+    bit what they were (the tick writes a busy slot's row and no other:
+    ISSUE 37); a prefix hit on the cached slot the ticks skipped serves
+    what a cold engine serves; and the engine's counters are the host twin
+    of the kernels' work list and of the writer's busy list, summed over
+    the ticks."""
     from chainermn_tpu.ops.decode_attention import live_blocks
 
     model = models(name)
@@ -457,23 +457,21 @@ def test_ticks_leave_the_slots_that_serve_nobody_alone(models, mesh, name):
             for pos, live in launches)
     assert m["serving/tick_row_bytes"] == rows * pool.bytes_per_token
     assert m["serving/tick_cache_blocks_total"] >= 3 * ticks * bool(blocks)
+    n_bufs = sum(leaf.ndim == 3
+                 for leaf in jax.tree_util.tree_leaves(before))
+    assert n_bufs == pool.n_row_buffers > 0
+    assert m["serving/tick_cache_rows_written"] == n_bufs * sum(
+        int(live.sum()) for _, live in launches)
+    assert m["serving/tick_cache_rows_offered"] == (
+        n_bufs * pool.n_slots * ticks)
 
     after = jax.tree_util.tree_map(np.asarray, pool.caches)
     free = [s for s in range(pool.n_slots) if s not in (cached, busy_slot)]
     for was, now in zip(jax.tree_util.tree_leaves(before),
                         jax.tree_util.tree_leaves(after)):
-        if was.ndim != 3:
-            # a state: never written unless busy
-            np.testing.assert_array_equal(now[cached], was[cached])
-            continue
-        n_rows = was.shape[1]
-        stray = held[cached] % n_rows           # rows: pos itself
-        keep = np.arange(n_rows) != stray
-        if n_rows == pool.max_total:
-            keep &= np.arange(n_rows) < held[cached]
-        np.testing.assert_array_equal(now[cached][keep], was[cached][keep])
-        for s in free:                          # position 0: row 0 alone
-            np.testing.assert_array_equal(now[s][1:], was[s][1:])
+        for s in [cached] + free:
+            np.testing.assert_array_equal(now[s], was[s])
+        assert not np.array_equal(now[busy_slot], was[busy_slot])
 
     # a prefix hit on the slot every one of those ticks skipped
     follow = np.concatenate([donated, tail])
