@@ -40,12 +40,13 @@ static: every distinct way a cell is computed (its *plan*) is one
 straight-line body, all of the cell's Q sub-blocks in one region, and the
 cell picks its plan from its program ids.  ``causal=False`` with no tail
 has one plan, the undivided cell.  A WINDOW (``window=W``: query ``q`` sees
-keys ``0 <= q - k < W``; forward only) is one more edge of the same
-schedule: grid cells wholly left of the band are skipped and their DMA
-elided like those above the diagonal, and inside a cell each Q sub-block
-starts its one product at the first K sub-block the band reaches and
-masks those the band's left edge crosses, as it masks those the diagonal
-crosses.
+keys ``0 <= q - k < W``), forward and backward, is one more edge of the
+same schedule: grid cells wholly left of the band (in the K-major backward:
+Q blocks wholly below it) are skipped and their DMA elided like those above
+the diagonal, and inside a cell each Q sub-block starts its one product at
+the first K sub-block the band reaches and masks those the band's left edge
+crosses, as it masks those the diagonal crosses.  The banded kernels carry
+their own names, ``window_flash_fwd`` and ``window_flash_bwd``.
 
 Layout: ``(B, S, H, D)`` — the same convention as ``parallel/``'s ring and
 Ulysses attention, which uses this kernel for its local (post-all-to-all)
@@ -519,9 +520,11 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret, seq_len,
 
 
 def _bwd_blockwise(q, k, v, out, lse, do, causal, scale, block_k, seq_len,
-                   dlse=None):
+                   dlse=None, window=None):
     """Memory-efficient backward: scan over K blocks, recomputing p from
     the saved LSE.  All operands (BH, S, D); returns (dq, dk, dv).
+    ``window``: the band ``0 <= q - k < window`` as one more term of the
+    mask (every K block is still computed: the fallback, not the schedule).
 
     ``dlse``: cotangent of the LSE output when the caller differentiates
     through it (ring attention's block-merge weights).  Since
@@ -546,6 +549,8 @@ def _bwd_blockwise(q, k, v, out, lse, do, causal, scale, block_k, seq_len,
             k_pos = ik * bk + jnp.arange(bk)
             mask = (q_pos[:, None] >= k_pos[None, :] if causal
                     else jnp.ones((s, bk), bool))
+            if window is not None:
+                mask = mask & (q_pos[:, None] - k_pos[None, :] < window)
             if tail:
                 # Padded q rows have lse ≈ NEG_INF, making exp() overflow to
                 # inf; padded k columns must contribute nothing.  Mask both.
@@ -610,46 +615,63 @@ def _bwd_fused_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def body(first, count, n_full, n_masked):
+    def body(first, count, *outcome):
         """The ``count`` Q sub-blocks from ``first`` against the cell's
         first ``n_full`` K sub-blocks unmasked and the next ``n_masked``
         under the mask; their dq for this cell is one float32 product over
         both, rounded to the slab's dtype once.  With nothing to compute
         they write zeros: the sum outside reads every slab slice, and an
-        unwritten one would be uninitialized memory."""
+        unwritten one would be uninitialized memory.  Under a window the
+        columns start behind the ``n_skip`` sub-blocks left of the band,
+        and the ``n_edge`` that its left edge crosses are masked too."""
+        n_skip, n_edge, n_full, n_masked = \
+            outcome if len(outcome) == 4 else (0, 0) + outcome
         rows = slice(first * sub_q, (first + count) * sub_q)
         height = count * sub_q
         row0 = iq * block_q + first * sub_q
         if sub_q % _LANES == 0:
             row0 = pl.multiple_of(row0, _LANES)
-        lo, hi = n_full * sub_k, (n_full + n_masked) * sub_k
-        if not hi:
+        start, edge = n_skip * sub_k, (n_skip + n_edge) * sub_k
+        lo = edge + n_full * sub_k
+        hi = lo + n_masked * sub_k
+        if hi == start:
             dqp_ref[0, 0, rows, :] = jnp.zeros_like(dqp_ref[0, 0, rows, :])
             return
         q, do = q_ref[0, rows, :], do_ref[0, rows, :]
-        k, v = k_ref[0, :hi, :], v_ref[0, :hi, :]
+        k, v = k_ref[0, start:hi, :], v_ref[0, start:hi, :]
         lse = lse_ref[0, 0, pl.ds(row0, height)]
         delta = delta_ref[0, 0, pl.ds(row0, height)]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale      # (height, hi)
+            preferred_element_type=jnp.float32) * scale   # (height, hi - start)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         yield
         p = jnp.exp(s - lse[:, None])
-        if n_masked:
-            # only the columns from ``lo`` on can be hidden
-            tail = jnp.where(
-                _score_mask(row0, jk * block_k + lo, height, hi - lo, causal,
-                            seq_len, True), p[:, lo:], 0.0)
-            p = jnp.concatenate([p[:, :lo], tail], 1) if lo else tail
+        if n_edge or n_masked:
+            # only the columns before ``edge`` and from ``lo`` on can be
+            # hidden; the hidden spans are written first (program order is
+            # where the chip's scheduler starts from: ``_run_plan``)
+            def columns(a, b, masked):
+                if not masked:
+                    return p[:, a - start:b - start]
+                return jnp.where(_score_mask(
+                    row0, jk * block_k + a, height, b - a, causal, seq_len,
+                    True, schedule["window"]), p[:, a - start:b - start], 0.0)
+
+            spans = [span for span in ((start, edge, True), (edge, lo, False),
+                                       (lo, hi, True)) if span[1] > span[0]]
+            done = {span: columns(*span)
+                    for span in sorted(spans, key=lambda span: not span[2])}
+            p = done[spans[0]] if len(spans) == 1 else jnp.concatenate(
+                [done[span] for span in spans], 1)
         ds = p * (dp - delta[:, None]) * scale
         yield
-        dv_acc[:hi, :] += jax.lax.dot_general(
+        dv_acc[start:hi, :] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # (hi, d)
-        dk_acc[:hi, :] += jax.lax.dot_general(
+            preferred_element_type=jnp.float32)              # (hi - start, d)
+        dk_acc[start:hi, :] += jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dqp_ref[0, 0, rows, :] = jax.lax.dot_general(
@@ -665,7 +687,7 @@ def _bwd_fused_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_pallas(q, k, v, out, lse, do, causal, scale, block_q, block_k,
-                interpret, seq_len, group, dlse=None):
+                interpret, seq_len, group, dlse=None, window=None):
     """Pallas dq/dk/dv via the ONE fused kernel (see
     :func:`_bwd_fused_kernel`), sharing one XLA-precomputed
     ``delta = rowsum(do·out) − dlse`` (the LSE cotangent folds in exactly:
@@ -687,7 +709,7 @@ def _bwd_pallas(q, k, v, out, lse, do, causal, scale, block_q, block_k,
     lse = lse.astype(jnp.float32)[:, None, :]
     delta = delta[:, None, :]
     sl = None if seq_len == s else seq_len
-    schedule = causal_schedule(s, bq, bk, causal, seq_len)
+    schedule = causal_schedule(s, bq, bk, causal, seq_len, window)
     _count_score_blocks(schedule, bh)
 
     def qdo_index(b, j, g, i):
@@ -696,6 +718,8 @@ def _bwd_pallas(q, k, v, out, lse, do, causal, scale, block_q, block_k,
         # so Pallas's revisit detection elides their dead Q/dO DMA
         if causal:
             i = jnp.maximum(i, (j * bk) // bq)
+        if window is not None:      # and those wholly below the band
+            i = jnp.minimum(i, (j * bk + bk + window - 2) // bq)
         return (b * group + g, i, 0)
 
     dq_part, dk, dv = pl.pallas_call(
@@ -737,7 +761,9 @@ def _bwd_pallas(q, k, v, out, lse, do, causal, scale, block_q, block_k,
         compiler_params=_tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
-        name="flash_bwd",
+        # the banded backward's name is its own, and still holds
+        # ``flash_bwd`` (as the forward's)
+        name="flash_bwd" if window is None else "window_flash_bwd",
         interpret=interpret,
     )(k, v, q, do, lse, delta)
     dq = dq_part.astype(jnp.float32).sum(axis=1).astype(q.dtype)
@@ -763,14 +789,14 @@ def _fold_dkv(dx, group):
 
 
 def _bwd_gqa(q, k, v, out, lse, do, causal, scale, block_k, seq_len, group,
-             dlse=None):
+             dlse=None, window=None):
     """GQA backward: recompute with KV expanded to the full q-head count,
     then fold the shared-head gradient groups back down.  The expansion is
     backward-only and O(S·D·H) — dominated by the (BH, S, block) score
     recompute the blockwise backward already carries."""
     dq, dk, dv = _bwd_blockwise(
         q, _expand_kv(k, group), _expand_kv(v, group), out, lse, do,
-        causal, scale, block_k, seq_len, dlse=dlse)
+        causal, scale, block_k, seq_len, dlse=dlse, window=window)
     return dq, _fold_dkv(dk, group).astype(k.dtype), \
         _fold_dkv(dv, group).astype(v.dtype)
 
@@ -784,7 +810,7 @@ _BWD_BLOCK_K = 2048  # blocks to amortise grid overhead (v5e-tuned; the
 
 def _bwd_dispatch(q, k, v, out, lse, do, causal, scale, block_q, block_k,
                   interpret, seq_len, group, backward, dlse=None,
-                  bwd_block_q=None, bwd_block_k=None):
+                  bwd_block_q=None, bwd_block_k=None, window=None):
     """Route to the Pallas dq/dk/dv kernels (``'pallas'``), the XLA
     blockwise scan (``'xla'``), or pick automatically (``'auto'``: Pallas
     whenever the block geometry is Mosaic-aligned — which on TPU with the
@@ -825,12 +851,13 @@ def _bwd_dispatch(q, k, v, out, lse, do, causal, scale, block_q, block_k,
             f"pad S to a multiple of {_LANES} or use backward='xla'")
     if backward == "pallas":
         return _bwd_pallas(q, k, v, out, lse, do, causal, scale, bwd_bq,
-                           bwd_bk, interpret, seq_len, group, dlse=dlse)
+                           bwd_bk, interpret, seq_len, group, dlse=dlse,
+                           window=window)
     if backward != "xla":
         raise ValueError(
             f"backward must be 'auto', 'pallas' or 'xla', got {backward!r}")
     return _bwd_gqa(q, k, v, out, lse, do, causal, scale, bwd_bk,
-                    seq_len, group, dlse=dlse)
+                    seq_len, group, dlse=dlse, window=window)
 
 
 _STATIC = tuple(range(3, 13))    # every argument after q, k, v
@@ -857,15 +884,12 @@ def _flash_bhsd_fwd(q, k, v, causal, block_q, block_k, interpret, seq_len,
 
 def _flash_bhsd_bwd(causal, block_q, block_k, interpret, seq_len, group,
                     backward, bwd_block_q, bwd_block_k, window, res, do):
-    if window is not None:
-        raise NotImplementedError(
-            "flash_attention(window=...) is forward only: neither backward "
-            "(the fused Pallas kernel, the XLA scan) takes the band")
     q, k, v, out, lse = res
     scale = 1.0 / (q.shape[-1] ** 0.5)
     return _bwd_dispatch(q, k, v, out, lse, do, causal, scale, block_q,
                          block_k, interpret, seq_len, group, backward,
-                         bwd_block_q=bwd_block_q, bwd_block_k=bwd_block_k)
+                         bwd_block_q=bwd_block_q, bwd_block_k=bwd_block_k,
+                         window=window)
 
 
 _flash_bhsd.defvjp(_flash_bhsd_fwd, _flash_bhsd_bwd)
@@ -961,12 +985,16 @@ def flash_attention(q, k, v, causal: bool = False,
     BACKWARD independently of the forward: its five-product body wants a
     wider K block than the forward's two.
 
-    ``window=W`` (with ``causal=True``; FORWARD ONLY, differentiating
-    raises): query ``q`` sees keys ``0 <= q - k < W`` — itself and the ``W -
-    1`` before it.  The band is one more edge of the sub-block schedule
-    (module docstring): at S = 3072, W = 512 a row of Q sub-blocks computes
-    5 K sub-blocks, 2 of them masked, where the causal schedule computes up
-    to 24.  The kernel is then named ``window_flash_fwd``.
+    ``window=W`` (with ``causal=True``): query ``q`` sees keys ``0 <= q - k
+    < W`` — itself and the ``W - 1`` before it.  The band is one more edge
+    of the sub-block schedule (module docstring): at S = 3072, W = 512 a row
+    of Q sub-blocks computes 5 K sub-blocks, 2 of them masked, where the
+    causal schedule computes up to 24.  The kernels are then named
+    ``window_flash_fwd`` and ``window_flash_bwd``: the gradient runs the
+    same fused dQ/dK/dV kernel on the band's plan (at S = 8192, W = 1024,
+    540 of the causal schedule's 2080 sub-block pairs a head; ``W >= S``
+    degenerates to the causal kernel's work), and the XLA scan
+    (``backward='xla'``) carries the band as one more term of its mask.
 
     ``return_lse=True`` additionally returns the per-query log-sum-exp
     ``(B, H, S)`` as a differentiable output (the block-merge currency of

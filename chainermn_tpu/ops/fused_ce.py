@@ -142,6 +142,24 @@ def _dtable_kernel(t_ref, h_ref, tgt_ref, lse_ref, dnll_ref, dt_ref, dt_acc,
         dt_ref[...] = dt_acc[...].astype(dt_ref.dtype)
 
 
+#: block and scratch bytes a kernel may hold before it asks for more than
+#: the compiler's default scoped VMEM (16 MiB; the score tile's temporaries
+#: come on top).  At d 1024 and the default blocks the largest kernel holds
+#: 13 MiB and asks for nothing; at d 2304 the backward kernels hold 20-31.
+_DEFAULT_VMEM_ROOM = 14 << 20
+
+
+def _compiler_params(semantics, block_bytes: int):
+    """The kernel's compiler parameters: its grid semantics and, only where
+    its blocks and scratch outgrow the default, a scoped-VMEM limit with
+    room for the score tile's temporaries (a wide model: ``d`` sets every
+    block's width)."""
+    if block_bytes <= _DEFAULT_VMEM_ROOM:
+        return _tpu_compiler_params(dimension_semantics=semantics)
+    return _tpu_compiler_params(dimension_semantics=semantics,
+                                vmem_limit_bytes=block_bytes + (16 << 20))
+
+
 def _blocks_for(t, v, block_t, block_v):
     bt = _pick_aligned_block(t, block_t)
     bv = _pick_aligned_block(v, block_v)
@@ -224,8 +242,9 @@ def ce_stats(h, table, targets, block_t: int = 256, block_v: int = 1024,
                    for _ in range(3)],
         scratch_shapes=[pltpu.VMEM((bt, _LANES), jnp.float32)
                         for _ in range(3)],
-        compiler_params=_tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=_compiler_params(
+            ("parallel", "arbitrary"),
+            2 * d * (bt * h.dtype.itemsize + bv * table.dtype.itemsize)),
         name="fused_ce_stats",
         interpret=interpret,
     )(h, table, tgt_row)
@@ -266,8 +285,10 @@ def ce_grads(h, table, targets, lse, dnll, block_t: int = 256,
         out_specs=pl.BlockSpec((bt, d), lambda i, j: (i, 0)),
         out_shape=_sds((t, d), h.dtype, vma=vma),
         scratch_shapes=[pltpu.VMEM((bt, d), jnp.float32)],
-        compiler_params=_tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=_compiler_params(
+            ("parallel", "arbitrary"),
+            d * (bt * (4 * h.dtype.itemsize + 4)
+                 + 2 * bv * table.dtype.itemsize)),
         name="fused_ce_dh",
         interpret=interpret,
     )(h, table, tgt_row, lse_row, dnll_row)
@@ -286,8 +307,10 @@ def ce_grads(h, table, targets, lse, dnll, block_t: int = 256,
         out_specs=pl.BlockSpec((bv, d), lambda j, i: (j, 0)),
         out_shape=_sds((v, d), table.dtype, vma=vma),
         scratch_shapes=[pltpu.VMEM((bv, d), jnp.float32)],
-        compiler_params=_tpu_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=_compiler_params(
+            ("parallel", "arbitrary"),
+            d * (bv * (4 * table.dtype.itemsize + 4)
+                 + 2 * bt * h.dtype.itemsize)),
         name="fused_ce_dtable",
         interpret=interpret,
     )(table, h, tgt_row, lse_row, dnll_row)

@@ -18,7 +18,8 @@ Parameter layout per ``arch`` value (all GLOBAL arrays):
   ``'swiglu'``: ``mlp = {w_gate, w_up, w_down}``, no biases.
 * layer kind ``'moe'``: ``moe = {router (D, E), router_bias (E,), shared =
   {w_gate, w_up, w_down}, w_gate/w_up (E_held, D, F), w_down (E_held, F,
-  D)}`` — ``parallel/moe.py::moe_dropless``.
+  D)}`` — ``parallel/moe.py::moe_dropless``; a ``'softmax'`` router has no
+  ``router_bias`` and a layer with ``n_shared = 0`` no ``shared``.
 * ``attn='mha'``: ``attn = {wqkv, bqkv, wo, bo}`` or the GQA form ``{wq,
   bq, wkv, bkv, wo, bo}`` (``wkv`` columns per KV head ``[k_h | v_h]``; the
   query-head count is the weights', so it may differ from layer to layer);
@@ -102,8 +103,13 @@ class MLAConfig:
 
 @dataclass(frozen=True)
 class MoEConfig:
-    """Routed experts: sigmoid scores, group-limited top-k, this chip's
-    share ``held = (first, n)`` of the ``n_experts`` the router scores."""
+    """Routed experts: this chip's share ``held = (first, n)`` of the
+    ``n_experts`` the router scores.  ``router``: ``'sigmoid_group'``
+    (sigmoid scores, a selection-only bias, group-limited top-k) or
+    ``'softmax'`` (softmax over all experts, plain top-k, no bias and no
+    groups: ``n_group`` / ``topk_group`` are not read).  ``n_shared``: how
+    many shared experts every token takes beside the routed ones (0: the
+    layer has no ``shared`` parameters at all)."""
     n_experts: int
     top_k: int
     n_group: int
@@ -111,6 +117,8 @@ class MoEConfig:
     routed_scaling_factor: float
     norm_topk_prob: bool = True
     held: Tuple[int, int] = (0, 0)
+    router: str = "sigmoid_group"      # | 'softmax'
+    n_shared: int = 1
 
 
 @dataclass(frozen=True)
@@ -340,6 +348,23 @@ def rotate(cfg: Rotary, x, positions):
     turned = apply_rope_freqs(x[..., :rot], positions, inv_freq,
                               cfg.attention_factor)
     return turned if rot == d else jnp.concatenate([turned, x[..., rot:]], -1)
+
+
+def turn_qk(arch: LMArch, layer: int, q, k, positions, rope: bool):
+    """A layer's queries and keys ``(B, S, H, d)`` rotated at ``positions``
+    as the model says: by the layer's own :class:`Rotary` record (theta,
+    the rotated fraction, YaRN) where ``arch.rotary`` names one, else by
+    ``transformer.apply_rope`` where ``rope`` (a model without a position
+    table), else as they are.  The one rotation of the training loss
+    (``transformer.tp_attention``) and of the serving prefill and tick
+    (``decode._decoder_core``)."""
+    turn = arch.rotary[layer] if arch.rotary is not None else None
+    if turn is not None:
+        return rotate(turn, q, positions), rotate(turn, k, positions)
+    if rope:
+        from .transformer import apply_rope
+        return apply_rope(q, positions), apply_rope(k, positions)
+    return q, k
 
 
 def ring_rows(rows, s_real, window: int):

@@ -32,7 +32,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from .._compat import pcast_varying
 from . import blocks as _blocks
 from .tensor_parallel import row_parallel_dense
-from .transformer import _layer_norm, _project_qkv, apply_rope
+from .transformer import _layer_norm, _project_qkv
 
 
 def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
@@ -155,14 +155,9 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
         with jax.named_scope("block/attn/proj"):
             h = _blocks.norm(arch, x, blk, "ln1")
             q, k, v = _project_qkv(h, a, head_dim, axis_name, arch.attn_bias)
-            turn = arch.rotary[layer] if arch.rotary is not None else None
-            if turn is not None:     # the layer's own rotation (theta, the
-                #                      rotated fraction, YaRN)
-                q = _blocks.rotate(turn, q, positions)
-                k = _blocks.rotate(turn, k, positions)
-            elif rope:
-                q = apply_rope(q, positions)
-                k = apply_rope(k, positions)
+            # the layer's own rotation (theta, the rotated fraction, YaRN),
+            # else the model's plain one
+            q, k = _blocks.turn_qk(arch, layer, q, k, positions, rope)
         # the attend stage's own cache append nests as .../core/cache_write
         with jax.named_scope("block/attn/core"):
             ctx, extras = attend(q, k, v)

@@ -28,6 +28,7 @@ from __future__ import annotations
 from typing import Callable, Optional
 
 import jax
+import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -300,6 +301,7 @@ def make_hybrid_shard_map_step(
     batch_spec: Optional[P] = None,
     has_aux: bool = False,
     donate: bool = True,
+    aux_specs=None,
 ):
     """Hybrid-parallel train step, explicit shard_map face.
 
@@ -308,6 +310,14 @@ def make_hybrid_shard_map_step(
     themselves; this builder pmeans the loss over ``data_axis`` so autodiff
     inserts the cross-replica gradient reduction (and ONLY that — params
     varying over the model axis get no spurious model-axis psum).
+
+    ``has_aux``: ``loss_fn`` returns ``(loss, aux)`` and the step hands out
+    ``aux`` reduced over ``data_axis`` — integer leaves (counts: an expert
+    layer's routing counts) SUMMED, every other leaf averaged.  ``aux_specs``
+    (a ``PartitionSpec`` for each leaf of ``aux``; None: ``P()`` for all)
+    names the leaves that are NOT reduced: a leaf whose spec names
+    ``data_axis`` is a value a sample (an expert layer's chosen experts) and
+    comes out as it is, sharded as its spec says.
 
     ``params``/``param_specs``: the TP layout (e.g. ``wi`` sharded on its
     output dim over ``'model'``); used to derive optimizer-state specs via
@@ -334,10 +344,23 @@ def make_hybrid_shard_map_step(
             updates, opt_state = optimizer.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
         if has_aux:
-            return params, opt_state, loss, jax.lax.pmean(aux, data_axis)
+            # a count (an integer leaf) is SUMMED over the replicas, every
+            # other leaf averaged as the loss is; a leaf a sample stays
+            def reduced(a, spec=P()):
+                if data_axis in jax.tree_util.tree_leaves(tuple(spec)):
+                    return a
+                return (jax.lax.psum if jnp.issubdtype(a.dtype, jnp.integer)
+                        else jax.lax.pmean)(a, data_axis)
+
+            with jax.named_scope("loss_grad"):
+                aux = (jax.tree_util.tree_map(reduced, aux)
+                       if aux_specs is None else
+                       jax.tree_util.tree_map(reduced, aux, aux_specs))
+            return params, opt_state, loss, aux
         return params, opt_state, loss
 
-    out_specs = ((param_specs, st_specs, P(), P()) if has_aux
+    out_specs = ((param_specs, st_specs, P(),
+                  P() if aux_specs is None else aux_specs) if has_aux
                  else (param_specs, st_specs, P()))
     # the jitted program is named after the function: ``jit_train_step``
     # on the profiler's "XLA Modules" line

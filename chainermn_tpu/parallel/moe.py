@@ -19,15 +19,18 @@ Switch-Transformer / Mesh-TF dispatch formulation, which XLA maps well):
   output; gradients flow through dispatch/combine einsums and the
   all_to_alls automatically (shard_map transposes them).
 
-:func:`moe_dropless` is the SERVING layer (a served token that loses an
-expert is a wrong answer, so nothing is dropped): sigmoid scores with a
-selection-only bias, group-limited top-k, renormalised and scaled gates
-(:func:`sigmoid_group_route`), a layer that is TOLD which experts it holds
+:func:`moe_dropless` is the DROPLESS layer, served and trained (a served
+token that loses an expert is a wrong answer, so nothing is dropped): by
+``MoEConfig.router`` sigmoid scores with a selection-only bias,
+group-limited top-k, renormalised and scaled gates
+(:func:`sigmoid_group_route`) or a softmax over all experts with plain top-k
+(:func:`softmax_topk_route`), a layer that is TOLD which experts it holds
 (``held = (first, n)``), routes over all of them and computes its own
 experts' part, with one grouped product per projection over the experts
 that have tokens (``ops/moe_gmm.py``) beside a shared expert every token
-takes.  It runs without an exchange: a chip's result is its PART of the
-layer (the exchange between the parts waits for a four-chip cell).
+takes (where the model has one).  It runs without an exchange: a chip's
+result is its PART of the layer (the exchange between the parts waits for a
+four-chip cell).
 """
 
 from __future__ import annotations
@@ -232,10 +235,104 @@ def sigmoid_group_route(x, router, bias, cfg):
     return idx.astype(jnp.int32), gates * cfg.routed_scaling_factor
 
 
+def softmax_topk_route(x, router, cfg):
+    """Softmax over ALL ``cfg.n_experts`` in float32, the ``top_k`` largest
+    chosen, their probabilities the gates — divided by their sum where
+    ``cfg.norm_topk_prob`` — times ``routed_scaling_factor``: ``(idx (T, k)
+    int32, gates (T, k) f32)``.  No bias, no groups.  The gates
+    differentiate into the router and ``x``; the choice does not."""
+    probs = jax.nn.softmax(jnp.matmul(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    gates, idx = jax.lax.top_k(probs, cfg.top_k)
+    if cfg.norm_topk_prob:
+        gates = gates / (gates.sum(-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), gates * cfg.routed_scaling_factor
+
+
 def _row_tile(n_assign: int) -> int:
     """Rows of one grouped-product tile: small while a tick's few rows per
-    expert would mostly be padding, MXU-high for a prefill."""
+    expert would mostly be padding, MXU-high for a prefill, and for a
+    training step's tens of thousands of rows an expert high enough that
+    the weight gradient's float32 accumulator (read and written once a
+    tile) is not what its product waits for."""
+    if n_assign > 65536:
+        return 512
     return 32 if n_assign <= 2048 else 128
+
+
+@jax.custom_vjp
+def _gather_rows(x, row_token, dest, is_held):
+    """``x[row_token]``: the routed rows in their experts' groups.  Its
+    transpose is written as the gather it is — a token reads back the rows
+    of its own held choices, ``dest (T, k)`` — and not as the scatter-add
+    of every row (dead and padding rows among them) that autodiff would
+    derive from ``jnp.take``."""
+    return jnp.take(x, row_token, axis=0)
+
+
+def _gather_rows_fwd(x, row_token, dest, is_held):
+    return jnp.take(x, row_token, axis=0), (dest, is_held)
+
+
+def _gather_rows_bwd(res, d_rows):
+    dest, is_held = res
+    return _weighted_rows(d_rows, None, dest, is_held, mode="clip").astype(
+        d_rows.dtype), None, None, None
+
+
+_gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
+
+
+def _weighted_rows(rows, gates, dest, is_held, mode=None):
+    """``sum_j held[t, j] * gates[t, j] * rows[dest[t, j]]`` in float32
+    (``gates`` None: 1), one choice at a time: each token reads its own
+    held rows back; rows of dead tiles are never named (a non-held choice
+    reads a clamped row and is masked, not multiplied).  ``mode``:
+    ``jnp.take``'s (every ``dest`` is in range: the backward passes say
+    ``'clip'``, whose gather keeps its scope in the compiled program; the
+    forward keeps the default the served programs were compiled with)."""
+    y = jnp.zeros((dest.shape[0], rows.shape[1]), jnp.float32)
+    for j in range(dest.shape[1]):
+        picked = jnp.take(rows, dest[:, j], axis=0, mode=mode).astype(
+            jnp.float32)
+        if gates is not None:
+            picked = picked * gates[:, j, None]
+        y = y + jnp.where(is_held[:, j, None], picked, 0.0)
+    return y
+
+
+@jax.custom_vjp
+def _combine(rows, gates, dest, is_held, row_token):
+    """The gather-combine of the experts' result rows.  Transposed, a
+    result row's cotangent is its token's, times its gate (``row_token``:
+    another gather, no scatter of ``(M, D)`` rows)."""
+    return _weighted_rows(rows, gates, dest, is_held)
+
+
+def _combine_fwd(rows, gates, dest, is_held, row_token):
+    return (_weighted_rows(rows, gates, dest, is_held),
+            (rows, gates, dest, is_held, row_token))
+
+
+def _combine_bwd(res, dy):
+    rows, gates, dest, is_held, row_token = res
+    m = rows.shape[0]
+    # each live row's gate (0: a padding or dead row, which no choice names)
+    row_gate = jnp.zeros((m,), jnp.float32).at[
+        jnp.where(is_held, dest, m).reshape(-1)].set(
+            gates.reshape(-1), mode="drop")
+    d_rows = (jnp.take(dy, row_token, axis=0, mode="clip")
+              * row_gate[:, None]).astype(rows.dtype)
+    d_gates = jnp.stack([
+        jnp.where(is_held[:, j],
+                  (jnp.take(rows, dest[:, j], axis=0, mode="clip").astype(
+                      jnp.float32) * dy).sum(-1), 0.0)
+        for j in range(dest.shape[1])], axis=1)
+    return d_rows, d_gates.astype(gates.dtype), None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def _held_experts_product(x, p, idx, gates, first, n_held: int,
@@ -286,28 +383,22 @@ def _held_experts_product(x, p, idx, gates, first, n_held: int,
             side="right"), n_held - 1)
         n_valid = ends[-1] // tm
     with jax.named_scope("block/moe/gmm"):
-        xs = jnp.take(x, row_token, axis=0)                      # (M, D)
+        dest = jnp.minimum(dest, m_pad - 1).reshape(t, k)
+        xs = _gather_rows(x, row_token, dest, is_held)           # (M, D)
         gmm = lambda lhs, w: moe_gmm(lhs, w, tile_expert, n_valid, tm=tm,
                                      interpret=interpret)
         hidden = (jax.nn.silu(gmm(xs, p["w_gate"]).astype(jnp.float32))
                   * gmm(xs, p["w_up"]).astype(jnp.float32)).astype(x.dtype)
         rows = gmm(hidden, p["w_down"])                          # (M, D)
-        # gather-combine, one choice at a time: each token reads its own
-        # held rows back; rows of dead tiles are never named (a non-held
-        # choice reads a clamped row and is masked, not multiplied)
-        dest = jnp.minimum(dest, m_pad - 1).reshape(t, k)
-        y = jnp.zeros((t, d), jnp.float32)
-        for j in range(k):
-            picked = jnp.take(rows, dest[:, j], axis=0).astype(jnp.float32)
-            y = y + jnp.where(is_held[:, j, None],
-                              picked * gates[:, j, None], 0.0)
+        y = _combine(rows, gates, dest, is_held, row_token)
     return y, counts
 
 
 def moe_dropless(x, params, cfg, *, live=None,
                  interpret: Optional[bool] = None):
     """Dropless expert FFN of ``x (T, D)``: ``(y, counts, idx)`` — ``y =
-    E_shared(x) + Σ_{chosen ∧ held here} gate · E_i(x)``, the int32
+    E_shared(x) + Σ_{chosen ∧ held here} gate · E_i(x)`` (no ``E_shared``
+    where ``cfg.n_shared`` is 0), the int32
     routing-count vector (:data:`COUNT_FIELDS`, then the tokens of each
     held expert) and the chosen experts ``idx (T, top_k)``.
 
@@ -327,20 +418,32 @@ def moe_dropless(x, params, cfg, *, live=None,
     The grouped product is the kernel ``moe_gmm`` on a TPU and a dense
     loop over the held experts elsewhere; ``interpret=True`` runs the
     kernel path in interpret mode (tests).
+
+    Differentiable in ``x`` and every parameter: through the gates (into
+    the router), the rows' gather, the three grouped products
+    (``moe_gmm``'s own VJP) and the gather-combine; the choice of experts
+    and the counts carry no gradient.
     """
     from .blocks import swiglu
 
     use_kernel = interpret is not None or jax.default_backend() == "tpu"
     first, n_held = cfg.held
     with jax.named_scope("block/moe/route"):
-        idx, gates = sigmoid_group_route(x, params["router"],
-                                         params["router_bias"], cfg)
+        if cfg.router == "softmax":
+            idx, gates = softmax_topk_route(x, params["router"], cfg)
+        elif cfg.router == "sigmoid_group":
+            idx, gates = sigmoid_group_route(x, params["router"],
+                                             params["router_bias"], cfg)
+        else:
+            raise ValueError(f"MoEConfig.router {cfg.router!r}: "
+                             "'sigmoid_group' or 'softmax'")
         if live is not None:
             idx = jnp.where(live[:, None], idx, jnp.int32(cfg.n_experts))
     y, per_expert = _held_experts_product(
         x, params, idx, gates, first, n_held, use_kernel, bool(interpret))
-    with jax.named_scope("block/moe/shared"):
-        y = y + swiglu(x, params["shared"]).astype(jnp.float32)
+    if cfg.n_shared:
+        with jax.named_scope("block/moe/shared"):
+            y = y + swiglu(x, params["shared"]).astype(jnp.float32)
     with jax.named_scope("block/moe/route"):    # the routing's counts
         n_rows = jnp.int32(x.shape[0]) if live is None else live.sum()
         counts = jnp.concatenate([
@@ -348,5 +451,31 @@ def moe_dropless(x, params, cfg, *, live=None,
                        per_expert.sum(),
                        (per_expert > 0).sum().astype(jnp.int32)]),
             per_expert])
-    with jax.named_scope("block/moe/shared"):
+    # (the cast of the layer's sum: the shared expert's scope where there
+    # is one, as ever; else the product's)
+    with jax.named_scope("block/moe/shared" if cfg.n_shared
+                         else "block/moe/gmm"):
         return y.astype(x.dtype), counts, idx
+
+
+def book_routing_counts(counts, steps: int = 1) -> None:
+    """Book routing-count vectors that are ALREADY ON THE HOST (a numpy
+    array or a list of ints, :data:`COUNT_FIELDS` then one entry a held
+    expert, summed over ``steps`` train steps and over the expert layers)
+    with the process tracer, as counters ``train/moe_steps``,
+    ``train/moe_assignments_total``, ``train/moe_assignments_held`` and
+    ``train/moe_expert_tokens/<i>``.  Nothing here touches the
+    device: the caller reads the step's aux back where it reads its loss
+    back anyway, or hands in a step whose result is known to be ready.  Off
+    (one attribute read) while the tracer is disabled."""
+    from ..observability import trace as _trace
+
+    tr = _trace.get_tracer()
+    if not tr.enabled:
+        return
+    n = len(COUNT_FIELDS)
+    tr.add_counter("train/moe_steps", float(steps))
+    for name, value in zip(COUNT_FIELDS[:2], counts[:2]):
+        tr.add_counter(f"train/moe_{name}", float(value))
+    for i, value in enumerate(counts[n:]):
+        tr.add_counter(f"train/moe_expert_tokens/{i}", float(value))

@@ -108,7 +108,8 @@ def _project_qkv(h, a, head_dim: int, axis_name: str, bias: bool = True):
 
 def tp_attention(x, params, *, head_dim: int, axis_name: str,
                  causal: bool = True, attn_impl: str = "auto",
-                 positions=None, bias: bool = True):
+                 positions=None, bias: bool = True, arch=None,
+                 layer: int = 0):
     """Multi-head self-attention with heads sharded over ``axis_name``.
 
     ``x``: replicated-local ``(B, S, D)``; ``params``: local shards
@@ -118,34 +119,64 @@ def tp_attention(x, params, *, head_dim: int, axis_name: str,
     ``wo (D/P, D)``, replicated ``bo (D,)`` (``bias=False``: none of the
     three biases).  One psum (in the row-parallel output projection) per
     call.
+
+    ``arch`` / ``layer`` (``blocks.LMArch``; None: none of either) give the
+    layer its rotation (``arch.rotary[layer]``: theta, fraction, YaRN —
+    the same ``blocks.turn_qk`` the serving prefill calls) and its window
+    (``arch.window(layer) = W``: query ``q`` sees keys ``0 <= q - k < W``,
+    the banded flash kernels forward and backward, or the band in the
+    materializing path's mask).  The projections lie under the scope
+    ``proj``, rotation and attention under ``core`` (a windowed layer's
+    inside ``block/attn/window``, as the serving prefill's) — inside
+    :func:`tp_block`'s ``block/attn``: the leaves ``block/attn/proj`` and
+    ``block/attn/core`` of docs/OBSERVABILITY.md.
     """
     from ..ops.flash_attention import resolve_attn_impl
+    from . import blocks as _blocks
 
+    arch = _blocks.resolve(arch)
     b, s, d = x.shape
     attn_impl = resolve_attn_impl(attn_impl, s)
-    q, k, v = _project_qkv(x, params, head_dim, axis_name, bias)
+    window = arch.window(layer)
+    with jax.named_scope("proj"):
+        q, k, v = _project_qkv(x, params, head_dim, axis_name, bias)
     h_local = q.shape[2]
 
-    if positions is not None:  # RoPE (positions are global token indices)
-        q = apply_rope(q, positions)
-        k = apply_rope(k, positions)
+    def core(q, k, v):
+        # the layer's own rotation, else plain RoPE where the model has no
+        # position table (positions are global token indices), else none
+        at = jnp.arange(s) if positions is None and arch.rotary is not None \
+            else positions
+        q, k = _blocks.turn_qk(arch, layer, q, k, at, positions is not None)
+        return _attend_local_heads(q, k, v, causal=causal,
+                                   attn_impl=attn_impl, head_dim=head_dim,
+                                   window=window)
 
-    ctx = _attend_local_heads(q, k, v, causal=causal, attn_impl=attn_impl,
-                              head_dim=head_dim)
-    ctx = ctx.reshape(b, s, h_local * head_dim)             # (B, S, D/P)
-    return row_parallel_dense(ctx, params["wo"],
-                              params["bo"] if bias else None,
-                              axis_name=axis_name)
+    with jax.named_scope("core"):
+        if window:
+            with jax.named_scope("block/attn/window"):
+                ctx = core(q, k, v)
+        else:
+            ctx = core(q, k, v)
+    with jax.named_scope("proj"):
+        ctx = ctx.reshape(b, s, h_local * head_dim)         # (B, S, D/P)
+        return row_parallel_dense(ctx, params["wo"],
+                                  params["bo"] if bias else None,
+                                  axis_name=axis_name)
 
 
-def _attend_local_heads(q, k, v, *, causal, attn_impl, head_dim):
+def _attend_local_heads(q, k, v, *, causal, attn_impl, head_dim,
+                        window=None):
     """Attention over this chip's heads, full sequence: ``q (B, S, Hl, hd)``,
-    GQA-aware (``k``/``v`` may carry fewer heads).  Shared by the
+    GQA-aware (``k``/``v`` may carry fewer heads).  ``window``: the band
+    ``0 <= q - k < window`` (causal only).  Shared by the
     replicated-activation (:func:`tp_attention`) and Megatron-SP
     (:func:`tp_attention_sp`) paths."""
+    if window and not causal:
+        raise ValueError("a window is the causal band 0 <= q - k < window")
     if attn_impl == "flash":
         from ..ops.flash_attention import flash_attention
-        return flash_attention(q, k, v, causal=causal)
+        return flash_attention(q, k, v, causal=causal, window=window or None)
     h_local, s = q.shape[2], q.shape[1]
     if k.shape[2] != h_local:  # GQA on the materializing path
         g = h_local // k.shape[2]
@@ -155,7 +186,10 @@ def _attend_local_heads(q, k, v, *, causal, attn_impl, head_dim):
                         preferred_element_type=jnp.float32)
     scores = scores / (head_dim ** 0.5)
     if causal:
-        mask = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
+        dist = jnp.arange(s)[:, None] - jnp.arange(s)[None, :]
+        mask = dist >= 0
+        if window:
+            mask = mask & (dist < window)
         scores = jnp.where(mask[None, None], scores, -1e30)
     p = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
@@ -172,55 +206,75 @@ def tp_block(x, params, *, head_dim: int, axis_name: str, causal: bool = True,
     description ``parallel/decode.py`` reads, so a model is described once
     for training, prefill and the decode tick.  ``layer`` picks the
     layer's kinds (attention: MHA | MLA | gated delta rule; dense MLP |
-    experts) from it."""
+    experts), its window and its rotation from it."""
+    return _tp_block_routed(x, params, head_dim=head_dim,
+                            axis_name=axis_name, causal=causal,
+                            attn_impl=attn_impl, positions=positions,
+                            arch=arch, layer=layer)[0]
+
+
+def _tp_block_routed(x, params, *, head_dim: int, axis_name: str,
+                     causal: bool = True, attn_impl: str = "auto",
+                     positions=None, arch=None, layer: int = 0):
+    """:func:`tp_block` as ``(x, routing)``: ``routing`` is what
+    ``blocks.ffn`` gives beside the result — None for a dense layer, the
+    routing-count vector and the chosen experts for an expert layer."""
     from . import blocks as _blocks
 
     arch = _blocks.resolve(arch)
     kind = arch.attn_kind(layer)
-    if kind == "mha" and (arch.window(layer) or arch.attn_gate
-                          or arch.rotary is not None):
-        # no silent full-attention substitute: the loss path has no band
-        # (the banded flash kernel is forward only), no output gate and no
-        # per-layer rotation yet
+    if kind == "mha" and arch.attn_gate:
+        # no silent ungated substitute: the loss path has no output gate
+        # yet (a window and a per-layer rotation it runs)
         raise NotImplementedError(
             f"tp_block: layer {layer} is described with window="
             f"{arch.window(layer)}, attn_gate={arch.attn_gate}, rotary="
-            f"{arch.rotary is not None}; the training block runs none of "
-            f"the three (serving does: parallel/decode.py)")
-    with jax.named_scope("block/kda" if kind == "kda" else "block/attn"):
-        h = _blocks.norm(arch, x, params, "ln1")
-        if kind == "kda":
-            # a gated delta-rule layer from a zero state, in the chunked
-            # form (plain XLA: matmuls, a triangular solve and a scan, all
-            # of which differentiate)
-            from .kda import kda_layer
-            state, window = (jnp.zeros((x.shape[0],) + shape, dtype)
-                             for shape, dtype in zip(
-                                 arch.kda.state_shapes,
-                                 (jnp.float32, x.dtype)))
-            x = x + kda_layer(arch.kda, h, params["attn"], state, window,
-                              None, arch.norm_eps)[0]
-        elif kind == "mla":
-            from ..ops.flash_attention import resolve_attn_impl
-            s = x.shape[1]
-            q_nope, q_rope, c_kv, k_rope = _blocks.mla_project(
-                arch.mla, h, params["attn"],
-                jnp.arange(s) if positions is None else positions,
-                arch.norm_eps)
-            ctx = _blocks.mla_attend_prefill(
-                arch.mla, q_nope, q_rope, c_kv, k_rope, params["attn"],
-                resolve_attn_impl(attn_impl, s))
-            x = x + jnp.matmul(ctx, params["attn"]["wo"],
-                               preferred_element_type=jnp.float32
-                               ).astype(x.dtype)
-        else:
-            x = x + tp_attention(h, params["attn"], head_dim=head_dim,
-                                 axis_name=axis_name, causal=causal,
-                                 attn_impl=attn_impl, positions=positions,
-                                 bias=arch.attn_bias)
+            f"{arch.rotary is not None}; the training block runs no "
+            f"output gate (serving does: parallel/decode.py)")
+    if kind == "mha":
+        # the attention half's own leaves inside ``block/attn``: ``proj``
+        # (norm, projections, residual) and ``core`` (``tp_attention``)
+        with jax.named_scope("block/attn"):
+            with jax.named_scope("proj"):
+                h = _blocks.norm(arch, x, params, "ln1")
+            y = tp_attention(h, params["attn"], head_dim=head_dim,
+                             axis_name=axis_name, causal=causal,
+                             attn_impl=attn_impl, positions=positions,
+                             bias=arch.attn_bias, arch=arch, layer=layer)
+            with jax.named_scope("proj"):
+                x = x + y
+    else:
+        with jax.named_scope("block/kda" if kind == "kda"
+                             else "block/attn"):
+            h = _blocks.norm(arch, x, params, "ln1")
+            if kind == "kda":
+                # a gated delta-rule layer from a zero state, in the chunked
+                # form (plain XLA: matmuls, a triangular solve and a scan, all
+                # of which differentiate)
+                from .kda import kda_layer
+                state, window = (jnp.zeros((x.shape[0],) + shape, dtype)
+                                 for shape, dtype in zip(
+                                     arch.kda.state_shapes,
+                                     (jnp.float32, x.dtype)))
+                x = x + kda_layer(arch.kda, h, params["attn"], state, window,
+                                  None, arch.norm_eps)[0]
+            else:
+                from ..ops.flash_attention import resolve_attn_impl
+                s = x.shape[1]
+                q_nope, q_rope, c_kv, k_rope = _blocks.mla_project(
+                    arch.mla, h, params["attn"],
+                    jnp.arange(s) if positions is None else positions,
+                    arch.norm_eps)
+                ctx = _blocks.mla_attend_prefill(
+                    arch.mla, q_nope, q_rope, c_kv, k_rope, params["attn"],
+                    resolve_attn_impl(attn_impl, s))
+                x = x + jnp.matmul(ctx, params["attn"]["wo"],
+                                   preferred_element_type=jnp.float32
+                                   ).astype(x.dtype)
     with jax.named_scope("block/mlp"):
         h = _blocks.norm(arch, x, params, "ln2")
-        return x + _blocks.ffn(arch, layer, h, params, axis_name)[0]
+        y, routing = _blocks.ffn(arch, layer, h, params, axis_name)
+        return x + y, routing
 
 
 def tp_attention_sp(x, params, *, head_dim: int, axis_name: str,
@@ -409,7 +463,8 @@ def vocab_parallel_logits_loss(h, table, targets, *, axis_name: str,
 
 def tp_transformer_lm_loss(params, batch, *, head_dim: int, axis_name: str,
                            causal: bool = True, attn_impl: str = "auto",
-                           ce_impl: str = "auto", arch=None):
+                           ce_impl: str = "auto", arch=None,
+                           remat: bool = False, aux: bool = False):
     """Per-token mean NLL of a decoder-only LM over the LOCAL batch shard.
 
     ``batch``: ``(tokens (B, S+1) int32,)`` — inputs are ``[:, :-1]``,
@@ -417,6 +472,19 @@ def tp_transformer_lm_loss(params, batch, *, head_dim: int, axis_name: str,
     (``functools.partial`` the static args first).  ``ce_impl`` selects
     the loss path (see :func:`vocab_parallel_logits_loss`).  ``arch``: the
     model's ``blocks.LMArch`` (None = the GPT-2-style default).
+
+    ``remat=True`` recomputes each block in the backward pass
+    (``jax.checkpoint`` around a block: what a block saves is its input
+    alone, and its intermediates — an expert layer's gathered rows and
+    three grouped products among them — live for one layer at a time).
+
+    ``aux=True`` (a model with expert layers) returns ``(loss, {'counts',
+    'routes'})``: the layers' int32 routing-count vectors
+    (``moe.COUNT_FIELDS`` then one entry a held expert) summed over the
+    expert layers, and the chosen experts ``(B, S, expert layers, top_k)``
+    — with ``make_hybrid_shard_map_step(has_aux=True, aux_specs={'counts':
+    P(), 'routes': P(data_axis)})`` the step hands the counts out summed
+    over the data axis and the routes a sample.
     """
     from . import blocks as _blocks
     from .tensor_parallel import vocab_parallel_embedding
@@ -434,15 +502,29 @@ def tp_transformer_lm_loss(params, batch, *, head_dim: int, axis_name: str,
             x = x + params["pos_embed"][: x.shape[1]][None]
         else:  # RoPE model (init with pos_impl='rope'): rotate in attention
             positions = jnp.arange(x.shape[1])
+    routing = []
     for i, blk in enumerate(params["blocks"]):
-        x = tp_block(x, blk, head_dim=head_dim, axis_name=axis_name,
-                     causal=causal, attn_impl=attn_impl, positions=positions,
-                     arch=arch, layer=i)
+        block = partial(_tp_block_routed, head_dim=head_dim,
+                        axis_name=axis_name, causal=causal,
+                        attn_impl=attn_impl, arch=arch, layer=i)
+        if remat:
+            block = jax.checkpoint(block)
+        x, routed = block(x, blk, positions=positions)
+        if routed is not None:
+            routing.append(routed)
     with jax.named_scope("head_ce"):
         x = _blocks.norm(arch, x, params, "lnf")
-        return vocab_parallel_logits_loss(
+        loss = vocab_parallel_logits_loss(
             x, _blocks.head_table(arch, params), targets,
             axis_name=axis_name, ce_impl=ce_impl)
+    if not aux:
+        return loss
+    if not routing:
+        raise ValueError("aux: the model has no expert layer to count")
+    with jax.named_scope("block/moe/route"):    # the layers' counts summed
+        return loss, {
+            "counts": sum((c for c, _ in routing[1:]), routing[0][0]),
+            "routes": jnp.stack([idx for _, idx in routing], axis=2)}
 
 
 def sp_block(x, params, *, head_dim: int, axis_name: str, causal: bool = True,

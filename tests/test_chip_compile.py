@@ -290,6 +290,95 @@ def test_lm_135m_train_step_in_shard_map(topo, as_tpu):
             < 16 * 2 ** 30)      # fits one v5e chip's HBM
 
 
+def test_windowed_expert_train_step_in_shard_map(topo, as_tpu):
+    """The train step of a model with sliding-window and full GQA layers and
+    softmax-routed experts (``mellum2-12b-ep4``'s published widths, one
+    sliding and one full layer, one 8192-token sequence) for the described
+    v5e: the banded and the causal flash kernels forward and backward (GQA
+    group 8), the grouped expert product with its weight-gradient kernel,
+    the fused loss at d 2304 (a scoped-VMEM limit of its own), per-layer
+    recomputation, the routing counts out as aux (ISSUE 38)."""
+    import json
+
+    import optax
+
+    from chainermn_tpu.parallel import (make_hybrid_shard_map_step,
+                                        state_specs_like,
+                                        tp_transformer_lm_loss)
+    from chainermn_tpu.parallel.blocks import (LMArch, MoEConfig, Rotary,
+                                               lm_specs)
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(os.path.dirname(here), "benchmark", "configs",
+                           "mellum2-12b-ep4.json")) as f:
+        cfg = json.load(f)
+    d, hd = cfg["hidden_size"], cfg["head_dim"]
+    heads, kv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+    held, inner = cfg["num_experts_held"], cfg["moe_intermediate_size"]
+    vocab, seq = cfg["vocab_size"], 8192
+    full = cfg["rope_parameters"]["full_attention"]
+    arch = LMArch(
+        norm="rmsnorm", norm_eps=cfg["rms_norm_eps"], mlp="swiglu",
+        attn="mha", tied_head=False, embed_scale=False, attn_bias=False,
+        layer_kinds=("moe", "moe"), windows=(cfg["sliding_window"], None),
+        rotary=(Rotary(theta=5e5), Rotary(
+            theta=5e5, yarn=(full["factor"],
+                             full["original_max_position_embeddings"],
+                             full["beta_fast"], full["beta_slow"]),
+            attention_factor=full["attention_factor"])),
+        moe=MoEConfig(n_experts=cfg["num_experts"],
+                      top_k=cfg["num_experts_per_tok"], n_group=1,
+                      topk_group=1, routed_scaling_factor=1.0,
+                      held=(0, held), router="softmax", n_shared=0))
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    block = {"ln1_scale": f32(d), "ln2_scale": f32(d),
+             "attn": {"wq": f32(d, heads * hd), "wkv": f32(d, 2 * kv * hd),
+                      "wo": f32(heads * hd, d)},
+             "moe": {"router": f32(d, cfg["num_experts"]),
+                     "w_gate": f32(held, d, inner),
+                     "w_up": f32(held, d, inner),
+                     "w_down": f32(held, inner, d)}}
+    params = {"embed": f32(vocab, d), "head": f32(vocab, d),
+              "lnf_scale": f32(d), "blocks": [block, block]}
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    specs = lm_specs(arch, params, "model")
+    lm_loss = partial(tp_transformer_lm_loss, head_dim=hd, axis_name="model",
+                      attn_impl="flash", ce_impl="fused", arch=arch,
+                      remat=True, aux=True)
+    optimizer = optax.adamw(3e-4)
+    step = make_hybrid_shard_map_step(
+        lambda p, batch: lm_loss(jax.tree_util.tree_map(
+            lambda a: a.astype(jnp.bfloat16), p), batch),
+        optimizer, mesh, params, specs, data_axis="data",
+        batch_spec=P("data"), has_aux=True,
+        aux_specs={"counts": P(), "routes": P("data")})
+    shaped = lambda tree, sp: jax.tree_util.tree_map(
+        lambda a, spec: _sds(a.shape, a.dtype, NamedSharding(mesh, spec)),
+        tree, sp)
+    opt_state = jax.eval_shape(optimizer.init, params)
+    compiled = step.lower(
+        shaped(params, specs),
+        shaped(opt_state, state_specs_like(optimizer, params, specs)),
+        (_sds((1, seq + 1), jnp.int32, NamedSharding(mesh, P("data"))),)
+    ).compile()
+    text = compiled.as_text()
+    assert "HloModule jit_train_step" in text
+    # every kernel by its own name: the profiler's ops line is read by them
+    for kernel in ("window_flash_fwd", "window_flash_bwd", "flash_fwd",
+                   "flash_bwd", "moe_gmm", "moe_gmm_dw", "fused_ce_stats",
+                   "fused_ce_dh", "fused_ce_dtable"):
+        assert f"%{kernel}" in text, kernel
+    # the weight gradient is written into the zero buffer it is handed
+    assert "input_output_alias" in text
+    # the step's leaves, by which ``train_ms.*`` split it
+    _assert_scopes(text, "/loss_grad/", "/optimizer/", "block/attn/proj",
+                   "block/attn/core", "block/attn/window", "block/moe/route",
+                   "block/moe/dispatch", "block/moe/gmm", "head_ce")
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 15 * 2 ** 30)
+
+
 def _assert_pool_written_in_place(text: str, pool_shape) -> None:
     """The program takes the cache pool donated: its result aliases the
     argument and no buffer of the pool's shape is copied to be written

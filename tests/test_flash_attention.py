@@ -508,3 +508,96 @@ class TestSubBlockSchedule:
             trace.get_tracer().reset()
             if not was:
                 obs.disable()
+
+
+# --------------------------------------------------------------------------
+# the band in the backward (ISSUE 38): window_flash_bwd and the XLA fallback
+# --------------------------------------------------------------------------
+
+def _banded_attention(q, k, v, window):
+    """Explicit-mask attention: query ``t`` sees keys ``0 <= t - s <
+    window``; GQA by repeating the KV heads."""
+    s, d = q.shape[1], q.shape[-1]
+    g = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / d ** 0.5
+    dist = jnp.arange(s)[:, None] - jnp.arange(s)[None, :]
+    seen = (dist >= 0) & (dist < window)
+    p = jax.nn.softmax(jnp.where(seen, scores, -1e30), -1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+BAND_CASES = [
+    # s, window, heads (q, kv), backward blocks (q, k)
+    (512, 64, (2, 2), (256, 256)),      # W < the sub-block (128)
+    (512, 128, (2, 1), (256, 256)),     # W = the sub-block
+    (512, 256, (1, 1), (256, 256)),     # W = the block
+    (256, 1000, (2, 2), (128, 128)),    # W > S: the causal kernel's work
+    (200, 48, (2, 1), (128, 128)),      # S no block multiple: pad and tail
+    (512, 200, (8, 1), (256, 512)),     # group 8, an edge off a sub-block
+    (768, 130, (1, 1), (128, 256)),     # group 1, cells wholly off the band
+]
+
+
+@pytest.mark.parametrize("backward", ["pallas", "xla"])
+@pytest.mark.parametrize("s,window,heads,blocks", BAND_CASES,
+                         ids=lambda v: str(v).replace(" ", ""))
+def test_banded_backward_is_the_masked_softmaxs(s, window, heads, blocks,
+                                                backward):
+    h, h_kv = heads
+    keys = jax.random.split(jax.random.PRNGKey(s + window), 4)
+    q = jax.random.normal(keys[0], (1, s, h, 16))
+    k = jax.random.normal(keys[1], (1, s, h_kv, 16))
+    v = jax.random.normal(keys[2], (1, s, h_kv, 16))
+    ct = jax.random.normal(keys[3], (1, s, h, 16))
+    got = jax.grad(lambda *a: (flash_attention(
+        *a, causal=True, window=window, backward=backward,
+        bwd_block_q=blocks[0], bwd_block_k=blocks[1]) * ct).sum(),
+        (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: (_banded_attention(*a, window) * ct).sum(),
+                    (0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-5)
+
+
+def test_banded_backward_runs_the_bands_sub_blocks_and_books_them():
+    """At S 8192, W 1024 (the training cell's sliding layers) the backward's
+    schedule computes about a quarter of the causal triangle's sub-block
+    pairs, and a window that covers the sequence degenerates to the causal
+    schedule's work; a traced banded call books its own schedule."""
+    from chainermn_tpu import observability as obs
+    from chainermn_tpu.observability import trace
+
+    mod = _module()
+    band = mod.causal_schedule(8192, 512, 2048, True, None, 1024)
+    full = mod.causal_schedule(8192, 512, 2048)
+    assert full["total"] == 64 * 64 and full["run"] == 64 * 65 // 2
+    # a row of Q sub-blocks: the diagonal's, eight behind it, less the
+    # first rows' missing history
+    assert band["run"] == 64 * 9 - 36
+    assert band["run"] / full["run"] < 0.27
+    wide = mod.causal_schedule(1024, 512, 1024, True, None, 4096)
+    plain = mod.causal_schedule(1024, 512, 1024)
+    assert (wide["run"], wide["masked"]) == (plain["run"], plain["masked"])
+    q, k, v = (jnp.asarray(x) for x in _qkv_default(512, h=5))
+    sched = mod.causal_schedule(512, 256, 512, True, None, 128)
+    heads = q.shape[0] * q.shape[2]
+    was = trace.get_tracer().enabled
+    obs.enable()
+    try:
+        trace.get_tracer().reset()
+        jax.jit(jax.grad(lambda q: flash_attention(
+            q, k, v, causal=True, window=128, block_q=256, block_k=512,
+            bwd_block_q=256, bwd_block_k=512,
+            backward="pallas").sum()))(q)
+        counters = trace.get_tracer().counters()
+        traces, rest = divmod(counters["flash/score_blocks_total"],
+                              heads * sched["total"])
+        assert rest == 0 and traces >= 2
+        assert (counters["flash/score_blocks_run"]
+                == traces * heads * sched["run"])
+    finally:
+        trace.get_tracer().reset()
+        if not was:
+            obs.disable()

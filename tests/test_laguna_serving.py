@@ -199,10 +199,15 @@ def test_band_schedule_skips_what_lies_left_of_it():
 
 
 def test_banded_flash_is_forward_only():
-    q = jnp.ones((1, 32, 2, 16))
-    with pytest.raises(NotImplementedError, match="forward only"):
-        jax.grad(lambda q: flash_attention(
-            q, q, q, causal=True, window=8).sum())(q)
+    """It was, until ISSUE 38 (the name stays so that the count does): the
+    band now differentiates, against the masked softmax's own gradient;
+    a window without ``causal`` is still refused."""
+    q = jax.random.normal(jax.random.PRNGKey(0), (1, 32, 2, 16))
+    got = jax.grad(lambda q: (flash_attention(
+        q, q, q, causal=True, window=8) ** 2).sum())(q)
+    want = jax.grad(lambda q: (_masked_softmax(q, q, q, 8) ** 2).sum())(q)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-4, atol=2e-4)
     with pytest.raises(ValueError, match="window"):
         flash_attention(q, q, q, causal=False, window=8)
 

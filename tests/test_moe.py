@@ -269,3 +269,123 @@ class TestTop2Routing:
         params, x = params_and_tokens(seed=10)
         with pytest.raises(ValueError, match="router_topk"):
             make_moe_mlp(E, mesh=mesh, router_topk=3)(x, params)
+
+
+# --------------------------------------------------------------------------
+# the grouped expert product differentiates (ISSUE 38): moe_gmm's VJP —
+# dX by the forward kernel on the transposed weights, dW by ``moe_gmm_dw``
+# --------------------------------------------------------------------------
+
+def _grouped(counts, tm, dead_tiles):
+    """Tile-aligned groups for per-expert ``counts``: ``(tile_expert,
+    n_valid, rows, live rows mask)`` with ``dead_tiles`` tiles of worst-case
+    padding past ``n_valid``."""
+    counts = np.asarray(counts)
+    padded = -(-counts // tm) * tm
+    ends = np.cumsum(padded)
+    n_valid = int(ends[-1]) // tm
+    m = (n_valid + dead_tiles) * tm
+    tile_expert = np.minimum(np.searchsorted(
+        ends, np.arange(m // tm) * tm, side="right"),
+        len(counts) - 1).astype(np.int32)
+    live = np.zeros(m, bool)
+    for e, (end, pad, n) in enumerate(zip(ends, padded, counts)):
+        live[end - pad:end - pad + n] = True
+    return tile_expert, n_valid, m, live
+
+
+@pytest.mark.parametrize("counts,dead", [
+    ((13, 0, 5, 8), 0),       # an empty expert, ragged groups
+    ((8, 16, 1, 24), 3),      # n_valid below the tile count
+    ((0, 0, 9, 0), 2),        # one expert has every row
+    ((0, 0, 0, 0), 2),        # nobody has any: nothing is live
+], ids=["empty+ragged", "dead-tiles", "one-expert", "no-rows"])
+def test_moe_gmm_vjp_is_the_dense_loops(counts, dead):
+    from chainermn_tpu.ops.moe_gmm import moe_gmm
+
+    tm, k, n = 8, 16, 24
+    tile_expert, n_valid, m, live = _grouped(counts, tm, dead)
+    rng = np.random.default_rng(sum(counts) + dead)
+    x = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(len(counts), k, n)), jnp.float32)
+    ct = jnp.asarray(rng.normal(size=(m, n)), jnp.float32)
+    # the caller's contract: dead tiles' rows are masked, padding rows of a
+    # live tile are computed and never read back
+    keep = jnp.asarray(live)[:, None]
+
+    def kernel(x, w):
+        y = moe_gmm(x, w, tile_expert, n_valid, tm=tm, interpret=True)
+        return (jnp.where(keep, y, 0.0) * ct).sum()
+
+    def dense(x, w):
+        y = jnp.zeros((m, n))
+        for e in range(len(counts)):       # a plain loop over the experts
+            mine = jnp.asarray(np.repeat(tile_expert, tm) == e)[:, None]
+            y = y + jnp.where(mine, x @ w[e], 0.0)
+        return (jnp.where(keep, y, 0.0) * ct).sum()
+
+    got = jax.grad(kernel, (0, 1))(x, w)
+    want = jax.grad(dense, (0, 1))(x, w)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+    # an expert with no rows gets zeros, not what the buffer held
+    for e, c in enumerate(counts):
+        if c == 0:
+            assert not np.asarray(got[1][e]).any()
+    # and nothing flows into the dead tiles' rows
+    assert not np.asarray(got[0])[n_valid * tm:].any()
+
+
+def test_moe_gmm_dead_rows_may_hold_anything():
+    """The forward leaves dead tiles unwritten; a NaN there must reach
+    neither gradient."""
+    from chainermn_tpu.ops.moe_gmm import moe_gmm
+
+    tm = 8
+    tile_expert, n_valid, m, live = _grouped((8, 8), tm, 2)
+    x = jnp.ones((m, 16)).at[n_valid * tm:].set(jnp.nan)
+    w = jnp.ones((2, 16, 8))
+    dx, dw = jax.grad(lambda x, w: jnp.where(
+        jnp.asarray(live)[:, None],
+        moe_gmm(x, w, tile_expert, n_valid, tm=tm, interpret=True),
+        0.0).sum(), (0, 1))(x, w)
+    assert np.isfinite(np.asarray(dw)).all()
+    assert np.isfinite(np.asarray(dx)).all()
+    np.testing.assert_allclose(np.asarray(dw), 8.0)
+
+
+@pytest.mark.parametrize("router", ["softmax", "sigmoid_group"])
+def test_moe_dropless_kernel_path_gradients_are_the_dense_loops(router):
+    """Through the gates, the rows' gather, the three grouped products and
+    the gather-combine: the kernel path's gradients (every parameter and the
+    input) equal the dense fallback's, with and without a shared expert."""
+    from chainermn_tpu.parallel.blocks import MoEConfig
+    from chainermn_tpu.parallel.moe import moe_dropless
+
+    shared = router == "sigmoid_group"
+    cfg = MoEConfig(n_experts=8, top_k=2, n_group=2, topk_group=1,
+                    routed_scaling_factor=1.5, held=(2, 4), router=router,
+                    n_shared=int(shared))
+    ks = jax.random.split(jax.random.PRNGKey(11), 8)
+    d, f, t = 16, 24, 40
+    p = {"router": jax.random.normal(ks[0], (d, 8)),
+         "w_gate": jax.random.normal(ks[1], (4, d, f)) * d ** -0.5,
+         "w_up": jax.random.normal(ks[2], (4, d, f)) * d ** -0.5,
+         "w_down": jax.random.normal(ks[3], (4, f, d)) * f ** -0.5}
+    if shared:
+        p["router_bias"] = jnp.zeros((8,))
+        p["shared"] = {"w_gate": jax.random.normal(ks[4], (d, f)) * 0.2,
+                       "w_up": jax.random.normal(ks[5], (d, f)) * 0.2,
+                       "w_down": jax.random.normal(ks[6], (f, d)) * 0.2}
+    x = jax.random.normal(ks[7], (t, d))
+    ct = jax.random.normal(jax.random.PRNGKey(12), (t, d))
+    loss = lambda x, p, interpret: (moe_dropless(
+        x, p, cfg, interpret=interpret)[0] * ct).sum()
+    got = jax.grad(loss, (0, 1))(x, p, True)
+    want = jax.grad(loss, (0, 1))(x, p, None)
+    assert float(jnp.abs(want[1]["router"]).max()) > 0
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-5)

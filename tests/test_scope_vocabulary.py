@@ -253,3 +253,97 @@ def test_the_table_is_ordered_inner_scopes_first():
             else "tick_ms."
         assert prefix + bucket in names
     assert {"tick_ms.unscoped", "step_ms.unscoped"} <= names
+
+
+# --------------------------------------------------------------------------
+# a trained model with windows, rotations and routed experts (ISSUE 38):
+# every equation of its train step lies under ONE leaf of the step's own
+# vocabulary, which ``benchmark/harness/train_scope_trace.py`` books by
+# --------------------------------------------------------------------------
+
+#: the leaves of a windowed-GQA + routed-experts train step inside
+#: ``loss_grad``; the FFN half's own scope comes last (its experts' nest in it)
+TRAIN_LEAVES = ("embed", "head_ce", "block/attn/proj", "block/attn/core",
+                "block/moe/route", "block/moe/dispatch", "block/moe/gmm",
+                "block/mlp")
+
+
+def _mellum_train_step(devices, remat):
+    import optax
+
+    from chainermn_tpu.parallel import (make_hybrid_shard_map_step,
+                                        tp_transformer_lm_loss)
+    from chainermn_tpu.parallel.blocks import lm_specs
+
+    mod = _fixture("test_mellum2_training")
+    mesh = mn.make_nd_mesh(("data", "model"), (1, 1), devices[:1])
+    params = mod.ref.init_params(jax.random.PRNGKey(0), mod.CFG)
+    optimizer = optax.adamw(1e-3)
+    step = make_hybrid_shard_map_step(
+        partial(tp_transformer_lm_loss, head_dim=mod.HEAD_DIM,
+                axis_name="model", attn_impl="flash", ce_impl="fused",
+                arch=mod.ARCH, remat=remat, aux=True),
+        optimizer, mesh, params, lm_specs(mod.ARCH, params, "model"),
+        data_axis="data", batch_spec=P("data"), has_aux=True, donate=False,
+        aux_specs={"counts": P(), "routes": P("data")})
+    # 128 positions: the flash backward takes its kernels from a lane
+    # multiple on (below it the XLA scan, which the chip never runs here)
+    return jax.make_jaxpr(step)(params, optimizer.init(params),
+                                (np.zeros((2, 128 + 1), np.int32),))
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["saved", "recomputed"])
+def test_every_equation_of_the_expert_train_step_lies_under_one_leaf(
+        devices, monkeypatch, remat):
+    from benchmark.harness import train_scope_trace
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    paths = list(paths_of(_mellum_train_step(devices, remat).jaxpr))
+    assert len(paths) > 500
+    assert {bucket_of(p) for p in paths} == {"fwd", "bwd", "optimizer"}
+    seen = set()
+    for p in paths:
+        path = scope_path(p)
+        if "/optimizer/" in path:
+            assert "/loss_grad/" not in path, p
+            continue
+        assert "/loss_grad/" in path, p
+        leaves = [leaf for leaf in TRAIN_LEAVES if f"/{leaf}/" in path]
+        if not leaves:
+            # the step builder's own: the batch cut into inputs and
+            # targets, the loss's mean over the data axis and the aux's sum
+            # (no equation of the model)
+            assert p.rsplit("/", 1)[-1] in (
+                "slice", "div", "pvary", "psum_invariant"), p
+            continue
+        # one leaf: an expert scope only inside the FFN half, and nothing
+        # of one half under the other's scopes
+        inner = [leaf for leaf in leaves if leaf != "block/mlp"]
+        assert len(inner) <= 1, p
+        # (the layers' counts are summed under the route's scope at the
+        # loss's end, outside any block)
+        if inner and inner[0] in ("block/moe/dispatch", "block/moe/gmm"):
+            assert "block/mlp" in leaves, p
+        if "block/mlp" in leaves:
+            assert "/block/attn/" not in path, p
+        seen.add((inner or leaves)[0])
+        # the banded kernels lie under the window's wrapper, inside core
+        if "window_flash" in p:
+            assert "/block/attn/core/" in path \
+                and "/block/attn/window/" in path, p
+    assert seen == set(TRAIN_LEAVES)
+    # the same table's served rows, the phase's scope taken off the path
+    booked = {train_scope_trace.bucket_of(p) for p in paths}
+    assert booked == {None, "optimizer", "attn_proj", "attn_core",
+                      "moe_route", "moe_experts", "ffn_dense"}
+    assert set(train_scope_trace.TRAIN_BUCKETS) <= booked
+    # each kernel of the step sits where its metric looks for it
+    where = {k: {train_scope_trace.bucket_of(p) for p in paths
+                 if p.endswith("/pallas_call") and f"/{k}/" in p}
+             for k in ("flash_fwd", "flash_bwd", "window_flash_fwd",
+                       "window_flash_bwd", "moe_gmm", "moe_gmm_dw")}
+    assert where == {"flash_fwd": {"attn_core"}, "flash_bwd": {"attn_core"},
+                     "window_flash_fwd": {"attn_core"},
+                     "window_flash_bwd": {"attn_core"},
+                     "moe_gmm": {"moe_experts"},
+                     "moe_gmm_dw": {"moe_experts"}}
