@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from .._compat import pcast_varying
 from ..topology import DEFAULT_AXIS_NAME
 
 
@@ -261,21 +262,71 @@ def _row_tile(n_assign: int) -> int:
     return 32 if n_assign <= 2048 else 128
 
 
-@jax.custom_vjp
-def _gather_rows(x, row_token, dest, is_held):
-    """``x[row_token]``: the routed rows in their experts' groups.  Its
-    transpose is written as the gather it is — a token reads back the rows
-    of its own held choices, ``dest (T, k)`` — and not as the scatter-add
-    of every row (dead and padding rows among them) that autodiff would
-    derive from ``jnp.take``."""
-    return jnp.take(x, row_token, axis=0)
+def _row_chunk(n_assign: int, tm: int) -> Optional[int]:
+    """Rows of one chunk of the backward's row-side pass
+    (:func:`_live_chunks`), a whole number of tiles, or None: the rows'
+    buffer is walked whole.  The line is :func:`_row_tile`'s own — a
+    training step's sizes, where the buffer is 139,264 rows a layer of
+    which a quarter to a half are live; a tick's buffer is under one chunk
+    and a prefill's a few, and neither pays for a ``while``."""
+    return 16 * tm if n_assign > 65536 else None
 
 
-def _gather_rows_fwd(x, row_token, dest, is_held):
-    return jnp.take(x, row_token, axis=0), (dest, is_held)
+def _live_chunks(per_chunk, row_args, n_live, chunk: Optional[int]):
+    """``per_chunk(*row_args)`` — a tuple of arrays by row, of arrays by row
+    (leading dimension ``M``) — computed over the LIVE rows only: the rows'
+    buffer is sorted by expert with its live tiles a prefix of ``n_live``
+    rows, so a ``fori_loop`` of ``ceil(n_live / chunk)`` trips runs
+    ``per_chunk`` on one chunk of every ``row_args`` a trip and writes the
+    results in place into zero-filled buffers.  Rows past the last live
+    chunk hold zeros (nothing reads them: ``moe_gmm`` skips dead tiles and
+    no choice names a dead row); where ``M`` is no multiple of ``chunk`` the
+    last chunk overlaps the one before it.  ``chunk`` None, or a buffer of
+    one chunk or less: ``per_chunk`` over the whole buffer, no loop.  The
+    loop's bound is a traced scalar, so this is called from the backward
+    FUNCTION of a custom VJP and never differentiated."""
+    m = row_args[0].shape[0]
+    if chunk is None or m <= chunk:
+        return per_chunk(*row_args)
+    cut = lambda start: [jax.lax.dynamic_slice_in_dim(a, start, chunk)
+                         for a in row_args]
+    outs = []
+    for like in jax.eval_shape(lambda: per_chunk(*cut(0))):
+        zeros = jnp.zeros((m,) + like.shape[1:], like.dtype)
+        for ax in sorted(like.vma or ()):
+            zeros = pcast_varying(zeros, ax)
+        outs.append(zeros)
+
+    def body(i, outs):
+        start = jnp.minimum(i * chunk, m - chunk)
+        return tuple(jax.lax.dynamic_update_slice_in_dim(out, got, start, 0)
+                     for out, got in zip(outs, per_chunk(*cut(start))))
+
+    return jax.lax.fori_loop(0, -(-n_live // chunk), body, tuple(outs))
 
 
-def _gather_rows_bwd(res, d_rows):
+@partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _gather_rows(x, row_token, dest, is_held, mode):
+    """``x[row_token]``: the routed rows in their experts' groups, ALL
+    ``M`` of them in one gather (a dead row reads ``x[0]``): its source is
+    ``(T, D)``, small enough that the chip's compiler keeps it in fast
+    memory, where a gathered row costs 7 ns — walking the live chunks
+    alone cost MORE, by the copy of each chunk into the buffer and the
+    buffer's zero fill (PERF.md, Findings PR 39).  ``mode``: ``jnp.take``'s (every ``row_token`` is in
+    range: a training step says ``'clip'`` and saves the fill mode's
+    select over all ``M`` rows; the served programs keep the default they
+    were compiled with).  Its transpose is written as the gather it is — a
+    token reads back the rows of its own held choices, ``dest (T, k)`` —
+    and not as the scatter-add of every row (dead and padding rows among
+    them) that autodiff would derive from ``jnp.take``."""
+    return jnp.take(x, row_token, axis=0, mode=mode)
+
+
+def _gather_rows_fwd(x, row_token, dest, is_held, mode):
+    return jnp.take(x, row_token, axis=0, mode=mode), (dest, is_held)
+
+
+def _gather_rows_bwd(mode, res, d_rows):
     dest, is_held = res
     return _weighted_rows(d_rows, None, dest, is_held, mode="clip").astype(
         d_rows.dtype), None, None, None
@@ -302,34 +353,47 @@ def _weighted_rows(rows, gates, dest, is_held, mode=None):
     return y
 
 
-@jax.custom_vjp
-def _combine(rows, gates, dest, is_held, row_token):
-    """The gather-combine of the experts' result rows.  Transposed, a
-    result row's cotangent is its token's, times its gate (``row_token``:
-    another gather, no scatter of ``(M, D)`` rows)."""
+@partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _combine(rows, gates, dest, is_held, row_token, n_live, chunk):
+    """The gather-combine of the experts' result rows.  Transposed, both
+    cotangents are taken on the ROW side, in one pass over the live chunks
+    (:func:`_live_chunks`) that gathers each row's token's cotangent
+    (``row_token``: another gather, no scatter of ``(M, D)`` rows): a
+    result row's is that, times its gate; a gate's is the dot of its row
+    with it — a scalar a row, which goes back to its choice with no row
+    moved.  ``chunk`` None (a tick, a prefill): the same pass over the
+    whole buffer."""
     return _weighted_rows(rows, gates, dest, is_held)
 
 
-def _combine_fwd(rows, gates, dest, is_held, row_token):
+def _combine_fwd(rows, gates, dest, is_held, row_token, n_live, chunk):
     return (_weighted_rows(rows, gates, dest, is_held),
-            (rows, gates, dest, is_held, row_token))
+            (rows, gates, dest, is_held, row_token, n_live))
 
 
-def _combine_bwd(res, dy):
-    rows, gates, dest, is_held, row_token = res
-    m = rows.shape[0]
-    # each live row's gate (0: a padding or dead row, which no choice names)
-    row_gate = jnp.zeros((m,), jnp.float32).at[
-        jnp.where(is_held, dest, m).reshape(-1)].set(
-            gates.reshape(-1), mode="drop")
-    d_rows = (jnp.take(dy, row_token, axis=0, mode="clip")
-              * row_gate[:, None]).astype(rows.dtype)
-    d_gates = jnp.stack([
-        jnp.where(is_held[:, j],
-                  (jnp.take(rows, dest[:, j], axis=0, mode="clip").astype(
-                      jnp.float32) * dy).sum(-1), 0.0)
-        for j in range(dest.shape[1])], axis=1)
-    return d_rows, d_gates.astype(gates.dtype), None, None, None
+def _combine_bwd(chunk, res, dy):
+    rows, gates, dest, is_held, row_token, n_live = res
+    m, a = rows.shape[0], dest.size
+    # each held choice's row (m: none) and, by it, each live row's gate and
+    # choice (0 and a: a padding or dead row, which no choice names)
+    row_of = jnp.where(is_held, dest, m).reshape(-1)
+    row_gate = jnp.zeros((m,), jnp.float32).at[row_of].set(
+        gates.reshape(-1), mode="drop")
+    row_choice = jnp.full((m,), a, jnp.int32).at[row_of].set(
+        jnp.arange(a, dtype=jnp.int32), mode="drop")
+
+    def per_chunk(token, gate, row):
+        dy_row = jnp.take(dy, token, axis=0, mode="clip")
+        return ((dy_row * gate[:, None]).astype(row.dtype),
+                (row.astype(jnp.float32) * dy_row).sum(-1))
+
+    d_rows, row_dot = _live_chunks(per_chunk, [row_token, row_gate, rows],
+                                   n_live, chunk)
+    # (scattered back, not gathered by ``dest``: an index costs the chip
+    # 4 ns scattered and 16 gathered, PERF.md, Findings PR 39)
+    d_gates = jnp.zeros((a,), gates.dtype).at[row_choice].set(
+        row_dot.astype(gates.dtype), mode="drop").reshape(gates.shape)
+    return d_rows, d_gates, None, None, None, None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -339,9 +403,17 @@ def _held_experts_product(x, p, idx, gates, first, n_held: int,
                           use_kernel: bool, interpret: bool):
     """``Σ_{chosen ∧ held} gate · E(x)`` over the held experts ``[first,
     first + n_held)`` and the per-held-expert token counts.  Kernel path:
-    assignments sorted by expert into tile-aligned groups, three grouped
-    products, a gather-combine (no scatter).  Fallback: a dense loop over
-    the held experts (tiny CPU sizes)."""
+    assignments sorted by expert into tile-aligned groups whose live tiles
+    are a prefix of the ``(M, D)`` rows' buffer, three grouped products
+    over that prefix, a gather-combine (no scatter).  The backward's
+    row-side pass (each row's token's cotangent gathered, scaled for the
+    products and dotted with the row for the gates) follows the same work
+    list: at a training step's sizes (the static ``n_assign = T·k``:
+    :func:`_row_chunk`) it walks the live chunks and leaves the dead rows
+    zero, at a tick's and a prefill's it would walk the buffer whole; the
+    rows themselves are gathered whole at every size (:func:`_gather_rows`
+    says why).  Fallback: a dense loop over the held experts (tiny CPU
+    sizes)."""
     t, d = x.shape
     k = idx.shape[1]
     # the index work between the routing and the product: which choices
@@ -381,16 +453,19 @@ def _held_experts_product(x, p, idx, gates, first, n_held: int,
         tile_expert = jnp.minimum(jnp.searchsorted(
             ends, jnp.arange(m_pad // tm, dtype=jnp.int32) * tm,
             side="right"), n_held - 1)
-        n_valid = ends[-1] // tm
+        n_live = ends[-1]
+        n_valid = n_live // tm
+    chunk = _row_chunk(a, tm)
     with jax.named_scope("block/moe/gmm"):
         dest = jnp.minimum(dest, m_pad - 1).reshape(t, k)
-        xs = _gather_rows(x, row_token, dest, is_held)           # (M, D)
+        xs = _gather_rows(x, row_token, dest, is_held,
+                          None if chunk is None else "clip")    # (M, D)
         gmm = lambda lhs, w: moe_gmm(lhs, w, tile_expert, n_valid, tm=tm,
                                      interpret=interpret)
         hidden = (jax.nn.silu(gmm(xs, p["w_gate"]).astype(jnp.float32))
                   * gmm(xs, p["w_up"]).astype(jnp.float32)).astype(x.dtype)
         rows = gmm(hidden, p["w_down"])                          # (M, D)
-        y = _combine(rows, gates, dest, is_held, row_token)
+        y = _combine(rows, gates, dest, is_held, row_token, n_live, chunk)
     return y, counts
 
 

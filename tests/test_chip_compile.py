@@ -379,6 +379,75 @@ def test_windowed_expert_train_step_in_shard_map(topo, as_tpu):
             < 15 * 2 ** 30)
 
 
+def test_expert_layer_backward_walks_the_live_chunks(one_chip, monkeypatch):
+    """The trained expert layer alone — recomputed forward and backward at
+    ``mellum2-ep4-train-s8192``'s sizes (2 x 8192 tokens, 8 of 64 experts a
+    token, 16 held, d 2304) — for the described v5e: the backward's
+    row-side pass is ONE ``while`` loop over the live chunks, which updates
+    the buffers it carries in place — alone it asks for less than ONE
+    chunk's float32 rows of temporaries, where the parent's whole-buffer
+    formulation (``tests/moe_rows_parent.py``) asks for all ``M`` of them
+    (ISSUE 39)."""
+    import json
+    import sys
+
+    from chainermn_tpu.parallel import moe as moe_mod
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    sys.path.insert(0, here)
+    import moe_rows_parent
+
+    with open(os.path.join(os.path.dirname(here), "benchmark", "configs",
+                           "mellum2-12b-ep4.json")) as f:
+        cfg = json.load(f)
+    t, k, d = 2 * 8192, cfg["num_experts_per_tok"], cfg["hidden_size"]
+    held, inner = cfg["num_experts_held"], cfg["moe_intermediate_size"]
+    tm = moe_mod._row_tile(t * k)
+    chunk = moe_mod._row_chunk(t * k, tm)
+    m = -(-(t * k + held * (tm - 1)) // tm) * tm
+    assert (tm, chunk, m) == (512, 8192, 139264)
+    sds = lambda dtype, *shape: _sds(shape, dtype, one_chip)
+    bf16 = partial(sds, jnp.bfloat16)
+    args = (bf16(t, d),
+            {"w_gate": bf16(held, d, inner), "w_up": bf16(held, d, inner),
+             "w_down": bf16(held, inner, d)},
+            sds(jnp.int32, t, k), sds(jnp.float32, t, k))
+
+    def compiled():
+        # (a fresh function a compile: tracing is cached by the function)
+        def layer(x, p, idx, gates):
+            return moe_mod._held_experts_product(
+                x, p, idx, gates, 0, held, True, False)[0].astype(x.dtype)
+
+        def grads(x, p, idx, gates):
+            return jax.grad(lambda x, p, g: jax.checkpoint(layer)(
+                x, p, idx, g).astype(jnp.float32).sum(),
+                argnums=(0, 1, 2))(x, p, gates)
+
+        return jax.jit(grads).lower(*args).compile()
+
+    loops = lambda c: sum(" while(" in ln for ln in c.as_text().split("\n"))
+    new = compiled()
+    with monkeypatch.context() as mp:
+        moe_rows_parent.install(mp)
+        old = compiled()
+    assert loops(new) - loops(old) == 1
+    text = new.as_text()
+    for kernel in ("moe_gmm", "moe_gmm_dw"):
+        assert f"%{kernel}" in text, kernel
+    assert "block/moe/gmm" in text
+
+    # the pass alone, both ways: (rows, gates, dest, is_held, row_token,
+    # n_live), dy -> (d_rows, d_gates)
+    res = (bf16(m, d), sds(jnp.float32, t, k), sds(jnp.int32, t, k),
+           sds(jnp.bool_, t, k), sds(jnp.int32, m), sds(jnp.int32))
+    temp = lambda fn: jax.jit(fn).lower(res, sds(jnp.float32, t, d)) \
+        .compile().memory_analysis().temp_size_in_bytes
+    now = temp(lambda res, dy: moe_mod._combine_bwd(chunk, res, dy)[:2])
+    was = temp(lambda res, dy: moe_rows_parent._combine_bwd(res[:5], dy)[:2])
+    assert now <= chunk * d * 4 and was >= m * d * 4, (now, was)
+
+
 def _assert_pool_written_in_place(text: str, pool_shape) -> None:
     """The program takes the cache pool donated: its result aliases the
     argument and no buffer of the pool's shape is copied to be written
