@@ -1,0 +1,341 @@
+"""Plain Jamba (Lieber et al. 2024, arXiv 2403.19887; ``model_type``
+``jamba`` as HF's ``modeling_jamba.py`` computes it, the equations as ISSUE
+40 of this repository states them), WHOLE: float32 ``jax.numpy``, matmuls at
+``highest`` precision, the Mamba-1 layers as the token-by-token RECURRENCE
+(a ``lax.scan`` over single positions: no chunks, no cache, no kernels, the
+discretisation formed inside the step), dense causal multi-query attention,
+one dense SwiGLU a layer.  Imports nothing of the program and takes nothing
+the program made: weights come from :func:`init_params` (a pure function of
+the seed), tokens from the driver.
+
+Per layer ``x = x + Mixer(RMSNorm_in(x))``, ``x = x + SwiGLU(RMSNorm_ff(
+x))``; layer ``l`` is attention where ``l % attn_layer_period ==
+attn_layer_offset`` and Mamba otherwise; final RMSNorm; logits against the
+embedding (tied), which is NOT scaled.
+
+* Mamba (``E = mamba_expand * hidden``, ``N = mamba_d_state``, ``R =
+  mamba_dt_rank``): ``[u; z] = W_in h``; ``c_t = SiLU(b_conv + sum_j
+  w_conv[j] u_{t-3+j})`` (depthwise, causal, zero before position 0);
+  ``[dt; B; C] = W_x c``, each RMS-normalised with a learned scale; ``Dt =
+  softplus(W_dt dt + b_dt)``; ``A = -exp(A_log)``; ``s_t = exp(Dt_t A) *
+  s_{t-1} + (Dt_t c_t) B_t^T`` (float32, zero at position 0); ``y_t = C_t .
+  s_t + D c_t``; ``W_out (y * SiLU(z))``.
+* Attention: ``num_attention_heads`` query heads on ``num_key_value_heads``
+  K/V heads of ``hidden / heads`` columns, no biases, NO rotation and no
+  position table (the Mamba layers carry the order); causal softmax at
+  ``head^-1/2``.
+
+Departures from the published code, each because the program under test
+stores it so and the check has to follow the same mathematics (the
+configuration's ``assumed`` repeats them):
+
+* ``A_log`` is stored ``(N, E)`` — the published ``(E, N)`` transposed —
+  and the convolution taps ``(4, E)`` with the LAST tap on the current
+  token (``conv1d.weight[:, 0, j]`` is ``conv[j]``);
+* ``k_proj`` and ``v_proj`` are one matrix ``wkv``, columns per K/V head
+  ``[k_h | v_h]``; ``gate_proj`` / ``up_proj`` / ``down_proj`` are
+  ``w_gate`` / ``w_up`` / ``w_down``, stored input-major;
+* the weights are seeded, not trained (``init_params``).
+
+``precision`` selects how every matmul's operands are rounded: ``float32``
+(the reference), ``bfloat16`` (what the configuration states), ``fp8`` (the
+control: e4m3 with one scale per tensor, the nearest precision below bf16 —
+and the recurrent state, which the configuration states as float32, kept in
+bfloat16: rounded after every token).
+"""
+
+import functools
+import json
+import math
+
+import jax
+import jax.numpy as jnp
+
+HIGHEST = jax.lax.Precision.HIGHEST
+
+# ---- limits of the comparison that decides ``correct`` --------------------
+# Each is set between two readings on the chip at the cell's own size (my
+# chip runs, PR 40, calls 1-5; PERF.md, section 2, repeats them with their
+# seeds): the largest that sound runs of the program gave, and the smallest
+# that a control gave — the lower-precision control (``benchmark/control.py``:
+# fp8 matmul operands and a bfloat16 state) and the two broken-state
+# programs (``benchmark/state_control.py``: a tick that skips the decay; a
+# prefill that hands over the state at the padded length).  No routing here,
+# so the logits move continuously with precision; the MEAN gap is limited
+# rather than the widest because a widest gap over some thousands of
+# positions swings by its nature (``reference/gpt2.py``; here 0.17 .. 0.28).
+LIMITS = {
+    # the MEAN gap by which a served token's float32 logit lies below the
+    # float32 best, over every generated position of 8 served requests
+    # (1.2-2.5 thousand; logits of order one over 65536 rows): program
+    # 5.2e-3 .. 7.7e-3 (17 seeds); control 0.930 .. 0.937 (2 seeds); prefill
+    # state at s_pad 1.12; tick without decay 4.18.  3.9 x over the sound
+    # largest, 31 x under the smallest control
+    "served_logit_gap": 0.03,
+    # the share of generated positions whose served token is not the
+    # float32 first (near-ties over 65536 rows flip on a bfloat16 rounding;
+    # one request of 8 served wrongly throughout adds 0.125): program 0.107
+    # .. 0.141 (17 seeds); prefill state at s_pad 0.566; control 0.892 ..
+    # 0.905; tick without decay 0.995.  1.8 x over the sound largest, 2.3 x
+    # under the smallest control
+    "argmax_disagreement": 0.25,
+}
+
+
+def _round(x, precision):
+    if precision == "float32":
+        return x
+    if precision == "bfloat16":
+        return x.astype(jnp.bfloat16).astype(x.dtype)
+    if precision == "fp8":
+        scale = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / 448.0
+        return (x / scale).astype(jnp.float8_e4m3fn).astype(x.dtype) * scale
+    raise ValueError(f"unknown precision {precision!r}")
+
+
+def _mm(spec, a, b, precision):
+    return jnp.einsum(spec, _round(a.astype(jnp.float32), precision),
+                      _round(b.astype(jnp.float32), precision),
+                      precision=HIGHEST, preferred_element_type=jnp.float32)
+
+
+def sizes(cfg) -> dict:
+    """The numbers the forward needs, from the configuration's keys."""
+    d, heads = cfg["hidden_size"], cfg["num_attention_heads"]
+    return {
+        "d": d, "layers": cfg["num_hidden_layers"],
+        "heads": heads, "kv_heads": cfg["num_key_value_heads"],
+        "head": d // heads,                        # HF JambaConfig
+        "inner": cfg["intermediate_size"],
+        "e": cfg["mamba_expand"] * d, "n": cfg["mamba_d_state"],
+        "r": cfg["mamba_dt_rank"], "conv": cfg["mamba_d_conv"],
+        "vocab": cfg["vocab_size"], "eps": cfg["rms_norm_eps"],
+    }
+
+
+def is_attention(cfg, layer: int) -> bool:
+    """Layer ``layer`` (from 0) is an attention layer (HF ``JambaConfig.
+    layers_block_type``)."""
+    return layer % cfg["attn_layer_period"] == cfg["attn_layer_offset"]
+
+
+def init_params(key, cfg, dtype=jnp.float32, put=None):
+    """Seeded weights in the layout the program's LM takes (the
+    configuration's ``assumed.init``), made ONE LAYER AT A TIME.
+    Projections are ``normal(0, 1 / sqrt(fan_in))`` so that every activation
+    stays of order one through the depth; the embedding (and with it the
+    tied head) ``normal(0, 1 / sqrt(hidden))``, so that the logits are of
+    order one; norms 1.  The convolution's four taps are ``normal(0,
+    1/2)``, its bias ``normal(0, 0.1)``.  ``A_log = log(1 .. N)`` for every
+    channel and ``dt_bias = softplus^-1(dt)``, ``dt = exp(uniform(log 0.001,
+    log 0.1))`` a channel, as the published initialiser sets them: the
+    per-token decay ``exp(-A Dt)`` then spreads from about 0.2 (state 16 of
+    a fast channel) to 0.999 (state 1 of a slow one), so the state neither
+    dies nor saturates within the cell's 1536 tokens.  ``D = 1 + normal(0,
+    0.1)``.  ``put``: an optional sharding for every leaf."""
+    z = sizes(cfg)
+    d, e, n, r = z["d"], z["e"], z["n"], z["r"]
+
+    def draw(k, shape, s):
+        return (jax.random.normal(k, shape, jnp.float32) * s).astype(dtype)
+
+    def dense(k, n_in, n_out):
+        return draw(k, (n_in, n_out), n_in ** -0.5)
+
+    def attention(k):
+        k1, k2, k3 = jax.random.split(k, 3)
+        return {"wq": dense(k1, d, z["heads"] * z["head"]),
+                "wkv": dense(k2, d, 2 * z["kv_heads"] * z["head"]),
+                "wo": dense(k3, z["heads"] * z["head"], d)}
+
+    def mamba(k):
+        k1, k2, k3, k4, k5, k6, k7, k8 = jax.random.split(k, 8)
+        dt = jnp.exp(jax.random.uniform(
+            k6, (e,), jnp.float32, math.log(1e-3), math.log(1e-1)))
+        return {"w_in": dense(k1, d, 2 * e),              # [u | z]
+                "conv": draw(k2, (z["conv"], e), z["conv"] ** -0.5),
+                "conv_bias": draw(k3, (e,), 0.1),
+                "w_x": dense(k4, e, r + 2 * n),           # [dt | B | C]
+                "dt_norm": jnp.ones((r,), dtype),
+                "b_norm": jnp.ones((n,), dtype),
+                "c_norm": jnp.ones((n,), dtype),
+                "w_dt": dense(k5, r, e),
+                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                "a_log": jnp.broadcast_to(jnp.log(jnp.arange(
+                    1, n + 1, dtype=jnp.float32))[:, None], (n, e)),
+                "d": 1.0 + 0.1 * jax.random.normal(k7, (e,), jnp.float32),
+                "w_out": dense(k8, e, d)}
+
+    def block(k, attn: bool):
+        ka, k1, k2, k3 = jax.random.split(k, 4)
+        return {"ln1_scale": jnp.ones((d,), dtype),
+                "ln2_scale": jnp.ones((d,), dtype),
+                "attn": attention(ka) if attn else mamba(ka),
+                "mlp": {"w_gate": dense(k1, d, z["inner"]),
+                        "w_up": dense(k2, d, z["inner"]),
+                        "w_down": dense(k3, z["inner"], d)}}
+
+    jit = lambda f, **kw: jax.jit(f, static_argnames=tuple(kw),
+                                  out_shardings=put)
+    keys = jax.random.split(key, z["layers"] + 1)
+    make_block = jit(block, attn=None)
+    table = jit(lambda k: draw(k, (z["vocab"], d), d ** -0.5))
+    return {
+        "embed": table(keys[0]),
+        "lnf_scale": jit(lambda: jnp.ones((d,), dtype))(),
+        "blocks": [make_block(keys[1 + i], attn=is_attention(cfg, i))
+                   for i in range(z["layers"])],
+    }
+
+
+# ---- the layers ------------------------------------------------------------
+
+def rms_norm(x, scale, eps):
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) \
+        * scale.astype(jnp.float32)
+
+
+def mamba_inputs(h, a, cfg, precision):
+    """What the recurrence takes, from normed ``h (B, S, D)``: ``c, dt (B,
+    S, E)``, ``B, C (B, S, N)`` and the gate input ``z (B, S, E)``."""
+    z = sizes(cfg)
+    s, e, n, r, w = h.shape[1], z["e"], z["n"], z["r"], z["conv"]
+    uz = _mm("bsd,df->bsf", h, a["w_in"], precision)
+    u, gate = uz[..., :e], uz[..., e:]
+    padded = jnp.pad(u, ((0, 0), (w - 1, 0), (0, 0)))
+    taps = a["conv"].astype(jnp.float32)
+    c = jax.nn.silu(sum(padded[:, j:j + s] * taps[j] for j in range(w))
+                    + a["conv_bias"].astype(jnp.float32))
+    low = _mm("bse,ef->bsf", c, a["w_x"], precision)
+    step = rms_norm(low[..., :r], a["dt_norm"], z["eps"])
+    bm = rms_norm(low[..., r:r + n], a["b_norm"], z["eps"])
+    cm = rms_norm(low[..., r + n:], a["c_norm"], z["eps"])
+    dt = jax.nn.softplus(_mm("bsr,re->bse", step, a["w_dt"], precision)
+                         + a["dt_bias"].astype(jnp.float32))
+    return c, dt, bm, cm, gate
+
+
+def mamba_recurrence(c, dt, bm, cm, a_log, d, state=None, low_state=False):
+    """The selective state update token by token from ``state (B, N, E)``
+    (None: zero): ``(y (B, S, E), final state)``.  ``a_log (N, E)``.
+    ``low_state``: the control's state, rounded to bfloat16 after every
+    token."""
+    b, _, e = c.shape
+    rate = -jnp.exp(a_log.astype(jnp.float32))                  # (N, E)
+    if state is None:
+        state = jnp.zeros((b, rate.shape[0], e), jnp.float32)
+
+    def step(s_prev, x):
+        c_t, dt_t, b_t, c_out = x
+        s_new = jnp.exp(dt_t[:, None, :] * rate) * s_prev \
+            + (dt_t * c_t)[:, None, :] * b_t[:, :, None]
+        if low_state:
+            s_new = s_new.astype(jnp.bfloat16).astype(jnp.float32)
+        return s_new, (s_new * c_out[:, :, None]).sum(1)
+
+    time_major = lambda x: jnp.moveaxis(x, 1, 0)
+    state, y = jax.lax.scan(step, state, tuple(
+        time_major(x) for x in (c, dt, bm, cm)))
+    return jnp.moveaxis(y, 0, 1) + d.astype(jnp.float32) * c, state
+
+
+def mamba(h, a, cfg, precision):
+    """The Mamba-1 mixer of normed ``h (B, S, D)``."""
+    c, dt, bm, cm, gate = mamba_inputs(h, a, cfg, precision)
+    y, _ = mamba_recurrence(c, dt, bm, cm, a["a_log"], a["d"],
+                            low_state=precision == "fp8")
+    return _mm("bse,ed->bsd", y * jax.nn.silu(gate), a["w_out"], precision)
+
+
+def attention(h, a, cfg, precision):
+    """Causal multi-query attention of normed ``h (B, S, D)``: no
+    rotation, no biases."""
+    z = sizes(cfg)
+    b, s, _ = h.shape
+    nh, nkv, hd = z["heads"], z["kv_heads"], z["head"]
+    q = _mm("bsd,df->bsf", h, a["wq"], precision).reshape(
+        b, s, nkv, nh // nkv, hd)
+    kv = _mm("bsd,df->bsf", h, a["wkv"], precision).reshape(
+        b, s, nkv, 2, hd)
+    k, v = kv[..., 0, :], kv[..., 1, :]
+    scores = _mm("bqhgd,bkhd->bhgqk", q, k, precision) * hd ** -0.5
+    causal = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
+    p = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), -1)
+    ctx = _mm("bhgqk,bkhd->bqhgd", p, v, precision).reshape(b, s, nh * hd)
+    return _mm("bsf,fd->bsd", ctx, a["wo"], precision)
+
+
+def gated_mlp(u, p, precision):
+    g = _mm("...d,df->...f", u, p["w_gate"], precision)
+    up = _mm("...d,df->...f", u, p["w_up"], precision)
+    return _mm("...f,fd->...d", jax.nn.silu(g) * up, p["w_down"], precision)
+
+
+@functools.lru_cache(maxsize=8)
+def _compiled(cfg_key: str, precision: str):
+    """One layer and the head as jitted functions, made once for a
+    configuration and a precision."""
+    cfg = json.loads(cfg_key)
+    z = sizes(cfg)
+
+    def layer(x, blk, attn):
+        h = rms_norm(x, blk["ln1_scale"], z["eps"])
+        x = x + (attention(h, blk["attn"], cfg, precision) if attn
+                 else mamba(h, blk["attn"], cfg, precision))
+        h = rms_norm(x, blk["ln2_scale"], z["eps"])
+        return x + gated_mlp(h, blk["mlp"], precision)
+
+    def head(x, scale, table):
+        return _mm("bsd,vd->bsv", rms_norm(x, scale, z["eps"]), table,
+                   precision)
+
+    return jax.jit(layer, static_argnames=("attn",)), jax.jit(head)
+
+
+def forward(params, cfg, tokens, precision="float32"):
+    """``logits (B, S, V)`` float32 of ``tokens (B, S)``."""
+    layer, head = _compiled(json.dumps(cfg, sort_keys=True), precision)
+    x = params["embed"].astype(jnp.float32)[tokens]      # not scaled
+    for i, blk in enumerate(params["blocks"]):
+        x = layer(x, blk, attn=is_attention(cfg, i))
+    return head(x, params["lnf_scale"], params["embed"])
+
+
+def served_gaps(params, cfg, tokens, prompt_lens, total_lens, *,
+                precision=None, rows_per_block=1):
+    """Over the generated positions of each served sequence, against ONE
+    full float32 forward, a dict of ``gap_mean`` (the mean gap by which the
+    emitted token's float32 logit lies below the float32 best; 0 where the
+    token is the reference's), ``gap_max`` (the widest), ``agree`` (the
+    share of exact argmax agreement) and ``n`` (positions).
+
+    ``tokens (N, L)``: prompt then emitted tokens, padded to one length
+    (causal: padding behind a sequence changes nothing before it).  With
+    ``precision`` set (the control) the token judged at each position is
+    the one that precision puts first on the same prefix."""
+    total = widest = 0.0
+    same = n = 0
+    tokens = jnp.asarray(tokens, jnp.int32)
+    for r in range(0, tokens.shape[0], rows_per_block):
+        tok = tokens[r: r + rows_per_block]
+        plen = jnp.asarray(prompt_lens[r: r + rows_per_block])[:, None]
+        tlen = jnp.asarray(total_lens[r: r + rows_per_block])[:, None]
+        ref = forward(params, cfg, tok[:, :-1])
+        chosen = tok[:, 1:] if precision is None else jnp.argmax(
+            forward(params, cfg, tok[:, :-1], precision), -1)
+        pos = jnp.arange(tok.shape[1] - 1)[None, :]  # logits at pos -> pos+1
+        live = (pos >= plen - 1) & (pos < tlen - 1)
+        picked = jnp.take_along_axis(ref, chosen[..., None], -1)[..., 0]
+        gap = ref.max(-1) - picked
+        # a token outside the table (the engine's no-winner sentinel) or a
+        # NaN logit is as wrong as a token can be
+        gap = jnp.where((chosen < 0) | (chosen >= ref.shape[-1])
+                        | jnp.isnan(gap), jnp.inf, gap)
+        total += float(jnp.where(live, gap, 0.0).sum())
+        widest = max(widest, float(jnp.where(live, gap, 0.0).max()))
+        same += int((live & (chosen == jnp.argmax(ref, -1))).sum())
+        n += int(live.sum())
+        del ref
+    return {"gap_mean": total / max(n, 1), "gap_max": widest,
+            "agree": same / max(n, 1), "n": n}

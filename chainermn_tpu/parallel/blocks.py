@@ -33,7 +33,14 @@ Parameter layout per ``arch`` value (all GLOBAL arrays):
   place of ``wdq / q_norm / wuq``; ``'kda'`` (a gated delta-rule layer,
   ``parallel/kda.py``): ``attn = {wqkv (D, 3·H·d), conv (W, 3·H·d), w_low
   (D, 2·rank + H), wf_up / wg_up (rank, H·d), dt_bias (H·d,), a_log (H,),
-  o_norm (d,), wo (H·d, D)}``.
+  o_norm (d,), wo (H·d, D)}``; ``'mamba'`` (a Mamba-1 selective
+  state-space layer, ``parallel/mamba.py``; ``E = expand·D`` inner channels,
+  ``N`` states a channel, ``R`` the step's rank): ``attn = {w_in (D, 2·E)``
+  columns ``[u | z]``, ``conv (W, E)`` (``conv[-1]`` meets the current
+  token), ``conv_bias (E,), w_x (E, R + 2·N)`` columns ``[dt | B | C]``,
+  ``dt_norm (R,), b_norm (N,), c_norm (N,), w_dt (R, E), dt_bias (E,),
+  a_log (N, E)`` (the published ``A_log`` TRANSPOSED: channels on the
+  lanes), ``d (E,), w_out (E, D)}``, no projection biases.
 * ``attn_kinds``: the attention kind of each LAYER where a model mixes
   them (None: every layer is ``attn``), as ``layer_kinds`` is for the MLP.
 * ``tied_head=False``: ``params['head'] (V, D)`` beside ``params['embed']``.
@@ -48,7 +55,13 @@ it) keeps its ``(k, v)`` pair for those alone, position ``p`` at ring row ``p
 % W``, each key rotated at its absolute position before it is cached (so the
 order of the rows means nothing to the softmax).  STATE, one a sequence whatever its length: a KDA layer its
 ``(H, d, d)`` float32 recurrent state and the last ``W - 1`` rows of its
-fused projection.
+fused projection; a Mamba layer its ``(N, E)`` float32 state — stored ``(N,
+E / L, L)``, ``L`` lanes of channels, the layout its kernels take
+(``ops/ssm_step.py``) — and the last ``W - 1`` rows of ``u``.
+
+``positions=False``: the model has NO positional signal — no position
+table and no rotation (its state layers carry the order); :func:`turn_qk`
+then hands q and k back as they are.
 """
 
 from __future__ import annotations
@@ -143,6 +156,28 @@ class KDAConfig:
 
 
 @dataclass(frozen=True)
+class MambaConfig:
+    """A Mamba-1 selective state-space layer (``parallel/mamba.py``):
+    ``d_inner`` channels (``expand`` times the model width), ``d_state``
+    states a channel, a causal depthwise convolution of ``conv_width``
+    tokens and a step size projected through ``dt_rank``."""
+    d_inner: int
+    d_state: int = 16
+    conv_width: int = 4
+    dt_rank: int = 160
+
+    @property
+    def state_shapes(self):
+        """What a sequence keeps: the state (float32; ``(N, E / L, L)``,
+        channels on the lanes: ``ops/ssm_step.py::lanes``) and the
+        convolution window (the model's dtype)."""
+        from ..ops.ssm_step import lanes
+        lane = lanes(self.d_inner)
+        return ((self.d_state, self.d_inner // lane, lane),
+                (self.conv_width - 1, self.d_inner))
+
+
+@dataclass(frozen=True)
 class Rotary:
     """Rotary positions of one kind of MHA/GQA layer, half-split pairs:
     the first ``fraction`` of each head's columns rotated (the rest carried
@@ -155,6 +190,10 @@ class Rotary:
     attention_factor: float = 1.0
 
 
+#: the attention kinds that keep a STATE a sequence and no row a token
+STATE_KINDS = ("kda", "mamba")
+
+
 @dataclass(frozen=True)
 class LMArch:
     """One LM's block vocabulary.  The defaults ARE the GPT-2-style block
@@ -162,7 +201,7 @@ class LMArch:
     norm: str = "layernorm"            # | 'rmsnorm'
     norm_eps: float = 1e-5
     mlp: str = "gelu"                  # | 'swiglu'
-    attn: str = "mha"                  # | 'mla' | 'kda' ('mha': MHA and GQA)
+    attn: str = "mha"          # | 'mla' | 'kda' | 'mamba' ('mha': MHA and GQA)
     layer_kinds: Optional[Tuple[str, ...]] = None   # 'dense' | 'moe' each
     tied_head: bool = True
     embed_scale: bool = True           # embedding times sqrt(d_model)
@@ -177,6 +216,9 @@ class LMArch:
     rotary: Optional[Tuple[Optional[Rotary], ...]] = None
     attn_gate: bool = False            # per-head sigmoid gate on the context
     attn_bias: bool = True             # False: no b* entries at all
+    mamba: Optional[MambaConfig] = None
+    # False: no positional signal at all — no table, no rotation
+    positions: bool = True
 
     def kind(self, layer: int) -> str:
         return "dense" if self.layer_kinds is None else \
@@ -195,7 +237,7 @@ class LMArch:
     @property
     def has_state(self) -> bool:
         """Some layer keeps a per-sequence state (and no row a token)."""
-        return "kda" in (self.attn_kinds or (self.attn,))
+        return any(k in STATE_KINDS for k in self.attn_kinds or (self.attn,))
 
     @property
     def has_ring(self) -> bool:
@@ -355,9 +397,12 @@ def turn_qk(arch: LMArch, layer: int, q, k, positions, rope: bool):
     as the model says: by the layer's own :class:`Rotary` record (theta,
     the rotated fraction, YaRN) where ``arch.rotary`` names one, else by
     ``transformer.apply_rope`` where ``rope`` (a model without a position
-    table), else as they are.  The one rotation of the training loss
+    table), else as they are — as they are, too, for a model that declares
+    no positions at all (``arch.positions`` False).  The one rotation of the training loss
     (``transformer.tp_attention``) and of the serving prefill and tick
     (``decode._decoder_core``)."""
+    if not arch.positions:
+        return q, k
     turn = arch.rotary[layer] if arch.rotary is not None else None
     if turn is not None:
         return rotate(turn, q, positions), rotate(turn, k, positions)
@@ -537,8 +582,8 @@ def cache_layout(arch: LMArch, n_layers: int, kv_dim: int,
         kind = arch.attn_kind(layer)
         if kind == "mla":
             return ((arch.mla.latent_width, P()),)
-        if kind == "kda":
-            state, window = arch.kda.state_shapes
+        if kind in STATE_KINDS:
+            state, window = getattr(arch, kind).state_shapes
             return ((state, jnp.float32, P()), (window, None, P()))
         buf = (kv_dim, P(None, None, axis_name))
         if arch.window(layer):

@@ -52,19 +52,19 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
     whatever its attention declares (``blocks.cache_layout``): ``(k, v)``
     for MHA/GQA — rows a token, or under a window a RING of the window's
     rows — one latent buffer for MLA, ``(state, window)`` for a
-    gated delta-rule layer — the kind is the LAYER's
+    gated delta-rule or a selective state-space layer — the kind is the LAYER's
     (``arch.attn_kind(layer)``).  ``attn_block.moe_routing``
     collects the expert layers' ``(counts, idx)`` in trace order; ``live
     (N, S_q) bool`` names the rows that carry a token (None: all) — the
-    expert layers send the others to no expert, a delta-rule layer
-    leaves their state as it is, and the tick's attention (``S_q == 1``)
+    expert layers send the others to no expert, a state layer (delta rule,
+    selective scan) leaves their state as it is, and the tick's attention (``S_q == 1``)
     reads their cache not at all: such a row's context is 0.
 
     Every equation of a block lies under one ``jax.named_scope`` of the
     vocabulary a traced program's device time is split by
     (docs/OBSERVABILITY.md, "Device time by scope"): the attention half is
-    ``block/{attn,mla,kda}/proj``, ``.../core`` and ``cache_write``, the
-    FFN half ``block/mlp`` — ``with`` blocks only, never a function layer.
+    ``block/{attn,mla,kda,mamba}/proj``, ``.../core`` and ``cache_write``,
+    the FFN half ``block/mlp`` — ``with`` blocks only, never a function layer.
     """
     arch = _blocks.resolve(arch)
     d_model = params["embed"].shape[1]
@@ -90,6 +90,21 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
         """``(N,) bool`` of a tick's rows that carry a token (None: all)."""
         return None if live is None else live[:, 0]
 
+    def tick_slots(work, n):
+        """The tick's busy list over ``n`` slots from its memo ``work``
+        (built where the first layer wants it, handed to every later one:
+        the row writers and the state kernels walk the same list); None
+        without a memo: the kernel builds its own."""
+        from ..ops.kv_cache import busy_slots
+
+        if work is None:
+            return None
+        if "slots" not in work:
+            # a layer that takes no kernel leaves the list unread, and the
+            # compiler drops it
+            work["slots"] = busy_slots(busy_rows(), n)
+        return work["slots"]
+
     def write_new_rows(bufs, rows, write_at, work):
         """The layer's new ``rows`` (a tuple of ``(N, S_q, W_i)``) into its
         cache buffers ``bufs`` at ``write_at`` — every caller's one door,
@@ -100,18 +115,13 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
         where the first layer writes, handed to every later one).  A
         scalar position keeps the closed batch's writers: ``cache_append``
         for a K/V pair, a ``dynamic_update_slice`` for one buffer."""
-        from ..ops.kv_cache import busy_slots, cache_append, write_rows
+        from ..ops.kv_cache import cache_append, write_rows
 
         if getattr(write_at, "ndim", 0) == 1:
             if rows[0].shape[1] != 1:       # a chunk behind a cache
                 return write_rows(bufs, rows, write_at)
-            if work is not None and "slots" not in work:
-                # a writer that takes no kernel leaves the list unread,
-                # and the compiler drops it
-                work["slots"] = busy_slots(busy_rows(), bufs[0].shape[0])
             return write_rows(bufs, rows, write_at, busy_rows(),
-                              slots=None if work is None
-                              else work["slots"])
+                              slots=tick_slots(work, bufs[0].shape[0]))
         if len(bufs) == 2:
             return cache_append(*bufs, *rows, write_at, axis=1)
         return tuple(jax.lax.dynamic_update_slice(
@@ -237,6 +247,26 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
                 x = x + y
         return second_half(x, blk, layer), state, window
 
+    def mamba_block(x, blk, state, window, layer, work):
+        """The selective state-space layer: no rows, a state a sequence, as
+        ``kda_block``.  One token a row is the tick (``ops/ssm_step`` over
+        the tick's busy list), more are the selective scan
+        (``ops/selective_scan``) from the state given."""
+        from .mamba import mamba_layer
+
+        with jax.named_scope("block/mamba"):
+            with jax.named_scope("proj"):
+                h = _blocks.norm(arch, x, blk, "ln1")
+            with jax.named_scope("core"):   # the busy list, where first
+                slots = tick_slots(work, x.shape[0]) \
+                    if x.shape[1] == 1 else None
+            y, state, window = mamba_layer(
+                arch.mamba, h, blk["attn"], state, window, live,
+                arch.norm_eps, slots)
+            with jax.named_scope("proj"):
+                x = x + y
+        return second_half(x, blk, layer), state, window
+
     def attn_block(x, blk, k_cache, v_cache, positions, write_at, q_valid,
                    layer: int = 0, work=None):
         """x (N,S,D) → block output; caches written at ``write_at + i`` for
@@ -244,8 +274,8 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
 
         An MLA layer (the layer's ``arch.attn_kind``) keeps ONE buffer:
         pass it as ``k_cache`` and None as ``v_cache``; the result is ``(x,
-        cache)``.  A delta-rule layer takes ``(state, window)`` there and
-        no position: the state says where it stands.
+        cache)``.  A delta-rule or selective-scan layer takes ``(state,
+        window)`` there and no position: the state says where it stands.
 
         ``write_at``/``q_valid`` may be RANK-1 vectors of length N (the
         serving tick): row ``b`` then writes at ``write_at[b]`` and
@@ -272,6 +302,8 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
                              layer, work)
         if kind == "kda":
             return kda_block(x, blk, k_cache, v_cache, layer)
+        if kind == "mamba":
+            return mamba_block(x, blk, k_cache, v_cache, layer, work)
         n = x.shape[0]
         per_row = getattr(write_at, "ndim", 0) == 1
         window = arch.window(layer)
@@ -435,12 +467,16 @@ def _check_length(params, total: int, rope: bool) -> None:
 
 
 def _kv_heads(params, head_dim: int) -> int:
-    a = params["blocks"][0]["attn"]
-    if "wdkv" in a or "conv" in a:
-        # MLA: one shared latent row; delta rule: a state.  No per-head K/V
-        return 0
-    return (a["wkv"].shape[1] // (2 * head_dim) if "wkv" in a
-            else a["wqkv"].shape[1] // (3 * head_dim))
+    """KV heads of the model's MHA/GQA layers (the first one's), 0 where
+    it has none: an MLA layer keeps one shared latent row, a delta-rule or
+    selective-scan layer (``conv``) a state — no per-head K/V."""
+    for blk in params["blocks"]:
+        a = blk["attn"]
+        if "wkv" in a:
+            return a["wkv"].shape[1] // (2 * head_dim)
+        if "wqkv" in a and "conv" not in a:
+            return a["wqkv"].shape[1] // (3 * head_dim)
+    return 0
 
 
 def _prefill(params, embed, attn_block, prompt, total: int, head_dim: int):
@@ -465,13 +501,15 @@ def _prefill(params, embed, attn_block, prompt, total: int, head_dim: int):
                                (buf[1] if _blocks.is_state(buf) else None)
                                or x.dtype) for buf in bufs]
         x, new = _run_layer(attn_block, x, blk, zeros, positions, 0, 0, i)
-        if arch.window(i):
+        if arch.window(i) or arch.attn_kind(i) == "mamba":
             # a ring is a gather of the layer's k and v that nothing wants
             # before the pool is written at the program's end: left alone,
             # the compiler defers every such gather and keeps each sliding
             # layer's (S, columns) k and v alive until then (0.9 GB more
             # temporaries at 40 layers and S = 3072: my ahead-of-time
-            # compile, PR 33)
+            # compile, PR 33).  A selective-scan layer's window is such a
+            # slice of its (S, E) ``u`` (0.27 GB more at 26 layers and S =
+            # 1024: PR 40)
             with jax.named_scope("cache_write"):
                 x, new = jax.lax.optimization_barrier((x, new))
         caches.append(new)

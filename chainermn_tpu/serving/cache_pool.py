@@ -35,11 +35,12 @@ different invariant for each:
   are therefore unreachable — asserted token-exactly by the cross-talk
   fuzz in tests/test_serving.py.
 * STATE ``(n_slots,) + shape``, one a sequence, overwritten in place (a
-  delta-rule layer's recurrent state and convolution window).  There is
+  delta-rule or a selective-scan layer's recurrent state and convolution
+  window).  There is
   no "above": whatever is written is read by the next token.  *Never
   written unless busy, overwritten whole on admission*: the tick is told
   which slots are busy and leaves every other slot's state bit-identical
-  (``ops/kda_step.py``), so a cached slot's state stays the state of its
+  (``ops/kda_step.py``, ``ops/ssm_step.py``), so a cached slot's state stays the state of its
   donated length and a free slot's is nobody's; and every way into a slot
   — a prefill, a prefix copy — writes the WHOLE state (the prefill's own,
   started from zero; the source slot's), so an occupant never reads its
@@ -427,7 +428,8 @@ class CachePool:
         # BUSY slot's position advances (``advance``) and only a busy
         # slot is written: the tick is given the busy mask, and a free,
         # cached or reserved slot's rows, ring and state come back bit
-        # for bit (``ops/kv_cache.py::write_rows``, ``ops/kda_step.py``).
+        # for bit (``ops/kv_cache.py::write_rows``, ``ops/kda_step.py``,
+        # ``ops/ssm_step.py``).
         self.pos = np.zeros(self.n_slots, np.int32)
 
     def fresh_buffers(self):

@@ -68,6 +68,7 @@ from .. import observability as obs
 from ..observability import flight as _flight
 from ..observability.slo import GoodputLedger, ReservoirSample, SLOTracker
 from ..ops.decode_attention import live_blocks
+from ..ops.selective_scan import walked as _scan_walked
 from .cache_pool import CachePool
 from .engine import DecodeEngine
 from .prefix_cache import PrefixCache
@@ -246,10 +247,10 @@ class ServingEngine:
         has_ring = bool(self.pool.ring_bytes_per_slot)
         if has_state and prefix_cache and int(spill_bytes) > 0:
             raise ValueError(
-                "this model has 'kda' layers, which keep a per-slot "
-                "recurrent state and no rows: the host spill tier packs "
-                "rows [0, len) of each buffer and would drop the state; "
-                "construct the engine with spill_bytes=0")
+                "this model has 'kda' layers or 'mamba' layers, which keep a "
+                "per-slot recurrent state and no rows: the host spill tier "
+                "packs rows [0, len) of each buffer and would drop the "
+                "state; construct the engine with spill_bytes=0")
         if has_ring and prefix_cache and int(spill_bytes) > 0:
             raise ValueError(
                 "this model has windowed attention layers, which declare a "
@@ -353,6 +354,13 @@ class ServingEngine:
         # position the keys in its band, x windowed layers
         self._prefill_band_pairs = 0
         self._prefill_band_pairs_padded = 0     # the same of the padded rows
+        # the selective-scan prefills: (real token, scan layer) pairs, and
+        # the pairs the scan walks — whole chunks, up to the one that holds
+        # the last real token (``ops/selective_scan.py::walked``)
+        self._n_scan_layers = sum(
+            arch.attn_kind(i) == "mamba" for i in range(n_layers))
+        self._prefill_scan_tokens = 0
+        self._prefill_scan_tokens_padded = 0
         self._t0 = time.monotonic()
         # goodput attribution: step() partitions its own wall clock, and
         # the gap between steps books as queue_wait (work was waiting)
@@ -654,6 +662,11 @@ class ServingEngine:
                             req.prompt_len, self.pool.ring_windows)
                         self._prefill_band_pairs_padded += _band_pairs(
                             s_pad, self.pool.ring_windows)
+                        self._prefill_scan_tokens += (
+                            req.prompt_len * self._n_scan_layers)
+                        self._prefill_scan_tokens_padded += (
+                            _scan_walked(req.prompt_len, s_pad)
+                            * self._n_scan_layers)
                     self._maybe_evict(req, time.monotonic())
 
             # one decode tick IN FLIGHT: launch the next tick over the rows
@@ -1191,6 +1204,8 @@ class ServingEngine:
             self._rejected = 0
             self._prefill_tokens_real = 0
             self._prefill_tokens_padded = 0
+            self._prefill_scan_tokens = 0
+            self._prefill_scan_tokens_padded = 0
             self._tick_cache_blocks_read = 0
             self._tick_cache_blocks_total = 0
             self._tick_cache_rows_written = 0
@@ -1277,6 +1292,12 @@ class ServingEngine:
                     self._prefill_tokens_real),
                 "serving/prefill_tokens_padded": float(
                     self._prefill_tokens_padded),
+                # the selective-scan layers' share of that: (real token,
+                # scan layer) pairs over the pairs the scan walks
+                "serving/prefill_scan_tokens": float(
+                    self._prefill_scan_tokens),
+                "serving/prefill_scan_tokens_padded": float(
+                    self._prefill_scan_tokens_padded),
                 # read over held: the share of the pool's cache blocks
                 # the ticks' attention read — the busy slots' live blocks
                 # (a layer of the pool on average: layers that keep rows

@@ -91,8 +91,9 @@ def _widths(pool):
         raise ValueError(
             "the KV-transfer plane (local transfer, pack/unpack, the host "
             "spill tier) moves rows [0, len) of each buffer; this pool "
-            "holds per-slot state of 'kda' layers (a recurrent state and "
-            "a convolution window), which it would drop — refused")
+            "holds per-slot state of 'kda' layers or 'mamba' layers (a "
+            "recurrent state and a convolution window), which it would "
+            "drop — refused")
     if getattr(pool, "ring_bytes_per_slot", 0):
         raise ValueError(
             "the KV-transfer plane (local transfer, pack/unpack, the host "

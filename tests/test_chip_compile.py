@@ -907,3 +907,169 @@ def test_ring_and_row_layers_in_one_pool_serving_programs(topo, as_tpu):
     # arguments plus the widest prefill's temporaries: under 15.0 GB, the
     # line ISSUE 33 draws for 24 slots
     assert pmem.argument_size_in_bytes + pmem.temp_size_in_bytes < 15.0e9
+
+
+@pytest.mark.parametrize("kernel", ["ssm_step", "selective_scan_256",
+                                    "selective_scan_1024"])
+def test_selective_state_kernels_at_the_published_widths(one_chip, kernel):
+    """``ops/ssm_step.py`` over a pool of 128 slots and
+    ``ops/selective_scan.py`` over the shortest and the longest prefill
+    bucket, at Jamba2-3B's widths (5120 channels, 16 states): the chip's
+    compiler takes the ``(16, 40, 128)`` state layout, the scalar-memory
+    ``B`` and ``C`` (128 KB at 1024 tokens) and the in-kernel token loop;
+    the step's state is aliased to its result."""
+    from chainermn_tpu.ops.selective_scan import selective_scan
+    from chainermn_tpu.ops.ssm_step import ssm_step
+
+    f32 = lambda *shape: _sds(shape, jnp.float32, one_chip)
+    e, n = 5120, 16
+    if kernel == "ssm_step":
+        slots = 128
+        compiled = jax.jit(partial(ssm_step, interpret=False),
+                           donate_argnums=(6,)).lower(
+            f32(slots, e), f32(slots, e), f32(slots, n), f32(slots, n),
+            f32(n, e), f32(e), f32(slots, n, 40, 128),
+            _sds((slots,), jnp.bool_, one_chip)).compile()
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes == slots * n * e * 4
+    else:
+        s = int(kernel.rsplit("_", 1)[1])
+        compiled = jax.jit(partial(selective_scan, interpret=False)).lower(
+            f32(1, s, e), f32(1, s, e), f32(1, s, n), f32(1, s, n),
+            f32(n, e), f32(e), f32(1, n, 40, 128),
+            _sds((1,), jnp.int32, one_chip)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert f"%{kernel.rsplit('_', 1)[0] if 'scan' in kernel else kernel}" \
+        in text
+
+
+def _jamba_programs(topo, n_layers, prompts, tick=True):
+    """The serving programs of the benchmark's ``jamba2-3b`` configuration
+    (every width as published, the whole vocabulary, 128 slots of 2048
+    rows) with its first ``n_layers`` layers: ``(arch, layout, tick,
+    {prompt: prefill})`` compiled."""
+    import importlib.util
+    import json
+
+    from chainermn_tpu._compat import shard_map
+    from chainermn_tpu.parallel import blocks
+    from chainermn_tpu.parallel.blocks import LMArch, MambaConfig
+    from chainermn_tpu.serving.engine import DecodeEngine, result_size
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    spec = importlib.util.spec_from_file_location(
+        "jamba_reference", os.path.join(here, "jamba_reference.py"))
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+    with open(os.path.join(os.path.dirname(here), "benchmark", "configs",
+                           "jamba2-3b.json")) as f:
+        cfg = dict(json.load(f), num_hidden_layers=n_layers)
+    arch = LMArch(
+        norm="rmsnorm", norm_eps=cfg["rms_norm_eps"], mlp="swiglu",
+        attn="mha", tied_head=True, embed_scale=False, attn_bias=False,
+        positions=False,
+        attn_kinds=tuple("mha" if ref.is_attention(cfg, i) else "mamba"
+                         for i in range(n_layers)),
+        mamba=MambaConfig(cfg["mamba_expand"] * cfg["hidden_size"],
+                          cfg["mamba_d_state"], cfg["mamba_d_conv"],
+                          cfg["mamba_dt_rank"]))
+    n_slots, total = 128, 2048
+    mesh = Mesh(np.array(topo.devices[:1]), ("model",))
+    rep = NamedSharding(mesh, P())
+    shapes = jax.eval_shape(
+        lambda k: ref.init_params(k, cfg, jnp.bfloat16), jax.random.PRNGKey(0))
+    specs = blocks.lm_specs(arch, shapes, "model")
+    p = jax.tree_util.tree_map(
+        lambda x, sp: _sds(x.shape, x.dtype, NamedSharding(mesh, sp)),
+        shapes, specs)
+    layout = blocks.cache_layout(arch, n_layers, 128, "model")
+    caches = [tuple(
+        _sds(blocks.buffer_shape(b, n_slots, total),
+             (b[1] if blocks.is_state(b) else None) or jnp.bfloat16,
+             NamedSharding(mesh, b[2] if blocks.is_state(b) else b[1]))
+        for b in bufs) for bufs in layout]
+    eng = DecodeEngine.__new__(DecodeEngine)
+    eng.mesh, eng.axis_name, eng.arch = mesh, "model", arch
+    eng.head_dim = 128
+    eng.n_counts = blocks.n_count_entries(arch)
+    eng._specs, eng._shard_map, eng._P = specs, shard_map, P
+    eng._cache_specs = [tuple(b[2] if blocks.is_state(b) else b[1]
+                              for b in bufs) for bufs in layout]
+    tick = eng._build_tick().lower(
+        p, caches, _sds((result_size(eng.arch, n_slots),), jnp.int32, rep),
+        _sds((n_slots,), jnp.int32, rep), _sds((n_slots,), jnp.int32, rep),
+        _sds((n_slots, 2), jnp.uint32, rep),
+        _sds((n_slots,), jnp.float32, rep),
+        _sds((n_slots,), jnp.bool_, rep)).compile() if tick else None
+    prefills = {
+        s: eng._build_prefill(s).lower(
+            p, caches, _sds((1, s), jnp.int32, rep),
+            _sds((), jnp.int32, rep), _sds((), jnp.int32, rep),
+            _sds((2,), jnp.uint32, rep), _sds((), jnp.float32, rep)
+        ).compile() for s in prompts}
+    return arch, layout, tick, prefills
+
+
+def test_selective_state_and_row_layers_in_one_pool_serving_programs(
+        topo, as_tpu):
+    """The serving programs of a model that keeps a selective-scan STATE a
+    slot in 26 layers and a multi-query ``(k, v)`` ROW a token in 2, with no
+    positions, at AI21-Jamba2-3B's published widths (the benchmark's
+    ``jamba2-3b``), WHOLE: 28 layers, 65536 rows.  The TICK: ``ssm_step``
+    over the float32 state (26 layers), the GQA flash-decode kernel at
+    group 20 on one KV head (2), the row writer (2); both kinds of buffer
+    written in place; weights + pool + temporaries inside one v5e chip.
+    The widest PREFILL (1024): ``selective_scan`` in the 26 state layers,
+    the causal flash forward at one KV head in the 2 others, and NO ``(S,
+    E, N)`` array anywhere — its temporaries are a few ``(S, E)`` float32
+    arrays.  Kernels, programs and scopes keep the names the trace readers
+    match."""
+    import re
+
+    arch, layout, tick, prefills = _jamba_programs(topo, 28, (1024,))
+    assert [i for i in range(28) if arch.attn_kind(i) == "mha"] == [7, 21]
+    assert [b[0] for b in layout[0]] == [(16, 40, 128), (3, 5120)]
+    assert [b[0] for b in layout[7]] == [128, 128]
+    text, mem = tick.as_text(), tick.memory_analysis()
+    assert "HloModule jit_serving_tick" in text
+    calls = [ln.split(" = ")[0] for ln in text.split("\n")
+             if "tpu_custom_call" in ln]
+    count = lambda name: sum(name in c for c in calls)
+    assert count("ssm_step") == 26 and count("decode_attn_gqa") == 2
+    assert count("decode_attn") == 2        # what decode_attn_ms_per_tick sums
+    # no reader of an accepted metric may match the new kernels by substring
+    for name in ("ssm_step", "selective_scan"):
+        assert not any(n in name for n in ("decode_attn", "moe_gmm", "kda",
+                                           "mla", "flash", "fused_ce",
+                                           "cache_write"))
+    _assert_scopes(text, "tick/layer/block/mamba/proj",
+                   "tick/layer/block/mamba/conv",
+                   "block/mamba/core/jit(ssm_step)/ssm_step",
+                   "tick/layer/block/attn/proj",
+                   "tick/layer/block/attn/core/cache_write",
+                   "block/attn/core/tick/work_list", "decode_attn_gqa",
+                   "tick/layer/block/mlp", tick=True)
+    _assert_pool_written_in_place(text, (128, 2048, 128))
+    _assert_tick_writes_rows_in_place(text, 2)
+    assert not re.findall(r"= f32\[128,16,40,128\]\S* copy\(", text)
+    # 6.06 GB of weights + 1.46 GB of pool (state 1.19, rows 0.27)
+    assert 7.4e9 < mem.argument_size_in_bytes < 7.7e9
+    assert mem.temp_size_in_bytes < 0.3e9
+
+    pre, pmem = prefills[1024].as_text(), prefills[1024].memory_analysis()
+    assert "HloModule jit_serving_prefill_1024" in pre
+    pre_calls = [ln.split(" = ")[0] for ln in pre.split("\n")
+                 if "tpu_custom_call" in ln]
+    assert sum("selective_scan" in c for c in pre_calls) == 26
+    assert not any("ssm_step" in c for c in pre_calls)   # the scan, not the step
+    assert pre.count("%flash_fwd") >= 2 and "decode_attn" not in pre
+    _assert_scopes(pre, "block/mamba/proj", "block/mamba/conv",
+                   "block/mamba/core/jit(selective_scan)/selective_scan",
+                   "block/attn/core", "prefill/head")
+    _assert_pool_written_in_place(pre, (128, 2048, 128))
+    # NO (S, E, N) array: one would be 1024 x 5120 x 16 x 4 B = 335 MB;
+    # the whole program's temporaries are far under one
+    assert not re.findall(r"f32\[(1,)?1024,(5120,16|16,5120|16,40,128)\]",
+                          pre)
+    assert pmem.temp_size_in_bytes < 0.2e9

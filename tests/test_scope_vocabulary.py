@@ -347,3 +347,44 @@ def test_every_equation_of_the_expert_train_step_lies_under_one_leaf(
                      "window_flash_bwd": {"attn_core"},
                      "moe_gmm": {"moe_experts"},
                      "moe_gmm_dw": {"moe_experts"}}
+
+
+# --------------------------------------------------------------------------
+# a served model with selective state-space layers (ISSUE 40): its programs
+# lie under the same vocabulary plus ``block/mamba/{proj,conv,core}``, which
+# ``benchmark/harness/ssm_scope_trace.py`` books by the accepted table with
+# three rows laid before it
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("program", ["serving_tick", "serving_prefill"])
+def test_every_equation_of_a_selective_state_model_lies_in_one_bucket(
+        devices, monkeypatch, program):
+    from benchmark.harness import ssm_scope_trace
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    FIXTURES["mamba+mqa"] = "test_jamba_serving"
+    try:
+        jaxpr = _served_programs("mamba+mqa", devices)[program]
+    finally:
+        del FIXTURES["mamba+mqa"]
+    paths = list(paths_of(jaxpr.jaxpr))
+    assert len(paths) > 100
+    unscoped = [p for p in paths if ssm_scope_trace.bucket_of(p) is None]
+    assert not unscoped, unscoped[:10]
+    found = {ssm_scope_trace.bucket_of(p) for p in paths}
+    assert found == SERVED | {"ssm_proj", "ssm_core"}
+    scopes = {ssm_scope_trace.leaf_of(p)[1] for p in paths}
+    assert {"block/mamba/proj", "block/mamba/conv", "block/mamba/core",
+            "block/attn/proj", "block/attn/core", "block/mlp"} <= scopes
+    # the accepted table alone books the Mamba scopes to nothing — why the
+    # cell is not on ``tick_ms.unscoped``'s list — and the rest as ever
+    for p in paths:
+        mine, theirs = ssm_scope_trace.bucket_of(p), bucket_of(p)
+        assert theirs == (None if mine in ("ssm_proj", "ssm_core")
+                          else mine), p
+        path = scope_path(p)
+        if any(f"/{s}" in path for s in FFN):
+            assert "/block/mamba/" not in path, p
+    kernel = "ssm_step" if program == "serving_tick" else "selective_scan"
+    assert any(p.endswith("/pallas_call") and f"/block/mamba/core/" in
+               scope_path(p) and kernel in p for p in paths)
