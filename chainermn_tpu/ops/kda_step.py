@@ -28,6 +28,7 @@ decay come from one in-kernel transpose of an ``(heads, d_k)`` tile each.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -35,7 +36,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .._compat import shape_dtype_struct as _sds
-from .kv_cache import busy_slots
+from .kv_cache import BusySlots, busy_slots
 
 __all__ = ["kda_step", "kda_step_xla"]
 
@@ -56,21 +57,24 @@ def kda_step_xla(q, k, v, g, beta, state, busy):
             jnp.where(keep[..., None], s_new, state))
 
 
-def _kernel(slot_ref, n_busy_ref, qkg_ref, vb_ref, s_ref, o_ref, so_ref):
-    del slot_ref                              # read by the index maps only
-    n_heads = s_ref.shape[1]
+def _kernel(slot_ref, n_busy_ref, beta_ref, q_ref, k_ref, g_ref, v_ref, s_ref,
+            o_ref, so_ref):
+    n_heads = s_ref.shape[1]                  # the heads of this block
+    i = pl.program_id(1)
+    # the slot's betas, scalars: one a head of the whole layer
+    at = (slot_ref[i] * pl.num_programs(0) + pl.program_id(0)) * n_heads
 
-    @pl.when(pl.program_id(1) < n_busy_ref[0])
+    @pl.when(i < n_busy_ref[0])
     def _step():
         # (heads, d_k) tiles to (d_k, heads): column j is head j's vector
-        q_t = qkg_ref[0, 0].T
-        k_t = qkg_ref[0, 1].T
-        a_t = jnp.exp(qkg_ref[0, 2]).T
+        q_t = q_ref[0].T
+        k_t = k_ref[0].T
+        a_t = jnp.exp(g_ref[0]).T
         for j in range(n_heads):
             k_col = k_t[:, j:j + 1]
             s_dec = s_ref[0, j] * a_t[:, j:j + 1]
             u = (s_dec * k_col).sum(0, keepdims=True)            # (1, d_v)
-            r = vb_ref[0, 1, j:j + 1] * (vb_ref[0, 0, j:j + 1] - u)
+            r = beta_ref[at + j] * (v_ref[0, j:j + 1] - u)
             s_new = s_dec + k_col * r
             so_ref[0, j] = s_new
             o_ref[0, j:j + 1] = (s_new * q_t[:, j:j + 1]).sum(
@@ -88,34 +92,31 @@ def _head_block(n_heads: int) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def kda_step(q, k, v, g, beta, state, busy, *, interpret: bool = False):
+def kda_step(q, k, v, g, beta, state, busy,
+             slots: Optional[BusySlots] = None, *, interpret: bool = False):
     """:func:`kda_step_xla` as one Pallas pass over the busy slots'
     state, written in place (``state`` is aliased to the result: donate
-    it).  Shapes as there; ``d_k`` and ``d_v`` whole lane tiles on a TPU."""
+    it).  Shapes as there; ``d_k`` and ``d_v`` whole lane tiles on a TPU.
+    ``slots``: the tick's busy list (built here from ``busy`` when not
+    given).  Every vector is read where it lies, a busy slot's head block
+    a step; ``beta`` from scalar memory."""
     n, h, dk = q.shape
     dv = v.shape[-1]
     hb = _head_block(h)
     f32 = jnp.float32
     # busy slots first, in slot order; the rest of the axis repeats the
     # last busy slot, whose blocks the kernel then already holds
-    slots, n_busy = busy_slots(busy, n)
-    qkg = jnp.stack([q, k, g], axis=1).astype(f32)            # (N, 3, H, dk)
-    vb = jnp.stack([v.astype(f32), jnp.broadcast_to(
-        beta.astype(f32)[..., None], (n, h, dv))], axis=1)     # (N, 2, H, dv)
+    if slots is None:
+        slots = busy_slots(busy, n)
 
+    heads = lambda b, i, s, nb, bt: (s[i], b, 0)
+    states = pl.BlockSpec((1, hb, dk, dv),
+                          lambda b, i, s, nb, bt: (s[i], b, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(h // hb, n),
-        in_specs=[
-            pl.BlockSpec((1, 3, hb, dk), lambda b, i, s, nb: (s[i], 0, b, 0)),
-            pl.BlockSpec((1, 2, hb, dv), lambda b, i, s, nb: (s[i], 0, b, 0)),
-            pl.BlockSpec((1, hb, dk, dv),
-                         lambda b, i, s, nb: (s[i], b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, hb, dv), lambda b, i, s, nb: (s[i], b, 0)),
-            pl.BlockSpec((1, hb, dk, dv),
-                         lambda b, i, s, nb: (s[i], b, 0, 0)),
-        ])
+        num_scalar_prefetch=3, grid=(h // hb, n),
+        in_specs=[pl.BlockSpec((1, hb, dk), heads)] * 3
+        + [pl.BlockSpec((1, hb, dv), heads), states],
+        out_specs=[pl.BlockSpec((1, hb, dv), heads), states])
     vma = frozenset().union(*(getattr(getattr(a, "aval", None), "vma", None)
                               or () for a in (q, state)))
     o, new_state = pl.pallas_call(
@@ -123,13 +124,14 @@ def kda_step(q, k, v, g, beta, state, busy, *, interpret: bool = False):
         grid_spec=grid_spec,
         out_shape=[_sds((n, h, dv), f32, vma=vma),
                    _sds(state.shape, f32, vma=vma)],
-        # operands count the two prefetched scalars: state is the fifth
-        input_output_aliases={4: 1},
+        # operands count the three prefetched scalars: state is the eighth
+        input_output_aliases={7: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         name="kda_step",
         interpret=interpret,
-    )(slots, n_busy, qkg, vb, state)
+    )(slots.slot, slots.n, beta.astype(f32).reshape(-1), q.astype(f32),
+      k.astype(f32), g.astype(f32), v.astype(f32), state)
     # a slot that is not busy was given no block: its read-out is whatever
     # the buffer held, and must not reach the rows above a cached prefix
     return jnp.where(busy[:, None, None], o, 0.0), new_state

@@ -230,19 +230,24 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
                     preferred_element_type=jnp.float32).astype(x.dtype)
         return second_half(x, blk, layer), cache
 
-    def kda_block(x, blk, state, window, layer):
+    def kda_block(x, blk, state, window, layer, work):
         """The gated delta-rule layer: no rows, a state a sequence.  One
-        token a row is the tick (``ops/kda_step``: the live rows' state
-        moves on in place, the others' is not touched); more are the
-        chunked form from the state given, which after a padded prompt
-        stands at the last live position, not at the last row."""
+        token a row is the tick (``ops/conv_step``, then ``ops/kda_step``
+        over the tick's busy list: the live rows' window and state move
+        on in place, the others' are not touched); more are the chunked
+        form from the state given, which after a padded prompt stands at
+        the last live position, not at the last row."""
         from .kda import kda_layer
 
         with jax.named_scope("block/kda"):
             with jax.named_scope("proj"):
                 h = _blocks.norm(arch, x, blk, "ln1")
-            y, state, window = kda_layer(arch.kda, h, blk["attn"], state,
-                                         window, live, arch.norm_eps)
+            with jax.named_scope("conv"):   # the busy list, where first
+                slots = tick_slots(work, x.shape[0]) \
+                    if x.shape[1] == 1 else None
+            y, state, window = kda_layer(
+                arch.kda, h, blk["attn"], state, window, live,
+                arch.norm_eps, slots)
             with jax.named_scope("proj"):
                 x = x + y
         return second_half(x, blk, layer), state, window
@@ -301,7 +306,7 @@ def _decoder_core(params, head_dim: int, axis_name: str, arch=None,
             return mla_block(x, blk, k_cache, positions, write_at, q_valid,
                              layer, work)
         if kind == "kda":
-            return kda_block(x, blk, k_cache, v_cache, layer)
+            return kda_block(x, blk, k_cache, v_cache, layer, work)
         if kind == "mamba":
             return mamba_block(x, blk, k_cache, v_cache, layer, work)
         n = x.shape[0]

@@ -25,8 +25,11 @@ its length (``blocks.cache_layout`` declares both as STATE buffers).
   chunks by a ``lax.scan``.  Plain XLA: matmuls, one batched triangular
   solve and a scan of ``S / chunk`` steps.
 * the one-token TICK form is ``ops/kda_step.py`` (a Pallas kernel that
-  touches the busy slots' state only, in place), with its plain twin for
-  other backends.
+  touches the busy slots' state only, in place) behind ``ops/conv_step.py``
+  (the convolution window one token on, in place, over the blocks of slots
+  that hold a busy one), each with its plain twin for other backends:
+  between the fused projection and the state kernel a tick materialises no
+  temporary of the pool's size.
 
 With a decay per channel the factor between two positions,
 ``exp(G_i - G_j)`` (``G`` the running sum of ``g``), cannot be split into
@@ -66,6 +69,11 @@ def _short_conv(window, mixed, weight, n_real):
     return y, new_window
 
 
+def _busy(live, b: int):
+    """``(B,) bool`` of a tick's rows that carry a token (None: all)."""
+    return jnp.ones((b,), bool) if live is None else live[:, 0]
+
+
 def kda_project(cfg, h, a, window, live):
     """Everything the recurrence takes, from normed ``h (B, S, D)``:
     ``q, k (B, S, H, d_k)`` float32 normalised, ``v (B, S, H, d_v)``,
@@ -73,14 +81,22 @@ def kda_project(cfg, h, a, window, live):
     output gate ``(B, S, H·d_v)`` and the convolution window after the
     live rows.  ``live (B, S) bool`` (None: all): a row that carries no
     token takes ``beta = 0, g = 0`` (it leaves the state as it is) and
-    is not in the window."""
+    is not in the window.  ``S == 1`` is the tick: the window moves
+    through ``ops/conv_step``, a row that carries no token keeps its
+    window bit for bit and reads ``q = k = v = 0``."""
     b, s, _ = h.shape
     nh, dk, dv, r = cfg.n_heads, cfg.head_dim, cfg.head_dim, cfg.gate_rank
     with jax.named_scope("conv"):
-        n_real = (jnp.full((b,), s, jnp.int32) if live is None
-                  else live.sum(-1).astype(jnp.int32))
         mixed = _dense(h, a["wqkv"])
-        y, window = _short_conv(window, mixed, a["conv"], n_real)
+        if s == 1:
+            from ..ops import conv_step as cs
+            step = cs.conv_step if jax.default_backend() == "tpu" \
+                and cs.fits(window) else cs.conv_step_xla
+            y, window = step(window, mixed, a["conv"], _busy(live, b))
+        else:
+            n_real = (jnp.full((b,), s, jnp.int32) if live is None
+                      else live.sum(-1).astype(jnp.int32))
+            y, window = _short_conv(window, mixed, a["conv"], n_real)
         y = jax.nn.silu(y)
         q, k, v = (y[..., i * nh * dk:(i + 1) * nh * dk].reshape(
             b, s, nh, -1) for i in range(3))
@@ -185,24 +201,23 @@ def kda_chunked(q, k, v, g, beta, state, chunk: int = 64):
     return o[:, :s], state
 
 
-def kda_layer(cfg, h, a, state, window, live, eps: float):
+def kda_layer(cfg, h, a, state, window, live, eps: float, slots=None):
     """The layer on normed ``h (B, S, D)`` from ``(state (B, H, d_k, d_v)
     float32, window (B, W-1, 3·H·d))``: ``(y (B, S, D), state, window)``
     after the live rows.  ``S == 1`` is the tick — every ``live (B, 1)``
-    row moves one token on through ``ops/kda_step``, the others keep
-    state and window bit for bit (no live row: the window after none of
-    them is the window) — and ``S > 1`` the chunked form."""
+    row moves one token on through ``ops/conv_step`` and ``ops/kda_step``
+    (``slots``: the tick's busy list), the others keep state and window
+    bit for bit — and ``S > 1`` the chunked form."""
     b, s, _ = h.shape
     q, k, v, g, beta, gate, new_window = kda_project(cfg, h, a, window,
                                                      live)
     with jax.named_scope("state_update"):
         if s == 1:
             from ..ops.kda_step import kda_step, kda_step_xla
-            busy = (jnp.ones((b,), bool) if live is None else live[:, 0])
-            step = kda_step if jax.default_backend() == "tpu" \
-                else kda_step_xla
-            o, state = step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
-                            state, busy)
+            one = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state,
+                   _busy(live, b))
+            o, state = kda_step(*one, slots) \
+                if jax.default_backend() == "tpu" else kda_step_xla(*one)
             o = o[:, None]
         else:
             o, state = kda_chunked(q, k, v, g, beta, state, cfg.chunk)

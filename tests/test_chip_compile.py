@@ -215,6 +215,33 @@ def test_cache_write_rows(one_chip, as_tpu, shape, n_bufs):
                 and f"[{shape[0]},{shape[1]},{shape[2]}]" in ln]
 
 
+@pytest.mark.parametrize("shape", [(64, 3, 12288), (128, 3, 5120)],
+                         ids=["kimi_window", "jamba_window"])
+def test_conv_step(one_chip, shape):
+    """The delta-rule tick's window step at the published widths (Kimi's
+    fused ``q | k | v``; Jamba's inner channels, which do not take it yet:
+    ROADMAP S1(c)).  The pool's window reaches the kernel as the ``(W-1,
+    N, C)`` array the chip's default layout makes of it — a bitcast, not a
+    copy — is aliased to its result, and the program holds no loop over
+    the slots."""
+    from chainermn_tpu.ops.conv_step import conv_step
+
+    n, keep, c = shape
+    fn = jax.jit(lambda win, new, wt, busy: conv_step(win, new, wt, busy),
+                 donate_argnums=(0,))
+    text = fn.lower(_sds(shape, jnp.bfloat16, one_chip),
+                    _sds((n, 1, c), jnp.bfloat16, one_chip),
+                    _sds((keep + 1, c), jnp.bfloat16, one_chip),
+                    _sds((n,), jnp.bool_, one_chip)).compile().as_text()
+    calls = [ln for ln in text.split("\n")
+             if "custom_call_target=\"tpu_custom_call\"" in ln]
+    assert len(calls) == 1 and "%conv_step" in calls[0].split(" = ")[0]
+    assert f"bf16[{keep},{n},{c}]" in calls[0].split(" = ")[1]
+    assert " while(" not in text and " sort(" not in text
+    assert not [ln for ln in text.split("\n") if " copy(" in ln and (
+        f"bf16[{n},{keep},{c}]" in ln or f"bf16[{keep},{n},{c}]" in ln)]
+
+
 def test_conv3x3_backward(one_chip):
     from chainermn_tpu.ops.conv_backward import conv3x3_dgrad, conv3x3_wgrad
 
@@ -737,10 +764,12 @@ def test_state_and_row_layers_in_one_pool_serving_programs(topo, as_tpu):
     in most layers (gated delta rule) and a latent ROW a token in the
     others, at Kimi-Linear-48B's published widths (the benchmark's
     ``kimi-linear-48b-ep16``).  The TICK at full depth, 27 layers: the
-    ``kda_step`` kernel over the float32 state (20 layers), the absorbed
-    flash-decode kernel over the 640-column latent pool (7), the grouped
-    expert product (26); both kinds of buffer written in place; weights +
-    pool + temporaries inside one v5e chip.  The widest PREFILL (2048) at
+    ``conv_step`` kernel over the convolution window then the ``kda_step``
+    kernel over the float32 state (20 layers each, one sort of the slots
+    for all of them, no loop over the slots and no window laid out anew),
+    the absorbed flash-decode kernel over the 640-column latent pool (7),
+    the grouped expert product (26); both kinds of buffer written in place;
+    weights + pool + temporaries inside one v5e chip.  The widest PREFILL (2048) at
     one period of the pattern behind the dense first layer (K K K M; its
     temporaries are a layer's, whatever the depth): the chunked delta rule
     is plain XLA, the latent layer takes the flash kernel at 192/128.
@@ -755,12 +784,26 @@ def test_state_and_row_layers_in_one_pool_serving_programs(topo, as_tpu):
              if "tpu_custom_call" in ln]
     count = lambda name: sum(name in c for c in calls)
     assert count("kda_step") == 20 and count("decode_attn_mla") == 7
+    assert count("conv_step") == 20
     assert count("moe_gmm") >= 3 * 26
-    # no reader of an accepted metric may match the new kernel by substring
-    assert not any(n in "kda_step" for n in ("decode_attn", "moe_gmm",
-                                             "flash"))
+    # no reader of an accepted metric may match the new kernels by substring
+    for name in ("kda_step", "conv_step"):
+        assert not any(n in name for n in ("decode_attn", "moe_gmm", "flash",
+                                           "cache_write", "ssm_step"))
+    assert "kda_step" not in "conv_step"
+    # one busy list a tick, whatever the layers; the window neither walked
+    # slot by slot (the vmapped dynamic_slice's loop until PR 41) nor laid
+    # out anew around the kernel, and no (N, W, C) concatenation of it
+    assert sum(" sort(" in ln and "s32[64]" in ln
+               for ln in text.split("\n")) == 1
+    assert not [ln for ln in text.split("\n") if " while(" in ln
+                and "12288" in ln]
+    assert not [ln for ln in text.split("\n") if " copy(" in ln and (
+        "bf16[64,3,12288]" in ln or "bf16[3,64,12288]" in ln)]
+    assert "bf16[64,4,12288]" not in text
     _assert_scopes(text, "tick/layer/block/kda/proj",
-                   "tick/layer/block/kda/conv", "tick/layer/block/kda/gate",
+                   "tick/layer/block/kda/conv/jit(conv_step)/conv_step",
+                   "tick/layer/block/kda/gate",
                    "block/kda/state_update/jit(kda_step)/kda_step",
                    "tick/layer/block/mla/proj", "block/mla/cache_write",
                    "block/mla/core/tick/work_list",
@@ -780,6 +823,7 @@ def test_state_and_row_layers_in_one_pool_serving_programs(topo, as_tpu):
     assert "HloModule jit_serving_prefill_2048" in pre
     assert pre.count("%flash_fwd") >= 1 and pre.count("%moe_gmm") >= 3
     assert "kda_step" not in pre            # the chunked form, not the step
+    assert "conv_step" not in pre           # _short_conv over the prompt
     _assert_scopes(pre, "block/kda/proj", "block/kda/conv",
                    "block/kda/gate", "block/kda/state_update",
                    "block/mla/core", "prefill/head")
@@ -1038,6 +1082,7 @@ def test_selective_state_and_row_layers_in_one_pool_serving_programs(
     count = lambda name: sum(name in c for c in calls)
     assert count("ssm_step") == 26 and count("decode_attn_gqa") == 2
     assert count("decode_attn") == 2        # what decode_attn_ms_per_tick sums
+    assert count("conv_step") == 0          # its window is _short_conv's still
     # no reader of an accepted metric may match the new kernels by substring
     for name in ("ssm_step", "selective_scan"):
         assert not any(n in name for n in ("decode_attn", "moe_gmm", "kda",

@@ -5,8 +5,8 @@ against the plain float32 reference (``tests/kimi_linear_reference.py``,
 the same text as ``benchmark/reference/kimi_linear.py``), whose KDA layer
 is the token-by-token recurrence.
 
-The ``kda_step`` kernel runs in interpret mode here; the engine itself
-takes the kernel's plain twin on the CPU."""
+The ``kda_step`` and ``conv_step`` kernels run in interpret mode here; the
+engine itself takes the kernels' plain twins on the CPU."""
 
 import dataclasses
 import importlib.util
@@ -20,6 +20,8 @@ from jax.sharding import PartitionSpec as P
 
 import chainermn_tpu as mn
 from chainermn_tpu._compat import shard_map
+from chainermn_tpu.ops.conv_step import (SLOTS, busy_blocks, conv_step,
+                                         conv_step_xla)
 from chainermn_tpu.ops.kda_step import kda_step, kda_step_xla
 from chainermn_tpu.parallel import blocks, kda
 from chainermn_tpu.parallel.blocks import (KDAConfig, LMArch, MLAConfig,
@@ -184,6 +186,59 @@ def test_kda_step_is_one_step_of_the_recurrence(busy):
         assert (o[~busy] == 0).all()
 
 
+def _busy_case(name, n):
+    slot = np.arange(n)
+    return {"none": slot < 0, "one": slot == n - 7, "alternating":
+            slot % 2 == 0, "all": slot >= 0, "one_block":
+            (slot >= SLOTS) & (slot < SLOTS + 3)}[name]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("busy", ["none", "one", "alternating", "all",
+                                  "one_block"])
+def test_conv_step_is_the_short_convolutions_tick(busy, dtype):
+    """The kernel (interpret mode) and its plain twin against
+    ``_short_conv`` at ``S == 1``, the idle slots' windows poisoned with
+    NaN: a busy slot's ``y`` and window are ``_short_conv``'s, an idle
+    slot's window comes back bit for bit and its ``y`` is exact 0."""
+    n, width, c = 3 * SLOTS, 4, 256
+    rng = np.random.default_rng(41)
+    f = lambda *sh: jnp.asarray(rng.normal(size=sh), dtype)
+    window, new, weight = f(n, width - 1, c), f(n, 1, c), f(width, c)
+    busy = _busy_case(busy, n)
+    poisoned = jnp.where(jnp.asarray(busy)[:, None, None], window, jnp.nan)
+    want_y, want_w = jax.jit(kda._short_conv)(
+        window, new, weight, jnp.asarray(busy, jnp.int32))
+    as_bits = lambda x: np.asarray(jnp.asarray(x, jnp.float32)).view(
+        np.uint32)
+    for step in (jax.jit(conv_step_xla),
+                 lambda *a: conv_step(*a, interpret=True)):
+        y, got = step(poisoned, new, weight, jnp.asarray(busy))
+        assert y.dtype == jnp.float32 and got.dtype == window.dtype
+        # a compiled sum may contract a multiply-add; the order is the same
+        np.testing.assert_allclose(np.asarray(y)[busy],
+                                   np.asarray(want_y)[busy], rtol=2e-6,
+                                   atol=2e-6)
+        np.testing.assert_array_equal(as_bits(got)[busy],
+                                      as_bits(want_w)[busy])
+        np.testing.assert_array_equal(as_bits(got)[~busy],
+                                      as_bits(poisoned)[~busy])
+        assert (np.asarray(y)[~busy] == 0).all()
+
+
+def test_busy_blocks_lists_the_blocks_that_hold_a_busy_slot():
+    """In order, no sort; the entries past the count repeat the last, and
+    a tick with nothing busy is one step on block 0."""
+    n = 5 * SLOTS
+    for held in ([], [3], [0, 2, 4], [1, 2], list(range(5))):
+        busy = np.zeros(n, bool)
+        busy[[b * SLOTS + 5 for b in held]] = True
+        blocks = busy_blocks(jnp.asarray(busy), n)
+        assert int(blocks.n[0]) == len(held)
+        want = held + [held[-1] if held else 0] * (5 - len(held))
+        assert np.asarray(blocks.slot).tolist() == want
+
+
 def _layer(params, s_pad, s_real, seed=5):
     """One KDA layer of the model on a prompt padded to ``s_pad``."""
     blk = params["blocks"][1]["attn"]
@@ -217,6 +272,35 @@ def test_a_padded_prompts_state_is_the_state_at_its_last_real_token(
     _, (_, padded, _) = _layer(params, 24, 24)
     if s_real < 24:
         assert np.abs(np.asarray(padded) - np.asarray(state)).max() > 1e-3
+
+
+@pytest.mark.parametrize("s_real", [1, 2, 11, 24])
+def test_the_tick_continues_the_window_a_padded_prefill_left(params, s_real):
+    """A prompt padded to its bucket, then ONE tick: state, window and
+    output are the unpadded prompt's with the token appended — the tick's
+    window step starts from the window at ``s_real``, also where that
+    window still reaches into the zeros before the prompt."""
+    blk = params["blocks"][1]["attn"]
+    x, (_, state, window) = _layer(params, 24, s_real)
+    nxt = jax.random.normal(jax.random.PRNGKey(6), (1, 1, 64))
+    y1, state1, window1 = kda.kda_layer(
+        ARCH.kda, nxt, blk, state, window, jnp.ones((1, 1), bool), 1e-5)
+    zeros = [jnp.zeros((1,) + shape, jnp.float32)
+             for shape in ARCH.kda.state_shapes]
+    y0, state0, window0 = kda.kda_layer(
+        ARCH.kda, jnp.concatenate([x[:, :s_real], nxt], 1), blk, *zeros,
+        None, 1e-5)
+    np.testing.assert_allclose(np.asarray(window1), np.asarray(window0),
+                               rtol=1e-6, atol=1e-7)
+    np.testing.assert_allclose(np.asarray(state1), np.asarray(state0),
+                               rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(y1[:, 0]), np.asarray(y0[:, -1]),
+                               rtol=1e-4, atol=1e-5)
+    # a row that carries no token keeps both, bit for bit
+    _, state2, window2 = kda.kda_layer(
+        ARCH.kda, nxt, blk, state, window, jnp.zeros((1, 1), bool), 1e-5)
+    np.testing.assert_array_equal(np.asarray(window2), np.asarray(window))
+    np.testing.assert_array_equal(np.asarray(state2), np.asarray(state))
 
 
 def test_the_layer_is_the_references(params):
@@ -305,6 +389,83 @@ def test_serving_engine_serves_the_references_tokens(params, mesh):
     assert m["serving/tick_latent_bytes"] == \
         m["serving/tick_cache_rows_live"] * N_MLA * 128 * 4
     assert m["serving/prefix/state_misses"] == 0
+    eng.close()
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr``, sub-jaxprs walked (a Pallas kernel's
+    body is one operation)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for value in eqn.params.values():
+            for x in (value if isinstance(value, (tuple, list)) else (value,)):
+                x = getattr(x, "jaxpr", x)
+                if hasattr(x, "eqns"):
+                    yield from _eqns(x)
+
+
+def test_the_tick_builds_one_busy_list_for_all_its_layers(params, mesh,
+                                                          monkeypatch):
+    """The tick as the chip runs it (``jax.default_backend`` steered, a
+    pool of whole blocks of slots): every delta-rule layer takes
+    ``conv_step`` then ``kda_step``, and the jaxpr holds ONE sort of the
+    slots for all of them and for the latent layers' row writer."""
+    from chainermn_tpu.serving.engine import result_size
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n = SLOTS
+    eng = _engine(params, mesh, n_slots=n)
+    dec = eng.engine
+    tick = jax.make_jaxpr(dec._build_tick())(
+        dec._params, eng.pool.read(lambda c: c),
+        np.zeros(result_size(dec.arch, n), np.int32), np.zeros(n, np.int32),
+        np.zeros(n, np.int32), np.zeros((n, 2), np.uint32),
+        np.zeros(n, np.float32), np.ones(n, bool))
+    eqns = list(_eqns(tick.jaxpr))
+    kernels = [e.params["name"] for e in eqns
+               if e.primitive.name == "pallas_call"]
+    assert kernels.count("conv_step") == N_KDA
+    assert kernels.count("kda_step") == N_KDA
+    assert kernels.count("cache_write_rows") == N_MLA
+    # interleaved a layer: the window first, then the state
+    order = [k for k in kernels if k in ("conv_step", "kda_step")]
+    assert order == ["conv_step", "kda_step"] * N_KDA
+    slot_sorts = [e for e in eqns if e.primitive.name == "sort"
+                  and e.invars[0].aval.shape == (n,)]
+    assert len(slot_sorts) == 1
+    eng.close()
+
+
+def test_a_tick_moves_exactly_the_windows_the_engine_counts(params, mesh):
+    """``serving/tick_state_slots_live`` counts (busy slot, state layer)
+    pairs a tick; the windows (and states) a tick changes are exactly
+    those pairs — every other slot's come back bit for bit."""
+    eng = _engine(params, mesh, n_slots=8, max_total=64)
+    rng = np.random.default_rng(2)
+    for n_prompt in (5, 9, 3):
+        eng.submit(rng.integers(0, CFG["vocab_size"], n_prompt,
+                                dtype=np.int32), 30)
+    while eng.scheduler.queue_depth or eng.engine.tick_calls < 3:
+        eng.step()
+    kda_layers = [i for i in range(CFG["num_hidden_layers"])
+                  if ARCH.attn_kind(i) == "kda"]
+    snap = lambda: [[np.asarray(b) for b in eng.pool.caches[i]]
+                    for i in kda_layers]
+    live = lambda: eng.metrics()["serving/tick_state_slots_live"]
+    before, counted = snap(), live()
+    eng.step()                                   # launches ONE tick
+    after, counted = snap(), live() - counted
+    moved = {(i, slot) for i, (was, now) in enumerate(zip(before, after))
+             for slot in range(8) if (was[1][slot] != now[1][slot]).any()}
+    assert counted == len(moved) == 3 * N_KDA
+    assert {slot for _, slot in moved} == set(
+        np.flatnonzero(eng.pool.busy_mask()).tolist())
+    for i, (was, now) in enumerate(zip(before, after)):
+        for slot in range(8):
+            changed = [(w[slot] != x[slot]).any() for w, x in zip(was, now)]
+            assert changed == [(i, slot) in moved] * 2
     eng.close()
 
 

@@ -67,7 +67,7 @@ def paths_of(jaxpr, prefix=""):
             yield f"{path}/{eqn.primitive.name}"
 
 
-def _served_programs(arch_name, devices):
+def _served_programs(arch_name, devices, **engine_kw):
     from chainermn_tpu.serving import ServingEngine
     from chainermn_tpu.serving.engine import result_size
 
@@ -81,7 +81,7 @@ def _served_programs(arch_name, devices):
     else:
         mod = _fixture(FIXTURES[arch_name])
         eng = mod._engine(mod.ref.init_params(jax.random.PRNGKey(3), mod.CFG,
-                                              jnp.float32), mesh)
+                                              jnp.float32), mesh, **engine_kw)
     dec, n = eng.engine, eng.pool.n_slots
     caches = eng.pool.read(lambda c: c)
     tick = jax.make_jaxpr(dec._tick_prog)(
@@ -174,6 +174,33 @@ def test_every_equation_lies_in_one_named_bucket(programs, monkeypatch,
     if program == "serving_tick":       # one wrapper a layer, and no other
         layered = [p for p in paths if "/tick/layer/" in scope_path(p)]
         assert {bucket_of(p) for p in layered} == want - {"embed_head"}
+
+
+def test_the_delta_rule_ticks_kernels_lie_where_their_bucket_reads(
+        devices, monkeypatch):
+    """A pool of whole blocks of slots (16: what ``ops/conv_step.py``
+    walks) puts the window kernel on the tick's path beside the state
+    kernel (ISSUE 41): every equation still lies in one bucket, both
+    kernels under ``attn_core`` — ``conv_step`` in ``block/kda/conv``,
+    ``kda_step`` in ``block/kda/state_update`` — and the tick's busy list,
+    built where the first delta-rule layer wants it, in ``block/kda/conv``
+    (the latent layers' row writer is handed the same list)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    tick = _served_programs("kda+mla+moe", devices, n_slots=16)[
+        "serving_tick"]
+    paths = list(paths_of(tick.jaxpr))
+    assert not [p for p in paths if bucket_of(p) is None]
+    assert {bucket_of(p) for p in paths} == SERVED | EXPERTS
+    where = {k: {leaf_of(p) for p in paths
+                 if p.endswith("/pallas_call") and f"/{k}/" in p}
+             for k in ("conv_step", "kda_step", "cache_write_rows")}
+    assert where == {
+        "conv_step": {("attn_core", "block/kda/conv")},
+        "kda_step": {("attn_core", "block/kda/state_update")},
+        "cache_write_rows": {("cache_write", "cache_write")}}
+    sorts = {leaf_of(p) for p in paths if p.endswith("/sort")
+             and "/block/moe/" not in scope_path(p)}
+    assert sorts == {("attn_core", "block/kda/conv")}
 
 
 UNIT = [
