@@ -45,7 +45,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .._compat import pcast_varying, shape_dtype_struct as _sds
 from .flash_attention import _inherit_vma as _vma
 
-__all__ = ["moe_gmm", "pick_tn"]
+__all__ = ["moe_gmm", "moe_gmm_rows", "moe_gmm_sum", "pick_tn"]
 
 _LANES = 128
 #: a weight block (K x tn, double-buffered by Pallas) stays under this
@@ -250,3 +250,195 @@ def moe_gmm(x, w, tile_expert, n_valid, *, tm: int, tn: int = 0,
         w = pcast_varying(w, ax)
     return _gmm(x, w, tile_expert.astype(jnp.int32),
                 jnp.asarray(n_valid, jnp.int32).reshape(1), tm, tn, interpret)
+
+
+# --------------------------------------------------------------------------
+# the layer at a tick's sizes: rows taken and summed INSIDE the products
+# --------------------------------------------------------------------------
+#
+# Where the layer's tokens are few enough that ``x (T, D)`` and the float32
+# result ``y (T, D)`` stay in a kernel's fast memory (``parallel/moe.py::
+# _rows_resident``: a served tick), the rows' gather is the prologue of the
+# first two products and the gated sum the epilogue of the third:
+#
+# * ``moe_gmm_rows``: ``hidden[tile t] = silu(x[row_token] @ w_gate[e]) *
+#   (x[row_token] @ w_up[e])`` — ``x`` is ONE block (constant index map: read
+#   once a call, widened once to a float32 scratch whose rows a dynamic index
+#   can name), a live tile copies the rows of its tokens (``row_token`` from
+#   scalar memory; a padding row names no token, ``T``, and stays zero) and
+#   multiplies by BOTH weight blocks of its expert.  No ``(M, D)`` buffer.
+# * ``moe_gmm_sum``: ``y[row_token[r]] += f32(hidden[r] @ w_down[e]) *
+#   row_gate[r]`` over the live tiles' real rows — the ``(T, tn)`` block of
+#   ``y`` stays resident over the row tiles (the fast grid axis), zeroed at
+#   the first.  No ``(M, D)`` result rows, no gather a choice.
+#
+# The roundings are the three separate products': bfloat16 products with
+# float32 accumulation, each cast to the rows' dtype, a float32 gate multiply
+# and a float32 sum — a token's held rows are added by EXPERT (it has at most
+# one row a tile), where the gather-combine adds them by choice.  A row only
+# ever reaches its own token: nothing multiplies another row by zero.  Both
+# are forward kernels; the layer differentiates through the staged path.
+
+def _rows_kernel(tile_expert_ref, n_valid_ref, row_token_ref, x_ref, wg_ref,
+                 wu_ref, o_ref, wide_ref, rows_ref, *, tm: int):
+    del tile_expert_ref                       # read by the index maps only
+    f32 = jnp.float32
+    i = pl.program_id(1)
+
+    @pl.when(jnp.logical_and(pl.program_id(0) == 0, i == 0))
+    def _widen():
+        wide_ref[...] = x_ref[...].astype(f32)
+
+    @pl.when(i < n_valid_ref[0])
+    def _tile():
+        rows_ref[...] = jnp.zeros_like(rows_ref)
+
+        def take(r, carry):
+            token = row_token_ref[i * tm + r]
+
+            @pl.when(token < x_ref.shape[0])
+            def _row():
+                rows_ref[pl.ds(r, 1), :] = wide_ref[pl.ds(token, 1), :]
+
+            return carry
+
+        jax.lax.fori_loop(0, tm, take, 0)
+        rows = rows_ref[...].astype(x_ref.dtype)
+        product = lambda w_ref: jax.lax.dot_general(
+            rows, w_ref[...], (((1,), (0,)), ((), ())),
+            preferred_element_type=f32).astype(o_ref.dtype).astype(f32)
+        o_ref[...] = (jax.nn.silu(product(wg_ref))
+                      * product(wu_ref)).astype(o_ref.dtype)
+
+
+def _sum_kernel(tile_expert_ref, n_valid_ref, row_token_ref, row_gate_ref,
+                h_ref, w_ref, y_ref, rows_ref, *, tm: int):
+    del tile_expert_ref
+    f32 = jnp.float32
+    i = pl.program_id(1)
+
+    @pl.when(i == 0)
+    def _zero():
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+    @pl.when(i < n_valid_ref[0])
+    def _tile():
+        rows_ref[...] = jax.lax.dot_general(
+            h_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
+            preferred_element_type=f32).astype(h_ref.dtype).astype(f32)
+
+        def add(r, carry):
+            token = row_token_ref[i * tm + r]
+
+            @pl.when(token < y_ref.shape[0])
+            def _row():
+                y_ref[pl.ds(token, 1), :] += (rows_ref[pl.ds(r, 1), :]
+                                              * row_gate_ref[i * tm + r])
+
+            return carry
+
+        jax.lax.fori_loop(0, tm, add, 0)
+
+
+def _row_scalars(tile_expert, n_valid, row_token):
+    return (tile_expert.astype(jnp.int32),
+            jnp.asarray(n_valid, jnp.int32).reshape(1),
+            row_token.astype(jnp.int32))
+
+
+def _vary_alike(*arrays):
+    """``arrays``, each varying over every mesh axis any of them varies
+    over (inside ``shard_map`` the rows vary and the weights do not)."""
+    union = _vma(*arrays)
+    out = []
+    for a in arrays:
+        for ax in sorted(union - _vma(a)):
+            a = pcast_varying(a, ax)
+        out.append(a)
+    return out
+
+
+@functools.partial(jax.jit, static_argnames=("tm", "interpret"))
+def moe_gmm_rows(x, w_gate, w_up, row_token, tile_expert, n_valid, *,
+                 tm: int, interpret: bool = False):
+    """``hidden (M, F)`` in ``x.dtype`` of ``x (T, D)`` whole: row ``r`` of a
+    live tile is ``silu(x[row_token[r]] @ w_gate[e]) * (x[row_token[r]] @
+    w_up[e])`` for the tile's expert ``e``, each product rounded to
+    ``x.dtype`` first; a row whose ``row_token`` is ``T`` (padding) is zero.
+    ``row_token (M,)``, ``tile_expert (M // tm,)`` and ``n_valid`` as
+    :func:`moe_gmm`'s; the rows of tiles at or past ``n_valid`` are
+    UNSPECIFIED.  Forward only."""
+    x, w_gate, w_up = _vary_alike(x, w_gate, w_up)
+    t, d = x.shape
+    e, d2, f = w_gate.shape
+    m = row_token.shape[0]
+    assert d == d2 and w_up.shape == w_gate.shape and m % tm == 0, (
+        x.shape, w_gate.shape, w_up.shape, m, tm)
+    tn = pick_tn(d, f, w_gate.dtype.itemsize)
+    last = _last_live
+    weight = pl.BlockSpec((None, d, tn),
+                          lambda j, i, te, nv, rt: (te[last(i, nv)], 0, j))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3, grid=(f // tn, m // tm),
+        in_specs=[pl.BlockSpec((t, d), lambda j, i, te, nv, rt: (0, 0)),
+                  weight, weight],
+        out_specs=pl.BlockSpec((tm, tn),
+                               lambda j, i, te, nv, rt: (last(i, nv), j)),
+        scratch_shapes=[pltpu.VMEM((t, d), jnp.float32),
+                        pltpu.VMEM((tm, d), jnp.float32)])
+    block_bytes = (4 * d * tn * w_gate.dtype.itemsize
+                   + t * d * (4 + 2 * x.dtype.itemsize) + 4 * tm * d
+                   + 2 * tm * tn * x.dtype.itemsize)
+    return pl.pallas_call(
+        functools.partial(_rows_kernel, tm=tm),
+        grid_spec=grid_spec,
+        out_shape=_sds((m, f), x.dtype, vma=_vma(x, w_gate, w_up)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=max(block_bytes + (8 << 20), 32 << 20)),
+        name="moe_gmm_rows",
+        interpret=interpret,
+    )(*_row_scalars(tile_expert, n_valid, row_token), x, w_gate, w_up)
+
+
+@functools.partial(jax.jit, static_argnames=("n_tokens", "tm", "interpret"))
+def moe_gmm_sum(hidden, w_down, row_gate, row_token, tile_expert, n_valid, *,
+                n_tokens: int, tm: int, interpret: bool = False):
+    """``y (n_tokens, D)`` float32: ``y[row_token[r]] += f32(hidden[r] @
+    w_down[e]) * row_gate[r]`` over the rows ``r`` of the live tiles whose
+    ``row_token`` is a token's (under ``n_tokens``), the product rounded to
+    ``hidden.dtype`` first, the tiles in order; zeros where no row names a
+    token, and everywhere with ``n_valid == 0``.  The rows of dead tiles
+    (``hidden``'s unwritten ones) and the padding rows are never read into
+    ``y``.  ``row_gate (M,)`` float32.  Forward only."""
+    hidden, w_down = _vary_alike(hidden, w_down)
+    m, f = hidden.shape
+    e, f2, d = w_down.shape
+    assert f == f2 and m % tm == 0, (hidden.shape, w_down.shape, tm)
+    tn = pick_tn(f, d, w_down.dtype.itemsize)
+    last = _last_live
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4, grid=(d // tn, m // tm),
+        in_specs=[
+            pl.BlockSpec((tm, f),
+                         lambda j, i, te, nv, rt, rg: (last(i, nv), 0)),
+            pl.BlockSpec((None, f, tn),
+                         lambda j, i, te, nv, rt, rg: (te[last(i, nv)], 0, j)),
+        ],
+        out_specs=pl.BlockSpec((n_tokens, tn),
+                               lambda j, i, te, nv, rt, rg: (0, j)),
+        scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)])
+    block_bytes = (2 * f * tn * w_down.dtype.itemsize
+                   + 2 * tm * f * hidden.dtype.itemsize
+                   + 4 * tn * (2 * n_tokens + tm))
+    return pl.pallas_call(
+        functools.partial(_sum_kernel, tm=tm),
+        grid_spec=grid_spec,
+        out_shape=_sds((n_tokens, d), jnp.float32, vma=_vma(hidden, w_down)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=max(block_bytes + (8 << 20), 32 << 20)),
+        name="moe_gmm_sum",
+        interpret=interpret,
+    )(*_row_scalars(tile_expert, n_valid, row_token),
+      row_gate.astype(jnp.float32), hidden, w_down)

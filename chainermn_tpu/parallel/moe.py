@@ -27,17 +27,18 @@ group-limited top-k, renormalised and scaled gates
 (:func:`softmax_topk_route`), a layer that is TOLD which experts it holds
 (``held = (first, n)``), routes over all of them and computes its own
 experts' part, with one grouped product per projection over the experts
-that have tokens (``ops/moe_gmm.py``) beside a shared expert every token
-takes (where the model has one).  It runs without an exchange: a chip's
-result is its PART of the layer (the exchange between the parts waits for a
-four-chip cell).
+that have tokens (``ops/moe_gmm.py``; at a served tick's few rows two
+kernels that take the rows and give the gated sum themselves) beside a
+shared expert every token takes (where the model has one).  It runs
+without an exchange: a chip's result is its PART of the layer (the exchange
+between the parts waits for a four-chip cell).
 """
 
 from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -272,6 +273,21 @@ def _row_chunk(n_assign: int, tm: int) -> Optional[int]:
     return 16 * tm if n_assign > 65536 else None
 
 
+#: the float32 sum of a layer whose rows stay in a kernel's fast memory
+_RESIDENT_BYTES = 4 << 20
+
+
+def _rows_resident(t: int, d: int, n_assign: int) -> bool:
+    """Is the layer small enough that ``x (T, D)`` and its float32 result
+    stay WHOLE in a kernel's fast memory — a served tick's few dozen rows —
+    so that the grouped products take their rows and give their sum
+    themselves (:func:`_resident_product`)?  The line is :func:`_row_tile`'s
+    small one, with a bound on the bytes; a prefill's thousand rows and a
+    training step's are over it and stage their rows in ``(M, D)``
+    buffers."""
+    return n_assign <= 2048 and 4 * t * d <= _RESIDENT_BYTES
+
+
 def _live_chunks(per_chunk, row_args, n_live, chunk: Optional[int]):
     """``per_chunk(*row_args)`` — a tuple of arrays by row, of arrays by row
     (leading dimension ``M``) — computed over the LIVE rows only: the rows'
@@ -399,21 +415,97 @@ def _combine_bwd(chunk, res, dy):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+class _Route(NamedTuple):
+    """The index work of ``block/moe/dispatch``: where each held choice's
+    row lies in the rows' buffer (``M`` rows in tile-aligned groups by
+    expert, the live tiles a prefix)."""
+    dest: jax.Array         # (T, k) the choice's row (a clamped one: not held)
+    is_held: jax.Array      # (T, k) bool
+    row_token: jax.Array    # (M,) the row's token
+    tile_expert: jax.Array  # (M // tm,) the tile's held expert
+    n_live: jax.Array       # rows of the live tiles
+    n_valid: jax.Array      # live tiles
+
+
+def _staged_product(x, w_gate, w_up, w_down, gates, route: _Route, tm: int,
+                    chunk: Optional[int], interpret: bool):
+    """The held experts' gated sum through ``(M, D)`` buffers: the rows
+    gathered whole (a padding row reads token 0), three grouped products
+    over the live tiles, a gather-combine (no scatter)."""
+    from ..ops.moe_gmm import moe_gmm
+
+    xs = _gather_rows(x, route.row_token, route.dest, route.is_held,
+                      None if chunk is None else "clip")        # (M, D)
+    gmm = lambda lhs, w: moe_gmm(lhs, w, route.tile_expert, route.n_valid,
+                                 tm=tm, interpret=interpret)
+    hidden = (jax.nn.silu(gmm(xs, w_gate).astype(jnp.float32))
+              * gmm(xs, w_up).astype(jnp.float32)).astype(x.dtype)
+    rows = gmm(hidden, w_down)                                  # (M, D)
+    return _combine(rows, gates, route.dest, route.is_held, route.row_token,
+                    route.n_live, chunk)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _resident_product(x, w_gate, w_up, w_down, gates, row_gate,
+                      route: _Route, tm: int, interpret: bool):
+    """:func:`_staged_product`'s sum where :func:`_rows_resident`: two
+    kernels and nothing else (``ops/moe_gmm.py``) — ``moe_gmm_rows`` takes a
+    live tile's rows out of the resident ``x`` and writes ``hidden``,
+    ``moe_gmm_sum`` adds each result row, times its gate, into its token's
+    row of the resident float32 ``y``.  ``row_gate (M,)`` float32 is
+    ``gates`` by row (``gates`` itself is here for the backward);
+    ``route.row_token`` names NO token (``T``) in a padding row.  The same
+    roundings; a token's held rows are added by expert, not by choice.
+    Differentiated through the staged path."""
+    from ..ops.moe_gmm import moe_gmm_rows, moe_gmm_sum
+
+    hidden = moe_gmm_rows(x, w_gate, w_up, route.row_token,
+                          route.tile_expert, route.n_valid, tm=tm,
+                          interpret=interpret)
+    return moe_gmm_sum(hidden, w_down, row_gate, route.row_token,
+                       route.tile_expert, route.n_valid,
+                       n_tokens=x.shape[0], tm=tm, interpret=interpret)
+
+
+def _resident_product_fwd(x, w_gate, w_up, w_down, gates, row_gate, route,
+                          tm, interpret):
+    return (_resident_product(x, w_gate, w_up, w_down, gates, row_gate,
+                              route, tm, interpret),
+            (x, w_gate, w_up, w_down, gates, route))
+
+
+def _resident_product_bwd(tm, interpret, res, dy):
+    *diff, route = res
+    # (the staged path gathers every row: a padding row reads token 0)
+    route = route._replace(
+        row_token=jnp.minimum(route.row_token, diff[0].shape[0] - 1))
+    staged = lambda *diff: _staged_product(*diff, route, tm, None, interpret)
+    return jax.vjp(staged, *diff)[1](dy) + (None, None)
+
+
+_resident_product.defvjp(_resident_product_fwd, _resident_product_bwd)
+
+
 def _held_experts_product(x, p, idx, gates, first, n_held: int,
                           use_kernel: bool, interpret: bool):
     """``Σ_{chosen ∧ held} gate · E(x)`` over the held experts ``[first,
     first + n_held)`` and the per-held-expert token counts.  Kernel path:
     assignments sorted by expert into tile-aligned groups whose live tiles
-    are a prefix of the ``(M, D)`` rows' buffer, three grouped products
-    over that prefix, a gather-combine (no scatter).  The backward's
-    row-side pass (each row's token's cotangent gathered, scaled for the
-    products and dotted with the row for the gates) follows the same work
-    list: at a training step's sizes (the static ``n_assign = T·k``:
-    :func:`_row_chunk`) it walks the live chunks and leaves the dead rows
-    zero, at a tick's and a prefill's it would walk the buffer whole; the
-    rows themselves are gathered whole at every size (:func:`_gather_rows`
-    says why).  Fallback: a dense loop over the held experts (tiny CPU
-    sizes)."""
+    are a prefix of the ``M`` rows, and ONE algorithm — grouped products
+    over that prefix — that stages its rows by the size it sees (static:
+    :func:`_rows_resident`).  A served tick's few rows stay in the kernels'
+    fast memory: two kernels take the rows and give the sum themselves
+    (:func:`_resident_product`).  A prefill's and a training step's go
+    through ``(M, D)`` buffers (:func:`_staged_product`): a gather, three
+    grouped products, a gather-combine (no scatter).  The backward is the
+    staged path's at every size: its row-side pass (each row's token's
+    cotangent gathered, scaled for the products and dotted with the row for
+    the gates) follows the same work list — at a training step's sizes (the
+    static ``n_assign = T·k``: :func:`_row_chunk`) it walks the live chunks
+    and leaves the dead rows zero, at a tick's and a prefill's it would
+    walk the buffer whole; the staged rows are gathered whole
+    (:func:`_gather_rows` says why).  Fallback: a dense loop over the held
+    experts (tiny CPU sizes)."""
     t, d = x.shape
     k = idx.shape[1]
     # the index work between the routing and the product: which choices
@@ -435,10 +527,10 @@ def _held_experts_product(x, p, idx, gates, first, n_held: int,
                 y = y + y_e.astype(jnp.float32) * g_e[:, None]
         return y, counts
 
-    from ..ops.moe_gmm import moe_gmm
     a = t * k
     tm = _row_tile(a)
     m_pad = -(-(a + n_held * (tm - 1)) // tm) * tm
+    resident = _rows_resident(t, d, a)
     with jax.named_scope("block/moe/dispatch"):
         padded = -(-counts // tm) * tm
         ends = jnp.cumsum(padded)
@@ -448,24 +540,29 @@ def _held_experts_product(x, p, idx, gates, first, n_held: int,
         dest = jnp.where(flat_held,
                          starts[jnp.clip(local.reshape(-1), 0, n_held - 1)]
                          + rank, m_pad).astype(jnp.int32)
-        row_token = jnp.zeros((m_pad,), jnp.int32).at[dest].set(
+        # a padding row's token: none (``t``) where the kernels take the
+        # rows themselves, the first where they are gathered whole
+        row_token = (jnp.full((m_pad,), t, jnp.int32) if resident
+                     else jnp.zeros((m_pad,), jnp.int32)).at[dest].set(
             jnp.repeat(jnp.arange(t, dtype=jnp.int32), k), mode="drop")
         tile_expert = jnp.minimum(jnp.searchsorted(
             ends, jnp.arange(m_pad // tm, dtype=jnp.int32) * tm,
             side="right"), n_held - 1)
         n_live = ends[-1]
         n_valid = n_live // tm
-    chunk = _row_chunk(a, tm)
+        if resident:    # each row's gate (0: a row that no choice names)
+            row_gate = jnp.zeros((m_pad,), jnp.float32).at[dest].set(
+                jax.lax.stop_gradient(gates).reshape(-1), mode="drop")
+    weights = (p["w_gate"], p["w_up"], p["w_down"])
     with jax.named_scope("block/moe/gmm"):
-        dest = jnp.minimum(dest, m_pad - 1).reshape(t, k)
-        xs = _gather_rows(x, row_token, dest, is_held,
-                          None if chunk is None else "clip")    # (M, D)
-        gmm = lambda lhs, w: moe_gmm(lhs, w, tile_expert, n_valid, tm=tm,
-                                     interpret=interpret)
-        hidden = (jax.nn.silu(gmm(xs, p["w_gate"]).astype(jnp.float32))
-                  * gmm(xs, p["w_up"]).astype(jnp.float32)).astype(x.dtype)
-        rows = gmm(hidden, p["w_down"])                          # (M, D)
-        y = _combine(rows, gates, dest, is_held, row_token, n_live, chunk)
+        route = _Route(jnp.minimum(dest, m_pad - 1).reshape(t, k), is_held,
+                       row_token, tile_expert, n_live, n_valid)
+        if resident:
+            y = _resident_product(x, *weights, gates, row_gate, route, tm,
+                                  interpret)
+        else:
+            y = _staged_product(x, *weights, gates, route, tm,
+                                _row_chunk(a, tm), interpret)
     return y, counts
 
 
@@ -496,8 +593,9 @@ def moe_dropless(x, params, cfg, *, live=None,
 
     Differentiable in ``x`` and every parameter: through the gates (into
     the router), the rows' gather, the three grouped products
-    (``moe_gmm``'s own VJP) and the gather-combine; the choice of experts
-    and the counts carry no gradient.
+    (``moe_gmm``'s own VJP) and the gather-combine — a tick's resident
+    forward (``moe_gmm_rows`` + ``moe_gmm_sum``) through that same staged
+    path; the choice of experts and the counts carry no gradient.
     """
     from .blocks import swiglu
 

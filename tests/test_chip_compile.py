@@ -519,6 +519,19 @@ def _assert_scopes(text: str, *scopes, tick: bool = False) -> None:
         assert scope in text, scope
 
 
+def _assert_resident_expert_layers(text: str, layers: int) -> None:
+    """A TICK's expert layers take their rows and give their sum inside the
+    grouped products (``parallel/moe.py::_rows_resident``, ISSUE 42): two
+    kernels a layer, ``moe_gmm_rows`` and ``moe_gmm_sum``, whose names hold
+    ``moe_gmm`` (what ``moe_gmm_ms_per_tick`` sums), and no staged
+    ``moe_gmm`` call beside them."""
+    calls = [ln.split(" = ")[0] for ln in text.split("\n")
+             if "tpu_custom_call" in ln]
+    count = lambda name: sum(name in c for c in calls)
+    assert count("moe_gmm_rows") == layers and count("moe_gmm_sum") == layers
+    assert count("moe_gmm") == 2 * layers
+
+
 # (heads, head_dim, slots, prompt, total): the LM width chip_smoke runs,
 # and gpt2-medium's heads in the serving cell's pool (BENCHMARK.json)
 SERVING_SHAPES = {"8x128": (N_HEADS, HEAD_DIM, 4, 512, 512 + 64),
@@ -658,7 +671,7 @@ def test_latent_attention_and_expert_serving_programs(topo, as_tpu):
         _sds((n_slots,), jnp.bool_, rep)).compile().as_text()   # live mask
     assert "HloModule jit_serving_tick" in tick
     assert tick.count("%decode_attn_mla") >= layers
-    assert tick.count("%moe_gmm") >= 3          # gate, up, down
+    _assert_resident_expert_layers(tick, 1)
     _assert_scopes(tick, "tick/layer/block/mla/proj",
                    "tick/layer/block/mla/cache_write",
                    "block/mla/core/tick/work_list", "decode_attn_mla",
@@ -785,7 +798,7 @@ def test_state_and_row_layers_in_one_pool_serving_programs(topo, as_tpu):
     count = lambda name: sum(name in c for c in calls)
     assert count("kda_step") == 20 and count("decode_attn_mla") == 7
     assert count("conv_step") == 20
-    assert count("moe_gmm") >= 3 * 26
+    _assert_resident_expert_layers(text, 26)
     # no reader of an accepted metric may match the new kernels by substring
     for name in ("kda_step", "conv_step"):
         assert not any(n in name for n in ("decode_attn", "moe_gmm", "flash",
@@ -921,7 +934,12 @@ def test_ring_and_row_layers_in_one_pool_serving_programs(topo, as_tpu):
     count = lambda name: sum(name in c for c in calls)
     assert count("decode_attn_gqa") == 40 and count("decode_attn_beam") == 0
     assert count("decode_attn") == 40       # what decode_attn_ms_per_tick sums
-    assert count("moe_gmm") >= 3 * 39
+    _assert_resident_expert_layers(text, 39)
+    # ... and nothing else between the routing's index work and the layer's
+    # sum: no gather, no operation over an (m_pad, D) = (704, 2048) buffer
+    under = [ln for ln in text.split("\n") if "block/moe/gmm" in ln]
+    assert len(under) >= 2 * 39
+    assert not [ln for ln in under if " gather(" in ln or "[704,2048]" in ln]
     # no reader of another model's metric may match the new names
     for name in ("decode_attn_gqa", "window_flash_fwd"):
         assert not any(n in name for n in ("mla", "moe_gmm", "kda"))

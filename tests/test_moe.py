@@ -389,3 +389,190 @@ def test_moe_dropless_kernel_path_gradients_are_the_dense_loops(router):
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-5)
+
+
+# --------------------------------------------------------------------------
+# a tick's expert layer takes its rows and gives its sum inside the grouped
+# products (ISSUE 42): ``moe_gmm_rows`` + ``moe_gmm_sum`` where the layer's
+# rows stay resident, against the staged path (gather, three ``moe_gmm``
+# calls, gather-combine), interpret mode
+# --------------------------------------------------------------------------
+
+def _tick_layer(router="softmax", *, top_k=2, held=(2, 4), t=24, seed=0,
+                dtype=jnp.bfloat16):
+    from chainermn_tpu.parallel.blocks import MoEConfig
+
+    cfg = MoEConfig(n_experts=8, top_k=top_k, n_group=2, topk_group=2,
+                    routed_scaling_factor=1.5, held=held, router=router,
+                    n_shared=0)
+    d, f, n = 128, 256, held[1]
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    p = {"router": jax.random.normal(ks[0], (d, 8), dtype),
+         "w_gate": (jax.random.normal(ks[1], (n, d, f)) * d ** -0.5
+                    ).astype(dtype),
+         "w_up": (jax.random.normal(ks[2], (n, d, f)) * d ** -0.5
+                  ).astype(dtype),
+         "w_down": (jax.random.normal(ks[3], (n, f, d)) * f ** -0.5
+                    ).astype(dtype)}
+    if router == "sigmoid_group":
+        p["router_bias"] = jax.random.normal(ks[4], (8,)) * 0.1
+    return cfg, p, jax.random.normal(ks[5], (t, d), dtype)
+
+
+def _both_paths(monkeypatch, fn):
+    """``fn()`` under the resident path, then under the staged one."""
+    from chainermn_tpu.parallel import moe
+
+    assert moe._rows_resident(24, 128, 24 * 4)
+    got = fn()
+    monkeypatch.setattr(moe, "_rows_resident", lambda t, d, a: False)
+    return got, fn()
+
+
+def _resident_router(monkeypatch, router):
+    from chainermn_tpu.parallel.moe import moe_dropless
+
+    cfg, p, x = _tick_layer(router)
+    got, want = _both_paths(monkeypatch, lambda: moe_dropless(
+        x, p, cfg, interpret=True))
+    assert int(want[1][1]) > 0                  # some assignment is held
+    for a, b in zip(got, want):                 # y, counts, idx: to the bit
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+
+def _resident_free_rows(monkeypatch):
+    from chainermn_tpu.parallel.moe import moe_dropless
+
+    cfg, p, x = _tick_layer("softmax", seed=1)
+    live = jnp.asarray(np.arange(24) % 3 != 1)
+    got, want = _both_paths(monkeypatch, lambda: moe_dropless(
+        x, p, cfg, live=live, interpret=True))
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    idle = ~np.asarray(live)
+    assert not np.asarray(got[0], np.float32)[idle].any()
+    assert (np.asarray(got[2])[idle] == cfg.n_experts).all()
+
+
+def _resident_nothing_held(monkeypatch):
+    from chainermn_tpu.parallel.moe import moe_dropless
+
+    cfg, p, x = _tick_layer("softmax", seed=2)
+    y, counts, _ = moe_dropless(x, p, cfg, live=jnp.zeros((24,), bool),
+                                interpret=True)     # n_valid == 0
+    assert not np.asarray(y, np.float32).any()
+    assert not np.asarray(counts).any()
+
+
+def _resident_three_held(monkeypatch):
+    """Every choice held: a token's four rows are summed by expert, where
+    the staged path sums them by choice — float32 rounding apart, no more."""
+    from chainermn_tpu.parallel import moe
+
+    cfg, p, x = _tick_layer("softmax", top_k=4, held=(0, 8), seed=3)
+    idx, gates = moe.softmax_topk_route(x, p["router"], cfg)
+    got, want = _both_paths(monkeypatch, lambda: moe._held_experts_product(
+        x, p, idx, gates, 0, 8, True, True))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    assert int(got[1].sum()) == 24 * 4
+    y, y_staged = np.asarray(got[0]), np.asarray(want[0])
+    assert y.dtype == np.float32
+    np.testing.assert_allclose(y, y_staged, rtol=0,
+                               atol=4 * np.finfo(np.float32).eps
+                               * np.abs(y_staged).max())
+
+
+def _resident_dead_rows_hold_nan(monkeypatch):
+    """``hidden``'s rows in dead tiles are never written and a live tile's
+    padding rows belong to no token: a NaN in either reaches no row of
+    ``y`` — and ``x``'s own NaN row reaches only the token that has it."""
+    from chainermn_tpu.ops.moe_gmm import moe_gmm_rows, moe_gmm_sum
+
+    tm, t, d, f = 8, 6, 128, 128
+    tile_expert, n_valid, m, live = _grouped((3, 0, 9), tm, 2)
+    rng = np.random.default_rng(5)
+    row_token = np.where(live, rng.integers(0, t - 1, m), t).astype(np.int32)
+    row_gate = np.where(live, rng.random(m), 0.0).astype(np.float32)
+    w = jnp.asarray(rng.normal(size=(3, f, d)) * 0.1, jnp.bfloat16)
+    hidden = jnp.asarray(rng.normal(size=(m, f)), jnp.bfloat16)
+    dirty = jnp.where(jnp.asarray(live)[:, None], hidden, jnp.nan)
+    y, y_dirty = (np.asarray(moe_gmm_sum(
+        h, w, row_gate, row_token, tile_expert, n_valid, n_tokens=t, tm=tm,
+        interpret=True)) for h in (hidden, dirty))
+    assert np.isfinite(y_dirty).all()
+    np.testing.assert_array_equal(y, y_dirty)
+    rows = np.asarray(hidden, np.float32)
+    want = np.zeros((t, d), np.float32)
+    for r in np.flatnonzero(live):
+        e = tile_expert[r // tm]
+        prod = jnp.dot(hidden[r], w[e], preferred_element_type=jnp.float32
+                       ).astype(jnp.bfloat16).astype(jnp.float32)
+        want[row_token[r]] += np.asarray(prod) * row_gate[r]
+    np.testing.assert_allclose(y, want, rtol=1e-6, atol=1e-6)
+    assert not y[t - 1].any() and rows.any()    # no row names the last token
+    # the rows' side: token t-1 holds a NaN and no row names it
+    x = jnp.asarray(rng.normal(size=(t, d)), jnp.bfloat16).at[t - 1].set(
+        jnp.nan)
+    wg = jnp.asarray(rng.normal(size=(3, d, f)) * 0.1, jnp.bfloat16)
+    got = np.asarray(moe_gmm_rows(x, wg, wg, row_token, tile_expert, n_valid,
+                                  tm=tm, interpret=True), np.float32)
+    assert np.isfinite(got[:n_valid * tm]).all()
+    assert not got[:n_valid * tm][~live[:n_valid * tm]].any()   # padding: 0
+
+
+def _resident_gradients(monkeypatch):
+    """A tick's size differentiates through the staged path."""
+    from chainermn_tpu.parallel.moe import moe_dropless
+
+    cfg, p, x = _tick_layer("sigmoid_group", seed=6, dtype=jnp.float32)
+    ct = jax.random.normal(jax.random.PRNGKey(7), x.shape)
+    loss = lambda x, p: (moe_dropless(x, p, cfg, interpret=True)[0]
+                         * ct).sum()
+    got, want = _both_paths(monkeypatch,
+                            lambda: jax.grad(loss, (0, 1))(x, p))
+    assert float(jnp.abs(want[1]["w_down"]).max()) > 0
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+RESIDENT_CASES = {
+    "softmax": lambda mp: _resident_router(mp, "softmax"),
+    "sigmoid_group": lambda mp: _resident_router(mp, "sigmoid_group"),
+    "free-rows": _resident_free_rows,
+    "nothing-held": _resident_nothing_held,
+    "three-held-choices": _resident_three_held,
+    "dead-rows-hold-nan": _resident_dead_rows_hold_nan,
+    "gradients": _resident_gradients,
+}
+
+
+@pytest.mark.parametrize("case", sorted(RESIDENT_CASES))
+def test_resident_expert_layer_is_the_staged_one(monkeypatch, case):
+    RESIDENT_CASES[case](monkeypatch)
+
+
+@pytest.mark.parametrize("name,t,d,top_k,resident", [
+    # the three expert cells' ticks (slots, hidden size, choices a token)
+    ("laguna-tick", 24, 2048, 8, True),
+    ("kimi-tick", 64, 2304, 8, True),
+    ("deepseek-tick", 64, 7168, 8, True),
+    # ... every prefill bucket of theirs ...
+    ("prefill-1024", 1024, 2048, 8, False),
+    ("prefill-2048", 2048, 2304, 8, False),
+    ("prefill-3072", 3072, 7168, 8, False),
+    # ... a prompt short enough by its assignments, too wide by its bytes ...
+    ("256-rows-of-7168", 256, 7168, 8, False),
+    # ... and Mellum's train step (2 x 8192 tokens)
+    ("mellum2-train-step", 16384, 2304, 8, False),
+])
+def test_rows_resident_is_a_ticks_size(name, t, d, top_k, resident):
+    from chainermn_tpu.parallel.moe import _row_chunk, _row_tile, \
+        _rows_resident
+
+    assert _rows_resident(t, d, t * top_k) is resident
+    if resident:    # the tick's row tile, no chunked backward
+        assert _row_tile(t * top_k) == 32
+        assert _row_chunk(t * top_k, 32) is None

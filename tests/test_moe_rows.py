@@ -98,9 +98,12 @@ def _same_bits(got, want, what):
 
 @pytest.fixture
 def chunked(monkeypatch, request):
-    """The chunked path at tiny sizes: ``request.param`` tiles a chunk."""
+    """The chunked path at tiny sizes: ``request.param`` tiles a chunk (and
+    the rows staged, as a training step's are: at these sizes the layer
+    would keep them resident, ISSUE 42)."""
     tiles = request.param
     monkeypatch.setattr(moe_mod, "_row_chunk", lambda a, tm: tiles * tm)
+    monkeypatch.setattr(moe_mod, "_rows_resident", lambda t, d, a: False)
     return tiles
 
 
@@ -203,8 +206,9 @@ SIZES = {"tick": (256, 2, 0), "prefill_1024": (1024, 8, 0),
 @pytest.mark.parametrize("size", sorted(SIZES))
 def test_the_path_is_static_by_the_assignments(size, monkeypatch):
     """A tick's and a prefill's programs hold no ``while`` of the row-side
-    pass (they walk the buffer whole, as the parent did: the forward lowers
-    to the parent's text); the training cell's forward holds none either
+    pass (they walk the buffer whole, as the parent did: a prefill's forward
+    lowers to the parent's text, a tick's to the two kernels that keep its
+    rows resident, ISSUE 42); the training cell's forward holds none either
     (its rows are gathered whole from fast memory), its backward one."""
     t, k, loops = SIZES[size]
     tm = moe_mod._row_tile(t * k)
@@ -240,6 +244,10 @@ def test_the_path_is_static_by_the_assignments(size, monkeypatch):
     assert _n_whiles(fwd.jaxpr) == _n_whiles(fwd0.jaxpr)
     assert _n_whiles(both.jaxpr) - _n_whiles(both0.jaxpr) == loops
     assert text == text0            # the served forward: the parent's text
+    if not loops:                   # ... but for a tick's resident rows
+        resident = moe_mod._rows_resident(t, D, t * k)
+        assert resident == (size == "tick")
+        assert ("moe_gmm_rows" in text) == ("moe_gmm_sum" in text) == resident
 
 
 def test_the_chunks_carry_the_rows_varying_type(devices):

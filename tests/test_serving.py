@@ -470,6 +470,49 @@ def test_pool_is_donated_to_every_program_that_returns_it(devices, program):
     eng.close()
 
 
+def test_tick_operands_that_stand_are_handed_in_again(devices):
+    """A tick's per-slot operands other than the positions change at
+    admissions and ends only, and a transfer is the dearest thing the
+    host does a tick (v5e: 0.25 ms each): while an operand's values stand
+    the program is handed the device array made for them, a changed value
+    gets a new one — also where the caller writes its own buffer in place
+    — and the tokens are the closed-batch generator's."""
+    from chainermn_tpu.serving import ServingEngine
+
+    params, mesh = _params(pos_impl="rope"), _mesh(devices, 1)
+    eng = ServingEngine(params, head_dim=HEAD_DIM, n_slots=3, max_total=16,
+                        mesh=mesh)
+    dec, pool = eng.engine, eng.pool
+    prompt = np.arange(1, 7, dtype=np.int32)
+    slot = pool.acquire()
+    tokens = [dec.prefill_into_slot(prompt, slot)]
+    override = np.zeros(pool.n_slots, np.int32)
+    temps = np.zeros(pool.n_slots, np.float32)
+    live = np.zeros(pool.n_slots, bool)
+    live[slot] = True
+    handed = []
+    for step in range(4):
+        # the first token is the host's, the later ones stay on the device
+        override[slot] = tokens[0] if step == 0 else -1
+        if step == 3:
+            temps[1] = 0.5          # a free slot's: in place, as the engine
+        out = dec.collect_tick(dec.launch_tick(override, None, temps, live))
+        tokens.append(int(out[slot]))
+        handed.append({k: v[1] for k, v in dec._operands.items()})
+    assert set(handed[0]) == {"override", "keys", "temps", "live"}
+    assert handed[1]["override"] is not handed[0]["override"]
+    assert handed[2]["override"] is handed[1]["override"]
+    assert all(handed[i]["live"] is handed[0]["live"]
+               and handed[i]["keys"] is handed[0]["keys"] for i in (1, 2, 3))
+    assert handed[2]["temps"] is handed[0]["temps"]
+    assert handed[3]["temps"] is not handed[0]["temps"]
+    np.testing.assert_array_equal(np.asarray(handed[3]["temps"]), temps)
+    np.testing.assert_array_equal(np.asarray(handed[0]["temps"]), 0.0)
+    np.testing.assert_array_equal(
+        tokens, _oracle(params, mesh, prompt, 5))
+    eng.close()
+
+
 def test_pool_update_is_safe_under_concurrent_callers(devices):
     """``CachePool.update`` / ``read`` do "read the buffers → launch → bind
     the result" under the pool's lock: more threads than cores hammering
