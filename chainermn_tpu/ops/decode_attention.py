@@ -55,9 +55,9 @@ its own kernel where a head is whole lane tiles (``head_dim % 128 == 0``):
 a KV head's columns are then an aligned slice of the flat row, and its
 ``g`` query heads meet it as two plain MXU matmuls (``(g, hd) x (hd,
 S_b)``, ``(g, S_b) x (S_b, hd)``), the cache read once.  Narrower heads
-ride the BEAM kernel (the g query groups of a batch row share its cache
-row — exactly the beam row mapping — ``masked='pos'``; its two other modes
-walk the list of every block of every row).  A cache of ``W``
+ride the QUERY-GROUP kernel (:func:`beam_attend_parts`, kernel name
+``decode_attn_beam``: the g query groups of a batch row are g query rows
+that share its cache row).  A cache of ``W``
 rows that is a RING (a windowed layer: position ``p`` at row ``p % W``,
 every key rotated at its own position before it was cached) needs nothing
 more: a position at or beyond ``W`` masks nothing, so ``min(pos + 1, W)``
@@ -90,6 +90,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .._compat import shape_dtype_struct as _sds
+from .kv_cache import _inherit_vma
 
 __all__ = ["decode_attend", "decode_attend_gqa", "decode_attend_mla",
            "DecodeWork", "work_list", "live_blocks", "beam_attend_parts",
@@ -97,15 +98,6 @@ __all__ = ["decode_attend", "decode_attend_gqa", "decode_attend_mla",
 
 _NEG = -1e30
 DEFAULT_BLOCK_S = 512  # single source for the kernel AND dispatch gates
-
-
-def _inherit_vma(*xs) -> frozenset:
-    vma = set()
-    for x in xs:
-        v = getattr(getattr(x, "aval", None), "vma", None)
-        if v:
-            vma |= set(v)
-    return frozenset(vma)
 
 
 def _seg(d: int, n_heads: int):
@@ -231,6 +223,15 @@ def _resident_zero(start, *refs):
             ref[...] = jnp.zeros_like(ref)
 
 
+def _open_softmax(first, m_ref, l_ref, acc_ref):
+    """A slot's first pair opens its online softmax: max, sum, weighted sum."""
+    @pl.when(first)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
 def _kernel(slot_ref, block_ref, n_ref, pos_ref, q_ref, k_ref, v_ref,
             seg_ref, segt_ref, o_ref, m_ref, l_ref, acc_ref, *, s, block_s,
             scale):
@@ -238,11 +239,7 @@ def _kernel(slot_ref, block_ref, n_ref, pos_ref, q_ref, k_ref, v_ref,
         slot_ref, block_ref, n_ref, pos_ref, s, block_s)
     _resident_zero(start, o_ref)
 
-    @pl.when(first)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    _open_softmax(first, m_ref, l_ref, acc_ref)
 
     @pl.when(run)
     def _block():
@@ -345,25 +342,18 @@ def decode_attend(q, kc, vc, pos, busy=None, *, n_heads: int, head_dim: int,
 
 
 def _beam_kernel(slot_ref, block_ref, n_ref, pos_ref, q_ref, k_ref, v_ref,
-                 seg_ref, segt_ref, mask_ref, acc_o_ref, m_o_ref, l_o_ref,
-                 m_ref, l_ref, acc_ref, *, beams, s, block_s, scale, masked):
-    """Beam variant: q rows [i·beams, (i+1)·beams) share batch row i's
-    cache segment; per-row online-softmax state; outputs UNNORMALIZED
-    (acc, m, l) so two segments (prompt + generated) merge outside with
-    the standard flash combine.  ``masked`` selects the ancestry-mask
-    operand (generated segment, ``'amask'``), the per-cache-row position
-    from scalar prefetch (GQA decode, ``'pos'``) or fully-valid (prompt
-    segment, ``'none'``); the walk is :func:`_kernel`'s, the list holding
-    every block of every row but under ``'pos'``."""
+                 seg_ref, segt_ref, acc_o_ref, m_o_ref, l_o_ref,
+                 m_ref, l_ref, acc_ref, *, beams, s, block_s, scale):
+    """Query groups of one cache row: q rows [i·beams, (i+1)·beams) share
+    batch row i's cache; per-row online-softmax state; outputs UNNORMALIZED
+    (acc, m, l), normalized outside by the flash combine
+    (:func:`merge_attend_parts`).  The walk and the mask (positions beyond
+    the cache row's own, from scalar prefetch) are :func:`_kernel`'s."""
     start, i, j, pos_i, run, first, last = _step(
         slot_ref, block_ref, n_ref, pos_ref, s, block_s)
     _resident_zero(start, acc_o_ref, m_o_ref, l_o_ref)
 
-    @pl.when(first)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    _open_softmax(first, m_ref, l_ref, acc_ref)
 
     @pl.when(run)
     def _block():
@@ -377,18 +367,12 @@ def _beam_kernel(slot_ref, block_ref, n_ref, pos_ref, q_ref, k_ref, v_ref,
             s_blk = jax.lax.dot_general(
                 kb * q, seg, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale       # (S_b, H)
-            if masked == "amask":
-                # mask operand is f32: Mosaic only supports non-no-op minor-
-                # dim insertion ([:, None]) on 32-bit types
-                mrow = mask_ref[0, r, :][:, None]                 # (S_b, 1)
-                s_blk = jnp.where(mrow > 0.5, s_blk, _NEG)
-            elif masked == "pos":
-                # position-validity from the row's prefetch scalar —
-                # zero HBM cost (the GQA path's mask; an f32 operand
-                # here would stream B·g·S·4 bytes per layer per tick)
-                idx = j * block_s + jax.lax.broadcasted_iota(
-                    jnp.int32, s_blk.shape, 0)
-                s_blk = jnp.where(idx <= pos_i, s_blk, _NEG)
+            # position-validity from the row's prefetch scalar — zero HBM
+            # cost (an f32 mask operand would stream B·g·S·4 bytes per
+            # layer per tick)
+            idx = j * block_s + jax.lax.broadcasted_iota(
+                jnp.int32, s_blk.shape, 0)
+            s_blk = jnp.where(idx <= pos_i, s_blk, _NEG)
             m_prev = m_ref[r:r + 1, :]                            # (1, H)
             l_prev = l_ref[r:r + 1, :]
             m_new = jnp.maximum(m_prev, s_blk.max(axis=0, keepdims=True))
@@ -421,58 +405,35 @@ def _beam_kernel(slot_ref, block_ref, n_ref, pos_ref, q_ref, k_ref, v_ref,
 
 @functools.partial(jax.jit, static_argnames=(
     "beams", "n_heads", "head_dim", "block_s", "interpret"))
-def beam_attend_parts(q, kc, vc, amask=None, pos=None, busy=None, *,
+def beam_attend_parts(q, kc, vc, pos, busy=None, *,
                       beams: int, n_heads: int, head_dim: int,
                       block_s: int = DEFAULT_BLOCK_S,
                       interpret: bool = False,
                       work: Optional[DecodeWork] = None):
-    """One cache SEGMENT's worth of beam attention, unnormalized.
+    """Attention of ``beams`` query rows a cache row, unnormalized — what
+    narrow-head GQA decode rides on (:func:`decode_attend_gqa`: a KV head's
+    query group is ``beams`` query rows of one cache row).
 
-    ``q (B·beams, H·hd)`` flat per-beam queries; ``kc/vc (B, S_seg,
-    H·hd)`` a cache segment shared by each batch row's ``beams`` rows —
-    the shared PROMPT cache (pass ``amask=None``: every position valid)
-    or the flat per-slot GENERATED caches ``(B, slots·T, D)`` with
-    ``amask (B, beams, S_seg)`` (any 0/1 dtype; carried as f32 in the
-    kernel) = ancestry ∧ validity.  With ``amask=None`` and ``pos`` (a
-    scalar or a ``(B,)`` int32 vector, ≥ 0) batch row ``b``'s rows see
-    its segment's prefix ``[0, pos[b]]`` and read only the blocks that
-    hold it, and only the ``busy`` rows' are read at all (GQA decode;
-    ``busy``, ``work`` as :func:`decode_attend`: a row that is not busy
-    gives ``(0, 0, 0)``, which merges to 0).  Returns
-    ``(acc (B·beams, D) f32 unnormalized, m (B·beams, H) f32,
-    l (B·beams, H) f32)``; merge segments with the flash combine
-    (see ``merge_attend_parts``).
-
-    Masking uses a finite ``-1e30`` sentinel, so a row with NO valid
-    position in ``amask`` still yields finite ``(acc, m, l)`` that the
-    merge cannot tell from real data — at least one segment per row must
-    contain a valid position (``merge_attend_parts`` documents the same
-    precondition; the always-present prompt segment satisfies it).
+    ``q (B·beams, H·hd)`` flat queries, row ``b·beams + r`` the ``r``-th of
+    batch row ``b``; ``kc/vc (B, S, H·hd)`` the cache; ``pos`` a scalar or
+    a ``(B,)`` int32 vector, ≥ 0: batch row ``b``'s rows see its prefix
+    ``[0, pos[b]]`` and read only the blocks that hold it, and only the
+    ``busy`` rows' are read at all (``busy``, ``work`` as
+    :func:`decode_attend`: a row that is not busy gives ``(0, 0, 0)``,
+    which merges to 0).  Returns ``(acc (B·beams, D) f32 unnormalized, m
+    (B·beams, H) f32, l (B·beams, H) f32)``: normalize with
+    :func:`merge_attend_parts`.
     """
     bk, d = q.shape
     b, s, _ = kc.shape
     assert bk == b * beams, (bk, b, beams)
     h = n_heads
     assert d == h * head_dim, (d, h, head_dim)
-    masked = "amask" if amask is not None else (
-        "pos" if pos is not None else "none")
-    if masked != "pos":
-        pos = s - 1                     # every block of every row
     bs, grid, scalars, _, kv_map = _work_spec(work, pos, busy, b, s,
                                               block_s)
     scale = 1.0 / (head_dim ** 0.5)
     seg = _seg(d, h)
     whole = lambda *_: (0, 0)
-    if amask is None:
-        # tiny constant dummy keeps ONE kernel signature at ~zero DMA
-        # (the pos/none modes never read it; an (b, beams, s) dummy
-        # would stream B·beams·S·4 bytes per tick for nothing)
-        amask = jnp.ones((1, beams, 8), jnp.float32)
-        mask_spec = pl.BlockSpec((1, beams, 8), lambda *_: (0, 0, 0))
-    else:
-        mask_spec = pl.BlockSpec(
-            (1, beams, bs),
-            lambda t, slot, block, n, p_: (slot[t], 0, block[t]))
     vma = _inherit_vma(q, kc, vc)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars), grid=grid,
@@ -482,7 +443,6 @@ def beam_attend_parts(q, kc, vc, amask=None, pos=None, busy=None, *,
             pl.BlockSpec((1, bs, d), kv_map),
             pl.BlockSpec((d, h), whole),
             pl.BlockSpec((h, d), whole),
-            mask_spec,
         ],
         out_specs=[
             pl.BlockSpec((bk, d), whole),
@@ -496,33 +456,24 @@ def beam_attend_parts(q, kc, vc, amask=None, pos=None, busy=None, *,
         ])
     return pl.pallas_call(
         functools.partial(_beam_kernel, beams=beams, s=s, block_s=bs,
-                          scale=scale, masked=masked),
+                          scale=scale),
         grid_spec=grid_spec,
         out_shape=[_sds((bk, d), jnp.float32, vma=vma),
                    _sds((bk, h), jnp.float32, vma=vma),
                    _sds((bk, h), jnp.float32, vma=vma)],
         name="decode_attn_beam",
         interpret=interpret,
-    )(*scalars, q, kc, vc, seg, seg.T, amask.astype(jnp.float32))
+    )(*scalars, q, kc, vc, seg, seg.T)
 
 
 def merge_attend_parts(parts, n_heads: int, head_dim: int, dtype):
-    """Flash combine of ≥2 ``(acc, m, l)`` segments → normalized context
+    """Flash combine of ``(acc, m, l)`` parts → normalized context
     ``(B·beams, H·hd)`` in ``dtype``.
 
-    PRECONDITION: every output row must have at least one VALID (unmasked)
-    key position across the segments.  A fully-masked row cannot be
-    detected here — masking uses a finite ``-1e30`` sentinel, so such a
-    row arrives with ``m = -1e30`` and ``l = S`` (every masked score
-    contributes ``exp(0)``), which is indistinguishable from real data and
-    would merge into silently junk context.  Every in-tree caller
-    satisfies this: the prompt segment is always present and position 0 is
-    always valid (``decode_attend``/``beam_attend_parts`` mask by
-    ``pos``-validity or ancestry, never the whole row).  The ``l > 0``
-    guard below only covers the benign exact-zero case (an all-zero
-    partial segment from :func:`zeros_like` initialization), returning
-    zeros instead of 0/0 NaNs.
-    """
+    Masking uses a finite ``-1e30`` sentinel, so a row whose every position
+    were masked could not be told from real data; :func:`beam_attend_parts`
+    masks by ``pos >= 0``, never a whole row.  The ``l > 0`` guard: a row
+    that is not busy arrives as ``(0, 0, 0)`` and gives zeros, not 0/0."""
     d = n_heads * head_dim
     seg_t = _seg(d, n_heads).T
 
@@ -563,11 +514,7 @@ def _gqa_kernel(slot_ref, block_ref, n_ref, pos_ref, q_ref, k_ref, v_ref,
     _, _, j, pos_i, run, first, last = _step(slot_ref, block_ref, n_ref,
                                              pos_ref, s, block_s)
 
-    @pl.when(first)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    _open_softmax(first, m_ref, l_ref, acc_ref)
 
     @pl.when(run)
     def _block():
@@ -651,11 +598,10 @@ def decode_attend_gqa(q, kc, vc, pos, busy=None, *, n_q_heads: int,
 
     Heads of whole lane tiles (``head_dim % 128 == 0``) take the
     one-position-per-cache-row face of :func:`decode_attend` through
-    :func:`_gqa_kernel`.  Narrower heads ride the beam kernel, for it is
-    structurally the BEAM problem: the ``g = n_q_heads/n_kv_heads`` query
-    groups of batch row ``b`` all attend batch row ``b``'s cache — so the
-    beam kernel serves GQA verbatim with ``beams=g`` and the position-
-    validity mask from the row's prefetch scalar (``masked='pos'``).
+    :func:`_gqa_kernel`.  Narrower heads ride the query-group kernel
+    (:func:`beam_attend_parts`): the ``g = n_q_heads/n_kv_heads`` query
+    groups of batch row ``b`` all attend batch row ``b``'s cache, so they
+    are ``beams=g`` query rows of it, masked by the row's position.
     Either way a block of the cache streams ONCE per tick (one grid step a
     listed block; the g groups iterate in-register) — GQA's inference
     payoff is preserved.
@@ -677,13 +623,11 @@ def decode_attend_gqa(q, kc, vc, pos, busy=None, *, n_q_heads: int,
             q, kc, vc, pos, busy, work, n_q_heads=n_q_heads,
             n_kv_heads=n_kv_heads, head_dim=head_dim, block_s=block_s,
             interpret=interpret)
-    # (B, Hkv, g, hd) -> group-major rows (B·g, Hkv·hd), b-major like the
-    # beam kernel's row->cache mapping expects
+    # (B, Hkv, g, hd) -> group-major rows (B·g, Hkv·hd), b-major as the
+    # kernel's row -> cache row mapping expects
     q_g = q.reshape(b, n_kv_heads, g, head_dim).transpose(0, 2, 1, 3) \
         .reshape(b * g, n_kv_heads * head_dim)
-    # position validity rides the prefetch scalar (masked='pos') — an
-    # f32 mask operand would stream B·g·S·4 bytes per layer per tick
-    part = beam_attend_parts(q_g, kc, vc, None, pos, busy, beams=g,
+    part = beam_attend_parts(q_g, kc, vc, pos, busy, beams=g,
                              n_heads=n_kv_heads, head_dim=head_dim,
                              block_s=block_s, interpret=interpret, work=work)
     ctx_g = merge_attend_parts([part], n_heads=n_kv_heads,
@@ -701,11 +645,7 @@ def _mla_kernel(slot_ref, block_ref, n_ref, pos_ref, q_ref, c_ref, o_ref,
     _, _, j, pos_i, run, first, last = _step(slot_ref, block_ref, n_ref,
                                              pos_ref, s, block_s)
 
-    @pl.when(first)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    _open_softmax(first, m_ref, l_ref, acc_ref)
 
     @pl.when(run)
     def _block():

@@ -26,7 +26,7 @@ else of it is touched.
   zeros, a cached prefix, a ring nobody decodes from; ``busy`` None writes
   every slot's row.
 * :func:`cache_append` — a SCALAR position for a whole batch
-  (``lm_generate``, beam search: kernel ``kv_cache_write``, one block of all
+  (``lm_generate``'s closed batch: kernel ``kv_cache_write``, one block of all
   batch rows), multi-row writes with ``rows | 8``, and the
   ``dynamic_update_slice`` everywhere else (other backends, a prefill's
   slab, an unaligned total).  Its per-row face (rank-1 ``pos``) is
@@ -237,13 +237,12 @@ def cache_append(kc, vc, k_new, v_new, pos, *, axis: int = 1,
     ``axis``; returns the updated ``(kc, vc)``.
 
     ``impl='auto'`` uses the Pallas scatter on TPU when the write is
-    ``rows`` rows with ``rows | 8`` (one row = the decode tick; rows=k =
-    the time-major beam tick writing all k slots at once), and the XLA
-    ``dynamic_update_slice`` everywhere else (other backends, and slab
-    prefill writes where a full-pass update is amortized and XLA's slab
-    write is fine).  CONTRACT for rows > 1: ``pos`` must be a multiple
-    of ``rows`` (the beam tick's ``(i-1)·k`` positions are) — the
-    in-tile placement relies on it.  A concrete misaligned ``pos`` falls
+    ``rows`` rows with ``rows | 8`` (one row = the decode tick; more: no
+    caller in the package since beam search went, ROADMAP D15(a)), and
+    the XLA ``dynamic_update_slice`` everywhere else (other backends, and
+    slab prefill writes where a full-pass update is amortized and XLA's
+    slab write is fine).  CONTRACT for rows > 1: ``pos`` must be a
+    multiple of ``rows`` — the in-tile placement relies on it.  A concrete misaligned ``pos`` falls
     back to the exact dus (or raises under ``impl='pallas'``); a TRACED
     ``pos`` cannot be checked, so multi-row auto-dispatch additionally
     requires the caller's ``pos_aligned=True`` promise — without it the

@@ -50,8 +50,6 @@ from .hybrid import (  # noqa: F401
 )
 from .decode import (  # noqa: F401
     lm_generate,
-    lm_generate_beam,
-    make_lm_beam_generator,
     make_lm_generator,
 )
 from .transformer import (  # noqa: F401
@@ -132,8 +130,6 @@ __all__ = [
     "state_specs_like",
     "apply_rope",
     "lm_generate",
-    "lm_generate_beam",
-    "make_lm_beam_generator",
     "make_lm_generator",
     "init_tp_transformer_lm",
     "sp_block",

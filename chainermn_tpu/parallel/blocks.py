@@ -42,7 +42,9 @@ Parameter layout per ``arch`` value (all GLOBAL arrays):
   a_log (N, E)`` (the published ``A_log`` TRANSPOSED: channels on the
   lanes), ``d (E,), w_out (E, D)}``, no projection biases.
 * ``attn_kinds``: the attention kind of each LAYER where a model mixes
-  them (None: every layer is ``attn``), as ``layer_kinds`` is for the MLP.
+  them (None: every layer is ``attn``), as ``layer_kinds`` is for the MLP;
+  a kind is a key of :data:`LAYER_KINDS`, the ONE table of what a kind
+  keeps and what runs it in the training loss and in serving.
 * ``tied_head=False``: ``params['head'] (V, D)`` beside ``params['embed']``.
 
 What a layer's attention keeps is DECLARED here (:func:`cache_layout`) and
@@ -53,11 +55,12 @@ replicated.  RING, the last ``W`` rows of a sequence: an MHA/GQA layer with a
 window (``windows[layer] = W``: a token sees itself and the ``W - 1`` before
 it) keeps its ``(k, v)`` pair for those alone, position ``p`` at ring row ``p
 % W``, each key rotated at its absolute position before it is cached (so the
-order of the rows means nothing to the softmax).  STATE, one a sequence whatever its length: a KDA layer its
-``(H, d, d)`` float32 recurrent state and the last ``W - 1`` rows of its
-fused projection; a Mamba layer its ``(N, E)`` float32 state — stored ``(N,
-E / L, L)``, ``L`` lanes of channels, the layout its kernels take
-(``ops/ssm_step.py``) — and the last ``W - 1`` rows of ``u``.
+order of the rows means nothing to the softmax).  STATE, one a sequence
+whatever its length: a KDA layer its ``(H, d, d)`` float32 recurrent state
+and the last ``W - 1`` rows of its fused projection; a Mamba layer its ``(N,
+E)`` float32 state — stored ``(N, E / L, L)``, ``L`` lanes of channels, the
+layout its kernels take (``ops/ssm_step.py``) — and the last ``W - 1`` rows
+of ``u``.
 
 ``positions=False``: the model has NO positional signal — no position
 table and no rotation (its state layers carry the order); :func:`turn_qk`
@@ -67,8 +70,10 @@ then hands q and k back as they are.
 from __future__ import annotations
 
 import math
+from contextlib import nullcontext
 from dataclasses import dataclass
-from typing import Optional, Tuple
+from importlib import import_module
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -190,10 +195,6 @@ class Rotary:
     attention_factor: float = 1.0
 
 
-#: the attention kinds that keep a STATE a sequence and no row a token
-STATE_KINDS = ("kda", "mamba")
-
-
 @dataclass(frozen=True)
 class LMArch:
     """One LM's block vocabulary.  The defaults ARE the GPT-2-style block
@@ -233,11 +234,6 @@ class LMArch:
         if self.windows is None or self.attn_kind(layer) != "mha":
             return None
         return self.windows[layer]
-
-    @property
-    def has_state(self) -> bool:
-        """Some layer keeps a per-sequence state (and no row a token)."""
-        return any(k in STATE_KINDS for k in self.attn_kinds or (self.attn,))
 
     @property
     def has_ring(self) -> bool:
@@ -400,7 +396,7 @@ def turn_qk(arch: LMArch, layer: int, q, k, positions, rope: bool):
     table), else as they are — as they are, too, for a model that declares
     no positions at all (``arch.positions`` False).  The one rotation of the training loss
     (``transformer.tp_attention``) and of the serving prefill and tick
-    (``decode._decoder_core``)."""
+    (``decode._mha_block``)."""
     if not arch.positions:
         return q, k
     turn = arch.rotary[layer] if arch.rotary is not None else None
@@ -410,6 +406,13 @@ def turn_qk(arch: LMArch, layer: int, q, k, positions, rope: bool):
         from .transformer import apply_rope
         return apply_rope(q, positions), apply_rope(k, positions)
     return q, k
+
+
+def window_scope(arch: LMArch, layer: int):
+    """``block/attn/window`` around a windowed layer's core stage, in the
+    training loss and in the serving prefill and tick alike."""
+    return jax.named_scope("block/attn/window") if arch.window(layer) \
+        else nullcontext()
 
 
 def ring_rows(rows, s_real, window: int):
@@ -567,9 +570,91 @@ def mla_attend_absorbed(cfg: MLAConfig, q_nope, q_rope, cache, valid, a,
 # what a layer keeps per token, and how a model's parameters are sharded
 # --------------------------------------------------------------------------
 
+def _late(module: str, name: str):
+    """``parallel/<module>.py::<name>``, looked up where it is first called:
+    those modules import this one."""
+    def call(*args, **kwargs):
+        return getattr(import_module("." + module, __package__), name)(
+            *args, **kwargs)
+    return call
+
+
+def _kv_rows(arch: LMArch, layer: int, kv_dim: int, axis_name: str):
+    """MHA/GQA: a ``(k, v)`` pair of ROWS, under a window a pair of RINGS."""
+    buf = (kv_dim, P(None, None, axis_name))
+    if arch.window(layer):
+        buf += (arch.window(layer),)
+    return (buf, buf)
+
+
+def _kv_heads(a, head_dim: int) -> int:
+    """K/V heads of an MHA/GQA layer, from its weights ``a``."""
+    if "wkv" in a:
+        return a["wkv"].shape[1] // (2 * head_dim)
+    return a["wqkv"].shape[1] // (3 * head_dim)
+
+
+def _state_and_window(config: str):
+    """A state layer: its float32 STATE and its convolution window, by the
+    ``state_shapes`` of its numbers, ``LMArch.<config>``."""
+    def buffers(arch: LMArch, layer: int, kv_dim: int, axis_name: str):
+        state, window = getattr(arch, config).state_shapes
+        return ((state, jnp.float32, P()), (window, None, P()))
+    return buffers
+
+
+@dataclass(frozen=True)
+class LayerKind:
+    """One attention kind, whole: what a layer of it KEEPS and what RUNS it
+    on each path.  A new kind is one entry of :data:`LAYER_KINDS`, the module
+    that holds its arithmetic, and its numbers' field in :class:`LMArch`."""
+    #: ``(arch, layer, kv_dim, axis_name)`` -> the layer's buffer
+    #: declarations, in :func:`cache_layout`'s three forms
+    buffers: Callable
+    #: the serving block, prefill and tick alike: ``(core, x, blk, bufs,
+    #: layer, work) -> (x, bufs)`` over the layer's whole buffer tuple
+    serve: Callable
+    #: the training block's attention half, ``(arch, x, params, layer,
+    #: **tp_block's own) -> x``; None: ``tp_block`` refuses the kind by name
+    train: Optional[Callable] = None
+    #: its prefill leaves a gather nothing wants before the program's end:
+    #: ``decode.lm_prefill`` puts a barrier behind such a layer
+    deferred_gather: bool = False
+    #: ``(attn weights, head_dim)`` -> its K/V heads; None: no per-head K/V
+    kv_heads: Optional[Callable] = None
+
+
+#: every attention kind ``LMArch.attn`` / ``attn_kinds`` may name
+LAYER_KINDS = {
+    "mha": LayerKind(_kv_rows, _late("decode", "_mha_block"),
+                     _late("transformer", "_mha_forward"),
+                     kv_heads=_kv_heads),
+    # ONE buffer of latent rows, replicated
+    "mla": LayerKind(lambda arch, *_: ((arch.mla.latent_width, P()),),
+                     _late("decode", "_mla_block"),
+                     _late("transformer", "_mla_forward")),
+    "kda": LayerKind(_state_and_window("kda"), _late("decode", "_kda_block"),
+                     _late("transformer", "_kda_forward")),
+    "mamba": LayerKind(_state_and_window("mamba"),
+                       _late("decode", "_mamba_block"), deferred_gather=True),
+}
+
+
+def layer_kind(arch: LMArch, layer: int) -> LayerKind:
+    """Layer ``layer``'s entry of :data:`LAYER_KINDS` — the one lookup of
+    every path; a kind the table lacks is refused by name."""
+    kind = arch.attn_kind(layer)
+    if kind not in LAYER_KINDS:
+        raise NotImplementedError(
+            f"layer {layer} is described with attention kind {kind!r}; "
+            f"blocks.LAYER_KINDS holds {sorted(LAYER_KINDS)}")
+    return LAYER_KINDS[kind]
+
+
 def cache_layout(arch: LMArch, n_layers: int, kv_dim: int,
                  axis_name: str):
-    """Per layer, the buffers its attention keeps, a tuple of
+    """Per layer, the buffers its attention keeps (its kind's
+    ``LayerKind.buffers``), a tuple of
     declarations of three forms.  ROWS ``(columns, PartitionSpec)``: one row
     a token — the serving pool allocates ``(n_slots, max_total, columns)``
     in its own dtype.  STATE ``(shape, dtype, PartitionSpec)``: one a
@@ -578,18 +663,8 @@ def cache_layout(arch: LMArch, n_layers: int, kv_dim: int,
     window)``: the last ``window`` rows of a sequence, position ``p`` at
     row ``p % window`` — the pool allocates ``(n_slots, window,
     columns)``."""
-    def one(layer):
-        kind = arch.attn_kind(layer)
-        if kind == "mla":
-            return ((arch.mla.latent_width, P()),)
-        if kind in STATE_KINDS:
-            state, window = getattr(arch, kind).state_shapes
-            return ((state, jnp.float32, P()), (window, None, P()))
-        buf = (kv_dim, P(None, None, axis_name))
-        if arch.window(layer):
-            buf += (arch.window(layer),)
-        return (buf, buf)
-    return [one(i) for i in range(n_layers)]
+    return [layer_kind(arch, i).buffers(arch, i, kv_dim, axis_name)
+            for i in range(n_layers)]
 
 
 def is_state(buf) -> bool:

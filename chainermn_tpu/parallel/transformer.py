@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from .._compat import pcast_varying, typeof as _typeof
+from . import blocks as _blocks
 from .tensor_parallel import column_parallel_dense, row_parallel_dense, tp_mlp
 
 
@@ -132,32 +133,23 @@ def tp_attention(x, params, *, head_dim: int, axis_name: str,
     ``block/attn/core`` of docs/OBSERVABILITY.md.
     """
     from ..ops.flash_attention import resolve_attn_impl
-    from . import blocks as _blocks
 
     arch = _blocks.resolve(arch)
     b, s, d = x.shape
     attn_impl = resolve_attn_impl(attn_impl, s)
-    window = arch.window(layer)
     with jax.named_scope("proj"):
         q, k, v = _project_qkv(x, params, head_dim, axis_name, bias)
     h_local = q.shape[2]
 
-    def core(q, k, v):
+    with jax.named_scope("core"), _blocks.window_scope(arch, layer):
         # the layer's own rotation, else plain RoPE where the model has no
         # position table (positions are global token indices), else none
         at = jnp.arange(s) if positions is None and arch.rotary is not None \
             else positions
         q, k = _blocks.turn_qk(arch, layer, q, k, at, positions is not None)
-        return _attend_local_heads(q, k, v, causal=causal,
-                                   attn_impl=attn_impl, head_dim=head_dim,
-                                   window=window)
-
-    with jax.named_scope("core"):
-        if window:
-            with jax.named_scope("block/attn/window"):
-                ctx = core(q, k, v)
-        else:
-            ctx = core(q, k, v)
+        ctx = _attend_local_heads(q, k, v, causal=causal,
+                                  attn_impl=attn_impl, head_dim=head_dim,
+                                  window=arch.window(layer))
     with jax.named_scope("proj"):
         ctx = ctx.reshape(b, s, h_local * head_dim)         # (B, S, D/P)
         return row_parallel_dense(ctx, params["wo"],
@@ -218,12 +210,28 @@ def _tp_block_routed(x, params, *, head_dim: int, axis_name: str,
                      positions=None, arch=None, layer: int = 0):
     """:func:`tp_block` as ``(x, routing)``: ``routing`` is what
     ``blocks.ffn`` gives beside the result — None for a dense layer, the
-    routing-count vector and the chosen experts for an expert layer."""
-    from . import blocks as _blocks
-
+    routing-count vector and the chosen experts for an expert layer.  The
+    attention half is the layer's kind's ``blocks.LAYER_KINDS[kind].train``
+    (the ``_*_forward`` below); a kind that has none is refused by name."""
     arch = _blocks.resolve(arch)
-    kind = arch.attn_kind(layer)
-    if kind == "mha" and arch.attn_gate:
+    forward = _blocks.layer_kind(arch, layer).train
+    if forward is None:
+        raise NotImplementedError(
+            f"tp_block: layer {layer} is described with attention kind "
+            f"{arch.attn_kind(layer)!r}, which has no training forward "
+            f"(serving runs it: parallel/decode.py)")
+    x = forward(arch, x, params, layer, head_dim=head_dim,
+                axis_name=axis_name, causal=causal, attn_impl=attn_impl,
+                positions=positions)
+    with jax.named_scope("block/mlp"):
+        h = _blocks.norm(arch, x, params, "ln2")
+        y, routing = _blocks.ffn(arch, layer, h, params, axis_name)
+        return x + y, routing
+
+
+def _mha_forward(arch, x, params, layer: int, **kw):
+    """The MHA/GQA layer's half of the training block."""
+    if arch.attn_gate:
         # no silent ungated substitute: the loss path has no output gate
         # yet (a window and a per-layer rotation it runs)
         raise NotImplementedError(
@@ -231,50 +239,50 @@ def _tp_block_routed(x, params, *, head_dim: int, axis_name: str,
             f"{arch.window(layer)}, attn_gate={arch.attn_gate}, rotary="
             f"{arch.rotary is not None}; the training block runs no "
             f"output gate (serving does: parallel/decode.py)")
-    if kind == "mha":
-        # the attention half's own leaves inside ``block/attn``: ``proj``
-        # (norm, projections, residual) and ``core`` (``tp_attention``)
-        with jax.named_scope("block/attn"):
-            with jax.named_scope("proj"):
-                h = _blocks.norm(arch, x, params, "ln1")
-            y = tp_attention(h, params["attn"], head_dim=head_dim,
-                             axis_name=axis_name, causal=causal,
-                             attn_impl=attn_impl, positions=positions,
-                             bias=arch.attn_bias, arch=arch, layer=layer)
-            with jax.named_scope("proj"):
-                x = x + y
-    else:
-        with jax.named_scope("block/kda" if kind == "kda"
-                             else "block/attn"):
+    # the attention half's own leaves inside ``block/attn``: ``proj``
+    # (norm, projections, residual) and ``core`` (``tp_attention``)
+    with jax.named_scope("block/attn"):
+        with jax.named_scope("proj"):
             h = _blocks.norm(arch, x, params, "ln1")
-            if kind == "kda":
-                # a gated delta-rule layer from a zero state, in the chunked
-                # form (plain XLA: matmuls, a triangular solve and a scan, all
-                # of which differentiate)
-                from .kda import kda_layer
-                state, window = (jnp.zeros((x.shape[0],) + shape, dtype)
-                                 for shape, dtype in zip(
-                                     arch.kda.state_shapes,
-                                     (jnp.float32, x.dtype)))
-                x = x + kda_layer(arch.kda, h, params["attn"], state, window,
-                                  None, arch.norm_eps)[0]
-            else:
-                from ..ops.flash_attention import resolve_attn_impl
-                s = x.shape[1]
-                q_nope, q_rope, c_kv, k_rope = _blocks.mla_project(
-                    arch.mla, h, params["attn"],
-                    jnp.arange(s) if positions is None else positions,
-                    arch.norm_eps)
-                ctx = _blocks.mla_attend_prefill(
-                    arch.mla, q_nope, q_rope, c_kv, k_rope, params["attn"],
-                    resolve_attn_impl(attn_impl, s))
-                x = x + jnp.matmul(ctx, params["attn"]["wo"],
-                                   preferred_element_type=jnp.float32
-                                   ).astype(x.dtype)
-    with jax.named_scope("block/mlp"):
-        h = _blocks.norm(arch, x, params, "ln2")
-        y, routing = _blocks.ffn(arch, layer, h, params, axis_name)
-        return x + y, routing
+        y = tp_attention(h, params["attn"], bias=arch.attn_bias, arch=arch,
+                         layer=layer, **kw)
+        with jax.named_scope("proj"):
+            return x + y
+
+
+def _mla_forward(arch, x, params, layer: int, *, attn_impl: str, positions,
+                 **_):
+    """The latent-attention layer's half of the training block: the
+    prefill form over the whole sequence."""
+    from ..ops.flash_attention import resolve_attn_impl
+
+    with jax.named_scope("block/attn"):
+        h = _blocks.norm(arch, x, params, "ln1")
+        s = x.shape[1]
+        q_nope, q_rope, c_kv, k_rope = _blocks.mla_project(
+            arch.mla, h, params["attn"],
+            jnp.arange(s) if positions is None else positions,
+            arch.norm_eps)
+        ctx = _blocks.mla_attend_prefill(
+            arch.mla, q_nope, q_rope, c_kv, k_rope, params["attn"],
+            resolve_attn_impl(attn_impl, s))
+        return x + jnp.matmul(ctx, params["attn"]["wo"],
+                              preferred_element_type=jnp.float32
+                              ).astype(x.dtype)
+
+
+def _kda_forward(arch, x, params, layer: int, **_):
+    """The gated delta-rule layer's half of the training block: from a zero
+    state, in the chunked form (plain XLA: matmuls, a triangular solve and
+    a scan, all of which differentiate)."""
+    from .kda import kda_layer
+
+    with jax.named_scope("block/kda"):
+        h = _blocks.norm(arch, x, params, "ln1")
+        kept = zip(arch.kda.state_shapes, (jnp.float32, x.dtype))
+        state, window = (jnp.zeros((x.shape[0],) + s, d) for s, d in kept)
+        return x + kda_layer(arch.kda, h, params["attn"], state, window,
+                             None, arch.norm_eps)[0]
 
 
 def tp_attention_sp(x, params, *, head_dim: int, axis_name: str,
@@ -486,7 +494,6 @@ def tp_transformer_lm_loss(params, batch, *, head_dim: int, axis_name: str,
     P(), 'routes': P(data_axis)})`` the step hands the counts out summed
     over the data axis and the routes a sample.
     """
-    from . import blocks as _blocks
     from .tensor_parallel import vocab_parallel_embedding
 
     arch = _blocks.resolve(arch)

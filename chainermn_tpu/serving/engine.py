@@ -106,7 +106,7 @@ class DecodeEngine:
         self.head_dim = int(head_dim)
         self.pool = pool
         self.prefill_bucket = max(int(prefill_bucket), 1)
-        self.n_kv_heads = _kv_heads(params, head_dim)
+        self.n_kv_heads = _kv_heads(params, head_dim, arch)
         self.rope = "pos_embed" not in params
         self.max_positions = (None if self.rope
                               else int(params["pos_embed"].shape[0]))

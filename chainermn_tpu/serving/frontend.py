@@ -215,7 +215,7 @@ class ServingEngine:
         # layer's attention keeps per token: the pool allocates exactly
         # that declaration
         arch = _blocks.resolve(arch)
-        n_kv = _kv_heads(params, head_dim)
+        n_kv = _kv_heads(params, head_dim, arch)
         dtype = params["embed"].dtype
         # pool and engine share one mesh (created here when not given,
         # like make_lm_generator)

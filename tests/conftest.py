@@ -54,6 +54,56 @@ def devices():
     return devs
 
 
+@pytest.fixture(scope="session")
+def kept_as_declared():
+    """The contract the serving pool relies on, as a check: the buffers a
+    layer's serving entry (``blocks.LAYER_KINDS[kind].serve``) hands back
+    after a whole prompt (``path='prefill'``) or one token a slot
+    (``'tick'``) have the shapes and dtypes ``blocks.cache_layout`` and
+    ``blocks.buffer_shape`` declare for that layer — what the pool
+    allocated and writes them into."""
+    def check(params, arch, head_dim, layer, path, mesh, n=2, total=16):
+        import jax.numpy as jnp
+        from jax.sharding import PartitionSpec as P
+
+        from chainermn_tpu._compat import shard_map
+        from chainermn_tpu.parallel import blocks
+        from chainermn_tpu.parallel.decode import _Core, _Work, _kv_heads
+
+        arch = blocks.resolve(arch)
+        dtype = params["embed"].dtype
+        declared = blocks.cache_layout(
+            arch, len(params["blocks"]),
+            _kv_heads(params, head_dim, arch) * head_dim, "model")[layer]
+        want = [(blocks.buffer_shape(buf, n, total), jnp.dtype(
+            (buf[1] if blocks.is_state(buf) else None) or dtype))
+            for buf in declared]
+
+        def fn(params):
+            bufs = tuple(jnp.zeros(*sd) for sd in want)
+            s_q = total // 2 if path == "prefill" else 1
+            x = jnp.zeros((n, s_q, params["embed"].shape[1]), dtype)
+            if path == "prefill":
+                core = _Core(params, head_dim, "model", arch)
+                work = _Work(jnp.arange(s_q), 0)
+            else:
+                pos = jnp.arange(3, 3 + n)
+                core = _Core(params, head_dim, "model", arch,
+                             jnp.ones((n, 1), bool))
+                work = _Work(pos[:, None], pos, {})
+            y, new = blocks.layer_kind(arch, layer).serve(
+                core, x, params["blocks"][layer], bufs, layer, work)
+            assert y.shape == x.shape and y.dtype == x.dtype
+            return new
+
+        new = jax.eval_shape(shard_map(fn, mesh=mesh, in_specs=(P(),),
+                                       out_specs=P()), params)
+        assert isinstance(new, tuple)
+        assert [(b.shape, b.dtype) for b in new] == want
+
+    return check
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: subprocess-spawning tests (larger virtual meshes)")

@@ -31,8 +31,7 @@ B, S_P, NEW = 2, 6, 5
 
 def _forward_logits(p, tokens):
     """Reference forward: per-position logits ``(B, S, V)`` from the public
-    training-path pieces — the ONE oracle both the greedy and beam tests
-    score against."""
+    training-path pieces — the oracle the greedy tests score against."""
     from chainermn_tpu.parallel.tensor_parallel import (
         vocab_parallel_embedding)
     from chainermn_tpu.parallel.transformer import _layer_norm, tp_block
@@ -159,159 +158,50 @@ def test_sampling_noise_is_fresh_per_step(devices):
     assert len(set(out.tolist())) > 1, out
 
 
-class TestBeamSearch:
-    """Beam search over the KV cache: beam_size=1 must equal greedy
-    exactly; larger beams must never score below greedy under the
-    cumulative-log-prob objective; TP width must not change the tokens."""
-
-    def _make(self, pos_impl="learned", n_kv_heads=None, seed=5):
-        return init_tp_transformer_lm(
-            jax.random.PRNGKey(seed), VOCAB, D, HEADS, LAYERS, max_len=SEQ,
-            pos_impl=pos_impl, n_kv_heads=n_kv_heads)
-
-    def _seq_logprob(self, params, prompt, continuation, devices):
-        """Score a continuation by full re-forward (the objective beam
-        search maximizes)."""
-        mesh = mn.make_nd_mesh(("data", "model"), (1, 1), devices[:1])
-        full = np.concatenate([prompt, continuation], axis=1)
-
-        def lp(p, tokens):
-            logp = jax.nn.log_softmax(
-                _forward_logits(p, tokens[:, :-1]), axis=-1)
-            picked = jnp.take_along_axis(
-                logp, tokens[:, 1:, None], axis=-1)[..., 0]
-            # only the continuation positions count
-            return picked[:, -continuation.shape[1]:].sum(-1)
-
-        fn = shard_map(lp, mesh=mesh, in_specs=(P(), P()), out_specs=P())
-        return np.asarray(jax.jit(fn)(params, full))
-
-    @pytest.mark.parametrize("pos_impl", ["learned", "rope"])
-    def test_beam1_equals_greedy(self, devices, pos_impl):
-        from chainermn_tpu.parallel import make_lm_beam_generator
-
-        params = self._make(pos_impl=pos_impl)
-        prompt = np.random.RandomState(5).randint(
-            0, VOCAB, (B, S_P)).astype(np.int32)
-        mesh = mn.make_nd_mesh(("data", "model"), (1, 2), devices[:2])
-        greedy = make_lm_generator(mesh, "model", head_dim=HEAD_DIM,
-                                   max_new_tokens=NEW)
-        beam1 = make_lm_beam_generator(mesh, "model", head_dim=HEAD_DIM,
-                                       max_new_tokens=NEW, beam_size=1)
-        np.testing.assert_array_equal(np.asarray(beam1(params, prompt)),
-                                      np.asarray(greedy(params, prompt)))
-
-    @pytest.mark.parametrize("n_kv_heads", [None, 2])
-    def test_beam_never_scores_below_greedy(self, devices, n_kv_heads):
-        from chainermn_tpu.parallel import make_lm_beam_generator
-
-        params = self._make(seed=6, n_kv_heads=n_kv_heads)
-        prompt = np.random.RandomState(6).randint(
-            0, VOCAB, (B, S_P)).astype(np.int32)
-        mesh = mn.make_nd_mesh(("data", "model"), (1, 2), devices[:2])
-        greedy = np.asarray(make_lm_generator(
-            mesh, "model", head_dim=HEAD_DIM, max_new_tokens=NEW)(
-            params, prompt))
-        beam = np.asarray(make_lm_beam_generator(
-            mesh, "model", head_dim=HEAD_DIM, max_new_tokens=NEW,
-            beam_size=4)(params, prompt))
-        lp_g = self._seq_logprob(params, prompt, greedy, devices)
-        lp_b = self._seq_logprob(params, prompt, beam, devices)
-        assert (lp_b >= lp_g - 1e-4).all(), (lp_b, lp_g)
-
-    def test_tp_width_invariant(self, devices):
-        from chainermn_tpu.parallel import make_lm_beam_generator
-
-        params = self._make(seed=7)
-        prompt = np.random.RandomState(7).randint(
-            0, VOCAB, (B, S_P)).astype(np.int32)
-        outs = {}
-        for tp in (1, 2, 4):
-            mesh = mn.make_nd_mesh(("data", "model"), (1, tp), devices[:tp])
-            gen = make_lm_beam_generator(mesh, "model", head_dim=HEAD_DIM,
-                                         max_new_tokens=NEW, beam_size=3)
-            outs[tp] = np.asarray(gen(params, prompt))
-        np.testing.assert_array_equal(outs[1], outs[2])
-        np.testing.assert_array_equal(outs[1], outs[4])
-
-    @pytest.mark.parametrize("pos_impl,n_kv_heads",
-                             [("learned", None), ("rope", 2)])
-    def test_lazy_reorder_matches_physical(self, devices, pos_impl,
-                                           n_kv_heads):
-        # The ancestry-indexed beam (default) must pick the SAME tokens as
-        # the physical cache-gather oracle — the lazy path only changes
-        # where bytes move, not the math.
-        from chainermn_tpu.parallel import make_lm_beam_generator
-
-        params = self._make(pos_impl=pos_impl, n_kv_heads=n_kv_heads,
-                            seed=8)
-        prompt = np.random.RandomState(8).randint(
-            0, VOCAB, (B, S_P)).astype(np.int32)
-        mesh = mn.make_nd_mesh(("data", "model"), (1, 2), devices[:2])
-        kw = dict(head_dim=HEAD_DIM, max_new_tokens=NEW, beam_size=3)
-        lazy = make_lm_beam_generator(mesh, "model", lazy_reorder=True, **kw)
-        phys = make_lm_beam_generator(mesh, "model", lazy_reorder=False, **kw)
-        np.testing.assert_array_equal(np.asarray(lazy(params, prompt)),
-                                      np.asarray(phys(params, prompt)))
+def _in_model_mesh(fn, devices, n_args):
+    mesh = mn.make_nd_mesh(("data", "model"), (1, 1), devices[:1])
+    return shard_map(fn, mesh=mesh, in_specs=(P(),) * n_args, out_specs=P())
 
 
-def test_beam_kernel_slot_flattening_convention():
-    """The lazy-beam kernel path flattens the generated caches TIME-MAJOR
-    (row = t·k + slot) with a matching (b, s, t, l) mask and reads a
-    static live-prefix window [:t_hi·k] — this test pins that the
-    flattenings agree (a transposed reshape would silently attend the
-    wrong slots).  The kernel runs in interpret mode directly (no
-    shard_map: interpret-Pallas under manual axes trips VMA checks); the
-    full TPU path is token-parity-checked against the physical-gather
-    oracle on-chip."""
-    from chainermn_tpu.ops.decode_attention import (beam_attend_parts,
-                                                    merge_attend_parts)
+@pytest.mark.parametrize("path,kind", [
+    ("train", "mamba"), ("train", "nope"), ("prefill", "nope"),
+    ("tick", "nope")])
+def test_a_kind_the_path_does_not_run_is_refused_by_name(devices, path,
+                                                         kind):
+    """``blocks.LAYER_KINDS`` is the one door: a kind with no training
+    forward (a selective-scan layer) and a kind the table lacks raise an
+    error that names the kind and the layer — neither falls through to
+    another kind's code (latent attention in the loss, MHA in serving)."""
+    from chainermn_tpu.parallel.blocks import LMArch, MambaConfig
+    from chainermn_tpu.parallel.decode import lm_decode_tick, lm_prefill
+    from chainermn_tpu.parallel.transformer import tp_block
 
-    rs = np.random.RandomState(7)
-    b, k, t_max, h, hd, sp, t_hi = 2, 3, 16, 2, 16, 16, 8
-    d = h * hd
-    q = jnp.asarray(rs.randn(b * k, d), jnp.float32)
-    pk = jnp.asarray(rs.randn(b, sp, d), jnp.float32)
-    pv = jnp.asarray(rs.randn(b, sp, d), jnp.float32)
-    # time-major generated rows: (b, t_max·k, d), row = t·k + l
-    gk = jnp.asarray(rs.randn(b, t_max * k, d), jnp.float32)
-    gv = jnp.asarray(rs.randn(b, t_max * k, d), jnp.float32)
-    anc = jnp.asarray(rs.randint(0, k, (b, k, t_max)), jnp.int32)
-    valid = jnp.arange(t_max) < 5                          # all < t_hi
-    amask_tl = ((anc[:, :, None, :] == jnp.arange(k)[None, None, :, None])
-                & valid[None, None, None, :]).transpose(0, 1, 3, 2)
+    params = init_tp_transformer_lm(
+        jax.random.PRNGKey(9), VOCAB, D, HEADS, LAYERS, max_len=SEQ)
+    arch = LMArch(attn_kinds=("mha", kind), mamba=MambaConfig(2 * D))
+    kw = dict(head_dim=HEAD_DIM, axis_name="model", arch=arch)
+    tokens = jnp.zeros((B, S_P), jnp.int32)
 
-    # kernel path: EXACTLY the reshapes/window decode.py uses
-    gk_w, gv_w = gk[:, :t_hi * k], gv[:, :t_hi * k]
-    part_p = beam_attend_parts(q, pk, pv, beams=k, n_heads=h, head_dim=hd,
-                               block_s=8, interpret=True)
-    part_g = beam_attend_parts(
-        q, gk_w, gv_w,
-        amask_tl[:, :, :t_hi, :].reshape(b, k, t_hi * k).astype(jnp.int8),
-        beams=k, n_heads=h, head_dim=hd, block_s=8, interpret=True)
-    got = merge_attend_parts([part_p, part_g], n_heads=h, head_dim=hd,
-                             dtype=jnp.float32)
+    def run(p, t):
+        if path == "train":
+            return tp_block(jnp.zeros((B, S_P, D)), p["blocks"][1], layer=1,
+                            **kw)
+        if path == "prefill":
+            return lm_prefill(p, t, SEQ, **kw)[0]
+        caches = [(jnp.zeros((B, SEQ, D)),) * 2] * LAYERS
+        return lm_decode_tick(p, t[:, 0], caches, jnp.full((B,), S_P), **kw)[0]
 
-    # oracle: the einsum fallback formulas on the windowed 5-D views
-    q6 = q.reshape(b, k, h, 1, hd)
-    pk4 = pk.reshape(b, sp, h, hd)
-    pv4 = pv.reshape(b, sp, h, hd)
-    gk5 = gk_w.reshape(b, t_hi, k, h, hd)
-    gv5 = gv_w.reshape(b, t_hi, k, h, hd)
-    scale = hd ** 0.5
-    s_p = jnp.einsum("bshgd,bthd->bshgt", q6, pk4,
-                     preferred_element_type=jnp.float32) / scale
-    s_g = jnp.einsum("bshgd,btlhd->bshgtl", q6, gk5,
-                     preferred_element_type=jnp.float32) / scale
-    s_g = jnp.where(amask_tl[:, :, None, None, :t_hi, :], s_g, -1e30)
-    joint = jnp.concatenate([s_p, s_g.reshape(b, k, h, 1, t_hi * k)],
-                            axis=-1)
-    p = jax.nn.softmax(joint, axis=-1)
-    ctx = (jnp.einsum("bshgt,bthd->bshgd", p[..., :sp], pv4,
-                      preferred_element_type=jnp.float32)
-           + jnp.einsum("bshgtl,btlhd->bshgd",
-                        p[..., sp:].reshape(s_g.shape), gv5,
-                        preferred_element_type=jnp.float32))
-    want = ctx.reshape(b * k, d)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-4, atol=2e-5)
+    with pytest.raises(NotImplementedError,
+                       match=f"layer 1 is described with attention kind "
+                             f"{kind!r}"):
+        jax.eval_shape(_in_model_mesh(run, devices, 2), params, tokens)
+
+
+@pytest.mark.parametrize("path", ["prefill", "tick"])
+def test_the_mha_entry_keeps_what_it_declares(devices, kept_as_declared,
+                                              path):
+    params = init_tp_transformer_lm(
+        jax.random.PRNGKey(9), VOCAB, D, HEADS, LAYERS, max_len=SEQ,
+        n_kv_heads=2)
+    mesh = mn.make_nd_mesh(("model",), (1,), devices[:1])
+    kept_as_declared(params, None, HEAD_DIM, 1, path, mesh)

@@ -173,85 +173,38 @@ def test_block_must_divide():
                       interpret=True)
 
 
-class TestBeamAttendParts:
-    """The two-segment beam kernel + flash combine vs a joint-softmax
-    einsum oracle (interpret mode)."""
+@pytest.mark.parametrize("g", [2, 4])
+def test_query_groups_of_one_cache_row_match_the_grouped_einsum(g):
+    """What narrow-head GQA decode rides on, called as ``decode_attend_gqa``
+    calls it: ``g`` query rows a cache row through ``beam_attend_parts``,
+    each cache row up to its own position, normalized by the flash combine;
+    a row that is not busy gives the exact-zero part, which merges to 0."""
+    from chainermn_tpu.ops.decode_attention import (beam_attend_parts,
+                                                    merge_attend_parts)
 
-    def _oracle_joint(self, q, pk, pv, gk, gv, amask, b, beams, h, hd):
-        # joint softmax over prompt (all valid) + generated (amask)
-        d = h * hd
-        sp = pk.shape[1]
-        q4 = q.reshape(b, beams, h, hd)
-        pk4 = pk.reshape(b, sp, h, hd)
-        pv4 = pv.reshape(b, sp, h, hd)
-        gt = gk.shape[1]
-        gk4 = gk.reshape(b, gt, h, hd)
-        gv4 = gv.reshape(b, gt, h, hd)
-        s_p = jnp.einsum("bshd,bthd->bsht", q4, pk4,
-                         preferred_element_type=jnp.float32) / (hd ** 0.5)
-        s_g = jnp.einsum("bshd,bthd->bsht", q4, gk4,
-                         preferred_element_type=jnp.float32) / (hd ** 0.5)
-        s_g = jnp.where(amask[:, :, None, :] != 0, s_g, -1e30)
-        joint = jnp.concatenate([s_p, s_g], axis=-1)
-        p = jax.nn.softmax(joint, axis=-1)
-        ctx = (jnp.einsum("bsht,bthd->bshd", p[..., :sp], pv4,
-                          preferred_element_type=jnp.float32)
-               + jnp.einsum("bsht,bthd->bshd", p[..., sp:], gv4,
-                            preferred_element_type=jnp.float32))
-        return ctx.reshape(b * beams, d)
-
-    def test_two_segment_merge_matches_joint_softmax(self):
-        from chainermn_tpu.ops.decode_attention import (beam_attend_parts,
-                                                        merge_attend_parts)
-
-        rs = np.random.RandomState(0)
-        b, beams, h, hd, sp, gt = 2, 3, 4, 16, 32, 24
-        d = h * hd
-        q = jnp.asarray(rs.randn(b * beams, d), jnp.float32)
-        pk = jnp.asarray(rs.randn(b, sp, d), jnp.float32)
-        pv = jnp.asarray(rs.randn(b, sp, d), jnp.float32)
-        gk = jnp.asarray(rs.randn(b, gt, d), jnp.float32)
-        gv = jnp.asarray(rs.randn(b, gt, d), jnp.float32)
-        amask = jnp.asarray(rs.rand(b, beams, gt) > 0.4, jnp.int8)
-        # every row must have ≥1 valid generated position for the oracle
-        amask = amask.at[:, :, 0].set(1)
-
-        part_p = beam_attend_parts(q, pk, pv, beams=beams, n_heads=h,
-                                   head_dim=hd, block_s=16, interpret=True)
-        part_g = beam_attend_parts(q, gk, gv, amask, beams=beams, n_heads=h,
-                                   head_dim=hd, block_s=8, interpret=True)
-        got = merge_attend_parts([part_p, part_g], n_heads=h, head_dim=hd,
-                                 dtype=jnp.float32)
-        want = self._oracle_joint(q, pk, pv, gk, gv, amask, b, beams, h, hd)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=2e-4, atol=2e-5)
-
-    def test_fully_masked_rows_are_prompt_only(self):
-        from chainermn_tpu.ops.decode_attention import (beam_attend_parts,
-                                                        merge_attend_parts)
-
-        rs = np.random.RandomState(1)
-        b, beams, h, hd, sp, gt = 1, 2, 2, 8, 16, 8
-        d = h * hd
-        q = jnp.asarray(rs.randn(b * beams, d), jnp.float32)
-        pk = jnp.asarray(rs.randn(b, sp, d), jnp.float32)
-        pv = jnp.asarray(rs.randn(b, sp, d), jnp.float32)
-        gk = jnp.asarray(rs.randn(b, gt, d), jnp.float32)
-        gv = jnp.asarray(rs.randn(b, gt, d), jnp.float32)
-        amask = jnp.zeros((b, beams, gt), jnp.int8)  # tick 1: nothing yet
-
-        part_p = beam_attend_parts(q, pk, pv, beams=beams, n_heads=h,
-                                   head_dim=hd, block_s=8, interpret=True)
-        part_g = beam_attend_parts(q, gk, gv, amask, beams=beams, n_heads=h,
-                                   head_dim=hd, block_s=8, interpret=True)
-        got = merge_attend_parts([part_p, part_g], n_heads=h, head_dim=hd,
-                                 dtype=jnp.float32)
-        acc, m, l = part_p
-        segt = (jnp.arange(h)[:, None]
-                == jnp.arange(d)[None, :] // hd).astype(jnp.float32)
-        want = acc / (l @ segt)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=1e-5, atol=1e-6)
+    rs = np.random.RandomState(43)
+    b, s, hkv, hd = 3, 64, 2, 16
+    q = jnp.asarray(rs.randn(b, hkv * g * hd), jnp.float32)
+    kc = jnp.asarray(rs.randn(b, s, hkv * hd), jnp.float32)
+    vc = jnp.asarray(rs.randn(b, s, hkv * hd), jnp.float32)
+    pos = jnp.asarray([40, 0, 63], jnp.int32)
+    busy = jnp.asarray([True, True, False])
+    # head-major (Hkv, g, hd) -> group-major rows (B·g, Hkv·hd)
+    q_g = q.reshape(b, hkv, g, hd).transpose(0, 2, 1, 3).reshape(
+        b * g, hkv * hd)
+    part = beam_attend_parts(q_g, kc, vc, pos, busy, beams=g, n_heads=hkv,
+                             head_dim=hd, block_s=16, interpret=True)
+    assert [p.shape for p in part] == [(b * g, hkv * hd), (b * g, hkv),
+                                       (b * g, hkv)]
+    got = merge_attend_parts([part], n_heads=hkv, head_dim=hd,
+                             dtype=jnp.float32)
+    got = np.asarray(got.reshape(b, g, hkv, hd).transpose(0, 2, 1, 3)
+                     .reshape(b, -1))
+    want = np.asarray(oracle_gqa(q, kc, vc, pos, hkv * g, hkv, hd))
+    np.testing.assert_allclose(got[:2], want[:2], rtol=2e-4, atol=2e-5)
+    np.testing.assert_array_equal(got[2], 0.0)
+    for p in part:
+        np.testing.assert_array_equal(np.asarray(p)[2 * g:], 0.0)
 
 
 class TestGQADecode:

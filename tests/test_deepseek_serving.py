@@ -581,6 +581,13 @@ def test_moe_gmm_multiplies_each_tile_by_its_expert(m_k_n_tm):
 # the pool follows the layer's declaration
 # --------------------------------------------------------------------------
 
+@pytest.mark.parametrize("path", ["prefill", "tick"])
+def test_the_mla_entry_keeps_what_it_declares(
+        params, mesh, kept_as_declared, path):
+    """Layer 0, a latent-attention layer: ONE buffer of latent rows."""
+    kept_as_declared(params, arch_of(CFG), HEAD_DIM, 0, path, mesh)
+
+
 def test_cache_layout_declares_what_each_attention_keeps():
     latent = blocks.cache_layout(arch_of(CFG), 3, 0, "model")
     assert [tuple(w for w, _ in layer) for layer in latent] == [(128,)] * 3
@@ -738,7 +745,7 @@ def test_gpt2_description_is_the_default():
     assert blocks.n_count_entries(a) == 0
     # one attention kind for the whole model, no state: nothing of the
     # per-layer kinds (PR 31) shows in the defaults
-    assert (a.attn_kinds, a.kda, a.has_state) == (None, None, False)
+    assert (a.attn_kinds, a.kda, a.mamba) == (None, None, None)
     # nor of a window, a rotation of the layer's own, a gate on the context
     # or absent biases (PR 33)
     assert (a.windows, a.rotary, a.attn_gate, a.attn_bias, a.has_ring) == (
@@ -788,7 +795,6 @@ def test_deepseek_description_is_bit_identical_with_a_kind_per_layer(
 
     whole = arch_of(CFG)
     each = dataclasses.replace(whole, attn_kinds=("mla",) * 3)
-    assert not whole.has_state and not each.has_state
     # a window names MHA/GQA layers: a latent layer has none, whatever the
     # tuple says, and keeps its one rows buffer
     windowed = dataclasses.replace(whole, windows=(8,) * 3)

@@ -358,6 +358,13 @@ def test_a_model_without_scan_layers_counts_no_scan_tokens(devices):
 # the pool: two kinds of buffer
 # --------------------------------------------------------------------------
 
+@pytest.mark.parametrize("path", ["prefill", "tick"])
+def test_the_mamba_entry_keeps_what_it_declares(
+        params, mesh, kept_as_declared, path):
+    """Layer 0, a selective-scan layer: ``(state, window)``, the state float32."""
+    kept_as_declared(params, ARCH, HEAD_DIM, 0, path, mesh)
+
+
 def test_cache_layout_declares_rows_or_state_per_layer():
     layout = blocks.cache_layout(ARCH, 6, HEAD_DIM, "model")
     kinds = ["state" if blocks.is_state(bufs[0]) else "rows"
@@ -367,7 +374,7 @@ def test_cache_layout_declares_rows_or_state_per_layer():
     assert layout[1] == (pair, pair)
     assert layout[0] == (((4, 1, 128), jnp.float32, P()),
                          ((3, 128), None, P()))
-    assert ARCH.has_state and not ARCH.has_ring
+    assert not ARCH.has_ring
     assert (ARCH.windows, ARCH.rotary) == (None, None)
 
 

@@ -473,6 +473,13 @@ def test_a_tick_moves_exactly_the_windows_the_engine_counts(params, mesh):
 # the pool: two kinds of buffer, two invariants
 # --------------------------------------------------------------------------
 
+@pytest.mark.parametrize("path", ["prefill", "tick"])
+def test_the_kda_entry_keeps_what_it_declares(
+        params, mesh, kept_as_declared, path):
+    """Layer 0, a delta-rule layer: ``(state, window)``, the state float32."""
+    kept_as_declared(params, ARCH, HEAD_DIM, 0, path, mesh)
+
+
 def test_cache_layout_declares_rows_or_state_per_layer():
     layout = blocks.cache_layout(ARCH, 8, 0, "model")
     kinds = ["state" if blocks.is_state(bufs[0]) else "rows"
@@ -485,7 +492,6 @@ def test_cache_layout_declares_rows_or_state_per_layer():
     state, window = KDAConfig(32, 128).state_shapes
     assert int(np.prod(state)) * 4 == 2_097_152
     assert int(np.prod(window)) * 2 == 73_728
-    assert ARCH.has_state and not blocks.DEFAULT_ARCH.has_state
     # a state is no ring, and no layer of this model has a window (PR 33)
     assert not ARCH.has_ring and not any(
         blocks.is_ring(buf) for bufs in layout for buf in bufs)

@@ -504,19 +504,27 @@ def test_zero_gate_weights_halve_the_context(params, mesh):
                          ("w_gate", "w_up", "w_down")})
 
         def fn(x, b):
-            from chainermn_tpu.parallel.decode import _decoder_core
-            _, attn_block, _, _ = _decoder_core(
+            from chainermn_tpu.parallel.decode import _Core, _Work
+            core = _Core(
                 {"embed": jnp.zeros((8, 64)), "blocks": [b]}, HEAD_DIM,
                 "model", dataclasses.replace(
                     arch, layer_kinds=("dense",), windows=(None,),
                     rotary=(arch.rotary[1],)))
             k = jnp.zeros((2, 6, KV_DIM))
-            return attn_block(x, b, k, k, jnp.arange(6), 0, 0, 0)[0] - x
+            return blocks.layer_kind(core.arch, 0).serve(
+                core, x, b, (k, k), 0, _Work(jnp.arange(6), 0))[0] - x
         return _in_mesh(fn, mesh, 2)(x, b)
 
     np.testing.assert_allclose(np.asarray(attn_out(ARCH, zero)),
                                0.5 * np.asarray(attn_out(ungated, blk)),
                                rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("path", ["prefill", "tick"])
+def test_the_windowed_mha_entry_keeps_what_it_declares(
+        params, mesh, kept_as_declared, path):
+    """Layer 1, a sliding layer: a ``(k, v)`` pair of RINGS of ``W`` rows."""
+    kept_as_declared(params, ARCH, HEAD_DIM, 1, path, mesh)
 
 
 def test_the_description_declares_rows_and_rings(params):
@@ -529,7 +537,7 @@ def test_the_description_declares_rows_and_rings(params):
     assert not any(blocks.is_state(buf) for bufs in layout for buf in bufs)
     assert blocks.buffer_shape(layout[1][0], 3, 48) == (3, W, KV_DIM)
     assert blocks.buffer_shape(layout[0][0], 3, 48) == (3, 48, KV_DIM)
-    assert ARCH.has_ring and not ARCH.has_state
+    assert ARCH.has_ring
     assert [ARCH.window(i) for i in range(3)] == [None, W, W]
     # no biases anywhere, a gate a head, the head count from the weights
     a = params["blocks"][1]["attn"]
@@ -553,14 +561,15 @@ def test_the_training_block_refuses_what_it_cannot_run(params, mesh):
 
 
 def test_a_chunk_behind_a_ring_is_refused(params, mesh):
-    from chainermn_tpu.parallel.decode import _decoder_core
+    from chainermn_tpu.parallel.decode import _Core, _Work
 
     def fn(x, b):
-        _, attn_block, _, _ = _decoder_core(
+        core = _Core(
             {"embed": jnp.zeros((8, 64)), "blocks": [b]}, HEAD_DIM, "model",
             ARCH)
         k = jnp.zeros((1, W, KV_DIM))
-        return attn_block(x, b, k, k, jnp.arange(4, 7), 4, 4, 1)[0]
+        return blocks.layer_kind(ARCH, 1).serve(
+            core, x, b, (k, k), 1, _Work(jnp.arange(4, 7), 4))[0]
 
     with pytest.raises(NotImplementedError, match="ring of 8 rows"):
         _in_mesh(fn, mesh, 2)(jnp.zeros((1, 3, 64)), params["blocks"][1])
