@@ -26,7 +26,7 @@ def serve_window(server, reqs, seconds, ctx, drain_factor=2.0,
     clock = time.perf_counter
     nxt, n = 0, len(reqs)
     busy_samples, backlog_mid, backlog_end = [], None, None
-    traced_from = None
+    traced_from, slice_ends = None, []
     start = clock()
     while True:
         now = clock() - start
@@ -52,9 +52,11 @@ def serve_window(server, reqs, seconds, ctx, drain_factor=2.0,
             # holds the loop for some seconds (14 s once), which would spoil
             # every request due after it
             traced_from = now
-            tracer.start()
+            slice_ends.append(server.metrics())    # read outside the slice:
+            tracer.start()                         # no tick runs between
         if tracer.active and clock() - tracer.started >= trace_seconds:
             tracer.stop()
+            slice_ends.append(server.metrics())
         if server.idle():
             due = recs[nxt]["due_s"] if nxt < n else seconds
             time.sleep(min(max(due - now, 0.0), 0.001))
@@ -67,10 +69,20 @@ def serve_window(server, reqs, seconds, ctx, drain_factor=2.0,
                 backlog_mid = server.backlog()
     if tracer.active:
         tracer.stop()
+        slice_ends.append(server.metrics())
     return {"recs": recs, "busy_samples": busy_samples,
+            "slice_metrics": _growth(*slice_ends) if slice_ends else None,
             "backlog_mid": backlog_mid, "backlog_end": backlog_end,
             "seconds": seconds, "traced_from": traced_from,
             "wall_s": clock() - start}
+
+
+def _growth(first: dict, last: dict) -> dict:
+    """The engine's counters over the traced slice: each number of
+    ``metrics()`` at the slice's end less what it read at its start (a
+    gauge reads 0 here: take it from the run's ``engine_metrics``)."""
+    return {k: v - first[k] for k, v in last.items()
+            if k in first and isinstance(v, (int, float))}
 
 
 def summarize(out, min_tail=10):
@@ -100,11 +112,18 @@ def summarize(out, min_tail=10):
         except stats.TooFewSamples:
             return None
 
+    # a gap that holds a prefill (the engine admits between two ticks) is
+    # several cadences long: where few gaps do, the p95 is the cadence's own
+    # tail; where a tenth or more do, it lies inside tick + prefill
+    gap_p50 = stats.median(gaps) if gaps else None
+    long_gaps = (100.0 * float((np.asarray(gaps) > 2 * gap_p50).mean())
+                 if gaps else None)
     return {
         "attempted": len(recs), "failed": failed,
+        "gaps_over_2x_p50_share": long_gaps,
         "serve_tokens_per_s": tokens_in / seconds,
         "ttft_p50_ms": stats.median(ttft), "ttft_p95_ms": tail(ttft, 95),
-        "gap_p50_ms": stats.median(gaps) if gaps else None,
+        "gap_p50_ms": gap_p50,
         "gap_p95_ms": tail(gaps, 95),
         "gen_late_p95_ms": tail(late, 95),
         "gen_late_max_ms": max(late) if late else None,
@@ -128,7 +147,8 @@ def run(ctx):
             f"{s['failed']} failed, drained after {out['wall_s']:.2f} s; "
             f"TTFT p50 {s['ttft_p50_ms']:.1f} ms p95 {s['ttft_p95_ms']} "
             f"(n={s['n_ttft']}); gap p50 {s['gap_p50_ms']} p95 "
-            f"{s['gap_p95_ms']} (n={s['n_gaps']}); generator late p95 "
+            f"{s['gap_p95_ms']} (n={s['n_gaps']}, over 2 x p50: "
+            f"{s['gaps_over_2x_p50_share']} %); generator late p95 "
             f"{s['gen_late_p95_ms']} max {s['gen_late_max_ms']} ms")
     checks = [{"name": "tails_have_samples", "limit": 0.0,
                "value": float(s["ttft_p95_ms"] is None
@@ -143,9 +163,10 @@ def run(ctx):
     checks += fam.serve_compare(ctx, handles)
     ctx.reference_s += time.perf_counter() - t0
     return {
-        "checks": checks, "attempted": len(out["recs"]),
-        "failed": sum(1 for r in out["recs"] if r["handle"] is None
-                      or r["handle"].status != "done"),
+        # as the window's numbers: in a traced run the requests due before
+        # the slice (the profiler's stop holds the loop for seconds, and what
+        # was due meanwhile is never offered: no failure of the system's)
+        "checks": checks, "attempted": s["attempted"], "failed": s["failed"],
         "end_to_end": {k: s[k] for k in (
             "serve_tokens_per_s", "ttft_p95_ms", "gap_p95_ms")
             if s[k] is not None},
@@ -156,7 +177,8 @@ def run(ctx):
             for r in out["recs"] if out["traced_from"] is None
             or r["due_s"] < out["traced_from"]],
             "busy_samples": out["busy_samples"], "n_slots": tr["engine"]["n_slots"],
-            "engine_metrics": engine_metrics, "window_s": ctx.seconds,
+            "engine_metrics": engine_metrics,
+            "slice_metrics": out["slice_metrics"], "window_s": ctx.seconds,
             "min_tail": tr["min_tail_samples"]},
     }
 

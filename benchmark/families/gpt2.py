@@ -171,6 +171,11 @@ class Server:
     """The program's ``ServingEngine`` at the configuration's sizes, with
     bfloat16 weights made on the device from the seed."""
 
+    #: this family's own servers alone warm a prefix hit: the families that
+    #: build on this class do not run this ``__init__`` and keep their
+    #: set-up as it was measured
+    warm_prefix_hit = False
+
     def __init__(self, ctx):
         import chainermn_tpu as mn
         from chainermn_tpu.serving import ServingEngine
@@ -183,16 +188,31 @@ class Server:
         self.eng = ServingEngine(
             params, head_dim=cfg["n_embd"] // cfg["n_head"], mesh=mesh, **eng)
         del params
+        self.warm_prefix_hit = True
         self.info = {"engine": eng, "prefix_cache": True}
 
     def warm(self, prompt_lens):
         """One request per prefill program the traffic uses, a few ticks
-        each: exactly the cell's shapes, no others."""
+        each: exactly the cell's shapes, no others.  Where
+        ``warm_prefix_hit``, the first prompt once more: it finds its own
+        rows in the prefix cache, so the engine builds
+        ``serving_prefix_copy`` here and not at the window's first hit
+        (``--seed 0`` draws its first prompt from this warm-up's own stream,
+        hit the cache and compiled inside the window: PERF.md, Findings
+        PR 46)."""
         rng = np.random.default_rng(0)
-        handles = [self.eng.submit(rng.integers(
-            0, self.vocab, n, dtype=np.int32), 4) for n in prompt_lens]
-        while not self.idle():
-            self.eng.step()
+        prompts = [rng.integers(0, self.vocab, n, dtype=np.int32)
+                   for n in prompt_lens]
+
+        def serve(batch):
+            handles = [self.eng.submit(p, 4) for p in batch]
+            while not self.idle():
+                self.eng.step()
+            return handles
+
+        handles = serve(prompts)
+        if self.warm_prefix_hit:
+            handles += serve(prompts[:1])
         bad = [h.status for h in handles if h.status != "done"]
         if bad:
             raise RuntimeError(f"warm-up requests did not finish: {bad}")

@@ -11,7 +11,11 @@ not edited; a kernel's measured time a tick is read as there
 Needed, not executed: the rows and the ring rows of the slots that are BUSY
 (the engine's ``serving/tick_row_bytes`` and ``serving/tick_ring_bytes``:
 host arithmetic on each slot's position; the kernel also reads the free and
-cached slots' up to their held positions), read once a tick; of a prefill
+cached slots' up to their held positions), read once a tick, over the ticks
+of the TRACED SLICE, whose kernel time they are divided by (the counters as
+the driver read them at the slice's two ends, ``run["slice_metrics"]``: a
+slice with fewer busy slots than the run's mean read 100.8 % against the
+whole run's ticks, ledger, PR 43); of a prefill
 the (query, key) pairs of REAL positions inside the band
 (``serving/prefill_band_pairs``), not the padded rows' and not the masked
 halves of the sub-blocks the band's edges cross.  A program without the
@@ -22,8 +26,7 @@ counters or the kernels, or a configuration without such layers, gives
 import bisect
 
 from benchmark.harness import program_trace
-from benchmark.harness.serve_kernel_costs import (_EVENTS, _per_tick,
-                                                 seconds_per_tick)
+from benchmark.harness.serve_kernel_costs import _EVENTS, seconds_per_tick
 from benchmark.harness.trace_reduce import KERNEL_TAG, read_events
 
 PREFILL = "serving_prefill_"
@@ -80,16 +83,23 @@ def band_pairs(s: int, window: int) -> int:
     return head * (head + 1) // 2 + (s - head) * window
 
 
+def _per_slice_tick(run: dict, key: str):
+    """An engine counter's growth over the traced slice, a tick of it."""
+    m = run.get("slice_metrics") or {}
+    ticks = m.get("serving/tick_calls")
+    return m[key] / ticks if ticks and key in m else None
+
+
 def decode_attn_gqa(config: dict, run: dict):
     """The tick's attention over both kinds of cache: the busy slots' rows
     (all full layers) and ring rows (all sliding layers) read once, bf16 K
     and V; every query head of a layer meets each of its live rows twice
     (the score, the weighted sum): ``4 x head_dim`` operations a (head,
-    row)."""
+    row).  A tick of the traced slice's own."""
     layers = _layers(config)
-    rows = _per_tick(run, "serving/tick_row_bytes")
-    ring = _per_tick(run, "serving/tick_ring_bytes")
-    ring_rows = _per_tick(run, "serving/tick_ring_rows_live")
+    rows = _per_slice_tick(run, "serving/tick_row_bytes")
+    ring = _per_slice_tick(run, "serving/tick_ring_bytes")
+    ring_rows = _per_slice_tick(run, "serving/tick_ring_rows_live")
     per_token = run.get("engine_metrics", {}).get(
         "serving/cache_bytes_per_token")
     if layers is None or rows is None or ring is None or not per_token:
@@ -115,9 +125,14 @@ def gqa_decode_roofline_share(trace: dict, run: dict):
         return None
     by_flops = cost["flops"] / peaks["bf16_flops"]
     by_bytes = cost["bytes"] / peaks["hbm_bytes_per_s"]
+    m = run.get("engine_metrics", {})
+    whole = (m.get("serving/tick_row_bytes", 0.0)
+             + m.get("serving/tick_ring_bytes", 0.0)) / max(
+                 m.get("serving/tick_calls", 0.0), 1.0)
     print(f"decode_attn (rows + rings): needs {by_flops * 1e3:.4f} ms by "
-          f"FLOPs, {by_bytes * 1e3:.4f} ms by bytes a tick; measured "
-          f"{seconds * 1e3:.4f} ms", flush=True)
+          f"FLOPs, {by_bytes * 1e3:.4f} ms by bytes a tick of the slice "
+          f"({cost['bytes']:.0f} B; the whole run's mean tick {whole:.0f} "
+          f"B); measured {seconds * 1e3:.4f} ms", flush=True)
     return 100.0 * max(by_flops, by_bytes) / seconds
 
 

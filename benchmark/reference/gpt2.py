@@ -56,9 +56,12 @@ LIMITS = {
     # serving (benchmark/drivers/serve_open_loop.py): the widest gap by which
     # a served token's float32 logit lies below the float32 best, over 8
     # served requests (750-1250 tokens).  Sound runs 0 .. 1.2e-2 over 14
-    # seeds (nine of them exactly 0: a widest gap swings by its nature); fp8
-    # control 5.4e-2 .. 1.5e-1 over 6.
-    "served_logit_gap": 4e-2,
+    # seeds at 3.6 requests/s (nine of them exactly 0: a widest gap swings by
+    # its nature) and 0 .. 8.0e-3 over 17 more at 33.6/s; fp8 control 5.4e-2
+    # .. 1.5e-1 over 6 seeds, and 3.96e-2, 1.27e-1, 1.31e-1 over three more
+    # (PR 46: the first PASSED the 4e-2 this limit was, so it came down;
+    # 2.5 x over the sound largest, 1.3 x under the control's smallest).
+    "served_logit_gap": 3e-2,
 }
 
 

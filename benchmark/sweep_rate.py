@@ -6,15 +6,18 @@
 
 One process, one compile: the cell's server is built and warmed once, then
 each rate gets one window of the cell's own traffic (same lengths, same
-generator) and a drain.  The knee is the highest rate at which the backlog
-(requests due and not finished) at the end of the window is no larger than
-at its middle; the cell runs at four fifths of it.  The generator's
-lateness is printed at each rate, so a starved generator is not read as a
-fast server.  The found rate is written into ``benchmark/cells/<cell>.json``
-by hand, with this script's output beside it."""
+generator) and a drain.  A rate is sustained where no request failed and the
+backlog (requests due and not finished) at the end of the window has not
+grown past its middle's by more than the schedule's own fluctuation
+(:func:`sustained`); the knee is the highest such rate and the cell runs at
+four fifths of it.  The generator's lateness is printed at each rate, so a
+starved generator is not read as a fast server.  The found rate is written
+into ``benchmark/cells/<cell>.json`` by hand, with this script's output
+beside it."""
 
 import argparse
 import json
+import math
 import os
 import sys
 
@@ -23,6 +26,36 @@ sys.path.insert(0, ROOT)
 
 from benchmark.harness import device, session             # noqa: E402
 from benchmark.harness import traffic as _traffic          # noqa: E402
+
+
+def sustained(failed: int, backlog_mid, backlog_end) -> bool:
+    """No request failed, and the backlog at the window's end is within two
+    standard deviations of a Poisson count above its middle's.  Two instants
+    of a steady queue with ``n`` requests in flight differ by about
+    ``sqrt(n)``: the plain ``end <= mid`` read that fluctuation as growth
+    (28 -> 37 and 50 -> 61 at rates that drained within seconds, PERF.md,
+    Findings PR 40), where a queue that grows reads 251 -> 357."""
+    if failed or backlog_mid is None or backlog_end is None:
+        return False
+    return backlog_end <= backlog_mid + 2.0 * math.sqrt(backlog_mid + 1)
+
+
+def deepest_queue(recs) -> int:
+    """The most requests that waited in the engine's queue at once, from the
+    handles' own ``submitted`` and ``prefill_start`` stamps (a request that
+    was never admitted waits to the end)."""
+    events = []
+    for r in recs:
+        stamps = r["handle"].timestamps if r["handle"] is not None else {}
+        if "submitted" in stamps:
+            events.append((stamps["submitted"], 1))
+            if "prefill_start" in stamps:
+                events.append((stamps["prefill_start"], -1))
+    depth = deepest = 0
+    for _, step in sorted(events):
+        depth += step
+        deepest = max(deepest, depth)
+    return deepest
 
 
 def main(argv=None, *, _allow_cpu=False, _sizes=None) -> int:
@@ -54,17 +87,17 @@ def main(argv=None, *, _allow_cpu=False, _sizes=None) -> int:
         row = {"rate_per_s": rate, "backlog_mid": out["backlog_mid"],
                "backlog_end": out["backlog_end"],
                "drained_after_s": out["wall_s"],
+               "queue_depth_max": deepest_queue(out["recs"]),
                "busy_slots_mean": (sum(out["busy_samples"])
                                    / max(len(out["busy_samples"]), 1)), **s}
-        row["sustained"] = (row["failed"] == 0 and out["backlog_end"]
-                            is not None and out["backlog_mid"] is not None
-                            and out["backlog_end"] <= out["backlog_mid"])
+        row["sustained"] = sustained(row["failed"], out["backlog_mid"],
+                                     out["backlog_end"])
         rows.append(row)
         ctx.say("sweep " + json.dumps(row))
-    sustained = [r["rate_per_s"] for r in rows if r["sustained"]]
+    held = [r["rate_per_s"] for r in rows if r["sustained"]]
     result = {"workload": args.workload, "seed": args.seed,
               "seconds": args.seconds, "device": device.stamp(devices),
-              "rows": rows, "knee_rate_per_s": max(sustained, default=None)}
+              "rows": rows, "knee_rate_per_s": max(held, default=None)}
     with open(args.out, "w") as f:
         json.dump(result, f, indent=1)
     print(json.dumps({"knee_rate_per_s": result["knee_rate_per_s"]}))

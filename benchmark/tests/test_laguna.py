@@ -192,9 +192,17 @@ def test_window_kernel_costs_from_counters():
 
     cfg = loader.cell(loader.manifest(), CELL)["config"]
     rows = 12 * 1400
+    # the traced slice's own ticks are counted (its counters' growth); the
+    # whole run's, here three times as busy, are not
     run_ = {"engine_metrics": {
-        "serving/tick_calls": 10.0,
+        "serving/tick_calls": 1000.0,
         "serving/cache_bytes_per_token": 10 * 4096.0,
+        "serving/tick_row_bytes": 3000 * rows * 10 * 4096.0,
+        "serving/tick_ring_rows_live": 3000 * 12 * 512 * 30.0,
+        "serving/tick_ring_bytes": 3000 * 12 * 512 * 30 * 4096.0},
+        "slice_metrics": {
+        "serving/tick_calls": 10.0,
+        "serving/cache_bytes_per_token": 0.0,
         "serving/tick_row_bytes": 10 * rows * 10 * 4096.0,
         "serving/tick_ring_rows_live": 10 * 12 * 512 * 30.0,
         "serving/tick_ring_bytes": 10 * 12 * 512 * 30 * 4096.0}}
@@ -208,6 +216,9 @@ def test_window_kernel_costs_from_counters():
     assert costs.band_pairs(3072, 512) == 512 * 513 // 2 + 2560 * 512
     assert costs.band_pairs(100, 512) == 100 * 101 // 2
     assert costs.decode_attn_gqa(cfg, {"engine_metrics": {}}) is None
+    # an untraced run has no slice: nothing to read, not the run's mean
+    assert costs.decode_attn_gqa(
+        cfg, dict(run_, slice_metrics=None)) is None
     # a configuration without such layers (every accepted cell's)
     other = loader.cell(loader.manifest(), GPT2_CELL)["config"]
     assert costs.decode_attn_gqa(other, run_) is None

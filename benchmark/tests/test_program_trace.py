@@ -148,7 +148,7 @@ def test_every_new_reader_is_silent_on_a_program_without_names(
     no counter — each reader returns ``None`` and raises nothing."""
     run = {"steps_in_slice": 4, "values": {}, "engine_metrics": {},
            "peaks": device.PEAKS["TPU v5 lite"]}
-    assert len(NEW) == 15
+    assert len(NEW) >= 15        # ISSUE 25's fifteen and every kernel reader since
     assert pt.load(recorded) is not None          # the trace itself is read
     assert loader.module("layer_metrics", name).read(
         recorded, [], run) is None

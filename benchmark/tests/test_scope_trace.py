@@ -21,8 +21,11 @@ SCOPES = os.path.join(HERE, "recorded_v5e_scopes.xplane.pb")
 BARE = os.path.join(HERE, "recorded_v5e_tiny.xplane.pb")
 SERVE = "deepseek-v3-ep16-serve-steady"
 TRAIN = "gpt2-medium-train-s1024"
+#: the split's readers that the two recorded programs' cells report (a
+#: bucket of another family's longer table, ``tick_ms.ssm_*``, is silent here)
 NEW = [m for m in loader.manifest()["per_layer"]
-       if m["name"].startswith(("tick_ms.", "step_ms."))]
+       if m["name"].startswith(("tick_ms.", "step_ms."))
+       and {SERVE, TRAIN} & set(m["workloads"])]
 SERVED = {"embed_head", "attn_proj", "attn_core", "cache_write", "ffn_dense",
           "moe_route", "moe_experts"}
 
