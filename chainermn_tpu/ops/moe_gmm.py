@@ -28,6 +28,10 @@ kernel ``moe_gmm_dw`` (same grid, a float32 accumulator an expert's run of
 tiles, the result aliased to a zero buffer so that an expert with no rows
 reads nothing and gets zeros).  Dead tiles contribute to neither.
 
+The layer's first two products read the same rows and meet in ``silu(gate) *
+up``: ``moe_gmm_glu`` is both and the activation in one kernel on this
+grid, with a transposed kernel of its own (``moe_gmm_glu_dx``); below.
+
 CPU and other backends take the same kernels in interpret mode in tests;
 the layer's XLA fallback (a dense loop over the held experts) is in
 ``parallel/moe.py``.
@@ -43,9 +47,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .._compat import pcast_varying, shape_dtype_struct as _sds
+from ..observability import trace as _trace
 from .flash_attention import _inherit_vma as _vma
 
-__all__ = ["moe_gmm", "moe_gmm_rows", "moe_gmm_sum", "pick_tn"]
+__all__ = ["moe_gmm", "moe_gmm_glu", "moe_gmm_rows", "moe_gmm_sum", "pick_tn"]
 
 _LANES = 128
 #: a weight block (K x tn, double-buffered by Pallas) stays under this
@@ -253,6 +258,245 @@ def moe_gmm(x, w, tile_expert, n_valid, *, tm: int, tn: int = 0,
 
 
 # --------------------------------------------------------------------------
+# the layer's first two products and their activation: ONE grouped kernel
+# --------------------------------------------------------------------------
+#
+# ``hidden = silu(xs @ w_gate[e]) * (xs @ w_up[e])`` a row tile: the gate and
+# the up product read the SAME rows, so a live tile is multiplied by both
+# weight blocks of its expert in one grid step and the activation is the
+# step's epilogue — no element-wise pass over all ``M`` rows beside the
+# kernels, which knows nothing of the work list.  Transposed
+# (``moe_gmm_glu_dx``) a live tile forms both products' cotangents from
+# ``d_hidden`` and writes ONE ``dxs`` tile: no second ``(M, D)`` cotangent,
+# no sum of two.  The roundings are the three separate products' and
+# autodiff's: each product rounded to the rows' dtype, the activation and
+# its derivative in float32, each ``dX`` product rounded and the two added
+# in the rows' dtype.
+
+def _glu_tile(rows, wg_ref, wu_ref, dtype):
+    """``(g, u, silu(g) * u)`` of one row tile by both weight blocks of its
+    expert: each product accumulated in float32 and rounded to ``dtype``,
+    the activation in float32 of the ROUNDED products, rounded again — the
+    epilogue of ``moe_gmm_glu`` and of a tick's ``moe_gmm_rows``."""
+    f32 = jnp.float32
+    product = lambda w_ref: jax.lax.dot_general(
+        rows, w_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=f32).astype(dtype)
+    g = product(wg_ref)
+    act = jax.nn.silu(g.astype(f32))
+    u = product(wu_ref)
+    return g, u, (act * u.astype(f32)).astype(dtype)
+
+
+def _glu_kernel(tile_expert_ref, n_valid_ref, x_ref, wg_ref, wu_ref, o_ref,
+                *gu_refs):
+    """``gu_refs``: none, or the two rounded products' own results (the
+    backward's residuals: the forward under differentiation)."""
+    del tile_expert_ref                       # read by the index maps only
+
+    @pl.when(pl.program_id(1) < n_valid_ref[0])
+    def _tile():
+        g, u, o_ref[...] = _glu_tile(x_ref[...], wg_ref, wu_ref, o_ref.dtype)
+        for ref, product in zip(gu_refs, (g, u)):
+            ref[...] = product
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("tm", "interpret", "keep_products"))
+def _glu_product(x, w_gate, w_up, tile_expert, n_valid, *, tm: int,
+                 interpret: bool, keep_products: bool):
+    """``hidden (M, F)`` — with ``keep_products`` ``(hidden, g, u)`` — on
+    :func:`_product`'s grid: ``(F tiles, row tiles)``, rows fastest, two
+    ``(D, tn)`` weight blocks a step."""
+    m, d = x.shape
+    e, d2, f = w_gate.shape
+    assert d == d2 and w_up.shape == w_gate.shape and m % tm == 0, (
+        x.shape, w_gate.shape, w_up.shape, tm)
+    tn = pick_tn(2 * d, f, w_gate.dtype.itemsize)
+    last = _last_live
+    weight = pl.BlockSpec((None, d, tn),
+                          lambda j, i, te, nv: (te[last(i, nv)], 0, j))
+    tile = pl.BlockSpec((tm, tn), lambda j, i, te, nv: (last(i, nv), j))
+    n_out = 3 if keep_products else 1
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(f // tn, m // tm),
+        in_specs=[pl.BlockSpec((tm, d), lambda j, i, te, nv: (last(i, nv), 0)),
+                  weight, weight],
+        out_specs=[tile] * n_out)
+    out = _sds((m, f), x.dtype, vma=_vma(x, w_gate, w_up))
+    # both weight blocks and every row tile twice (Pallas's double
+    # buffers), the two float32 products and the activation once
+    block_bytes = (4 * d * tn * w_gate.dtype.itemsize
+                   + 2 * tm * (d + n_out * tn) * x.dtype.itemsize
+                   + 12 * tm * tn)
+    got = pl.pallas_call(
+        _glu_kernel,
+        grid_spec=grid_spec,
+        out_shape=[out] * n_out,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=max(block_bytes + (8 << 20), 32 << 20)),
+        name="moe_gmm_glu",
+        interpret=interpret,
+    )(tile_expert, n_valid, x, w_gate, w_up)
+    return tuple(got) if keep_products else got[0]
+
+
+def _glu_dx_kernel(tile_expert_ref, n_valid_ref, dh_ref, g_ref, u_ref, wg_ref,
+                   wu_ref, dx_ref, dg_ref, du_ref):
+    """A live tile's ``dg = dh * u * silu'(g)`` and ``du = dh * silu(g)`` in
+    float32 (``silu``'s own VJP: autodiff's operations in autodiff's order),
+    rounded as the products were, and its block of ``dxs = dg @ w_gate[e]^T
+    + du @ w_up[e]^T`` — contracted over ``F`` against the weights as they
+    lie, each product rounded to the rows' dtype, the two added in it (on
+    float32 registers, rounded once more: what an addition in the rows'
+    dtype is)."""
+    del tile_expert_ref
+    f32 = jnp.float32
+
+    @pl.when(pl.program_id(1) < n_valid_ref[0])
+    def _tile():
+        dh = dh_ref[...].astype(f32)
+        g = g_ref[...].astype(f32)
+        # (differentiated at a zero offset from ``g``: inside ``shard_map`` a
+        # value read from a kernel's reference keeps the operand's varying
+        # type and what the kernel computes carries none, which ``jax.vjp``
+        # refuses of a cotangent — a float32 ``g`` would be such a value)
+        act, silu_vjp = jax.vjp(lambda zero: jax.nn.silu(g + zero),
+                                jnp.zeros(g.shape, f32))
+        du = (dh * act).astype(du_ref.dtype)
+        dg, = silu_vjp(dh * u_ref[...].astype(f32))
+        dg = dg.astype(dg_ref.dtype)
+        dg_ref[...], du_ref[...] = dg, du
+        product = lambda dy, w_ref: jax.lax.dot_general(
+            dy, w_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=f32).astype(dx_ref.dtype).astype(f32)
+        dx_ref[...] = (product(dg, wg_ref)
+                       + product(du, wu_ref)).astype(dx_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("tm", "interpret"))
+def _glu_dx(dh, g, u, w_gate, w_up, tile_expert, n_valid, *, tm: int,
+            interpret: bool):
+    """``(dxs (M, D), dg (M, F), du (M, F))`` (kernel ``moe_gmm_glu_dx``).
+    Grid ``(D tiles, row tiles)``, rows fastest, two ``(td, F)`` weight
+    blocks a step — the whole contraction in one block, so no accumulator;
+    where ``D`` is tiled a row tile's ``dg``, ``du`` are formed (and written,
+    the same) once a ``D`` tile; where it is not (the widths that train),
+    they are written over ``g`` and ``u``, which nothing reads after them.
+    Tiles at or past ``n_valid`` hold every index and are neither read nor
+    written."""
+    m, f = dh.shape
+    e, d, f2 = w_gate.shape
+    assert f == f2 and m % tm == 0, (dh.shape, w_gate.shape, tm)
+    td = pick_tn(2 * f, d, w_gate.dtype.itemsize)
+    last = _last_live
+    rows = pl.BlockSpec((tm, f), lambda j, i, te, nv: (last(i, nv), 0))
+    weight = pl.BlockSpec((None, td, f),
+                          lambda j, i, te, nv: (te[last(i, nv)], j, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(d // td, m // tm),
+        in_specs=[rows, rows, rows, weight, weight],
+        out_specs=[pl.BlockSpec((tm, td),
+                                lambda j, i, te, nv: (last(i, nv), j)),
+                   rows, rows])
+    vma = _vma(dh, g, u, w_gate, w_up)
+    # both weight blocks and every row tile twice, the float32 forms of the
+    # five ``(tm, F)`` tiles and of the two ``(tm, td)`` products once
+    block_bytes = (4 * td * f * w_gate.dtype.itemsize
+                   + 2 * tm * (5 * f + td) * dh.dtype.itemsize
+                   + 4 * tm * (6 * f + 2 * td))
+    return pl.pallas_call(
+        _glu_dx_kernel,
+        grid_spec=grid_spec,
+        out_shape=[_sds((m, d), dh.dtype, vma=vma),
+                   _sds((m, f), dh.dtype, vma=vma),
+                   _sds((m, f), dh.dtype, vma=vma)],
+        # operands count the two prefetched scalars
+        input_output_aliases={3: 1, 4: 2} if td == d else {},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=max(block_bytes + (8 << 20), 32 << 20)),
+        name="moe_gmm_glu_dx",
+        interpret=interpret,
+    )(tile_expert, n_valid, dh, g, u, w_gate, w_up)
+
+
+def _count_fused(which: str) -> None:
+    """Book one traced staged layer's fused products with the tracer (as
+    ``flash/score_blocks_*``: at trace time, off when disabled)."""
+    _trace.get_tracer().add_counter(f"moe/glu_products_fused{which}", 1)
+
+
+# The custom VJP's three rules are traced once a CALL (a layer) and book the
+# counter there; the kernels under them are ``jit`` functions, traced and
+# lowered once a shape whatever the layers.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _glu(x, w_gate, w_up, tile_expert, n_valid, tm, interpret):
+    _count_fused("")
+    return _glu_product(x, w_gate, w_up, tile_expert, n_valid, tm=tm,
+                        interpret=interpret, keep_products=False)
+
+
+def _glu_fwd(x, w_gate, w_up, tile_expert, n_valid, tm, interpret):
+    _count_fused("")
+    hidden, g, u = _glu_product(x, w_gate, w_up, tile_expert, n_valid, tm=tm,
+                                interpret=interpret, keep_products=True)
+    return hidden, (x, w_gate, w_up, g, u, tile_expert, n_valid)
+
+
+_weight_grad_jit = jax.jit(_weight_grad, static_argnums=(4, 5, 6, 7))
+
+
+def _glu_bwd(tm, interpret, res, dh):
+    """What the forward, ``d_hidden``'s producer and the residuals left
+    unspecified in the dead tiles reaches nothing, and ``dxs``, ``dg``,
+    ``du`` are UNSPECIFIED there in their turn: the weight gradients skip
+    them, and a rows' gather reads back held choices' rows only."""
+    x, w_gate, w_up, g, u, tile_expert, n_valid = res
+    _count_fused("_vjp")
+    dx, dg, du = _glu_dx(dh, g, u, w_gate, w_up, tile_expert, n_valid, tm=tm,
+                         interpret=interpret)
+    dw = lambda dy, w: _weight_grad_jit(x, dy, tile_expert, n_valid,
+                                        w.shape[0], tm, interpret, w.dtype)
+    return dx, dw(dg, w_gate), dw(du, w_up), None, None
+
+
+_glu.defvjp(_glu_fwd, _glu_bwd)
+
+
+def moe_gmm_glu(x, w_gate, w_up, tile_expert, n_valid, *, tm: int,
+                interpret: bool = False):
+    """``hidden (M, F)`` in ``x.dtype`` of the grouped rows ``x (M, D)``:
+    ``silu(x_tile @ w_gate[e]) * (x_tile @ w_up[e])`` a live row tile, each
+    product rounded to ``x.dtype`` first and the activation taken in
+    float32 — bit for bit ``moe_gmm``'s two products and the element-wise
+    line between them (kernel ``moe_gmm_glu``; its ``N`` tile follows the
+    two weight blocks' bytes).  ``tile_expert``, ``n_valid``, ``tm`` as
+    :func:`moe_gmm`'s; the rows of tiles at or past ``n_valid`` are
+    UNSPECIFIED.
+
+    Differentiable in ``x`` and both weights (``jax.custom_vjp``): the
+    forward under differentiation also keeps the two rounded products (live
+    tiles only); the kernel ``moe_gmm_glu_dx`` turns ``d_hidden`` into the
+    two products' cotangents and ONE ``dx = dg @ w_gate[e]^T + du @
+    w_up[e]^T`` (each product rounded, the two added in ``x.dtype``, as
+    two ``moe_gmm`` transposes and autodiff's sum would), and ``moe_gmm_dw``
+    takes the weight gradients.  Unlike ``moe_gmm``'s, this ``dx`` is
+    UNSPECIFIED in the dead tiles too — never written, never to be read: a
+    padding row INSIDE a live tile is the caller's (its ``d_hidden`` row
+    has to be zero).  Books ``moe/glu_products_fused`` (and ``…_vjp`` in the
+    backward) with the tracer, once a traced call — which is why the
+    ``jit`` that wraps its siblings whole wraps the kernels alone here: its
+    cache would make that once a shape."""
+    # (the weights' promotion outside the custom VJP: ``moe_gmm`` says why)
+    x, w_gate, w_up = _vary_alike(x, w_gate, w_up)
+    return _glu(x, w_gate, w_up, tile_expert.astype(jnp.int32),
+                jnp.asarray(n_valid, jnp.int32).reshape(1), tm, interpret)
+
+
+# --------------------------------------------------------------------------
 # the layer at a tick's sizes: rows taken and summed INSIDE the products
 # --------------------------------------------------------------------------
 #
@@ -303,12 +547,8 @@ def _rows_kernel(tile_expert_ref, n_valid_ref, row_token_ref, x_ref, wg_ref,
             return carry
 
         jax.lax.fori_loop(0, tm, take, 0)
-        rows = rows_ref[...].astype(x_ref.dtype)
-        product = lambda w_ref: jax.lax.dot_general(
-            rows, w_ref[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=f32).astype(o_ref.dtype).astype(f32)
-        o_ref[...] = (jax.nn.silu(product(wg_ref))
-                      * product(wu_ref)).astype(o_ref.dtype)
+        o_ref[...] = _glu_tile(rows_ref[...].astype(x_ref.dtype), wg_ref,
+                               wu_ref, o_ref.dtype)[2]
 
 
 def _sum_kernel(tile_expert_ref, n_valid_ref, row_token_ref, row_gate_ref,

@@ -26,8 +26,9 @@ group-limited top-k, renormalised and scaled gates
 (:func:`sigmoid_group_route`) or a softmax over all experts with plain top-k
 (:func:`softmax_topk_route`), a layer that is TOLD which experts it holds
 (``held = (first, n)``), routes over all of them and computes its own
-experts' part, with one grouped product per projection over the experts
-that have tokens (``ops/moe_gmm.py``; at a served tick's few rows two
+experts' part, with grouped products over the experts that have tokens
+(``ops/moe_gmm.py``: the gate and up projections and their activation one
+kernel, the down projection another; at a served tick's few rows two
 kernels that take the rows and give the gated sum themselves) beside a
 shared expert every token takes (where the model has one).  It runs
 without an exchange: a chip's result is its PART of the layer (the exchange
@@ -431,16 +432,18 @@ def _staged_product(x, w_gate, w_up, w_down, gates, route: _Route, tm: int,
                     chunk: Optional[int], interpret: bool):
     """The held experts' gated sum through ``(M, D)`` buffers: the rows
     gathered whole (a padding row reads token 0), three grouped products
-    over the live tiles, a gather-combine (no scatter)."""
-    from ..ops.moe_gmm import moe_gmm
+    over the live tiles — the first two and their activation ONE kernel,
+    forward and transposed (``moe_gmm_glu``) — a gather-combine (no
+    scatter)."""
+    from ..ops.moe_gmm import moe_gmm, moe_gmm_glu
 
     xs = _gather_rows(x, route.row_token, route.dest, route.is_held,
                       None if chunk is None else "clip")        # (M, D)
-    gmm = lambda lhs, w: moe_gmm(lhs, w, route.tile_expert, route.n_valid,
-                                 tm=tm, interpret=interpret)
-    hidden = (jax.nn.silu(gmm(xs, w_gate).astype(jnp.float32))
-              * gmm(xs, w_up).astype(jnp.float32)).astype(x.dtype)
-    rows = gmm(hidden, w_down)                                  # (M, D)
+    tiles = dict(tm=tm, interpret=interpret)
+    hidden = moe_gmm_glu(xs, w_gate, w_up, route.tile_expert, route.n_valid,
+                         **tiles)                               # (M, F)
+    rows = moe_gmm(hidden, w_down, route.tile_expert, route.n_valid,
+                   **tiles)                                     # (M, D)
     return _combine(rows, gates, route.dest, route.is_held, route.row_token,
                     route.n_live, chunk)
 
@@ -497,15 +500,15 @@ def _held_experts_product(x, p, idx, gates, first, n_held: int,
     fast memory: two kernels take the rows and give the sum themselves
     (:func:`_resident_product`).  A prefill's and a training step's go
     through ``(M, D)`` buffers (:func:`_staged_product`): a gather, three
-    grouped products, a gather-combine (no scatter).  The backward is the
-    staged path's at every size: its row-side pass (each row's token's
-    cotangent gathered, scaled for the products and dotted with the row for
-    the gates) follows the same work list — at a training step's sizes (the
-    static ``n_assign = T·k``: :func:`_row_chunk`) it walks the live chunks
-    and leaves the dead rows zero, at a tick's and a prefill's it would
-    walk the buffer whole; the staged rows are gathered whole
-    (:func:`_gather_rows` says why).  Fallback: a dense loop over the held
-    experts (tiny CPU sizes)."""
+    grouped products in two kernels, a gather-combine (no scatter).  The
+    backward is the staged path's at every size: its row-side pass (each
+    row's token's cotangent gathered, scaled for the products and dotted
+    with the row for the gates) follows the same work list — at a training
+    step's sizes (the static ``n_assign = T·k``: :func:`_row_chunk`) it
+    walks the live chunks and leaves the dead rows zero, at a tick's and a
+    prefill's it would walk the buffer whole; the staged rows are gathered
+    whole (:func:`_gather_rows` says why).  Fallback: a dense loop over the
+    held experts (tiny CPU sizes)."""
     t, d = x.shape
     k = idx.shape[1]
     # the index work between the routing and the product: which choices
@@ -593,9 +596,10 @@ def moe_dropless(x, params, cfg, *, live=None,
 
     Differentiable in ``x`` and every parameter: through the gates (into
     the router), the rows' gather, the three grouped products
-    (``moe_gmm``'s own VJP) and the gather-combine — a tick's resident
-    forward (``moe_gmm_rows`` + ``moe_gmm_sum``) through that same staged
-    path; the choice of experts and the counts carry no gradient.
+    (``moe_gmm_glu``'s and ``moe_gmm``'s own VJPs) and the gather-combine —
+    a tick's resident forward (``moe_gmm_rows`` + ``moe_gmm_sum``) through
+    that same staged path; the choice of experts and the counts carry no
+    gradient.
     """
     from .blocks import swiglu
 

@@ -355,6 +355,154 @@ def test_moe_gmm_dead_rows_may_hold_anything():
     np.testing.assert_allclose(np.asarray(dw), 8.0)
 
 
+# --------------------------------------------------------------------------
+# the layer's first two products and their activation are ONE grouped kernel
+# with its own backward (ISSUE 47): ``moe_gmm_glu`` / ``moe_gmm_glu_dx``
+# against two ``moe_gmm`` calls, the element-wise line between them and
+# autodiff's sum of their two ``dX``, interpret mode, bit for bit
+# --------------------------------------------------------------------------
+
+def _three_products(xs, w_gate, w_up, tile_expert, n_valid, tm):
+    """``parallel/moe.py::_staged_product``'s ``hidden`` as it was."""
+    from chainermn_tpu.ops.moe_gmm import moe_gmm
+
+    gmm = lambda w: moe_gmm(xs, w, tile_expert, n_valid, tm=tm,
+                            interpret=True)
+    return (jax.nn.silu(gmm(w_gate).astype(jnp.float32))
+            * gmm(w_up).astype(jnp.float32)).astype(xs.dtype)
+
+
+#: ``counts`` a held expert, dead tiles past ``n_valid``, ``tm``, ``D``,
+#: ``F``, the bytes a weight block may take (None: the module's)
+GLU_CASES = {
+    "held25": ((16, 8, 24, 16), 24, 8, 64, 40, None),     # 8 of 32 tiles live
+    "held57": ((40, 24, 32, 32), 12, 8, 64, 40, None),    # 16 of 28
+    "empty-expert": ((13, 0, 5, 8), 1, 8, 16, 24, None),
+    "no-rows": ((0, 0, 0, 0), 3, 8, 16, 24, None),        # n_valid == 0
+    # two (256, 128) blocks a step: F in two tiles forward, D in two back
+    "n-tiled": ((40, 16, 1, 24), 3, 16, 256, 256, 2 * 256 * 128 * 2),
+}
+
+
+def _glu_inputs(case, monkeypatch):
+    from chainermn_tpu.ops import moe_gmm as mod
+
+    counts, dead, tm, d, f, block_bytes = GLU_CASES[case]
+    if block_bytes:
+        monkeypatch.setattr(mod, "_W_BLOCK_BYTES", block_bytes)
+        assert mod.pick_tn(2 * d, f) == f // 2
+        assert mod.pick_tn(2 * f, d) == d // 2
+    tile_expert, n_valid, m, _ = _grouped(counts, tm, dead)
+    ks = jax.random.split(jax.random.PRNGKey(sorted(GLU_CASES).index(case)),
+                          4)
+    xs = jax.random.normal(ks[0], (m, d), jnp.bfloat16)
+    w_gate, w_up = (jax.random.normal(k, (len(counts), d, f), jnp.bfloat16)
+                    * d ** -0.5 for k in ks[1:3])
+    # ``d_hidden`` as the down product hands it over: zero in the dead tiles
+    live = jnp.asarray(np.arange(m) // tm < n_valid)[:, None]
+    ct = jnp.where(live, jax.random.normal(ks[3], (m, f), jnp.bfloat16), 0)
+    return (xs, w_gate, w_up), ct, jnp.asarray(tile_expert), n_valid, tm, live
+
+
+def _as_numbers(a):
+    return np.asarray(a.astype(jnp.float32))
+
+
+@pytest.mark.parametrize("case", sorted(GLU_CASES))
+def test_moe_gmm_glu_is_the_three_products_bit_for_bit(case, monkeypatch):
+    """``hidden`` over the live tiles and the VJP — ``dxs`` over the live
+    tiles, ``dW_gate``, ``dW_up`` whole — are the separate products' bits."""
+    from chainermn_tpu.ops.moe_gmm import moe_gmm_glu
+
+    args, ct, tile_expert, n_valid, tm, live = _glu_inputs(case, monkeypatch)
+    want, vjp0 = jax.vjp(lambda *a: _three_products(
+        *a, tile_expert, n_valid, tm), *args)
+    got, vjp = jax.vjp(lambda *a: moe_gmm_glu(
+        *a, tile_expert, n_valid, tm=tm, interpret=True), *args)
+    assert got.dtype == want.dtype == jnp.bfloat16
+    n = n_valid * tm
+    np.testing.assert_array_equal(_as_numbers(got)[:n], _as_numbers(want)[:n])
+    assert bool(n) == bool(_as_numbers(want)[:n].any())
+    (dx, dwg, dwu), (dx0, dwg0, dwu0) = vjp(ct), vjp0(ct)
+    np.testing.assert_array_equal(_as_numbers(dx)[:n], _as_numbers(dx0)[:n])
+    for a, b in ((dwg, dwg0), (dwu, dwu0)):
+        assert a.dtype == b.dtype and np.isfinite(_as_numbers(a)).all()
+        np.testing.assert_array_equal(_as_numbers(a), _as_numbers(b))
+    assert bool(n) == bool(_as_numbers(dwg).any())
+    # an expert with no rows gets zeros
+    for e, c in enumerate(GLU_CASES[case][0]):
+        if c == 0:
+            assert not _as_numbers(dwu)[e].any()
+
+
+@pytest.mark.parametrize("case", ["held25", "held57", "no-rows", "n-tiled"])
+def test_moe_gmm_glu_never_reads_a_dead_tile(case, monkeypatch):
+    """NaN in every dead tile of the rows and of ``d_hidden`` (the forward
+    left its own dead tiles unwritten: the interpreter fills them with NaN)
+    reaches neither the live tiles of ``hidden`` and ``dxs`` nor a weight
+    gradient."""
+    from chainermn_tpu.ops.moe_gmm import moe_gmm_glu
+
+    (xs, w_gate, w_up), ct, tile_expert, n_valid, tm, live = _glu_inputs(
+        case, monkeypatch)
+    n = n_valid * tm
+    assert n < xs.shape[0]
+    glu = lambda xs: jax.vjp(lambda *a: moe_gmm_glu(
+        *a, tile_expert, n_valid, tm=tm, interpret=True), xs, w_gate, w_up)
+    clean, vjp0 = glu(xs)
+    dirty, vjp = glu(jnp.where(live, xs, jnp.nan))
+    np.testing.assert_array_equal(_as_numbers(dirty)[:n],
+                                  _as_numbers(clean)[:n])
+    assert np.isfinite(_as_numbers(dirty)[:n]).all()
+    got, want = vjp(jnp.where(live, ct, jnp.nan)), vjp0(ct)
+    np.testing.assert_array_equal(_as_numbers(got[0])[:n],
+                                  _as_numbers(want[0])[:n])
+    for a, b in zip(got[1:], want[1:]):
+        assert np.isfinite(_as_numbers(a)).all()
+        np.testing.assert_array_equal(_as_numbers(a), _as_numbers(b))
+
+
+def test_moe_gmm_glu_counts_a_forward_and_a_vjp_a_staged_layer(monkeypatch):
+    """``moe/glu_products_fused`` (+ ``_vjp``): one a traced forward of a
+    staged layer and one a traced backward of it, whatever the layers'
+    shapes share — a tick's resident layer books none forward and
+    differentiates through the staged path, whose forward its backward
+    runs."""
+    from chainermn_tpu import observability as obs
+    from chainermn_tpu.observability import trace
+    from chainermn_tpu.parallel import moe
+    from chainermn_tpu.parallel.moe import moe_dropless
+
+    cfg, p, x = _tick_layer("softmax", seed=3)
+    # (a fresh function a reading: tracing is cached by the function)
+    layers = lambda: lambda x: moe_dropless(moe_dropless(
+        x, p, cfg, interpret=True)[0].astype(x.dtype), p, cfg,
+        interpret=True)[0].sum()
+    fused = lambda: {k: v for k, v in trace.get_tracer().counters().items()
+                     if k.startswith("moe/glu_products_fused")}
+    was = trace.get_tracer().enabled
+    obs.enable()
+    try:
+        trace.get_tracer().reset()
+        jax.make_jaxpr(layers())(x)               # resident: two other kernels
+        assert fused() == {}
+        both = {"moe/glu_products_fused": 2.0,
+                "moe/glu_products_fused_vjp": 2.0}
+        jax.make_jaxpr(jax.grad(layers()))(x)     # ... staged in the backward
+        assert fused() == both
+        monkeypatch.setattr(moe, "_rows_resident", lambda t, d, a: False)
+        trace.get_tracer().reset()
+        jax.make_jaxpr(layers())(x)
+        assert fused() == {"moe/glu_products_fused": 2.0}
+        trace.get_tracer().reset()
+        jax.make_jaxpr(jax.grad(layers()))(x)
+        assert fused() == both
+    finally:
+        trace.get_tracer().reset()
+        if not was:
+            obs.disable()
+
+
 @pytest.mark.parametrize("router", ["softmax", "sigmoid_group"])
 def test_moe_dropless_kernel_path_gradients_are_the_dense_loops(router):
     """Through the gates, the rows' gather, the three grouped products and

@@ -182,6 +182,44 @@ def test_rows_past_the_live_chunks_are_zeros(case, chunked, monkeypatch):
     assert not np.asarray(got[edge:].astype(jnp.float32)).any()
 
 
+@jax.custom_vjp
+def _nan_where(a, dead):
+    """``a`` with NaN in the rows ``dead`` — and its cotangent likewise."""
+    return jnp.where(dead, jnp.nan, a)
+
+
+_nan_where.defvjp(lambda a, dead: (_nan_where(a, dead), dead),
+                  lambda dead, ct: (jnp.where(dead, jnp.nan, ct), None))
+
+
+@pytest.mark.parametrize("chunked", [3], indirect=True, ids=["chunk3"])
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_nan_in_the_dead_tiles_reaches_nothing(case, chunked, monkeypatch):
+    """The fused gate/up product (ISSUE 47) leaves the dead tiles of
+    ``hidden``, ``dxs``, ``dg`` and ``du`` unwritten and masks nothing:
+    with NaN in every dead tile of its rows, of its result and of both
+    their cotangents, the layer's sum and every gradient are finite and the
+    clean layer's bits."""
+    from chainermn_tpu.ops import moe_gmm as ops
+
+    args = _inputs(case)
+    want = _layer(*args)
+    real = ops.moe_gmm_glu
+
+    def dirty(xs, w_gate, w_up, tile_expert, n_valid, *, tm, interpret):
+        dead = (jnp.arange(xs.shape[0]) // tm >= n_valid)[:, None]
+        return _nan_where(real(_nan_where(xs, dead), w_gate, w_up,
+                               tile_expert, n_valid, tm=tm,
+                               interpret=interpret), dead)
+
+    monkeypatch.setattr(ops, "moe_gmm_glu", dirty)
+    got = _layer(*args)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert np.isfinite(np.asarray(a.astype(jnp.float32))).all()
+        _same_bits(a, b, case)
+
+
 def _n_whiles(jaxpr) -> int:
     """``while`` equations of a jaxpr, the kernels' own bodies left out."""
     n = 0

@@ -364,16 +364,29 @@ def test_every_equation_of_the_expert_train_step_lies_under_one_leaf(
     assert booked == {None, "optimizer", "attn_proj", "attn_core",
                       "moe_route", "moe_experts", "ffn_dense"}
     assert set(train_scope_trace.TRAIN_BUCKETS) <= booked
-    # each kernel of the step sits where its metric looks for it
+    # each kernel of the step sits where its metric looks for it (a
+    # kernel's own name is the last scope of its call, inside the autodiff
+    # wrapping where no ``jit`` stands between: ``jvp(moe_gmm_glu)``)
+    kernel_of = lambda p: scope_path(p).rstrip("/").rsplit("/", 2)[-2]
     where = {k: {train_scope_trace.bucket_of(p) for p in paths
-                 if p.endswith("/pallas_call") and f"/{k}/" in p}
+                 if p.endswith("/pallas_call") and kernel_of(p) == k}
              for k in ("flash_fwd", "flash_bwd", "window_flash_fwd",
-                       "window_flash_bwd", "moe_gmm", "moe_gmm_dw")}
+                       "window_flash_bwd", "moe_gmm", "moe_gmm_dw",
+                       "moe_gmm_glu", "moe_gmm_glu_dx")}
     assert where == {"flash_fwd": {"attn_core"}, "flash_bwd": {"attn_core"},
                      "window_flash_fwd": {"attn_core"},
                      "window_flash_bwd": {"attn_core"},
                      "moe_gmm": {"moe_experts"},
-                     "moe_gmm_dw": {"moe_experts"}}
+                     "moe_gmm_dw": {"moe_experts"},
+                     "moe_gmm_glu": {"moe_experts"},
+                     "moe_gmm_glu_dx": {"moe_experts"}}
+    # ... and the fused pair under the products' own scope, in the phase
+    # that runs it: the transposed kernel in the backward alone
+    for p in paths:
+        if p.endswith("/pallas_call") and "moe_gmm_glu" in kernel_of(p):
+            assert "/block/moe/gmm/" in scope_path(p), p
+            if kernel_of(p) == "moe_gmm_glu_dx":
+                assert bucket_of(p) == "bwd", p
 
 
 # --------------------------------------------------------------------------
