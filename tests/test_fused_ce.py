@@ -13,6 +13,7 @@ from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import chainermn_tpu as mn
+from chainermn_tpu.ops import fused_ce
 from chainermn_tpu.ops.fused_ce import fused_cross_entropy
 
 T, D, V = 64, 32, 256
@@ -55,6 +56,73 @@ class TestSingleShard:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-4, atol=1e-5,
                                        err_msg=f"grad wrt {name}")
+
+    @pytest.mark.parametrize("mode", ["plain", "tpu"])
+    @pytest.mark.parametrize("nv", [1, 3])
+    @pytest.mark.parametrize("nt,resident,d,dtype", [
+        # all rows resident in VMEM
+        (1, None, 128, "float32"), (2, None, 128, "float32"),
+        (3, None, 128, "bfloat16"), (5, None, 128, "float32"),
+        # rows cut into super-blocks: dtable carried in HBM
+        (5, 2, 128, "float32"), (6, 1, 128, "bfloat16"),
+        (8, 3, 128, "float32"), (9, 4, 128, "float32"),
+        # wide enough to outgrow the default scoped VMEM
+        (6, 1, 8192, "float32")])
+    def test_one_backward_kernel_matches_the_emulation(
+            self, monkeypatch, nt, resident, d, dtype, nv, mode):
+        """``fused_ce_grads`` against ``_grads_xla`` over (T blocks, V
+        blocks): all rows resident, and rows cut into super-blocks whose
+        ``dtable`` is carried in HBM (``resident`` = the T blocks of ``dh``
+        the VMEM budget is set to hold) — 5 row blocks admit no cut into
+        super-blocks of three or more, so they stay whole: the floor the
+        revisit invariant sets.  Target ids outside the shard's range match
+        nothing (the vocab-parallel caller's masking); ``dnll`` is not
+        uniform.  ``tpu``: the interpreter that keeps an aliased input and
+        its result in ONE buffer, as the chip does."""
+        from jax.experimental.pallas import tpu as pltpu
+
+        bt, bv = 8, 128
+        t, v = nt * bt, nv * bv
+        rs = np.random.RandomState(nt * 10 + nv)
+        h = jnp.asarray(rs.randn(t, d) * 0.1, dtype)
+        tab = jnp.asarray(rs.randn(v, d) * 0.1, dtype)
+        tgt = jnp.asarray(rs.randint(-v // 2, v + v // 2, (t,)), jnp.int32)
+        assert int(((tgt < 0) | (tgt >= v)).sum()) > 0
+        dnll = jnp.asarray(rs.rand(t) + 0.1, jnp.float32)
+        if resident is not None:
+            monkeypatch.setattr(fused_ce, "_DH_RESIDENT_BYTES",
+                                resident * bt * d * 4)
+        ni = fused_ce._super_block(nt, bt * d * 4)
+        assert nt % ni == 0 and (ni == nt or ni >= 3)
+        assert (ni < nt) == (resident is not None and nt != 5)
+        asked = []
+        params = fused_ce._compiler_params
+        monkeypatch.setattr(
+            fused_ce, "_compiler_params",
+            lambda sem, nbytes: asked.append(nbytes) or params(sem, nbytes))
+        interpret = pltpu.InterpretParams() if mode == "tpu" else True
+        m, l, _ = fused_ce.ce_stats(h, tab, tgt, bt, bv)
+        lse = m + jnp.log(l)
+        dh, dtable = fused_ce.ce_grads(h, tab, tgt, lse, dnll, bt, bv,
+                                       interpret)
+        assert (asked[-1] > fused_ce._DEFAULT_VMEM_ROOM) == (d == 8192)
+        dh_x, dtable_x = fused_ce._grads_xla(h, tab, tgt, lse, dnll)
+        assert dh.dtype == h.dtype and dh.shape == h.shape
+        assert dtable.dtype == tab.dtype and dtable.shape == tab.shape
+        tol = dict(rtol=1e-4, atol=1e-5) if dtype == "float32" \
+            else dict(rtol=2e-2, atol=1e-3)
+        f32 = lambda x: np.asarray(x.astype(jnp.float32))
+        np.testing.assert_allclose(f32(dh), f32(dh_x), **tol)
+        np.testing.assert_allclose(f32(dtable), f32(dtable_x), **tol)
+
+    def test_backward_asks_for_the_vmem_it_holds(self):
+        """The limit follows the shapes: nothing asked where blocks and
+        scratch fit the default, the real count plus the tile's room where
+        they do not (``d`` sets every block's width)."""
+        small = fused_ce._compiler_params(("arbitrary",), 2 << 20)
+        assert small.vmem_limit_bytes is None
+        wide = fused_ce._compiler_params(("arbitrary",), 80 << 20)
+        assert wide.vmem_limit_bytes == (96 << 20)
 
     def test_small_row_count_uses_full_dim_block(self):
         """T smaller than the block is legal (full-dim blocks always are)."""

@@ -247,6 +247,9 @@ UNIT = [
      "rematted_computation/jvp(block/mlp)/tanh", "bwd", "block/mlp"),
     ("jit(train_step)/loss_grad/jvp(head_ce)/fused_ce_stats",
      "fwd", "head_ce"),
+    # the loss's one backward kernel, as its custom_vjp's rule is traced
+    ("jit(train_step)/loss_grad/transpose(loss_grad)/jvp(head_ce)/"
+     "fused_ce_grads/pallas_call", "bwd", "head_ce"),
     ("jit(train_step)/loss_grad/transpose(jvp(embed))/scatter-add",
      "bwd", "embed"),
     ("jit(train_step)/loss_grad/pmean", "fwd", "loss_grad"),
